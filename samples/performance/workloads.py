@@ -40,6 +40,21 @@ CSE_DEF = ("define stream cseEventStream (symbol string, price float, "
            "volume int, timestamp long); ")
 B = 8192
 
+# SimpleWindowSingleQueryPerformance.java:35
+SLIDING_WINDOW_Q = (
+    CSE_DEF + "@info(name='q0') from cseEventStream#window.length(10) "
+    "select symbol, sum(price) as total, avg(volume) as avgVolume, "
+    "timestamp insert into outputStream;")
+
+# GroupByWindowSingleQueryPerformance.java:35, device-eligible variant:
+# group keys + aggregates only (the faithful shape's bare `timestamp`
+# select item needs per-group last-row registers)
+GROUPBY_LENGTH_BATCH_AGG_ONLY_Q = (
+    CSE_DEF + "@info(name='q0') from cseEventStream"
+    "#window.lengthBatch(10) select symbol, sum(price) as total, "
+    "avg(volume) as avgVolume group by symbol "
+    "insert into outputStream;")
+
 
 def cse_batch(n_symbols: int, seed: int = 7) -> EventBatch:
     rng = np.random.default_rng(seed)
@@ -138,10 +153,7 @@ def workloads(seconds: float):
          "select symbol, price insert into outputStream;")
     row("filter_async", q, tpu + q, b)
 
-    # SimpleWindowSingleQueryPerformance.java:35
-    q = (CSE_DEF + "@info(name='q0') from cseEventStream#window.length(10) "
-         "select symbol, sum(price) as total, avg(volume) as avgVolume, "
-         "timestamp insert into outputStream;")
+    q = SLIDING_WINDOW_Q
     row("sliding_window", q, tpu + q, b, dev_expect={"q0": "device"})
 
     # GroupByWindowSingleQueryPerformance.java:35 (faithful shape: the
@@ -153,11 +165,7 @@ def workloads(seconds: float):
          "insert into outputStream;")
     row("groupby_length_batch", q, tpu + q, b)
 
-    # device-eligible variant: group keys + aggregates only
-    q = (CSE_DEF + "@info(name='q0') from cseEventStream"
-         "#window.lengthBatch(10) select symbol, sum(price) as total, "
-         "avg(volume) as avgVolume group by symbol "
-         "insert into outputStream;")
+    q = GROUPBY_LENGTH_BATCH_AGG_ONLY_Q
     row("groupby_length_batch_agg_only", q, tpu + q, b,
         dev_expect={"q0": "device"})
 

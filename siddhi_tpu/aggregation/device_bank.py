@@ -302,6 +302,25 @@ class DeviceBucketBank:
             self._scatter = jax.jit(fn)
         return self._scatter
 
+    def smoke_compile(self):
+        """Compile this bank's scatter — every lane's op and dtype — and
+        raise on failure with the compiler's message.  One event block
+        is enough: the kernel's block shapes do not depend on the batch
+        (kernels/bank_scatter.py)."""
+        import jax
+
+        from siddhi_tpu.kernels.bank_scatter import EVENT_BLOCK
+
+        def lane(kind, n):
+            return jax.ShapeDtypeStruct(
+                (n,), np.int32 if kind == "i32" else np.float32)
+
+        self._scatter_fn().lower(
+            [lane(kind, self.cap + 1) for _op, kind in self._lanes],
+            lane("i32", EVENT_BLOCK),
+            [lane(kind, EVENT_BLOCK) for _op, kind in self._lanes],
+        ).compile()
+
     # -- row assignment ------------------------------------------------------
 
     def assign(self, keys) -> bool:

@@ -189,12 +189,6 @@ ALLOWLISTS = {
         "siddhi_tpu/kernels/bank_scatter.py:segmented_reduce":
             "int(rows.shape[0]) / int(r_pad): static shape + python int "
             "forming the compile-cache key, not tracer material",
-        "siddhi_tpu/kernels/scan_chain.py:_build":
-            "float(neg) of a python scalar at build time — deliberately "
-            "a weak python float so Pallas sees a literal, not a const",
-        "siddhi_tpu/kernels/scan_chain.py:fused_scan":
-            "int(H)/int(n)/int(S)/float(neg): static shape unpack + "
-            "python scalar forming the compile-cache key",
         "siddhi_tpu/ops/device_query.py:DeviceQueryEngine.make_step.step":
             "bool(kinds & {...}) on a python set of aggregation kinds — "
             "static config closed over at trace time, not a tracer",
@@ -202,13 +196,9 @@ ALLOWLISTS = {
     "retrace-hazard": {
         # hot-sounding names that are actually plan-time, one-shot:
         "siddhi_tpu/planner/kernels.py:try_enable_scan_kernel":
-            "smoke_lower() jits once per app creation to validate the "
-            "Pallas lowering before committing the packed step — plan "
-            "time, never on the batch path",
-        "siddhi_tpu/planner/kernels.py:try_enable_bank_kernel":
-            "smoke_lower() jits once per app creation to validate the "
-            "Pallas lowering before committing the segmented reduce — "
-            "plan time, never on the batch path",
+            "smoke_compile() jits once per app creation to compile the "
+            "fused chain kernel before committing to it — plan time, "
+            "never on the batch path",
     },
     "fallback-discipline": {
         "siddhi_tpu/planner/monitor.py:PlanMonitor.decide":

@@ -58,7 +58,6 @@ SCANNED = (
     # Pallas kernels: the hottest device code in the tree — a
     # materialization inside a kernel wrapper would sync every step
     "siddhi_tpu/kernels/probe.py",
-    "siddhi_tpu/kernels/plane_pack.py",
     "siddhi_tpu/kernels/bank_scatter.py",
     "siddhi_tpu/kernels/scan_chain.py",
     "siddhi_tpu/kernels/dense_step.py",

@@ -11,7 +11,7 @@ IngestStats increments, logging, wall-clock reads — on the host side of
 the step boundary.
 
 The rule finds ``jax.jit(...)`` / ``shard_map(...)`` call sites (incl.
-``self.jax.jit`` receivers and ``get_shard_map()(...)``), resolves the
+``self.jax.jit`` receivers), resolves the
 callable argument to a function definition, and reports banned
 constructs anywhere in the resolved body:
 
@@ -66,11 +66,6 @@ def jit_call_sites(index: ModuleIndex) -> List[Tuple[ast.Call, ast.AST]]:
     for call in index.calls():
         name = index.dotted(call.func)
         is_wrapper = name in JIT_NAMES or name in SHARD_NAMES
-        if not is_wrapper and isinstance(call.func, ast.Call):
-            # get_shard_map()(step, ...): the wrapper is itself a call
-            inner = index.dotted(call.func.func)
-            is_wrapper = inner is not None and \
-                inner.split(".")[-1] == "get_shard_map"
         if is_wrapper and call.args:
             out.append((call, call.args[0]))
     return out

@@ -44,6 +44,7 @@ from siddhi_tpu.core.exceptions import (
     SiddhiAppRuntimeError,
 )
 from siddhi_tpu.query_api import AttrType, StateInputStream, Variable
+from siddhi_tpu.util.faults import notify_listeners
 
 log = logging.getLogger("siddhi_tpu")
 
@@ -614,15 +615,15 @@ class DensePatternRuntime:
         self.emit_queue.drain()
 
     def _on_fault(self, e: Exception):
-        """Emit-queue fault channel: surface isolated drain/callback
-        failures to the app's exception listeners (via the injector's
-        listener list, wired to them by the planner)."""
+        """Emit-queue / ingest-stage fault channel: surface isolated
+        drain, count-gate and callback failures to the app's exception
+        listeners — with or without the @app:faults harness."""
         # freeze the span ring: the post-mortem shows the cycles that
         # led into the isolated failure
         if self.tracer is not None:
             self.tracer.dump(f"onerror-isolation:{type(e).__name__}")
-        if self.faults is not None:
-            self.faults.notify(e)
+        notify_listeners(
+            getattr(self._app_context, "exception_listeners", None), e)
 
     def _emit_deferred(self, pending, ts, keys, host_arrays, now=None):
         ev_idx, out = pending.materialize(host_arrays)
@@ -657,8 +658,7 @@ class DensePatternRuntime:
     def overflow_total(self) -> int:
         """Total pending instances dropped because every successor lane
         was occupied (0 == the dense match set is bit-exact vs host).
-        Reduced ON DEVICE — only a scalar crosses to host (transfers are
-        expensive on tunneled/remote devices)."""
+        Reduced ON DEVICE — only a scalar crosses to host."""
         return int(self.engine.jnp.sum(self.state["overflow"]))
 
     def stats(self) -> Dict:

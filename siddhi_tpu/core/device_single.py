@@ -37,6 +37,7 @@ from siddhi_tpu.core.emit_queue import EmitQueue, EmitStats, PendingEmit
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats
 from siddhi_tpu.core.exceptions import SiddhiAppRuntimeError
+from siddhi_tpu.util.faults import notify_listeners
 
 import logging
 
@@ -66,8 +67,10 @@ class DeviceQueryRuntime:
     def __init__(self, engine, out_stream_id: str,
                  emit: Callable[[EventBatch], None], emit_depth=1,
                  clock: Optional[Callable[[], int]] = None, faults=None,
-                 ingest_depth=1, tracer=None):  # int or 'auto'
+                 ingest_depth=1, tracer=None,  # depths: int or 'auto'
+                 listeners=None):
         self.engine = engine
+        self._listeners = listeners  # the app's exception listeners
         self.out_stream_id = out_stream_id
         self.emit_cb = emit
         self.state = engine.init_state()
@@ -78,8 +81,9 @@ class DeviceQueryRuntime:
         self.step_invocations = 0  # proof the jitted path ran (tests)
         self.emit_stats = EmitStats()
         # @app:faults(...) injector: arms the emit.drain/state.poison
-        # sites and the isolation hook so a failing drain batch is
-        # logged + fed to exception listeners instead of killing the app
+        # sites.  The isolation hook below works with or without it: a
+        # failing drain batch is logged + fed to exception listeners
+        # instead of killing the app
         self.faults = faults
         self.emit_queue = EmitQueue(depth=emit_depth, stats=self.emit_stats,
                                     faults=faults, on_fault=self._on_fault)
@@ -107,8 +111,7 @@ class DeviceQueryRuntime:
         # span ring so the post-mortem shows the cycles leading up to it
         if self.tracer is not None:
             self.tracer.dump(f"onerror-isolation:{type(e).__name__}")
-        if self.faults is not None:
-            self.faults.notify(e)
+        notify_listeners(self._listeners, e)
 
     def _poison_guard(self) -> bool:
         """NaN/Inf quarantine, active only while a ``state.poison``

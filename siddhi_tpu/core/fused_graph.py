@@ -39,6 +39,7 @@ from siddhi_tpu.core.emit_queue import EmitQueue, EmitStats, PendingEmit
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.exceptions import SiddhiAppRuntimeError
 from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats
+from siddhi_tpu.util.faults import notify_listeners
 
 log = logging.getLogger("siddhi_tpu")
 
@@ -50,8 +51,10 @@ class FusedChainRuntime:
     def __init__(self, graph, out_stream_id: str,
                  emit: Callable[[EventBatch], None], emit_depth=1,
                  clock: Optional[Callable[[], int]] = None, faults=None,
-                 ingest_depth=1, tracer=None):  # int or 'auto'
+                 ingest_depth=1, tracer=None,  # depths: int or 'auto'
+                 listeners=None):
         self.graph = graph
+        self._listeners = listeners  # the app's exception listeners
         self.out_stream_id = out_stream_id
         self.emit_cb = emit
         self.state = graph.init_state()
@@ -85,8 +88,7 @@ class FusedChainRuntime:
         # led into the isolated failure
         if self.tracer is not None:
             self.tracer.dump(f"onerror-isolation:{type(e).__name__}")
-        if self.faults is not None:
-            self.faults.notify(e)
+        notify_listeners(self._listeners, e)
 
     def _poison_guard(self) -> bool:
         """NaN/Inf quarantine over the WHOLE chain's state tuple, active

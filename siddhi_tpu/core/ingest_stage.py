@@ -56,12 +56,15 @@ class IngestStats:
     thin-gauge style as ``EmitStats``)."""
 
     __slots__ = ("staged_batches", "device_puts", "ingest_stalls",
-                 "overlapped_batches", "flush_syncs", "max_staging_depth",
-                 "auto_depth")
+                 "overlapped_batches", "flush_syncs", "dropped_batches",
+                 "max_staging_depth", "auto_depth")
 
     def __init__(self):
         self.staged_batches = 0
         self.device_puts = 0
+        # staged batches whose finish (the count-gate fetch, where XLA
+        # reports an asynchronous step failure) raised and was isolated
+        self.dropped_batches = 0
         self.ingest_stalls = 0
         self.overlapped_batches = 0
         self.flush_syncs = 0
@@ -80,6 +83,7 @@ class IngestStats:
             "ingestStalls": self.ingest_stalls,
             "overlappedBatches": self.overlapped_batches,
             "flushSyncs": self.flush_syncs,
+            "droppedBatches": self.dropped_batches,
             "maxStagingDepth": self.max_staging_depth,
             "autoIngestDepth": self.auto_depth,
         }
@@ -91,7 +95,7 @@ def staged_put(x, sharding=None, faults=None, stats: Optional[IngestStats] = Non
     The one sanctioned ingest-path transfer primitive: arms the fault
     injector's ``ingest.put`` site (when a harness is configured) with
     the same bounded retry-with-backoff ladder the emit drain uses, so
-    transient tunnel faults recover and sticky ones propagate.  Counts
+    transient transfer faults recover and sticky ones propagate.  Counts
     one ``device_puts`` per call when ``stats`` is supplied.
     """
     import jax
@@ -225,6 +229,7 @@ class IngestStage:
         try:
             finish()
         except Exception as err:
+            self.stats.dropped_batches += 1
             log.error("ingest finish failed; dropping one staged "
                       "batch's emit: %s", err)
             if trace is not None:

@@ -13,10 +13,12 @@ from siddhi_tpu.compiler import SiddhiCompiler
 from siddhi_tpu.core.app_runtime import SiddhiAppRuntime
 from siddhi_tpu.core.context import SiddhiContext
 from siddhi_tpu.query_api import SiddhiApp
+from siddhi_tpu.util.compile_cache import configure_compile_cache
 
 
 class SiddhiManager:
     def __init__(self):
+        configure_compile_cache()
         self.siddhi_context = SiddhiContext()
         self._app_runtimes: Dict[str, SiddhiAppRuntime] = {}
 

@@ -43,6 +43,7 @@ from siddhi_tpu.core.emit_queue import EmitQueue, EmitStats, PendingEmit, fetch_
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats, staged_put
 from siddhi_tpu.planner.expr import N_KEY, TS_KEY
+from siddhi_tpu.util.faults import notify_listeners
 
 log = logging.getLogger("siddhi_tpu")
 
@@ -59,10 +60,11 @@ class DevTableJoinRuntime:
     def __init__(self, name: str, stream_side, table_side, stream_is_left: bool,
                  condition, key_expr, cond_stream_lanes: Dict[str, Tuple[str, np.dtype]],
                  out_stream_id: str, emit, emit_depth=1, ingest_depth=1,
-                 clock=None, faults=None, tracer=None):
+                 clock=None, faults=None, tracer=None, listeners=None):
         import jax
 
         self.name = name
+        self._listeners = listeners  # the app's exception listeners
         self.stream_side = stream_side
         self.table_side = table_side
         self.table = table_side.table
@@ -118,8 +120,7 @@ class DevTableJoinRuntime:
     def _on_fault(self, e):
         if self.tracer is not None:
             self.tracer.dump(f"onerror-isolation:{type(e).__name__}")
-        if self.faults is not None:
-            self.faults.notify(e)
+        notify_listeners(self._listeners, e)
 
     # -- batch entry ------------------------------------------------------
 

@@ -196,6 +196,7 @@ def try_plan_devtable_join(name: str, j, left, right, condition,
         clock=app_context.timestamp_generator.current_time,
         faults=app_context.fault_injector,
         tracer=app_context.tracer,
+        listeners=app_context.exception_listeners,
     )
 
 

@@ -4,17 +4,19 @@ Three kernels replace the XLA-compiled hot loops, each pinned
 bit-identical to the path it replaces and gated behind the planner the
 same way the shard/multiplex/fuse/hotkey paths are:
 
-- ``dense_step``  — bit-packed dense-NFA step: 32 batch rows' boolean
-  node activity per int32 lane (``plane_pack`` holds the layout and
-  the host converters that round-trip ``DensePatternEngine`` state).
+- ``dense_step``  — plane-layout dense-NFA step: every (node,
+  instance) pair is one int32 plane over the batch, a block of 1024
+  batch rows one vreg per plane.
 - ``bank_scatter`` — collision-free segmented reduce for the
   aggregation device bank, replacing the serializing scatter-add.
 - ``scan_chain``  — one fused kernel for the hotkey scan's max-plus
   matrix chain + counting chain, replacing the two-pass
   ``associative_scan``.
 
-Kernels compile via ``jax.experimental.pallas`` on TPU and run under
-``interpret=True`` everywhere else; ``probe.kernels_available()`` is
-the capability gate and every unavailable/ineligible engine falls back
-to the XLA path with a counted ``kernelFallbackReason``.
+Kernels compile through Mosaic on TPU and run under ``interpret=True``
+everywhere else (``probe.interpret_mode()``).  Block shapes never depend
+on the batch, so ``planner/kernels.py`` compiles each kernel once at app
+creation and knows then whether it runs; every refused or ineligible
+engine falls back to the XLA path with a counted
+``kernelFallbackReason`` carrying the compiler's message.
 """

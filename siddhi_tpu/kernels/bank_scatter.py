@@ -43,8 +43,8 @@ def _build(n_pad, r_pad, dtype_name, op, identity, interpret):
     from jax.experimental import pallas as pl
 
     dtype = jnp.dtype(dtype_name)
-    EB = min(n_pad, EVENT_BLOCK)
-    RB = min(r_pad, ROW_BLOCK)
+    EB = EVENT_BLOCK
+    RB = ROW_BLOCK
     grid = (r_pad // RB, n_pad // EB)
 
     def kernel(rows_ref, vals_ref, out_ref):
@@ -89,29 +89,24 @@ def _build(n_pad, r_pad, dtype_name, op, identity, interpret):
 def segmented_reduce(rows, vals, r_pad, op, identity, interpret):
     """Per-row reduction delta: (``rows [n]``, ``vals [n]``) → ``[r_pad]``.
 
-    ``rows`` must already be padded to a whole number of event blocks
-    with entries pointing at a dump row < ``r_pad`` and ``vals`` padded
-    with ``identity``.  The result is the reduction of each row's
-    events against ``identity`` — the caller combines it with the live
-    accumulator (``+`` for sums, ``min``/``max`` for extrema).
+    Events are padded here to a whole number of event blocks with a row
+    id no block owns and ``identity`` as the value, so the block shapes
+    — and the body Mosaic compiles — do not depend on ``n``.  The
+    result is the reduction of each row's events against ``identity`` —
+    the caller combines it with the live accumulator (``+`` for sums,
+    ``min``/``max`` for extrema).
     """
-    key = (int(rows.shape[0]), int(r_pad), str(vals.dtype), op, interpret)
+    import jax.numpy as jnp
+
+    n = int(rows.shape[0])
+    n_pad = -(-n // EVENT_BLOCK) * EVENT_BLOCK
+    if n_pad != n:
+        rows = jnp.pad(rows, (0, n_pad - n), constant_values=-1)
+        vals = jnp.pad(vals, (0, n_pad - n), constant_values=identity)
+    key = (n_pad, int(r_pad), str(vals.dtype), op, interpret)
     call = _cache.get(key)
     if call is None:
         call = _build(*key[:2], key[2], op, identity, interpret)
         _cache[key] = call
     out = call(rows.reshape(-1, 1), vals.reshape(-1, 1))
     return out[0]
-
-
-def smoke_lower():
-    """Lower one tiny segmented reduce end to end; raise on failure."""
-    import jax
-    import numpy as np
-
-    from siddhi_tpu.kernels import probe
-
-    call = _build(256, 256, "int32", "sum", 0, probe.interpret_mode())
-    rows = jax.ShapeDtypeStruct((256, 1), np.int32)
-    vals = jax.ShapeDtypeStruct((256, 1), np.int32)
-    jax.jit(call).lower(rows, vals)

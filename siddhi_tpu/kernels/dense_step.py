@@ -1,52 +1,51 @@
-"""Bit-packed Pallas step for the simple every-chain dense NFA class.
+"""Plane-layout Pallas step for the simple every-chain dense NFA class.
 
 The eligible class (gated in ``planner/kernels.py``) is the capture-free
 every-start chain: all nodes are plain stream states (``min==max==1``),
 no sequences, no group-every, no absent deadlines, no register slots, no
 mesh.  Inside that class the XLA step's carry shrinks to two arrays —
-node activity and the within anchor — and node activity packs 32 batch
-rows per int32 word: bit ``b`` of word ``w`` is batch row ``w*32 + b``
-(the collision rounds upstream guarantee each partition appears once
-per dispatch, so a batch row IS a partition for the step's purposes).
-``counts``/``regs`` are provably constant in this class and pass
-through the state dict untouched, so snapshot/restore, sharding, and
-the multiplex seat tiling keep seeing the existing layout.
+node activity and the within anchor.  ``counts``/``regs`` are provably
+constant in this class and pass through the state dict untouched, so
+snapshot/restore, sharding, and the multiplex seat tiling keep seeing
+the existing layout.
+
+Layout: the batch axis is the vector.  Every ``(node, instance)`` pair
+is one int32 *plane* over the batch, and a block of 1024 batch rows is
+exactly one ``(8, 128)`` vreg per plane — arrays enter the kernel as
+``[S, I, Bp/128, 128]`` with ``(S, I, 8, 128)`` blocks, so every block's
+last two dims are the native tile whatever the batch size, and the
+kernel body Mosaic compiles is the same for every batch the runtime
+dispatches (only the grid length changes).  The node sweep is unrolled
+in Python; the instance lanes and their rank matching ride the leading
+(untiled) dims, so every operand is a stack of whole vregs: elementwise
+int32/bool arithmetic, no reshapes, no in-kernel cumsum.
 
 The kernel mirrors the XLA step operation for operation — within
 expiry, the reversed node sweep, the rank-matched placement
-(``_rank_place``) and the overflow count — on packed planes, so
-detections, anchors, and overflow counters are bit-identical (pure
-boolean/int32 arithmetic; there is no float in the whole step).
-Candidate filters are lane-uniform in this class and are evaluated on
-the XLA side into one packed eligibility word row per node; output
-columns are pure per-event selects and are assembled outside the
-kernel from the emit mask, exactly as ``_emit_rows`` writes them.
+(``_rank_place``) and the overflow count — so detections, anchors, and
+overflow counters are bit-identical (there is no float in the whole
+step).  Candidate filters are lane-uniform in this class and are
+evaluated on the XLA side into one eligibility plane per node; output
+columns are pure per-event selects and are assembled outside the kernel
+from the emit mask, exactly as ``_emit_rows`` writes them.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict
 
 from siddhi_tpu.planner.expr import N_KEY, TS_KEY
 from siddhi_tpu.query_api import AttrType
 
 _INT_TYPES = (AttrType.INT, AttrType.LONG)
 
-# single-block ceiling: batches up to this size run as one grid point;
-# larger batches tile in 1024-row blocks (32 words) along the grid
-MAX_SINGLE_BLOCK = 1024
+SUBLANES = 8
+LANES = 128
+# batch rows per grid point: one int32 vreg per (node, instance) plane
+BLOCK_ROWS = SUBLANES * LANES
 
 
-def _batch_blocks(B: int) -> Tuple[int, int, int]:
-    """(padded batch, total words, words per block) for a batch of B."""
-    Bp = ((B + 31) // 32) * 32
-    if Bp <= MAX_SINGLE_BLOCK:
-        return Bp, Bp // 32, Bp // 32
-    Bp = ((Bp + MAX_SINGLE_BLOCK - 1) // MAX_SINGLE_BLOCK) * MAX_SINGLE_BLOCK
-    return Bp, Bp // 32, MAX_SINGLE_BLOCK // 32
-
-
-def build_packed_nfa(engine, stream_key: str, jit: bool = True):
+def build_plane_nfa(engine, stream_key: str, jit: bool = True):
     """Kernel-backed replacement for ``DensePatternEngine.make_step``.
 
     Same signature and same returns as the XLA step; only callable for
@@ -56,7 +55,6 @@ def build_packed_nfa(engine, stream_key: str, jit: bool = True):
     from jax.experimental import pallas as pl
 
     from siddhi_tpu.kernels import probe
-    from siddhi_tpu.kernels.plane_pack import pack_bits, unpack_bits
 
     S, I = engine.S, engine.I
     nodes = engine.nodes
@@ -66,118 +64,110 @@ def build_packed_nfa(engine, stream_key: str, jit: bool = True):
     out_int = engine.out_int
     O = max(len(out_spec), 1)
     n_iout = sum(out_int)
-    scratch_row = engine.n_partitions
     interpret = probe.interpret_mode()
     on_stream = [n.specs[0].stream_key == stream_key for n in nodes]
     int_out_idx: Dict[int, int] = {}
     for _oi, _isint in enumerate(out_int):
         if _isint:
             int_out_idx[_oi] = len(int_out_idx)
+    i32 = jnp.int32
 
-    _calls: Dict[Tuple[int, int], object] = {}
+    def kernel(ok_ref, a_ref, first_ref, ts_ref,
+               a_out, first_out, emit_out, anch_out, ovf_out):
+        ts = ts_ref[...]  # (8, 128): this block's relative timestamps
+        zero = jnp.zeros_like(ts)
+        lanes0 = jnp.zeros((I,) + ts.shape, i32)
 
-    def _pallas_call(W: int, WB: int):
-        call = _calls.get((W, WB))
-        if call is not None:
-            return call
-        BB = WB * 32
-        i32 = jnp.int32
+        def as_i32(mask):
+            # a select, not a cast: Mosaic folds eq(extui(x), extui(y))
+            # into an i1 compare it then cannot legalize
+            return jnp.where(mask, 1, 0).astype(i32)
 
-        def kernel(ok_ref, a_ref, first_ref, ts_ref,
-                   a_out, first_out, emit_out, anch_out, ovf_out):
-            ok = ok_ref[...]          # [S, WB] packed (valid pre-ANDed)
-            A = a_ref[...]            # [S*I, WB] packed activity
-            FT = first_ref[...]       # [S*I, BB] anchors
-            ts = ts_ref[...]          # [1, BB]
-            a = {s: A[s * I:(s + 1) * I, :] for s in range(S)}
-            first = {s: FT[s * I:(s + 1) * I, :] for s in range(S)}
+        # per node: the instance lanes' planes, (I, 8, 128)
+        a = [a_ref[s] != 0 for s in range(S)]
+        first = [first_ref[s] for s in range(S)]
 
-            if within is not None:
-                for s in range(S):
-                    fs = first[s]
-                    expired = (fs > 0) & ((ts - fs) > within)
-                    a[s] = a[s] & ~pack_bits(jax, jnp, expired)
-                    first[s] = jnp.where(expired, 0, fs)
+        if within is not None:
+            for s in range(S):
+                expired = (first[s] > 0) & ((ts - first[s]) > within)
+                a[s] = a[s] & ~expired
+                first[s] = jnp.where(expired, 0, first[s])
 
-            # the standing virgin: instance lane 0 of node 0, every row
-            row_i = jax.lax.broadcasted_iota(i32, (I, WB), 0)
-            lane0_pk = jnp.where(row_i == 0, i32(-1), i32(0))
+        emit = lanes0 != 0
+        anch = lanes0
+        ovf = zero
+        for s in reversed(range(S)):
+            if not on_stream[s]:
+                continue
+            ok = ok_ref[s] != 0  # valid pre-ANDed
+            if s == 0:
+                # the standing virgin: instance lane 0 of node 0 is
+                # always pending, on every row; fresh arming each event,
+                # so the anchor is THIS event
+                fire = jnp.concatenate(
+                    [ok[None], a[0][1:] & ok], axis=0) if I > 1 else ok[None]
+                first[0] = jnp.where(fire, ts, first[0])
+            else:
+                fire = a[s] & ok
+                first[s] = jnp.where(fire & (first[s] == 0), ts, first[s])
+                a[s] = a[s] & ~fire
+            anchor = jnp.where(first[s] > 0, first[s], ts)
+            if s == S - 1:
+                emit = emit | fire
+                anch = jnp.where(fire, anchor, anch)
+                continue
+            # rank-matched placement into node s+1 (_rank_place with
+            # counts == 0: free lanes are just the inactive ones).  The
+            # XLA step's inclusive cumsum - 1 equals the exclusive
+            # running count on every lane the masks let through.
+            free = ~a[s + 1]
+            src_rank, free_rank = [], []
+            n_fire, n_free = zero, zero
+            for i in range(I):
+                src_rank.append(n_fire)
+                free_rank.append(n_free)
+                n_fire = n_fire + as_i32(fire[i])
+                n_free = n_free + as_i32(free[i])
+            src_rank, free_rank = jnp.stack(src_rank), jnp.stack(free_rank)
+            placed = fire & (src_rank < n_free)
+            ovf = ovf + jnp.sum(as_i32(fire & ~placed), axis=0)
+            # (source lane, target lane, 8, 128) one-hot assignment: the
+            # pairing runs over leading dims, every operand a whole vreg
+            assign = (placed[:, None] & free[None, :]
+                      & (src_rank[:, None] == free_rank[None, :]))
+            got = jnp.sum(as_i32(assign), axis=0) > 0
+            moved = jnp.sum(jnp.where(assign, anchor[:, None], 0), axis=0)
+            a[s + 1] = a[s + 1] | got
+            first[s + 1] = jnp.where(got, moved, first[s + 1])
 
-            emit_pk = jnp.zeros((I, WB), i32)
-            anch = jnp.zeros((I, BB), i32)
-            ovf = jnp.zeros((1, BB), i32)
-            for s in reversed(range(S)):
-                if not on_stream[s]:
-                    continue
-                pend = a[s] | lane0_pk if s == 0 else a[s]
-                fire_pk = pend & ok[s:s + 1, :]
-                fire = unpack_bits(jax, jnp, fire_pk)  # [I, BB]
-                if s == 0:
-                    # fresh arming each event: anchor is THIS event
-                    first[0] = jnp.where(fire, ts, first[0])
-                else:
-                    first[s] = jnp.where(fire & (first[s] == 0), ts,
-                                         first[s])
-                    a[s] = a[s] & ~fire_pk
-                anchor = jnp.where(first[s] > 0, first[s], ts)  # [I, BB]
-                if s == S - 1:
-                    emit_pk = emit_pk | fire_pk
-                    anch = jnp.where(fire, anchor, anch)
-                    continue
-                # rank-matched placement into node s+1 (_rank_place with
-                # counts == 0: free lanes are just the inactive ones)
-                free = unpack_bits(jax, jnp, ~a[s + 1])  # [I, BB]
-                fire_i = fire.astype(i32)
-                free_i = free.astype(i32)
-                src_rank = jnp.cumsum(fire_i, axis=0) - 1
-                free_rank = jnp.cumsum(free_i, axis=0) - 1
-                n_free = jnp.sum(free_i, axis=0, keepdims=True)  # [1, BB]
-                placed = fire & (src_rank < n_free)
-                ovf = ovf + jnp.sum((fire & ~placed).astype(i32), axis=0,
-                                    keepdims=True)
-                assign = (placed[:, None, :] & free[None, :, :]
-                          & (src_rank[:, None, :] == free_rank[None, :, :]))
-                got = jnp.any(assign, axis=0)  # [I, BB] target lanes
-                moved = jnp.sum(jnp.where(assign, anchor[:, None, :], 0),
-                                axis=0)
-                a[s + 1] = a[s + 1] | pack_bits(jax, jnp, got)
-                first[s + 1] = jnp.where(got, moved, first[s + 1])
+        for s in range(S):
+            a_out[s] = as_i32(a[s])
+            first_out[s] = first[s]
+        emit_out[...] = as_i32(emit)
+        anch_out[...] = anch
+        ovf_out[...] = ovf
 
-            a_out[...] = jnp.concatenate([a[s] for s in range(S)], axis=0)
-            first_out[...] = jnp.concatenate(
-                [first[s] for s in range(S)], axis=0)
-            emit_out[...] = emit_pk
-            anch_out[...] = anch
-            ovf_out[...] = ovf
-
-        Bp = W * 32
-        call = pl.pallas_call(
+    def _call(G: int):
+        """pallas_call over ``G`` row groups of 128 (``G % 8 == 0``)."""
+        nodes_ = pl.BlockSpec((S, I, SUBLANES, LANES),
+                              lambda g: (0, 0, g, 0))
+        lanes_ = pl.BlockSpec((I, SUBLANES, LANES), lambda g: (0, g, 0))
+        oks = pl.BlockSpec((S, SUBLANES, LANES), lambda g: (0, g, 0))
+        row = pl.BlockSpec((SUBLANES, LANES), lambda g: (g, 0))
+        return pl.pallas_call(
             kernel,
-            grid=(W // WB,),
-            in_specs=[
-                pl.BlockSpec((S, WB), lambda i: (0, i)),
-                pl.BlockSpec((S * I, WB), lambda i: (0, i)),
-                pl.BlockSpec((S * I, BB), lambda i: (0, i)),
-                pl.BlockSpec((1, BB), lambda i: (0, i)),
-            ],
-            out_specs=[
-                pl.BlockSpec((S * I, WB), lambda i: (0, i)),
-                pl.BlockSpec((S * I, BB), lambda i: (0, i)),
-                pl.BlockSpec((I, WB), lambda i: (0, i)),
-                pl.BlockSpec((I, BB), lambda i: (0, i)),
-                pl.BlockSpec((1, BB), lambda i: (0, i)),
-            ],
+            grid=(G // SUBLANES,),
+            in_specs=[oks, nodes_, nodes_, row],
+            out_specs=[nodes_, nodes_, lanes_, lanes_, row],
             out_shape=[
-                jax.ShapeDtypeStruct((S * I, W), jnp.int32),
-                jax.ShapeDtypeStruct((S * I, Bp), jnp.int32),
-                jax.ShapeDtypeStruct((I, W), jnp.int32),
-                jax.ShapeDtypeStruct((I, Bp), jnp.int32),
-                jax.ShapeDtypeStruct((1, Bp), jnp.int32),
+                jax.ShapeDtypeStruct((S, I, G, LANES), i32),
+                jax.ShapeDtypeStruct((S, I, G, LANES), i32),
+                jax.ShapeDtypeStruct((I, G, LANES), i32),
+                jax.ShapeDtypeStruct((I, G, LANES), i32),
+                jax.ShapeDtypeStruct((G, LANES), i32),
             ],
             interpret=interpret,
         )
-        _calls[(W, WB)] = call
-        return call
 
     def env_for(s, cols, ts):
         env = {}
@@ -196,11 +186,12 @@ def build_packed_nfa(engine, stream_key: str, jit: bool = True):
 
     def step(state, part_idx, cols, ts, valid):
         B = part_idx.shape[0]
-        Bp, W, WB = _batch_blocks(B)
+        Bp = -(-B // BLOCK_ROWS) * BLOCK_ROWS
+        G = Bp // LANES
         pad = Bp - B
 
-        # lane-uniform candidate filters, evaluated XLA-side: one packed
-        # eligibility row per node, pre-ANDed with the valid mask
+        # lane-uniform candidate filters, evaluated XLA-side: one
+        # eligibility plane per node, pre-ANDed with the valid mask
         ok_rows = []
         for s in range(S):
             if not on_stream[s]:
@@ -226,20 +217,23 @@ def build_packed_nfa(engine, stream_key: str, jit: bool = True):
         else:
             ts_p = ts
 
-        a_pk = pack_bits(jax, jnp,
-                         a.transpose(1, 2, 0).reshape(S * I, Bp))
-        first_t = first.transpose(1, 2, 0).reshape(S * I, Bp)
-        ok_pk = pack_bits(jax, jnp, ok_mat)
+        def to_planes(x):  # [Bp, S, I] -> [S, I, G, 128]
+            return x.transpose(1, 2, 0).reshape(S, I, G, LANES)
 
-        a_o, first_o, emit_o, anch_o, ovf_o = _pallas_call(W, WB)(
-            ok_pk, a_pk, first_t, ts_p.reshape(1, Bp))
+        def from_planes(x):  # [..., I, G, 128] -> [B, ..., I]
+            x = x.reshape(x.shape[:-2] + (Bp,))
+            return jnp.moveaxis(x, -1, 0)[:B]
 
-        a_new = unpack_bits(jax, jnp, a_o).reshape(S, I, Bp)
-        a_new = a_new.transpose(2, 0, 1)[:B]
-        first_new = first_o.reshape(S, I, Bp).transpose(2, 0, 1)[:B]
-        emit_b0 = unpack_bits(jax, jnp, emit_o).transpose(1, 0)[:B]  # [B, I]
-        anch_b0 = anch_o.transpose(1, 0)[:B]
-        ovf_delta = ovf_o[0, :B]
+        a_o, first_o, emit_o, anch_o, ovf_o = _call(G)(
+            ok_mat.astype(i32).reshape(S, G, LANES),
+            to_planes(a.astype(i32)), to_planes(first),
+            ts_p.reshape(G, LANES))
+
+        a_new = from_planes(a_o) != 0
+        first_new = from_planes(first_o)
+        emit_b0 = from_planes(emit_o) != 0  # [B, I]
+        anch_b0 = from_planes(anch_o)
+        ovf_delta = ovf_o.reshape(Bp)[:B]
 
         emit = jnp.concatenate(
             [emit_b0, jnp.zeros((B, I), dtype=bool)], axis=1)
@@ -296,13 +290,16 @@ def build_packed_nfa(engine, stream_key: str, jit: bool = True):
     return jax.jit(step, donate_argnums=(0,)) if jit else step
 
 
-def smoke_lower(engine):
-    """Lower the kernel step for every source stream at a tiny batch;
-    raise on any failure (Mosaic rejection, shape bug, ...).
+def smoke_compile(engine):
+    """Compile the kernel step for every source stream; raise on any
+    failure (a Mosaic refusal, a shape bug, ...) with the compiler's
+    message.
 
-    Goes through ``engine.make_step`` (the engine's ``use_kernel`` flag
-    must already be set) so the traced function lands in the engine's
-    step cache and is reused at runtime.
+    One block of batch rows is enough: the kernel's block shapes do not
+    depend on the batch size, so the body Mosaic accepts here is the
+    body every runtime batch runs.  Goes through ``engine.make_step``
+    (the engine's ``use_kernel`` flag must already be set) so the traced
+    function lands in the engine's step cache and is reused at runtime.
     """
     import numpy as np
 
@@ -311,7 +308,7 @@ def smoke_lower(engine):
     state_shapes = {
         k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in host.items()
     }
-    B = 32
+    B = BLOCK_ROWS
     i32 = jax.ShapeDtypeStruct((B,), np.int32)
     b1 = jax.ShapeDtypeStruct((B,), np.bool_)
     for sk in engine.stream_keys:
@@ -320,5 +317,4 @@ def smoke_lower(engine):
                 (B,), np.int32 if "|" in k else np.float32)
             for k in engine.device_col_keys(sk)
         }
-        step = engine.make_step(sk)
-        step.lower(state_shapes, i32, cols, i32, b1)
+        engine.make_step(sk).lower(state_shapes, i32, cols, i32, b1).compile()

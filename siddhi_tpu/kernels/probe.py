@@ -1,25 +1,11 @@
-"""Capability probe for the Pallas kernel layer.
+"""Where the Pallas kernels compile and where they are interpreted.
 
-``kernels_available()`` answers "can this process build Pallas kernels
-at all" once per process: the ``jax.experimental.pallas`` import plus a
-trivial kernel lowered end to end.  Per-engine eligibility and the
-per-engine smoke lowering live in ``planner/kernels.py``; this module
-only rules out environments where no kernel could ever build (no
-Pallas in the jax install, broken lowering pipeline).
-
-On anything that is not a TPU backend the kernels run under
-``interpret=True`` — semantics-exact, speed-irrelevant — which is what
-keeps the tier-1 differential tests meaningful on CPU.
+On a TPU backend the kernels go through Mosaic.  On anything else they
+run under ``interpret=True`` — semantics-exact, speed-irrelevant — which
+is what keeps the tier-1 differential tests meaningful on CPU.
 """
 
 from __future__ import annotations
-
-import logging
-from typing import Optional, Tuple
-
-log = logging.getLogger("siddhi_tpu")
-
-_PROBE: Optional[Tuple[bool, str]] = None
 
 
 def interpret_mode() -> bool:
@@ -27,43 +13,3 @@ def interpret_mode() -> bool:
     import jax
 
     return jax.default_backend() != "tpu"
-
-
-def kernels_available() -> Tuple[bool, str]:
-    """(ok, reason): can this process lower a Pallas kernel at all?
-
-    Cached for the life of the process — the answer cannot change
-    underneath us, and the trivial lowering is not free.
-    """
-    global _PROBE
-    if _PROBE is not None:
-        return _PROBE
-
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-    except Exception as e:  # pragma: no cover - depends on jax build
-        log.warning("pallas kernels unavailable: import failed: %s", e)
-        _PROBE = (False, f"pallas import failed: {e}")
-        return _PROBE
-
-    try:
-
-        def _k(x_ref, o_ref):
-            o_ref[...] = x_ref[...] + 1
-
-        fn = pl.pallas_call(
-            _k,
-            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.int32),
-            interpret=interpret_mode(),
-        )
-        x = jax.ShapeDtypeStruct((8, 128), jnp.int32)
-        jax.jit(fn).lower(x)
-    except Exception as e:  # pragma: no cover - depends on backend
-        log.warning("pallas kernels unavailable: probe lowering failed: %s", e)
-        _PROBE = (False, f"pallas probe lowering failed: {e}")
-        return _PROBE
-
-    _PROBE = (True, "")
-    return _PROBE

@@ -82,12 +82,21 @@ class DenseMultiplexGroup:
         engine.ingest_stats = self.ingest_stats
         engine.faults = None  # per-tenant injection lives in the adapters
         self.emit_queue = EmitQueue(depth=1, stats=self.emit_stats,
-                                    faults=None, on_fault=None)
+                                    faults=None, on_fault=self._on_fault)
         self.state = engine.init_state()
         self._init_host = engine.init_state_host()
         self.dispatches = 0
         self.combined_steps = 0
         self._ovf_warned = 0
+
+    def _on_fault(self, e: BaseException) -> None:
+        """A failed group drain loses the cycle's matches of every
+        seated tenant: each one's exception listeners hear of it."""
+        with self.lock:
+            adapters = [s.adapter for s in self.seats
+                        if s is not None and s.adapter is not None]
+        for a in adapters:
+            a._on_fault(e)
 
     # -- seat lifecycle ----------------------------------------------------
 
@@ -328,8 +337,9 @@ class DenseMultiplexTenantRuntime:
 
     def __init__(self, group: DenseMultiplexGroup, slot: int,
                  out_stream_id: str, emit,
-                 clock=None, faults=None, registry=None):
+                 clock=None, faults=None, registry=None, listeners=None):
         self.group = group
+        self._listeners = listeners  # the app's exception listeners
         self.slot = slot
         self.engine = group.engine
         self.out_stream_id = out_stream_id
@@ -395,8 +405,7 @@ class DenseMultiplexTenantRuntime:
             self.emit_cb(mb)
 
     def _on_fault(self, e: BaseException) -> None:
-        if self.faults is not None:
-            self.faults.notify(e)
+        _faults.notify_listeners(self._listeners, e)
 
     # -- barriers / scheduler ----------------------------------------------
 

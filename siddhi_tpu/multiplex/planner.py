@@ -172,7 +172,8 @@ class MultiplexPlanner:
             emit=lambda b: qr.process(b, 0),
             clock=self.ctx.timestamp_generator.current_time,
             faults=self.ctx.fault_injector,
-            registry=registry)
+            registry=registry,
+            listeners=self.ctx.exception_listeners)
         qr.device_runtime = runtime
         junction = self.app.junction_for_input(s)
         junction.subscribe(_DeviceQueryReceiver(runtime))
@@ -274,7 +275,8 @@ class MultiplexPlanner:
             emit=lambda b: qr.process(b, 0),
             clock=self.ctx.timestamp_generator.current_time,
             faults=self.ctx.fault_injector,
-            registry=registry)
+            registry=registry,
+            listeners=self.ctx.exception_listeners)
         qr.pattern_processor = runtime
         for sk in engine.stream_keys:
             junction = self.app.junctions.get(sk)

@@ -516,8 +516,9 @@ class MultiplexTenantRuntime:
 
     def __init__(self, group: TumblingMultiplexGroup, slot: int,
                  out_stream_id: str, emit,
-                 clock=None, faults=None, registry=None):
+                 clock=None, faults=None, registry=None, listeners=None):
         self.group = group
+        self._listeners = listeners  # the app's exception listeners
         self.slot = slot
         self.engine = group.engine
         self.out_stream_id = out_stream_id
@@ -592,8 +593,7 @@ class MultiplexTenantRuntime:
         self.emit_cb(mb)
 
     def _on_fault(self, e: BaseException) -> None:
-        if self.faults is not None:
-            self.faults.notify(e)
+        _faults.notify_listeners(self._listeners, e)
 
     # -- barriers / scheduler ----------------------------------------------
 

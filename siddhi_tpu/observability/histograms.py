@@ -22,7 +22,7 @@ class LatencyHistogram:
 
     #: upper bounds in ms; everything past the last bound lands in the
     #: +Inf overflow bucket.  50 µs .. 5 s covers a host callback tick
-    #: through a tunneled checkpoint write.
+    #: through a slow checkpoint write.
     BOUNDS_MS: Tuple[float, ...] = (
         0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
         100.0, 250.0, 500.0, 1000.0, 2500.0, 5000.0,

@@ -632,9 +632,9 @@ class DensePatternEngine:
         if cache_key in self._step_cache:
             return self._step_cache[cache_key]
         if self.use_kernel:
-            from siddhi_tpu.kernels.dense_step import build_packed_nfa
+            from siddhi_tpu.kernels.dense_step import build_plane_nfa
 
-            fn = build_packed_nfa(self, stream_key, jit)
+            fn = build_plane_nfa(self, stream_key, jit)
             self._step_cache[cache_key] = fn
             return fn
         jnp = self.jnp

@@ -266,6 +266,17 @@ def _subst_aliases(expr: Expression, aliases: Dict[str, Expression]) -> Expressi
     return _map_children(expr, lambda e: _subst_aliases(e, aliases))
 
 
+def _mm_f32(a, b):
+    """Mask-times-values matmul in true float32.  The TPU's default f32
+    matmul is one bf16 pass, which would round sums of prices to ~3
+    digits; HIGHEST keeps the float32 contract of the module docstring
+    (ops/hotkey_scan.py does the same for its count matmuls)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _pow2(n: int, floor: int = 16) -> int:
     return max(1 << (max(n, 1) - 1).bit_length(), floor)
 
@@ -1053,8 +1064,9 @@ class DeviceQueryEngine:
                 same = (grp[:, None] == grp[None, :]) & fmask[None, :]
                 m = tri * same.astype(jnp.float32)  # [B, B]
                 masked_vals = argvals * fmask[:, None].astype(jnp.float32)
-                psum = m @ masked_vals  # [B, A]
-                pcnt = m @ fmask[:, None].astype(jnp.float32)  # [B, 1]
+                psum = _mm_f32(m, masked_vals)  # [B, A]
+                pcnt = _mm_f32(
+                    m, fmask[:, None].astype(jnp.float32))  # [B, 1]
                 kinds = self._kinds()
                 prev_sum = state.get("acc_sum")
                 wsum = ((prev_sum[grp] if prev_sum is not None else 0.0)
@@ -1063,7 +1075,7 @@ class DeviceQueryEngine:
                 wsumsq = None
                 if "acc_sumsq" in state:
                     wsumsq = (state["acc_sumsq"][grp]
-                              + m @ (masked_vals * argvals))
+                              + _mm_f32(m, masked_vals * argvals))
                 # one prefix pass covers min/max AND the forever pair
                 wmin = wmax = None
                 pmin, pmax = self._prefix_minmax(
@@ -1161,7 +1173,7 @@ class DeviceQueryEngine:
         mbufa = mbuf & (b_grp == grp[:, None])
         f32 = jnp.float32
         kinds = self._kinds()
-        bsum = mba.astype(f32) @ argvals  # [B, A]
+        bsum = _mm_f32(mba.astype(f32), argvals)  # [B, A]
         bcnt = jnp.sum(mba, axis=1).astype(f32)[:, None]  # [B, 1]
         usum = jnp.sum(b_vals * mbufa.astype(f32)[:, :, None], axis=1)
         ucnt = jnp.sum(mbufa, axis=1).astype(f32)[:, None]
@@ -1169,7 +1181,7 @@ class DeviceQueryEngine:
         wcnt = bcnt + ucnt
         wsumsq = None
         if "stdDev" in kinds:
-            wsumsq = (mba.astype(f32) @ (argvals * argvals)
+            wsumsq = (_mm_f32(mba.astype(f32), argvals * argvals)
                       + jnp.sum(b_vals * b_vals
                                 * mbufa.astype(f32)[:, :, None], axis=1))
         env_out = dict(env)

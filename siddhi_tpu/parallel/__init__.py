@@ -23,7 +23,6 @@ from siddhi_tpu.parallel.device_shard import ShardedDeviceQueryEngine
 from siddhi_tpu.parallel.mesh import (
     ShardedPatternEngine,
     distributed_initialize,
-    ensure_virtual_devices,
     make_mesh,
     route_to_shards,
 )
@@ -32,7 +31,6 @@ __all__ = [
     "ShardedDeviceQueryEngine",
     "ShardedPatternEngine",
     "distributed_initialize",
-    "ensure_virtual_devices",
     "make_mesh",
     "route_to_shards",
 ]

@@ -134,9 +134,7 @@ class ShardedDeviceQueryEngine:
         col_keys = list(engine.host_lane_cols({}, 0))
         out_names = [nm for kind, _v, nm in engine.out_spec
                      if kind == "expr"]
-        from siddhi_tpu.parallel.mesh import get_shard_map
-
-        shard_map = get_shard_map()
+        shard_map = jax.shard_map
         self._P = P
         self._NamedSharding = NamedSharding
         self._jax = jax

@@ -71,9 +71,7 @@ class ShardedFusedGraphEngine(FusedGraphEngine):
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from siddhi_tpu.parallel.mesh import get_shard_map
-
-        shard_map = get_shard_map()
+        shard_map = jax.shard_map
         raw = self._build_fused()
         a = self.axis_name
 

@@ -33,10 +33,7 @@ def distributed_initialize(coordinator_address: Optional[str] = None,
     plat = (os.environ.get("JAX_PLATFORMS", "")
             or str(getattr(jax.config, "jax_platforms", None) or ""))
     if plat.startswith("cpu"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # older jax: option absent; collectives may
-            pass           # still work via the default implementation
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -44,98 +41,26 @@ def distributed_initialize(coordinator_address: Optional[str] = None,
     )
 
 
-def ensure_virtual_devices(n_devices: int) -> bool:
-    """Best-effort bootstrap of >=n virtual CPU devices for mesh testing.
-
-    Must run before the CPU backend initializes (jax.config rejects the
-    update afterwards).  Returns True if >=n CPU devices are configured
-    or already available; False (with a warning) if the update was
-    rejected because backends initialized first — callers then see the
-    real device count and can raise a clear error."""
-    import warnings
-
-    import jax
-
-    try:
-        if int(jax.config.jax_num_cpu_devices or 0) < n_devices:
-            jax.config.update("jax_num_cpu_devices", n_devices)
-        return True
-    except Exception as e:
-        # older jax (< jax_num_cpu_devices): the XLA flag serves the
-        # same purpose and is likewise read lazily at CPU-backend init.
-        # Must run before the jax.devices() probe below — the probe
-        # itself initializes the CPU backend.
-        try:
-            import os
-
-            from jax._src import xla_bridge as _xb
-
-            if not _xb.backends_are_initialized():
-                flags = os.environ.get("XLA_FLAGS", "")
-                if "xla_force_host_platform_device_count" not in flags:
-                    os.environ["XLA_FLAGS"] = (
-                        f"{flags} --xla_force_host_platform_device_count="
-                        f"{n_devices}").strip()
-                return True
-        except Exception as xe:
-            # internal-module probe (jax._src.xla_bridge) is version-
-            # fragile by design; fall through to the device-count probe
-            import logging
-
-            logging.getLogger("siddhi_tpu.mesh").debug(
-                "XLA_FLAGS virtual-device probe unavailable: %s", xe)
-        try:
-            if len(jax.devices("cpu")) >= n_devices:
-                return True
-        except RuntimeError:
-            pass
-        warnings.warn(
-            f"could not configure {n_devices} virtual CPU devices "
-            f"(backends already initialized?): {e}", RuntimeWarning)
-        return False
-
-
-def get_shard_map():
-    """``jax.shard_map`` moved out of ``jax.experimental`` only in newer
-    jax releases — resolve whichever spelling this jax provides."""
-    import jax
-
-    try:
-        return jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
 def make_mesh(n_devices: Optional[int] = None, axis_name: str = "p",
               devices=None):
-    """1-D device mesh over the partition axis.
-
-    Falls back to virtual CPU devices when the default platform is short
-    (e.g. a single real TPU chip): sharding semantics are identical, so
-    the multi-chip path stays testable everywhere.  The fallback must
-    configure the CPU device count BEFORE any backend initializes, so it
-    is attempted before the default jax.devices() lookup."""
+    """1-D device mesh over the partition axis, built from the default
+    platform's devices and nothing else: a platform with fewer than
+    ``n_devices`` raises — it never borrows devices of another platform,
+    so a mesh asked for on a one-chip machine cannot land on host CPUs.
+    CPU runs (tests, ``dryrun_multichip``) pin ``JAX_PLATFORMS=cpu`` and
+    the virtual device count before any backend starts."""
     import jax
     from jax.sharding import Mesh
 
     if devices is None:
-        if n_devices is not None:
-            ensure_virtual_devices(n_devices)
         devices = jax.devices()
-        if n_devices is not None and len(devices) < n_devices:
-            try:
-                devices = jax.devices("cpu")
-            except RuntimeError:
-                pass
     if n_devices is not None:
         if len(devices) < n_devices:
             raise SiddhiAppCreationError(
-                f"need {n_devices} devices, have {len(devices)} "
-                "(set JAX_NUM_CPU_DEVICES / "
-                "XLA_FLAGS=--xla_force_host_platform_device_count for CPU testing)"
-            )
+                f"need {n_devices} devices, platform "
+                f"'{devices[0].platform}' has {len(devices)} (for CPU "
+                "testing set JAX_PLATFORMS=cpu and JAX_NUM_CPU_DEVICES "
+                "before JAX starts)")
         devices = devices[:n_devices]
     return Mesh(np.asarray(devices), axis_names=(axis_name,))
 
@@ -256,7 +181,7 @@ class ShardedPatternEngine:
 
         # donate the state pytree: at 1M+ partitions the rows dominate
         # HBM and double-buffering them would halve capacity
-        self._step = jax.jit(get_shard_map()(
+        self._step = jax.jit(jax.shard_map(
             sharded_step,
             mesh=mesh,
             in_specs=(specs, P(a), {k: P(a) for k in self.col_keys},
@@ -319,10 +244,9 @@ class ShardedPatternEngine:
         """One sharded step: ``(state', emit[B, 2I], out_vals[B, 2I, O],
         emit_anchor[B, 2I], global_matches)``.
 
-        The input ``state`` is DONATED (its device buffers are consumed
-        on real hardware — snapshot it before stepping if needed; always
-        rebind to the returned state).  CPU meshes ignore donation, so
-        only device runs surface misuse."""
+        The input ``state`` is DONATED (its buffers are consumed, on the
+        CPU backend too — snapshot it before stepping if needed; always
+        rebind to the returned state)."""
         return self._step(state, part, cols, ts, valid)
 
     def process(self, state, part: np.ndarray, cols: Dict[str, np.ndarray],

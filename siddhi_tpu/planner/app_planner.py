@@ -44,6 +44,7 @@ class AppPlanner:
 
         self.name = (name_ann.element() if name_ann else None) or f"app_{uuid.uuid4().hex[:8]}"
         self.app_context = SiddhiAppContext(siddhi_context, self.name)
+        self.tpu_mesh = None  # @app:execution('tpu', devices='N')
         playback = find_annotation(siddhi_app.annotations, "app:playback")
         if playback is not None:
             from siddhi_tpu.compiler.parser import parse_time_string
@@ -107,6 +108,14 @@ class AppPlanner:
                         f"@app:execution: partitions="
                         f"{self.app_context.tpu_partitions} must be "
                         f"divisible by devices={nd}")
+                # one app-wide mesh (shared by the dense pattern axis and
+                # the device-query group axis), built here rather than
+                # inside a query's lowering: a platform with too few
+                # devices fails the app instead of becoming one more
+                # counted fallback to the host engine
+                from siddhi_tpu.parallel import make_mesh
+
+                self.tpu_mesh = make_mesh(nd)
             depth = exec_ann.element("emit.depth")
             if depth:
                 if depth.lower() == "auto":

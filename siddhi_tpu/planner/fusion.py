@@ -388,7 +388,7 @@ def _try_lower_chain(app, qp, entries, run: List[int], hops: List[str],
 
         sm = ctx.statistics_manager
         try:
-            graph = ShardedFusedGraphEngine(stages, qp._get_mesh(nd))
+            graph = ShardedFusedGraphEngine(stages, app.tpu_mesh)
             log.info("fused chain %s: batch axis sharded over %d devices",
                      chain_label, nd)
         except SiddhiAppCreationError as e:
@@ -515,7 +515,8 @@ def _wire_chain(app, qp, entries, run: List[int], hops: List[str],
         clock=ctx.timestamp_generator.current_time,
         faults=ctx.fault_injector,
         ingest_depth=ctx.tpu_ingest_depth,
-        tracer=ctx.tracer)
+        tracer=ctx.tracer,
+        listeners=ctx.exception_listeners)
     qr.device_runtime = runtime
 
     head_q, _hn = entries[run[0]]

@@ -18,9 +18,10 @@ Mirrors planner/hotkeys.py: every rejection raises
 wrappers convert that into a counted ``Queries.<q>.kernelFallbacks`` /
 ``kernelFallbackReason`` on the stats feed and leave the runtime on
 its plain XLA path (graceful: @app:kernels never breaks a running
-app).  Each enable ends with a smoke lowering through the real shapes,
-so a Mosaic rejection on an exotic TPU generation is also a counted
-fallback, not a first-batch crash.
+app).  Each enable ends by COMPILING the kernel at the block shape it
+runs with (on TPU through Mosaic; interpreted elsewhere), so a compiler
+refusal is known at app creation with the compiler's message in
+``kernelFallbackReason`` — never at the first batch, never silently.
 """
 
 from __future__ import annotations
@@ -30,15 +31,6 @@ import logging
 from siddhi_tpu.core.exceptions import SiddhiAppCreationError
 
 log = logging.getLogger("siddhi_tpu")
-
-
-def check_kernels_available() -> None:
-    """Process-level gate: Pallas importable + trivial kernel lowers."""
-    from siddhi_tpu.kernels import probe
-
-    ok, reason = probe.kernels_available()
-    if not ok:
-        raise SiddhiAppCreationError(reason)
 
 
 def check_dense_kernel_eligible(engine) -> None:
@@ -74,12 +66,11 @@ def check_dense_kernel_eligible(engine) -> None:
 
 
 def try_enable_dense_kernel(app, runtime, qname: str) -> bool:
-    """Swap a DensePatternRuntime's step for the packed-plane kernel;
-    False (counted, logged) when ineligible or the lowering fails."""
+    """Swap a DensePatternRuntime's step for the plane kernel; False
+    (counted, logged) when ineligible or the compile fails."""
     sm = app.app_context.statistics_manager
     engine = runtime.engine
     try:
-        check_kernels_available()
         check_dense_kernel_eligible(engine)
         if getattr(runtime, "mesh", None) is not None:
             raise SiddhiAppCreationError(
@@ -90,17 +81,17 @@ def try_enable_dense_kernel(app, runtime, qname: str) -> bool:
         try:
             from siddhi_tpu.kernels import dense_step
 
-            dense_step.smoke_lower(engine)
+            dense_step.smoke_compile(engine)
         except Exception as e:
             engine.use_kernel = False
             engine._step_cache.clear()
             raise SiddhiAppCreationError(
-                f"nfa kernel: lowering failed: {e}")
+                f"nfa kernel: compile failed: {e}")
         runtime.lowered_to = "kernel"
         return True
     except SiddhiAppCreationError as e:
         log.warning(
-            "query '%s': @app:kernels(nfa) requested but the packed "
+            "query '%s': @app:kernels(nfa) requested but the plane "
             "step cannot be used, staying on XLA: %s", qname, e)
         if sm is not None:
             sm.record_kernel_fallback(qname, str(e))
@@ -109,23 +100,22 @@ def try_enable_dense_kernel(app, runtime, qname: str) -> bool:
 
 def try_enable_scan_kernel(app, router, qname: str) -> bool:
     """Swap a hotkey router's scan step for the fused chain kernel;
-    False (counted, logged) when unavailable or the lowering fails."""
+    False (counted, logged) when the compile fails."""
     sm = app.app_context.statistics_manager
     scan = router._scan
     try:
-        check_kernels_available()
         scan.use_kernel = True
         scan._step_fn = None
         try:
             from siddhi_tpu.kernels import scan_chain
             from siddhi_tpu.ops.nfa_scan import NEG
 
-            scan_chain.smoke_lower(scan.n_nodes, scan.n_slots, NEG)
+            scan_chain.smoke_compile(scan.n_nodes, scan.n_slots, NEG)
         except Exception as e:
             scan.use_kernel = False
             scan._step_fn = None
             raise SiddhiAppCreationError(
-                f"scan kernel: lowering failed: {e}")
+                f"scan kernel: compile failed: {e}")
         return True
     except SiddhiAppCreationError as e:
         log.warning(
@@ -136,28 +126,25 @@ def try_enable_scan_kernel(app, router, qname: str) -> bool:
         return False
 
 
-def try_enable_bank_kernel(ctx, agg_name: str) -> bool:
-    """Decide whether a DeviceBucketBank should route its scatter
-    through the segmented-reduce kernel; False (counted, logged) when
-    unavailable or the lowering fails."""
+def try_enable_bank_kernel(ctx, agg_name: str, bank) -> bool:
+    """Route a DeviceBucketBank's scatter through the segmented-reduce
+    kernel; False (counted, logged) when the compile fails."""
     sm = ctx.statistics_manager
+    bank.use_kernel = True
+    bank._scatter = None
     try:
-        check_kernels_available()
-        try:
-            from siddhi_tpu.kernels import bank_scatter
-
-            bank_scatter.smoke_lower()
-        except Exception as e:
-            raise SiddhiAppCreationError(
-                f"bank kernel: lowering failed: {e}")
+        bank.smoke_compile()
         return True
-    except SiddhiAppCreationError as e:
+    except Exception as e:
+        bank.use_kernel = False
+        bank._scatter = None
+        reason = f"bank kernel: compile failed: {e}"
         log.warning(
             "aggregation '%s': @app:kernels(bank) requested but the "
             "segmented-reduce kernel cannot be used, staying on the "
-            "XLA scatter: %s", agg_name, e)
+            "XLA scatter: %s", agg_name, reason)
         if sm is not None:
-            sm.record_kernel_fallback(agg_name, str(e))
+            sm.record_kernel_fallback(agg_name, reason)
         return False
 
 

@@ -217,17 +217,6 @@ class QueryPlanner:
             offset,
         )
 
-    def _get_mesh(self, nd: int):
-        """One app-wide device mesh, built on first use (shared by the
-        dense pattern axis and the device-query group axis)."""
-        mesh = getattr(self.app, "_tpu_mesh", None)
-        if mesh is None:
-            from siddhi_tpu.parallel import make_mesh
-
-            mesh = make_mesh(nd)
-            self.app._tpu_mesh = mesh
-        return mesh
-
     def plan_query(self, query: Query, query_index: int) -> QueryRuntime:
         """Unified lowering entry: build the query's PlanRecord (cost
         candidates + pick), plan through the existing per-kind paths
@@ -717,7 +706,7 @@ class QueryPlanner:
         mesh = None
         nd = self.app.app_context.tpu_devices
         if nd and n_partitions > 1 and self._want("shard", name):
-            mesh = self._get_mesh(nd)
+            mesh = self.app.tpu_mesh
         runtime = DensePatternRuntime(
             engine, f"#matches_{name}", emit=lambda b: qr.process(b, 0),
             key_fn=key_fn, mesh=mesh, app_context=self.app.app_context,
@@ -925,7 +914,7 @@ class QueryPlanner:
 
             try:
                 engine = ShardedDeviceQueryEngine(engine,
-                                                  self._get_mesh(nd))
+                                                  self.app.tpu_mesh)
                 logging.getLogger("siddhi_tpu").info(
                     "query '%s': device %s state sharded over %d devices",
                     name, engine.engine.kind, nd)
@@ -960,7 +949,8 @@ class QueryPlanner:
             clock=self.app.app_context.timestamp_generator.current_time,
             faults=self.app.app_context.fault_injector,
             ingest_depth=self.app.app_context.tpu_ingest_depth,
-            tracer=self.app.app_context.tracer)
+            tracer=self.app.app_context.tracer,
+            listeners=self.app.app_context.exception_listeners)
         qr.device_runtime = runtime
         if subscribe:
             junction = self.app.junction_for_input(s)

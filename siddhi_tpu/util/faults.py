@@ -91,6 +91,16 @@ DEFAULT_TRANSFER_RETRY_SCALE = 0.001  # seconds multiplier on the backoff ladder
 DEFAULT_JOURNAL_DEPTH = 256
 
 
+def notify_listeners(listeners, e: BaseException) -> None:
+    """Feed a handled runtime failure to an app's exception listeners
+    (best effort: a failing listener is logged, never raised)."""
+    for ln in list(listeners or ()):
+        try:
+            ln(e)
+        except Exception:
+            log.exception("exception listener failed")
+
+
 class FaultStats:
     """Counters for injected faults and the recovery machinery.
 
@@ -341,11 +351,7 @@ class FaultInjector:
     def notify(self, e: BaseException) -> None:
         """Feed an injected/handled fault to the runtime's exception
         listeners (best effort)."""
-        for ln in list(self.listeners):
-            try:
-                ln(e)
-            except Exception:  # pragma: no cover - listener bug
-                log.exception("fault-injection: exception listener failed")
+        notify_listeners(self.listeners, e)
 
 
 # -- poison helpers ---------------------------------------------------

@@ -447,3 +447,62 @@ class TestEmitDepthKnob:
                     "from S[v > 0.0] select k insert into Out;")
         finally:
             m.shutdown()
+
+
+class TestIsolatedFailuresAreVisible:
+    """A device error surfaces at the count-gate fetch or at the drain
+    fetch; both are isolated (the runtime lives on) but must reach the
+    app's exception listeners and a counter on EVERY app — not only
+    under the @app:faults harness."""
+
+    APP = ("@app:playback @app:execution('tpu') " + DEFINE +
+           "@info(name='q') from every e1=S[v > 50.0] -> e2=S[v > e1.v] "
+           "within 10 sec select e1.v as a, e2.v as b "
+           "insert into OutputStream;")
+
+    def _run(self, monkeypatch, target, name):
+        m = SiddhiManager()
+        try:
+            rt = m.create_siddhi_app_runtime(self.APP)
+            assert rt.app_context.fault_injector is None  # no harness
+            errors, got = [], []
+            rt.add_exception_listener(errors.append)
+            rt.add_callback("OutputStream",
+                            lambda evs: got.extend(e.data for e in evs))
+            rt.start()
+            runtime = rt.query_runtimes["q"].pattern_processor
+            h = rt.get_input_handler("S")
+            h.send([1, 60.0], timestamp=1000)
+            real = getattr(target, name)
+            calls = []
+
+            def boom(*a, **kw):
+                if not calls:
+                    calls.append(1)
+                    raise RuntimeError("device step failed")
+                return real(*a, **kw)
+
+            monkeypatch.setattr(target, name, boom)
+            h.send([1, 70.0], timestamp=1001)  # this match is lost
+            assert got == []
+            assert [str(e) for e in errors] == ["device step failed"]
+            h.send([1, 80.0], timestamp=1002)  # the runtime lives on
+            rt.shutdown()
+            assert got == [[70.0, 80.0]]
+            return runtime
+        finally:
+            m.shutdown()
+
+    def test_drain_failure_reaches_listener_and_counter(self, monkeypatch):
+        from siddhi_tpu.core import emit_queue
+
+        runtime = self._run(monkeypatch, emit_queue, "fetch_coalesced")
+        assert runtime.emit_stats.dropped_batches == 1
+        assert runtime.emit_stats.as_dict()["droppedBatches"] == 1
+
+    def test_count_gate_failure_reaches_listener_and_counter(
+            self, monkeypatch):
+        from siddhi_tpu.ops.dense_nfa import DeferredDenseEmit
+
+        runtime = self._run(monkeypatch, DeferredDenseEmit, "resolve")
+        assert runtime.ingest_stats.dropped_batches == 1

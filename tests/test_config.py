@@ -105,3 +105,44 @@ class TestConfigManagers:
         rt.start()
         rt.shutdown()
         assert seen["reader"].read_config("flush.interval") == "9"
+
+
+class TestCompileCachePlacement:
+    """JAX_COMPILATION_CACHE_DIR places the persistent compile cache
+    from outside; without it the directory is one fixed path beside the
+    package — never a temporary name."""
+
+    @pytest.fixture
+    def jax_config(self):
+        import jax
+
+        names = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        saved = {n: getattr(jax.config, n) for n in names}
+        yield jax.config
+        for n, v in saved.items():
+            jax.config.update(n, v)
+
+    def test_environment_names_the_directory(self, jax_config, monkeypatch):
+        from siddhi_tpu.util import compile_cache
+
+        monkeypatch.setenv(compile_cache.CACHE_DIR_ENV, "/some/dir")
+        jax_config.update("jax_compilation_cache_dir", None)
+        SiddhiManager().shutdown()  # the one door every entry uses
+        assert jax_config.jax_compilation_cache_dir is None  # none in code
+        assert jax_config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+    def test_fixed_directory_without_the_environment(self, jax_config,
+                                                     monkeypatch):
+        import os
+
+        import siddhi_tpu
+        from siddhi_tpu.util import compile_cache
+
+        monkeypatch.delenv(compile_cache.CACHE_DIR_ENV, raising=False)
+        compile_cache.configure_compile_cache()
+        want = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(siddhi_tpu.__file__))), ".jax_cache")
+        assert jax_config.jax_compilation_cache_dir == want
+        assert jax_config.jax_persistent_cache_min_entry_size_bytes == -1

@@ -42,9 +42,7 @@ WORKER = textwrap.dedent("""
     def f(x):
         return jax.lax.psum(jnp.sum(x), axis_name="p")
 
-    from siddhi_tpu.parallel.mesh import get_shard_map
-
-    total = jax.jit(get_shard_map()(
+    total = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=P("p", None), out_specs=P()))(garr)
     expect = 4.0 * sum(range(1, n + 1))
     assert float(total) == expect, (float(total), expect)

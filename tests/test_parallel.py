@@ -136,3 +136,35 @@ class TestShardedEngine:
         state = sharded.init_state()
         assert len(state["active"].sharding.device_set) == 8
         assert state["active"].shape[0] == 8 * 65  # 64 partitions + scratch
+
+
+class TestMeshNeverBorrowsDevices:
+    """A mesh asked for on a platform that is short must fail — never
+    land on devices of another platform, never become a host fallback."""
+
+    def test_make_mesh_raises_when_platform_is_short(self):
+        import jax
+
+        from siddhi_tpu.core.exceptions import SiddhiAppCreationError
+        from siddhi_tpu.parallel import make_mesh
+
+        n = len(jax.devices())
+        with pytest.raises(SiddhiAppCreationError, match=f"has {n}"):
+            make_mesh(n + 1)
+        assert make_mesh(n).devices.size == n
+
+    def test_app_asking_for_too_many_devices_fails_at_creation(self):
+        from siddhi_tpu import SiddhiManager
+        from siddhi_tpu.core.exceptions import SiddhiAppCreationError
+
+        m = SiddhiManager()
+        try:
+            with pytest.raises(SiddhiAppCreationError, match="need 16"):
+                m.create_siddhi_app_runtime(
+                    "@app:execution('tpu', partitions='64', devices='16') "
+                    "define stream S (k long, v double); "
+                    "partition with (k of S) begin "
+                    "from every a=S[v > 1.0] -> b=S[v > a.v] "
+                    "select a.v as x, b.v as y insert into Out; end;")
+        finally:
+            m.shutdown()
