@@ -31,7 +31,7 @@ ALLOWLISTS = {
     "host-sync-hazard": {
         f"{_E}:fetch_coalesced":
             "drain: THE sanctioned coalesced device→host fetch",
-        f"{_DS}:DeviceQueryRuntime.process_stream_batch":
+        f"{_DS}:DeviceQueryRuntime._advance":
             "ingest: converts HOST batch cols/ts before staged_put",
         f"{_DS}:DeviceQueryRuntime.snapshot":
             "barrier: snapshot path, behind drain()",
@@ -43,7 +43,7 @@ ALLOWLISTS = {
             "ingest: host-side key interning before device routing",
         f"{_DP}:DensePatternRuntime._rebuild_key_index":
             "ingest: host-side key-index rebuild on purge",
-        f"{_DP}:DensePatternRuntime.process_stream_batch":
+        f"{_DP}:DensePatternRuntime._advance":
             "ingest: converts HOST batch cols/ts before staged_put",
         f"{_DP}:DensePatternRuntime.purge_idle":
             "barrier: idle purge, behind drain()",
@@ -65,7 +65,7 @@ ALLOWLISTS = {
             "ingest: host-side window-group interning",
         f"{_DQ}:DeviceQueryEngine.host_lane_cols":
             "ingest: HOST lane materialization for host fallbacks",
-        f"{_DQ}:DeviceQueryEngine._pad":
+        f"{_DQ}:DeviceQueryEngine._pad_lanes":
             "ingest: pads HOST cols to the pow-2 batch shape",
         f"{_DQ}:DeviceQueryEngine._host_filter_mask":
             "ingest: null-safe HOST filter probe",

@@ -43,6 +43,11 @@ from siddhi_tpu.core.exceptions import (
     SiddhiAppCreationError,
     SiddhiAppRuntimeError,
 )
+from siddhi_tpu.observability.trace import (
+    STAGE_CONVERT,
+    STAGE_INTERN,
+    span,
+)
 from siddhi_tpu.query_api import AttrType, StateInputStream, Variable
 from siddhi_tpu.util.faults import notify_listeners
 
@@ -362,6 +367,10 @@ class DensePatternRuntime:
         dtype promotion), so the runtime then degrades permanently to
         the exact per-event dict intern."""
         arr = np.asarray(keys)
+        with span(STAGE_INTERN, len(arr)):
+            return self._intern(arr)
+
+    def _intern(self, arr: np.ndarray) -> np.ndarray:
         if self._vector_intern:
             if arr.dtype.kind in ("O", "V"):
                 self._vector_intern = False
@@ -530,31 +539,50 @@ class DensePatternRuntime:
 
     # -- event path ----------------------------------------------------------
 
+    def _begin_cycle(self, n: int):
+        """One sampled-or-None cycle token per junction batch."""
+        return (self.tracer.begin_cycle(self.engine_kind, n)
+                if self.tracer is not None else None)
+
+    def receive_keyed(self, stream_key: str, cur: EventBatch, keys):
+        """The partitioned receiver's entry (core/partition.py): the
+        cycle begins here, where the batch enters the engine, so the
+        interning of its keys is a span of the cycle; the ingest span
+        starts after it, where it always has."""
+        tok = self._begin_cycle(len(cur))
+        try:
+            part = self.intern_keys(keys)
+            if tok is not None:
+                tok.ingest_begins()
+            self._advance(stream_key, cur, part, keys, tok)
+        except BaseException:
+            if tok is not None:
+                tok.raised()
+            raise
+
     def process_stream_batch(self, stream_key: str, batch: EventBatch,
                              part: Optional[np.ndarray] = None,
                              keys=None):
         """Advance the NFA with a junction batch.  ``part`` overrides the
-        partition-row assignment (the partitioned receiver computes it
-        from the partition executor + intern_keys); ``keys`` carries the
-        raw partition-key values aligned with the batch so aggregating
-        selectors can keep per-key state (aux side channel)."""
+        partition-row assignment (callers that interned the keys
+        themselves); ``keys`` carries the raw partition-key values
+        aligned with the batch so aggregating selectors can keep per-key
+        state (aux side channel)."""
         cur = batch.only(ev.CURRENT)
         n = len(cur)
         if n == 0:
             return
-        # one sampled-or-None cycle token per junction batch: ingest
-        # span starts here, at receive time
-        tok = (self.tracer.begin_cycle(self.engine_kind, n)
-               if self.tracer is not None else None)
+        # the ingest span starts here, at receive time
+        tok = self._begin_cycle(n)
+        try:
+            self._advance(stream_key, cur, part, keys, tok)
+        except BaseException:
+            if tok is not None:
+                tok.raised()
+            raise
+
+    def _advance(self, stream_key: str, cur: EventBatch, part, keys, tok):
         eng = self.engine
-        cols = {}
-        for a in _numeric_attrs(eng, stream_key):
-            col = cur.columns.get(a)
-            if col is None:
-                continue
-            # native dtype: the engine splits integer columns into
-            # bit-exact hi/lo pairs itself (prepare_cols)
-            cols[a] = np.asarray(col)
         if part is None:
             if self.key_fn is None:
                 part = np.zeros(len(cur), dtype=np.int32)
@@ -562,9 +590,18 @@ class DensePatternRuntime:
                 if keys is None:
                     keys = self.key_fn(cur)
                 part = self.intern_keys(keys)
-        ts = np.asarray(cur.timestamps, dtype=np.int64)
-        if len(ts):
-            np.maximum.at(self._row_last_used, part, ts)
+        with span(STAGE_CONVERT, len(cur)):
+            cols = {}
+            for a in _numeric_attrs(eng, stream_key):
+                col = cur.columns.get(a)
+                if col is None:
+                    continue
+                # native dtype: the engine splits integer columns into
+                # bit-exact hi/lo pairs itself (prepare_cols)
+                cols[a] = np.asarray(col)
+            ts = np.asarray(cur.timestamps, dtype=np.int64)
+            if len(ts):
+                np.maximum.at(self._row_last_used, part, ts)
         if self._sharded is not None:
             self.state, pending = self._sharded[
                 stream_key].process_deferred(self.state, part, cols, ts)
@@ -575,7 +612,12 @@ class DensePatternRuntime:
         if eng.has_deadlines:
             self._wake_dirty = True
         if self.step_invocations % self._OVF_POLL == 0:
-            self._check_overflow()
+            # the poll's fetch blocks on the step just dispatched
+            if tok is None:
+                self._check_overflow()
+            else:
+                with tok.step_wait():
+                    self._check_overflow()
         from siddhi_tpu.core.emit_queue import PendingEmit
 
         # clock sampled at RECEIVE time: the finish step may run a batch
@@ -584,7 +626,13 @@ class DensePatternRuntime:
                if self._app_context is not None else None)
 
         def _finish(p=pending, t=ts, k=keys, n=now, tk=tok):
-            c = 0 if p is None else p.resolve()
+            if p is None:
+                c = 0
+            elif tk is None:
+                c = p.resolve()
+            else:
+                with tk.step_wait():
+                    c = p.resolve()
             if tk is not None:
                 # match-count gate resolved: the jitted step finished
                 tk.step_done(c)

@@ -37,6 +37,7 @@ from siddhi_tpu.core.emit_queue import EmitQueue, EmitStats, PendingEmit
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats
 from siddhi_tpu.core.exceptions import SiddhiAppRuntimeError
+from siddhi_tpu.observability.trace import STAGE_CONVERT, span
 from siddhi_tpu.util.faults import notify_listeners
 
 import logging
@@ -165,12 +166,21 @@ class DeviceQueryRuntime:
         # span starts here, at receive time
         tok = (self.tracer.begin_cycle(self.engine_kind, n)
                if self.tracer is not None else None)
+        try:
+            self._advance(cur, n, keys, tok)
+        except BaseException:
+            if tok is not None:
+                tok.raised()
+            raise
+
+    def _advance(self, cur: EventBatch, n: int, keys, tok):
         eng = self.engine
-        cols = {
-            a: np.asarray(cur.columns[a])
-            for a in eng.all_attrs if a in cur.columns
-        }
-        ts = np.asarray(cur.timestamps, dtype=np.int64)
+        with span(STAGE_CONVERT, n):
+            cols = {
+                a: np.asarray(cur.columns[a])
+                for a in eng.all_attrs if a in cur.columns
+            }
+            ts = np.asarray(cur.timestamps, dtype=np.int64)
         self.state, pending = eng.process_batch_deferred(
             self.state, cols, ts, part_keys=keys)
         self.step_invocations += 1
@@ -190,8 +200,11 @@ class DeviceQueryRuntime:
         def _finish(p=pending, t=now, tk=tok):
             if p is None:
                 c = 0
-            else:
+            elif tk is None:
                 c = p.resolve()
+            else:
+                with tk.step_wait():
+                    c = p.resolve()
             if tk is not None:
                 # count gate resolved: the jitted step finished
                 tk.step_done(c)

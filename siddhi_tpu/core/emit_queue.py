@@ -36,6 +36,7 @@ from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
+from ..observability.trace import STAGE_DELIVER, STAGE_FETCH, annotation
 from .exceptions import TransferFaultError
 
 log = logging.getLogger("siddhi_tpu.emit")
@@ -339,8 +340,14 @@ class EmitQueue:
             # fetch serves every entry in this round, so they share the
             # fetch start and each stamps its own materialize end
             t_fetch = time.perf_counter()
+            traced = any(e.trace is not None for e in entries)
             try:
-                host = self._fetch(arrays)
+                if traced:
+                    with annotation(STAGE_FETCH):
+                        host = self._fetch(arrays)
+                    t_fetched = time.perf_counter()
+                else:
+                    host = self._fetch(arrays)
             except Exception as err:
                 fi = self.faults
                 if fi is not None:
@@ -363,7 +370,15 @@ class EmitQueue:
                 seg = host[off:off + n]
                 off += n
                 try:
-                    e.materialize(seg)
+                    if e.trace is None:
+                        e.materialize(seg)
+                    else:
+                        # one fetch serves the round: each sampled
+                        # entry records it with its own bytes
+                        e.trace.record(STAGE_FETCH, t_fetch, t_fetched,
+                                       sum(a.nbytes for a in seg))
+                        with e.trace.span(STAGE_DELIVER, e.trace.n_emit):
+                            e.materialize(seg)
                 except Exception as err:
                     fi = self.faults
                     if fi is not None:

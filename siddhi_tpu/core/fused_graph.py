@@ -129,6 +129,14 @@ class FusedChainRuntime:
         # span starts here, at receive time
         tok = (self.tracer.begin_cycle(self.engine_kind, n)
                if self.tracer is not None else None)
+        try:
+            self._advance(cur, tok)
+        except BaseException:
+            if tok is not None:
+                tok.raised()
+            raise
+
+    def _advance(self, cur: EventBatch, tok):
         head = self.graph.stages[0]
         cols = {
             a: cur.columns[a]

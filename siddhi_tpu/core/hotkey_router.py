@@ -305,6 +305,10 @@ class HotKeyRouterRuntime:
 
     # -- event path ----------------------------------------------------------
 
+    def receive_keyed(self, stream_key: str, cur: EventBatch, keys):
+        self.process_stream_batch(
+            stream_key, cur, part=self.intern_keys(keys), keys=keys)
+
     def process_stream_batch(self, stream_key: str, batch: EventBatch,
                              part: Optional[np.ndarray] = None,
                              keys=None):
@@ -349,6 +353,19 @@ class HotKeyRouterRuntime:
 
     def _process_hot(self, slot_pos: Dict[int, np.ndarray],
                      cur: EventBatch, keys):
+        # hot-path batches get their own cycle tokens (engine kind
+        # 'hotkey'); the cold remainder traced under 'dense' already
+        tracer = self._dense.tracer
+        tok = (tracer.begin_cycle("hotkey", len(cur))
+               if tracer is not None else None)
+        try:
+            self._advance_hot(slot_pos, cur, keys, tok)
+        except BaseException:
+            if tok is not None:
+                tok.raised()
+            raise
+
+    def _advance_hot(self, slot_pos, cur: EventBatch, keys, tok):
         from siddhi_tpu.core.emit_queue import PendingEmit
         from siddhi_tpu.core.ingest_stage import staged_put
 
@@ -356,11 +373,6 @@ class HotKeyRouterRuntime:
         cols = {a: c for a, c in cur.columns.items()
                 if a in scan.base._lane_dtype}
         ts = cur.timestamps
-        # hot-path batches get their own cycle tokens (engine kind
-        # 'hotkey'); the cold remainder traced under 'dense' already
-        tracer = dense.tracer
-        tok = (tracer.begin_cycle("hotkey", len(ts))
-               if tracer is not None else None)
         put, meta = scan.pack_cycle(slot_pos, cols, ts)
         put_dev = staged_put(put, faults=self.faults,
                              stats=dense.ingest_stats)

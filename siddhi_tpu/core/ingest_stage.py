@@ -46,6 +46,7 @@ import logging
 import time
 from typing import Callable, List, Optional, Tuple
 
+from ..observability.trace import STAGE_PUT, span
 from .exceptions import TransferFaultError
 
 log = logging.getLogger("siddhi_tpu.ingest")
@@ -89,6 +90,14 @@ class IngestStats:
         }
 
 
+def host_nbytes(x) -> int:
+    """Bytes of the host arrays in a pytree: what a put of it hands to
+    ``device_put`` (the ``put`` span's count)."""
+    import jax
+
+    return sum(getattr(a, "nbytes", 0) for a in jax.tree_util.tree_leaves(x))
+
+
 def staged_put(x, sharding=None, faults=None, stats: Optional[IngestStats] = None):
     """H2D ``device_put`` behind the ``ingest.put`` injection site.
 
@@ -96,42 +105,46 @@ def staged_put(x, sharding=None, faults=None, stats: Optional[IngestStats] = Non
     injector's ``ingest.put`` site (when a harness is configured) with
     the same bounded retry-with-backoff ladder the emit drain uses, so
     transient transfer faults recover and sticky ones propagate.  Counts
-    one ``device_puts`` per call when ``stats`` is supplied.
+    one ``device_puts`` per call when ``stats`` is supplied, and is one
+    ``put`` span of the calling thread's open cycle (retries included).
     """
     import jax
 
     if stats is not None:
         stats.device_puts += 1
-    if faults is None:
-        return (jax.device_put(x, sharding) if sharding is not None
-                else jax.device_put(x))
-    fi = faults
-    attempts = fi.transfer_retry_attempts
-    backoff = None
-    attempt = 0
-    while True:
-        try:
-            fi.check("ingest.put")
-            out = (jax.device_put(x, sharding) if sharding is not None
-                   else jax.device_put(x))
-            if attempt:
-                fi.stats.drains_recovered += 1
-            return out
-        except TransferFaultError:
-            if attempt >= attempts:
-                raise
-            attempt += 1
-            fi.stats.transfer_retries += 1
-            if backoff is None:
-                from ..transport.retry import BackoffRetryCounter
+    with span(STAGE_PUT) as sp:
+        if sp is not None:
+            sp.count = host_nbytes(x)
+        if faults is None:
+            return (jax.device_put(x, sharding) if sharding is not None
+                    else jax.device_put(x))
+        fi = faults
+        attempts = fi.transfer_retry_attempts
+        backoff = None
+        attempt = 0
+        while True:
+            try:
+                fi.check("ingest.put")
+                out = (jax.device_put(x, sharding) if sharding is not None
+                       else jax.device_put(x))
+                if attempt:
+                    fi.stats.drains_recovered += 1
+                return out
+            except TransferFaultError:
+                if attempt >= attempts:
+                    raise
+                attempt += 1
+                fi.stats.transfer_retries += 1
+                if backoff is None:
+                    from ..transport.retry import BackoffRetryCounter
 
-                backoff = BackoffRetryCounter(scale=fi.transfer_retry_scale)
-            wait_s = backoff.get_time_interval_ms() / 1000.0
-            backoff.increment()
-            log.warning("ingest put: transient device_put fault; "
-                        "retry %d/%d in %.3fs", attempt, attempts, wait_s)
-            if wait_s > 0:
-                time.sleep(wait_s)
+                    backoff = BackoffRetryCounter(scale=fi.transfer_retry_scale)
+                wait_s = backoff.get_time_interval_ms() / 1000.0
+                backoff.increment()
+                log.warning("ingest put: transient device_put fault; "
+                            "retry %d/%d in %.3fs", attempt, attempts, wait_s)
+                if wait_s > 0:
+                    time.sleep(wait_s)
 
 
 class IngestStage:

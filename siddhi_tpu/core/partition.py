@@ -297,9 +297,7 @@ class DensePartitionReceiver:
             keys = np.asarray(keys.tolist())
         for rt in self.runtimes:
             if hasattr(rt, "intern_keys"):  # dense NFA pattern runtime
-                part = rt.intern_keys(keys)
-                rt.process_stream_batch(self.stream_id, cur, part=part,
-                                        keys=keys)
+                rt.receive_keyed(self.stream_id, cur, keys)
             else:  # partitioned device-query runtime
                 rt.process_stream_batch(cur, keys=keys)
 

@@ -141,10 +141,15 @@ class DevTableJoinRuntime:
             return
         tok = (self.tracer.begin_cycle(self.engine_kind, n)
                if self.tracer is not None else None)
-        keys = self._event_keys(cur)
-        for lo in range(0, n, self.MAX_CHUNK):
-            hi = min(n, lo + self.MAX_CHUNK)
-            self._dispatch_chunk(cur, keys, lo, hi, now, tok)
+        try:
+            keys = self._event_keys(cur)
+            for lo in range(0, n, self.MAX_CHUNK):
+                hi = min(n, lo + self.MAX_CHUNK)
+                self._dispatch_chunk(cur, keys, lo, hi, now, tok)
+        except BaseException:
+            if tok is not None:
+                tok.raised()
+            raise
 
     def _host_only_reason(self, cur: EventBatch) -> Optional[str]:
         if self.table.demoted:

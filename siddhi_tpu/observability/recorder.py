@@ -5,7 +5,8 @@ fixed-size tuples ``(cycle, stage, engine, t_start, t_end, n_events)``
 held in a ``collections.deque(maxlen=...)`` — appends are GIL-atomic,
 so the emit-drain, ingest and checkpoint-writer threads all record
 without a lock, and the ring self-evicts to the newest N cycles' worth
-of spans.
+of spans (a batch that splits into more rounds or chunks than
+``spans_per_cycle`` allows for takes more than its share).
 
 On a terminal event (poison quarantine, @OnError isolation, crash
 restore, fault-injector kill) ``dump(reason)`` freezes the ring into a
@@ -44,21 +45,20 @@ def default_dump_dir() -> str:
 class FlightRecorder:
     """Span ring + dump machinery for one app runtime."""
 
-    #: ring capacity per kept cycle: ingest + step + emit leaves head
-    #: room for persist spans interleaving with batch cycles
-    SPANS_PER_CYCLE = 4
-
     #: file-write cap per recorder — a chaos run triggering hundreds of
     #: isolation dumps must not litter the dump dir unboundedly (the
     #: in-memory ``last_dump`` keeps updating past the cap)
     MAX_DUMP_FILES = 32
 
-    def __init__(self, app_name: str, cycles: int = 64,
-                 dump_dir: Optional[str] = None):
+    def __init__(self, app_name: str, cycles: int = 64, *,
+                 spans_per_cycle: int, dump_dir: Optional[str] = None):
+        """``spans_per_cycle``: the most spans one kept cycle records;
+        the tracer derives it from its span vocabulary, so that "the
+        last N complete cycles" holds."""
         self.app_name = app_name
         self.cycles = max(1, int(cycles))
         self.ring: collections.deque = collections.deque(
-            maxlen=self.cycles * self.SPANS_PER_CYCLE)
+            maxlen=self.cycles * spans_per_cycle)
         self.dump_dir = dump_dir if dump_dir is not None else default_dump_dir()
         self.last_dump: Optional[dict] = None
         self.dumps = 0

@@ -4,15 +4,36 @@ One :class:`Tracer` per app runtime hands out a monotonically
 increasing cycle id per device-engine batch (``begin_cycle``).  The id
 rides a :class:`CycleToken` through the existing async machinery:
 
-    runtime ``process_stream_batch``          -> begin_cycle (t0)
-    IngestStage.submit (put + step dispatched) -> tok.dispatched()  [ingest span]
-    runtime ``_finish`` (count gate resolved)  -> tok.step_done(n)  [step span]
-    EmitQueue.drain (batch materialized)       -> tok.emitted(t0)   [emit span]
+    receiver / runtime ``process_stream_batch`` -> begin_cycle (t0)
+    IngestStage.submit (put + step dispatched)  -> tok.dispatched()  [ingest span]
+    runtime ``_finish`` (count gate resolved)   -> tok.step_done(n)  [step span]
+    EmitQueue.drain (batch materialized)        -> tok.emitted(t0)   [emit span]
 
-plus free-running ``persist.capture`` / ``persist.write`` spans from
-the checkpoint path (``record_span``), which draw ids from the same
-counter so a capture and its async write stay ordered against the
-batch cycles around them.
+Inside those three, one flat vocabulary tiles the rest of a batch's
+time in ``send_batch``: ``intern`` (keys to engine rows), ``convert``
+(host columns to padded device lanes), ``route`` (bucketing by shard,
+sharded engines only), ``put`` (one per H2D transfer), ``dispatch``
+(the call of the jitted step), and under ``emit`` the coalesced
+``fetch`` and the ``deliver`` of rows to the callback.  A span's parent
+is the span of the same cycle whose interval contains it; siblings
+never overlap, so a stage's time is the plain sum of its spans.
+
+The code that does that work lives in engines that know no tracer
+(``ops/``, ``parallel/``, ``core/ingest_stage.py``).  It reaches the
+cycle through :func:`span`: ``begin_cycle`` leaves the token open on
+the calling thread until its ingest span ends, and ``span`` reads it
+there.  Every sampled span is also a
+``jax.profiler.TraceAnnotation('siddhi.<stage>')`` around the work, so
+a profiler trace of the process carries the program's spans in its
+host plane beside the device's operations (``step`` appears as
+``siddhi.step_wait``, around the blocking count-gate fetch).  The
+``siddhi.*`` scopes below name the phases of the jitted steps on the
+device side of the same trace.
+
+Free-running ``persist.capture`` / ``persist.write`` spans from the
+checkpoint path (``record_span``) draw ids from the same counter, so a
+capture and its async write stay ordered against the batch cycles
+around them.
 
 Everything here is host-side bookkeeping OUTSIDE jit: a span is a
 six-tuple appended to the flight recorder's deque (GIL-atomic) plus a
@@ -21,24 +42,45 @@ materialized, which is what keeps the ``jit-purity`` and
 ``host-sync-hazard`` analysis rules clean with zero allowlist entries.
 
 Sampling (``@app:trace(sample='1/64')``) gates token creation: an
-unsampled cycle pays one ``itertools.count`` tick and a modulo, and
-every downstream hook short-circuits on ``token is None`` — that is
-the whole default-on cost.
+unsampled cycle pays one ``itertools.count`` tick, a modulo and the
+store of ``None`` as the thread's open cycle; every downstream hook
+short-circuits on ``token is None`` and every ``span`` site on one
+thread-local read — no token, span or annotation is allocated.  That
+is the whole default-on cost.
 """
 
 from __future__ import annotations
 
 import itertools
+import threading
 import time
 from typing import Dict, Optional
 
 from .histograms import LatencyHistogram
 from .recorder import FlightRecorder
 
-#: batch-cycle stages in pipeline order
-STAGE_INGEST = "ingest"
-STAGE_STEP = "step"
-STAGE_EMIT = "emit"
+#: batch-cycle stages in pipeline order; the n_events slot of each
+#: span carries the count named beside it
+STAGE_INTERN = "intern"      # keys interned
+STAGE_INGEST = "ingest"      # events
+STAGE_CONVERT = "convert"    # events
+STAGE_ROUTE = "route"        # events
+STAGE_PUT = "put"            # bytes handed to device_put
+STAGE_DISPATCH = "dispatch"  # rounds (1 per call of the jitted step)
+STAGE_STEP = "step"          # events
+STAGE_EMIT = "emit"          # rows
+STAGE_FETCH = "fetch"        # bytes fetched
+STAGE_DELIVER = "deliver"    # rows delivered
+CYCLE_STAGES = (STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT, STAGE_ROUTE,
+                STAGE_PUT, STAGE_DISPATCH, STAGE_STEP, STAGE_EMIT,
+                STAGE_FETCH, STAGE_DELIVER)
+#: what a further collision round (or device chunk) of one batch repeats
+ROUND_STAGES = (STAGE_CONVERT, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH)
+#: most spans one batch cycle records on a served path: every stage
+#: once, the host preparation ahead of the rounds in two more pieces
+#: (the runtime's column views, the engine's lane conversion), and a
+#: second collision round.  The tracer sizes the recorder's ring from it.
+SPANS_PER_CYCLE = len(CYCLE_STAGES) + 2 + len(ROUND_STAGES)
 #: checkpoint-path stages (free-running, engine kind 'persist')
 STAGE_PERSIST_CAPTURE = "persist.capture"
 STAGE_PERSIST_WRITE = "persist.write"
@@ -51,10 +93,100 @@ STAGE_TABLE_UPSERT = "table.upsert"
 #: latency distribution like any other stage
 STAGE_WATCHDOG_HEAL = "watchdog.heal"
 
-_STAGES = (STAGE_INGEST, STAGE_STEP, STAGE_EMIT,
-           STAGE_PERSIST_CAPTURE, STAGE_PERSIST_WRITE,
-           STAGE_TABLE_PROBE, STAGE_TABLE_UPSERT,
-           STAGE_WATCHDOG_HEAL)
+_STAGES = CYCLE_STAGES + (
+    STAGE_PERSIST_CAPTURE, STAGE_PERSIST_WRITE,
+    STAGE_TABLE_PROBE, STAGE_TABLE_UPSERT,
+    STAGE_WATCHDOG_HEAL)
+
+#: host spans on the profiler's clock are named ANNOTATION_PREFIX + stage;
+#: the ``step`` stage appears as the blocking part of it, ``step_wait``
+ANNOTATION_PREFIX = "siddhi."
+ANNOTATION_STEP_WAIT = "step_wait"
+
+#: ``jax.named_scope`` names of the jitted steps' phases: they reach the
+#: ``op_name`` metadata of every HLO operation traced under them, so a
+#: device trace groups operations by phase whatever XLA names them
+SCOPE_DENSE_GATHER = "siddhi.dense.gather"      # ops/dense_nfa.py make_step
+SCOPE_DENSE_ADVANCE = "siddhi.dense.advance"
+SCOPE_DENSE_SCATTER = "siddhi.dense.scatter"
+SCOPE_DENSE_COUNT = "siddhi.dense.count"
+SCOPE_SHARD_COUNT_PSUM = "siddhi.shard.count_psum"  # parallel/mesh.py
+SCOPE_WINDOW_FILTER = "siddhi.window.filter"    # ops/device_query.py make_step
+SCOPE_WINDOW_SLOT = "siddhi.window.slot"
+SCOPE_WINDOW_AGGREGATE = "siddhi.window.aggregate"
+SCOPE_WINDOW_EMIT = "siddhi.window.emit"
+SCOPE_WINDOW_UPDATE = "siddhi.window.update"
+SCOPE_WINDOW_COUNT = "siddhi.window.count"
+DEVICE_SCOPES = (
+    SCOPE_DENSE_GATHER, SCOPE_DENSE_ADVANCE, SCOPE_DENSE_SCATTER,
+    SCOPE_DENSE_COUNT, SCOPE_SHARD_COUNT_PSUM, SCOPE_WINDOW_FILTER,
+    SCOPE_WINDOW_SLOT, SCOPE_WINDOW_AGGREGATE, SCOPE_WINDOW_EMIT,
+    SCOPE_WINDOW_UPDATE, SCOPE_WINDOW_COUNT)
+
+# the calling thread's open cycle: set by begin_cycle (None for an
+# unsampled cycle), cleared when the cycle's ingest span ends
+_open = threading.local()
+_annotation = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def annotation(stage: str):
+    """``jax.profiler.TraceAnnotation('siddhi.<stage>')``: outside a
+    profiler session a flag test.  Made for sampled cycles only."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(ANNOTATION_PREFIX + stage)
+
+
+class Span:
+    """One sampled span in flight: a ``TraceAnnotation`` on the
+    profiler's clock and, on exit, a tuple in the ring.  ``count`` may
+    be set inside the ``with`` body, where the work is what yields it."""
+
+    __slots__ = ("tok", "stage", "count", "t0", "_ann")
+
+    def __init__(self, tok: "CycleToken", stage: str, count: int):
+        self.tok = tok
+        self.stage = stage
+        self.count = count
+
+    def __enter__(self) -> "Span":
+        self._ann = annotation(self.stage)
+        self._ann.__enter__()
+        self.t0 = self.tok.tracer.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        now = self.tok.tracer.clock()
+        self._ann.__exit__(*exc)
+        self.tok.record(self.stage, self.t0, now, self.count)
+        return False
+
+
+class _NoSpan:
+    """What :func:`span` hands an unsampled cycle: one shared object,
+    nothing allocated; ``with span(...) as sp`` binds ``sp`` to None."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(stage: str, count: int = 0):
+    """Context manager for one stage of the calling thread's open
+    cycle; the shared no-op when that cycle is unsampled (or none is
+    open).  For code that does a batch's work and knows no tracer."""
+    tok = getattr(_open, "tok", None)
+    return _NO_SPAN if tok is None else Span(tok, stage, count)
 
 
 class CycleToken:
@@ -77,41 +209,72 @@ class CycleToken:
         self.t0 = t0
         self.t_dispatch = t0
 
+    def span(self, stage: str, count: int = 0) -> Span:
+        """A stage of this cycle, for code that holds the token."""
+        return Span(self, stage, count)
+
+    def record(self, stage: str, t_start: float, t_end: float,
+               count: int) -> None:
+        """A stage of this cycle whose interval the caller clocked."""
+        self.tracer.record(self.cycle, stage, self.engine, t_start, t_end,
+                           count)
+
+    def ingest_begins(self) -> None:
+        """Restart the ingest span: the partitioned receiver begins the
+        cycle ahead of interning, and ingest starts after it."""
+        self.t0 = self.t_dispatch = self.tracer.clock()
+
     def dispatched(self) -> None:
         """Receive-time work done: conversion + H2D put + jitted step
-        dispatch are all queued.  Ends the ingest span."""
+        dispatch are all queued.  Ends the ingest span, and with it the
+        time this cycle is the thread's open one."""
+        _open.tok = None
         now = self.tracer.clock()
-        self.tracer.record(self.cycle, STAGE_INGEST, self.engine,
-                           self.t0, now, self.n_events)
+        self.record(STAGE_INGEST, self.t0, now, self.n_events)
         self.t_dispatch = now
+
+    def step_wait(self):
+        """``siddhi.step_wait`` on the profiler's clock, for the caller
+        to hold around the blocking count-gate fetch; the ring's
+        ``step`` span (from dispatch to ``step_done``) is the record."""
+        return annotation(ANNOTATION_STEP_WAIT)
 
     def step_done(self, n_emit: int) -> None:
         """Count gate resolved: the jitted step (and the H2D transfer
         it waited on) finished on device.  Ends the step span."""
         now = self.tracer.clock()
         self.n_emit = n_emit
-        self.tracer.record(self.cycle, STAGE_STEP, self.engine,
-                           self.t_dispatch, now, self.n_events)
+        self.record(STAGE_STEP, self.t_dispatch, now, self.n_events)
 
     def emitted(self, t_fetch_start: float) -> None:
         """This cycle's batch materialized on the host (post coalesced
         fetch + callback).  Ends the emit span."""
-        self.tracer.record(self.cycle, STAGE_EMIT, self.engine,
-                           t_fetch_start, self.tracer.clock(), self.n_emit)
+        self.record(STAGE_EMIT, t_fetch_start, self.tracer.clock(),
+                    self.n_emit)
 
     def aborted(self, stage: str) -> None:
         """The cycle died inside ``stage`` (isolated fault): leave a
         zero-width tombstone span so the flight recorder shows where
         the batch was lost instead of a silent gap."""
+        if getattr(_open, "tok", None) is self:
+            _open.tok = None
         now = self.tracer.clock()
-        self.tracer.record(self.cycle, f"{stage}.aborted", self.engine,
-                           now, now, self.n_events)
+        self.record(f"{stage}.aborted", now, now, self.n_events)
+
+    def raised(self) -> None:
+        """An exception is leaving the batch path.  If this is still
+        the thread's open cycle it died inside ingest: close it, so no
+        later ``span`` of code that opens no cycle lands in it."""
+        if getattr(_open, "tok", None) is self:
+            self.aborted(STAGE_INGEST)
 
 
 class Tracer:
     """Per-app cycle-id source, span sink and flight-recorder owner."""
 
-    #: default: record every 64th cycle (≤5%-throughput contract)
+    #: default: record every 64th cycle (every cycle costs 12 ms window
+    #: batches 2.6% on the chip, of which the spans' own bookkeeping is
+    #: 0.4%: PERF.md, PR 26)
     DEFAULT_SAMPLE = 64
     #: default flight-recorder depth in cycles
     DEFAULT_CYCLES = 64
@@ -122,8 +285,10 @@ class Tracer:
         self.app_name = app_name
         # 0 = tracing off; 1 = every cycle; N = every Nth cycle
         self.sample = max(0, int(sample))
-        self.recorder = FlightRecorder(app_name, cycles=cycles,
-                                       dump_dir=dump_dir)
+        # the two persist spans can interleave with a batch's own
+        self.recorder = FlightRecorder(
+            app_name, cycles=cycles, spans_per_cycle=SPANS_PER_CYCLE + 2,
+            dump_dir=dump_dir)
         self.clock = time.perf_counter
         self._ids = itertools.count(1)
         # pre-created so hot-path record() never mutates the dict
@@ -132,18 +297,17 @@ class Tracer:
 
     # -- cycle ids -----------------------------------------------------------
 
-    def next_id(self) -> int:
-        return next(self._ids)
-
     def begin_cycle(self, engine: str, n_events: int) -> Optional[CycleToken]:
         """Start one batch cycle; None when this cycle is unsampled
-        (every downstream hook no-ops on a None token)."""
-        if not self.sample:
+        (every downstream hook no-ops on a None token).  Either way it
+        becomes the calling thread's open cycle, which :func:`span`
+        reads, so no span of this batch lands in an older cycle."""
+        if not self.sample or (cid := next(self._ids)) % self.sample:
+            _open.tok = None
             return None
-        cid = next(self._ids)
-        if self.sample > 1 and cid % self.sample:
-            return None
-        return CycleToken(self, cid, engine, n_events, self.clock())
+        tok = _open.tok = CycleToken(self, cid, engine, n_events,
+                                     self.clock())
+        return tok
 
     # -- span sink -----------------------------------------------------------
 

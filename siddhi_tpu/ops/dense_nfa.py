@@ -68,6 +68,15 @@ from siddhi_tpu.core.exceptions import (
     SiddhiAppCreationError,
     SiddhiAppRuntimeError,
 )
+from siddhi_tpu.observability.trace import (
+    SCOPE_DENSE_ADVANCE,
+    SCOPE_DENSE_COUNT,
+    SCOPE_DENSE_GATHER,
+    SCOPE_DENSE_SCATTER,
+    STAGE_CONVERT,
+    STAGE_DISPATCH,
+    span,
+)
 from siddhi_tpu.ops.nfa import ANY, NFABuilder, Node, PatternScope, Spec
 from siddhi_tpu.planner.expr import (
     CompiledExpression,
@@ -692,20 +701,15 @@ class DensePatternEngine:
 
         n_iout = sum(self.out_int)
 
-        def step(state, part_idx, cols, ts, valid):
-            B = part_idx.shape[0]
-            a = state["active"][part_idx]        # [B, S, I] bool
-            first = state["first_ts"][part_idx]  # [B, S, I]
-            counts = state["counts"][part_idx]   # [B, S, I]
-            regs = state["regs"][part_idx]       # [B, S, I, R]
-            iregs = (state["iregs"][part_idx] if "iregs" in state
-                     else jnp.zeros((B, S, I, 0), dtype=jnp.int32))
-            ovf = state["overflow"][part_idx]    # [B]
+        def advance(a, first, counts, regs, iregs, ovf, dl, cols, ts,
+                    valid):
+            """The gathered rows of the batch's partitions through one
+            event each: expiry, filters, placement, emission."""
+            B = ts.shape[0]
             # deadline registers ride OUTSIDE the functional carry in a
             # one-cell holder: only placement and the absent kill/complete
             # branches touch them, and tracing is sequential python
-            dlh = [state["deadline"][part_idx] if "deadline" in state
-                   else None]
+            dlh = [dl]
             emit = jnp.zeros((B, 2 * I), dtype=bool)
             out_vals = jnp.zeros((B, 2 * I, O), dtype=jnp.float32)
             out_ivals = jnp.zeros((B, 2 * I, 2 * n_iout), dtype=jnp.int32)
@@ -1202,38 +1206,62 @@ class DensePatternEngine:
                 if dlh[0] is not None:
                     dlh[0] = jnp.where(any_emit[:, None, None], 0, dlh[0])
 
+            return (a, first, counts, regs, iregs, ovf, dlh[0], emit,
+                    out_vals, out_ivals, emit_anchor)
+
+        named_scope = self.jax.named_scope
+
+        def step(state, part_idx, cols, ts, valid):
+            B = part_idx.shape[0]
+            with named_scope(SCOPE_DENSE_GATHER):
+                a = state["active"][part_idx]        # [B, S, I] bool
+                first = state["first_ts"][part_idx]  # [B, S, I]
+                counts = state["counts"][part_idx]   # [B, S, I]
+                regs = state["regs"][part_idx]       # [B, S, I, R]
+                iregs = (state["iregs"][part_idx] if "iregs" in state
+                         else jnp.zeros((B, S, I, 0), dtype=jnp.int32))
+                ovf = state["overflow"][part_idx]    # [B]
+                dl = (state["deadline"][part_idx] if "deadline" in state
+                      else None)
+            with named_scope(SCOPE_DENSE_ADVANCE):
+                (a, first, counts, regs, iregs, ovf, dl, emit, out_vals,
+                 out_ivals, emit_anchor) = advance(
+                    a, first, counts, regs, iregs, ovf, dl, cols, ts, valid)
             # scatter back (valid rows only)
-            v1 = valid[:, None, None]
-            new_state = {
-                "active": state["active"].at[part_idx].set(
-                    jnp.where(v1, a, state["active"][part_idx])
-                ),
-                "first_ts": state["first_ts"].at[part_idx].set(
-                    jnp.where(v1, first, state["first_ts"][part_idx])
-                ),
-                "counts": state["counts"].at[part_idx].set(
-                    jnp.where(v1, counts, state["counts"][part_idx])
-                ),
-                "regs": state["regs"].at[part_idx].set(
-                    jnp.where(valid[:, None, None, None], regs,
-                              state["regs"][part_idx])
-                ),
-                "overflow": state["overflow"].at[part_idx].set(
-                    jnp.where(valid, ovf, state["overflow"][part_idx])
-                ),
-            }
-            if "iregs" in state:
-                new_state["iregs"] = state["iregs"].at[part_idx].set(
-                    jnp.where(valid[:, None, None, None], iregs,
-                              state["iregs"][part_idx]))
-            if "deadline" in state:
-                new_state["deadline"] = state["deadline"].at[part_idx].set(
-                    jnp.where(v1, dlh[0], state["deadline"][part_idx]))
+            with named_scope(SCOPE_DENSE_SCATTER):
+                v1 = valid[:, None, None]
+                new_state = {
+                    "active": state["active"].at[part_idx].set(
+                        jnp.where(v1, a, state["active"][part_idx])
+                    ),
+                    "first_ts": state["first_ts"].at[part_idx].set(
+                        jnp.where(v1, first, state["first_ts"][part_idx])
+                    ),
+                    "counts": state["counts"].at[part_idx].set(
+                        jnp.where(v1, counts, state["counts"][part_idx])
+                    ),
+                    "regs": state["regs"].at[part_idx].set(
+                        jnp.where(valid[:, None, None, None], regs,
+                                  state["regs"][part_idx])
+                    ),
+                    "overflow": state["overflow"].at[part_idx].set(
+                        jnp.where(valid, ovf, state["overflow"][part_idx])
+                    ),
+                }
+                if "iregs" in state:
+                    new_state["iregs"] = state["iregs"].at[part_idx].set(
+                        jnp.where(valid[:, None, None, None], iregs,
+                                  state["iregs"][part_idx]))
+                if "deadline" in state:
+                    new_state["deadline"] = state["deadline"].at[
+                        part_idx].set(
+                        jnp.where(v1, dl, state["deadline"][part_idx]))
             # outs is a pytree: float lanes + integer hi/lo pair lanes;
             # n_emit is the count-gate scalar for the async emit
             # pipeline — the host fetches it alone and skips the column
             # transfer entirely on zero-match batches
-            n_emit = jnp.sum((emit & valid[:, None]).astype(jnp.int32))
+            with named_scope(SCOPE_DENSE_COUNT):
+                n_emit = jnp.sum((emit & valid[:, None]).astype(jnp.int32))
             return (new_state, emit, {"f": out_vals, "i": out_ivals},
                     emit_anchor, n_emit)
 
@@ -1582,34 +1610,38 @@ class DensePatternEngine:
             faults.check("step.dense")
         from siddhi_tpu.core.ingest_stage import staged_put
 
-        step = self.make_step(stream_key)
-        rel64 = self.rel_ts64(np.asarray(ts, dtype=np.int64))
-        state, rel64 = self.maybe_re_anchor(state, rel64)
-        rel = rel64.astype(np.int32)
-        prepared = self.prepare_cols(stream_key, cols)
+        with span(STAGE_CONVERT, len(part_idx)):
+            step = self.make_step(stream_key)
+            rel64 = self.rel_ts64(np.asarray(ts, dtype=np.int64))
+            state, rel64 = self.maybe_re_anchor(state, rel64)
+            rel = rel64.astype(np.int32)
+            prepared = self.prepare_cols(stream_key, cols)
+            rounds = _collision_rounds(part_idx)
         pending = DeferredDenseEmit(self)
-        for ridx in _collision_rounds(part_idx):
+        for ridx in rounds:
             b = len(ridx)
-            bp = max(1 << (b - 1).bit_length(), 16)  # pad to pow2, min 16
-            pi = np.full(bp, self.n_partitions, dtype=np.int32)  # scratch row
-            pi[:b] = part_idx[ridx]
-            tb = np.zeros(bp, dtype=np.int32)
-            tb[:b] = rel[ridx]
-            valid = np.zeros(bp, dtype=bool)
-            valid[:b] = True
-            cb = {}
-            for k, v in prepared.items():
-                col = np.zeros(bp, dtype=v.dtype)
-                col[:b] = v[ridx]
-                cb[k] = col
+            with span(STAGE_CONVERT, b):
+                bp = max(1 << (b - 1).bit_length(), 16)  # pad to pow2, min 16
+                pi = np.full(bp, self.n_partitions, dtype=np.int32)  # scratch row
+                pi[:b] = part_idx[ridx]
+                tb = np.zeros(bp, dtype=np.int32)
+                tb[:b] = rel[ridx]
+                valid = np.zeros(bp, dtype=bool)
+                valid[:b] = True
+                cb = {}
+                for k, v in prepared.items():
+                    col = np.zeros(bp, dtype=v.dtype)
+                    col[:b] = v[ridx]
+                    cb[k] = col
             # one pytree H2D put per round behind the ingest.put fault
             # site (core/ingest_stage.py — the sanctioned ingest path)
             pi, cb, tb, valid = staged_put(
                 (pi, cb, tb, valid), faults=faults,
                 stats=getattr(self, "ingest_stats", None))
-            state, emit, outs, emit_anchor, n_emit = step(
-                state, pi, cb, tb, valid
-            )
+            with span(STAGE_DISPATCH, 1):
+                state, emit, outs, emit_anchor, n_emit = step(
+                    state, pi, cb, tb, valid
+                )
             # count gate deferred: n_emit stays a device scalar until
             # DeferredDenseEmit.resolve() (driven by the ingest stage)
             pending.chunks.append({

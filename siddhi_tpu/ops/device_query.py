@@ -93,6 +93,18 @@ from siddhi_tpu.planner.expr import (
     Scope,
     TS_KEY,
 )
+from siddhi_tpu.observability.trace import (
+    SCOPE_WINDOW_AGGREGATE,
+    SCOPE_WINDOW_COUNT,
+    SCOPE_WINDOW_EMIT,
+    SCOPE_WINDOW_FILTER,
+    SCOPE_WINDOW_SLOT,
+    SCOPE_WINDOW_UPDATE,
+    STAGE_CONVERT,
+    STAGE_DISPATCH,
+    STAGE_INTERN,
+    span,
+)
 from siddhi_tpu.query_api import (
     AndOp,
     ArithmeticOp,
@@ -961,21 +973,24 @@ class DeviceQueryEngine:
         because each output row's window reduction is unchanged.
         Returns (new_state, ov[nb], out {name: [nb]})."""
         jnp = self.jnp
+        named_scope = self.jax.named_scope
         W = self.W
         A = max(len(self.aggs), 1)
-        argvals = self._arg_vals(env, B)  # [B, A]
-        pos = jnp.cumsum(fmask.astype(jnp.int32)) - 1  # [B]
-        n_pass = jnp.sum(fmask.astype(jnp.int32))
-        sidx = jnp.where(fmask, pos, B)  # dump lane B
-        comp_vals = jnp.zeros((B + 1, A), jnp.float32).at[sidx].set(argvals)[:B]
-        comp_ts = jnp.zeros(B + 1, jnp.int32).at[sidx].set(ts)[:B]
-        comp_grp = jnp.zeros(B + 1, jnp.int32).at[sidx].set(grp)[:B]
-        comp_valid = (jnp.zeros(B + 1, bool)
-                      .at[sidx].set(jnp.ones(B, bool))[:B])
-        cat_vals = jnp.concatenate([state["win_vals"], comp_vals], 0)
-        cat_ts = jnp.concatenate([state["win_ts"], comp_ts], 0)
-        cat_grp = jnp.concatenate([state["win_grp"], comp_grp], 0)
-        cat_valid = jnp.concatenate([state["win_valid"], comp_valid], 0)
+        # slot: passing rows compacted behind the ring's W entries
+        with named_scope(SCOPE_WINDOW_SLOT):
+            argvals = self._arg_vals(env, B)  # [B, A]
+            pos = jnp.cumsum(fmask.astype(jnp.int32)) - 1  # [B]
+            n_pass = jnp.sum(fmask.astype(jnp.int32))
+            sidx = jnp.where(fmask, pos, B)  # dump lane B
+            comp_vals = jnp.zeros((B + 1, A), jnp.float32).at[sidx].set(argvals)[:B]
+            comp_ts = jnp.zeros(B + 1, jnp.int32).at[sidx].set(ts)[:B]
+            comp_grp = jnp.zeros(B + 1, jnp.int32).at[sidx].set(grp)[:B]
+            comp_valid = (jnp.zeros(B + 1, bool)
+                          .at[sidx].set(jnp.ones(B, bool))[:B])
+            cat_vals = jnp.concatenate([state["win_vals"], comp_vals], 0)
+            cat_ts = jnp.concatenate([state["win_ts"], comp_ts], 0)
+            cat_grp = jnp.concatenate([state["win_grp"], comp_grp], 0)
+            cat_valid = jnp.concatenate([state["win_valid"], comp_valid], 0)
         dyn = self.jax.lax.dynamic_slice_in_dim
         if nb is None:
             nb = B
@@ -990,41 +1005,44 @@ class DeviceQueryEngine:
         fmask_b = blk(fmask)
         env_b = {k: blk(v) for k, v in env.items() if k != N_KEY}
         env_b[N_KEY] = nb
-        # window of output row i: concat positions pos[i]+1 .. pos[i]+W
-        # (the W entries ending at the row itself)
-        gidx = pos_b[:, None] + 1 + jnp.arange(W)[None, :]  # [nb, W]
-        gidx = jnp.clip(gidx, 0, W + B - 1)
-        w_vals = cat_vals[gidx]  # [nb, W, A]
-        member = cat_valid[gidx] & (cat_grp[gidx] == grp_b[:, None])
-        if self.window_name == "time":
-            T = self.window_param
-            member = member & (cat_ts[gidx] > (ts_b[:, None] - T))
-        mf = member.astype(jnp.float32)[:, :, None]
-        env_out = dict(env_b)
-        kinds = self._kinds()
-        wsum = jnp.sum(w_vals * mf, axis=1)  # [nb, A]
-        wcnt = jnp.sum(mf, axis=1)  # [nb, 1]
-        wsumsq = (jnp.sum(w_vals * w_vals * mf, axis=1)
-                  if "stdDev" in kinds else None)
-        m3 = member[:, :, None]
-        wmin = (jnp.min(jnp.where(m3, w_vals, jnp.inf), axis=1)
-                if "min" in kinds else None)
-        wmax = (jnp.max(jnp.where(m3, w_vals, -jnp.inf), axis=1)
-                if "max" in kinds else None)
-        fmin, fmax = self._forever_block(state, argvals, grp, fmask, B,
-                                         rows, grp_b)
-        self._finalize_aggs(env_out, wsum, wcnt, wsumsq, wmin, wmax,
-                            fmin, fmax)
-        ov, out = self._emit(env_out, fmask_b, nb)
+        with named_scope(SCOPE_WINDOW_AGGREGATE):
+            # window of output row i: concat positions pos[i]+1 .. pos[i]+W
+            # (the W entries ending at the row itself)
+            gidx = pos_b[:, None] + 1 + jnp.arange(W)[None, :]  # [nb, W]
+            gidx = jnp.clip(gidx, 0, W + B - 1)
+            w_vals = cat_vals[gidx]  # [nb, W, A]
+            member = cat_valid[gidx] & (cat_grp[gidx] == grp_b[:, None])
+            if self.window_name == "time":
+                T = self.window_param
+                member = member & (cat_ts[gidx] > (ts_b[:, None] - T))
+            mf = member.astype(jnp.float32)[:, :, None]
+            env_out = dict(env_b)
+            kinds = self._kinds()
+            wsum = jnp.sum(w_vals * mf, axis=1)  # [nb, A]
+            wcnt = jnp.sum(mf, axis=1)  # [nb, 1]
+            wsumsq = (jnp.sum(w_vals * w_vals * mf, axis=1)
+                      if "stdDev" in kinds else None)
+            m3 = member[:, :, None]
+            wmin = (jnp.min(jnp.where(m3, w_vals, jnp.inf), axis=1)
+                    if "min" in kinds else None)
+            wmax = (jnp.max(jnp.where(m3, w_vals, -jnp.inf), axis=1)
+                    if "max" in kinds else None)
+            fmin, fmax = self._forever_block(state, argvals, grp, fmask, B,
+                                             rows, grp_b)
+            self._finalize_aggs(env_out, wsum, wcnt, wsumsq, wmin, wmax,
+                                fmin, fmax)
+        with named_scope(SCOPE_WINDOW_EMIT):
+            ov, out = self._emit(env_out, fmask_b, nb)
         # new buffer = last W entries ending at the batch's final
         # passing row: concat[n_pass : n_pass + W]
-        start = jnp.clip(n_pass, 0, B)
-        new_state = dict(state)
-        new_state["win_vals"] = dyn(cat_vals, start, W, axis=0)
-        new_state["win_ts"] = dyn(cat_ts, start, W, axis=0)
-        new_state["win_grp"] = dyn(cat_grp, start, W, axis=0)
-        new_state["win_valid"] = dyn(cat_valid, start, W, axis=0)
-        self._forever_scatter(state, new_state, argvals, grp, fmask)
+        with named_scope(SCOPE_WINDOW_UPDATE):
+            start = jnp.clip(n_pass, 0, B)
+            new_state = dict(state)
+            new_state["win_vals"] = dyn(cat_vals, start, W, axis=0)
+            new_state["win_ts"] = dyn(cat_ts, start, W, axis=0)
+            new_state["win_grp"] = dyn(cat_grp, start, W, axis=0)
+            new_state["win_valid"] = dyn(cat_valid, start, W, axis=0)
+            self._forever_scatter(state, new_state, argvals, grp, fmask)
         return new_state, ov, out
 
     def make_step(self, jit: bool = True) -> Callable:
@@ -1045,14 +1063,18 @@ class DeviceQueryEngine:
         jnp = self.jnp
         A = max(len(self.aggs), 1)
 
+        named_scope = self.jax.named_scope
+
         def step(state, cols, ts, grp, wgrp, valid):
             B = ts.shape[0]
-            env = self._base_env(cols, ts, B)
-            fmask = self._filter_mask(env, valid)
+            with named_scope(SCOPE_WINDOW_FILTER):
+                env = self._base_env(cols, ts, B)
+                fmask = self._filter_mask(env, valid)
 
             if self.kind == "filter":
                 env_out = env
-                ov, out = self._emit(env_out, fmask, B)
+                with named_scope(SCOPE_WINDOW_EMIT):
+                    ov, out = self._emit(env_out, fmask, B)
                 return state, ov, out
 
             argvals = self._arg_vals(env, B)  # [B, A]
@@ -1121,7 +1143,8 @@ class DeviceQueryEngine:
 
         def step_counted(state, cols, ts, grp, wgrp, valid):
             new_state, ov, out = step(state, cols, ts, grp, wgrp, valid)
-            n = jnp.sum((ov.astype(bool) & valid).astype(jnp.int32))
+            with named_scope(SCOPE_WINDOW_COUNT):
+                n = jnp.sum((ov.astype(bool) & valid).astype(jnp.int32))
             return new_state, ov, out, n
 
         fn = (self.jax.jit(step_counted, donate_argnums=(0,)) if jit
@@ -1637,7 +1660,9 @@ class DeviceQueryEngine:
             out[k + "|hi"], out[k + "|lo"] = hi, lo
         return out
 
-    def _pad(self, cols, rel, grp, n, wgrp=None):
+    def _pad_lanes(self, cols, rel, grp, n, wgrp=None):
+        """The batch as zero-padded power-of-two host lanes:
+        ``((cols, ts, grp, wgrp, valid), B)``."""
         B = _pow2(n)
         valid = np.zeros(B, dtype=bool)
         valid[:n] = True
@@ -1663,15 +1688,21 @@ class DeviceQueryEngine:
         wg = np.zeros(B, dtype=np.int32)
         if wgrp is not None:
             wg[:n] = wgrp[:n]
+        return (c, t, g, wg, valid), B
+
+    def _put_lanes(self, lanes):
         # ONE H2D put for the whole padded batch (a pytree device_put),
         # behind the ingest.put fault site — the single sanctioned
         # ingest transfer (core/ingest_stage.py, tests/test_ingest_guard)
         from siddhi_tpu.core.ingest_stage import staged_put
 
-        c, t, g, wg, valid = staged_put(
-            (c, t, g, wg, valid), faults=self.faults,
-            stats=getattr(self, "ingest_stats", None))
-        return c, t, g, wg, valid, B
+        return staged_put(lanes, faults=self.faults,
+                          stats=getattr(self, "ingest_stats", None))
+
+    def _pad(self, cols, rel, grp, n, wgrp=None):
+        with span(STAGE_CONVERT, n):
+            lanes, B = self._pad_lanes(cols, rel, grp, n, wgrp)
+        return (*self._put_lanes(lanes), B)
 
     def _out_columns(self, vals, sel, gids, in_cols, in_sel,
                      host_env=None, key_cols=None,
@@ -1818,31 +1849,45 @@ class DeviceQueryEngine:
         """Process one <=MAX_DEVICE_BATCH slice; non-empty match outputs
         are appended to ``pending`` as device refs."""
         n = len(ts)
-        if self.base_ts is None:
-            self.base_ts = int(ts[0]) - 1
-        rel64 = ts - self.base_ts
-        if int(rel64.max()) >= self._REL_LIMIT:
-            state, rel64 = self._re_anchor(state, rel64)
-        rel = rel64.astype(np.int32)
-        now = int(ts.max())
-        if self.kind == "filter":
-            # stateless: no interning at all (group-key select items are
-            # evaluated host-side at materialize time) — unbounded key
-            # cardinality
-            grp = wgrp = np.zeros(n, dtype=np.int32)
-        elif self.partition_mode:
-            wgrp = self._intern_wgroups(pk, now)
-            grp = (self._intern_groups(cols, ts, n, pk=pk, now=now)
-                   if self.group_exprs else wgrp)
+        if self.kind != "filter" and (self.partition_mode
+                                      or self.group_exprs):
+            with span(STAGE_INTERN, n):
+                now = int(ts.max())
+                if self.partition_mode:
+                    wgrp = self._intern_wgroups(pk, now)
+                    grp = (self._intern_groups(cols, ts, n, pk=pk, now=now)
+                           if self.group_exprs else wgrp)
+                else:
+                    wgrp = None
+                    grp = self._intern_groups(cols, ts, n)
         else:
+            # nothing to intern: a stateless filter (group-key select
+            # items are evaluated host-side at materialize time, so key
+            # cardinality is unbounded) or one ungrouped window
             wgrp = None
-            grp = self._intern_groups(cols, ts, n)
-        if self.kind in ("filter", "running", "sliding", "keyed_sliding"):
+            grp = np.zeros(n, dtype=np.int32)
+        device = self.kind in ("filter", "running", "sliding",
+                               "keyed_sliding")
+        # one convert span a chunk: relative timestamps, then the lanes
+        with span(STAGE_CONVERT, n):
+            if self.base_ts is None:
+                self.base_ts = int(ts[0]) - 1
+            rel64 = ts - self.base_ts
+            if int(rel64.max()) >= self._REL_LIMIT:
+                state, rel64 = self._re_anchor(state, rel64)
+            rel = rel64.astype(np.int32)
+            if device:
+                lanes, _b = self._pad_lanes(cols, rel, grp, n, wgrp)
+        if device:
             step = self.make_step()
-            c, t, g, wg, valid, B = self._pad(cols, rel, grp, n, wgrp)
+            lanes = self._put_lanes(lanes)
             if self.faults is not None:
                 self.faults.check("step.device")
-            state, ov, out, n_match = step(state, c, t, g, wg, valid)
+            with span(STAGE_DISPATCH, 1):
+                state, ov, out, n_match = step(state, *lanes)
+                # the call's inputs are released with it: dropping five
+                # device buffers a chunk is time of the dispatch
+                del lanes
             # the count gate is DEFERRED: ``n_match`` stays a device
             # scalar until ``DeferredDeviceEmit.resolve()`` fetches it
             # (the ingest stage calls resolve only after the NEXT

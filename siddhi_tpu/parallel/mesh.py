@@ -7,6 +7,15 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from siddhi_tpu.core.exceptions import SiddhiAppCreationError
+from siddhi_tpu.core.ingest_stage import host_nbytes
+from siddhi_tpu.observability.trace import (
+    SCOPE_SHARD_COUNT_PSUM,
+    STAGE_CONVERT,
+    STAGE_DISPATCH,
+    STAGE_PUT,
+    STAGE_ROUTE,
+    span,
+)
 
 
 def distributed_initialize(coordinator_address: Optional[str] = None,
@@ -176,7 +185,8 @@ class ShardedPatternEngine:
         def sharded_step(state, part, cols, ts, valid):
             new_state, emit, outs, anchor, local = step(state, part, cols,
                                                         ts, valid)
-            total = jax.lax.psum(local, axis_name=a)
+            with jax.named_scope(SCOPE_SHARD_COUNT_PSUM):
+                total = jax.lax.psum(local, axis_name=a)
             return new_state, emit, outs, anchor, total
 
         # donate the state pytree: at 1M+ partitions the rows dominate
@@ -230,15 +240,22 @@ class ShardedPatternEngine:
         + int32 hi/lo pairs)."""
         P = self._P
         a = self.axis_name
-        lp, rc, rts, valid, pos = route_to_shards(
-            self.n_shards, self.parts_per_shard, part, cols, ts,
-            batch_per_shard)
-        return (
-            self._put(lp, P(a)),
-            {k: self._put(np.asarray(v), P(a)) for k, v in rc.items()},
-            self._put(np.asarray(rts, dtype=np.int32), P(a)),
-            self._put(valid, P(a)),
-        ), pos
+        with span(STAGE_ROUTE, len(part)):
+            lp, rc, rts, valid, pos = route_to_shards(
+                self.n_shards, self.parts_per_shard, part, cols, ts,
+                batch_per_shard)
+            rc = {k: np.asarray(v) for k, v in rc.items()}
+            rts = np.asarray(rts, dtype=np.int32)
+        # the round's H2D transfer: one put span over its sharded puts
+        with span(STAGE_PUT) as sp:
+            if sp is not None:
+                sp.count = host_nbytes((lp, rc, rts, valid))
+            return (
+                self._put(lp, P(a)),
+                {k: self._put(v, P(a)) for k, v in rc.items()},
+                self._put(rts, P(a)),
+                self._put(valid, P(a)),
+            ), pos
 
     def step(self, state, part, cols, ts, valid):
         """One sharded step: ``(state', emit[B, 2I], out_vals[B, 2I, O],
@@ -284,24 +301,28 @@ class ShardedPatternEngine:
             _collision_rounds,
         )
 
-        part = np.asarray(part)
-        rel64 = self.engine.rel_ts64(np.asarray(ts, dtype=np.int64))
-        state, rel64 = self.engine.maybe_re_anchor(
-            state, rel64,
-            to_device=lambda k, v: self._put(v, self.state_specs[k]))
-        rel = rel64.astype(np.int32)
-        prepared = self.engine.prepare_cols(self.stream_key, cols)
+        with span(STAGE_CONVERT, len(part)):
+            part = np.asarray(part)
+            rel64 = self.engine.rel_ts64(np.asarray(ts, dtype=np.int64))
+            state, rel64 = self.engine.maybe_re_anchor(
+                state, rel64,
+                to_device=lambda k, v: self._put(v, self.state_specs[k]))
+            rel = rel64.astype(np.int32)
+            prepared = self.engine.prepare_cols(self.stream_key, cols)
+            rounds = _collision_rounds(part)
         pending = DeferredDenseEmit(self.engine)
         faults = getattr(self.engine, "faults", None)
         if faults is not None:
             faults.check("step.shard")
-        for ridx in _collision_rounds(part):
-            args, pos = self.route(
-                part[ridx],
-                {k: v[ridx] for k, v in prepared.items()},
-                rel[ridx],
-            )
-            state, emit, outs, anchor, round_total = self.step(state, *args)
+        for ridx in rounds:
+            with span(STAGE_CONVERT, len(ridx)):
+                round_part = part[ridx]
+                round_cols = {k: v[ridx] for k, v in prepared.items()}
+                round_rel = rel[ridx]
+            args, pos = self.route(round_part, round_cols, round_rel)
+            with span(STAGE_DISPATCH, 1):
+                state, emit, outs, anchor, round_total = self.step(
+                    state, *args)
             pending.chunks.append({
                 "emit": emit, "f": outs["f"], "i": outs["i"],
                 "anchor": anchor, "sel": pos, "ridx": ridx,

@@ -30,6 +30,7 @@ from siddhi_tpu.observability import (
     Tracer,
     render_prometheus,
 )
+from siddhi_tpu.observability import trace as trace_mod
 from siddhi_tpu.observability.prometheus import CONTENT_TYPE
 from siddhi_tpu.service import SiddhiService
 from siddhi_tpu.util.statistics import (
@@ -234,19 +235,31 @@ def test_trace_annotation_configures_tracer(tmp_path):
 
 
 def test_recorder_ring_evicts_to_newest_cycles():
-    r = FlightRecorder("app", cycles=2)  # ring depth 2*4 spans
-    for c in range(1, 6):
-        for stage in ("ingest", "step", "emit"):
-            r.record((c, stage, "device", 0.0, 1.0, 1))
+    """The ring at cycles=N holds the last N complete cycles: every
+    stage once, the host preparation in two more pieces, a second
+    collision round, and the two persist spans interleaving."""
+    one_cycle = (trace_mod.CYCLE_STAGES + ("convert", "convert")
+                 + trace_mod.ROUND_STAGES)
+    assert len(one_cycle) == trace_mod.SPANS_PER_CYCLE
+    n = 3
+    r = FlightRecorder("app", cycles=n,
+                       spans_per_cycle=len(one_cycle) + 2)
+    for c in range(1, 9):
+        for stage in one_cycle:
+            r.record((c, stage, "shard", 0.0, 1.0, 1))
+        r.record((100 + c, "persist.capture", "persist", 0.0, 1.0, 0))
+        r.record((100 + c, "persist.write", "persist", 0.0, 1.0, 0))
     groups = r.cycle_groups()
-    # oldest cycles evicted, newest complete
-    assert list(groups)[-1] == 5
-    assert [s[1] for s in groups[5]] == ["ingest", "step", "emit"]
+    # oldest cycles evicted, the newest N complete
+    for c in (6, 7, 8):
+        assert [s[1] for s in groups[c]] == list(one_cycle), c
+    assert 5 not in groups
     assert len(r.spans()) == r.ring.maxlen
 
 
 def test_recorder_dump_writes_json(tmp_path):
-    r = FlightRecorder("app", cycles=4, dump_dir=str(tmp_path))
+    r = FlightRecorder("app", cycles=4, spans_per_cycle=4,
+                       dump_dir=str(tmp_path))
     r.record((1, "ingest", "device", 0.0, 1.0, 8))
     payload = r.dump("unit-test")
     assert r.last_dump is payload
@@ -259,7 +272,8 @@ def test_recorder_dump_writes_json(tmp_path):
 
 
 def test_recorder_dump_file_cap(tmp_path):
-    r = FlightRecorder("app", cycles=4, dump_dir=str(tmp_path))
+    r = FlightRecorder("app", cycles=4, spans_per_cycle=4,
+                       dump_dir=str(tmp_path))
     for i in range(FlightRecorder.MAX_DUMP_FILES + 5):
         r.dump(f"r{i}")
     assert len(list(tmp_path.glob("*.json"))) == FlightRecorder.MAX_DUMP_FILES
@@ -312,18 +326,332 @@ def test_crash_dump_has_complete_ordered_final_cycles(tmp_path):
         cycles = list(by_cycle)
         assert cycles == sorted(cycles), "cycles must appear in order"
         # every cycle except the one the crash interrupted is a
-        # complete, ordered ingest -> step -> emit triple
+        # complete, ordered ingest -> step -> emit triple among its spans
+        assert len(cycles) >= 2
         for cid in cycles[:-1]:
-            group = by_cycle[cid]
+            group = [s for s in by_cycle[cid]
+                     if s["stage"] in ("ingest", "step", "emit")]
             assert [s["stage"] for s in group] == ["ingest", "step",
                                                    "emit"], cid
             starts = [s["t_start"] for s in group]
             assert starts == sorted(starts), cid
-            assert all(s["t_end"] >= s["t_start"] for s in group)
+            assert all(s["t_end"] >= s["t_start"] for s in by_cycle[cid])
             assert group[0]["n_events"] == 32
         # the dump also survived to disk
         files = list(tmp_path.glob("crashbox-*.json"))
         assert files and json.loads(files[0].read_text())["spans"]
+    finally:
+        m.shutdown()
+
+
+# -- the span vocabulary on the served paths ----------------------------------
+
+PARTITIONED_BODY = (
+    "define stream S (k long, v double); partition with (k of S) begin "
+    "@info(name='q') from every a=S[v > 8.0] -> b=S[v > 12.0] "
+    "select b.v as bv insert into Out; end;")
+WINDOW_BODY = (
+    "define stream S (symbol string, price float, volume int); "
+    "@info(name='q') from S#window.length(10) select symbol, "
+    "sum(price) as total, avg(volume) as av %sinsert into Out;")
+
+
+def keyed_batch(i, n=32):
+    rng = np.random.default_rng(50 + i)
+    # 24 keys over 32 rows: eight keys come twice, so every batch has a
+    # second collision round
+    return EventBatch(
+        "S", ["k", "v"],
+        {"k": np.arange(n, dtype=np.int64) % 24,
+         "v": rng.uniform(0.0, 20.0, n)},
+        np.full(n, 1_000 + i * 10, dtype=np.int64))
+
+
+def window_batch(i, n=32):
+    rng = np.random.default_rng(70 + i)
+    return EventBatch(
+        "S", ["symbol", "price", "volume"],
+        {"symbol": np.array(["a", "b"] * (n // 2)),
+         "price": rng.uniform(0.0, 20.0, n).astype(np.float32),
+         "volume": np.arange(n, dtype=np.int32)},
+        np.full(n, 1_000 + i * 10, dtype=np.int64))
+
+
+# path -> (execution options, body, batch maker, stages per cycle with
+# the least number of each, engine kind)
+SERVED_PATHS = {
+    "dense": ("partitions='64'", PARTITIONED_BODY, keyed_batch,
+              {"intern": 1, "convert": 4, "put": 2, "dispatch": 2}, "dense"),
+    "shard": ("partitions='64', devices='4'", PARTITIONED_BODY, keyed_batch,
+              {"intern": 1, "convert": 4, "route": 2, "put": 2,
+               "dispatch": 2}, "shard"),
+    # a chunk is three spans (convert, put, dispatch) and, only where
+    # keys are interned, a fourth; the runtime's column views are one
+    # more convert
+    "window": ("", WINDOW_BODY % "", window_batch,
+               {"convert": 2, "put": 1, "dispatch": 1}, "device"),
+    "window_grouped": ("", WINDOW_BODY % "group by symbol ", window_batch,
+                       {"intern": 1, "convert": 2, "put": 1, "dispatch": 1},
+                       "device"),
+}
+EVERY_CYCLE = ("ingest", "step", "emit", "fetch", "deliver")
+
+
+def covered(spans, lo, hi):
+    """Seconds of [lo, hi] that the union of ``spans`` covers."""
+    total, at = 0.0, lo
+    for a, b in sorted((s[3], s[4]) for s in spans):
+        a, b = max(a, at), min(b, hi)
+        if b > a:
+            total += b - a
+            at = b
+    return total
+
+
+@pytest.mark.parametrize("path", list(SERVED_PATHS))
+def test_spans_tile_send_batch(path, monkeypatch):
+    """At sample='1' every batch yields every span its path has, under
+    one cycle id, children inside parents, ``put`` counting the bytes
+    put; together they cover ``send_batch`` but for the entry's own
+    work ahead of the receiver."""
+    import jax
+
+    opts, body, make, owed, kind = SERVED_PATHS[path]
+    put_bytes = []
+    real_put = jax.device_put
+
+    def counting_put(x, *a, **kw):
+        put_bytes.append(sum(leaf.nbytes
+                             for leaf in jax.tree_util.tree_leaves(x)))
+        return real_put(x, *a, **kw)
+
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(
+            f"@app:name('tile_{path}') @app:playback "
+            f"@app:execution('tpu'{', ' + opts if opts else ''}) "
+            "@app:trace(sample='1', cycles='16') " + body)
+        rows = []
+        rt.add_callback("Out", rows.extend)
+        rt.start()
+        h = rt.get_input_handler("S")
+        h.send_batch(make(0))   # compiles
+        monkeypatch.setattr(jax, "device_put", counting_put)
+        sends = []
+        for i in range(1, 9):
+            del put_bytes[:]
+            t0 = time.perf_counter()
+            h.send_batch(make(i))
+            sends.append((t0, time.perf_counter(), sum(put_bytes)))
+        monkeypatch.undo()
+        assert rows
+        groups = rt.app_context.tracer.recorder.cycle_groups()
+        assert len(groups) == 9
+        remainders, shares = [], []
+        for (t0, t1, nbytes), spans in zip(sends, list(groups.values())[1:]):
+            assert {s[2] for s in spans} == {kind}
+            by = {}
+            for s in spans:
+                assert s[4] >= s[3]
+                by.setdefault(s[1], []).append(s)
+            assert set(by) == set(owed) | set(EVERY_CYCLE), set(by)
+            for stage, least in owed.items():
+                assert len(by[stage]) >= least, (stage, len(by[stage]))
+            for stage in EVERY_CYCLE:
+                assert len(by[stage]) == 1, stage
+            ingest, step, emit = by["ingest"][0], by["step"][0], by["emit"][0]
+            # ingest, step and emit start and end where they always did:
+            # ingest closes on the dispatch, step runs from there to the
+            # count gate, emit from the fetch to the delivery
+            assert step[3] == ingest[4] and step[4] <= emit[3]
+            assert by["fetch"][0][3] == emit[3]
+            assert emit[3] <= by["fetch"][0][4] <= by["deliver"][0][3]
+            assert by["deliver"][0][4] <= emit[4]
+            inside = [s for st in ("convert", "route", "put", "dispatch")
+                      for s in by.get(st, [])]
+            if kind == "device":   # interning lies inside ingest there
+                inside += by.get("intern", [])
+                assert len(spans) == len(owed) + 1 + len(EVERY_CYCLE)
+            else:                  # and ahead of it on the partitioned path
+                assert by["intern"][0][4] <= ingest[3]
+            assert all(s[5] == 32 for s in by.get("intern", []))
+            assert all(ingest[3] <= s[3] and s[4] <= ingest[4]
+                       for s in inside)
+            # siblings never overlap: a stage's time is a plain sum
+            inside.sort(key=lambda s: s[3])
+            assert all(a[4] <= b[3] for a, b in zip(inside, inside[1:]))
+            shares.append(covered(inside, ingest[3], ingest[4])
+                          / (ingest[4] - ingest[3]))
+            assert sum(s[5] for s in by["put"]) == nbytes > 0
+            assert sum(s[5] for s in by["dispatch"]) == len(by["dispatch"])
+            assert by["deliver"][0][5] == emit[5] > 0
+            assert by["fetch"][0][5] > 0
+            assert all(t0 <= s[3] and s[4] <= t1 for s in spans)
+            remainders.append((t1 - t0) - covered(spans, t0, t1))
+        # the children cover ingest (the median of eight: a batch of 32
+        # events is short enough for one preemption to be a fifth of it)
+        assert sorted(shares)[len(shares) // 2] >= 0.8
+        # the stated remainder: InputHandler, junction and receiver ahead
+        # of the cycle, and the return through them; the median of eight
+        # is under half a millisecond on any host the suite runs on
+        assert sorted(remainders)[len(remainders) // 2] < 0.5e-3
+    finally:
+        m.shutdown()
+
+
+def test_unsampled_cycles_allocate_nothing(monkeypatch):
+    """An unsampled cycle makes no token, no span and no annotation,
+    and leaves nothing in the ring."""
+    made = {"token": 0, "span": 0, "annotation": 0}
+
+    def counted(cls, what):
+        init = cls.__init__
+
+        def counting(self, *a, **kw):
+            made[what] += 1
+            init(self, *a, **kw)
+        monkeypatch.setattr(cls, "__init__", counting)
+
+    counted(trace_mod.CycleToken, "token")
+    counted(trace_mod.Span, "span")
+    real = trace_mod.annotation
+
+    def counting_annotation(stage):
+        made["annotation"] += 1
+        return real(stage)
+    monkeypatch.setattr(trace_mod, "annotation", counting_annotation)
+    import siddhi_tpu.core.emit_queue as eq
+
+    monkeypatch.setattr(eq, "annotation", counting_annotation)
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(
+            "@app:name('unsampled') @app:playback "
+            "@app:execution('tpu', partitions='64') "
+            "@app:trace(sample='1/4', cycles='16') " + PARTITIONED_BODY)
+        rows = []
+        rt.add_callback("Out", rows.extend)
+        rt.start()
+        h = rt.get_input_handler("S")
+        tracer = rt.app_context.tracer
+        for i in range(3):      # cycle ids 1..3: none on the stride
+            h.send_batch(keyed_batch(i))
+        assert rows and made == {"token": 0, "span": 0, "annotation": 0}
+        assert tracer.recorder.spans() == []
+        h.send_batch(keyed_batch(3))    # cycle 4 is sampled
+        assert made["token"] == 1
+        spans = tracer.recorder.spans()
+        assert spans and {s[0] for s in spans} == {4}
+        # every Span and the two spans clocked by hand (step_wait, fetch)
+        # made one annotation each
+        assert made["annotation"] == made["span"] + 2
+        assert made["span"] == len(spans) - 4  # ingest, step, emit, fetch
+        before = dict(made)
+        for i in range(4, 7):   # 5..7 unsampled again
+            h.send_batch(keyed_batch(i))
+        assert made == before and len(tracer.recorder.spans()) == len(spans)
+    finally:
+        m.shutdown()
+
+
+def test_a_dead_cycle_takes_no_later_span():
+    """A cycle that died before its ingest span closed is not the
+    thread's open cycle for the batches after it."""
+    t = Tracer("app", sample=2)
+    assert t.begin_cycle("dense", 1) is None
+    tok = t.begin_cycle("dense", 1)             # cycle 2, sampled, dies
+    with trace_mod.span("put", 8):
+        pass
+    assert t.begin_cycle("dense", 1) is None    # cycle 3, unsampled
+    with trace_mod.span("put", 8) as sp:
+        assert sp is None
+    tok4 = t.begin_cycle("dense", 1)
+    tok4.dispatched()
+    with trace_mod.span("put", 8) as sp:        # after ingest closed
+        assert sp is None
+    assert [(s[0], s[1]) for s in t.recorder.spans()] == [
+        (tok.cycle, "put"), (tok4.cycle, "ingest")]
+
+
+@pytest.mark.parametrize("path,site", [("dense", "step.dense"),
+                                       ("window", "step.device")])
+def test_a_raising_batch_closes_its_cycle(path, site, tmp_path):
+    """An exception that leaves the batch path (no ``dispatched()``, no
+    ``aborted()`` ran) closes the thread's open cycle: the ring shows
+    where the batch died, and a later ``span`` of code that opens no
+    cycle records nothing."""
+    opts, body, make, _owed, _kind = SERVED_PATHS[path]
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(
+            f"@app:name('raise_{path}') @app:playback "
+            f"@app:execution('tpu'{', ' + opts if opts else ''}) "
+            f"@app:trace(sample='1', cycles='8', dir='{tmp_path}') "
+            f"@app:faults({site}='crash:after=2') " + body)
+        rt.start()
+        h = rt.get_input_handler("S")
+        with pytest.raises(SimulatedCrashError):
+            for i in range(4):
+                h.send_batch(make(i))
+        assert getattr(trace_mod._open, "tok", None) is None
+        tracer = rt.app_context.tracer
+        before = tracer.recorder.spans()
+        dead = before[-1]
+        assert dead[1] == "ingest.aborted" and dead[0] == max(
+            s[0] for s in before)
+        with trace_mod.span("put", 8) as sp:
+            assert sp is None
+        assert tracer.recorder.spans() == before
+    finally:
+        m.shutdown()
+
+
+def test_jitted_steps_carry_the_device_scopes():
+    """The compiled HLO of the dense step, the sharded step and the
+    window step names each phase in its ``op_name`` metadata."""
+    from siddhi_tpu.ops.dense_nfa import compile_pattern
+    from siddhi_tpu.parallel.mesh import ShardedPatternEngine, make_mesh
+
+    def scopes_in(lowered):
+        text = lowered.compile().as_text()
+        return {sc for sc in trace_mod.DEVICE_SCOPES
+                if re.search(r'op_name="[^"]*/' + re.escape(sc) + r'[/"]',
+                             text)}
+
+    n = 16
+    idx = np.arange(n, dtype=np.int32)
+    eng = compile_pattern(PATTERN_BODY, n_partitions=64)
+    sk = eng.default_stream
+    cols = eng.prepare_cols(sk, {"k": idx.astype(np.int64),
+                                 "v": np.linspace(0.0, 20.0, n)})
+    dense = {sc for sc in trace_mod.DEVICE_SCOPES if ".dense." in sc}
+    assert len(dense) == 4
+    assert scopes_in(eng.make_step(sk).lower(
+        eng.init_state(), idx, cols, idx, np.ones(n, bool))) == dense
+    sharded = ShardedPatternEngine(
+        compile_pattern(PATTERN_BODY, n_partitions=64), make_mesh(4))
+    args, _pos = sharded.route(idx, cols, idx)
+    assert scopes_in(sharded._step.lower(
+        sharded.init_state(), *args)) == dense | {
+            trace_mod.SCOPE_SHARD_COUNT_PSUM}
+    m = SiddhiManager()
+    try:
+        # a filter and a having, so that no phase of the step is empty
+        rt = m.create_siddhi_app_runtime(
+            "@app:name('scopes') @app:playback @app:execution('tpu') "
+            "define stream S (symbol string, price float, volume int); "
+            "@info(name='q') from S[price > 1.0]#window.length(10) "
+            "select symbol, sum(price) * 2.0 as total, avg(volume) as av "
+            "having total > 3.0 insert into Out;", register=False)
+        weng = rt.query_runtimes["q"].device_runtime.engine
+        c, t, g, wg, valid, _b = weng._pad(
+            {"price": np.linspace(0.0, 9.0, n).astype(np.float32),
+             "volume": idx}, idx, np.zeros(n, np.int32), n)
+        window = {sc for sc in trace_mod.DEVICE_SCOPES if ".window." in sc}
+        assert len(window) == 6
+        assert scopes_in(weng.make_step().lower(
+            weng.init_state(), c, t, g, wg, valid)) == window
+        rt.shutdown()
     finally:
         m.shutdown()
 
