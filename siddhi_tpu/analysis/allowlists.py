@@ -24,6 +24,7 @@ _DS = "siddhi_tpu/core/device_single.py"
 _DP = "siddhi_tpu/core/dense_pattern.py"
 _DQ = "siddhi_tpu/ops/device_query.py"
 _DN = "siddhi_tpu/ops/dense_nfa.py"
+_DL = "siddhi_tpu/ops/dense_layout.py"
 _SH = "siddhi_tpu/parallel/device_shard.py"
 _M = "siddhi_tpu/parallel/mesh.py"
 
@@ -49,12 +50,8 @@ ALLOWLISTS = {
             "barrier: idle purge, behind drain()",
         f"{_DP}:DensePatternRuntime.on_time":
             "barrier: timer step, behind drain()",
-        f"{_DP}:DensePatternRuntime.snapshot":
-            "barrier: snapshot path, behind drain()",
         f"{_DP}:DensePatternRuntime.restore":
             "barrier: restore path, behind drain()",
-        f"{_DP}:DensePatternRuntime.stats":
-            "stats: slow-polled pattern_state gauge",
         f"{_DQ}:_split_i64":
             "ingest: splits HOST int64 cols into device i32 lanes",
         f"{_DQ}:DeviceQueryEngine._host_env":
@@ -95,8 +92,15 @@ ALLOWLISTS = {
             "ingest: converts HOST batch inputs before staged_put",
         f"{_DN}:DensePatternEngine.on_time_state":
             "barrier: deadline-timer step, behind drain()",
-        f"{_DN}:DensePatternEngine.maybe_re_anchor":
-            "barrier: ts re-anchor, behind drain()",
+        f"{_DL}:DenseStateLayout.encode":
+            "ingest: HOST logical field -> row words (restore, handoff)",
+        f"{_DL}:DenseStateLayout.decode":
+            "barrier: words fetched by snapshot/stats/handoff callers, "
+            "behind drain()",
+        f"{_DL}:DenseStateLayout.pack":
+            "barrier: restore / re-anchor path, behind drain()",
+        f"{_DL}:DenseStateLayout.unpack":
+            "barrier: snapshot / re-anchor path, behind drain()",
         f"{_DN}:DeferredDenseEmit.materialize":
             "drain: deferred-emit materializer (runs on fetched host arrays)",
         f"{_DN}:DeferredDenseEmit.resolve":

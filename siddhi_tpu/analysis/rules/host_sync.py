@@ -34,6 +34,7 @@ SCANNED = (
     "siddhi_tpu/core/dense_pattern.py",
     "siddhi_tpu/ops/device_query.py",
     "siddhi_tpu/ops/dense_nfa.py",
+    "siddhi_tpu/ops/dense_layout.py",
     "siddhi_tpu/parallel/device_shard.py",
     "siddhi_tpu/parallel/mesh.py",
     "siddhi_tpu/ops/fused_graph.py",

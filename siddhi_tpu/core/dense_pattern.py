@@ -185,9 +185,9 @@ def _trace_check(eng):
     first event (mirrors DeviceQueryEngine._trace_check)."""
     import jax
 
-    host = eng.init_state_host()
     state_shapes = {
-        k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in host.items()
+        k: jax.ShapeDtypeStruct(shape, np.int32) for k, shape in
+        eng.layout.physical_shapes(eng.n_partitions + 1).items()
     }
     B = 16
     i32 = jax.ShapeDtypeStruct((B,), np.int32)
@@ -519,7 +519,7 @@ class DensePatternRuntime:
         self.drain()
         rows = self._phys_rows(np.asarray([r for _k, r in idle],
                                           dtype=np.int32))
-        init = self.engine.init_state_host()
+        init = self.engine.layout.init_physical(1)
         jnp = self.engine.jnp
         state = dict(self.state)
         for key, arr in state.items():
@@ -716,18 +716,19 @@ class DensePatternRuntime:
         USE (interned keys; row 0 when unpartitioned) — the scratch row
         and never-touched pre-armed rows of non-every engines don't
         inflate it."""
-        active = np.asarray(self.state["active"])
         partitioned = self.engine.n_partitions > 1
         if self._key_rows:
             rows = self._phys_rows(np.fromiter(
                 self._key_rows.values(), dtype=np.int64,
                 count=len(self._key_rows)))
-            act = int(active[rows].sum())
         elif not partitioned:
             # unpartitioned: the single automaton lives in row 0
-            act = int(active[0].sum())
+            rows = np.zeros(1, dtype=np.int64)
         else:
-            act = 0
+            rows = None
+        # the rows' active words alone are gathered and summed on device
+        act = 0 if rows is None else int(self.engine.jnp.sum(
+            self.engine.layout.words(self.state, "active", rows) != 0))
         return {
             "engine": "dense",
             "partitions_in_use": (
@@ -775,7 +776,10 @@ class DensePatternRuntime:
         self.drain()
         self._check_overflow()
         return {
-            "dense_state": {k: np.asarray(v) for k, v in self.state.items()},
+            # the LOGICAL form ([rows, S, I] / [rows, S, I, R] arrays
+            # per field): checkpoints do not depend on the resident
+            # layout (ops/dense_layout.py)
+            "dense_state": self.engine.layout.unpack(self.state),
             "base_ts": self.engine.base_ts,
             "key_rows": dict(self._key_rows),
             "next_row": self._next_row,
@@ -786,29 +790,31 @@ class DensePatternRuntime:
     def restore(self, state: Dict):
         self.drain()
         jnp = self.engine.jnp
-        rows = len(next(iter(state["dense_state"].values())))
+        logical = state["dense_state"]
+        rows = len(next(iter(logical.values())))
         if self._sharded is not None:
-            first = next(iter(self._sharded.values()))
             want = self.n_shards * (self.parts_per_shard + 1)
-            if rows != want:
-                raise SiddhiAppRuntimeError(
-                    f"cannot restore: snapshot has {rows} state rows but "
-                    f"this app's sharded layout needs {want} "
-                    "(snapshot taken under a different "
-                    "@app:execution devices/partitions setting)")
-            self.state = {
-                k: first._put(np.asarray(v), first.state_specs[k])
-                for k, v in state["dense_state"].items()
-            }
         else:
             want = self.engine.n_partitions + 1
-            if rows != want:
-                raise SiddhiAppRuntimeError(
-                    f"cannot restore: snapshot has {rows} state rows but "
-                    f"this app needs {want} (snapshot taken under a "
-                    "different @app:execution devices/partitions setting)")
-            self.state = {
-                k: jnp.asarray(v) for k, v in state["dense_state"].items()}
+        if rows != want:
+            whose = ("this app's sharded layout" if self._sharded is not None
+                     else "this app")
+            raise SiddhiAppRuntimeError(
+                f"cannot restore: snapshot has {rows} state rows but "
+                f"{whose} needs {want} (snapshot taken under a different "
+                "@app:execution devices/partitions setting)")
+        try:
+            physical = self.engine.layout.pack(logical)
+        except (KeyError, ValueError) as e:
+            raise SiddhiAppRuntimeError(
+                f"cannot restore: {e} (snapshot taken under a different "
+                "app definition or instances setting)") from e
+        if self._sharded is not None:
+            first = next(iter(self._sharded.values()))
+            self.state = {k: first._put(v, first.state_specs[k])
+                          for k, v in physical.items()}
+        else:
+            self.state = {k: jnp.asarray(v) for k, v in physical.items()}
         self.engine.base_ts = state["base_ts"]
         self._key_rows = dict(state["key_rows"])
         self._row_keys = {r: k for k, r in self._key_rows.items()}

@@ -230,8 +230,12 @@ class FusedChainRuntime:
             "chain": [host_copy(st) for st in self.state],
             "hosts": [eng.host_snapshot() for eng in self.graph.stages],
         }
-        if self.graph.dense is not None:
-            snap["dense_base_ts"] = self.graph.dense.base_ts
+        dense = self.graph.dense
+        if dense is not None:
+            # the dense tail's state in its logical form, whatever the
+            # resident layout (ops/dense_layout.py)
+            snap["chain"][-1] = dense.layout.unpack(self.state[-1])
+            snap["dense_base_ts"] = dense.base_ts
         return snap
 
     def restore(self, state: Dict):
@@ -248,13 +252,18 @@ class FusedChainRuntime:
                 "use the same app definition")
         restored: List = []
         for si, st in enumerate(chain):
-            eng = g.stages[si] if si < len(g.stages) else g.dense
-            expect = {k: v.shape for k, v in eng.init_state_host().items()}
+            is_dense = si >= len(g.stages)
+            eng = g.dense if is_dense else g.stages[si]
+            expect = (eng.layout.logical_shapes(eng.n_partitions + 1)
+                      if is_dense else
+                      {k: v.shape for k, v in eng.init_state_host().items()})
             for k, v in st.items():
                 if k in expect and v.shape != expect[k]:
                     raise SiddhiAppRuntimeError(
                         f"fused-chain snapshot stage {si} array '{k}' has "
                         f"shape {v.shape}; this chain expects {expect[k]}")
+            if is_dense:
+                st = eng.layout.pack(st)
             restored.append({k: jnp.asarray(v) for k, v in st.items()})
         self.state = tuple(restored)
         for eng, h in zip(g.stages, state["hosts"]):

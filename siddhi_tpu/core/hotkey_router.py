@@ -207,10 +207,14 @@ class HotKeyRouterRuntime:
         jnp = scan.jnp
         phys = int(dense._phys_rows(np.int64(row)))
         st = dense.state
+        layout = dense.engine.layout
         host = self._fetch_rows(
-            [st["active"][phys], st["first_ts"][phys]])
+            [layout.words(st, "active", phys),
+             layout.words(st, "first_ts", phys)])
         if host is None:
             return False
+        host = [layout.decode("active", host[0]),
+                layout.decode("first_ts", host[1])]
         dense_base = dense.engine.base_ts or 0
         if scan.base_ts is None:
             scan.base_ts = dense_base
@@ -224,7 +228,7 @@ class HotKeyRouterRuntime:
         # clear the dense row to its init template (the pending chains
         # moved); the row stays interned to the key — demotion writes
         # back into it.  `overflow` is a durable drop counter, keep it.
-        init = dense.engine.init_state_host()
+        init = dense.engine.layout.init_physical(1)
         new_state = dict(st)
         for k, arr in new_state.items():
             if k == "overflow":
@@ -252,10 +256,9 @@ class HotKeyRouterRuntime:
             host[0], host[1], scan.base_ts or 0,
             dense.engine.base_ts or 0, dense.engine.I)
         phys = int(dense._phys_rows(np.int64(row)))
-        st = dict(dense.state)
-        st["active"] = st["active"].at[phys].set(jnp.asarray(active))
-        st["first_ts"] = st["first_ts"].at[phys].set(
-            jnp.asarray(first_ts))
+        layout = dense.engine.layout
+        st = layout.with_field(dense.state, "active", phys, active)
+        st = layout.with_field(st, "first_ts", phys, first_ts)
         if dropped:
             st["overflow"] = st["overflow"].at[phys].add(
                 np.int32(dropped))

@@ -3,11 +3,13 @@
 The eligible class (gated in ``planner/kernels.py``) is the capture-free
 every-start chain: all nodes are plain stream states (``min==max==1``),
 no sequences, no group-every, no absent deadlines, no register slots, no
-mesh.  Inside that class the XLA step's carry shrinks to two arrays —
+mesh.  Inside that class the XLA step's carry shrinks to two fields —
 node activity and the within anchor.  ``counts``/``regs`` are provably
-constant in this class and pass through the state dict untouched, so
-snapshot/restore, sharding, and the multiplex seat tiling keep seeing
-the existing layout.
+constant in this class: the step gathers the batch's rows through the
+engine's state layout (``ops/dense_layout.py``, one contiguous row per
+partition), replaces the two fields it advances and scatters the rows
+back, so snapshot/restore, sharding, and the multiplex seat tiling see
+the same physical state as under the XLA step.
 
 Layout: the batch axis is the vector.  Every ``(node, instance)`` pair
 is one int32 *plane* over the batch, and a block of 1024 batch rows is
@@ -207,8 +209,9 @@ def build_plane_nfa(engine, stream_key: str, jit: bool = True):
                 ok_rows.append(okb & valid)
         ok_mat = jnp.stack(ok_rows, axis=0)  # [S, B]
 
-        a = state["active"][part_idx]        # [B, S, I]
-        first = state["first_ts"][part_idx]  # [B, S, I]
+        fields, old_rows = engine.layout.gather(state, part_idx)
+        a = fields["active"]        # [B, S, I]
+        first = fields["first_ts"]  # [B, S, I]
         if pad:
             a = jnp.pad(a, ((0, pad), (0, 0), (0, 0)))
             first = jnp.pad(first, ((0, pad), (0, 0), (0, 0)))
@@ -266,23 +269,12 @@ def build_plane_nfa(engine, stream_key: str, jit: bool = True):
                 jnp.where(emit_b0, val.astype(jnp.float32)[:, None],
                           out_vals[:, sl, oi]))
 
-        new_ovf = state["overflow"][part_idx] + ovf_delta
-
-        v1 = valid[:, None, None]
-        new_state = {
-            "active": state["active"].at[part_idx].set(
-                jnp.where(v1, a_new, state["active"][part_idx])),
-            "first_ts": state["first_ts"].at[part_idx].set(
-                jnp.where(v1, first_new, state["first_ts"][part_idx])),
-            # constant in the eligible class: pass through value-identical
-            # (a same-value scatter keeps donation layouts unchanged)
-            "counts": state["counts"].at[part_idx].set(
-                state["counts"][part_idx]),
-            "regs": state["regs"].at[part_idx].set(
-                state["regs"][part_idx]),
-            "overflow": state["overflow"].at[part_idx].set(
-                jnp.where(valid, new_ovf, state["overflow"][part_idx])),
-        }
+        # counts/regs are constant in the eligible class: they ride back
+        # inside the gathered rows, value-identical
+        new_state = engine.layout.scatter(
+            state, part_idx,
+            {**fields, "active": a_new, "first_ts": first_new},
+            ovf_delta, valid, old_rows)
         n_emit = jnp.sum((emit & valid[:, None]).astype(jnp.int32))
         return (new_state, emit, {"f": out_vals, "i": out_ivals},
                 emit_anchor, n_emit)
@@ -304,9 +296,9 @@ def smoke_compile(engine):
     import numpy as np
 
     jax = engine.jax
-    host = engine.init_state_host()
     state_shapes = {
-        k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in host.items()
+        k: jax.ShapeDtypeStruct(shape, np.int32) for k, shape in
+        engine.layout.physical_shapes(engine.n_partitions + 1).items()
     }
     B = BLOCK_ROWS
     i32 = jax.ShapeDtypeStruct((B,), np.int32)

@@ -84,7 +84,6 @@ class DenseMultiplexGroup:
         self.emit_queue = EmitQueue(depth=1, stats=self.emit_stats,
                                     faults=None, on_fault=self._on_fault)
         self.state = engine.init_state()
-        self._init_host = engine.init_state_host()
         self.dispatches = 0
         self.combined_steps = 0
         self._ovf_warned = 0
@@ -122,12 +121,7 @@ class DenseMultiplexGroup:
             self._check_overflow()
             self.seats[slot] = None
             self._free.append(slot)
-            jnp = self.engine.jnp
-            self.state = {
-                k: self.state[k].at[slot:slot + 1].set(
-                    jnp.asarray(self._init_host[k][slot:slot + 1]))
-                for k in self.state
-            }
+            self._set_tenant_rows(slot, self.engine.layout.init_logical(1))
 
     def occupied_count(self) -> int:
         with self.lock:
@@ -189,10 +183,10 @@ class DenseMultiplexGroup:
             # shift the shared base down so relative ts stay positive
             # (host round trip; rare — admission-time skew only)
             delta = (ts_min - eng.base_ts) - 1
-            host = {k: np.asarray(v) for k, v in self.state.items()}
-            host = eng.shift_row_ts(host, delta)
+            host = eng.shift_row_ts(eng.layout.unpack(self.state), delta)
             jnp = eng.jnp
-            self.state = {k: jnp.asarray(v) for k, v in host.items()}
+            self.state = {k: jnp.asarray(v)
+                          for k, v in eng.layout.pack(host).items()}
             eng.base_ts += delta
 
     def _dispatch_stream(self, stream_key: str, items) -> None:
@@ -251,25 +245,30 @@ class DenseMultiplexGroup:
         if fi is None or not fi.watches("state.poison"):
             return
         t = seat.slot
-        rows = {k: self.state[k][t:t + 1] for k in self.state}
+        layout = self.engine.layout
+        # the tenant's row in the logical form: the float leaf (`regs`)
+        # is where a NaN/Inf can live, and the physical row is int32
+        rows = layout.unpack({k: self.state[k][t:t + 1] for k in self.state})
         if fi.poisoned("state.poison"):
             rows = _faults.poison_state(rows)
-            self.state = {
-                k: self.state[k].at[t:t + 1].set(rows[k])
-                for k in self.state
-            }
+            self._set_tenant_rows(t, rows)
         if not _faults.state_has_poison(rows):
-            seat.last_good = _faults.host_copy(rows)
+            seat.last_good = rows
             return
         fi.stats.poison_quarantines += 1
         log.warning(
             "multiplex: poisoned state in dense tenant slot %d "
             "quarantined; restoring last known good rows", t)
-        good = (seat.last_good if seat.last_good is not None
-                else {k: v[t:t + 1] for k, v in self._init_host.items()})
+        self._set_tenant_rows(
+            t, seat.last_good if seat.last_good is not None
+            else layout.init_logical(1))
+
+    def _set_tenant_rows(self, t: int, logical: Dict) -> None:
+        """Write one tenant's row, given in the logical form."""
         jnp = self.engine.jnp
+        rows = self.engine.layout.pack(logical)
         self.state = {
-            k: self.state[k].at[t:t + 1].set(jnp.asarray(good[k]))
+            k: self.state[k].at[t:t + 1].set(jnp.asarray(rows[k]))
             for k in self.state
         }
 
@@ -290,8 +289,8 @@ class DenseMultiplexGroup:
             self._dispatch_locked()
             t = adapter.slot
             return {
-                "dense_state": {k: np.asarray(v[t:t + 1])
-                                for k, v in self.state.items()},
+                "dense_state": self.engine.layout.unpack(
+                    {k: v[t:t + 1] for k, v in self.state.items()}),
                 "base_ts": self.engine.base_ts,
             }
 
@@ -304,9 +303,8 @@ class DenseMultiplexGroup:
             seat.pending_out.clear()
             seat.last_good = None
             rows = {k: np.asarray(v) for k, v in snap["dense_state"].items()}
-            for k, ref in self._init_host.items():
+            for k, want in eng.layout.logical_shapes(1).items():
                 got = rows.get(k)
-                want = (1,) + ref.shape[1:]
                 if got is None or got.shape != want:
                     raise SiddhiAppRuntimeError(
                         f"cannot restore: tenant snapshot key '{k}' has "
@@ -320,11 +318,7 @@ class DenseMultiplexGroup:
                 # the snapshot's relative anchors were taken against its
                 # own base; re-express them against the group base
                 rows = eng.shift_row_ts(rows, eng.base_ts - b_snap)
-            jnp = eng.jnp
-            self.state = {
-                k: self.state[k].at[t:t + 1].set(jnp.asarray(rows[k]))
-                for k in self.state
-            }
+            self._set_tenant_rows(t, rows)
 
 
 class DenseMultiplexTenantRuntime:
@@ -438,13 +432,14 @@ class DenseMultiplexTenantRuntime:
         pass
 
     def stats(self) -> Dict:
-        active = np.asarray(self.group.state["active"])
+        active = self.engine.layout.field(
+            self.group.state, "active", self.slot)
         return {
             "engine": "dense-multiplex",
             "partitions_in_use": 1,
             "partition_capacity": 1,
             "instance_lanes": self.engine.I,
-            "active_instances": int(active[self.slot].sum()),
+            "active_instances": int(active.sum()),
             "dropped_instances": int(
                 np.asarray(self.group.state["overflow"])[self.slot]),
             "step_invocations": self.step_invocations,

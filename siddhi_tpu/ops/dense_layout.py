@@ -1,0 +1,248 @@
+"""Resident layout of the dense pattern state — the one file that knows it.
+
+**Logical** state (what the automaton, the host engine's parity tests
+and every snapshot speak): per partition row ``active`` [S, I] bool,
+``first_ts`` / ``counts`` [S, I] int32, ``regs`` [S, I, R] float32,
+optionally ``iregs`` [S, I, 2*RI] int32 and ``deadline`` [S, I] int32,
+plus one ``overflow`` int32 counter.
+
+**Physical** state (what lives in HBM and what the jitted steps take
+and donate): ``{"rows": int32[N, W], "overflow": int32[N]}``.  A
+partition is ONE contiguous row of W 32-bit words, the partition axis
+major, W a multiple of 128 lanes: the fields above, flattened in the
+order listed and bit-cast to int32 (``active`` one 0/1 word per lane),
+then zero padding up to W.  A batch is then one gather of B rows and one
+scatter of B rows; the fields are split out of the gathered rows (a
+relayout of B rows, never of N), and the donated ``rows`` array is
+updated in place.  Trailing dims of 16 x 4 as separate ``[N, S, I]``
+arrays made XLA put the partition axis on the lanes and transpose the
+whole state twice a step (PERF.md, PR 27).
+
+W and the offsets follow from S, I and the register allocator alone;
+no app, annotation or option selects anything here.  ``overflow`` stays
+its own vector: ``overflow_total`` sums it without touching the rows.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Tuple
+
+import numpy as np
+
+ROWS = "rows"
+OVERFLOW = "overflow"
+LANES = 128
+
+
+class DenseStateLayout:
+    """Offsets of every logical field inside a partition's row, and the
+    conversions between the logical and the physical form: ``pack`` /
+    ``unpack`` on the host (numpy), ``split`` / ``join`` on gathered rows
+    inside a jitted step, ``words`` / ``decode`` / ``with_field`` for
+    callers that read or write single fields of a few rows."""
+
+    def __init__(self, S: int, I: int, n_regs: int, n_iregs: int,
+                 has_deadlines: bool, armed_start: bool):
+        self.S, self.I = S, I
+        # name -> (logical dtype, trailing logical shape)
+        self.fields: Dict[str, Tuple[np.dtype, Tuple[int, ...]]] = {
+            "active": (np.dtype(bool), (S, I)),
+            # relative ms since the engine's base_ts (int32: ~24 days of
+            # horizon), 0 == unset
+            "first_ts": (np.dtype(np.int32), (S, I)),
+            "counts": (np.dtype(np.int32), (S, I)),
+            "regs": (np.dtype(np.float32), (S, I, max(n_regs, 1))),
+        }
+        if n_iregs:
+            # integer capture bank: hi/lo int32 pair per slot
+            self.fields["iregs"] = (np.dtype(np.int32), (S, I, 2 * n_iregs))
+        if has_deadlines:
+            # absent-node deadlines (relative ms; 0 == unset)
+            self.fields["deadline"] = (np.dtype(np.int32), (S, I))
+        self.offsets: Dict[str, Tuple[int, int]] = {}
+        off = 0
+        for name, (_dt, shape) in self.fields.items():
+            w = int(np.prod(shape))
+            self.offsets[name] = (off, w)
+            off += w
+        self.used = off
+        self.width = -(-off // LANES) * LANES
+        # non-every: node 0 armed once per partition (lane 0); after a
+        # match reset_on_emit clears it and the automaton is done
+        self.armed_start = armed_start
+
+    # -- shapes ---------------------------------------------------------------
+
+    def logical_shapes(self, n_rows: int) -> Dict[str, Tuple[int, ...]]:
+        shapes = {k: (n_rows,) + shape for k, (_dt, shape) in
+                  self.fields.items()}
+        shapes[OVERFLOW] = (n_rows,)
+        return shapes
+
+    def physical_shapes(self, n_rows: int) -> Dict[str, Tuple[int, ...]]:
+        """Shapes of the physical arrays (both int32), for abstract
+        tracing without allocating a state."""
+        return {ROWS: (n_rows, self.width), OVERFLOW: (n_rows,)}
+
+    def pspecs(self, axis: str):
+        """Partition-axis sharding spec per physical array (row-sharded,
+        the words of a row stay together)."""
+        from jax.sharding import PartitionSpec as Pspec
+
+        return {ROWS: Pspec(axis, None), OVERFLOW: Pspec(axis)}
+
+    # -- host side (numpy) ----------------------------------------------------
+
+    def init_logical(self, n_rows: int) -> Dict[str, np.ndarray]:
+        state = {k: np.zeros((n_rows,) + shape, dtype=dt)
+                 for k, (dt, shape) in self.fields.items()}
+        if self.armed_start:
+            state["active"][:, 0, 0] = True
+        # per-partition dropped-instance count (successor slots full)
+        state[OVERFLOW] = np.zeros(n_rows, dtype=np.int32)
+        return state
+
+    def init_physical(self, n_rows: int) -> Dict[str, np.ndarray]:
+        rows = np.zeros((n_rows, self.width), dtype=np.int32)
+        if self.armed_start:
+            rows[:, self.offsets["active"][0]] = 1
+        return {ROWS: rows, OVERFLOW: np.zeros(n_rows, dtype=np.int32)}
+
+    def encode(self, name: str, value) -> np.ndarray:
+        """Logical host array [..., *shape] -> int32 words [..., w]."""
+        dt, shape = self.fields[name]
+        v = np.ascontiguousarray(np.asarray(value))
+        lead = v.shape[:v.ndim - len(shape)]
+        flat = v.reshape(lead + (-1,))
+        if dt == np.float32:
+            return flat.astype(np.float32, copy=False).view(np.int32)
+        return flat.astype(np.int32)
+
+    def decode(self, name: str, words) -> np.ndarray:
+        """int32 words [..., w] (host) -> logical array [..., *shape]."""
+        dt, shape = self.fields[name]
+        w = np.ascontiguousarray(np.asarray(words))
+        if dt == np.float32:
+            out = w.view(np.float32)
+        elif dt == np.bool_:
+            out = w != 0
+        else:
+            out = w
+        return out.reshape(w.shape[:-1] + shape)
+
+    def pack(self, logical: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+        """Logical host state (the snapshot format) -> physical host
+        state.  Raises on a missing or mis-shaped field."""
+        n = len(np.asarray(logical[OVERFLOW]))
+        want = self.logical_shapes(n)
+        rows = np.zeros((n, self.width), dtype=np.int32)
+        for name, (off, w) in self.offsets.items():
+            got = logical.get(name)
+            if got is None or tuple(np.shape(got)) != want[name]:
+                raise ValueError(
+                    f"dense state field '{name}' has shape "
+                    f"{None if got is None else tuple(np.shape(got))}, "
+                    f"this engine needs {want[name]}")
+            rows[:, off:off + w] = self.encode(name, got)
+        return {ROWS: rows,
+                OVERFLOW: np.asarray(logical[OVERFLOW], dtype=np.int32)}
+
+    def unpack(self, physical) -> Dict[str, np.ndarray]:
+        """Physical state (host or device arrays) -> logical host state."""
+        rows = np.asarray(physical[ROWS])
+        out = {name: self.decode(name, rows[:, off:off + w])
+               for name, (off, w) in self.offsets.items()}
+        out[OVERFLOW] = np.array(physical[OVERFLOW], dtype=np.int32)
+        return out
+
+    # -- single fields of a few rows (eager device ops) -----------------------
+
+    def words(self, state, name: str, rows=None):
+        """Device int32 words [..., w] of one field — of the given
+        physical row indices, or of every row.  ``decode`` turns the
+        fetched words into the logical view."""
+        off, w = self.offsets[name]
+        r = state[ROWS]
+        if rows is None:
+            return r[:, off:off + w]
+        return r[rows, off:off + w]
+
+    def field(self, state, name: str, rows=None) -> np.ndarray:
+        """Logical host view ``[..., *shape]`` of one field (fetches the
+        words of the given rows, or of every row)."""
+        return self.decode(name, self.words(state, name, rows))
+
+    def with_field(self, state, name: str, rows, value):
+        """``state`` with one logical field of the given physical rows
+        replaced by the host array ``value``."""
+        import jax.numpy as jnp
+
+        off, w = self.offsets[name]
+        new = dict(state)
+        new[ROWS] = state[ROWS].at[rows, off:off + w].set(
+            jnp.asarray(self.encode(name, value)))
+        return new
+
+    # -- inside a jitted step (traced) ----------------------------------------
+
+    def split(self, rows, shaped: bool = True) -> Dict[str, object]:
+        """Rows [N, W] -> logical fields, ``[N, *shape]`` each, or flat
+        ``[N, w]`` with ``shaped=False`` (the timer step, which runs over
+        every row and must not reshape the partition axis)."""
+        import jax
+        import jax.numpy as jnp
+
+        out = {}
+        for name, (off, w) in self.offsets.items():
+            dt, shape = self.fields[name]
+            x = rows[:, off:off + w]
+            if dt == np.float32:
+                x = jax.lax.bitcast_convert_type(x, jnp.float32)
+            elif dt == np.bool_:
+                x = x != 0
+            out[name] = x.reshape((x.shape[0],) + shape) if shaped else x
+        return out
+
+    def join(self, fields: Dict[str, object]):
+        """Logical fields (shaped or flat) -> rows [N, W]."""
+        import jax
+        import jax.numpy as jnp
+
+        parts = []
+        n = None
+        for name, (_off, w) in self.offsets.items():
+            dt, _shape = self.fields[name]
+            x = fields[name]
+            n = x.shape[0]
+            x = x.reshape(n, w)
+            if dt == np.float32:
+                x = jax.lax.bitcast_convert_type(x, jnp.int32)
+            else:
+                x = x.astype(jnp.int32)
+            parts.append(x)
+        if self.width > self.used:
+            parts.append(jnp.zeros((n, self.width - self.used), jnp.int32))
+        return jnp.concatenate(parts, axis=1)
+
+    def gather(self, state, part_idx):
+        """The batch's rows: ``(fields [B, *shape], old rows [B, W])``.
+        ``old`` feeds ``scatter`` so padded batch rows write back exactly
+        what they read.  ``overflow`` is not gathered: a step only ever
+        adds to it."""
+        old = state[ROWS][part_idx]
+        return self.split(old), old
+
+    def scatter(self, state, part_idx, fields, ovf_delta, valid, old):
+        """Write the batch's rows back in place and add each row's newly
+        dropped instances to its ``overflow`` counter.  Invalid (padded)
+        batch rows all point at the scratch row: they write back the
+        words they gathered and add 0, so the scratch row and every row
+        outside the batch keep their values."""
+        import jax.numpy as jnp
+
+        new = jnp.where(valid[:, None], self.join(fields), old)
+        return {
+            ROWS: state[ROWS].at[part_idx].set(new),
+            OVERFLOW: state[OVERFLOW].at[part_idx].add(
+                jnp.where(valid, ovf_delta, 0)),
+        }

@@ -5,16 +5,23 @@ Replaces the reference's per-event pattern processing
 object walks under a ReentrantLock per event) with a bit-parallel,
 jit-compiled step over **micro-batches of events across partitions**:
 
-- per-partition NFA state lives in HBM as dense arrays:
-  ``active`` (uint32 bitmask, one bit per chain node), ``first_ts``
-  (within-window anchors), ``counts`` (Kleene counters), ``regs``
-  (captured attribute registers used by cross-state filters/selects);
-- one step gathers the state rows for the batch's partitions, unrolls
-  the node chain in reverse (so an event advances at most one node, the
-  staged-update semantics of the host engine), evaluates all node
-  filters vectorized, and scatters the state back;
+- per-partition NFA state lives in HBM as ONE contiguous int32 row per
+  partition (``ops/dense_layout.py`` holds the layout and is the only
+  file that knows it): ``active`` (one lane per chain node and instance),
+  ``first_ts`` (within-window anchors), ``counts`` (Kleene counters),
+  ``regs`` (captured attribute registers used by cross-state
+  filters/selects), bit-cast and laid side by side, padded to a
+  multiple of 128 words.  ``engine.layout`` is the accessor: logical
+  ``[P, S, I]`` fields in, physical rows out, and back; snapshots hold
+  the logical form;
+- one step gathers the rows of the batch's partitions, splits the
+  fields out of the gathered rows, unrolls the node chain in reverse
+  (so an event advances at most one node, the staged-update semantics
+  of the host engine), evaluates all node filters vectorized, and
+  scatters the rows back in place on the donated state;
 - cost is O(batch × states × regs) independent of the partition count —
-  1M+ partitions are just HBM rows;
+  1M+ partitions are just HBM rows, and no operation of the step reads
+  or writes more than the batch's rows;
 - multi-chip: the partition axis is sharded over a ``jax.sharding.Mesh``
   (``shard()``); each shard owns its keys so the step needs no
   cross-device collectives, and emitted matches ride an all-gather only
@@ -77,6 +84,7 @@ from siddhi_tpu.observability.trace import (
     STAGE_DISPATCH,
     span,
 )
+from siddhi_tpu.ops.dense_layout import OVERFLOW, ROWS, DenseStateLayout
 from siddhi_tpu.ops.nfa import ANY, NFABuilder, Node, PatternScope, Spec
 from siddhi_tpu.planner.expr import (
     CompiledExpression,
@@ -246,20 +254,24 @@ class DenseExprCompiler(ExpressionCompiler):
         return super()._c_Variable(e)
 
 
-def _rank_place(jnp, t, mask, anchor, src_regs, src_iregs, entry_dl,
-                a, first, counts, regs, iregs, dl, ovf):
+def _rank_place(jnp, mask, anchor, src_regs, src_iregs, entry_dl,
+                a_t, first_t, counts_t, regs_t, iregs_t, dl_t, ovf):
     """Rank-matched placement of advancing instances into free lanes of
-    node ``t`` (shared by the event step and the timer step): the k-th
-    advancing instance takes the k-th free lane; advancers beyond the
-    free-lane count are dropped and counted in ``ovf`` — explicit
+    one target node (shared by the event step and the timer step): the
+    k-th advancing instance takes the k-th free lane; advancers beyond
+    the free-lane count are dropped and counted in ``ovf`` — explicit
     capacity where the reference grows an unbounded pending list.
 
+    ``a_t`` .. ``dl_t`` are the TARGET NODE's lanes alone ([B, I];
+    ``regs_t`` / ``iregs_t`` [B, I, R]); the callers slice them out of
+    whatever form they hold the state in and write the results back.
     ``entry_dl`` ([B, I] int32 or None) carries per-source deadline
-    values for a target node with an absent 'for' spec; ``dl`` may be
+    values for a target node with an absent 'for' spec; ``dl_t`` may be
     None when the engine has no deadline state at all.
 
-    Returns updated ``(a, first, counts, regs, iregs, dl, ovf)``."""
-    free = ~a[:, t, :] & (counts[:, t, :] == 0)  # [B, I]
+    Returns updated ``(a_t, first_t, counts_t, regs_t, iregs_t, dl_t,
+    ovf)``."""
+    free = ~a_t & (counts_t == 0)  # [B, I]
     src_rank = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1
     free_rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1
     n_free = jnp.sum(free.astype(jnp.int32), axis=1)  # [B]
@@ -274,30 +286,25 @@ def _rank_place(jnp, t, mask, anchor, src_regs, src_iregs, entry_dl,
         axis=1)  # [B, I, R]
     moved_anchor = jnp.sum(
         jnp.where(assign, anchor[:, :, None], 0), axis=1)  # [B, I]
-    a = a.at[:, t, :].set(a[:, t, :] | got)
-    regs = regs.at[:, t, :, :].set(
-        jnp.where(got[:, :, None], moved_regs, regs[:, t, :, :]))
-    if iregs.shape[-1]:
+    a_t = a_t | got
+    regs_t = jnp.where(got[:, :, None], moved_regs, regs_t)
+    if iregs_t.shape[-1]:
         moved_iregs = jnp.sum(
             jnp.where(assign[:, :, :, None], src_iregs[:, :, None, :], 0),
             axis=1)
-        iregs = iregs.at[:, t, :, :].set(
-            jnp.where(got[:, :, None], moved_iregs, iregs[:, t, :, :]))
-    first = first.at[:, t, :].set(
-        jnp.where(got, moved_anchor.astype(jnp.int32), first[:, t, :]))
-    counts = counts.at[:, t, :].set(
-        jnp.where(got, 0, counts[:, t, :]))
-    if dl is not None:
+        iregs_t = jnp.where(got[:, :, None], moved_iregs, iregs_t)
+    first_t = jnp.where(got, moved_anchor.astype(jnp.int32), first_t)
+    counts_t = jnp.where(got, 0, counts_t)
+    if dl_t is not None:
         if entry_dl is not None:
             moved_dl = jnp.sum(
                 jnp.where(assign, entry_dl[:, :, None], 0), axis=1)
-            dl = dl.at[:, t, :].set(
-                jnp.where(got, moved_dl.astype(jnp.int32), dl[:, t, :]))
+            dl_t = jnp.where(got, moved_dl.astype(jnp.int32), dl_t)
         else:
             # target without a deadline spec: clear any stale value left
             # by a previous occupant of the lane
-            dl = dl.at[:, t, :].set(jnp.where(got, 0, dl[:, t, :]))
-    return a, first, counts, regs, iregs, dl, ovf
+            dl_t = jnp.where(got, 0, dl_t)
+    return a_t, first_t, counts_t, regs_t, iregs_t, dl_t, ovf
 
 
 class DensePatternEngine:
@@ -483,6 +490,11 @@ class DensePatternEngine:
                     if ref == spec.ref:
                         writes.append(slot)
             self.node_writes.append(writes)
+        # where each field of a partition's state lives in its row: a
+        # function of S, I and the register banks alone
+        self.layout = DenseStateLayout(
+            self.S, self.I, self.alloc.n, self.alloc.n_int,
+            self.has_deadlines, armed_start=not self.every_start)
         self._step_cache: Dict[str, Callable] = {}
         # @app:kernels: swap the jitted XLA step for the bit-packed
         # Pallas plane kernel (siddhi_tpu/kernels/dense_step.py).  Set
@@ -551,55 +563,20 @@ class DensePatternEngine:
     # -- state --------------------------------------------------------------
 
     def init_state_host(self) -> Dict[str, np.ndarray]:
-        """Zero state as NUMPY arrays — no device allocation, so callers
-        (e.g. the sharded wrapper) can lay out rows before any backend
-        is selected."""
+        """Zero state in its PHYSICAL form (ops/dense_layout.py: one
+        int32 row of ``layout.width`` words per partition, plus the
+        ``overflow`` vector) as NUMPY arrays — no device allocation, so
+        callers (e.g. the sharded wrapper) can lay out rows before any
+        backend is selected.  ``layout.unpack`` gives the logical
+        ``[P, S, I]`` view, ``layout.pack`` the way back."""
         # one scratch row (index P) absorbs padded/invalid batch rows so
         # their scatter-back cannot collide with a real partition
-        P, S, I, R = (self.n_partitions + 1, self.S, self.I,
-                      max(self.alloc.n, 1))
-        active0 = np.zeros((P, S, I), dtype=bool)
-        if not self.every_start:
-            # non-every: node 0 armed once per partition (lane 0); after
-            # a match reset_on_emit clears it and the automaton is done
-            active0[:, 0, 0] = True
-        state = {
-            "active": active0,
-            # relative ms since self.base_ts (int32: ~24 days of horizon),
-            # 0 == unset
-            "first_ts": np.zeros((P, S, I), dtype=np.int32),
-            "counts": np.zeros((P, S, I), dtype=np.int32),
-            "regs": np.zeros((P, S, I, R), dtype=np.float32),
-            # per-partition dropped-instance count (successor slots full)
-            "overflow": np.zeros(P, dtype=np.int32),
-        }
-        if self.alloc.n_int:
-            # integer capture bank: hi/lo int32 pair per slot
-            state["iregs"] = np.zeros((P, S, I, 2 * self.alloc.n_int),
-                                      dtype=np.int32)
-        if self.has_deadlines:
-            # absent-node deadlines (relative ms; 0 == unset)
-            state["deadline"] = np.zeros((P, S, I), dtype=np.int32)
-        return state
+        return self.layout.init_physical(self.n_partitions + 1)
 
     def state_pspecs(self):
         """Partition-axis sharding spec per state array (row-sharded;
-        trailing node/instance/register dims replicated)."""
-        from jax.sharding import PartitionSpec as Pspec
-
-        a = self.partition_axis
-        specs = {
-            "active": Pspec(a, None, None),
-            "first_ts": Pspec(a, None, None),
-            "counts": Pspec(a, None, None),
-            "regs": Pspec(a, None, None, None),
-            "overflow": Pspec(a),
-        }
-        if self.alloc.n_int:
-            specs["iregs"] = Pspec(a, None, None, None)
-        if self.has_deadlines:
-            specs["deadline"] = Pspec(a, None, None)
-        return specs
+        the words of a row replicated)."""
+        return self.layout.pspecs(self.partition_axis)
 
     def init_state(self):
         jnp = self.jnp
@@ -843,9 +820,21 @@ class DensePatternEngine:
                 entry_dl = (
                     jnp.broadcast_to(ts[:, None] + w, mask.shape)
                     if w is not None else None)
-                a, first, counts, regs, iregs, dlh[0], ovf = _rank_place(
-                    jnp, t, mask, anchor, src_regs, si, entry_dl,
-                    a, first, counts, regs, iregs, dlh[0], ovf)
+                dl = dlh[0]
+                a_t, first_t, counts_t, regs_t, iregs_t, dl_t, ovf = (
+                    _rank_place(
+                        jnp, mask, anchor, src_regs, si, entry_dl,
+                        a[:, t, :], first[:, t, :], counts[:, t, :],
+                        regs[:, t, :, :], iregs[:, t, :, :],
+                        None if dl is None else dl[:, t, :], ovf))
+                a = a.at[:, t, :].set(a_t)
+                first = first.at[:, t, :].set(first_t)
+                counts = counts.at[:, t, :].set(counts_t)
+                regs = regs.at[:, t, :, :].set(regs_t)
+                if iregs.shape[-1]:
+                    iregs = iregs.at[:, t, :, :].set(iregs_t)
+                if dl is not None:
+                    dlh[0] = dl.at[:, t, :].set(dl_t)
                 return (a, first, counts, regs, iregs, emit, out_vals, out_ivals,
                         emit_anchor, ovf)
 
@@ -1211,51 +1200,30 @@ class DensePatternEngine:
 
         named_scope = self.jax.named_scope
 
+        layout = self.layout
+
         def step(state, part_idx, cols, ts, valid):
             B = part_idx.shape[0]
+            # one gather of the batch's rows; the fields are split out of
+            # the gathered rows, so every relayout is of B rows
             with named_scope(SCOPE_DENSE_GATHER):
-                a = state["active"][part_idx]        # [B, S, I] bool
-                first = state["first_ts"][part_idx]  # [B, S, I]
-                counts = state["counts"][part_idx]   # [B, S, I]
-                regs = state["regs"][part_idx]       # [B, S, I, R]
-                iregs = (state["iregs"][part_idx] if "iregs" in state
-                         else jnp.zeros((B, S, I, 0), dtype=jnp.int32))
-                ovf = state["overflow"][part_idx]    # [B]
-                dl = (state["deadline"][part_idx] if "deadline" in state
-                      else None)
+                f, old = layout.gather(state, part_idx)
+                iregs = f.get("iregs")
+                if iregs is None:
+                    iregs = jnp.zeros((B, S, I, 0), dtype=jnp.int32)
             with named_scope(SCOPE_DENSE_ADVANCE):
                 (a, first, counts, regs, iregs, ovf, dl, emit, out_vals,
                  out_ivals, emit_anchor) = advance(
-                    a, first, counts, regs, iregs, ovf, dl, cols, ts, valid)
-            # scatter back (valid rows only)
+                    f["active"], f["first_ts"], f["counts"], f["regs"],
+                    iregs, jnp.zeros((B,), dtype=jnp.int32),
+                    f.get("deadline"), cols, ts, valid)
+            # scatter back (valid rows only), in place on the donated rows
             with named_scope(SCOPE_DENSE_SCATTER):
-                v1 = valid[:, None, None]
-                new_state = {
-                    "active": state["active"].at[part_idx].set(
-                        jnp.where(v1, a, state["active"][part_idx])
-                    ),
-                    "first_ts": state["first_ts"].at[part_idx].set(
-                        jnp.where(v1, first, state["first_ts"][part_idx])
-                    ),
-                    "counts": state["counts"].at[part_idx].set(
-                        jnp.where(v1, counts, state["counts"][part_idx])
-                    ),
-                    "regs": state["regs"].at[part_idx].set(
-                        jnp.where(valid[:, None, None, None], regs,
-                                  state["regs"][part_idx])
-                    ),
-                    "overflow": state["overflow"].at[part_idx].set(
-                        jnp.where(valid, ovf, state["overflow"][part_idx])
-                    ),
-                }
-                if "iregs" in state:
-                    new_state["iregs"] = state["iregs"].at[part_idx].set(
-                        jnp.where(valid[:, None, None, None], iregs,
-                                  state["iregs"][part_idx]))
-                if "deadline" in state:
-                    new_state["deadline"] = state["deadline"].at[
-                        part_idx].set(
-                        jnp.where(v1, dl, state["deadline"][part_idx]))
+                new = {"active": a, "first_ts": first, "counts": counts,
+                       "regs": regs, "iregs": iregs, "deadline": dl}
+                # `ovf` went in as zeros: it is this step's increment
+                new_state = layout.scatter(state, part_idx, new, ovf, valid,
+                                           old)
             # outs is a pytree: float lanes + integer hi/lo pair lanes;
             # n_emit is the count-gate scalar for the async emit
             # pipeline — the host fetches it alone and skips the column
@@ -1298,16 +1266,31 @@ class DensePatternEngine:
         O = max(len(out_spec), 1)
         n_iout = sum(self.out_int)
 
+        layout = self.layout
+        R = layout.fields["regs"][1][2]
+        RI = 2 * self.alloc.n_int
+
+        def nd(x, s, k=None):
+            """Node ``s`` of a flat field [Pr, S*I] or register bank
+            [Pr, S*I*k]: its lanes [Pr, I], or [Pr, I, k] for a bank.
+            Column slices only — the state is never reshaped to
+            [Pr, S, I] (a relayout of every row; ops/dense_layout.py)."""
+            if k is None:
+                return x[:, s * I:(s + 1) * I]
+            return x[:, s * I * k:(s + 1) * I * k].reshape(x.shape[0], I, k)
+
+        def set_nd(x, s, v):
+            w = x.shape[1] // S
+            return x.at[:, s * w:(s + 1) * w].set(v.reshape(x.shape[0], w))
+
         def time_step(state, now):
-            a = state["active"]
-            first = state["first_ts"]
-            counts = state["counts"]
-            regs = state["regs"]
-            iregs = (state["iregs"] if "iregs" in state
-                     else jnp.zeros(a.shape + (0,), dtype=jnp.int32))
-            dl = state["deadline"]
-            ovf = state["overflow"]
+            f = layout.split(state[ROWS], shaped=False)
+            a, first, counts = f["active"], f["first_ts"], f["counts"]
+            regs, dl = f["regs"], f["deadline"]
             Pr = a.shape[0]
+            iregs = (f["iregs"] if "iregs" in f
+                     else jnp.zeros((Pr, 0), dtype=jnp.int32))
+            ovf = state[OVERFLOW]
             emit = jnp.zeros((Pr, I), dtype=bool)
             out_f = jnp.zeros((Pr, I, O), dtype=jnp.float32)
             out_i = jnp.zeros((Pr, I, 2 * n_iout), dtype=jnp.int32)
@@ -1331,8 +1314,8 @@ class DensePatternEngine:
                 if w is None:
                     continue
                 node = nodes[s]
-                due = a[:, s, :] & (dl[:, s, :] > 0) & (now >= dl[:, s, :])
-                ft = dl[:, s, :]  # fire timestamps (valid where due)
+                ft = nd(dl, s)  # fire timestamps (valid where due)
+                due = nd(a, s) & (ft > 0) & (now >= ft)
                 if node.kind == "logical":
                     # complete only if every present side already
                     # matched; either way the deadline is CONSUMED (host
@@ -1340,12 +1323,12 @@ class DensePatternEngine:
                     # match then completes immediately)
                     pmask = sum(1 << i for i, sp in enumerate(node.specs)
                                 if not sp.is_absent)
-                    fire_mask = due & ((counts[:, s, :] & pmask) == pmask)
+                    fire_mask = due & ((nd(counts, s) & pmask) == pmask)
                 else:
                     fire_mask = due
-                dl = dl.at[:, s, :].set(
-                    jnp.where(due, 0, dl[:, s, :]))
-                anchor = jnp.where(first[:, s, :] > 0, first[:, s, :], ft)
+                dl = set_nd(dl, s, jnp.where(due, 0, ft))
+                anchor = jnp.where(nd(first, s) > 0, nd(first, s), ft)
+                regs_s, iregs_s = nd(regs, s, R), nd(iregs, s, RI)
                 if s == S - 1:
                     emit = emit | fire_mask
                     fire = jnp.where(fire_mask, ft, fire)
@@ -1356,46 +1339,52 @@ class DensePatternEngine:
                     for oi, (_name, src) in enumerate(out_spec):
                         if self.out_int[oi]:
                             out_i = out_i.at[:, :, 2 * ii].set(jnp.where(
-                                fire_mask, iregs[:, s, :, 2 * src.index],
+                                fire_mask, iregs_s[:, :, 2 * src.index],
                                 out_i[:, :, 2 * ii]))
                             out_i = out_i.at[:, :, 2 * ii + 1].set(jnp.where(
-                                fire_mask, iregs[:, s, :, 2 * src.index + 1],
+                                fire_mask, iregs_s[:, :, 2 * src.index + 1],
                                 out_i[:, :, 2 * ii + 1]))
                             ii += 1
                         else:
                             out_f = out_f.at[:, :, oi].set(jnp.where(
-                                fire_mask, regs[:, s, :, src.index],
+                                fire_mask, regs_s[:, :, src.index],
                                 out_f[:, :, oi]))
                 else:
-                    w2 = self.deadline_w[s + 1]
+                    t = s + 1
+                    w2 = self.deadline_w[t]
                     entry_dl = (ft + w2) if w2 is not None else None
-                    a, first, counts, regs, iregs, dl, ovf = _rank_place(
-                        jnp, s + 1, fire_mask, anchor,
-                        regs[:, s, :, :], iregs[:, s, :, :], entry_dl,
-                        a, first, counts, regs, iregs, dl, ovf)
-                a = a.at[:, s, :].set(a[:, s, :] & ~fire_mask)
-                counts = counts.at[:, s, :].set(
-                    jnp.where(fire_mask, 0, counts[:, s, :]))
-                first = first.at[:, s, :].set(
-                    jnp.where(fire_mask, 0, first[:, s, :]))
+                    a_t, first_t, counts_t, regs_t, iregs_t, dl_t, ovf = (
+                        _rank_place(
+                            jnp, fire_mask, anchor, regs_s, iregs_s,
+                            entry_dl, nd(a, t), nd(first, t), nd(counts, t),
+                            nd(regs, t, R), nd(iregs, t, RI), nd(dl, t),
+                            ovf))
+                    a = set_nd(a, t, a_t)
+                    first = set_nd(first, t, first_t)
+                    counts = set_nd(counts, t, counts_t)
+                    regs = set_nd(regs, t, regs_t)
+                    if RI:
+                        iregs = set_nd(iregs, t, iregs_t)
+                    dl = set_nd(dl, t, dl_t)
+                a = set_nd(a, s, nd(a, s) & ~fire_mask)
+                counts = set_nd(counts, s,
+                                jnp.where(fire_mask, 0, nd(counts, s)))
+                first = set_nd(first, s,
+                               jnp.where(fire_mask, 0, nd(first, s)))
 
             if reset_on_emit:
-                any_emit = jnp.any(emit, axis=1)
-                a = jnp.where(any_emit[:, None, None], False, a)
-                counts = jnp.where(any_emit[:, None, None], 0, counts)
-                first = jnp.where(any_emit[:, None, None], 0, first)
-                dl = jnp.where(any_emit[:, None, None], 0, dl)
+                any_emit = jnp.any(emit, axis=1)[:, None]
+                a = jnp.where(any_emit, False, a)
+                counts = jnp.where(any_emit, 0, counts)
+                first = jnp.where(any_emit, 0, first)
+                dl = jnp.where(any_emit, 0, dl)
 
             new_state = {
-                "active": a,
-                "first_ts": first,
-                "counts": counts,
-                "regs": regs,
-                "overflow": ovf,
-                "deadline": dl,
+                ROWS: layout.join({
+                    "active": a, "first_ts": first, "counts": counts,
+                    "regs": regs, "iregs": iregs, "deadline": dl}),
+                OVERFLOW: ovf,
             }
-            if "iregs" in state:
-                new_state["iregs"] = iregs
             n_emit = jnp.sum(emit.astype(jnp.int32))
             return new_state, emit, {"f": out_f, "i": out_i}, fire, n_emit
 
@@ -1411,9 +1400,16 @@ class DensePatternEngine:
             return None
         if not hasattr(self, "_wakeup_fn"):
             jnp = self.jnp
-            self._wakeup_fn = self.jax.jit(lambda a, dl: jnp.min(
-                jnp.where(a & (dl > 0), dl, jnp.int32(2**31 - 1))))
-        m = int(self._wakeup_fn(state["active"], state["deadline"]))
+            layout = self.layout
+
+            def earliest(rows):
+                f = layout.split(rows, shaped=False)
+                dl = f["deadline"]
+                return jnp.min(jnp.where(f["active"] & (dl > 0), dl,
+                                         jnp.int32(2**31 - 1)))
+
+            self._wakeup_fn = self.jax.jit(earliest)
+        m = int(self._wakeup_fn(state[ROWS]))
         if m >= 2**31 - 1:
             return None
         return self.base_ts + m
@@ -1480,23 +1476,9 @@ class DensePatternEngine:
                 "horizon exceeds the int32 relative-time range")
         self.base_ts += delta
         rel64 = rel64 - delta
-        first = np.asarray(state["first_ts"]).astype(np.int64)  # [P, S, I]
-        shifted = np.where(first > 0, first - delta, 0)
-        if self.within_ms is not None:
-            # anchors at/below the new zero were expired before the shift
-            dead = (first > 0) & (shifted <= 0)
-            active = np.asarray(state["active"]).copy()
-            counts = np.asarray(state["counts"]).copy()
-            if dead.any():
-                active[dead] = False
-                counts[dead] = 0
-                shifted = np.where(dead, 0, shifted)
-        else:
-            # no within: anchors are semantically inert, clamp to stay
-            # "set" (>0) without wrapping
-            active = np.asarray(state["active"])
-            counts = np.asarray(state["counts"])
-            shifted = np.where(first > 0, np.maximum(shifted, 1), 0)
+        # host round trip in the logical form; shift_row_ts holds the
+        # one definition of what a base shift does to anchors/deadlines
+        logical = self.shift_row_ts(self.layout.unpack(state), delta)
         if to_device is not None:
             conv = to_device
         elif self.mesh is not None:
@@ -1511,16 +1493,7 @@ class DensePatternEngine:
         else:
             conv = lambda _k, v: self.jnp.asarray(v)
         state = dict(state)
-        state["first_ts"] = conv("first_ts", shifted.astype(np.int32))
-        state["active"] = conv("active", active)
-        state["counts"] = conv("counts", counts)
-        if "deadline" in state:
-            # armed deadlines shift with the base; one already at/below
-            # the new zero clamps to 1 (long overdue — fires on the next
-            # tick, which is where the un-shifted value pointed too)
-            dlv = np.asarray(state["deadline"]).astype(np.int64)
-            dshift = np.where(dlv > 0, np.maximum(dlv - delta, 1), 0)
-            state["deadline"] = conv("deadline", dshift.astype(np.int32))
+        state[ROWS] = conv(ROWS, self.layout.pack(logical)[ROWS])
         return state, rel64
 
     def shift_row_ts(self, rows: Dict[str, np.ndarray],
@@ -1532,8 +1505,8 @@ class DensePatternEngine:
         tenants: restoring a tenant snapshot taken under a different
         anchor, or admitting a tenant whose events predate the group
         anchor (a group-wide down-shift, delta < 0), rewrites the
-        ``first_ts``/``deadline`` anchors with the same semantics as
-        :meth:`maybe_re_anchor` — forward shifts expire instances that
+        ``first_ts``/``deadline`` anchors (:meth:`maybe_re_anchor` is
+        built on this) — forward shifts expire instances that
         fall out of the ``within`` horizon (or clamp inert anchors to
         stay set), backward shifts only grow the values, bounded by the
         int32 range.  ``rows`` must already be HOST numpy arrays (both

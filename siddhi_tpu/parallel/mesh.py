@@ -215,20 +215,14 @@ class ShardedPatternEngine:
         ``parts_per_shard`` partition rows plus one trailing scratch
         row (same per-row init values as the unsharded engine).
 
-        Built from the engine's NUMPY init (init_state_host) — calling
+        Built from the engine's NUMPY init (its state layout) — calling
         the device init here would allocate on the default backend,
         which may be a TPU the caller never intends to touch (the
         round-2 dryrun crash)."""
-        host = self.engine.init_state_host()
-        n_rows = self.n_shards * self.rows_per_shard
-        state = {}
-        for k, v in host.items():
-            arr = np.zeros((n_rows,) + v.shape[1:], dtype=v.dtype)
-            # replicate the engine's per-row init (row 0 of the host
-            # state — all rows are initialized identically)
-            arr[...] = v[0]
-            state[k] = self._put(arr, self.state_specs[k])
-        return state
+        host = self.engine.layout.init_physical(
+            self.n_shards * self.rows_per_shard)
+        return {k: self._put(v, self.state_specs[k])
+                for k, v in host.items()}
 
     # -- stepping ------------------------------------------------------------
 

@@ -23,7 +23,7 @@ def test_entry_compiles_and_steps():
 
     fn, args = entry()
     out_state, emit, out_vals, emit_anchor, n_emit = jax.jit(fn)(*args)
-    assert set(out_state) == {"active", "first_ts", "counts", "regs", "overflow"}
+    assert set(out_state) == {"rows", "overflow"}
     assert np.asarray(emit).dtype == bool
     # async emit pipeline: the step returns a scalar match count so the
     # host can skip all column transfers on zero-match batches
@@ -48,7 +48,7 @@ def test_sharded_engine_init_is_host_only(monkeypatch):
     mesh = make_mesh(8)
     sharded = ShardedPatternEngine(eng, mesh)
     state = sharded.init_state()
-    assert state["active"].shape[0] == 8 * (64 + 1)
+    assert state["rows"].shape[0] == 8 * (64 + 1)
 
 
 def test_chip_smoke_refuses_without_a_tpu(capsys):

@@ -128,14 +128,14 @@ class TestShardedEngine:
             sharded, [3 * 64 + 7],
             [150.0, 160.0, 170.0, 180.0])
         assert total == 1
-        active = np.asarray(state["active"])
+        active = sharded.engine.layout.field(state, "active")
         # scratch rows and every partition row are clear after emission
         assert not active.any()
 
     def test_state_sharding_placement(self, sharded):
         state = sharded.init_state()
-        assert len(state["active"].sharding.device_set) == 8
-        assert state["active"].shape[0] == 8 * 65  # 64 partitions + scratch
+        assert len(state["rows"].sharding.device_set) == 8
+        assert state["rows"].shape[0] == 8 * 65  # 64 partitions + scratch
 
 
 class TestMeshNeverBorrowsDevices:
