@@ -90,6 +90,8 @@ ALLOWLISTS = {
             "ingest: converts HOST batch cols before staged_put",
         f"{_DN}:DensePatternEngine.process_deferred":
             "ingest: converts HOST batch inputs before staged_put",
+        f"{_DN}:round_plan":
+            "ingest: host-side round plan of the batch's HOST partition ids",
         f"{_DN}:DensePatternEngine.on_time_state":
             "barrier: deadline-timer step, behind drain()",
         f"{_DL}:DenseStateLayout.encode":

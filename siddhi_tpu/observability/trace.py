@@ -64,22 +64,25 @@ from .recorder import FlightRecorder
 STAGE_INTERN = "intern"      # keys interned
 STAGE_INGEST = "ingest"      # events
 STAGE_CONVERT = "convert"    # events
+STAGE_PLAN = "plan"          # rounds in the batch (its longest run of one key)
 STAGE_ROUTE = "route"        # events
 STAGE_PUT = "put"            # bytes handed to device_put
-STAGE_DISPATCH = "dispatch"  # rounds (1 per call of the jitted step)
+STAGE_DISPATCH = "dispatch"  # 1 per call of a jitted step
 STAGE_STEP = "step"          # events
 STAGE_EMIT = "emit"          # rows
 STAGE_FETCH = "fetch"        # bytes fetched
 STAGE_DELIVER = "deliver"    # rows delivered
-CYCLE_STAGES = (STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT, STAGE_ROUTE,
-                STAGE_PUT, STAGE_DISPATCH, STAGE_STEP, STAGE_EMIT,
-                STAGE_FETCH, STAGE_DELIVER)
+CYCLE_STAGES = (STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT, STAGE_PLAN,
+                STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH, STAGE_STEP,
+                STAGE_EMIT, STAGE_FETCH, STAGE_DELIVER)
 #: what a further collision round (or device chunk) of one batch repeats
 ROUND_STAGES = (STAGE_CONVERT, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH)
 #: most spans one batch cycle records on a served path: every stage
 #: once, the host preparation ahead of the rounds in two more pieces
 #: (the runtime's column views, the engine's lane conversion), and a
-#: second collision round.  The tracer sizes the recorder's ring from it.
+#: second collision round.  The tracer sizes the recorder's ring from it;
+#: a skewed batch of a thousand rounds outgrows it, and the ring then
+#: keeps the newest spans.
 SPANS_PER_CYCLE = len(CYCLE_STAGES) + 2 + len(ROUND_STAGES)
 #: checkpoint-path stages (free-running, engine kind 'persist')
 STAGE_PERSIST_CAPTURE = "persist.capture"

@@ -67,7 +67,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -82,6 +82,7 @@ from siddhi_tpu.observability.trace import (
     SCOPE_DENSE_SCATTER,
     STAGE_CONVERT,
     STAGE_DISPATCH,
+    STAGE_PLAN,
     span,
 )
 from siddhi_tpu.ops.dense_layout import OVERFLOW, ROWS, DenseStateLayout
@@ -1589,9 +1590,13 @@ class DensePatternEngine:
             state, rel64 = self.maybe_re_anchor(state, rel64)
             rel = rel64.astype(np.int32)
             prepared = self.prepare_cols(stream_key, cols)
-            rounds = _collision_rounds(part_idx)
+        with span(STAGE_PLAN) as sp:
+            plan = round_plan(part_idx)
+            if sp is not None:
+                sp.count = plan.n_rounds
         pending = DeferredDenseEmit(self)
-        for ridx in rounds:
+        for r in range(plan.n_rounds):
+            ridx = plan.round(r)
             b = len(ridx)
             with span(STAGE_CONVERT, b):
                 bp = max(1 << (b - 1).bit_length(), 16)  # pad to pow2, min 16
@@ -1813,20 +1818,70 @@ def flatten_match_parts(ev_parts, out_parts, key_parts, n_out: int
     return ev[order].astype(np.int64), out[order]
 
 
-def _collision_rounds(part_idx: np.ndarray) -> List[np.ndarray]:
-    """Split indices into rounds where each partition appears at most once,
-    preserving per-partition order."""
-    order = np.argsort(part_idx, kind="stable")
-    sorted_parts = part_idx[order]
-    # occurrence number of each element within its partition group
-    is_new = np.ones(len(part_idx), dtype=bool)
+class RoundPlan(NamedTuple):
+    """Order in which a batch's events reach the device: ``lanes`` holds
+    the batch-row index of every event, ``off[r]:off[r + 1]`` are the
+    lanes of round ``r``."""
+
+    lanes: np.ndarray  # int64 [n]
+    off: np.ndarray    # int64 [n_rounds + 1]
+
+    @property
+    def n_rounds(self) -> int:
+        """The longest run of one partition in the batch."""
+        return len(self.off) - 1
+
+    def round(self, r: int) -> np.ndarray:
+        return self.lanes[self.off[r]:self.off[r + 1]]
+
+
+def round_plan(part_idx: np.ndarray) -> RoundPlan:
+    """Split a batch into rounds in which each partition appears at
+    most once, preserving per-partition order: round ``r`` holds every
+    partition's ``r``-th event of the batch.
+
+    Within a round the partitions stand in one fixed order: those with
+    more events in the batch first, ties by first arrival, and the
+    partitions that appear once behind them in arrival order.  So the
+    partitions of round ``r`` are a prefix of those of round ``r - 1``
+    and position ``j`` of every round is the same partition: a device
+    program that runs the rounds itself can keep the last rounds' few
+    rows resident (ROADMAP.md Speed 10; today every round is stepped
+    from the host and only needs its partitions to be distinct).
+
+    One sort of the batch and one of the repeated events; no pass over
+    the batch per round."""
+    n = len(part_idx)
+    if n == 0:
+        return RoundPlan(np.empty(0, dtype=np.int64),
+                         np.zeros(1, dtype=np.int64))
+    # (partition, arrival) packed into one word: the keys are distinct,
+    # so the plain sort, several times faster than a stable one, orders
+    # each partition's events by arrival
+    key = (part_idx.astype(np.int64) << 32) | np.arange(n, dtype=np.int64)
+    key.sort()
+    order = key & 0xFFFFFFFF
+    sorted_parts = key >> 32
+    is_new = np.ones(n, dtype=bool)
     is_new[1:] = sorted_parts[1:] != sorted_parts[:-1]
-    group_start = np.maximum.accumulate(np.where(is_new, np.arange(len(part_idx)), 0))
-    occ = np.arange(len(part_idx)) - group_start
-    occ_orig = np.empty(len(part_idx), dtype=np.int64)
-    occ_orig[order] = occ
-    n_rounds = int(occ.max()) + 1 if len(occ) else 0
-    return [np.flatnonzero(occ_orig == r) for r in range(n_rounds)]
+    starts = np.flatnonzero(is_new)            # of each partition's group
+    if len(starts) == n:                       # no partition repeats
+        return RoundPlan(np.arange(n, dtype=np.int64),
+                         np.asarray([0, n], dtype=np.int64))
+    cnt = np.diff(starts, append=n)            # events per group
+    group = np.cumsum(is_new) - 1              # group of each sorted event
+    pos = np.flatnonzero(cnt[group] > 1)       # sorted events that repeat
+    g = group[pos]
+    occ = pos - starts[g]                      # occurrence within the group
+    # a group's first arrival is its first sorted event
+    ranked = order[pos[np.lexsort((order[starts[g]], -cnt[g], occ))]]
+    widths = np.bincount(occ)
+    repeated = np.zeros(n, dtype=bool)
+    repeated[ranked] = True
+    once = np.flatnonzero(~repeated)           # arrival order
+    lanes = np.concatenate([ranked[:widths[0]], once, ranked[widths[0]:]])
+    widths[0] += len(once)
+    return RoundPlan(lanes, np.concatenate([[0], np.cumsum(widths)]))
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ from siddhi_tpu.observability.trace import (
     SCOPE_SHARD_COUNT_PSUM,
     STAGE_CONVERT,
     STAGE_DISPATCH,
+    STAGE_PLAN,
     STAGE_PUT,
     STAGE_ROUTE,
     span,
@@ -290,10 +291,7 @@ class ShardedPatternEngine:
         ``pending.resolve()`` — the ingest stage (core/ingest_stage.py)
         defers that fetch past the next batch's dispatch.  Returns
         ``(state, pending_or_None)``."""
-        from siddhi_tpu.ops.dense_nfa import (
-            DeferredDenseEmit,
-            _collision_rounds,
-        )
+        from siddhi_tpu.ops.dense_nfa import DeferredDenseEmit, round_plan
 
         with span(STAGE_CONVERT, len(part)):
             part = np.asarray(part)
@@ -303,12 +301,16 @@ class ShardedPatternEngine:
                 to_device=lambda k, v: self._put(v, self.state_specs[k]))
             rel = rel64.astype(np.int32)
             prepared = self.engine.prepare_cols(self.stream_key, cols)
-            rounds = _collision_rounds(part)
+        with span(STAGE_PLAN) as sp:
+            plan = round_plan(part)
+            if sp is not None:
+                sp.count = plan.n_rounds
         pending = DeferredDenseEmit(self.engine)
         faults = getattr(self.engine, "faults", None)
         if faults is not None:
             faults.check("step.shard")
-        for ridx in rounds:
+        for r in range(plan.n_rounds):
+            ridx = plan.round(r)
             with span(STAGE_CONVERT, len(ridx)):
                 round_part = part[ridx]
                 round_cols = {k: v[ridx] for k, v in prepared.items()}

@@ -1,0 +1,333 @@
+"""Skewed batches through the dense engine: one key many times in one
+batch.  ``round_plan`` splits the events into rounds in which a key
+appears once, and the engines step round after round.  Whatever the
+skew, the result must be what the same events
+give one at a time: state and emissions exact, for every engine kind of
+``dense_layout_cases.ENGINES``, on one device and over the 4-device CPU
+mesh; and through ``SiddhiManager`` what the host engine (``ops/nfa.py``)
+and a plain chain automaton give."""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+from dense_layout_cases import ENGINES, MESHES, STREAMS, logical
+
+from siddhi_tpu import SiddhiManager
+from siddhi_tpu.core.event import EventBatch
+from siddhi_tpu.ops.dense_nfa import compile_pattern, round_plan
+
+P = 24
+
+
+# -- the plan -----------------------------------------------------------------
+
+def _skewed(rng, runs, n_once, n_keys=10_000):
+    """A shuffled batch in which key ``i`` appears ``runs[i]`` times and
+    ``n_once`` further keys once each."""
+    keys = rng.choice(n_keys, size=len(runs) + n_once, replace=False)
+    part = np.concatenate([np.repeat(keys[:len(runs)], runs),
+                           keys[len(runs):]])
+    rng.shuffle(part)
+    return part.astype(np.int32)
+
+
+PLAN_CASES = {
+    "empty": ([], 0),
+    "runs_of_1": ([], 40),
+    "run_of_2": ([2], 30),
+    "run_of_17": ([17], 30),
+    "run_of_1000": ([1000], 100),
+    "one_key_only": ([64], 0),
+    "several_hot_keys": ([300, 300, 120, 17, 5, 2, 2, 2], 200),
+    "equal_runs": ([4, 4, 4, 4], 0),
+    # tests/test_dense_nfa.py::test_batch_collision_rounds and
+    # tests/test_parallel.py::test_collision_rounds_same_partition drive
+    # these two shapes through the engines
+    "five_of_one_partition": ([5], 0),
+    "four_of_one_partition": ([4], 0),
+}
+
+
+@pytest.mark.parametrize("case", list(PLAN_CASES))
+def test_round_plan(case):
+    runs, n_once = PLAN_CASES[case]
+    part = _skewed(np.random.default_rng(len(case)), runs, n_once)
+    plan = round_plan(part)
+    n = len(part)
+    assert sorted(plan.lanes.tolist()) == list(range(n))
+    assert plan.off[0] == 0 and plan.off[-1] == n
+    assert plan.n_rounds == (max(runs + [1]) if n else 0)
+    widths = np.diff(plan.off)
+    assert (widths > 0).all() and (np.diff(widths) <= 0).all()
+    seen = {}
+    for r in range(plan.n_rounds):
+        ev = plan.round(r)
+        keys = part[ev]
+        assert len(set(keys.tolist())) == len(keys)   # once a round
+        if r:
+            # the partitions of a round are a prefix of the one before
+            assert (keys == part[plan.round(r - 1)][:len(keys)]).all()
+        for k, e in zip(keys.tolist(), ev.tolist()):
+            assert seen.get(k, -1) < e                # arrival order kept
+            seen[k] = e
+    if plan.n_rounds:
+        # more events first, ties by first arrival, then the keys that
+        # come once in arrival order
+        first = part[plan.round(0)]
+        count = {k: int((part == k).sum()) for k in first.tolist()}
+        arrival = {k: int(np.flatnonzero(part == k)[0])
+                   for k in first.tolist()}
+        assert first.tolist() == sorted(
+            first.tolist(),
+            key=lambda k: (-count[k], arrival[k]) if count[k] > 1
+            else (0, arrival[k]))
+
+
+# -- every engine kind: a skewed batch against one event at a time ------------
+
+def _events(rng, scenario, n_part=P):
+    """``(part, cols, ts)`` of one skewed batch."""
+    # every scenario's later rounds pad to 64 lanes: one program each
+    if scenario == "runs_1_2_17":
+        runs, n_once, span = [17, 17, 2, 2], 8, 600
+    elif scenario == "several_hot_keys":
+        runs, n_once, span = [24, 24, 9, 3, 2], 7, 1_500
+    elif scenario == "hot_key_meets_padded_row":
+        # 9 + 39 events: round 0 and the rest are both padded, and the
+        # hot key's partition is the last one, next to the scratch row
+        runs, n_once, span = [40], 8, 400
+    elif scenario == "run_crosses_within":
+        runs, n_once, span = [60, 5], 4, 9_000   # within is 2 sec
+    elif scenario == "run_of_1000":
+        runs, n_once, span = [1000, 30, 2], 12, 1_900
+    else:
+        raise KeyError(scenario)
+    keys = rng.choice(n_part - 1, size=len(runs) + n_once, replace=False)
+    if scenario == "hot_key_meets_padded_row":
+        keys[0] = n_part - 1
+    part = np.concatenate([np.repeat(keys[:len(runs)], runs),
+                           keys[len(runs):]]).astype(np.int64)
+    rng.shuffle(part)
+    n = len(part)
+    # mostly rising within a key, with some falls: chains advance, some
+    # lanes fill up and overflow
+    occ = np.zeros(n, dtype=np.int64)
+    seen = {}
+    for i, k in enumerate(part.tolist()):
+        occ[i] = seen.get(k, 0)
+        seen[k] = occ[i] + 1
+    v = 1.5 + (occ % 7) + rng.integers(0, 3, size=n)
+    cols = {"k": part.copy(), "v": v.astype(np.float64),
+            "n": (occ % 5 + rng.integers(0, 2, size=n)).astype(np.int64)
+            * (2 ** 31 + 7)}
+    ts = 1_000_000 + np.sort(rng.integers(0, span, size=n)).astype(np.int64)
+    return part, cols, ts
+
+
+def _engine(eng_name, n_dev):
+    import jax
+
+    query, inst, streams = ENGINES[eng_name]
+    eng = compile_pattern(STREAMS + query, "q", n_partitions=P,
+                          n_instances=inst)
+    if n_dev == 1:
+        return eng, eng.init_state(), lambda st, sk, *a: eng.process(
+            st, sk, *a)
+    from siddhi_tpu.parallel.mesh import ShardedPatternEngine, make_mesh
+
+    mesh = make_mesh(n_dev, devices=jax.devices("cpu")[:n_dev])
+    sharded = {sk: ShardedPatternEngine(eng, mesh, stream_key=sk)
+               for sk in streams}
+    return eng, sharded[streams[0]].init_state(), (
+        lambda st, sk, *a: sharded[sk].process(st, *a)[:3])
+
+
+SCENARIOS = ["runs_1_2_17", "several_hot_keys", "hot_key_meets_padded_row",
+             "run_crosses_within"]
+
+
+def check_against_one_event_at_a_time(eng_name, n_dev, what):
+    streams = ENGINES[eng_name][2]
+    scenarios = SCENARIOS if what == "skewed" else [what]
+    got, want = [], []
+    states = []
+    for sink, whole in ((got, True), (want, False)):
+        eng, state, process = _engine(eng_name, n_dev)
+        rng = np.random.default_rng(              # the same events twice
+            [ord(c) for c in eng_name])
+        # one batch after another: each meets the state the last left
+        for b, scenario in enumerate(scenarios):
+            sk = streams[b % len(streams)]
+            part, cols, ts = _events(rng, scenario)
+            ts = ts + 700 * b
+            pieces = ([np.arange(len(part))] if whole
+                      else [np.asarray([i]) for i in range(len(part))])
+            for ev in pieces:
+                state, idx, out = process(
+                    state, sk, part[ev], {k: c[ev] for k, c in cols.items()},
+                    ts[ev])
+                sink.extend(
+                    (b, int(ev[i]), tuple(np.asarray(o, dtype=np.float64)))
+                    for i, o in zip(np.asarray(idx), np.asarray(out)))
+        states.append(logical(eng, state))
+    # same-event matches come ordered by arming age on both paths
+    assert got == want
+    for field, value in states[1].items():
+        assert np.array_equal(states[0][field], value), field
+    if eng_name == "every_r2":
+        # lanes do run out here, and are counted as they always were
+        assert got and states[0]["overflow"].sum() > 0
+
+
+# the run of 1,000 is in test_dense_skew_long.py, a file of its own so
+# that another worker takes it
+@pytest.mark.parametrize("eng_name,n_dev",
+                         [(e, d) for e in ENGINES for d in MESHES])
+def test_skewed_batch_equals_one_event_at_a_time(eng_name, n_dev):
+    check_against_one_event_at_a_time(eng_name, n_dev, "skewed")
+
+
+def test_one_fetch_of_the_count_gates_a_batch(monkeypatch):
+    """A batch with a run of 24 is 24 rounds: a put and a dispatch a
+    round (ROADMAP.md Speed 10 moves the rounds on to the device), and
+    however many rounds, one fetch of all their count gates."""
+    import jax
+
+    eng = compile_pattern(STREAMS + ENGINES["every_r2"][0], "q",
+                          n_partitions=P, n_instances=4)
+    state = eng.init_state()
+    part, cols, ts = _events(np.random.default_rng(5), "several_hot_keys")
+    state, pending = eng.process_deferred(state, "S", part, cols, ts)
+    pending.resolve()       # compiled; now count
+    calls = {"put": 0, "get": 0}
+    real_put, real_get = jax.device_put, jax.device_get
+
+    def put(*a, **kw):
+        calls["put"] += 1
+        return real_put(*a, **kw)
+
+    def get(*a, **kw):
+        calls["get"] += 1
+        return real_get(*a, **kw)
+
+    monkeypatch.setattr(jax, "device_put", put)
+    monkeypatch.setattr(jax, "device_get", get)
+    rounds = round_plan(part).n_rounds
+    assert rounds == 24
+    state, pending = eng.process_deferred(state, "S", part, cols, ts + 5_000)
+    assert calls == {"put": rounds, "get": 0}
+    assert len(pending.chunks) == rounds
+    assert pending.resolve() > 0
+    assert calls == {"put": rounds, "get": 1}
+
+
+# -- through SiddhiManager: the host engine and a plain automaton -------------
+
+CHAIN = (
+    "define stream Txn (key long, v double); "
+    "partition with (key of Txn) begin @info(name='bench') "
+    "from every e1=Txn[v > 0.0] -> e2=Txn[v > 1.0 and v > e1.v] -> "
+    "e3=Txn[v > 2.0 and v > e1.v] -> e4=Txn[v > 3.0 and v > e1.v] "
+    "within 2 sec select e1.v as v1, e4.v as v4 insert into Alerts; end;")
+
+
+def _chain_rows(events, states, within_ms):
+    """``every e1=[v>0] -> e2=[v>1 and v>e1.v] -> ...`` over one key's
+    ``(ts, v)`` events: a match is ``(ts, e1.v, e<states>.v)``."""
+    rows, pending = [], []
+    for ts, v in events:
+        nxt = []
+        for v1, t1, k in pending:
+            if ts - t1 > within_ms:
+                continue
+            if v > k and v > v1:
+                if k + 1 == states:
+                    rows.append((ts, v1, v))
+                    continue
+                k += 1
+            nxt.append((v1, t1, k))
+        if v > 0.0:
+            nxt.append((v, ts, 1))
+        pending = nxt
+    return rows
+
+
+def _zipf_batches(rng, n_keys, batch, n_batches):
+    """Keys Zipf(0.99) over ``n_keys``, values rising one step an event
+    within a key (no lane overflows), a millisecond an event."""
+    w = np.arange(1, n_keys + 1, dtype=np.float64) ** -0.99
+    ids = rng.permutation(n_keys)[rng.choice(
+        n_keys, size=batch * n_batches, p=w / w.sum())]
+    occ = np.zeros(len(ids), dtype=np.int64)
+    seen = {}
+    for i, k in enumerate(ids.tolist()):
+        occ[i] = seen.get(k, 0)
+        seen[k] = occ[i] + 1
+    ts = 1_000 + np.arange(len(ids), dtype=np.int64)
+    return [EventBatch("Txn", ["key", "v"],
+                       {"key": ids[s:s + batch].astype(np.int64) * 7 + 3,
+                        "v": occ[s:s + batch] + 0.5}, ts[s:s + batch])
+            for s in range(0, len(ids), batch)]
+
+
+def _run_app(header, batches, one_by_one=False):
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(header + CHAIN)
+        got = []
+        rt.add_callback("Alerts", lambda evs: got.extend(
+            (e.timestamp, float(e.data[0]), float(e.data[1])) for e in evs))
+        rt.start()
+        h = rt.get_input_handler("Txn")
+        for b in batches:
+            if one_by_one:
+                for i in range(len(b)):
+                    h.send([int(b.columns["key"][i]),
+                            float(b.columns["v"][i])],
+                           timestamp=int(b.timestamps[i]))
+            else:
+                h.send_batch(b)
+        lowering = rt.lowering() if not one_by_one else None
+        overflow = sum(
+            q.pattern_processor.overflow_total()
+            for pr in rt.partitions.values()
+            for q in getattr(pr, "dense_query_runtimes", {}).values())
+        rt.shutdown()
+        return got, lowering, overflow
+    finally:
+        m.shutdown()
+
+
+@pytest.mark.parametrize("devices", MESHES)
+def test_zipf_keys_through_the_manager(devices):
+    """The benchmark's skew at a small size: the dense path (one device,
+    and sharded over four) delivers the rows of the host engine and of a
+    plain chain automaton, in each key's event-time order."""
+    batches = _zipf_batches(np.random.default_rng(28), 256, 512, 3)
+    assert max(round_plan(b.columns["key"]).n_rounds for b in batches) > 30
+    opts = "partitions='256'" + (f", devices='{devices}'"
+                                 if devices > 1 else "")
+    got, lowering, overflow = _run_app(
+        f"@app:playback @app:execution('tpu', {opts}) ", batches)
+    assert lowering == {"bench": "dense"} and overflow == 0
+    by_key = {}
+    for b in batches:
+        for k, v, ts in zip(b.columns["key"].tolist(),
+                            b.columns["v"].tolist(), b.timestamps.tolist()):
+            by_key.setdefault(k, []).append((ts, v))
+    plain = sorted(r for evs in by_key.values()
+                   for r in _chain_rows(evs, 4, 2_000))
+    assert len(plain) > 500
+    assert sorted(got) == plain
+    # one key's rows arrive in event-time order
+    key_at = {ts: k for b in batches for k, ts in zip(
+        b.columns["key"].tolist(), b.timestamps.tolist())}
+    last = {}
+    for ts, _v1, _v4 in got:
+        assert last.get(key_at[ts], -1) <= ts
+        last[key_at[ts]] = ts
+    if devices == 1:
+        host, _l, _o = _run_app("@app:playback ", batches, one_by_one=True)
+        assert sorted(host) == plain

@@ -381,9 +381,10 @@ def window_batch(i, n=32):
 # the least number of each, engine kind)
 SERVED_PATHS = {
     "dense": ("partitions='64'", PARTITIONED_BODY, keyed_batch,
-              {"intern": 1, "convert": 4, "put": 2, "dispatch": 2}, "dense"),
+              {"intern": 1, "convert": 4, "plan": 1, "put": 2, "dispatch": 2},
+              "dense"),
     "shard": ("partitions='64', devices='4'", PARTITIONED_BODY, keyed_batch,
-              {"intern": 1, "convert": 4, "route": 2, "put": 2,
+              {"intern": 1, "convert": 4, "plan": 1, "route": 2, "put": 2,
                "dispatch": 2}, "shard"),
     # a chunk is three spans (convert, put, dispatch) and, only where
     # keys are interned, a fourth; the runtime's column views are one
@@ -459,6 +460,8 @@ def test_spans_tile_send_batch(path, monkeypatch):
                 assert len(by[stage]) >= least, (stage, len(by[stage]))
             for stage in EVERY_CYCLE:
                 assert len(by[stage]) == 1, stage
+            if "plan" in by:
+                assert [s[5] for s in by["plan"]] == [2]  # rounds
             ingest, step, emit = by["ingest"][0], by["step"][0], by["emit"][0]
             # ingest, step and emit start and end where they always did:
             # ingest closes on the dispatch, step runs from there to the
@@ -467,7 +470,8 @@ def test_spans_tile_send_batch(path, monkeypatch):
             assert by["fetch"][0][3] == emit[3]
             assert emit[3] <= by["fetch"][0][4] <= by["deliver"][0][3]
             assert by["deliver"][0][4] <= emit[4]
-            inside = [s for st in ("convert", "route", "put", "dispatch")
+            inside = [s for st in ("convert", "plan", "route", "put",
+                                   "dispatch")
                       for s in by.get(st, [])]
             if kind == "device":   # interning lies inside ingest there
                 inside += by.get("intern", [])
