@@ -205,6 +205,10 @@ ALLOWLISTS = {
             "smoke_compile() jits once per app creation to compile the "
             "fused chain kernel before committing to it — plan time, "
             "never on the batch path",
+        f"{_DN}:DensePatternEngine._make_run_kernel":
+            "smoke_compile() jits once per engine and stream to put the "
+            "run kernel through Mosaic before make_rounds commits to "
+            "it; make_rounds memoizes the program in _step_cache",
     },
     "fallback-discipline": {
         "siddhi_tpu/planner/monitor.py:PlanMonitor.decide":

@@ -687,7 +687,11 @@ class DensePatternRuntime:
             ts[ev_idx], np.full(len(ev_idx), ev.CURRENT, dtype=np.int8),
         )
         if keys is not None:
-            mb.aux["partition_keys"] = [keys[int(i)] for i in ev_idx]
+            # one fancy index, not a Python loop: a skewed batch owes
+            # thousands of rows
+            mb.aux["partition_keys"] = (
+                keys[ev_idx].tolist() if isinstance(keys, np.ndarray)
+                else [keys[int(i)] for i in ev_idx])
         # original-batch positions of the completing events: the hot-key
         # router splits each cycle into cold/hot sub-batches, and
         # consumers that need the interleaved order re-sort on these

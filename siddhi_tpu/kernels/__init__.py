@@ -13,6 +13,14 @@ same way the shard/multiplex/fuse/hotkey paths are:
   matrix chain + counting chain, replacing the two-pass
   ``associative_scan``.
 
+A fourth kernel is behind no annotation:
+
+- ``dense_run`` — the *run* of a skewed batch (one key hundreds of
+  times): the dependence chain of ``DensePatternEngine.make_rounds`` in
+  one kernel, its rows resident in VMEM from the first link to the
+  last.  The engine takes it wherever its class is eligible and the
+  kernel compiles, and keeps the XLA loop elsewhere.
+
 Kernels compile through Mosaic on TPU and run under ``interpret=True``
 everywhere else (``probe.interpret_mode()``).  Block shapes never depend
 on the batch, so ``planner/kernels.py`` compiles each kernel once at app

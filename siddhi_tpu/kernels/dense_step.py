@@ -47,11 +47,13 @@ LANES = 128
 BLOCK_ROWS = SUBLANES * LANES
 
 
-def build_plane_nfa(engine, stream_key: str, jit: bool = True):
-    """Kernel-backed replacement for ``DensePatternEngine.make_step``.
-
-    Same signature and same returns as the XLA step; only callable for
-    engines that passed ``check_dense_kernel_eligible``.
+def build_plane_advance(engine, stream_key: str):
+    """Kernel-backed replacement for the automaton of
+    ``DensePatternEngine.make_advance``: same signature and same
+    returns, on the logical fields of B gathered rows (``make_step``
+    gathers and scatters around it, ``make_rounds`` loops over it).
+    Only callable for engines that passed
+    ``check_dense_kernel_eligible``.
     """
     jax, jnp = engine.jax, engine.jnp
     from jax.experimental import pallas as pl
@@ -186,8 +188,8 @@ def build_plane_nfa(engine, stream_key: str, jit: bool = True):
         env[N_KEY] = ts.shape[0]
         return env
 
-    def step(state, part_idx, cols, ts, valid):
-        B = part_idx.shape[0]
+    def advance(fields, cols, ts, valid):
+        B = ts.shape[0]
         Bp = -(-B // BLOCK_ROWS) * BLOCK_ROWS
         G = Bp // LANES
         pad = Bp - B
@@ -209,7 +211,6 @@ def build_plane_nfa(engine, stream_key: str, jit: bool = True):
                 ok_rows.append(okb & valid)
         ok_mat = jnp.stack(ok_rows, axis=0)  # [S, B]
 
-        fields, old_rows = engine.layout.gather(state, part_idx)
         a = fields["active"]        # [B, S, I]
         first = fields["first_ts"]  # [B, S, I]
         if pad:
@@ -238,16 +239,14 @@ def build_plane_nfa(engine, stream_key: str, jit: bool = True):
         anch_b0 = from_planes(anch_o)
         ovf_delta = ovf_o.reshape(Bp)[:B]
 
-        emit = jnp.concatenate(
-            [emit_b0, jnp.zeros((B, I), dtype=bool)], axis=1)
-        emit_anchor = jnp.concatenate(
-            [anch_b0, jnp.zeros((B, I), dtype=jnp.int32)], axis=1)
+        # (the eligible class has no via-path: the emit lanes are bank 0)
+        emit, emit_anchor = emit_b0, anch_b0
 
         # output columns: pure candidate selects, assembled from the
         # emit mask exactly as the XLA _emit_rows writes them (bank 0
         # only — the eligible class has no via-path)
-        out_vals = jnp.zeros((B, 2 * I, O), dtype=jnp.float32)
-        out_ivals = jnp.zeros((B, 2 * I, 2 * n_iout), dtype=jnp.int32)
+        out_vals = jnp.zeros((B, I, O), dtype=jnp.float32)
+        out_ivals = jnp.zeros((B, I, 2 * n_iout), dtype=jnp.int32)
         sl = slice(0, I)
         for oi, (_name, src) in enumerate(out_spec):
             ii = int_out_idx.get(oi)
@@ -271,15 +270,11 @@ def build_plane_nfa(engine, stream_key: str, jit: bool = True):
 
         # counts/regs are constant in the eligible class: they ride back
         # inside the gathered rows, value-identical
-        new_state = engine.layout.scatter(
-            state, part_idx,
-            {**fields, "active": a_new, "first_ts": first_new},
-            ovf_delta, valid, old_rows)
-        n_emit = jnp.sum((emit & valid[:, None]).astype(jnp.int32))
-        return (new_state, emit, {"f": out_vals, "i": out_ivals},
-                emit_anchor, n_emit)
+        return ({**fields, "active": a_new, "first_ts": first_new},
+                ovf_delta, emit, {"f": out_vals, "i": out_ivals},
+                emit_anchor)
 
-    return jax.jit(step, donate_argnums=(0,)) if jit else step
+    return advance
 
 
 def smoke_compile(engine):

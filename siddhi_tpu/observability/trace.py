@@ -75,14 +75,18 @@ STAGE_DELIVER = "deliver"    # rows delivered
 CYCLE_STAGES = (STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT, STAGE_PLAN,
                 STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH, STAGE_STEP,
                 STAGE_EMIT, STAGE_FETCH, STAGE_DELIVER)
-#: what a further collision round (or device chunk) of one batch repeats
+#: what a further round of one batch repeats where rounds are stepped
+#: from the host (the sharded engine; a device chunk of the window
+#: path).  The dense engine runs its rounds on the device: whatever the
+#: batch's longest run, it repeats them once, for all the rounds past
+#: the first together.
 ROUND_STAGES = (STAGE_CONVERT, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH)
 #: most spans one batch cycle records on a served path: every stage
 #: once, the host preparation ahead of the rounds in two more pieces
 #: (the runtime's column views, the engine's lane conversion), and a
-#: second collision round.  The tracer sizes the recorder's ring from it;
-#: a skewed batch of a thousand rounds outgrows it, and the ring then
-#: keeps the newest spans.
+#: second host-stepped round or, on the dense engine, the rounds
+#: program's lanes, put and dispatch.  The tracer sizes the recorder's
+#: ring from it.
 SPANS_PER_CYCLE = len(CYCLE_STAGES) + 2 + len(ROUND_STAGES)
 #: checkpoint-path stages (free-running, engine kind 'persist')
 STAGE_PERSIST_CAPTURE = "persist.capture"
@@ -113,6 +117,10 @@ SCOPE_DENSE_GATHER = "siddhi.dense.gather"      # ops/dense_nfa.py make_step
 SCOPE_DENSE_ADVANCE = "siddhi.dense.advance"
 SCOPE_DENSE_SCATTER = "siddhi.dense.scatter"
 SCOPE_DENSE_COUNT = "siddhi.dense.count"
+# make_rounds: the wide rounds past a batch's first, and the run of
+# narrow ones on resident rows; the step's four scopes nest under them
+SCOPE_DENSE_ROUNDS = "siddhi.dense.rounds"
+SCOPE_DENSE_RUN = "siddhi.dense.run"
 SCOPE_SHARD_COUNT_PSUM = "siddhi.shard.count_psum"  # parallel/mesh.py
 SCOPE_WINDOW_FILTER = "siddhi.window.filter"    # ops/device_query.py make_step
 SCOPE_WINDOW_SLOT = "siddhi.window.slot"
@@ -122,7 +130,8 @@ SCOPE_WINDOW_UPDATE = "siddhi.window.update"
 SCOPE_WINDOW_COUNT = "siddhi.window.count"
 DEVICE_SCOPES = (
     SCOPE_DENSE_GATHER, SCOPE_DENSE_ADVANCE, SCOPE_DENSE_SCATTER,
-    SCOPE_DENSE_COUNT, SCOPE_SHARD_COUNT_PSUM, SCOPE_WINDOW_FILTER,
+    SCOPE_DENSE_COUNT, SCOPE_DENSE_ROUNDS, SCOPE_DENSE_RUN,
+    SCOPE_SHARD_COUNT_PSUM, SCOPE_WINDOW_FILTER,
     SCOPE_WINDOW_SLOT, SCOPE_WINDOW_AGGREGATE, SCOPE_WINDOW_EMIT,
     SCOPE_WINDOW_UPDATE, SCOPE_WINDOW_COUNT)
 

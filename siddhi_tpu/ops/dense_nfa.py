@@ -66,6 +66,7 @@ ops/nfa.py — the planner falls back to the host engine otherwise):
 from __future__ import annotations
 
 import functools
+import logging
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -79,6 +80,8 @@ from siddhi_tpu.observability.trace import (
     SCOPE_DENSE_ADVANCE,
     SCOPE_DENSE_COUNT,
     SCOPE_DENSE_GATHER,
+    SCOPE_DENSE_ROUNDS,
+    SCOPE_DENSE_RUN,
     SCOPE_DENSE_SCATTER,
     STAGE_CONVERT,
     STAGE_DISPATCH,
@@ -95,6 +98,8 @@ from siddhi_tpu.planner.expr import (
 )
 from siddhi_tpu.query_api import AttrType, StateInputStream, Variable
 from siddhi_tpu.query_api.definition import StreamDefinition
+
+log = logging.getLogger("siddhi_tpu.dense")
 
 
 @dataclass
@@ -496,6 +501,21 @@ class DensePatternEngine:
         self.layout = DenseStateLayout(
             self.S, self.I, self.alloc.n, self.alloc.n_int,
             self.has_deadlines, armed_start=not self.every_start)
+        # emit lanes of a step: bank [0, I) for completions at the last
+        # node and, only where the chain has one, bank [I, 2I) for the
+        # via-path's clones (a dually-pending open count before a plain
+        # last node).  A bank nothing can fire in is not carried: the
+        # emit arrays are fetched whole whenever a batch owes one row.
+        last, before = self.nodes[-1], (self.nodes[-2] if self.S > 1
+                                        else None)
+        via_emits = (
+            before is not None and last.kind == "stream"
+            and last.min_count == 1 and last.max_count == 1
+            and before.kind == "stream"
+            and not (before.min_count == 1 and before.max_count == 1)
+            and (before.max_count == ANY
+                 or before.max_count > before.min_count))
+        self.emit_lanes = self.I * (2 if via_emits else 1)
         self._step_cache: Dict[str, Callable] = {}
         # @app:kernels: swap the jitted XLA step for the bit-packed
         # Pallas plane kernel (siddhi_tpu/kernels/dense_step.py).  Set
@@ -599,12 +619,13 @@ class DensePatternEngine:
 
         step(state, part_idx[B] i32, cols {attr: [B] f32}, ts[B] i32
              relative-ms, valid[B] bool)
-          -> (state, emit[B, 2*I] bool, out_vals[B, 2*I, n_out] f32,
-              emit_anchor[B, 2*I] i32, n_emit i32 scalar)
+          -> (state, emit[B, E] bool, out_vals[B, E, n_out] f32,
+              emit_anchor[B, E] i32, n_emit i32 scalar)
 
         ``emit[b, i]``: a pending instance of event ``b``'s partition
-        completed the chain on this event.  The emit arrays carry 2*I
-        lanes: [0, I) for instances completing AT the last node, [I, 2I)
+        completed the chain on this event.  The emit arrays carry
+        E = ``emit_lanes`` lanes: [0, I) for instances completing AT the
+        last node and, in chains that have a via-path into it, [I, 2I)
         for via-path clones (a dually-pending count's clone passing
         straight through the last node) — the two can fire on the same
         event at the same lane index, so they must not share a bank.
@@ -612,16 +633,62 @@ class DensePatternEngine:
         so the host wrapper can order same-event matches by arming age,
         matching the reference's pendingStateEventList iteration order.
 
+        One gather of the batch's rows, :meth:`make_advance`'s automaton
+        on their fields, one scatter in place on the donated rows.
+
         ``jit=False`` returns the raw traceable function (for embedding in
         shard_map / outer jit).
         """
         cache_key = (stream_key, jit)
         if cache_key in self._step_cache:
             return self._step_cache[cache_key]
-        if self.use_kernel:
-            from siddhi_tpu.kernels.dense_step import build_plane_nfa
+        jnp = self.jnp
+        advance = self.make_advance(stream_key)
+        named_scope = self.jax.named_scope
+        layout = self.layout
 
-            fn = build_plane_nfa(self, stream_key, jit)
+        def step(state, part_idx, cols, ts, valid):
+            # one gather of the batch's rows; the fields are split out of
+            # the gathered rows, so every relayout is of B rows
+            with named_scope(SCOPE_DENSE_GATHER):
+                f, old = layout.gather(state, part_idx)
+            new, ovf, emit, outs, emit_anchor = advance(f, cols, ts, valid)
+            # scatter back (valid rows only), in place on the donated rows
+            with named_scope(SCOPE_DENSE_SCATTER):
+                new_state = layout.scatter(state, part_idx, new, ovf, valid,
+                                           old)
+            # outs is a pytree: float lanes + integer hi/lo pair lanes;
+            # n_emit is the count-gate scalar for the async emit
+            # pipeline — the host fetches it alone and skips the column
+            # transfer entirely on zero-match batches
+            with named_scope(SCOPE_DENSE_COUNT):
+                n_emit = jnp.sum((emit & valid[:, None]).astype(jnp.int32))
+            return new_state, emit, outs, emit_anchor, n_emit
+
+        fn = self.jax.jit(step, donate_argnums=(0,)) if jit else step
+        self._step_cache[cache_key] = fn
+        return fn
+
+    def make_advance(self, stream_key: str) -> Callable:
+        """The automaton alone, on the logical fields of B rows:
+
+        advance(fields {name: [B, S, I(, R)]}, cols, ts[B], valid[B])
+          -> (fields after one event a row, overflow increment [B],
+              emit, {"f": out_vals, "i": out_ivals}, emit_anchor)
+
+        What comes back for a row with ``valid`` false is not defined
+        (expiry, for one, does not look at ``valid``): the caller keeps
+        the fields it read for such a row and masks the rest.
+        Traceable, not jitted: :meth:`make_step` wraps it in a gather
+        and a scatter, :meth:`make_rounds` also applies it to resident
+        rows event after event."""
+        cache_key = (stream_key, "advance")
+        if cache_key in self._step_cache:
+            return self._step_cache[cache_key]
+        if self.use_kernel:
+            from siddhi_tpu.kernels.dense_step import build_plane_advance
+
+            fn = build_plane_advance(self, stream_key)
             self._step_cache[cache_key] = fn
             return fn
         jnp = self.jnp
@@ -688,10 +755,11 @@ class DensePatternEngine:
             # one-cell holder: only placement and the absent kill/complete
             # branches touch them, and tracing is sequential python
             dlh = [dl]
-            emit = jnp.zeros((B, 2 * I), dtype=bool)
-            out_vals = jnp.zeros((B, 2 * I, O), dtype=jnp.float32)
-            out_ivals = jnp.zeros((B, 2 * I, 2 * n_iout), dtype=jnp.int32)
-            emit_anchor = jnp.zeros((B, 2 * I), dtype=jnp.int32)
+            E = self.emit_lanes
+            emit = jnp.zeros((B, E), dtype=bool)
+            out_vals = jnp.zeros((B, E, O), dtype=jnp.float32)
+            out_ivals = jnp.zeros((B, E, 2 * n_iout), dtype=jnp.int32)
+            emit_anchor = jnp.zeros((B, E), dtype=jnp.int32)
 
             # within-window expiry: clear expired instances (active bits,
             # in-progress counts and logical side masks alike)
@@ -1201,40 +1269,266 @@ class DensePatternEngine:
 
         named_scope = self.jax.named_scope
 
-        layout = self.layout
-
-        def step(state, part_idx, cols, ts, valid):
-            B = part_idx.shape[0]
-            # one gather of the batch's rows; the fields are split out of
-            # the gathered rows, so every relayout is of B rows
-            with named_scope(SCOPE_DENSE_GATHER):
-                f, old = layout.gather(state, part_idx)
-                iregs = f.get("iregs")
-                if iregs is None:
-                    iregs = jnp.zeros((B, S, I, 0), dtype=jnp.int32)
+        def advance_fields(f, cols, ts, valid):
+            B = ts.shape[0]
+            iregs = f.get("iregs")
+            if iregs is None:
+                iregs = jnp.zeros((B, S, I, 0), dtype=jnp.int32)
             with named_scope(SCOPE_DENSE_ADVANCE):
                 (a, first, counts, regs, iregs, ovf, dl, emit, out_vals,
                  out_ivals, emit_anchor) = advance(
                     f["active"], f["first_ts"], f["counts"], f["regs"],
                     iregs, jnp.zeros((B,), dtype=jnp.int32),
                     f.get("deadline"), cols, ts, valid)
-            # scatter back (valid rows only), in place on the donated rows
-            with named_scope(SCOPE_DENSE_SCATTER):
-                new = {"active": a, "first_ts": first, "counts": counts,
-                       "regs": regs, "iregs": iregs, "deadline": dl}
-                # `ovf` went in as zeros: it is this step's increment
-                new_state = layout.scatter(state, part_idx, new, ovf, valid,
-                                           old)
-            # outs is a pytree: float lanes + integer hi/lo pair lanes;
-            # n_emit is the count-gate scalar for the async emit
-            # pipeline — the host fetches it alone and skips the column
-            # transfer entirely on zero-match batches
-            with named_scope(SCOPE_DENSE_COUNT):
-                n_emit = jnp.sum((emit & valid[:, None]).astype(jnp.int32))
-            return (new_state, emit, {"f": out_vals, "i": out_ivals},
-                    emit_anchor, n_emit)
+            new = {"active": a, "first_ts": first, "counts": counts,
+                   "regs": regs, "iregs": iregs, "deadline": dl}
+            # `ovf` went in as zeros: it is this event's increment
+            return (new, ovf, emit, {"f": out_vals, "i": out_ivals},
+                    emit_anchor)
 
-        fn = self.jax.jit(step, donate_argnums=(0,)) if jit else step
+        self._step_cache[cache_key] = advance_fields
+        return advance_fields
+
+    # -- rounds past the first, on the device -------------------------------
+
+    #: rounds at most this wide are the *run*: their rows stay resident
+    #: (one vector of 128 lanes)
+    RUN_WIDTH = 128
+    #: links of a run the kernel takes in one call; a longer run takes
+    #: several calls
+    RUN_LINKS = 2048
+    #: the wide rounds run at the segment's width, then at this fraction
+    #: of it, so a round a little over RUN_WIDTH is not stepped at the
+    #: width of the widest
+    ROUNDS_NARROW = 8
+
+    def _make_run_kernel(self, stream_key: str) -> Optional[Callable]:
+        """The Pallas kernel for the run (``kernels/dense_run.py``) where
+        the engine is in its class and Mosaic compiles it; None where
+        the XLA loop stays: every other engine, and every backend but
+        the TPU."""
+        from siddhi_tpu.kernels import dense_run, probe
+
+        if not dense_run.eligible(self, stream_key) or (
+                probe.interpret_mode() and not dense_run.INTERPRET_OFF_TPU):
+            return None
+        run = dense_run.build_run(self, stream_key)
+        if not probe.interpret_mode():
+            try:
+                dense_run.smoke_compile(self, stream_key, run)
+            except Exception as e:   # Mosaic's refusal, with its message
+                log.warning("dense run kernel not used for '%s', the XLA "
+                            "loop stays: %s", stream_key, e)
+                return None
+        return run
+
+    def make_rounds(self, stream_key: str) -> Callable:
+        """Build the program for a batch's rounds past the first.
+
+        rounds(state, part_idx[R] i32, cols {attr: [R]}, ts[R] i32,
+               off[R + 1] i32)
+          -> as :meth:`make_step`, the emit arrays indexed by lane
+
+        The lanes hold the events of the batch's second and later
+        rounds in :func:`round_plan`'s order: round ``r`` is lanes
+        ``off[r]:off[r + 1]``, each round's partitions a prefix of the
+        one before, ``off`` padded with the number of lanes.  How many
+        rounds there are and how wide each is are runtime values: one
+        program serves every batch of ``R`` padded lanes.
+
+        Wide rounds each go through :meth:`make_step`'s step (gather,
+        automaton, scatter) inside a ``while_loop``.  From the first
+        round of at most ``RUN_WIDTH`` partitions on, those partitions'
+        rows are gathered once, their fields go through one round after
+        another (a dependence chain as long as the longest run, with no
+        host round trip and no access to the state per link), and the
+        rows are scattered back once.  A link is
+        :meth:`make_advance`'s automaton, the one every other path runs,
+        so captures, counts, ``within``, deadlines, integer registers
+        and overflow keep their semantics exactly; for the class of
+        engines ``kernels/dense_run.py`` covers the whole chain is one
+        Pallas kernel that mirrors that automaton bit for bit (a link
+        is some 220 XLA operations of 128 rows each, a thousand links a
+        quarter of a million kernel launches)."""
+        cache_key = (stream_key, "rounds")
+        if cache_key in self._step_cache:
+            return self._step_cache[cache_key]
+        jax, jnp = self.jax, self.jnp
+        lax = jax.lax
+        tree_map = jax.tree_util.tree_map
+        step = self.make_step(stream_key, jit=False)
+        advance = self.make_advance(stream_key)
+        run_kernel = self._make_run_kernel(stream_key)
+        layout = self.layout
+        I, E = self.I, self.emit_lanes
+        O = max(len(self.out_spec), 1)
+        n_iout = sum(self.out_int)
+        H = self.RUN_WIDTH
+        named_scope = jax.named_scope
+
+        def rounds(state, part_idx, cols, ts, off):
+            R = part_idx.shape[0]
+            scratch = state[ROWS].shape[0] - 1
+            end = off[R]
+            n_rounds = jnp.sum((off[:R] < end).astype(jnp.int32))
+            # a round is sliced at its loop's static width: pad the
+            # lanes so that no slice is clamped back into other rounds
+            pad = max(R, H)
+            lanes = tree_map(
+                lambda x: jnp.concatenate(
+                    [x, jnp.zeros((pad,), dtype=x.dtype)]),
+                {"p": part_idx, "t": ts, "c": cols})
+            bufs = {
+                "emit": jnp.zeros((R + pad, E), dtype=bool),
+                "f": jnp.zeros((R + pad, E, O), dtype=jnp.float32),
+                "i": jnp.zeros((R + pad, E, 2 * n_iout), dtype=jnp.int32),
+                "anchor": jnp.zeros((R + pad, E), dtype=jnp.int32),
+            }
+
+            def width(r):
+                return off[jnp.minimum(r + 1, R)] - off[jnp.minimum(r, R)]
+
+            def loop(one_round, w, narrower, carry):
+                """Rounds from ``r = carry[-1]`` on while they are wider
+                than ``narrower``, each sliced ``w`` lanes wide.
+                ``one_round(st, at, valid)`` takes the loop's state
+                through the round's lanes and returns it with the
+                round's emit arrays and match count."""
+                def body(c):
+                    st, bufs, count, r = c
+                    at = tree_map(
+                        lambda x: lax.dynamic_slice_in_dim(x, off[r], w),
+                        lanes)
+                    valid = jnp.arange(w) < width(r)
+                    st, emit, outs, anchor = one_round(st, at, valid)
+                    emit = emit & valid[:, None]
+                    new = {"emit": emit, "f": outs["f"], "i": outs["i"],
+                           "anchor": anchor}
+                    # later rounds lie further on: what a round writes
+                    # past its own lanes, the rounds that own them
+                    # overwrite
+                    bufs = {k: lax.dynamic_update_slice_in_dim(
+                        bufs[k], v, off[r], 0) for k, v in new.items()}
+                    return (st, bufs,
+                            count + jnp.sum(emit.astype(jnp.int32)), r + 1)
+
+                return lax.while_loop(
+                    lambda c: (c[3] < n_rounds) & (width(c[3]) > narrower),
+                    body, carry)
+
+            def stepped(st, at, valid):
+                st, emit, outs, anchor, _n = step(
+                    st, jnp.where(valid, at["p"], scratch), at["c"],
+                    at["t"], valid)
+                return st, emit, outs, anchor
+
+            # a segment no wider than the run has no wide rounds
+            widths = [w for w in (R, R // self.ROUNDS_NARROW) if w > H]
+            carry = (state, bufs, jnp.int32(0), jnp.int32(0))
+            with named_scope(SCOPE_DENSE_ROUNDS):
+                for w, narrower in zip(widths, widths[1:] + [H]):
+                    carry = loop(stepped, w, narrower, carry)
+            state, bufs, count, r = carry
+
+            def advanced(st, at, valid):
+                f, ovf = st
+                new, d_ovf, emit, outs, anchor = advance(
+                    f, at["c"], at["t"], valid)
+                keep = lambda n, o: jnp.where(
+                    valid.reshape((-1,) + (1,) * (o.ndim - 1)), n, o)
+                f = {k: keep(new[k], v) for k, v in f.items()}
+                return ((f, ovf + jnp.where(valid, d_ovf, 0)), emit, outs,
+                        anchor)
+
+            def run_by_kernel(f, ovf, bufs, count, r):
+                """The run through ``run_kernel``, up to ``RUN_LINKS``
+                links a call: each link's lanes laid out as a row of
+                ``H``, and what the links emit brought back to lane
+                order."""
+                # the lanes in tiles of H, one tile past the last lane;
+                # and the round every lane lies in
+                n_tiles = -(-R // H) + 1
+                tiles = tree_map(
+                    lambda x: jnp.concatenate([x, jnp.zeros(
+                        (n_tiles * H - R,), x.dtype)]).reshape(n_tiles, H),
+                    {"c": cols, "t": ts})
+                lane = jnp.arange(R)
+                rnd = jnp.searchsorted(off, lane, side="right") - 1
+                L = self.RUN_LINKS
+
+                def one_call(c):
+                    f, ovf, bufs, count, r = c
+                    n_links = jnp.clip(n_rounds - r, 0, L)
+                    at = jnp.minimum(r + jnp.arange(L), R)
+                    starts = off[at]
+                    widths = jnp.where(
+                        jnp.arange(L) < n_links,
+                        off[jnp.minimum(at + 1, R)] - starts, 0)
+                    f, d_ovf, emit, anchor, out = run_kernel(
+                        f, tiles["c"], tiles["t"], starts, widths, n_links)
+                    # lane -> (link, position): the round it lies in
+                    mine = (lane < end) & (rnd >= r) & (rnd < r + n_links)
+                    li = jnp.clip(rnd - r, 0, L - 1)
+                    pos = jnp.clip(lane - off[jnp.clip(rnd, 0, R)], 0,
+                                   H - 1)
+                    fired = emit[:, li, pos].T & mine[:, None]    # [R, I]
+                    vals, o = [], 0
+                    for _name, src in self.out_spec:
+                        if isinstance(src, tuple):   # ('cand', attr)
+                            col = cols.get(src[1])
+                            vals.append(
+                                jnp.zeros((R, I), jnp.float32)
+                                if col is None else jnp.where(
+                                    fired,
+                                    col.astype(jnp.float32)[:, None], 0.0))
+                        else:
+                            vals.append(jnp.where(
+                                fired, out[o][:, li, pos].T, 0.0))
+                            o += 1
+                    if not vals:
+                        vals = [jnp.zeros((R, I), jnp.float32)]
+                    new = {"emit": fired,
+                           "f": jnp.stack(vals, axis=-1),
+                           "anchor": jnp.where(fired,
+                                               anchor[:, li, pos].T, 0)}
+                    # (this class has no via-path: E == I)
+                    bufs = dict(bufs)
+                    for k, v in new.items():
+                        keep = mine.reshape((R,) + (1,) * (v.ndim - 1))
+                        bufs[k] = bufs[k].at[:R, :I].set(
+                            jnp.where(keep, v, bufs[k][:R, :I]))
+                    return (f, ovf + d_ovf, bufs,
+                            count + jnp.sum(fired.astype(jnp.int32)),
+                            r + n_links)
+
+                return lax.while_loop(lambda c: c[4] < n_rounds, one_call,
+                                      (f, ovf, bufs, count, r))
+
+            with named_scope(SCOPE_DENSE_RUN):
+                # position j of every remaining round is one partition:
+                # that of lane j of the first of them.  Positions past
+                # its width name the scratch row and write back the
+                # words they read.
+                in_run = jnp.arange(H) < width(r)
+                rows = jnp.where(
+                    in_run,
+                    lax.dynamic_slice_in_dim(lanes["p"], off[r], H), scratch)
+                with named_scope(SCOPE_DENSE_GATHER):
+                    f, old = layout.gather(state, rows)
+                ovf = jnp.zeros((H,), dtype=jnp.int32)
+                if run_kernel is not None:
+                    f, ovf, bufs, count, r = run_by_kernel(
+                        f, ovf, bufs, count, r)
+                else:
+                    (f, ovf), bufs, count, r = loop(
+                        advanced, H, -1, ((f, ovf), bufs, count, r))
+                with named_scope(SCOPE_DENSE_SCATTER):
+                    state = layout.scatter(state, rows, f, ovf, in_run, old)
+            return (state, bufs["emit"][:R], {"f": bufs["f"][:R],
+                                              "i": bufs["i"][:R]},
+                    bufs["anchor"][:R], count)
+
+        fn = jax.jit(rounds, donate_argnums=(0,))
         self._step_cache[cache_key] = fn
         return fn
 
@@ -1571,20 +1865,31 @@ class DensePatternEngine:
 
     def process_deferred(self, state, stream_key: str, part_idx: np.ndarray,
                          cols: Dict[str, np.ndarray], ts: np.ndarray):
-        """Async-emit variant of :meth:`process`: every round's match
+        """Async-emit variant of :meth:`process`: the batch's match
         outputs stay resident on device inside the returned
         :class:`DeferredDenseEmit` (None only for empty input).  NOTHING
-        crosses device->host here — even the per-round ``n_emit`` count
-        gate stays a device scalar until ``resolve()`` fetches it, which
-        the ingest stage (core/ingest_stage.py) defers past the next
-        batch's dispatch so the H2D transfer overlaps this batch's
-        step."""
+        crosses device->host here — even the ``n_emit`` count gates stay
+        device scalars until ``resolve()`` fetches them, which the
+        ingest stage (core/ingest_stage.py) defers past the next batch's
+        dispatch so the H2D transfer overlaps this batch's step.
+
+        A partition may appear any number of times in the batch.
+        :func:`round_plan` orders the events by (occurrence within their
+        partition, partition); the first occurrences go through the
+        plain step, every later occurrence through :meth:`make_rounds`'
+        program, which loops over the remaining rounds on the device (a
+        single second round through the step again).  At most two H2D
+        puts and two dispatches a batch, however long the longest run
+        of one partition is."""
         faults = getattr(self, "faults", None)
         if faults is not None:
             faults.check("step.dense")
         from siddhi_tpu.core.ingest_stage import staged_put
 
-        with span(STAGE_CONVERT, len(part_idx)):
+        n = len(part_idx)
+        if n == 0:
+            return state, None
+        with span(STAGE_CONVERT, n):
             step = self.make_step(stream_key)
             rel64 = self.rel_ts64(np.asarray(ts, dtype=np.int64))
             state, rel64 = self.maybe_re_anchor(state, rel64)
@@ -1594,40 +1899,60 @@ class DensePatternEngine:
             plan = round_plan(part_idx)
             if sp is not None:
                 sp.count = plan.n_rounds
+        # the first round, and everything behind it; a second round that
+        # is also the last is one more call of the step, and only a
+        # third makes the rounds program worth its trace (a second or
+        # two a shape, which a cell of two rounds would pay at set-up)
+        programs = ((step,) * plan.n_rounds if plan.n_rounds <= 2
+                    else (step, self.make_rounds(stream_key)))
+        bounds = (0, int(plan.off[1]), n)[:len(programs) + 1]
         pending = DeferredDenseEmit(self)
-        for r in range(plan.n_rounds):
-            ridx = plan.round(r)
-            b = len(ridx)
-            with span(STAGE_CONVERT, b):
-                bp = max(1 << (b - 1).bit_length(), 16)  # pad to pow2, min 16
-                pi = np.full(bp, self.n_partitions, dtype=np.int32)  # scratch row
-                pi[:b] = part_idx[ridx]
-                tb = np.zeros(bp, dtype=np.int32)
-                tb[:b] = rel[ridx]
-                valid = np.zeros(bp, dtype=bool)
-                valid[:b] = True
-                cb = {}
-                for k, v in prepared.items():
-                    col = np.zeros(bp, dtype=v.dtype)
-                    col[:b] = v[ridx]
-                    cb[k] = col
-            # one pytree H2D put per round behind the ingest.put fault
-            # site (core/ingest_stage.py — the sanctioned ingest path)
-            pi, cb, tb, valid = staged_put(
-                (pi, cb, tb, valid), faults=faults,
-                stats=getattr(self, "ingest_stats", None))
+        for program, lo, hi in zip(programs, bounds, bounds[1:]):
+            ev = plan.lanes[lo:hi]
+            with span(STAGE_CONVERT, hi - lo):
+                lanes = self._pad_lanes(part_idx, prepared, rel, ev)
+                if program is step:
+                    where = np.zeros(len(lanes[0]), dtype=bool)  # valid
+                    where[:hi - lo] = True
+                else:
+                    # starts of rounds 1.. within the rest, then its
+                    # end on every further entry
+                    where = np.full(len(lanes[0]) + 1, hi - lo,
+                                    dtype=np.int32)
+                    where[:plan.n_rounds] = plan.off[1:] - lo
+            # one pytree H2D put a program behind the ingest.put fault
+            # site (core/ingest_stage.py — the sanctioned ingest path);
+            # the second goes while the device steps the first round
+            args = staged_put(lanes + (where,), faults=faults,
+                              stats=getattr(self, "ingest_stats", None))
             with span(STAGE_DISPATCH, 1):
-                state, emit, outs, emit_anchor, n_emit = step(
-                    state, pi, cb, tb, valid
-                )
+                state, emit, outs, emit_anchor, n_emit = program(
+                    state, *args)
             # count gate deferred: n_emit stays a device scalar until
             # DeferredDenseEmit.resolve() (driven by the ingest stage)
             pending.chunks.append({
                 "emit": emit, "f": outs["f"], "i": outs["i"],
-                "anchor": emit_anchor, "sel": slice(0, b), "ridx": ridx,
-                "count": n_emit,
+                "anchor": emit_anchor, "sel": slice(0, hi - lo),
+                "ridx": ev, "count": n_emit,
             })
-        return state, (pending if pending.chunks else None)
+        return state, pending
+
+    def _pad_lanes(self, part_idx, prepared, rel, ev):
+        """Host lanes of the events ``ev`` padded to a power of two (at
+        least 16, bounding jit recompilation): partition rows (padding
+        points at the scratch row), device columns, relative ms."""
+        b = len(ev)
+        bp = max(1 << (b - 1).bit_length(), 16)
+        pi = np.full(bp, self.n_partitions, dtype=np.int32)  # scratch row
+        pi[:b] = part_idx[ev]
+        tb = np.zeros(bp, dtype=np.int32)
+        tb[:b] = rel[ev]
+        cb = {}
+        for k, v in prepared.items():
+            col = np.zeros(bp, dtype=v.dtype)
+            col[:b] = v[ev]
+            cb[k] = col
+        return pi, cb, tb
 
     def assemble_out(self, out_f: np.ndarray, out_i: np.ndarray,
                      rows: np.ndarray, lanes: np.ndarray) -> np.ndarray:
@@ -1698,10 +2023,14 @@ class DensePatternEngine:
     def device_col_keys(self, stream_key: str) -> List[str]:
         """Exact device col-dict keys the step expects: float attrs ride
         one float32 lane, integer attrs ride an ``|hi``/``|lo`` int32
-        pair — the fixed pytree structure of shard_map in_specs."""
+        pair — the fixed pytree structure of shard_map in_specs.  Only
+        the attributes the automaton reads (:meth:`read_attrs`): a
+        column nothing looks at costs a transfer of its own in every
+        put."""
         keys: List[str] = []
+        read = self.read_attrs(stream_key)
         for a in self._stream_def(stream_key).attributes:
-            if not a.type.is_numeric:
+            if a.name not in read:
                 continue
             if a.type in _INT_TYPES:
                 keys.extend((f"{a.name}|hi", f"{a.name}|lo"))
@@ -1709,15 +2038,72 @@ class DensePatternEngine:
                 keys.append(a.name)
         return keys
 
+    def read_attrs(self, stream_key: str) -> frozenset:
+        """Numeric attributes of one stream that some node filter looks
+        up, some capture stores or the select reads from the completing
+        event.  Found by evaluating the compiled filters once over an
+        ``env`` that records its lookups."""
+        cache_key = (stream_key, "read_attrs")
+        if cache_key in self._step_cache:
+            return self._step_cache[cache_key]
+        numeric = [a for a in self._stream_def(stream_key).attributes
+                   if a.type.is_numeric]
+        looked_up = set()
+
+        class Recording(dict):
+            def __getitem__(self, key):
+                looked_up.add(key)
+                return dict.__getitem__(self, key)
+
+            def get(self, key, default=None):
+                looked_up.add(key)
+                return dict.get(self, key, default)
+
+        env = {TS_KEY: np.int32(1), N_KEY: 1}
+        for a in numeric:
+            if a.type in _INT_TYPES:
+                env[f"__cand.{a.name}|hi"] = np.int32(1)
+                env[f"__cand.{a.name}|lo"] = np.int32(1)
+            else:
+                env["__cand." + a.name] = np.float32(1)
+        for slot in self.alloc.slots.values():
+            if slot.integer:
+                env[f"__ireg.{slot.index}|hi"] = np.int32(1)
+                env[f"__ireg.{slot.index}|lo"] = np.int32(1)
+            else:
+                env[f"__reg.{slot.index}"] = np.float32(1)
+        read = set()
+        try:
+            for s, node in enumerate(self.nodes):
+                for si, spec in enumerate(node.specs):
+                    if spec.stream_key != stream_key:
+                        continue
+                    f = self.node_filters[s][si]
+                    if f is not None:
+                        f.fn(Recording(env))
+                    read |= {slot.attr for slot in self.node_writes[s]
+                             if slot.ref == spec.ref}
+            read |= {k[len("__cand."):].split("|")[0] for k in looked_up
+                     if k.startswith("__cand.")}
+            read |= {src[1] for _name, src in self.out_spec
+                     if isinstance(src, tuple)}
+            read &= {a.name for a in numeric}
+        except Exception:   # an expression that wants arrays: keep all
+            read = {a.name for a in numeric}
+        self._step_cache[cache_key] = frozenset(read)
+        return self._step_cache[cache_key]
+
     def prepare_cols(self, stream_key: str,
                      cols: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         """Host numpy columns (native dtypes) -> device lane columns:
         float attrs cast to float32, integer attrs split into the
-        bias-signed hi/lo int32 pair (bit-exact at any magnitude)."""
+        bias-signed hi/lo int32 pair (bit-exact at any magnitude).
+        Attributes the automaton never reads are left on the host."""
         out: Dict[str, np.ndarray] = {}
+        read = self.read_attrs(stream_key)
         for a in self._stream_def(stream_key).attributes:
             v = cols.get(a.name)
-            if v is None:
+            if v is None or a.name not in read:
                 continue
             v = np.asarray(v)
             if a.type in _INT_TYPES:
@@ -1725,7 +2111,7 @@ class DensePatternEngine:
                 out[f"{a.name}|hi"] = (v64 >> 32).astype(np.int32)
                 out[f"{a.name}|lo"] = (
                     (v64 & 0xFFFFFFFF) - 2**31).astype(np.int32)
-            elif a.type.is_numeric:
+            else:
                 out[a.name] = v.astype(np.float32)
         return out
 
@@ -1733,11 +2119,13 @@ class DensePatternEngine:
 class DeferredDenseEmit:
     """Device-resident match outputs of one dense batch, pending drain.
 
-    Each chunk is one collision round whose count gate fired: the
+    Each chunk is one dispatched program whose count gate fired (the
+    unsharded engine: the batch's first round, and all its later rounds
+    together; the sharded engine: one collision round each): the
     ``emit``/``f``/``i``/``anchor`` arrays are still jit outputs on
-    device; ``sel`` maps padded device rows back to the round's events
+    device; ``sel`` maps padded device rows back to the chunk's events
     (a ``slice`` on the unsharded engine, a routed-slot index array on
-    the sharded one) and ``ridx`` maps round rows to batch rows.
+    the sharded one) and ``ridx`` maps the chunk's rows to batch rows.
     ``device_arrays()`` + ``materialize()`` is the pending-emit queue
     contract (core/emit_queue.py): materialize receives the fetched host
     arrays in ``device_arrays()`` order and reproduces exactly what the
@@ -1754,7 +2142,7 @@ class DeferredDenseEmit:
     def probe(self):
         """Device scalar marking step completion (ingest-stage overlap
         evidence); None when no round dispatched."""
-        return self.chunks[0]["count"] if self.chunks else None
+        return self.chunks[-1]["count"] if self.chunks else None
 
     def resolve(self) -> int:
         """Fetch the deferred per-round count gates (scalars only) and
@@ -1843,14 +2231,14 @@ def round_plan(part_idx: np.ndarray) -> RoundPlan:
     Within a round the partitions stand in one fixed order: those with
     more events in the batch first, ties by first arrival, and the
     partitions that appear once behind them in arrival order.  So the
-    partitions of round ``r`` are a prefix of those of round ``r - 1``
-    and position ``j`` of every round is the same partition: a device
-    program that runs the rounds itself can keep the last rounds' few
-    rows resident (ROADMAP.md Speed 10; today every round is stepped
-    from the host and only needs its partitions to be distinct).
+    partitions of round ``r`` are a prefix of those of round ``r - 1``:
+    position ``j`` of every round is the same partition, which is what
+    lets the device keep the last rounds' few rows resident
+    (:meth:`DensePatternEngine.make_rounds`).
 
     One sort of the batch and one of the repeated events; no pass over
     the batch per round."""
+    part_idx = np.asarray(part_idx)
     n = len(part_idx)
     if n == 0:
         return RoundPlan(np.empty(0, dtype=np.int64),

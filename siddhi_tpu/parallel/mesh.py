@@ -309,6 +309,8 @@ class ShardedPatternEngine:
         faults = getattr(self.engine, "faults", None)
         if faults is not None:
             faults.check("step.shard")
+        # the rounds are stepped from the host here: each is routed to
+        # the shards on its own (PERF.md, Open question 3)
         for r in range(plan.n_rounds):
             ridx = plan.round(r)
             with span(STAGE_CONVERT, len(ridx)):

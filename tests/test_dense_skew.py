@@ -1,7 +1,7 @@
 """Skewed batches through the dense engine: one key many times in one
-batch.  ``round_plan`` splits the events into rounds in which a key
-appears once, and the engines step round after round.  Whatever the
-skew, the result must be what the same events
+batch.  ``round_plan`` orders the events, the first occurrences go
+through the plain step and every later one through ``make_rounds``'
+device loop.  Whatever the skew, the result must be what the same events
 give one at a time: state and emissions exact, for every engine kind of
 ``dense_layout_cases.ENGINES``, on one device and over the 4-device CPU
 mesh; and through ``SiddhiManager`` what the host engine (``ops/nfa.py``)
@@ -189,10 +189,36 @@ def test_skewed_batch_equals_one_event_at_a_time(eng_name, n_dev):
     check_against_one_event_at_a_time(eng_name, n_dev, "skewed")
 
 
-def test_one_fetch_of_the_count_gates_a_batch(monkeypatch):
-    """A batch with a run of 24 is 24 rounds: a put and a dispatch a
-    round (ROADMAP.md Speed 10 moves the rounds on to the device), and
-    however many rounds, one fetch of all their count gates."""
+@pytest.fixture
+def run_kernel(monkeypatch):
+    """The Pallas kernel for the run, interpreted: off a TPU the engine
+    keeps the XLA loop unless told otherwise."""
+    from siddhi_tpu.kernels import dense_run
+
+    monkeypatch.setattr(dense_run, "INTERPRET_OFF_TPU", True)
+    return dense_run
+
+
+@pytest.mark.parametrize("links_a_call", [2048, 8])
+def test_the_run_kernel_equals_one_event_at_a_time(run_kernel, monkeypatch,
+                                                   links_a_call):
+    """``every_r2`` is in the run kernel's class (the others are not):
+    the kernel gives the state, rows and overflow of the XLA step, also
+    where the runs of 17 to 60 take several calls of eight links."""
+    from siddhi_tpu.ops.dense_nfa import DensePatternEngine
+
+    eng = _engine("every_r2", 1)[0]
+    assert run_kernel.eligible(eng, "S")
+    assert eng._make_run_kernel("S") is not None
+    assert not any(run_kernel.eligible(_engine(e, 1)[0], ENGINES[e][2][0])
+                   for e in ENGINES if e != "every_r2")
+    monkeypatch.setattr(DensePatternEngine, "RUN_LINKS", links_a_call)
+    check_against_one_event_at_a_time("every_r2", 1, "skewed")
+
+
+def test_two_puts_and_one_count_gate_a_batch(monkeypatch):
+    """A batch with a run of 24 takes two H2D puts and two dispatches
+    (the first round; all the rest) and one fetch of its count gates."""
     import jax
 
     eng = compile_pattern(STREAMS + ENGINES["every_r2"][0], "q",
@@ -214,13 +240,12 @@ def test_one_fetch_of_the_count_gates_a_batch(monkeypatch):
 
     monkeypatch.setattr(jax, "device_put", put)
     monkeypatch.setattr(jax, "device_get", get)
-    rounds = round_plan(part).n_rounds
-    assert rounds == 24
     state, pending = eng.process_deferred(state, "S", part, cols, ts + 5_000)
-    assert calls == {"put": rounds, "get": 0}
-    assert len(pending.chunks) == rounds
+    assert calls == {"put": 2, "get": 0}
+    assert len(pending.chunks) == 2
     assert pending.resolve() > 0
-    assert calls == {"put": rounds, "get": 1}
+    assert calls == {"put": 2, "get": 1}
+    assert round_plan(part).n_rounds == 24
 
 
 # -- through SiddhiManager: the host engine and a plain automaton -------------
@@ -300,11 +325,74 @@ def _run_app(header, batches, one_by_one=False):
         m.shutdown()
 
 
-@pytest.mark.parametrize("devices", MESHES)
-def test_zipf_keys_through_the_manager(devices):
-    """The benchmark's skew at a small size: the dense path (one device,
-    and sharded over four) delivers the rows of the host engine and of a
-    plain chain automaton, in each key's event-time order."""
+@pytest.fixture(scope="module")
+def traced_chain():
+    """The chain app at ``sample='1'``: ``send(runs)`` sends one batch
+    in which key ``i`` comes ``runs[i]`` times and returns the batch's
+    ``plan`` counts, its numbers of ``put`` and ``dispatch`` spans, and
+    whether the engine built its rounds program for it."""
+    m = SiddhiManager()
+    rt = m.create_siddhi_app_runtime(
+        "@app:name('paths') @app:playback @app:execution('tpu', "
+        "partitions='64') @app:trace(sample='1', cycles='4') " + CHAIN)
+    rt.start()
+    h = rt.get_input_handler("Txn")
+    engine = next(q.pattern_processor.engine
+                  for pr in rt.partitions.values()
+                  for q in pr.dense_query_runtimes.values())
+    sent = [0]
+
+    def has_rounds():
+        return any(k[1] == "rounds" for k in engine._step_cache)
+
+    def send(runs):
+        keys = np.repeat(np.arange(len(runs), dtype=np.int64), runs)
+        n = len(keys)
+        had = has_rounds()
+        h.send_batch(EventBatch(
+            "Txn", ["key", "v"], {"key": keys, "v": np.arange(n) + 0.5},
+            1_000 + sent[0] + np.arange(n, dtype=np.int64)))
+        sent[0] += n
+        spans = list(rt.app_context.tracer.recorder.cycle_groups()
+                     .values())[-1]
+        return ([s[5] for s in spans if s[1] == "plan"],
+                [sum(s[1] == st for s in spans)
+                 for st in ("put", "dispatch")],
+                has_rounds() != had)
+
+    send.engine_has_rounds = has_rounds
+    yield send
+    m.shutdown()
+
+
+@pytest.mark.parametrize("longest,dispatches", [(1, 1), (2, 2), (3, 2),
+                                                (40, 2)])
+def test_the_round_count_alone_chooses_the_path(traced_chain, longest,
+                                                dispatches):
+    """One round: the step once.  Two: the step twice, and no rounds
+    program is built for it.  Three or more: the step and the rounds
+    program, two dispatches however long the run.  A put a dispatch."""
+    built = traced_chain.engine_has_rounds()
+    for _again in range(2):
+        # (the engine builds the program with the first batch that
+        # needs it and keeps it)
+        first_use = longest >= 3 and not built
+        assert traced_chain([longest, 1, 1, min(longest, 2)]) == (
+            [longest], [dispatches, dispatches], first_use)
+        built = built or first_use
+
+
+@pytest.mark.parametrize("devices", MESHES + ("kernel",))
+def test_zipf_keys_through_the_manager(devices, request):
+    """The benchmark's skew at a small size: the dense path (one device
+    with the run as the XLA loop and as the Pallas kernel, and sharded
+    over four) delivers the rows of the host engine and of a plain chain
+    automaton, in each key's event-time order."""
+    if devices == "kernel":
+        # captures in filters and select, `within`, no restart on
+        # emission: the north-star app's class
+        request.getfixturevalue("run_kernel")
+        devices = 1
     batches = _zipf_batches(np.random.default_rng(28), 256, 512, 3)
     assert max(round_plan(b.columns["key"]).n_rounds for b in batches) > 30
     opts = "partitions='256'" + (f", devices='{devices}'"

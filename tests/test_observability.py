@@ -237,7 +237,7 @@ def test_trace_annotation_configures_tracer(tmp_path):
 def test_recorder_ring_evicts_to_newest_cycles():
     """The ring at cycles=N holds the last N complete cycles: every
     stage once, the host preparation in two more pieces, a second
-    collision round, and the two persist spans interleaving."""
+    host-stepped round, and the two persist spans interleaving."""
     one_cycle = (trace_mod.CYCLE_STAGES + ("convert", "convert")
                  + trace_mod.ROUND_STAGES)
     assert len(one_cycle) == trace_mod.SPANS_PER_CYCLE
@@ -460,6 +460,11 @@ def test_spans_tile_send_batch(path, monkeypatch):
                 assert len(by[stage]) >= least, (stage, len(by[stage]))
             for stage in EVERY_CYCLE:
                 assert len(by[stage]) == 1, stage
+            if path == "dense":
+                # the rounds run on the device: a put and a dispatch
+                # for the first round and for all the rest, however
+                # often a key repeats
+                assert (len(by["put"]), len(by["dispatch"])) == (2, 2)
             if "plan" in by:
                 assert [s[5] for s in by["plan"]] == [2]  # rounds
             ingest, step, emit = by["ingest"][0], by["step"][0], by["emit"][0]
@@ -629,9 +634,28 @@ def test_jitted_steps_carry_the_device_scopes():
     cols = eng.prepare_cols(sk, {"k": idx.astype(np.int64),
                                  "v": np.linspace(0.0, 20.0, n)})
     dense = {sc for sc in trace_mod.DEVICE_SCOPES if ".dense." in sc}
-    assert len(dense) == 4
+    assert len(dense) == 6
+    rounds = {trace_mod.SCOPE_DENSE_ROUNDS, trace_mod.SCOPE_DENSE_RUN}
+    dense -= rounds
     assert scopes_in(eng.make_step(sk).lower(
         eng.init_state(), idx, cols, idx, np.ones(n, bool))) == dense
+    # the rounds program: wide enough to have wide rounds beside the run
+    wide = np.arange(4 * eng.RUN_WIDTH, dtype=np.int32) % 64
+    text = eng.make_rounds(sk).lower(
+        eng.init_state(), wide,
+        eng.prepare_cols(sk, {"k": wide.astype(np.int64),
+                              "v": np.linspace(0.0, 20.0, len(wide))}),
+        wide, np.append(wide, 0)).compile().as_text()
+    for outer in rounds:
+        # the wide rounds gather, advance and scatter round by round;
+        # the run gathers once and scatters once, and between them this
+        # app's chain is the Pallas kernel (a chain outside its class
+        # loops over `advance` there)
+        for inner in dense - {trace_mod.SCOPE_DENSE_COUNT} - (
+                {trace_mod.SCOPE_DENSE_ADVANCE}
+                if outer == trace_mod.SCOPE_DENSE_RUN else set()):
+            assert re.search(r'op_name="[^"]*/' + re.escape(outer)
+                             + r'/[^"]*' + re.escape(inner) + r'[/"]', text)
     sharded = ShardedPatternEngine(
         compile_pattern(PATTERN_BODY, n_partitions=64), make_mesh(4))
     args, _pos = sharded.route(idx, cols, idx)
