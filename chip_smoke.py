@@ -13,7 +13,7 @@ Phases:
 
 - *pattern*: the north-star deployment of BASELINE.json — the 16-state
   ``every e1 -> ... -> e16 within 10 min`` chain of
-  ``bench.partitioned_app()``, partitioned by key, 1,000,000 key
+  ``partitioned_app()``, partitioned by key, 1,000,000 key
   partitions, batches of 131,072 events.  Every key is interned,
   duplicate keys inside a batch run collision rounds, a seeded set of
   keys completes the chain so the count gate opens and matches are
@@ -23,7 +23,7 @@ Phases:
   ``samples/performance/workloads.py`` (``sliding_window``,
   ``groupby_length_batch_agg_only``) on prices that bf16 cannot hold.
 - *kernels*: each ``@app:kernels`` kind live through Mosaic at the
-  batch sizes ``bench.py`` uses, bit-identical to the XLA path.
+  ``PK_*`` / ``HK_*`` sizes below, bit-identical to the XLA path.
 - *multichip*: the pattern phase again with ``devices='4'`` when the
   host has four chips.
 
@@ -59,6 +59,14 @@ REHEARSAL = {
     "bank_events": 1_024, "scan_keys": 256, "scan_batch": 1_024,
 }
 
+N_STATES = 16          # chain length of both pattern apps
+# kernel phase sizes: the nfa chain, the bank scatter, the hot-key scan
+PK_PARTITIONS = 65_536
+PK_BATCH = 1 << 15
+PK_BANK_EVENTS = 1 << 15
+HK_KEYS = 4_096
+HK_BATCH = 8_192
+
 # float32 contract of ops/device_query.py: float sums accumulate in
 # float32, the host engine in float64 — a few tens of roundings apart
 F32_RTOL = 64 * float(np.finfo(np.float32).eps)
@@ -66,6 +74,33 @@ F32_RTOL = 64 * float(np.finfo(np.float32).eps)
 
 def say(msg: str) -> None:
     print(msg, flush=True)
+
+
+def partitioned_app() -> str:
+    """The north-star app: the 16-state escalation pattern ``every
+    e1=[v>θ1] -> e2=[v>θ2 and v>e1.v] -> ... within 10 min``, one
+    automaton per key."""
+    states = ["every e1=Txn[v > 0.0]"]
+    for i in range(2, N_STATES + 1):
+        states.append(f"e{i}=Txn[v > {float(i - 1)} and v > e1.v]")
+    pattern = " -> ".join(states)
+    return ("define stream Txn (key long, v double); "
+            "partition with (key of Txn) begin "
+            f"@info(name='bench') from {pattern} within 10 min "
+            "select e1.v as v1, e16.v as v16 insert into Alerts; end;")
+
+
+def kernel_eligible_app() -> str:
+    """Capture-free escalation chain: fixed thresholds, final-node
+    select only — the class the packed-plane NFA kernel covers (any
+    e1.v capture would need the register file and fall back)."""
+    states = ["every e1=Txn[v > 1.0]"]
+    for i in range(2, N_STATES + 1):
+        states.append(f"e{i}=Txn[v > {float(i)}]")
+    pattern = " -> ".join(states)
+    return ("define stream Txn (key long, v double); "
+            f"@info(name='bench') from {pattern} within 10 min "
+            f"select e{N_STATES}.v as v insert into Alerts;")
 
 
 class CompileMeter:
@@ -280,13 +315,11 @@ def median(xs):
 
 
 def phase_pattern(env, sizes, devices: int = 0):
-    import bench
-
     n_keys, batch = sizes["partitions"], sizes["batch"]
     name = f"multichip[{devices}]" if devices else "pattern"
     say(f"[{name}] {n_keys:,} partitions x {batch:,}-event batches, "
-        f"{N_BATCHES} batches x 2 passes, {bench.N_STATES} states")
-    out = run_pattern(env, bench.partitioned_app(), n_keys, batch,
+        f"{N_BATCHES} batches x 2 passes, {N_STATES} states")
+    out = run_pattern(env, partitioned_app(), n_keys, batch,
                       devices=devices)
     assert out["lowering"] == "dense", out["lowering"]
     rows, stats = out["rows"], out["memory"]
@@ -326,7 +359,7 @@ def phase_pattern(env, sizes, devices: int = 0):
     keep = np.zeros(n_keys, dtype=bool)
     keep[sample] = True
     t0 = time.perf_counter()
-    host = host_pattern_rows(env, bench.partitioned_app(), n_keys, batch,
+    host = host_pattern_rows(env, partitioned_app(), n_keys, batch,
                              keep)
     dev = [r for r, i in zip(rows, row_ids) if keep[i]]
     assert sorted(dev) == sorted(host), (
@@ -441,8 +474,6 @@ def phase_windows(env, sizes):
 
 
 def phase_kernels(env, sizes):
-    import bench
-
     from siddhi_tpu import SiddhiManager
     from siddhi_tpu.core.event import EventBatch
     from siddhi_tpu.kernels import probe
@@ -451,9 +482,9 @@ def phase_kernels(env, sizes):
     mode = "interpreted" if probe.interpret_mode() else "Mosaic"
 
     # nfa: the pattern driver on the capture-free chain, kernel vs XLA
-    n_keys = sizes.get("nfa_partitions", bench.PK_PARTITIONS)
-    batch = sizes.get("nfa_batch", bench.PK_BATCH)
-    define, query = bench.kernel_eligible_app().split("; ", 1)
+    n_keys = sizes.get("nfa_partitions", PK_PARTITIONS)
+    batch = sizes.get("nfa_batch", PK_BATCH)
+    define, query = kernel_eligible_app().split("; ", 1)
     app = f"{define}; partition with (key of Txn) begin {query} end;"
     kern = run_pattern(env, app, n_keys, batch, kernels="nfa")
     xla = run_pattern(env, app, n_keys, batch)
@@ -467,7 +498,7 @@ def phase_kernels(env, sizes):
         f"and the whole engine state bit-identical to XLA")
 
     # bank: one aggregation, LONG sums and extrema + an exact float sum
-    n_ev = sizes.get("bank_events", bench.PK_BANK_EVENTS)
+    n_ev = sizes.get("bank_events", PK_BANK_EVENTS)
     rng = np.random.default_rng(env["seed"] + 3)
     t_base = 1_600_000_000_000
     ts = np.sort(t_base + rng.integers(0, 8_000, n_ev)).astype(np.int64)
@@ -515,8 +546,8 @@ def phase_kernels(env, sizes):
         f"{len(kern_rows)} bucket rows bit-identical to XLA")
 
     # scan: the hot-key router's scan under Zipf keys
-    keys = sizes.get("scan_keys", bench.HK_KEYS)
-    n_ev = sizes.get("scan_batch", bench.HK_BATCH)
+    keys = sizes.get("scan_keys", HK_KEYS)
+    n_ev = sizes.get("scan_batch", HK_BATCH)
     rng = np.random.default_rng(env["seed"] + 4)
     scan_batches = []
     for i in range(4):

@@ -30,6 +30,7 @@ from ..index import ModuleIndex
 #: package is host-side and free to use numpy
 SCANNED = (
     "siddhi_tpu/core/emit_queue.py",
+    "siddhi_tpu/core/device_pipeline.py",
     "siddhi_tpu/core/device_single.py",
     "siddhi_tpu/core/dense_pattern.py",
     "siddhi_tpu/ops/device_query.py",

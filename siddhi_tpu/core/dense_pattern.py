@@ -38,6 +38,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from siddhi_tpu.core import event as ev
+from siddhi_tpu.core.device_pipeline import DevicePipeline
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.exceptions import (
     SiddhiAppCreationError,
@@ -49,7 +50,6 @@ from siddhi_tpu.observability.trace import (
     span,
 )
 from siddhi_tpu.query_api import AttrType, StateInputStream, Variable
-from siddhi_tpu.util.faults import notify_listeners
 
 log = logging.getLogger("siddhi_tpu")
 
@@ -232,41 +232,19 @@ class DensePatternRuntime:
     def __init__(self, engine, out_stream_id: str,
                  emit: Callable[[EventBatch], None],
                  key_fn: Optional[Callable] = None,
-                 mesh=None, app_context=None, emit_depth=1,
-                 ingest_depth=1):  # int or 'auto'
-        from siddhi_tpu.core.emit_queue import EmitQueue, EmitStats
-        from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats
-
+                 mesh=None, app_context=None):
         self.engine = engine
         self.out_stream_id = out_stream_id
         self.emit_cb = emit
         self.key_fn = key_fn
         self.mesh = mesh
-        self.emit_stats = EmitStats()
-        self._app_context = app_context  # exception-listener channel
-        # cycle-correlated span tracer (observability/trace.py), shared
-        # per app; dense spans carry the engine kind (shard when meshed)
-        self.tracer = getattr(app_context, "tracer", None)
-        self.engine_kind = "dense" if mesh is None else "shard"
-        # @app:faults harness: wired onto the engine (the step hook
-        # reads engine.faults) and the emit queue (drain retry +
-        # isolation); None when chaos testing is off
-        self.faults = getattr(app_context, "fault_injector", None)
-        if self.faults is not None:
-            engine.faults = self.faults
-        self.emit_queue = EmitQueue(depth=emit_depth, stats=self.emit_stats,
-                                    faults=self.faults,
-                                    on_fault=self._on_fault)
-        # ingest staging window (@app:execution('tpu', ingest.depth='N')):
-        # depth 2 defers each batch's match-count fetch until the next
-        # batch's H2D puts + step dispatch are in flight; depth 1
-        # (default) finishes inline, matching synchronous timing.  The
-        # engine carries the stats ref so staged_put counts device puts.
-        self.ingest_stats = IngestStats()
-        engine.ingest_stats = self.ingest_stats
-        self.ingest_stage = IngestStage(
-            depth=ingest_depth, stats=self.ingest_stats, faults=self.faults,
-            on_fault=self._on_fault)
+        # count gate, emit queue, drain(), fault isolation
+        # (core/device_pipeline.py); dense spans carry the engine kind
+        # (shard when meshed).  The dense state is all int32: nothing
+        # here to poison, so no quarantine.
+        self.pipeline = DevicePipeline(
+            app_context, "dense" if mesh is None else "shard")
+        self.pipeline.attach(self, engine)
         self._sharded: Optional[Dict[str, object]] = None
         if mesh is not None:
             from siddhi_tpu.parallel.mesh import ShardedPatternEngine
@@ -539,26 +517,16 @@ class DensePatternRuntime:
 
     # -- event path ----------------------------------------------------------
 
-    def _begin_cycle(self, n: int):
-        """One sampled-or-None cycle token per junction batch."""
-        return (self.tracer.begin_cycle(self.engine_kind, n)
-                if self.tracer is not None else None)
-
     def receive_keyed(self, stream_key: str, cur: EventBatch, keys):
         """The partitioned receiver's entry (core/partition.py): the
         cycle begins here, where the batch enters the engine, so the
         interning of its keys is a span of the cycle; the ingest span
         starts after it, where it always has."""
-        tok = self._begin_cycle(len(cur))
-        try:
+        with self.pipeline.cycle(len(cur)) as tok:
             part = self.intern_keys(keys)
             if tok is not None:
                 tok.ingest_begins()
             self._advance(stream_key, cur, part, keys, tok)
-        except BaseException:
-            if tok is not None:
-                tok.raised()
-            raise
 
     def process_stream_batch(self, stream_key: str, batch: EventBatch,
                              part: Optional[np.ndarray] = None,
@@ -573,13 +541,8 @@ class DensePatternRuntime:
         if n == 0:
             return
         # the ingest span starts here, at receive time
-        tok = self._begin_cycle(n)
-        try:
+        with self.pipeline.cycle(n) as tok:
             self._advance(stream_key, cur, part, keys, tok)
-        except BaseException:
-            if tok is not None:
-                tok.raised()
-            raise
 
     def _advance(self, stream_key: str, cur: EventBatch, part, keys, tok):
         eng = self.engine
@@ -618,60 +581,13 @@ class DensePatternRuntime:
             else:
                 with tok.step_wait():
                     self._check_overflow()
-        from siddhi_tpu.core.emit_queue import PendingEmit
-
-        # clock sampled at RECEIVE time: the finish step may run a batch
-        # later (ingest.depth > 1) but replays the synchronous `now`
-        now = (self._app_context.timestamp_generator.current_time()
-               if self._app_context is not None else None)
-
-        def _finish(p=pending, t=ts, k=keys, n=now, tk=tok):
-            if p is None:
-                c = 0
-            elif tk is None:
-                c = p.resolve()
-            else:
-                with tk.step_wait():
-                    c = p.resolve()
-            if tk is not None:
-                # match-count gate resolved: the jitted step finished
-                tk.step_done(c)
-            if c == 0:
-                self.emit_queue.skip()
-                return
-            self.emit_queue.push(PendingEmit(
-                p.device_arrays(),
-                lambda host, pp=p, tt=t, kk=k, nn=n: self._emit_deferred(
-                    pp, tt, kk, host, now=nn),
-                trace=tk))
-
-        # the match-count fetch (resolve) is the blocking device sync;
-        # staging it lets batch N+1's H2D puts + step dispatch go out
-        # before batch N's count scalar is fetched
-        self.ingest_stage.submit(
-            pending.probe() if pending is not None else None, _finish,
-            trace=tok)
-
-    def drain(self):
-        """Flush barrier: materialize and emit every queued match batch
-        (one coalesced transfer) — called wherever host code could
-        observe emit timing (snapshot/restore, timer fires, purges,
-        shutdown).  The ingest stage flushes first: staged batches must
-        enqueue (or skip) before the emit queue drains, preserving the
-        synchronous callback order."""
-        self.ingest_stage.flush()
-        self.emit_queue.drain()
-
-    def _on_fault(self, e: Exception):
-        """Emit-queue / ingest-stage fault channel: surface isolated
-        drain, count-gate and callback failures to the app's exception
-        listeners — with or without the @app:faults harness."""
-        # freeze the span ring: the post-mortem shows the cycles that
-        # led into the isolated failure
-        if self.tracer is not None:
-            self.tracer.dump(f"onerror-isolation:{type(e).__name__}")
-        notify_listeners(
-            getattr(self._app_context, "exception_listeners", None), e)
+        # clock sampled at RECEIVE time: the count gate may resolve a
+        # batch later (ingest.depth > 1) but replays the synchronous `now`
+        now = self.pipeline.now()
+        self.pipeline.submit(
+            tok, pending,
+            lambda host: self._emit_deferred(pending, ts, keys, host,
+                                             now=now))
 
     def _emit_deferred(self, pending, ts, keys, host_arrays, now=None):
         ev_idx, out = pending.materialize(host_arrays)
@@ -758,13 +674,7 @@ class DensePatternRuntime:
             # listeners observe lost-match capacity pressure (the
             # reference's runtime ExceptionListener channel,
             # SiddhiAppRuntimeImpl.handleRuntimeExceptionWith:827)
-            listeners = getattr(self._app_context, "exception_listeners",
-                                None) if self._app_context else None
-            for listener in listeners or ():
-                try:
-                    listener(SiddhiAppRuntimeError(msg))
-                except Exception:  # a bad listener must not kill the flow
-                    log.exception("exception listener failed")
+            self.pipeline.notify(SiddhiAppRuntimeError(msg))
             self._ovf_warned = total
 
     def close(self):

@@ -1,0 +1,274 @@
+"""One device pipeline: the path from "step dispatched" to "rows at the
+callback", written once for every device runtime.
+
+Each runtime shell (core/device_single.py, core/dense_pattern.py,
+core/fused_graph.py, core/hotkey_router.py, devtable/join.py) owns what
+is particular to it — converting a junction batch to columns, interning,
+routing, chunking, building the output ``EventBatch`` — and holds one
+``DevicePipeline`` for the protocol they all share:
+
+- the sampled-or-None cycle token of a batch, closed as raised when an
+  exception leaves the batch path (:meth:`DevicePipeline.cycle`);
+- the count gate: the blocking fetch of a step's match count, staged
+  behind the next batch's dispatch (core/ingest_stage.py), then either a
+  counted skip or a device-resident ``PendingEmit`` in the bounded emit
+  queue (core/emit_queue.py) — :meth:`DevicePipeline.submit`;
+- the flush barrier, ingest stage before emit queue
+  (:meth:`DevicePipeline.drain`);
+- fault isolation: a batch that dies in the gate, the drain or the
+  callback freezes the span ring and reaches the app's exception
+  listeners (:meth:`DevicePipeline.on_fault`);
+- the whole-state NaN/Inf quarantine, armed only while a
+  ``state.poison`` fault is watched (:meth:`DevicePipeline.quarantine`).
+
+A change to this seam — where the host waits for the count gate, what
+rides its fetch — is a change for every runtime and every benchmark
+cell.  The two multiplex groups (multiplex/) have no ingest stage and
+guard poison per seat; they keep their own wiring.
+"""
+
+from __future__ import annotations
+
+import logging
+from typing import Callable, Optional, Sequence
+
+from siddhi_tpu.core.emit_queue import (
+    EmitQueue,
+    EmitStats,
+    PendingEmit,
+    fetch_coalesced,
+)
+from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats
+from siddhi_tpu.util import faults as _faults
+
+log = logging.getLogger("siddhi_tpu")
+
+
+class CountGate:
+    """The ``pending`` of a step whose outputs are one device count
+    scalar and a fixed list of device arrays (the hot-key scan, the
+    devtable probe).  The engines' own deferred emits
+    (``DeferredDenseEmit``, ``DeferredDeviceEmit``, the fused graph's)
+    carry the same three methods."""
+
+    __slots__ = ("count", "arrays")
+
+    def __init__(self, count, arrays: Sequence):
+        self.count = count
+        self.arrays = arrays
+
+    def probe(self):
+        return self.count
+
+    def resolve(self) -> int:
+        return int(fetch_coalesced([self.count])[0])
+
+    def device_arrays(self) -> Sequence:
+        return self.arrays
+
+
+class _Cycle:
+    """A sampled cycle's ``with`` block: hands out the token and closes
+    it as raised when an exception leaves the batch path."""
+
+    __slots__ = ("tok",)
+
+    def __init__(self, tok):
+        self.tok = tok
+
+    def __enter__(self):
+        return self.tok
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if exc_type is not None:
+            self.tok.raised()
+        return False
+
+
+class _NoCycle:
+    """What an unsampled cycle gets: one shared object, nothing
+    allocated; ``with pipeline.cycle(n) as tok`` binds ``tok`` to None."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_CYCLE = _NoCycle()
+
+
+class DevicePipeline:
+    """Count gate, emit push, fault isolation and poison quarantine of
+    one device runtime.  Everything comes from the ``app_context``: the
+    tracer, the ``@app:faults`` injector, the exception listeners, the
+    clock and the ``emit.depth`` / ``ingest.depth`` of
+    ``@app:execution``.  Without a context (a runtime built by hand in a
+    test) it is depth 1, untraced, with no injector, listeners or clock.
+    """
+
+    def __init__(self, app_context=None, engine_kind: str = "device"):
+        self._ctx = app_context
+        # labels this runtime's spans (observability/trace.py)
+        self.engine_kind = engine_kind
+        self.tracer = getattr(app_context, "tracer", None)
+        # @app:faults(...) injector: arms the ingest.put / emit.drain /
+        # state.poison sites.  Isolation works with or without it.
+        self.faults = getattr(app_context, "fault_injector", None)
+        self.emit_stats = EmitStats()
+        self.emit_queue = EmitQueue(
+            depth=getattr(app_context, "tpu_emit_depth", 1),
+            stats=self.emit_stats, faults=self.faults,
+            on_fault=self.on_fault)
+        # ingest staging window (@app:execution('tpu', ingest.depth='N')):
+        # depth 2 defers each batch's count-gate fetch until the NEXT
+        # batch's H2D put + step dispatch are in flight; depth 1 (the
+        # default) finishes inline, identical to synchronous ingest
+        self.ingest_stats = IngestStats()
+        self.ingest_stage = IngestStage(
+            depth=getattr(app_context, "tpu_ingest_depth", 1),
+            stats=self.ingest_stats, faults=self.faults,
+            on_fault=self.on_fault)
+        # last known-poison-free host copy of the quarantined state,
+        # kept only while a state.poison fault is watched
+        self._last_good = None
+
+    def attach(self, shell, engine=None) -> None:
+        """Wire a runtime shell to this pipeline.  The shell keeps, as
+        plain aliases, the attributes the statistics feed, the planner
+        and the tests read off a runtime; the engine carries the stats
+        ref so ``staged_put`` (ops layer) counts its device puts, and
+        the injector its step hook reads."""
+        for name in ("engine_kind", "tracer", "faults", "emit_stats",
+                     "emit_queue", "ingest_stats", "ingest_stage", "drain"):
+            setattr(shell, name, getattr(self, name))
+        if engine is not None:
+            engine.ingest_stats = self.ingest_stats
+            if self.faults is not None:
+                engine.faults = self.faults
+
+    def now(self) -> Optional[int]:
+        """The app clock, for the shell to sample at RECEIVE time and
+        bind into its ``deliver``: a deferred emit replays with the
+        ``now`` the synchronous path would have read (time-based rate
+        limiters key their period grid off it)."""
+        tg = getattr(self._ctx, "timestamp_generator", None)
+        return tg.current_time() if tg is not None else None
+
+    # -- the batch path ------------------------------------------------------
+
+    def cycle(self, n: int, kind: Optional[str] = None):
+        """``with pipeline.cycle(n) as tok``: one sampled-or-None cycle
+        token per junction batch, its ingest span starting here."""
+        if self.tracer is None:
+            return _NO_CYCLE
+        tok = self.tracer.begin_cycle(kind or self.engine_kind, n)
+        return _NO_CYCLE if tok is None else _Cycle(tok)
+
+    def submit(self, tok, pending, deliver: Optional[Callable]) -> None:
+        """Stage one dispatched step.  ``pending`` has ``probe()``,
+        ``resolve() -> int`` and ``device_arrays()`` (None: the batch
+        made no device work); ``deliver(host_arrays)`` materializes and
+        emits the batch once its arrays are fetched.
+
+        The count-gate fetch (``resolve``) is what blocks on the device;
+        staging it lets batch N+1's H2D put + step dispatch go out
+        before batch N's scalar is fetched."""
+        queue = self.emit_queue
+
+        def finish():
+            if pending is None:
+                c = 0
+            elif tok is None:
+                c = pending.resolve()
+            else:
+                with tok.step_wait():
+                    c = pending.resolve()
+            if tok is not None:
+                # count gate resolved: the jitted step finished
+                tok.step_done(c)
+            if c == 0:
+                queue.skip()
+                return
+            queue.push(PendingEmit(pending.device_arrays(), deliver,
+                                   trace=tok))
+
+        self.ingest_stage.submit(
+            pending.probe() if pending is not None else None, finish,
+            trace=tok)
+
+    def drain(self) -> None:
+        """Flush barrier: materialize and emit every queued batch (one
+        coalesced transfer).  Called wherever host code could observe
+        emit timing — snapshot/restore, timer fires, rate-limiter
+        decisions, pull queries, purges, shutdown, debugger.  The ingest
+        stage flushes first: staged batches must enqueue (or skip)
+        before the emit queue drains, preserving the synchronous
+        callback order."""
+        self.ingest_stage.flush()
+        self.emit_queue.drain()
+
+    # -- faults --------------------------------------------------------------
+
+    def notify(self, e: BaseException) -> None:
+        """Feed a handled failure to the app's exception listeners."""
+        _faults.notify_listeners(
+            getattr(self._ctx, "exception_listeners", None), e)
+
+    def on_fault(self, e: BaseException) -> None:
+        """A batch just died in isolation (count gate, drain or
+        callback): freeze the span ring so the post-mortem shows the
+        cycles leading up to it, then tell the listeners."""
+        if self.tracer is not None:
+            self.tracer.dump(f"onerror-isolation:{type(e).__name__}")
+        self.notify(e)
+
+    def quarantine(self, state, init: Callable,
+                   put_back: Optional[Callable] = None):
+        """NaN/Inf quarantine of a whole device state, active only while
+        a ``state.poison`` fault is watched.  Poisons ``state`` when the
+        fault trips, then scans it; on detection puts the last clean
+        host copy back (``put_back(host_state)``; by default every leaf
+        through ``jnp.asarray``) or, when there is none, starts from
+        ``init()``.  Returns ``(state, poisoned)``: the caller drops the
+        corrupted batch's outputs when ``poisoned``
+        (:meth:`drop_poisoned`)."""
+        fi = self.faults
+        if fi is None or not fi.watches("state.poison"):
+            return state, False
+        if fi.poisoned("state.poison"):
+            state = _faults.poison_state(state)
+        if not _faults.state_has_poison(state):
+            self._last_good = _faults.host_copy(state)
+            return state, False
+        fi.stats.poison_quarantines += 1
+        if self._last_good is not None:
+            log.error("%s state poisoned (NaN/Inf); quarantining batch "
+                      "and re-materializing last clean state",
+                      self.engine_kind)
+            if put_back is not None:
+                return put_back(self._last_good), True
+            import jax
+            import jax.numpy as jnp
+
+            return jax.tree_util.tree_map(jnp.asarray, self._last_good), True
+        log.error("%s state poisoned (NaN/Inf) with no clean copy; "
+                  "quarantining batch and re-initializing",
+                  self.engine_kind)
+        return init(), True
+
+    def drop_poisoned(self, tok) -> None:
+        """The shell drops the quarantined batch's outputs: tombstone
+        its cycle in the step and freeze the ring."""
+        if tok is not None:
+            tok.aborted("step")
+        if self.tracer is not None:
+            self.tracer.dump("poison-quarantine")
+
+    def forget_clean_copy(self) -> None:
+        """A restore replaced the state: the quarantine's copy is of a
+        state that no longer exists."""
+        self._last_good = None

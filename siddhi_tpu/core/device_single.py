@@ -33,16 +33,10 @@ from typing import Callable, Dict, Optional
 import numpy as np
 
 from siddhi_tpu.core import event as ev
-from siddhi_tpu.core.emit_queue import EmitQueue, EmitStats, PendingEmit
+from siddhi_tpu.core.device_pipeline import DevicePipeline
 from siddhi_tpu.core.event import EventBatch
-from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats
 from siddhi_tpu.core.exceptions import SiddhiAppRuntimeError
 from siddhi_tpu.observability.trace import STAGE_CONVERT, span
-from siddhi_tpu.util.faults import notify_listeners
-
-import logging
-
-log = logging.getLogger("siddhi_tpu")
 
 
 class DeviceQueryRuntime:
@@ -66,87 +60,27 @@ class DeviceQueryRuntime:
     scheduler contract)."""
 
     def __init__(self, engine, out_stream_id: str,
-                 emit: Callable[[EventBatch], None], emit_depth=1,
-                 clock: Optional[Callable[[], int]] = None, faults=None,
-                 ingest_depth=1, tracer=None,  # depths: int or 'auto'
-                 listeners=None):
+                 emit: Callable[[EventBatch], None], app_context=None):
         self.engine = engine
-        self._listeners = listeners  # the app's exception listeners
         self.out_stream_id = out_stream_id
         self.emit_cb = emit
         self.state = engine.init_state()
-        # cycle-correlated span tracer (observability/trace.py), wired by
-        # the planner; the engine kind labels this runtime's spans
-        self.tracer = tracer
-        self.engine_kind = getattr(engine, "engine_kind", "device")
         self.step_invocations = 0  # proof the jitted path ran (tests)
-        self.emit_stats = EmitStats()
-        # @app:faults(...) injector: arms the emit.drain/state.poison
-        # sites.  The isolation hook below works with or without it: a
-        # failing drain batch is logged + fed to exception listeners
-        # instead of killing the app
-        self.faults = faults
-        self.emit_queue = EmitQueue(depth=emit_depth, stats=self.emit_stats,
-                                    faults=faults, on_fault=self._on_fault)
-        # ingest staging window (@app:execution('tpu', ingest.depth='N')):
-        # depth 2 defers each batch's count-gate fetch until the NEXT
-        # batch's H2D put + step dispatch are in flight, overlapping
-        # transfer with compute; depth 1 (default) finishes inline —
-        # identical timing to synchronous ingest.  The engine carries the
-        # stats ref so staged_put (ops layer) counts its device puts.
-        self.ingest_stats = IngestStats()
-        engine.ingest_stats = self.ingest_stats
-        self.ingest_stage = IngestStage(
-            depth=ingest_depth, stats=self.ingest_stats, faults=faults,
-            on_fault=self._on_fault)
-        # last known-poison-free host copy of the device state, kept
-        # only while a state.poison fault is armed (quarantine source)
-        self._last_good = None
-        # app clock sampled at ENQUEUE time: deferred emits replay with
-        # the `now` the synchronous path would have used (time-based
-        # rate limiters key their period grid off it)
-        self.clock = clock
+        # count gate, emit queue, drain(), fault isolation, poison
+        # quarantine (core/device_pipeline.py); the engine kind labels
+        # this runtime's spans
+        self.pipeline = DevicePipeline(
+            app_context, getattr(engine, "engine_kind", "device"))
+        self.pipeline.attach(self, engine)
 
-    def _on_fault(self, e: BaseException):
-        # a batch just died in isolation (@OnError route): freeze the
-        # span ring so the post-mortem shows the cycles leading up to it
-        if self.tracer is not None:
-            self.tracer.dump(f"onerror-isolation:{type(e).__name__}")
-        notify_listeners(self._listeners, e)
-
-    def _poison_guard(self) -> bool:
-        """NaN/Inf quarantine, active only while a ``state.poison``
-        fault is armed.  Poisons the state when the fault trips, then
-        scans it; on detection, re-materializes from the last clean host
-        copy (or re-initializes) and reports True so the caller drops
-        the corrupted batch's outputs."""
-        fi = self.faults
-        if fi is None or not fi.watches("state.poison"):
-            return False
-        from siddhi_tpu.util import faults as _faults
-
-        if fi.poisoned("state.poison"):
-            self.state = _faults.poison_state(self.state)
-        if not _faults.state_has_poison(self.state):
-            self._last_good = _faults.host_copy(self.state)
-            return False
-        fi.stats.poison_quarantines += 1
+    def _put_back(self, host_state):
+        """A host copy of the state back onto the device (quarantine,
+        restore)."""
         eng = self.engine
-        if self._last_good is not None:
-            log.error("device state poisoned (NaN/Inf); quarantining "
-                      "batch and re-materializing last clean state")
-            if hasattr(eng, "put_state"):  # sharded: restore placement
-                self.state = eng.put_state(self._last_good)
-            else:
-                jnp = eng.jnp
-                self.state = {
-                    k: jnp.asarray(v) for k, v in self._last_good.items()
-                }
-        else:
-            log.error("device state poisoned (NaN/Inf) with no clean "
-                      "copy; quarantining batch and re-initializing")
-            self.state = eng.init_state()
-        return True
+        if hasattr(eng, "put_state"):  # sharded: restore placement
+            return eng.put_state(host_state)
+        jnp = eng.jnp
+        return {k: jnp.asarray(v) for k, v in host_state.items()}
 
     # -- event path ----------------------------------------------------------
 
@@ -162,19 +96,12 @@ class DeviceQueryRuntime:
         n = len(cur)
         if n == 0:
             return
-        # one sampled-or-None cycle token per junction batch: ingest
-        # span starts here, at receive time
-        tok = (self.tracer.begin_cycle(self.engine_kind, n)
-               if self.tracer is not None else None)
-        try:
+        with self.pipeline.cycle(n) as tok:
             self._advance(cur, n, keys, tok)
-        except BaseException:
-            if tok is not None:
-                tok.raised()
-            raise
 
     def _advance(self, cur: EventBatch, n: int, keys, tok):
         eng = self.engine
+        pipe = self.pipeline
         with span(STAGE_CONVERT, n):
             cols = {
                 a: np.asarray(cur.columns[a])
@@ -184,54 +111,17 @@ class DeviceQueryRuntime:
         self.state, pending = eng.process_batch_deferred(
             self.state, cols, ts, part_keys=keys)
         self.step_invocations += 1
-        if self._poison_guard():
+        self.state, poisoned = pipe.quarantine(
+            self.state, eng.init_state, self._put_back)
+        if poisoned:
             # corrupted step: state was re-materialized from the last
             # clean copy; this batch's device outputs are quarantined
-            if tok is not None:
-                tok.aborted("step")
-            if self.tracer is not None:
-                self.tracer.dump("poison-quarantine")
+            pipe.drop_poisoned(tok)
             return
-        # `now` is the clock the SYNCHRONOUS path would have read; the
-        # finish step may run a batch later (ingest.depth > 1), so it is
-        # captured here, at receive time
-        now = self.clock() if self.clock is not None else None
-
-        def _finish(p=pending, t=now, tk=tok):
-            if p is None:
-                c = 0
-            elif tk is None:
-                c = p.resolve()
-            else:
-                with tk.step_wait():
-                    c = p.resolve()
-            if tk is not None:
-                # count gate resolved: the jitted step finished
-                tk.step_done(c)
-            if c == 0:
-                self.emit_queue.skip()
-                return
-            self.emit_queue.push(PendingEmit(
-                p.device_arrays(),
-                lambda host, pp=p, tt=t: self._emit_deferred(pp, host, tt),
-                trace=tk))
-
-        # the count-gate fetch (resolve) is what blocks on the device;
-        # staging it lets batch N+1's H2D put + step dispatch go out
-        # before batch N's scalar is fetched
-        self.ingest_stage.submit(
-            pending.probe() if pending is not None else None, _finish,
-            trace=tok)
-
-    def drain(self):
-        """Flush barrier: materialize and emit every queued batch (one
-        coalesced transfer).  Called wherever host code could observe
-        emit timing — snapshot/restore, timer fires, rate-limiter
-        decisions, pull queries, shutdown, debugger.  The ingest stage
-        flushes first: staged batches must enqueue (or skip) before the
-        emit queue drains, preserving the synchronous callback order."""
-        self.ingest_stage.flush()
-        self.emit_queue.drain()
+        now = pipe.now()  # sampled at receive time, bound into deliver
+        pipe.submit(
+            tok, pending,
+            lambda host: self._emit_deferred(pending, host, now))
 
     def _emit_deferred(self, pending, host_arrays, now=None):
         out_cols, out_ts, keys = pending.materialize(host_arrays)
@@ -299,11 +189,9 @@ class DeviceQueryRuntime:
 
     def restore(self, state: Dict):
         self.drain()
-        self._last_good = None
+        self.pipeline.forget_clean_copy()
         eng = self.engine
-        if hasattr(eng, "put_state"):  # sharded: restore the placement
-            self.state = eng.put_state(state["device_state"])
-        else:
+        if not hasattr(eng, "put_state"):
             # row-count guard: a snapshot persisted under a SHARDED
             # layout (@app:execution devices='N') has N extra scratch
             # rows and a shard-major row bijection — restoring it here
@@ -316,10 +204,7 @@ class DeviceQueryRuntime:
                         f"{np.asarray(v).shape}; this engine expects "
                         f"{expect[k]} — persist and restore must use "
                         "the same @app:execution devices count")
-            jnp = eng.jnp
-            self.state = {
-                k: jnp.asarray(v) for k, v in state["device_state"].items()
-            }
+        self.state = self._put_back(state["device_state"])
         eng.host_restore(state["host"])
 
 

@@ -29,19 +29,14 @@ through the emit queue's coalesced drain.
 
 from __future__ import annotations
 
-import logging
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 from siddhi_tpu.core import event as ev
-from siddhi_tpu.core.emit_queue import EmitQueue, EmitStats, PendingEmit
+from siddhi_tpu.core.device_pipeline import DevicePipeline
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.exceptions import SiddhiAppRuntimeError
-from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats
-from siddhi_tpu.util.faults import notify_listeners
-
-log = logging.getLogger("siddhi_tpu")
 
 
 class FusedChainRuntime:
@@ -49,74 +44,22 @@ class FusedChainRuntime:
     chain out, everything between device-resident."""
 
     def __init__(self, graph, out_stream_id: str,
-                 emit: Callable[[EventBatch], None], emit_depth=1,
-                 clock: Optional[Callable[[], int]] = None, faults=None,
-                 ingest_depth=1, tracer=None,  # depths: int or 'auto'
-                 listeners=None):
+                 emit: Callable[[EventBatch], None], app_context=None):
         self.graph = graph
-        self._listeners = listeners  # the app's exception listeners
         self.out_stream_id = out_stream_id
         self.emit_cb = emit
         self.state = graph.init_state()
-        # cycle-correlated span tracer (observability/trace.py); one
-        # fused dispatch is one cycle, labeled with the 'fused' kind
-        self.tracer = tracer
-        self.engine_kind = "fused"
         self.step_invocations = 0  # fused program dispatches (tests)
         # hops kept device-resident: (stages - 1) junction dispatches
-        # saved per fused dispatch (the bench's fusedHops counter)
+        # saved per fused dispatch (the fusedHops counter)
         self.hops_per_dispatch = (
             len(graph.stages) + (1 if graph.dense is not None else 0) - 1)
         self.fused_hops = 0
-        self.emit_stats = EmitStats()
-        self.faults = faults
-        graph.faults = faults
-        self.emit_queue = EmitQueue(depth=emit_depth, stats=self.emit_stats,
-                                    faults=faults, on_fault=self._on_fault)
-        self.ingest_stats = IngestStats()
-        graph.ingest_stats = self.ingest_stats
-        self.ingest_stage = IngestStage(
-            depth=ingest_depth, stats=self.ingest_stats, faults=faults,
-            on_fault=self._on_fault)
-        # last known-poison-free host copy of the chain state (only
-        # while a state.poison fault is armed — quarantine source)
-        self._last_good = None
-        self.clock = clock
-
-    def _on_fault(self, e: BaseException):
-        # freeze the span ring: the post-mortem shows the cycles that
-        # led into the isolated failure
-        if self.tracer is not None:
-            self.tracer.dump(f"onerror-isolation:{type(e).__name__}")
-        notify_listeners(self._listeners, e)
-
-    def _poison_guard(self) -> bool:
-        """NaN/Inf quarantine over the WHOLE chain's state tuple, active
-        only while a ``state.poison`` fault is armed (the
-        DeviceQueryRuntime contract, applied chain-wide)."""
-        fi = self.faults
-        if fi is None or not fi.watches("state.poison"):
-            return False
-        from siddhi_tpu.util import faults as _faults
-
-        if fi.poisoned("state.poison"):
-            self.state = _faults.poison_state(self.state)
-        if not _faults.state_has_poison(self.state):
-            self._last_good = _faults.host_copy(self.state)
-            return False
-        fi.stats.poison_quarantines += 1
-        jnp = self.graph.jnp
-        if self._last_good is not None:
-            log.error("fused chain state poisoned (NaN/Inf); quarantining "
-                      "batch and re-materializing last clean state")
-            self.state = tuple(
-                {k: jnp.asarray(v) for k, v in st.items()}
-                for st in self._last_good)
-        else:
-            log.error("fused chain state poisoned (NaN/Inf) with no clean "
-                      "copy; quarantining batch and re-initializing")
-            self.state = self.graph.init_state()
-        return True
+        # count gate, emit queue, drain(), fault isolation, poison
+        # quarantine (core/device_pipeline.py); one fused dispatch is
+        # one cycle, labeled with the 'fused' kind
+        self.pipeline = DevicePipeline(app_context, "fused")
+        self.pipeline.attach(self, graph)
 
     # -- event path ----------------------------------------------------------
 
@@ -125,18 +68,11 @@ class FusedChainRuntime:
         n = len(cur)
         if n == 0:
             return
-        # one sampled-or-None cycle token per junction batch: ingest
-        # span starts here, at receive time
-        tok = (self.tracer.begin_cycle(self.engine_kind, n)
-               if self.tracer is not None else None)
-        try:
+        with self.pipeline.cycle(n) as tok:
             self._advance(cur, tok)
-        except BaseException:
-            if tok is not None:
-                tok.raised()
-            raise
 
     def _advance(self, cur: EventBatch, tok):
+        pipe = self.pipeline
         head = self.graph.stages[0]
         cols = {
             a: cur.columns[a]
@@ -147,37 +83,16 @@ class FusedChainRuntime:
             self.state, cols, ts)
         self.step_invocations += 1
         self.fused_hops += self.hops_per_dispatch
-        if self._poison_guard():
-            if tok is not None:
-                tok.aborted("step")
-            if self.tracer is not None:
-                self.tracer.dump("poison-quarantine")
+        # NaN/Inf quarantine over the WHOLE chain's state tuple
+        self.state, poisoned = pipe.quarantine(
+            self.state, self.graph.init_state)
+        if poisoned:
+            pipe.drop_poisoned(tok)
             return
-        now = self.clock() if self.clock is not None else None
-
-        def _finish(p=pending, t=now, tk=tok):
-            c = 0 if p is None else p.resolve()
-            if tk is not None:
-                # count gate resolved: the fused step finished
-                tk.step_done(c)
-            if c == 0:
-                self.emit_queue.skip()
-                return
-            self.emit_queue.push(PendingEmit(
-                p.device_arrays(),
-                lambda host, pp=p, tt=t: self._emit_deferred(pp, host, tt),
-                trace=tk))
-
-        self.ingest_stage.submit(
-            pending.probe() if pending is not None else None, _finish,
-            trace=tok)
-
-    def drain(self):
-        """Flush barrier (snapshot/restore, rate-limiter fires, pull
-        queries, shutdown): staged batches enqueue first, then one
-        coalesced drain emits everything in the synchronous order."""
-        self.ingest_stage.flush()
-        self.emit_queue.drain()
+        now = pipe.now()  # sampled at receive time, bound into deliver
+        pipe.submit(
+            tok, pending,
+            lambda host: self._emit_deferred(pending, host, now))
 
     def _emit_deferred(self, pending, host_arrays, now=None):
         out_cols, out_ts = pending.materialize(host_arrays)
@@ -240,7 +155,7 @@ class FusedChainRuntime:
 
     def restore(self, state: Dict):
         self.drain()
-        self._last_good = None
+        self.pipeline.forget_clean_copy()
         g = self.graph
         jnp = g.jnp
         chain = state["chain"]

@@ -21,17 +21,18 @@ partitioned ``DensePatternRuntime`` and, per junction cycle:
    keys are packed on the scan's ``[H, n_pad]`` slot axis and advance
    in O(log n) scan depth via ONE jitted step.
 
-The hot path rides the dense runtime's OWN machinery: its
-``IngestStage`` (``staged_put`` H2D + count-gate staging), its
-count-gated async ``EmitQueue`` (the only device→host path — state
-handoffs at promote/demote fetch through a queued ``PendingEmit`` +
-drain barrier, so the fault harness's ``emit.drain`` retry ladder and
-isolation cover them), and the ``state.poison`` quarantine idiom of
-``core/device_single.py``.  Emission content is bit-identical to the
-host engine on the eligible class; within one cycle the cold
-sub-batch's rows emit before the hot sub-batch's (each internally in
-event order, carrying ``aux["event_indices"]`` for consumers that need
-the interleaved order).
+The hot path rides the dense runtime's OWN ``DevicePipeline``
+(core/device_pipeline.py): its ``IngestStage`` (``staged_put`` H2D +
+count-gate staging), its count-gated async ``EmitQueue`` (the only
+device→host path — state handoffs at promote/demote fetch through a
+queued ``PendingEmit`` + drain barrier, so the fault harness's
+``emit.drain`` retry ladder and isolation cover them), and its
+``state.poison`` quarantine, here over the scan state.  Emission
+content is bit-identical to the host engine on the eligible class;
+within one cycle the cold sub-batch's rows emit before the hot
+sub-batch's (each internally in event order, carrying
+``aux["event_indices"]`` for consumers that need the interleaved
+order).
 
 Snapshot/restore demotes every hot key first, so the persisted tree is
 a plain dense snapshot (plus sketch counters) — restorable by older
@@ -125,12 +126,15 @@ class HotKeyRouterRuntime:
     snapshot and stats wiring see one runtime."""
 
     def __init__(self, dense, scan_engine, *, promote: float,
-                 demote: float, app_context=None, query_name: str = ""):
+                 demote: float, query_name: str = ""):
         self._dense = dense
         self._scan = scan_engine
+        # the hot path rides the dense runtime's pipeline: one ingest
+        # stage and one emit queue keep cold and hot emits FIFO.  Its
+        # quarantine guards the scan state (the dense state is int32).
+        self._pipe = dense.pipeline
         self._promote_at = float(promote)
         self._demote_at = float(demote)
-        self._app_context = app_context
         self.query_name = query_name
         self.hot_stats = HotKeyStats()
         self.sketch = SpaceSavingSketch(
@@ -140,8 +144,6 @@ class HotKeyRouterRuntime:
         self._free_slots: List[int] = list(
             range(scan_engine.n_slots))[::-1]
         self._state = scan_engine.init_state()
-        self._last_good = None  # poison-quarantine restore point
-        self.faults = dense.faults
         self.lowered_to = "hotkey"
 
     # everything not overridden IS the dense runtime's behavior —
@@ -193,8 +195,8 @@ class HotKeyRouterRuntime:
         def grab(host):
             got["host"] = list(host)
 
-        self._dense.emit_queue.push(PendingEmit(list(arrays), grab))
-        self._dense.drain()
+        self._pipe.emit_queue.push(PendingEmit(list(arrays), grab))
+        self._pipe.drain()
         if "host" not in got:
             self.hot_stats.handoff_aborts += 1
             return None
@@ -358,55 +360,37 @@ class HotKeyRouterRuntime:
                      cur: EventBatch, keys):
         # hot-path batches get their own cycle tokens (engine kind
         # 'hotkey'); the cold remainder traced under 'dense' already
-        tracer = self._dense.tracer
-        tok = (tracer.begin_cycle("hotkey", len(cur))
-               if tracer is not None else None)
-        try:
+        with self._pipe.cycle(len(cur), kind="hotkey") as tok:
             self._advance_hot(slot_pos, cur, keys, tok)
-        except BaseException:
-            if tok is not None:
-                tok.raised()
-            raise
 
     def _advance_hot(self, slot_pos, cur: EventBatch, keys, tok):
-        from siddhi_tpu.core.emit_queue import PendingEmit
+        from siddhi_tpu.core.device_pipeline import CountGate
         from siddhi_tpu.core.ingest_stage import staged_put
 
-        dense, scan = self._dense, self._scan
+        dense, scan, pipe = self._dense, self._scan, self._pipe
         cols = {a: c for a, c in cur.columns.items()
                 if a in scan.base._lane_dtype}
         ts = cur.timestamps
         put, meta = scan.pack_cycle(slot_pos, cols, ts)
-        put_dev = staged_put(put, faults=self.faults,
-                             stats=dense.ingest_stats)
+        put_dev = staged_put(put, faults=pipe.faults,
+                             stats=pipe.ingest_stats)
         self._state, emit_dev, n_rows = scan.dispatch(
             self._state, put_dev)
-        self._poison_guard()
+        # the scan's emit counts were computed before the poison: the
+        # state is put back, the cycle's outputs still go out
+        self._state, _poisoned = pipe.quarantine(
+            self._state, scan.init_state)
         n_routed = int(sum(len(p) for p in slot_pos.values()))
         self.hot_stats.routed_events += n_routed
         self.hot_stats.routed_cycles += 1
         dense.step_invocations += 1
-        now = (self._app_context.timestamp_generator.current_time()
-               if self._app_context is not None else None)
+        now = pipe.now()  # sampled at receive time, bound into deliver
         out_cols = {attr: cur.columns[attr]
                     for _nm, attr in self._out_pairs()}
-        keys_ref = keys
-
-        def _finish(nr=n_rows, emit=emit_dev, m=meta, oc=out_cols,
-                    t=ts, k=keys_ref, nw=now, tk=tok):
-            c = int(nr)
-            if tk is not None:
-                # row-count gate resolved: the scan cycle finished
-                tk.step_done(c)
-            if c == 0:
-                dense.emit_queue.skip()
-                return
-            dense.emit_queue.push(PendingEmit(
-                [emit],
-                lambda host: self._emit_hot(host, m, oc, t, k, nw),
-                trace=tk))
-
-        dense.ingest_stage.submit(n_rows, _finish, trace=tok)
+        pipe.submit(
+            tok, CountGate(n_rows, [emit_dev]),
+            lambda host: self._emit_hot(host, meta, out_cols, ts, keys,
+                                        now))
 
     def _out_pairs(self):
         """(output name, final-node attribute) pairs — eligibility
@@ -435,31 +419,6 @@ class HotKeyRouterRuntime:
         if now is not None:
             mb.aux["emit_now"] = now
         self._dense.emit_cb(mb)
-
-    # -- poison quarantine (device_single._poison_guard idiom) ---------------
-
-    def _poison_guard(self):
-        fi = self.faults
-        if fi is None or not fi.watches("state.poison"):
-            return
-        from siddhi_tpu.util import faults as _faults
-
-        if fi.poisoned("state.poison"):
-            self._state = _faults.poison_state(self._state)
-        if _faults.state_has_poison(self._state):
-            fi.stats.poison_quarantines += 1
-            log.warning(
-                "hotkey router '%s': NaN/Inf poison in scan state; "
-                "restoring last good copy", self.query_name)
-            if self._last_good is not None:
-                jnp = self._scan.jnp
-                self._state = {
-                    k: jnp.asarray(v) for k, v in self._last_good.items()
-                }
-            else:
-                self._state = self._scan.init_state()
-        else:
-            self._last_good = _faults.host_copy(self._state)
 
     # -- barriers / lifecycle ------------------------------------------------
 
@@ -493,7 +452,7 @@ class HotKeyRouterRuntime:
         self._free_slots = list(range(self._scan.n_slots))[::-1]
         self._state = self._scan.init_state()
         self._scan.base_ts = None
-        self._last_good = None
+        self._pipe.forget_clean_copy()
         sk = state.get("hotkey_sketch")
         self.sketch = SpaceSavingSketch(cap=self.sketch.cap,
                                         decay=self.sketch.decay)

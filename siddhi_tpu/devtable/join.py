@@ -39,11 +39,10 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from siddhi_tpu.core import event as ev
-from siddhi_tpu.core.emit_queue import EmitQueue, EmitStats, PendingEmit, fetch_coalesced
+from siddhi_tpu.core.device_pipeline import CountGate, DevicePipeline
 from siddhi_tpu.core.event import EventBatch
-from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats, staged_put
+from siddhi_tpu.core.ingest_stage import staged_put
 from siddhi_tpu.planner.expr import N_KEY, TS_KEY
-from siddhi_tpu.util.faults import notify_listeners
 
 log = logging.getLogger("siddhi_tpu")
 
@@ -59,12 +58,10 @@ class DevTableJoinRuntime:
 
     def __init__(self, name: str, stream_side, table_side, stream_is_left: bool,
                  condition, key_expr, cond_stream_lanes: Dict[str, Tuple[str, np.dtype]],
-                 out_stream_id: str, emit, emit_depth=1, ingest_depth=1,
-                 clock=None, faults=None, tracer=None, listeners=None):
+                 out_stream_id: str, emit, app_context):
         import jax
 
         self.name = name
-        self._listeners = listeners  # the app's exception listeners
         self.stream_side = stream_side
         self.table_side = table_side
         self.table = table_side.table
@@ -76,19 +73,13 @@ class DevTableJoinRuntime:
         self._cond_lanes = cond_stream_lanes
         self.out_stream_id = out_stream_id
         self.emit = emit
-        self.clock = clock
-        self.faults = faults
-        self.tracer = tracer
-        self.engine_kind = "devtable_join"
         self.step_invocations = 0
         self.probe_invocations = 0
         self.host_fallback_batches = 0
-        self.emit_stats = EmitStats()
-        self.emit_queue = EmitQueue(depth=emit_depth, stats=self.emit_stats,
-                                    faults=faults, on_fault=self._on_fault)
-        self.ingest_stats = IngestStats()
-        self.ingest_stage = IngestStage(depth=ingest_depth, stats=self.ingest_stats,
-                                        faults=faults, on_fault=self._on_fault)
+        # count gate, emit queue, drain(), fault isolation
+        # (core/device_pipeline.py); the table owns its own puts
+        self.pipeline = DevicePipeline(app_context, "devtable_join")
+        self.pipeline.attach(self)
         left, right = ((stream_side, table_side) if stream_is_left
                        else (table_side, stream_side))
         self._out_names = [
@@ -117,11 +108,6 @@ class DevTableJoinRuntime:
 
         self._probe = jax.jit(probe)
 
-    def _on_fault(self, e):
-        if self.tracer is not None:
-            self.tracer.dump(f"onerror-isolation:{type(e).__name__}")
-        notify_listeners(self._listeners, e)
-
     # -- batch entry ------------------------------------------------------
 
     def process_stream_batch(self, batch: EventBatch):
@@ -129,27 +115,20 @@ class DevTableJoinRuntime:
         n = len(cur)
         if n == 0:
             return
-        now = self.clock() if self.clock is not None else 0
+        now = self.pipeline.now()  # sampled at receive time
         host_reason = self._host_only_reason(cur)
         if host_reason is not None:
             # pipeline barrier first so the synchronous host emit cannot
             # overtake queued device emits from earlier batches
-            self.ingest_stage.flush()
-            self.emit_queue.drain()
+            self.drain()
             self.host_fallback_batches += 1
             self._host_join(cur, now)
             return
-        tok = (self.tracer.begin_cycle(self.engine_kind, n)
-               if self.tracer is not None else None)
-        try:
+        with self.pipeline.cycle(n) as tok:
             keys = self._event_keys(cur)
             for lo in range(0, n, self.MAX_CHUNK):
                 hi = min(n, lo + self.MAX_CHUNK)
                 self._dispatch_chunk(cur, keys, lo, hi, now, tok)
-        except BaseException:
-            if tok is not None:
-                tok.raised()
-            raise
 
     def _host_only_reason(self, cur: EventBatch) -> Optional[str]:
         if self.table.demoted:
@@ -193,20 +172,11 @@ class DevTableJoinRuntime:
             self.tracer.record_span(STAGE_TABLE_PROBE, self.engine_kind,
                                     t0, time.perf_counter(), n_events=cn)
 
-        def finish():
-            c = int(fetch_coalesced([count_d])[0])
-            if tok is not None:
-                tok.step_done(c)
-            if c == 0:
-                self.emit_queue.skip()
-                return
-            arrays = [mask_d] + [gathered_d[nm] for nm in self._tbl_names]
-            self.emit_queue.push(PendingEmit(
-                arrays,
-                lambda host: self._materialize(host, cur, lo, now),
-                trace=tok))
-
-        self.ingest_stage.submit(count_d, finish, trace=tok)
+        self.pipeline.submit(
+            tok,
+            CountGate(count_d, [mask_d] + [gathered_d[nm]
+                                           for nm in self._tbl_names]),
+            lambda host: self._materialize(host, cur, lo, now))
 
     # -- deferred materialization (runs on fetched HOST arrays) -----------
 
@@ -269,10 +239,6 @@ class DevTableJoinRuntime:
         self.emit(out)
 
     # -- barrier contract ---------------------------------------------------
-
-    def drain(self):
-        self.ingest_stage.flush()
-        self.emit_queue.drain()
 
     def snapshot(self) -> Dict:
         self.drain()

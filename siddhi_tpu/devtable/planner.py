@@ -190,14 +190,7 @@ def try_plan_devtable_join(name: str, j, left, right, condition,
     return DevTableJoinRuntime(
         name, stream_side, table_side, stream_is_left,
         condition, key_c, used,
-        out_stream_id=f"#join_{name}", emit=emit,
-        emit_depth=app_context.tpu_emit_depth,
-        ingest_depth=app_context.tpu_ingest_depth,
-        clock=app_context.timestamp_generator.current_time,
-        faults=app_context.fault_injector,
-        tracer=app_context.tracer,
-        listeners=app_context.exception_listeners,
-    )
+        out_stream_id=f"#join_{name}", emit=emit, app_context=app_context)
 
 
 def plan_devtable_mutation(name: str, out, out_def, out_scope: Scope,

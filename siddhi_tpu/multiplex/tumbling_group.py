@@ -431,7 +431,7 @@ class TumblingMultiplexGroup:
     def _poison_guard(self, seat: _TenantSeat) -> None:
         """Quarantine a poisoned tenant's rows without touching the
         other seats — the packed-bank analog of
-        ``DeviceQueryRuntime._poison_guard``."""
+        ``DevicePipeline.quarantine``."""
         adapter = seat.adapter
         fi = adapter.faults if adapter is not None else None
         if fi is None or not fi.watches("state.poison"):

@@ -4,9 +4,9 @@ One :class:`Tracer` per app runtime hands out a monotonically
 increasing cycle id per device-engine batch (``begin_cycle``).  The id
 rides a :class:`CycleToken` through the existing async machinery:
 
-    receiver / runtime ``process_stream_batch`` -> begin_cycle (t0)
+    DevicePipeline.cycle (a runtime's batch)    -> begin_cycle (t0)
     IngestStage.submit (put + step dispatched)  -> tok.dispatched()  [ingest span]
-    runtime ``_finish`` (count gate resolved)   -> tok.step_done(n)  [step span]
+    DevicePipeline.submit (count gate resolved) -> tok.step_done(n)  [step span]
     EmitQueue.drain (batch materialized)        -> tok.emitted(t0)   [emit span]
 
 Inside those three, one flat vocabulary tiles the rest of a batch's

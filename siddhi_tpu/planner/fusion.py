@@ -511,12 +511,7 @@ def _wire_chain(app, qp, entries, run: List[int], hops: List[str],
 
     runtime = FusedChainRuntime(
         graph, f"#fused_{tail_name}", emit=lambda b: qr.process(b, 0),
-        emit_depth=ctx.tpu_emit_depth,
-        clock=ctx.timestamp_generator.current_time,
-        faults=ctx.fault_injector,
-        ingest_depth=ctx.tpu_ingest_depth,
-        tracer=ctx.tracer,
-        listeners=ctx.exception_listeners)
+        app_context=ctx)
     qr.device_runtime = runtime
 
     head_q, _hn = entries[run[0]]

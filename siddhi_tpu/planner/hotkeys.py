@@ -72,7 +72,7 @@ def build_hotkey_router(app, st, dense_runtime, query_name: str):
     return HotKeyRouterRuntime(
         dense_runtime, scan,
         promote=ctx.hotkey_promote, demote=ctx.hotkey_demote,
-        app_context=ctx, query_name=query_name)
+        query_name=query_name)
 
 
 def try_wrap_hotkey(app, st, dense_runtime, query_name: str

@@ -709,10 +709,7 @@ class QueryPlanner:
             mesh = self.app.tpu_mesh
         runtime = DensePatternRuntime(
             engine, f"#matches_{name}", emit=lambda b: qr.process(b, 0),
-            key_fn=key_fn, mesh=mesh, app_context=self.app.app_context,
-            emit_depth=self.app.app_context.tpu_emit_depth,
-            ingest_depth=self.app.app_context.tpu_ingest_depth,
-        )
+            key_fn=key_fn, mesh=mesh, app_context=self.app.app_context)
         if getattr(selector, "partition_axis", False):
             # idle-key purges must also drop the shared selector's
             # per-key aggregation state (host: the instance dies whole)
@@ -945,12 +942,7 @@ class QueryPlanner:
 
         runtime = DeviceQueryRuntime(
             engine, f"#device_{name}", emit=lambda b: qr.process(b, 0),
-            emit_depth=self.app.app_context.tpu_emit_depth,
-            clock=self.app.app_context.timestamp_generator.current_time,
-            faults=self.app.app_context.fault_injector,
-            ingest_depth=self.app.app_context.tpu_ingest_depth,
-            tracer=self.app.app_context.tracer,
-            listeners=self.app.app_context.exception_listeners)
+            app_context=self.app.app_context)
         qr.device_runtime = runtime
         if subscribe:
             junction = self.app.junction_for_input(s)

@@ -1,0 +1,362 @@
+"""The device pipeline's contract, over every runtime that holds one.
+
+``core/device_pipeline.py`` owns the path from "step dispatched" to
+"rows at the callback" for the five device runtimes — window
+(core/device_single.py), dense (core/dense_pattern.py), fused
+(core/fused_graph.py), hot-key (core/hotkey_router.py) and devtable
+(devtable/join.py).  Each case below drives one of them through
+``SiddhiManager`` on a small app and holds it to the same four
+promises:
+
+- a failing count gate drops that batch and nothing else: it is counted
+  as ``droppedBatches``, reaches the exception listener, and the runtime
+  goes on serving;
+- ``drain()`` finishes staged batches before it drains emits, so the
+  callbacks at ``ingest.depth='2'``, ``emit.depth='4'`` equal those at
+  depth 1, in order;
+- a sampled cycle holds one ``step_wait`` and one ``step`` span;
+- an exception that leaves the shell's own work closes the cycle's
+  token as raised.
+"""
+
+import numpy as np
+import pytest
+
+from siddhi_tpu import SiddhiManager
+from siddhi_tpu.core.device_pipeline import DevicePipeline
+from siddhi_tpu.core.event import EventBatch
+from siddhi_tpu.observability import trace as trace_mod
+
+
+def _batch(attrs, cols, i):
+    n = len(next(iter(cols.values())))
+    return EventBatch("S", attrs, cols,
+                      np.full(n, 1_000 + i * 10, dtype=np.int64))
+
+
+def window_batch(i, n=16):
+    rng = np.random.default_rng(10 + i)
+    return _batch(["k", "v"], {
+        "k": (np.arange(n) % 4).astype(np.int32),
+        "v": rng.uniform(0.0, 20.0, n).astype(np.float32)}, i)
+
+
+def keyed_batch(i, n=32):
+    rng = np.random.default_rng(20 + i)
+    # 24 keys over 32 rows: eight keys come twice in every batch
+    return _batch(["k", "v"], {
+        "k": np.arange(n, dtype=np.int64) % 24,
+        "v": rng.uniform(0.0, 20.0, n)}, i)
+
+
+def skewed_batch(i, n=40):
+    rng = np.random.default_rng(30 + i)
+    # key 7 takes four rows in five: promoted by the second batch
+    k = np.where(np.arange(n) % 5 < 4, 7, np.arange(n) % 24)
+    return _batch(["k", "v"], {
+        "k": k.astype(np.int64), "v": rng.uniform(0.0, 20.0, n)}, i)
+
+
+def probe_batch(i, n=16):
+    rng = np.random.default_rng(40 + i)
+    return _batch(["k", "x"], {
+        "k": (np.arange(n) % 12).astype(np.int32),   # keys 8..11 miss
+        "x": rng.uniform(0.0, 20.0, n).astype(np.float32)}, i)
+
+
+def fill_table(rt):
+    rt.get_input_handler("Ins").send_batch(EventBatch(
+        "Ins", ["k", "v"],
+        {"k": np.arange(8, dtype=np.int32),
+         "v": np.arange(8, dtype=np.float32) * 1.5},
+        np.full(8, 900, dtype=np.int64)))
+    # the insert query is a device runtime of its own: at ingest.depth 2
+    # its rows reach the table at its next batch or a barrier
+    rt.drain_device_emits()
+
+
+PATTERN = ("define stream S (k long, v double); partition with (k of S) "
+           "begin @info(name='q') from every a=S[v > 8.0] -> b=S[v > 12.0] "
+           "select b.v as bv insert into Out; end;")
+
+# kind -> (execution options, other annotations, body, batch maker,
+#          set-up after start, engine kind of its spans, lowering,
+#          where the shell's own work is made to raise)
+CASES = {
+    "window": (
+        "", "",
+        "define stream S (k int, v float); @info(name='q') "
+        "from S#window.length(4) select k, sum(v) as s insert into Out;",
+        window_batch, None, "device", "device",
+        lambda shell: (shell.engine, "process_batch_deferred")),
+    "dense": (
+        "partitions='64'", "", PATTERN, keyed_batch, None, "dense", "dense",
+        lambda shell: (shell.engine, "process_deferred")),
+    "fused": (
+        "", "@app:fuse ",
+        "define stream S (k int, v float); "
+        "@info(name='q1') from S[v > 4.0] select k, v insert into Mid; "
+        "@info(name='q') from Mid[v > 8.0] select k, v insert into Out;",
+        window_batch, None, "fused", "fused",
+        lambda shell: (shell.graph, "process_batch_deferred")),
+    "hotkey": (
+        "partitions='64', instances='16'",
+        "@app:hotkeys(k='4', promote='0.3', demote='0.1') ",
+        PATTERN, skewed_batch, None, "hotkey", "hotkey",
+        lambda shell: (shell._scan, "pack_cycle")),
+    "devtable": (
+        "", "@app:devtables(capacity='64') ",
+        "define stream S (k int, x float); "
+        "define stream Ins (k int, v float); "
+        "@PrimaryKey('k') define table T (k int, v float); "
+        "from Ins insert into T; "
+        "@info(name='q') from S join T as t on S.k == t.k "
+        "select S.k as k, S.x as x, t.v as v insert into Out;",
+        probe_batch, fill_table, "devtable_join", "devtable",
+        lambda shell: (shell, "_event_keys")),
+}
+KINDS = list(CASES)
+N_BATCHES = 6
+
+
+class Deployed:
+    """One case's app, started, with its rows, its listener and its
+    runtime shell at hand."""
+
+    def __init__(self, kind, depths="", trace=""):
+        (opts, extra, body, self.make, setup, self.engine_kind, lowering,
+         self.breaks) = CASES[kind]
+        opts = ", ".join(o for o in (opts, depths) if o)
+        self.manager = SiddhiManager()
+        self.rt = rt = self.manager.create_siddhi_app_runtime(
+            f"@app:name('pipe_{kind}') @app:playback "
+            f"@app:execution('tpu'{', ' + opts if opts else ''}) "
+            + extra + trace + body)
+        self.rows, self.errors = [], []
+        rt.add_callback("Out", lambda evs: self.rows.extend(
+            (e.timestamp, tuple(e.data)) for e in evs))
+        rt.add_exception_listener(self.errors.append)
+        rt.start()
+        assert rt.lowering()["q"] == lowering, rt.lowering()
+        if setup is not None:
+            setup(rt)
+        self.handler = rt.get_input_handler("S")
+        queries = dict(rt.query_runtimes)
+        for pr in rt.partitions.values():
+            queries.update(getattr(pr, "dense_query_runtimes", {}))
+        self.shell = (getattr(queries["q"], "device_runtime", None)
+                      or queries["q"].pattern_processor)
+        self.pipe = self.shell.pipeline
+        assert isinstance(self.pipe, DevicePipeline)
+
+    def send(self, i):
+        """Batch ``i``; returns the rows it delivered at once."""
+        before = len(self.rows)
+        self.handler.send_batch(self.make(i))
+        return self.rows[before:]
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.manager.shutdown()
+        return False
+
+
+def reference(kind):
+    """Rows per batch at depth 1, where every batch delivers inline."""
+    with Deployed(kind) as app:
+        per_batch = [app.send(i) for i in range(N_BATCHES)]
+        if kind == "hotkey":
+            assert app.shell.hot_stats.routed_cycles > 0
+    assert all(per_batch[2:]), "every batch past warm-up owes rows"
+    return per_batch
+
+
+class _BrokenGate:
+    """A pending whose count-gate fetch fails, as XLA reports an
+    asynchronous step failure."""
+
+    def __init__(self, pending):
+        self.pending = pending
+
+    def probe(self):
+        return self.pending.probe()
+
+    def resolve(self):
+        raise RuntimeError("injected count-gate failure")
+
+    def device_arrays(self):
+        return self.pending.device_arrays()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_failing_count_gate_drops_one_batch(kind):
+    want = reference(kind)
+    with Deployed(kind) as app:
+        got = [app.send(i) for i in range(3)]
+        real, broken = app.pipe.submit, []
+
+        def breaking(tok, pending, deliver):
+            broken.append(pending)
+            real(tok, _BrokenGate(pending), deliver)
+
+        app.pipe.submit = breaking
+        got.append(app.send(3))
+        del app.pipe.submit
+        got += [app.send(i) for i in range(4, N_BATCHES)]
+        assert broken, "the batch never reached the pipeline"
+        assert got[3] == [] and want[3]
+        assert got[:3] + got[4:] == want[:3] + want[4:]
+        assert app.pipe.ingest_stats.dropped_batches == len(broken)
+        assert app.shell.ingest_stats is app.pipe.ingest_stats
+        assert [str(e) for e in app.errors] == [
+            "injected count-gate failure"] * len(broken)
+        assert len(app.pipe.ingest_stage) == 0
+        assert len(app.pipe.emit_queue) == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_drain_finishes_staged_batches_before_emits(kind):
+    want = [r for rows in reference(kind) for r in rows]
+    with Deployed(kind, depths="ingest.depth='2', emit.depth='4'") as app:
+        for i in range(N_BATCHES):
+            app.send(i)
+        stage, queue = app.pipe.ingest_stage, app.pipe.emit_queue
+        assert stage.depth == 2 and queue.depth == 4
+        assert len(stage) == 1, "the last batch's count gate is staged"
+        assert len(app.rows) < len(want)
+        app.shell.drain()
+        assert len(stage) == 0 and len(queue) == 0
+        assert app.rows == want
+        assert app.pipe.ingest_stats.dropped_batches == 0 and not app.errors
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_sampled_cycle_holds_one_step_wait_and_one_step(kind, monkeypatch):
+    waits = []
+    real = trace_mod.annotation
+
+    def counting(stage):
+        if stage == trace_mod.ANNOTATION_STEP_WAIT:
+            waits.append(stage)
+        return real(stage)
+
+    with Deployed(kind, trace="@app:trace(sample='1', cycles='16') ") as app:
+        for i in range(3):
+            app.send(i)
+        recorder = app.rt.app_context.tracer.recorder
+        seen = set(recorder.cycle_groups())
+        monkeypatch.setattr(trace_mod, "annotation", counting)
+        assert app.send(3)
+        monkeypatch.undo()
+        # (a table probe is a free-running span under a cycle id of its
+        # own: not a batch cycle)
+        cycles = {cid: spans for cid, spans in recorder.cycle_groups().items()
+                  if cid not in seen
+                  and {s[1] for s in spans} != {"table.probe"}}
+        ours = [spans for spans in cycles.values()
+                if {s[2] for s in spans} == {app.engine_kind}]
+        assert ours, {s[2] for spans in cycles.values() for s in spans}
+        for spans in cycles.values():
+            assert [s[1] for s in spans].count("step") == 1
+            assert [s[1] for s in spans].count("ingest") == 1
+        assert len(waits) == len(cycles)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_exception_in_the_shell_closes_the_token_as_raised(kind, monkeypatch):
+    want = reference(kind)
+    with Deployed(kind, trace="@app:trace(sample='1', cycles='16') ") as app:
+        got = [app.send(i) for i in range(3)]
+        owner, name = app.breaks(app.shell)
+
+        def raising(*a, **kw):
+            raise ValueError("injected conversion failure")
+
+        monkeypatch.setattr(owner, name, raising)
+        app.send(3)
+        monkeypatch.undo()
+        # the junction handed the error to the listeners; the cycle is
+        # closed where it died and is no longer the thread's open one
+        assert [str(e) for e in app.errors] == ["injected conversion failure"]
+        assert getattr(trace_mod._open, "tok", None) is None
+        recorder = app.rt.app_context.tracer.recorder
+        dead = recorder.spans()[-1]
+        assert dead[1] == "ingest.aborted" and dead[2] == app.engine_kind
+        assert dead[0] == max(s[0] for s in recorder.spans())
+        with trace_mod.span("put", 8) as sp:
+            assert sp is None
+        if kind in ("fused", "devtable"):
+            # stateless per batch: the runtime serves on as if batch 3
+            # had never come
+            got += [app.send(i) for i in range(4, N_BATCHES)]
+            assert got == want[:3] + want[4:]
+        else:
+            assert app.send(4), "the runtime serves on"
+
+
+# -- the quarantine, on the pipeline alone ------------------------------------
+
+
+class _Poisoner:
+    """The two calls the quarantine makes of an ``@app:faults``
+    injector: the site is watched, and the second batch trips it."""
+
+    def __init__(self, trips):
+        self.trips = list(trips)
+        self.stats = type("S", (), {"poison_quarantines": 0})()
+
+    def watches(self, site):
+        return site == "state.poison"
+
+    def poisoned(self, site):
+        return self.trips.pop(0)
+
+
+def _chain(scale):
+    import jax.numpy as jnp
+
+    return ({"acc": jnp.arange(4, dtype=jnp.float32) * scale,
+             "n": jnp.arange(4, dtype=jnp.int32)},
+            {"ring": jnp.ones((2, 3), dtype=jnp.float32) * scale})
+
+
+@pytest.mark.parametrize("shape", ["dict", "tuple_of_dicts", "put_back"])
+def test_quarantine_puts_the_last_clean_state_back(shape):
+    """The shells' states: one dict of arrays (window, hot-key scan), a
+    tuple of them (fused chain), or whatever a ``put_back`` places (the
+    sharded engine's ``put_state``)."""
+    import jax
+
+    class Ctx:
+        fault_injector = _Poisoner([False, True, True])
+
+    pipe = DevicePipeline(Ctx(), "unit")
+    make = (lambda s: _chain(s)[0]) if shape == "dict" else _chain
+    placed = []
+    put_back = None
+    if shape == "put_back":
+        def put_back(host):
+            placed.append(host)
+            return make(1.0)
+
+    def fresh():
+        return make(0.0)
+
+    clean, poisoned = pipe.quarantine(make(1.0), fresh, put_back)
+    assert not poisoned and Ctx.fault_injector.stats.poison_quarantines == 0
+    state, poisoned = pipe.quarantine(make(2.0), fresh, put_back)
+    assert poisoned and Ctx.fault_injector.stats.poison_quarantines == 1
+    assert (jax.tree_util.tree_structure(state)
+            == jax.tree_util.tree_structure(clean))
+    for got, want in zip(jax.tree_util.tree_leaves(state),
+                         jax.tree_util.tree_leaves(clean)):
+        assert isinstance(got, jax.Array) and got.dtype == want.dtype
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert len(placed) == (1 if shape == "put_back" else 0)
+    # a restore replaced the state: nothing clean to go back to
+    pipe.forget_clean_copy()
+    state, poisoned = pipe.quarantine(make(3.0), fresh, put_back)
+    assert poisoned and Ctx.fault_injector.stats.poison_quarantines == 2
+    assert float(np.asarray(jax.tree_util.tree_leaves(state)[0]).sum()) == 0.0
