@@ -40,10 +40,9 @@ ALLOWLISTS = {
             "barrier: restore path, behind drain()",
         f"{_DP}:DensePatternRuntime.intern_keys":
             "ingest: host-side key interning before device routing",
-        f"{_DP}:DensePatternRuntime._intern_keys_dict":
-            "ingest: host-side key interning before device routing",
         f"{_DP}:DensePatternRuntime._rebuild_key_index":
-            "ingest: host-side key-index rebuild on purge",
+            "ingest: host-side key-index rebuild on purge or restore "
+            "(core/key_index.py holds the index itself: host numpy only)",
         f"{_DP}:DensePatternRuntime._advance":
             "ingest: converts HOST batch cols/ts before staged_put",
         f"{_DP}:DensePatternRuntime.purge_idle":

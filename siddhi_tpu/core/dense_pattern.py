@@ -44,6 +44,7 @@ from siddhi_tpu.core.exceptions import (
     SiddhiAppCreationError,
     SiddhiAppRuntimeError,
 )
+from siddhi_tpu.core.key_index import index_for
 from siddhi_tpu.observability.trace import (
     STAGE_CONVERT,
     STAGE_INTERN,
@@ -280,15 +281,14 @@ class DensePatternRuntime:
         self._row_keys: Dict = {}  # reverse map: engine row -> key value
         self._next_row = 0
         self._free_rows: List[int] = []
-        # sorted-key index backing the vectorized intern: _key_arr is the
-        # sorted array of known keys (NATIVE dtype — int64/'<U' — so
-        # searchsorted compares in C, not via boxed python objects),
-        # _key_row_arr the row per sorted position.  _key_rows stays the
-        # source of truth for snapshots/purges; the index is a
-        # rebuildable cache.
-        self._key_arr = np.empty(0, dtype=np.int64)
-        self._key_row_arr = np.empty(0, dtype=np.int32)
+        # the index backing the vectorized intern (core/key_index.py):
+        # None until the first key batch shows its dtype family, and
+        # for good in dict mode.  _key_rows stays the source of truth
+        # for snapshots/purges; the index is a rebuildable cache.
+        self._index = None
         self._vector_intern = True
+        self._intern_probe_lanes = 0  # lanes probed past their first slot
+        self._intern_new_keys = 0  # keys given a row
         # host-side per-row activity clock driving idle-key reclamation
         # (@purge on dense partitions; the instance path purges whole
         # PartitionInstances instead)
@@ -331,104 +331,89 @@ class DensePatternRuntime:
         """Partition-key values -> dense engine row ids (stable until the
         key is purged; shared by all source streams).
 
-        Vectorized: the batch is factorized once (np.unique), existing
-        keys resolve with one searchsorted against the sorted key index,
-        and only NEVER-SEEN keys take the python allocation path — so a
-        131k-event batch over warm keys costs O(n log n) numpy, not 131k
-        dict probes.
+        Vectorized: known keys resolve through the key index
+        (``core/key_index.py``: a hash table for integer keys, so a
+        warm 131k-event batch costs one gather and no sort; a sorted
+        array for strings and floats) and only NEVER-SEEN keys are
+        given rows, in ascending key order within a batch.
 
-        The sorted index only works while every key batch shares one
-        dtype family (all-int, all-string, ...).  Mixing families — e.g.
+        An index only works while every key batch shares one dtype
+        family (all-int, all-string, ...).  Mixing families — e.g.
         ``partition with (k of A, sym of B)`` with an int key on one
-        stream and a string on the other — would corrupt searchsorted
-        ordering (and 7 vs 7.0 alias under python hashing but not under
-        dtype promotion), so the runtime then degrades permanently to
-        the exact per-event dict intern."""
+        stream and a string on the other — has no common order (and
+        7 vs 7.0 alias under python hashing but not under dtype
+        promotion), so the runtime then degrades permanently to the
+        exact per-event dict intern."""
         arr = np.asarray(keys)
         with span(STAGE_INTERN, len(arr)):
             return self._intern(arr)
 
     def _intern(self, arr: np.ndarray) -> np.ndarray:
+        index = self._index
         if self._vector_intern:
             if arr.dtype.kind in ("O", "V"):
                 self._vector_intern = False
-            elif len(self._key_arr) == 0 and not self._key_rows:
+            elif index is None:
                 pass  # first batch adopts its dtype below
-            elif arr.dtype != self._key_arr.dtype:
-                if np.can_cast(arr.dtype, self._key_arr.dtype, "safe"):
-                    arr = arr.astype(self._key_arr.dtype)
-                elif np.can_cast(self._key_arr.dtype, arr.dtype, "safe"):
-                    self._key_arr = self._key_arr.astype(arr.dtype)
+            elif arr.dtype != index.dtype:
+                if np.can_cast(arr.dtype, index.dtype, "safe"):
+                    arr = arr.astype(index.dtype)
+                elif np.can_cast(index.dtype, arr.dtype, "safe"):
+                    index = self._index = index.widen(arr.dtype)
                 else:
                     log.warning(
                         "dense pattern: partition keys mix dtypes (%s vs "
                         "index %s); falling back to the exact dict intern",
-                        arr.dtype, self._key_arr.dtype)
+                        arr.dtype, index.dtype)
                     self._vector_intern = False
         if not self._vector_intern:
+            self._index = None  # dict mode is for good
             return self._intern_keys_dict(arr)
-        uniq, inv = np.unique(arr, return_inverse=True)
-        nu = len(uniq)
-        urows = np.empty(nu, dtype=np.int32)
-        if len(self._key_arr):
-            pos = np.searchsorted(self._key_arr, uniq)
-            pos_c = np.minimum(pos, len(self._key_arr) - 1)
-            found = self._key_arr[pos_c] == uniq
-            urows[found] = self._key_row_arr[pos_c[found]]
-            new_idx = np.flatnonzero(~found)
+        if index is None:
+            new_keys, inv = np.unique(arr, return_inverse=True)
+            rows = (-1 - inv).astype(np.int32)
         else:
-            new_idx = np.arange(nu)
-        if len(new_idx):
-            cap = self.engine.n_partitions
-            n_new = len(new_idx)
-            # bulk row allocation: recycled rows first, then a fresh range
-            take_free = min(len(self._free_rows), n_new)
-            fresh = n_new - take_free
-            if self._next_row + fresh > cap:
-                raise SiddhiAppRuntimeError(
-                    f"dense pattern: partition-key cardinality exceeded "
-                    f"capacity {cap} (raise it via "
-                    f"@app:execution('tpu', partitions='N') or enable "
-                    "@purge on the partition)")
-            row_ids = np.empty(n_new, dtype=np.int32)
-            if take_free:
-                row_ids[:take_free] = self._free_rows[-take_free:][::-1]
-                del self._free_rows[-take_free:]
-            if fresh:
-                row_ids[take_free:] = self._deal_rows(np.arange(
-                    self._next_row, self._next_row + fresh, dtype=np.int64)
-                ).astype(np.int32)
-                self._next_row += fresh
-            urows[new_idx] = row_ids
-            self._key_rows.update(
-                zip(uniq[new_idx].tolist(), row_ids.tolist()))
-            self._row_keys.update(
-                zip(row_ids.tolist(), uniq[new_idx].tolist()))
-            # merge the (sorted) new keys into the sorted index with an
-            # O(K+U) two-way merge (a full argsort of ~1M keys per batch
-            # would dominate the step); dtype promotes explicitly so
-            # widening string keys never truncate
-            new_keys = uniq[new_idx]
-            new_rows = urows[new_idx]
-            K, U = len(self._key_arr), len(new_keys)
-            if K == 0:
-                self._key_arr = new_keys.copy()
-                self._key_row_arr = new_rows.copy()
+            rows, new_keys, probed = index.lookup(arr)
+            self._intern_probe_lanes += probed
+        if len(new_keys):
+            # new keys take rows in ascending key order; their lanes
+            # hold -1 - (position in new_keys)
+            row_ids = self._take_rows(len(new_keys))
+            if index is None:
+                self._index = index_for(
+                    new_keys, row_ids, self.engine.n_partitions)
             else:
-                ins = np.searchsorted(self._key_arr, new_keys)
-                new_pos = ins + np.arange(U)
-                old_mask = np.ones(K + U, dtype=bool)
-                old_mask[new_pos] = False
-                dt = np.promote_types(self._key_arr.dtype, new_keys.dtype)
-                merged_keys = np.empty(K + U, dtype=dt)
-                merged_keys[new_pos] = new_keys
-                merged_keys[old_mask] = self._key_arr
-                merged_rows = np.empty(K + U, dtype=np.int32)
-                merged_rows[new_pos] = new_rows
-                merged_rows[old_mask] = self._key_row_arr
-                self._key_arr = merged_keys
-                self._key_row_arr = merged_rows
-        return urows[inv].astype(np.int32, copy=False)
+                index.insert(new_keys, row_ids)
+            key_list, row_list = new_keys.tolist(), row_ids.tolist()
+            self._key_rows.update(zip(key_list, row_list))
+            self._row_keys.update(zip(row_list, key_list))
+            missing = rows < 0
+            rows[missing] = row_ids[-1 - rows[missing]]
+        return rows
+
+    def _take_rows(self, n_new: int) -> np.ndarray:
+        """Rows for ``n_new`` never-seen keys: recycled rows first, then
+        a fresh range dealt across shards."""
+        cap = self.engine.n_partitions
+        take_free = min(len(self._free_rows), n_new)
+        fresh = n_new - take_free
+        if self._next_row + fresh > cap:
+            raise SiddhiAppRuntimeError(
+                f"dense pattern: partition-key cardinality exceeded "
+                f"capacity {cap} (raise it via "
+                f"@app:execution('tpu', partitions='N') or enable "
+                "@purge on the partition)")
+        row_ids = np.empty(n_new, dtype=np.int32)
+        if take_free:
+            row_ids[:take_free] = self._free_rows[-take_free:][::-1]
+            del self._free_rows[-take_free:]
+        if fresh:
+            row_ids[take_free:] = self._deal_rows(np.arange(
+                self._next_row, self._next_row + fresh, dtype=np.int64)
+            ).astype(np.int32)
+            self._next_row += fresh
+        self._intern_new_keys += n_new
+        return row_ids
 
     def _intern_keys_dict(self, keys) -> np.ndarray:
         """Exact per-event intern (hash semantics): the fallback when
@@ -436,49 +421,31 @@ class DensePatternRuntime:
         for the vectorized path."""
         out = np.zeros(len(keys), dtype=np.int32)
         rows = self._key_rows
-        cap = self.engine.n_partitions
         for i, k in enumerate(keys):
             row = rows.get(k)
             if row is None:
-                if self._free_rows:
-                    row = self._free_rows.pop()
-                elif self._next_row < cap:
-                    row = int(self._deal_rows(np.asarray(self._next_row)))
-                    self._next_row += 1
-                else:
-                    raise SiddhiAppRuntimeError(
-                        f"dense pattern: partition-key cardinality exceeded "
-                        f"capacity {cap} (raise it via "
-                        f"@app:execution('tpu', partitions='N') or enable "
-                        "@purge on the partition)")
+                row = int(self._take_rows(1)[0])
                 rows[k] = row
                 self._row_keys[row] = k
             out[i] = row
         return out
 
     def _rebuild_key_index(self):
-        """Rebuild the sorted intern index from _key_rows (after purge
-        or restore); degrades to dict mode when the stored keys do not
+        """Rebuild the intern index from _key_rows (after purge or
+        restore); degrades to dict mode when the stored keys do not
         form one sortable dtype family."""
-        if self._key_rows:
-            try:
-                karr = np.array(list(self._key_rows.keys()))
-            except ValueError:  # inhomogeneous keys
-                karr = None
-            if karr is None or karr.dtype.kind in ("O", "V"):
-                self._vector_intern = False
-                self._key_arr = np.empty(0, dtype=np.int64)
-                self._key_row_arr = np.empty(0, dtype=np.int32)
-                return
-            rarr = np.fromiter(
-                (self._key_rows[k] for k in self._key_rows), np.int32,
-                len(karr))
-            order = np.argsort(karr, kind="stable")
-            self._key_arr = karr[order]
-            self._key_row_arr = rarr[order]
-        else:
-            self._key_arr = np.empty(0, dtype=np.int64)
-            self._key_row_arr = np.empty(0, dtype=np.int32)
+        self._index = None
+        if not self._key_rows or not self._vector_intern:
+            return
+        try:
+            karr = np.array(list(self._key_rows.keys()))
+        except ValueError:  # inhomogeneous keys
+            karr = None
+        if karr is None or karr.dtype.kind in ("O", "V"):
+            self._vector_intern = False
+            return
+        rarr = np.fromiter(self._key_rows.values(), np.int32, len(karr))
+        self._index = index_for(karr, rarr, self.engine.n_partitions)
 
     def purge_idle(self, now: int, idle_ms: int):
         """Reclaim rows of keys idle for >= idle_ms: reset their device
@@ -658,6 +625,12 @@ class DensePatternRuntime:
             "active_instances": act,
             "dropped_instances": self.overflow_total(),
             "step_invocations": self.step_invocations,
+            "intern_index": (
+                "dict" if not self._vector_intern
+                else None if self._index is None  # no key seen yet
+                else self._index.kind),
+            "intern_probe_lanes": self._intern_probe_lanes,
+            "intern_new_keys": self._intern_new_keys,
         }
 
     def _check_overflow(self):
