@@ -1,154 +1,41 @@
-"""The plain references and the arithmetic of ``correct``, ``attempted``
-and ``failed``.  Nothing here imports the program: the references are
-numpy and plain Python over the batches the schedule re-makes from the
-seed.
+"""The arithmetic of ``correct``, ``attempted`` and ``failed``, and how a
+configuration's plain reference is found.  Nothing here imports the
+program.
 
-Every reference returns ``(bad, compared)``: the set of window batch
-indices whose answers differ, and the numbers compared, each as
-``(name, value, limit)`` — a number over its limit makes the run not
-correct.
+A reference is a file of its own, ``references/<reference.kind>.py``,
+with one function ``reference(spec, schedule, collector, n_sent, seed,
+rehearsal)``: ``spec`` is the configuration's ``reference`` block,
+``schedule`` re-makes every batch from the seed, ``collector`` holds the
+rows the callback kept and the row count of every batch.  It is numpy
+and plain Python, imports nothing of the program, and returns ``(bad,
+compared)``: the set of window batch indices whose answers differ, and
+the numbers compared, each as ``(name, value, limit)`` — a number over
+its limit makes the run not correct.
 """
 
 from __future__ import annotations
 
-import collections
+import importlib.util
+import os
 
 import numpy as np
 
 EPS32 = float(np.finfo(np.float32).eps)
+REFERENCES = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "references")
 
 
-def _chain_rows(events, states: int, within_ms: int):
-    """``every e1=[v>0] -> e2=[v>1 and v>e1.v] -> ... -> e<states>
-    within`` over one key's ``(n, ts, v)`` events in arrival order: a
-    match is ``(n, e1.v, e<states>.v)``."""
-    rows, pending = [], []   # pending: (e1.v, e1.ts, states matched)
-    for n, ts, v in events:
-        nxt = []
-        for v1, t1, k in pending:
-            if ts - t1 > within_ms:
-                continue
-            if v > k and v > v1:
-                if k + 1 == states:
-                    rows.append((n, v1, v))
-                    continue
-                k += 1
-            nxt.append((v1, t1, k))
-        if v > 0.0:
-            nxt.append((v, ts, 1))
-        pending = nxt
-    return rows
-
-
-def ref_pattern_chain(spec, schedule, collector, n_sent, seed, rehearsal):
-    """Every active key and a seeded sample of the swept ones through
-    ``_chain_rows`` over the first window pass and one seeded other
-    pass, payloads compared exactly; every other batch must deliver as
-    many rows as its twin in the first pass; no row may belong to a key
-    that was only swept; one key's rows arrive in event-time order."""
-    rng = np.random.default_rng(seed + 1)
-    per_pass = schedule.per_pass
-    active = schedule.active_keys
-    sample = np.concatenate([active, rng.choice(
-        np.setdiff1d(schedule.all_keys, active),
-        spec["rehearsal_swept_keys" if rehearsal else "swept_keys"],
-        replace=False)])
-    n_passes = -(-n_sent // per_pass)
-    passes = {0} | ({int(rng.integers(1, n_passes))} if n_passes > 1 else set())
-    checked = [n for p in sorted(passes)
-               for n in range(p * per_pass, min((p + 1) * per_pass, n_sent))]
-
-    by_key = {}
-    for n in checked:
-        b = schedule.batch(n)
-        keys, v = b.columns["key"], b.columns["v"]
-        for i in np.flatnonzero(np.isin(keys, sample)):
-            by_key.setdefault(int(keys[i]), []).append(
-                (n, int(b.timestamps[i]), float(v[i])))
-    want = [r for evs in by_key.values() for r in _chain_rows(
-        evs, spec["states"], spec["within_ms"])]
-
-    rows = collector.rows()
-    bad = set()
-    if rows is None:
-        got, strays, disorder = [], 0, 0
-    else:
-        keys = schedule.row_keys(rows)
-        pick = np.isin(rows["_n"], checked) & np.isin(keys, sample)
-        got = list(zip(rows["_n"][pick].tolist(),
-                       rows["v1"][pick].astype(np.float64).tolist(),
-                       rows["v16"][pick].astype(np.float64).tolist()))
-        stray = ~np.isin(keys, active)
-        strays = int(stray.sum())
-        bad |= set(rows["_n"][stray].tolist())
-        order = np.argsort(keys, kind="stable")
-        back = (np.diff(rows["_ts"][order]) < 0) & (np.diff(keys[order]) == 0)
-        disorder = int(back.sum())
-        bad |= set(rows["_n"][order][1:][back].tolist())
-    want_c, got_c = collections.Counter(want), collections.Counter(got)
-    differ = (want_c - got_c) + (got_c - want_c)   # rows, with multiplicity
-    bad |= {r[0] for r in differ}
-    uneven = [n for n in range(n_sent)
-              if collector.counts.get(n, 0)
-              != collector.counts.get(schedule.twin(n), 0)]
-    bad |= set(uneven)
-    compared = [
-        (f"sampled rows that differ from the reference ({len(sample)} keys,"
-         f" passes {sorted(passes)}, {len(want)} rows owed)",
-         sum(differ.values()), 0),
-        ("rows of keys that were only swept", strays, 0),
-        ("rows of one key out of event-time order", disorder, 0),
-        (f"batches whose row count differs from the first pass's "
-         f"({n_sent} batches)", len(uneven), 0),
-        # a run that owes nothing checks nothing: limit is at least one row
-        ("rows owed on the sample: none", int(not want), 0)]
-    if not want:
-        bad |= set(checked)
-    return bad, compared
-
-
-def ref_sliding_length(spec, schedule, collector, n_sent, seed, rehearsal):
-    """``#window.length(L) select symbol, sum(price), avg(volume),
-    timestamp`` in float64 by prefix sums, on the batches whose rows
-    were kept (the first, the last, a seeded sample between); every
-    other batch must deliver one row per tick."""
-    L, B = spec["length"], schedule.batch_events
-    limit = spec["rtol_eps32"] * EPS32
-    rows = collector.rows()
-    bad = {n for n in range(n_sent) if collector.counts.get(n, 0) != B}
-    uneven = len(bad)
-    worst, exact_off, checked = 0.0, 0, 0
-    for n in (np.unique(rows["_n"]).tolist() if rows is not None else []):
-        if n in bad or n >= n_sent:
-            continue
-        prev, cur = schedule.batch(n - 1).columns, schedule.batch(n).columns
-        got = {k: v[rows["_n"] == n] for k, v in rows.items()}
-        checked += 1
-        off = int((got["symbol"] != cur["symbol"]).sum()
-                  + (got["timestamp"] != cur["timestamp"]).sum())
-        err = 0.0
-        for out, col, div in (("total", spec["sum"], 1.0),
-                              ("avgVolume", spec["avg"], float(L))):
-            x = np.concatenate([prev[col][-(L - 1):], cur[col]]).astype(
-                np.float64)
-            c = np.concatenate([[0.0], np.cumsum(x)])
-            ref = (c[L:] - c[:-L]) / div
-            err = max(err, float(np.max(
-                np.abs(got[out].astype(np.float64) - ref)
-                / np.abs(ref).clip(1.0))))
-        worst = max(worst, err)
-        exact_off += off
-        if off or not err <= limit:
-            bad.add(n)
-    if not checked:
-        bad |= set(range(n_sent))
-    compared = [
-        (f"worst relative error of sum(price), avg(volume) "
-         f"({checked} batches of {n_sent})", worst, limit),
-        ("symbols and timestamps that differ", exact_off, 0),
-        ("batches that did not deliver one row per tick", uneven, 0),
-        ("batches checked against the reference: none", int(not checked), 0)]
-    return bad, compared
+def load_reference(kind: str):
+    """``references/<kind>.py``'s ``reference``; a kind with no file is
+    an error that names the file looked for."""
+    path = os.path.join(REFERENCES, kind + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"reference kind {kind!r}: no file {path}")
+    spec = importlib.util.spec_from_file_location("references." + kind, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.reference
 
 
 def judge(dep, schedule, window, reference, platform: str):

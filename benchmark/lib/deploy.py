@@ -92,6 +92,18 @@ class Collector(StreamCallback):
         return out
 
 
+def sender(handlers: dict):
+    """What the window calls with every batch.  One input stream: the
+    handler's bound ``send_batch`` itself, no wrapper and no lookup in
+    the timed path.  Several: each batch goes to the handler of its
+    ``stream_id``."""
+    if len(handlers) == 1:
+        (handler,) = handlers.values()
+        return handler.send_batch
+    sends = {stream: h.send_batch for stream, h in handlers.items()}
+    return lambda batch: sends[batch.stream_id](batch)
+
+
 class Deployment:
     def __init__(self, config, schedule, rehearsal: bool, traced: bool):
         from siddhi_tpu import SiddhiManager
@@ -114,7 +126,10 @@ class Deployment:
             schedule, config["reference"].get("edge_batches", 1), self.span)
         self.rt.add_callback(config["output"], self.collector)
         self.rt.start()
-        self.handler = self.rt.get_input_handler(config["stream"])
+        # ``stream``: one name, or a list where the app has several inputs
+        streams = config["stream"]
+        self.send = sender({s: self.rt.get_input_handler(s) for s in (
+            [streams] if isinstance(streams, str) else streams)})
         # every device engine of the app, whichever path it lowered to
         self.engines = [q.pattern_processor
                         for pr in self.rt.partitions.values()

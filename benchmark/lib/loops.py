@@ -15,7 +15,8 @@ SPIN_S = 0.010
 
 
 def run(dep, schedule, traffic: dict, seconds: float, send, profile=None):
-    """Drive the window.  ``send(batch)`` is ``handler.send_batch`` or
+    """Drive the window.  ``send(batch)`` is the deployment's ``send``
+    (with one input stream the handler's bound ``send_batch`` itself) or
     the control's rounding wrapper.  ``profile`` (traced runs) is an
     object with ``start()``, ``mark()`` and ``stop()``, called at batch
     boundaries: the profiler starts, one batch absorbs its first use on

@@ -105,6 +105,8 @@ def round_bf16(batch, columns):
 
     cols = dict(batch.columns)
     for c in columns:
+        if c not in cols:   # a column of another input stream
+            continue
         cols[c] = cols[c].astype(ml_dtypes.bfloat16).astype(cols[c].dtype)
     return EventBatch(batch.stream_id, batch.attribute_names, cols,
                       batch.timestamps)
@@ -146,12 +148,19 @@ def main(argv=None) -> int:
               os.path.join(BENCH, "layers")):
         if p not in sys.path:
             sys.path.insert(0, p)
+    from lib import check
+
+    try:   # a kind with no file stops the run here, before JAX is imported
+        reference = check.load_reference(config["reference"]["kind"])
+    except FileNotFoundError as e:
+        print(f"benchmark: {e}; nothing was measured", file=sys.stderr)
+        return 2
     from siddhi_tpu.util.compile_cache import configure_compile_cache
 
     configure_compile_cache()
     import jax
 
-    from lib import check, deploy, loops, xplane
+    from lib import deploy, loops, xplane
 
     platform = jax.default_backend()
     if not args.rehearsal and (platform != "tpu"
@@ -172,7 +181,7 @@ def main(argv=None) -> int:
         args.seed, config, traffic, args.rehearsal)
     dep = deploy.Deployment(config, schedule, args.rehearsal, bool(args.trace))
     ages.append(("traffic made, app built", deploy.process_age_s()))
-    send = dep.handler.send_batch
+    send = dep.send
     if args.control:
         def send(batch, _send=send, _cols=config["control"]["round_bf16"]):
             _send(round_bf16(batch, _cols))
@@ -203,11 +212,10 @@ def main(argv=None) -> int:
         device = deploy.device_line(jax)
 
         t_ref = time.perf_counter()
-        reference = getattr(check, "ref_" + config["reference"]["kind"])(
-            config["reference"], schedule, dep.collector, window.n_sent,
-            args.seed, args.rehearsal)
+        answers = reference(config["reference"], schedule, dep.collector,
+                            window.n_sent, args.seed, args.rehearsal)
         correct, attempted, failed, compared = check.judge(
-            dep, schedule, window, reference, platform)
+            dep, schedule, window, answers, platform)
         say(f"reference and judgement took "
             f"{time.perf_counter() - t_ref:.3f} s, after the clock stopped")
         for name, value, limit in compared:
@@ -218,19 +226,26 @@ def main(argv=None) -> int:
         wanted = metrics_of(bench, "per_layer" if args.trace else
                             "end_to_end", cell["name"])
         if args.trace:
-            traced = xplane.reduce_dir(profile.dir)
-            shutil.rmtree(profile.dir, ignore_errors=True)
-            run = types.SimpleNamespace(
-                wanted=[m["name"] for m in wanted], window=window,
-                ring_spans=dep.ring_spans(), xplane=traced,
-                traced_batches=window.traced[1] - window.traced[0],
-                setup_compile_s=compile_s)
-            values = {}
-            for path in sorted(glob.glob(os.path.join(BENCH, "layers",
-                                                      "*.py"))):
-                reader = importlib.import_module(
-                    os.path.splitext(os.path.basename(path))[0])
-                values.update(reader.read(run))
+            t_trace = time.perf_counter()
+            try:   # the file is read once; the readers are handed the trace
+                batches = window.traced[1] - window.traced[0]
+                trace = xplane.read_dir(profile.dir, batches)
+                traced = xplane.reduce(trace) if trace else None
+                run = types.SimpleNamespace(
+                    wanted=[m["name"] for m in wanted], window=window,
+                    ring_spans=dep.ring_spans(), xplane=traced, trace=trace,
+                    traced_batches=batches, setup_compile_s=compile_s)
+                values = {}
+                for path in sorted(glob.glob(os.path.join(BENCH, "layers",
+                                                          "*.py"))):
+                    reader = importlib.import_module(
+                        os.path.splitext(os.path.basename(path))[0])
+                    values.update(reader.read(run))
+            finally:
+                shutil.rmtree(profile.dir, ignore_errors=True)
+            say(f"trace read, {len(values)} per-layer values reduced from it "
+                f"in {time.perf_counter() - t_trace:.3f} s, after the clock "
+                "stopped")
             if traced:
                 device.update(busy_s=traced["busy_s"],
                               window_s=traced["window_s"])

@@ -56,11 +56,11 @@ def test_rehearsal_line_is_well_formed_and_correct(capsys, cell, trace):
     assert set(line["device"]) >= {"platform", "kind", "count",
                                    "memory_peak_bytes"}
     kind = "per_layer" if trace else "end_to_end"
+    # no device plane on the CPU: the readers of the trace return nothing
     owed = {m["name"]: m["unit"] for m in SPEC[kind]
-            if cell in m.get("workloads", [cell])}
+            if cell in m.get("workloads", [cell])
+            and m["source"] != "device_trace"}
     got = {k: v["unit"] for k, v in line["metrics"].items()}
-    if trace:  # no device plane on the CPU: those readers return nothing
-        owed = {k: u for k, u in owed.items() if ".device_" not in k}
     assert got == owed
     assert all(v["value"] > 0 for v in line["metrics"].values())
     assert any(ln.startswith("compared: ") and "(limit " in ln for ln in out)
@@ -124,9 +124,10 @@ def test_the_lower_precision_control_is_not_correct(capsys, cell):
 
 
 def test_the_chain_reference_equals_the_host_engine():
-    """`lib/check.py`'s plain-Python chain against `ops/nfa.py`."""
+    """`references/pattern_chain.py`'s plain-Python chain against
+    `ops/nfa.py`."""
     import fraud_cycle
-    from lib import check
+    from references import pattern_chain
     from siddhi_tpu import SiddhiManager
 
     with open(os.path.join(BENCH, "configs", "fraud16_1m.json")) as f:
@@ -159,7 +160,7 @@ def test_the_chain_reference_equals_the_host_engine():
     finally:
         m.shutdown()
     want = sorted(r for evs in by_key.values()
-                  for r in check._chain_rows(evs, 16, 600_000))
+                  for r in pattern_chain._chain_rows(evs, 16, 600_000))
     assert len(want) > 100 and sorted(got) == want
 
 
@@ -169,12 +170,13 @@ def test_xplane_reduction_on_known_intervals():
     assert xplane.union([(5, 7), (0, 2), (1, 3), (7, 8)]) == [(0, 3), (5, 8)]
     us = 1_000
     device = {
-        "/device:TPU:0": [(0, 20 * us, "copy"), (10 * us, 30 * us, "fusion"),
-                          (60 * us, 80 * us, "copy")],
-        "/device:TPU:1": [(0, 10 * us, "copy")]}
+        "/device:TPU:0": [(0, 20 * us, "copy", None),
+                          (10 * us, 30 * us, "fusion", None),
+                          (60 * us, 80 * us, "copy", None)],
+        "/device:TPU:1": [(0, 10 * us, "copy", None)]}
     host = [(0, 100 * us, xplane.MARK), (0, 70 * us, "bench.send_batch"),
             (40 * us, 50 * us, "bench.callback")]
-    r = xplane.reduce(device, host)
+    r = xplane.reduce(xplane.Trace(device, host))
     assert r["window_s"] == pytest.approx(100e-6)
     assert r["busy_s"] == pytest.approx((50e-6 + 10e-6) / 2)
     assert r["device_ops"][0] == ["copy", pytest.approx(50e-6 / 2)]
@@ -184,7 +186,7 @@ def test_xplane_reduction_on_known_intervals():
         "bench.send_batch": pytest.approx(20e-6),
         "bench.callback": pytest.approx(10e-6),
         "none": pytest.approx(20e-6)}
-    assert xplane.reduce({"/device:TPU:0": []}, host) is None
+    assert xplane.reduce(xplane.Trace({"/device:TPU:0": []}, host)) is None
 
 
 def test_benchmark_json_names_files_and_readers():

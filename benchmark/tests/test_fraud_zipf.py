@@ -26,7 +26,7 @@ for _p in (ROOT, BENCH, os.path.join(BENCH, "generators")):
         sys.path.insert(0, _p)
 
 import fraud_zipf  # noqa: E402
-from lib import check  # noqa: E402
+from references import pattern_chain  # noqa: E402
 
 CELL = "fraud16_1m_zipf.saturated"
 
@@ -62,7 +62,7 @@ def _owed(schedule, first, last):
                             b.columns["v"].tolist()):
             by_key.setdefault(k, []).append(((n, ts), ts, v))
     return [(n, k, ts, v1, v16) for k, evs in by_key.items()
-            for (n, ts), v1, v16 in check._chain_rows(
+            for (n, ts), v1, v16 in pattern_chain._chain_rows(
                 evs, REF["states"], REF["within_ms"])]
 
 
@@ -165,7 +165,8 @@ def test_twin_batches_owe_equal_counts(small):
 
 
 def test_the_chain_reference_equals_the_host_engine_on_this_traffic(small):
-    """``lib/check.py``'s plain-Python chain against ``ops/nfa.py``."""
+    """``references/pattern_chain.py``'s plain-Python chain against
+    ``ops/nfa.py``."""
     from siddhi_tpu import SiddhiManager
     from siddhi_tpu.core.event import EventBatch
 
