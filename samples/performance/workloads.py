@@ -46,9 +46,10 @@ SLIDING_WINDOW_Q = (
     "select symbol, sum(price) as total, avg(volume) as avgVolume, "
     "timestamp insert into outputStream;")
 
-# GroupByWindowSingleQueryPerformance.java:35, device-eligible variant:
-# group keys + aggregates only (the faithful shape's bare `timestamp`
-# select item needs per-group last-row registers)
+# GroupByWindowSingleQueryPerformance.java:35 without its bare
+# `timestamp` select item: group keys + aggregates only (the form
+# chip_smoke.py's windows phase runs; the faithful shape lowers to the
+# device too since PR 33)
 GROUPBY_LENGTH_BATCH_AGG_ONLY_Q = (
     CSE_DEF + "@info(name='q0') from cseEventStream"
     "#window.lengthBatch(10) select symbol, sum(price) as total, "
@@ -157,13 +158,15 @@ def workloads(seconds: float):
     row("sliding_window", q, tpu + q, b, dev_expect={"q0": "device"})
 
     # GroupByWindowSingleQueryPerformance.java:35 (faithful shape: the
-    # bare `timestamp` select item needs per-group last-row registers,
-    # so the tumbling device path declines — host engine, by design)
+    # bare `timestamp` select item is its group's last row's in the
+    # pane, gathered host-side at native width — since PR 33 the query
+    # lowers to the device, all the panes of a batch in one program;
+    # the benchmark's cell cse_groupby.saturated runs it)
     q = (CSE_DEF + "@info(name='q0') from cseEventStream"
          "#window.lengthBatch(10) select symbol, sum(price) as total, "
          "avg(volume) as avgVolume, timestamp group by symbol "
          "insert into outputStream;")
-    row("groupby_length_batch", q, tpu + q, b)
+    row("groupby_length_batch", q, tpu + q, b, dev_expect={"q0": "device"})
 
     q = GROUPBY_LENGTH_BATCH_AGG_ONLY_Q
     row("groupby_length_batch_agg_only", q, tpu + q, b,

@@ -71,6 +71,11 @@ ALLOWLISTS = {
             "ingest: converts HOST chunk inputs before staged_put",
         f"{_DQ}:DeviceQueryEngine._acc_segment":
             "ingest: converts HOST segment inputs before the acc step",
+        f"{_DQ}:DeviceQueryEngine._pane_chunk":
+            "ingest: joins HOST batch cols to the open pane's carried rows "
+            "before staged_put",
+        f"{_DQ}:DeviceQueryEngine._note_last_rows":
+            "ingest: HOST batch cols into the sweep's last-row registers",
         f"{_DQ}:DeviceQueryEngine._out_columns":
             "drain: deferred-emit column materializer",
         f"{_DQ}:DeviceQueryEngine._flush_cols":

@@ -66,6 +66,7 @@ class DeviceQueryRuntime:
         self.emit_cb = emit
         self.state = engine.init_state()
         self.step_invocations = 0  # proof the jitted path ran (tests)
+        self.rows_emitted = 0
         # count gate, emit queue, drain(), fault isolation, poison
         # quarantine (core/device_pipeline.py); the engine kind labels
         # this runtime's spans
@@ -139,6 +140,7 @@ class DeviceQueryRuntime:
               keys=None, now=None):
         if len(out_ts) == 0:
             return
+        self.rows_emitted += len(out_ts)
         mb = EventBatch(
             self.out_stream_id, self.engine.output_names, out_cols,
             out_ts, np.full(len(out_ts), ev.CURRENT, dtype=np.int8),
@@ -158,6 +160,16 @@ class DeviceQueryRuntime:
         if now is not None:
             mb.aux["emit_now"] = now
         self.emit_cb(mb)
+
+    def stats(self) -> Dict:
+        """Ops introspection: the engine's kind, the rows this runtime
+        handed to its output chain and, for a tumbling window, the panes
+        it closed."""
+        eng = self.engine
+        out = {"engine": eng.kind, "rows_emitted": self.rows_emitted}
+        if eng.kind == "tumbling":
+            out["panes_closed"] = eng.panes_closed
+        return out
 
     # -- scheduler task (timeBatch pane flushes) -----------------------------
 

@@ -136,6 +136,10 @@ class MultiplexPlanner:
             if engine.kind != "tumbling":
                 raise SiddhiAppCreationError(
                     "engine lowered to a non-tumbling form")
+            if engine.bare_attrs:
+                raise SiddhiAppCreationError(
+                    "bare select attributes need the dedicated engine's "
+                    "last-row bookkeeping")
             return TumblingMultiplexGroup(engine, slots)
 
         registry = registry_for(self.ctx.siddhi_context)

@@ -393,7 +393,7 @@ class TumblingMultiplexGroup:
                     [] if eng.group_exprs else None)
         off = seat.slot * self.G
         sl = {k: state[k][off:off + self.G] for k in state}
-        sl, fcols, nf, keys = eng._flush_cols(sl)
+        sl, fcols, nf, keys, _stamps = eng._flush_cols(sl)
         state = {k: state[k].at[off:off + self.G].set(sl[k]) for k in state}
         return state, fcols, nf, keys
 

@@ -11,7 +11,9 @@ rides a :class:`CycleToken` through the existing async machinery:
 
 Inside those three, one flat vocabulary tiles the rest of a batch's
 time in ``send_batch``: ``intern`` (keys to engine rows), ``convert``
-(host columns to padded device lanes), ``route`` (bucketing by shard,
+(host columns to padded device lanes), ``plan`` (the dense engine's
+round plan), ``pane`` (the open tumbling pane's carried rows joined to
+the batch, lengthBatch queries only), ``route`` (bucketing by shard,
 sharded engines only), ``put`` (one per H2D transfer), ``dispatch``
 (the call of the jitted step), and under ``emit`` the coalesced
 ``fetch`` and the ``deliver`` of rows to the callback.  A span's parent
@@ -65,6 +67,7 @@ STAGE_INTERN = "intern"      # keys interned
 STAGE_INGEST = "ingest"      # events
 STAGE_CONVERT = "convert"    # events
 STAGE_PLAN = "plan"          # rounds in the batch (its longest run of one key)
+STAGE_PANE = "pane"          # tumbling panes the batch closed
 STAGE_ROUTE = "route"        # events
 STAGE_PUT = "put"            # bytes handed to device_put
 STAGE_DISPATCH = "dispatch"  # 1 per call of a jitted step
@@ -73,8 +76,8 @@ STAGE_EMIT = "emit"          # rows
 STAGE_FETCH = "fetch"        # bytes fetched
 STAGE_DELIVER = "deliver"    # rows delivered
 CYCLE_STAGES = (STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT, STAGE_PLAN,
-                STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH, STAGE_STEP,
-                STAGE_EMIT, STAGE_FETCH, STAGE_DELIVER)
+                STAGE_PANE, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH,
+                STAGE_STEP, STAGE_EMIT, STAGE_FETCH, STAGE_DELIVER)
 #: what a further round of one batch repeats where rounds are stepped
 #: from the host (the sharded engine; a device chunk of the window
 #: path).  The dense engine runs its rounds on the device: whatever the
@@ -128,12 +131,18 @@ SCOPE_WINDOW_AGGREGATE = "siddhi.window.aggregate"
 SCOPE_WINDOW_EMIT = "siddhi.window.emit"
 SCOPE_WINDOW_UPDATE = "siddhi.window.update"
 SCOPE_WINDOW_COUNT = "siddhi.window.count"
+# make_pane_step: every lengthBatch pane a batch closes, in one program
+SCOPE_PANE_ASSIGN = "siddhi.pane.assign"        # lanes tiled [L, panes]
+SCOPE_PANE_REDUCE = "siddhi.pane.reduce"        # per (pane, group) segment
+SCOPE_PANE_EMIT = "siddhi.pane.emit"            # a group's last row, select
+SCOPE_PANE_COUNT = "siddhi.pane.count"
 DEVICE_SCOPES = (
     SCOPE_DENSE_GATHER, SCOPE_DENSE_ADVANCE, SCOPE_DENSE_SCATTER,
     SCOPE_DENSE_COUNT, SCOPE_DENSE_ROUNDS, SCOPE_DENSE_RUN,
     SCOPE_SHARD_COUNT_PSUM, SCOPE_WINDOW_FILTER,
     SCOPE_WINDOW_SLOT, SCOPE_WINDOW_AGGREGATE, SCOPE_WINDOW_EMIT,
-    SCOPE_WINDOW_UPDATE, SCOPE_WINDOW_COUNT)
+    SCOPE_WINDOW_UPDATE, SCOPE_WINDOW_COUNT, SCOPE_PANE_ASSIGN,
+    SCOPE_PANE_REDUCE, SCOPE_PANE_EMIT, SCOPE_PANE_COUNT)
 
 # the calling thread's open cycle: set by begin_cycle (None for an
 # unsampled cycle), cleared when the cycle's ingest span ends

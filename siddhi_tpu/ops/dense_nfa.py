@@ -94,6 +94,7 @@ from siddhi_tpu.planner.expr import (
     CompiledExpression,
     ExpressionCompiler,
     N_KEY,
+    RecordingEnv,
     TS_KEY,
 )
 from siddhi_tpu.query_api import AttrType, StateInputStream, Variable
@@ -2049,16 +2050,6 @@ class DensePatternEngine:
         numeric = [a for a in self._stream_def(stream_key).attributes
                    if a.type.is_numeric]
         looked_up = set()
-
-        class Recording(dict):
-            def __getitem__(self, key):
-                looked_up.add(key)
-                return dict.__getitem__(self, key)
-
-            def get(self, key, default=None):
-                looked_up.add(key)
-                return dict.get(self, key, default)
-
         env = {TS_KEY: np.int32(1), N_KEY: 1}
         for a in numeric:
             if a.type in _INT_TYPES:
@@ -2080,7 +2071,7 @@ class DensePatternEngine:
                         continue
                     f = self.node_filters[s][si]
                     if f is not None:
-                        f.fn(Recording(env))
+                        f.fn(RecordingEnv(env, looked_up))
                     read |= {slot.attr for slot in self.node_writes[s]
                              if slot.ref == spec.ref}
             read |= {k[len("__cand."):].split("|")[0] for k in looked_up

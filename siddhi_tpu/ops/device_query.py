@@ -20,8 +20,16 @@ step over columnar micro-batches:
   aggregator state lives as ``[G]`` device arrays updated with
   scatter-add/min/max, and within-batch running prefixes use a masked
   ``[B, B]`` same-group matmul that XLA maps onto the MXU;
-- **tumbling windows** (lengthBatch/timeBatch): per-group accumulators
-  plus a flush kernel emitting one row per touched group; the host
+- **tumbling windows**: ``lengthBatch(L)`` closes every pane of a batch
+  in ONE program (``make_pane_step``): the host joins the open pane's
+  carried rows (fewer than ``L``) to the batch's passing rows, the lanes
+  are tiled ``[L, panes]``, each (pane, group) segment is reduced under
+  an ``[L, L, panes]`` same-group mask and emits at its group's last
+  row, so the emit is a mask over lanes and rides the deferred,
+  count-gated fetch like the sliding kind's.  ``timeBatch`` (and
+  ``lengthBatch`` past ``PANE_MAX_LENGTH``, with minForever/maxForever,
+  under order by/limit, or sharded) keeps per-group accumulators plus a
+  flush kernel emitting one row per touched group; there the host
   wrapper splits incoming batches at pane boundaries so each step call
   stays a static-shape program.
 
@@ -37,8 +45,16 @@ contract):
  - filter / select / having expressions must be jax-traceable (numeric
    attrs, arithmetic/comparison/boolean ops) — checked at compile time
    by actually tracing them;
- - tumbling select items may reference only group keys and aggregates
-   (the host engine's last-row-per-group attrs need per-attr registers);
+ - tumbling select items are group keys, aggregates, expressions over
+   those, or BARE input attributes of any type: a bare attribute takes
+   the value of its group's last passing row in the pane, as the host
+   engine's batch selector gives it, gathered host-side at native width
+   (from the batch's columns on the one-program path, from per-group
+   last-row registers on the per-pane sweep).  Such a query's rows carry
+   the host's stamps and order: each row its own last row's timestamp,
+   a pane's rows in the order of those last rows.  With neither an
+   aggregate nor a group-by a pane owes its every row, not a last one:
+   declined, the host engine runs it;
  - time windows hold at most ``window_capacity`` passing events (the
    reference buffer is unbounded; overflow drops the oldest).
 
@@ -90,10 +106,15 @@ from siddhi_tpu.planner.expr import (
     CompiledExpression,
     ExpressionCompiler,
     N_KEY,
+    RecordingEnv,
     Scope,
     TS_KEY,
 )
 from siddhi_tpu.observability.trace import (
+    SCOPE_PANE_ASSIGN,
+    SCOPE_PANE_COUNT,
+    SCOPE_PANE_EMIT,
+    SCOPE_PANE_REDUCE,
     SCOPE_WINDOW_AGGREGATE,
     SCOPE_WINDOW_COUNT,
     SCOPE_WINDOW_EMIT,
@@ -103,6 +124,7 @@ from siddhi_tpu.observability.trace import (
     STAGE_CONVERT,
     STAGE_DISPATCH,
     STAGE_INTERN,
+    STAGE_PANE,
     span,
 )
 from siddhi_tpu.query_api import (
@@ -143,6 +165,18 @@ PER_FLUSH = "per_flush"
 # junction batch would allocate quadratically; chunks advance state
 # sequentially, which is semantics-preserving for every kind
 MAX_DEVICE_BATCH = 2048
+
+# longest lengthBatch pane the one-program path tiles.  Its same-group
+# mask is [L, L, panes], L times the batch: XLA fuses it away at an
+# 8,192-row batch and keeps L x batch bytes of it at a 131,072-row one
+# (537 MB at L = 4,096; TPU compiler, memory_analysis).  Measured on a
+# v5e at 8,192-row batches, the cse_groupby query, ms a batch, program
+# against sweep (PERF.md, PR 33): L = 64: 17.6 / 972; 256: 10.7 / 202;
+# 1,024: 9.7 / 54; 4,096: 9.3 / 18.1; 8,192 (one pane a batch): 9.6 /
+# 11.9.  The sweep costs some 8 ms a pane, so it is never ahead while a
+# batch closes a pane; the cap is where the mask's memory, not the time,
+# would start to bind
+PANE_MAX_LENGTH = 4096
 
 
 @dataclass
@@ -287,6 +321,18 @@ def _mm_f32(a, b):
     import jax.numpy as jnp
 
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+# host-side rows of a tumbling pane (the one-program path's carried rows,
+# the sweep's last-row registers) are one flat dict of equal-length
+# arrays: input attributes by name, the event timestamp under TS_KEY,
+# and these two
+GRP_KEY = "__grp"   # interned group id
+SEQ_KEY = "__seq"   # place in the stream
+
+
+def _copy_rows(rows: Optional[Dict]) -> Optional[Dict]:
+    return None if rows is None else {k: v.copy() for k, v in rows.items()}
 
 
 def _pow2(n: int, floor: int = 16) -> int:
@@ -506,14 +552,24 @@ class DeviceQueryEngine:
                 "the direct compile_query API does not apply them")
         if self.mode == PER_FLUSH:
             for kind, _v, name in self.out_spec:
-                if kind == "passthrough":
-                    raise SiddhiAppCreationError(
-                        f"tumbling device query: select item '{name}' may "
-                        "reference only group keys and aggregates")
                 if kind == "expr" and not self._flush_expr_ok(_v):
                     raise SiddhiAppCreationError(
                         f"tumbling device query: select item '{name}' may "
-                        "reference only group keys and aggregates")
+                        "be a group key, an aggregate, an expression over "
+                        "those, or a bare input attribute")
+        # bare input attributes of a tumbling select: the value of the
+        # group's last passing row in the pane, kept host-side
+        self.bare_attrs: List[str] = sorted({
+            v for kind, v, _n in self.out_spec
+            if kind == "passthrough"}) if self.mode == PER_FLUSH else []
+        if self.bare_attrs and not (self.group_exprs or self.aggs):
+            # the host's batch selector keeps a group's last row only
+            # where there is a group-by or an aggregate
+            # (core/query.py); without either a pane emits every row
+            raise SiddhiAppCreationError(
+                "tumbling device query: a select of bare attributes "
+                f"{self.bare_attrs} with neither an aggregate nor a "
+                "group-by emits every row of a pane — host engine used")
         if self.mode == PER_EVENT and self.window_name is None and not self.aggs:
             self.kind = "filter"  # stateless filter/projection query
         elif self.mode == PER_EVENT and self.window_name is None:
@@ -529,6 +585,17 @@ class DeviceQueryEngine:
                     "boundaries — per-key host instances used")
             if self.kind == "sliding":
                 self.kind = "keyed_sliding"
+
+        # lengthBatch: every pane a batch closes in one program
+        # (make_pane_step); the per-pane sweep keeps what that program
+        # does not hold (see the module docstring)
+        self.pane_batched = (
+            self.kind == "tumbling" and self.window_name == "lengthBatch"
+            and 1 <= int(self.window_param) <= PANE_MAX_LENGTH
+            and not {a.kind for a in self.aggs} & {"minForever",
+                                                   "maxForever"}
+            and not (sel.order_by or sel.limit is not None
+                     or sel.offset is not None))
 
         # window geometry
         if self.kind in ("sliding", "keyed_sliding"):
@@ -572,6 +639,15 @@ class DeviceQueryEngine:
         self._pane_end: Optional[int] = None  # timeBatch
         self._pane_fill = 0  # passing events in the open pane
         self._prev_pane_fill = 0  # previous pane's fill (idle detection)
+        # one-program path: the open pane's passing rows, fewer than L
+        # (flat rows, see GRP_KEY; None while the pane is empty)
+        self._pane_carry: Optional[Dict[str, np.ndarray]] = None
+        # per-pane sweep with bare attributes: per-group registers of
+        # the open pane's last passing row (flat rows of [G], made at
+        # the first batch); _rows_seen numbers rows across batches
+        self._last_row: Optional[Dict[str, np.ndarray]] = None
+        self._rows_seen = 0
+        self.panes_closed = 0  # stats(): tumbling panes flushed
 
     # -- compilation helpers -------------------------------------------------
 
@@ -1354,6 +1430,97 @@ class DeviceQueryEngine:
         self._step_cache[key] = fn
         return fn
 
+    def make_pane_step(self, jit: bool = True) -> Callable:
+        """Every lengthBatch pane a batch closes, in one program:
+
+        panes(cols {lane: [B]}, grp[B] i32)
+          -> (out_valid[B], out {name: [B]}, n_match scalar i32)
+
+        ``B`` is a whole number of panes: the lanes hold passing rows
+        only, pane after pane (the host joined the open pane's carried
+        rows ahead of the batch's and dropped what the filters drop), a
+        lane past the last closed pane has ``grp`` -1.  Lanes are tiled
+        ``[L, panes]`` (panes on the minor axis); each row is reduced
+        over the rows of its group in its pane under an ``[L, L, panes]``
+        mask — at most ``L`` float32 terms a sum, never a difference of
+        batch-long prefixes — and the row that is its group's last in
+        the pane emits, so a pane's rows come in the host engine's
+        order.  No state: the open pane lives host-side as rows."""
+        key = ("panes", jit)
+        if key in self._step_cache:
+            return self._step_cache[key]
+        jnp = self.jnp
+        named_scope = self.jax.named_scope
+        L = int(self.window_param)
+        kinds = self._kinds()
+
+        def panes(cols, grp):
+            B = grp.shape[0]
+            P = B // L
+
+            def tile(x):    # [B, ...] -> [L, P, ...]
+                return jnp.swapaxes(x.reshape((P, L) + x.shape[1:]), 0, 1)
+
+            def lanes(x):   # [L, P, ...] -> [B, ...]
+                return jnp.swapaxes(x, 0, 1).reshape((B,) + x.shape[2:])
+
+            with named_scope(SCOPE_PANE_ASSIGN):
+                env = cols.copy()
+                env[N_KEY] = B
+                g = tile(grp)
+                ok = g >= 0
+                v = tile(self._arg_vals(env, B))        # [L, P, A]
+            with named_scope(SCOPE_PANE_REDUCE):
+                # same[i, j, p]: row j of pane p is of row i's group
+                same = (g[:, None, :] == g[None, :, :]) & ok[None, :, :]
+                m4 = same[:, :, :, None]
+                wsum = lanes(jnp.sum(jnp.where(m4, v[None], 0.0), axis=1))
+                wcnt = lanes(jnp.sum(same, axis=1).astype(
+                    jnp.float32))[:, None]
+                wsumsq = (lanes(jnp.sum(jnp.where(m4, (v * v)[None], 0.0),
+                                        axis=1))
+                          if "stdDev" in kinds else None)
+                wmin = (lanes(jnp.min(jnp.where(m4, v[None], jnp.inf),
+                                      axis=1))
+                        if "min" in kinds else None)
+                wmax = (lanes(jnp.max(jnp.where(m4, v[None], -jnp.inf),
+                                      axis=1))
+                        if "max" in kinds else None)
+            with named_scope(SCOPE_PANE_EMIT):
+                later = jnp.triu(jnp.ones((L, L), dtype=bool), k=1)
+                last = lanes(ok & ~jnp.any(same & later[:, :, None],
+                                           axis=1))
+                self._finalize_aggs(env, wsum, wcnt, wsumsq, wmin, wmax)
+                ov, out = self._emit(env, last, B)
+            with named_scope(SCOPE_PANE_COUNT):
+                n = jnp.sum(ov.astype(jnp.int32))
+            return ov, out, n
+
+        fn = self.jax.jit(panes) if jit else panes
+        self._step_cache[key] = fn
+        return fn
+
+    def pane_lanes(self) -> List[str]:
+        """The input lanes the pane program reads (aggregate arguments,
+        numeric group keys inside select expressions and having), found
+        by tracing it over an ``env`` that records its lookups: a lane
+        nothing looks at would cost a transfer of its own in every
+        put."""
+        key = ("pane_lanes",)
+        if key in self._step_cache:
+            return self._step_cache[key]
+        seen = set()
+        L = int(self.window_param)
+        shapes = {k: v for k, v in self._env_shapes(L).items()
+                  if k in self._lane_dtype or k == TS_KEY}
+        panes = self.make_pane_step(jit=False)
+        self.jax.eval_shape(
+            lambda cols, grp: panes(RecordingEnv(cols, seen), grp), shapes,
+            self.jax.ShapeDtypeStruct((L,), np.int32))
+        read = [k for k in shapes if k in seen]
+        self._step_cache[key] = read
+        return read
+
     # -- host wrapper --------------------------------------------------------
 
     # re-anchor before relative ms approach int32 range (~24.8 days of
@@ -1906,6 +2073,9 @@ class DeviceQueryEngine:
                 "cols": {k: np.asarray(v) for k, v in cols.items()},
             })
             return state
+        if self.pane_batched:
+            self._pane_chunk(cols, ts, rel, grp, n, pending)
+            return state
         state, out_cols, out_ts = self._process_tumbling(
             state, cols, rel, grp, n)
         if len(out_ts):
@@ -1914,6 +2084,71 @@ class DeviceQueryEngine:
                 "keys": self.last_group_keys,
             })
         return state
+
+    def _pane_chunk(self, cols, ts, rel, grp, n, pending):
+        """One batch of the one-program lengthBatch path: the open
+        pane's carried rows and the batch's passing rows, every pane
+        they complete through ``make_pane_step`` as one put and one
+        dispatch, the rest carried.  The emit is a "device" chunk of
+        ``pending`` like the sliding kind's: a mask over the lanes, bare
+        attributes and row timestamps gathered host-side from the joined
+        rows at materialize time."""
+        L = int(self.window_param)
+        read = self.pane_lanes()
+        with span(STAGE_PANE) as sp:
+            keep = (np.flatnonzero(self._host_filter_mask(cols, rel, n))
+                    if self.filters else slice(0, n))
+            rows = {a: np.asarray(cols[a])[keep]
+                    for a in {*read, *self.bare_attrs} - {TS_KEY}}
+            rows[TS_KEY], rows[GRP_KEY] = ts[keep], grp[keep]
+            carry, self._pane_carry = self._pane_carry, None
+            if carry is not None:
+                rows = {k: np.concatenate([carry[k], v])
+                        for k, v in rows.items()}
+            closed = len(rows[TS_KEY]) // L
+            m = closed * L
+            if m < len(rows[TS_KEY]):
+                self._pane_carry = {k: v[m:].copy() for k, v in rows.items()}
+            rows = {k: v[:m] for k, v in rows.items()}
+            self.panes_closed += closed
+            if sp is not None:
+                sp.count = closed
+        if not closed:
+            return
+        with span(STAGE_CONVERT, m):
+            # a whole number of panes that holds whatever a batch of
+            # this size (to its power of two) and a carry can complete
+            B = (_pow2(n) + L - 1) // L * L
+            lanes = {}
+            for a in read:
+                dtype = np.int32 if a == TS_KEY else self._lane_dtype[a]
+                lanes[a] = np.zeros(B, dtype=dtype)
+                lanes[a][:m] = (rows[a] - self.base_ts if a == TS_KEY
+                                else rows[a])
+            g = np.full(B, -1, dtype=np.int32)
+            g[:m] = rows[GRP_KEY]
+        step = self.make_pane_step()
+        lanes, g = self._put_lanes((lanes, g))
+        if self.faults is not None:
+            self.faults.check("step.device")
+        with span(STAGE_DISPATCH, 1):
+            ov, out, n_match = step(lanes, g)
+            del lanes, g
+        stamps, order = rows[TS_KEY], None
+        if self.group_exprs and not self.bare_attrs:
+            # with no bare attribute a pane's rows come as the per-pane
+            # sweep gives them (here, sharded or multiplexed alike): its
+            # groups in id order, each stamped with the pane's last row
+            order = (np.arange(m, dtype=np.int64) // L * self.n_groups
+                     + rows[GRP_KEY])
+            stamps = np.repeat(stamps[L - 1::L], L)
+        pending.chunks.append({
+            "kind": "device", "ov": ov, "out": dict(out),
+            "names": list(out), "n": m, "count": n_match,
+            "gids": rows[GRP_KEY] if self.group_exprs else None,
+            "ts": stamps, "order": order,
+            "cols": {a: rows[a] for a in self.bare_attrs},
+        })
 
     def process(self, state, cols: Dict[str, np.ndarray], ts: np.ndarray,
                 part_keys: Optional[np.ndarray] = None):
@@ -1948,12 +2183,40 @@ class DeviceQueryEngine:
         if int(self.jax.device_get(n_match)) == 0:
             # count gate: empty pane — no group/output column fetched
             return state, self._empty_cols(), 0, (
-                [] if self.group_exprs else None)
+                [] if self.group_exprs else None), None
         gidx = np.flatnonzero(np.asarray(ov))
         out_np = {k: np.asarray(col) for k, col in out.items()}
-        out_cols = self._out_columns(out_np, gidx, gidx, None, None)
+        reg, stamps = self._last_row, None
+        if reg is not None:
+            # bare attributes: the host engine's rows, each stamped with
+            # its group's last row and in the order of those rows
+            gidx = gidx[np.argsort(reg[SEQ_KEY][gidx], kind="stable")]
+            stamps = reg[TS_KEY][gidx]
+        out_cols = self._out_columns(out_np, gidx, gidx, reg, gidx)
         keys = self._keys_for_gids(gidx) if self.group_exprs else None
-        return state, out_cols, len(gidx), keys
+        return state, out_cols, len(gidx), keys, stamps
+
+    def _note_last_rows(self, cols, rel, grp, rows, seen):
+        """Per-group registers of the open pane's last passing row (the
+        per-pane sweep with bare attributes): its place in the stream
+        (``seen`` rows came before this batch), its timestamp, and the
+        bare attributes at native width."""
+        if not len(rows):
+            return
+        reg = self._last_row
+        if reg is None:
+            G = self.n_groups
+            reg = self._last_row = {
+                a: np.zeros(G, dtype=self.stream_def.attribute_type(
+                    a).np_dtype) for a in self.bare_attrs}
+            reg[SEQ_KEY] = np.zeros(G, dtype=np.int64)
+            reg[TS_KEY] = np.zeros(G, dtype=np.int64)
+        uniq, at = np.unique(grp[rows][::-1], return_index=True)
+        last = rows[len(rows) - 1 - at]
+        reg[SEQ_KEY][uniq] = seen + last
+        reg[TS_KEY][uniq] = self.base_ts + rel[last].astype(np.int64)
+        for a in self.bare_attrs:
+            reg[a][uniq] = np.asarray(cols[a])[last]
 
     def _advance_pane(self):
         """Post-flush timeBatch pane bookkeeping (mirrors the host
@@ -1984,8 +2247,9 @@ class DeviceQueryEngine:
             w = self.pane_wakeup()
             if w is None or w > now:
                 break
-            state, fcols, nf, keys = self._flush_cols(state)
-            chunks.append((fcols, w, nf, keys))
+            state, fcols, nf, keys, stamps = self._flush_cols(state)
+            chunks.append((fcols, w if stamps is None else stamps, nf, keys))
+            self.panes_closed += 1
             self._advance_pane()
         out_cols, out_ts = self._concat_chunks(chunks)
         return state, out_cols, out_ts
@@ -2013,6 +2277,19 @@ class DeviceQueryEngine:
         accumulate/flush steps, so pane placement (``_pane_end``,
         lengthBatch fill counts — host scalars either way) cannot
         diverge between them."""
+        fmask = (self._host_filter_mask(cols, rel, n)
+                 if self.window_name == "lengthBatch"
+                 or (self.bare_attrs and self.filters) else None)
+        if self.bare_attrs:
+            # a bare attribute is its group's last passing row's
+            accumulate, seen = acc_segment, self._rows_seen
+            self._rows_seen += n
+
+            def acc_segment(state, cols, rel, grp, idx):
+                self._note_last_rows(
+                    cols, rel, grp,
+                    idx if fmask is None else idx[fmask[idx]], seen)
+                return accumulate(state, cols, rel, grp, idx)
         if self.window_name == "timeBatch":
             # pane bookkeeping mirrors the host TimeBatchWindow: the
             # first event anchors the boundary, boundaries advance by T
@@ -2037,12 +2314,12 @@ class DeviceQueryEngine:
                     i = j
                 if i < n:  # boundary crossed by remaining events
                     state = flush_pane(state, self.base_ts + self._pane_end)
+                    self.panes_closed += 1
                     self._advance_pane()
             return state
         # lengthBatch: need passing counts to place flush boundaries,
         # so probe the filter mask first (host-visible)
         L = int(self.window_param)
-        fmask = self._host_filter_mask(cols, rel, n)
         i = 0
         while i < n:
             remaining = L - self._pane_fill
@@ -2056,6 +2333,7 @@ class DeviceQueryEngine:
             state, _ = acc_segment(state, cols, rel, grp,
                                    np.arange(i, j))
             state = flush_pane(state, self.base_ts + int(rel[j - 1]))
+            self.panes_closed += 1
             self._pane_fill = 0
             i = j
         return state
@@ -2064,8 +2342,9 @@ class DeviceQueryEngine:
         chunks = []  # (cols, abs_ts, n_rows, keys|None)
 
         def flush_pane(st, when):
-            st, fcols, nf, keys = self._flush_cols(st)
-            chunks.append((fcols, when, nf, keys))
+            st, fcols, nf, keys, stamps = self._flush_cols(st)
+            chunks.append((fcols, when if stamps is None else stamps, nf,
+                           keys))
             return st
 
         state = self._pane_sweep(state, cols, rel, grp, n,
@@ -2104,6 +2383,9 @@ class DeviceQueryEngine:
             "pane_end": self._pane_end,
             "pane_fill": self._pane_fill,
             "prev_pane_fill": self._prev_pane_fill,
+            "pane_carry": _copy_rows(self._pane_carry),
+            "last_row": _copy_rows(self._last_row),
+            "rows_seen": self._rows_seen,
         }
 
     def host_restore(self, s: Dict):
@@ -2154,6 +2436,9 @@ class DeviceQueryEngine:
         self._pane_end = s["pane_end"]
         self._pane_fill = s["pane_fill"]
         self._prev_pane_fill = s["prev_pane_fill"]
+        self._pane_carry = _copy_rows(s.get("pane_carry"))
+        self._last_row = _copy_rows(s.get("last_row"))
+        self._rows_seen = s.get("rows_seen", 0)
 
     # -- introspection -------------------------------------------------------
 
@@ -2293,6 +2578,8 @@ class DeferredDeviceEmit:
                 out_np[nm] = raw_col[sel] if sel is not None else raw_col[:n]
                 pos += 1
             idx = np.flatnonzero(ov_np)
+            if ch.get("order") is not None:   # _pane_chunk: rows by key
+                idx = idx[np.argsort(ch["order"][idx], kind="stable")]
             cols, ts = ch["cols"], ch["ts"]
             if eng.kind == "filter":
                 host_env = eng._host_env(cols, ts, n)

@@ -82,6 +82,13 @@ class ShardedDeviceQueryEngine:
                 f"mesh sharding of the device query engine covers the "
                 f"{'/'.join(SHARDED_KINDS)} kinds; kind="
                 f"'{engine.kind}' is stateless and runs single-device")
+        if engine.kind == "tumbling" and engine.bare_attrs:
+            raise SiddhiAppCreationError(
+                "sharded tumbling: bare select attributes "
+                f"{engine.bare_attrs} need the single-device engine's "
+                "last-row bookkeeping; runs single-device")
+        # the wrapper drives the per-pane sweep with its own steps
+        engine.pane_batched = False
         host = engine.init_state_host()
         if engine.kind == "keyed_sliding" and (
                 "acc_minf" in host or "acc_maxf" in host):

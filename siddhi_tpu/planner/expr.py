@@ -49,6 +49,27 @@ TS_KEY = "__ts"
 N_KEY = "__n"
 
 
+class RecordingEnv(dict):
+    """An expression ``env`` that notes in ``seen`` every key looked up
+    in it: evaluating (or tracing) compiled expressions over one finds
+    the columns they read, so that only those are sent to the device."""
+
+    def __init__(self, env, seen: set):
+        super().__init__(env)
+        self.seen = seen
+
+    def __getitem__(self, key):
+        self.seen.add(key)
+        return dict.__getitem__(self, key)
+
+    def get(self, key, default=None):
+        self.seen.add(key)
+        return dict.get(self, key, default)
+
+    def copy(self):
+        return RecordingEnv(self, self.seen)
+
+
 @dataclass
 class CompiledExpression:
     fn: Callable[[Dict[str, np.ndarray]], np.ndarray]

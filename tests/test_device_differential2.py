@@ -137,6 +137,22 @@ class TestDeviceQueryFuzz:
         "k, avg(v) as a, min(v) as mn, max(v) as mx group by k",
     ]
 
+    @pytest.mark.parametrize("win", WINDOWS[2::2])
+    @pytest.mark.parametrize("filt", ["", "[v > 40.0]"])
+    @pytest.mark.parametrize("sel", ["k, v", "*"])
+    def test_batch_window_without_aggregate_runs_on_the_host(
+            self, win, filt, sel):
+        """The pairing ``test_random_combination`` redraws: a pane's
+        every row is owed, not a group's last, so the device engine
+        declines and the rows are the host engine's."""
+        q = (DEFS + f"@info(name='q') from S{filt}{win.format(n=5, t=1)} "
+             f"select {sel} insert into O;")
+        sends = mk_sends(60, seed=231)
+        host, _ = drive(q, sends)
+        dev, runtimes = drive("@app:execution('tpu') " + q, sends)
+        assert not any(isinstance(r, DeviceQueryRuntime) for r in runtimes)
+        assert len(host) > 20 and dev == host
+
     @pytest.mark.parametrize("seed", range(8))
     def test_random_combination(self, seed):
         rng = np.random.default_rng(100 + seed)
@@ -144,9 +160,11 @@ class TestDeviceQueryFuzz:
             n=int(rng.integers(2, 7)), t=int(rng.integers(1, 3)))
         sel = self.SELECTS[rng.integers(0, len(self.SELECTS))]
         if "Batch" in win and "(" not in sel:
-            # tumbling device queries reduce per flush: select items may
-            # reference only group keys and aggregates (documented
-            # eligibility) — pair batch windows with aggregating selects
+            # a batch window whose select has neither an aggregate nor
+            # a group-by emits every row of a pane: the device engine
+            # declines it and the host engine runs it
+            # (test_batch_window_without_aggregate_runs_on_the_host) —
+            # the draw pairs batch windows with aggregating selects
             sel = self.SELECTS[1 + rng.integers(0, len(self.SELECTS) - 1)]
         thr = float(rng.integers(10, 80))
         filt = f"[v > {thr}]" if rng.integers(0, 2) else ""
