@@ -166,8 +166,7 @@ class SiddhiAppRuntime:
                 eq.controller = None
             stage = getattr(rt, "ingest_stage", None)
             if stage is not None:
-                stage.flush()
-                stage.depth = 1
+                stage.pin(1)
         self.start()
         return debugger
 
@@ -334,6 +333,9 @@ class SiddhiAppRuntime:
         # barrier: queued device emits reach their callbacks/sinks
         # before the scheduler and junctions stop accepting output
         self.drain_device_emits()
+        # nothing is staged any more: the idle finisher's thread (there
+        # is one only if a stage ever left a batch in flight) goes
+        self.app_context.idle_finisher.stop()
         for s in self.sinks:
             s.shutdown()
         self.scheduler.stop()

@@ -13,6 +13,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from .ingest_stage import IdleFinisher
+
 
 class TimestampGenerator:
     """Event/wall time source.  In playback mode (@app:playback) current
@@ -125,12 +127,18 @@ class SiddhiAppContext:
         # 'auto' derives the effective depth at runtime from observed
         # transfer RTT vs batch cadence (EmitDepthController).
         self.tpu_emit_depth = 1
-        # @app:execution('tpu', ingest.depth='N'): ingest staging window
-        # (core/ingest_stage.py) — each batch's count-gate fetch defers
-        # until N-1 later batches have dispatched, overlapping H2D
-        # transfer with the jitted step.  1 (default) = synchronous;
-        # 'auto' = RTT-vs-cadence adaptive (EmitDepthController).
-        self.tpu_ingest_depth = 1
+        # ingest staging window (core/ingest_stage.py).  None (unset, or
+        # ingest.depth='auto'): the stage chooses per batch.  send_batch
+        # returns with the batch's callbacks delivered (count gate
+        # finished inline) unless the stage has seen, four batches
+        # running, a gate that kept the host for milliseconds and a
+        # caller straight back for more: then ONE batch stays in flight
+        # past send_batch's return, finished by the next batch's submit,
+        # any flush barrier or, within about a cycle, the idle finisher
+        # below.  @app:execution('tpu', ingest.depth='N') pins the
+        # window: '1' always inline, 'N' > 1 each gate deferred until
+        # N-1 later batches have dispatched (or a barrier).
+        self.tpu_ingest_depth = None
         # @app:execution('tpu', agg.device.min.batch='N'): minimum batch
         # size before incremental aggregation uses the jitted device
         # segment-reduce instead of the host np.add.at path
@@ -233,6 +241,10 @@ class SiddhiAppContext:
         # one re-entrant lock quiesces the whole app for snapshot/restore —
         # the ThreadBarrier analog (reference: util/ThreadBarrier.java:30)
         self.process_lock = threading.RLock()
+        # finishes, under that lock, a staged count gate that no later
+        # batch came for; its thread starts when a stage first leaves a
+        # batch in flight and is joined at shutdown
+        self.idle_finisher = IdleFinisher(self)
         self.scheduler = None  # set by app runtime
         self.snapshot_service = None  # set by app runtime
         self.statistics_manager = None

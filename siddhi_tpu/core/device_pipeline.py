@@ -9,12 +9,16 @@ routing, chunking, building the output ``EventBatch`` — and holds one
 
 - the sampled-or-None cycle token of a batch, closed as raised when an
   exception leaves the batch path (:meth:`DevicePipeline.cycle`);
-- the count gate: the blocking fetch of a step's match count, staged
-  behind the next batch's dispatch (core/ingest_stage.py), then either a
+- the count gate: the blocking fetch of a step's match count, finished
+  inline or left staged behind the next batch's dispatch as the stage
+  decides from what it observes (core/ingest_stage.py), then either a
   counted skip or a device-resident ``PendingEmit`` in the bounded emit
   queue (core/emit_queue.py) — :meth:`DevicePipeline.submit`;
 - the flush barrier, ingest stage before emit queue
-  (:meth:`DevicePipeline.drain`);
+  (:meth:`DevicePipeline.drain`); once the stage has ever left a
+  batch in flight it takes the app's ``process_lock`` itself, so a
+  bare ``drain()`` from a client thread is safe against the idle
+  finisher;
 - fault isolation: a batch that dies in the gate, the drain or the
   callback freezes the span ring and reaches the app's exception
   listeners (:meth:`DevicePipeline.on_fault`);
@@ -105,9 +109,10 @@ class DevicePipeline:
     """Count gate, emit push, fault isolation and poison quarantine of
     one device runtime.  Everything comes from the ``app_context``: the
     tracer, the ``@app:faults`` injector, the exception listeners, the
-    clock and the ``emit.depth`` / ``ingest.depth`` of
-    ``@app:execution``.  Without a context (a runtime built by hand in a
-    test) it is depth 1, untraced, with no injector, listeners or clock.
+    clock, the idle finisher, the ``emit.depth`` and a pinned
+    ``ingest.depth`` of ``@app:execution``.  Without a context (a
+    runtime built by hand in a test) it is depth 1, untraced, with no
+    injector, listeners, clock or finisher, so always inline.
     """
 
     def __init__(self, app_context=None, engine_kind: str = "device"):
@@ -123,15 +128,17 @@ class DevicePipeline:
             depth=getattr(app_context, "tpu_emit_depth", 1),
             stats=self.emit_stats, faults=self.faults,
             on_fault=self.on_fault)
-        # ingest staging window (@app:execution('tpu', ingest.depth='N')):
-        # depth 2 defers each batch's count-gate fetch until the NEXT
-        # batch's H2D put + step dispatch are in flight; depth 1 (the
-        # default) finishes inline, identical to synchronous ingest
+        # ingest staging window: unset (None) the stage chooses per
+        # batch between finishing the count gate inline and leaving one
+        # batch in flight behind the next dispatch (PipelineRule);
+        # @app:execution('tpu', ingest.depth='N') pins it
         self.ingest_stats = IngestStats()
         self.ingest_stage = IngestStage(
             depth=getattr(app_context, "tpu_ingest_depth", 1),
             stats=self.ingest_stats, faults=self.faults,
-            on_fault=self.on_fault)
+            on_fault=self.on_fault,
+            finisher=getattr(app_context, "idle_finisher", None))
+        self.emit_queue.step_in_flight = self.ingest_stage.__len__
         # last known-poison-free host copy of the quarantined state,
         # kept only while a state.poison fault is watched
         self._last_good = None
@@ -163,6 +170,7 @@ class DevicePipeline:
     def cycle(self, n: int, kind: Optional[str] = None):
         """``with pipeline.cycle(n) as tok``: one sampled-or-None cycle
         token per junction batch, its ingest span starting here."""
+        self.ingest_stage.arrive()
         if self.tracer is None:
             return _NO_CYCLE
         tok = self.tracer.begin_cycle(kind or self.engine_kind, n)
@@ -176,29 +184,35 @@ class DevicePipeline:
 
         The count-gate fetch (``resolve``) is what blocks on the device;
         staging it lets batch N+1's H2D put + step dispatch go out
-        before batch N's scalar is fetched."""
-        queue = self.emit_queue
+        before batch N's scalar is fetched.  ``finish`` returns how
+        long it kept the host: what the stage's rule goes by."""
+        queue, stage = self.emit_queue, self.ingest_stage
 
         def finish():
+            blocked = None
             if pending is None:
                 c = 0
-            elif tok is None:
-                c = pending.resolve()
             else:
-                with tok.step_wait():
+                t0 = stage.clock()
+                if tok is None:
                     c = pending.resolve()
+                else:
+                    with tok.step_wait():
+                        c = pending.resolve()
+                blocked = stage.clock() - t0
             if tok is not None:
                 # count gate resolved: the jitted step finished
                 tok.step_done(c)
             if c == 0:
                 queue.skip()
-                return
-            queue.push(PendingEmit(pending.device_arrays(), deliver,
-                                   trace=tok))
+            else:
+                queue.push(PendingEmit(pending.device_arrays(), deliver,
+                                       trace=tok))
+            return blocked
 
-        self.ingest_stage.submit(
+        stage.submit(
             pending.probe() if pending is not None else None, finish,
-            trace=tok)
+            trace=tok, may_defer=self._in_lock())
 
     def drain(self) -> None:
         """Flush barrier: materialize and emit every queued batch (one
@@ -207,9 +221,35 @@ class DevicePipeline:
         decisions, pull queries, purges, shutdown, debugger.  The ingest
         stage flushes first: staged batches must enqueue (or skip)
         before the emit queue drains, preserving the synchronous
-        callback order."""
-        self.ingest_stage.flush()
-        self.emit_queue.drain()
+        callback order.
+
+        A stage that has ever left a batch in flight may be in the idle
+        finisher's hands, which finishes under ``process_lock``: from
+        then on a drain takes that lock too (re-entrant for a caller
+        inside it), so a bare ``drain()`` from a client thread is safe.
+        A stage that never did has no finisher to meet and drains as it
+        always has, without it: a junction's async worker, which holds
+        no lock and never defers, must not wait here for the lock of a
+        sender that waits for the worker's queue."""
+        lock = getattr(self._ctx, "process_lock", None)
+        if lock is not None and self.ingest_stats.pipeline_entries:
+            with lock:
+                self.ingest_stage.flush()
+                self.emit_queue.drain()
+        else:
+            self.ingest_stage.flush()
+            self.emit_queue.drain()
+
+    def _in_lock(self) -> bool:
+        """Does the calling thread hold the app's ``process_lock``
+        (``send_batch``, a scheduler tick, a snapshot; read from the
+        context when used: a replan swaps it)?  Only then may a batch
+        stay in flight: the finisher, which takes that lock, cannot meet
+        a submit made inside it.  ``RLock._is_owned`` is CPython's own
+        (``threading.Condition`` uses it); without it nothing defers."""
+        owned = getattr(getattr(self._ctx, "process_lock", None),
+                        "_is_owned", None)
+        return owned is not None and owned()
 
     # -- faults --------------------------------------------------------------
 

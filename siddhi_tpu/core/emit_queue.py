@@ -83,7 +83,8 @@ def _is_device_array(a) -> bool:
     return not isinstance(a, (np.ndarray, np.generic, int, float, bool))
 
 
-def fetch_coalesced(arrays: Sequence) -> List[np.ndarray]:
+def fetch_coalesced(arrays: Sequence,
+                    on_device: bool = True) -> List[np.ndarray]:
     """One device→host round trip for a list of arrays.
 
     Device arrays are grouped by (dtype, trailing shape), each group is
@@ -91,6 +92,11 @@ def fetch_coalesced(arrays: Sequence) -> List[np.ndarray]:
     fetched with a single ``jax.device_get``, and the result is split
     back host-side in input order.  Host numpy arrays pass through
     untouched.  Counts as ONE emit transfer.
+
+    ``on_device`` False fetches every array as it is, still in the one
+    ``device_get`` (its copies are all started before any is awaited):
+    a concatenation is a program, and a program dispatched while a step
+    is in flight runs after that step.
     """
     if not arrays:
         return []
@@ -101,8 +107,8 @@ def fetch_coalesced(arrays: Sequence) -> List[np.ndarray]:
             out[i] = np.asarray(a)
             continue
         shape = getattr(a, "shape", ())
-        if len(shape) == 0:
-            key = ("scalar", i)  # 0-d: no concat axis; fetch alone
+        if len(shape) == 0 or not on_device:
+            key = ("alone", i)  # 0-d: no concat axis; fetch alone
         else:
             key = (str(a.dtype), tuple(shape[1:]))
         groups.setdefault(key, []).append(i)
@@ -257,6 +263,11 @@ class EmitQueue:
         self.stats = stats or EmitStats()
         self.faults = faults
         self.on_fault = on_fault
+        # is a later step dispatched and not yet waited for (the ingest
+        # stage holds a batch in flight)?  Then a drain concatenates
+        # nothing on the device: that program would queue behind the
+        # step and the fetch wait it out.  The pipeline wires it.
+        self.step_in_flight: Callable[[], bool] = lambda: False
         self._entries: List[PendingEmit] = []
 
     def __len__(self) -> int:
@@ -283,15 +294,16 @@ class EmitQueue:
         with bounded retry-with-backoff on transient transfer faults
         (sticky device loss and other errors propagate immediately)."""
         fi = self.faults
+        on_device = not self.step_in_flight()
         if fi is None:
-            return fetch_coalesced(arrays)
+            return fetch_coalesced(arrays, on_device)
         attempts = fi.transfer_retry_attempts
         backoff = None
         attempt = 0
         while True:
             try:
                 fi.check("emit.drain")
-                host = fetch_coalesced(arrays)
+                host = fetch_coalesced(arrays, on_device)
                 if attempt:
                     fi.stats.drains_recovered += 1
                 return host

@@ -7,6 +7,8 @@ rides a :class:`CycleToken` through the existing async machinery:
     DevicePipeline.cycle (a runtime's batch)    -> begin_cycle (t0)
     IngestStage.submit (put + step dispatched)  -> tok.dispatched()  [ingest span]
     DevicePipeline.submit (count gate resolved) -> tok.step_done(n)  [step span]
+      (a gate left staged behind the next dispatch: tok.step_begins() first,
+       so the step span is always the host blocked on the gate)
     EmitQueue.drain (batch materialized)        -> tok.emitted(t0)   [emit span]
 
 Inside those three, one flat vocabulary tiles the rest of a batch's
@@ -254,10 +256,17 @@ class CycleToken:
         self.record(STAGE_INGEST, self.t0, now, self.n_events)
         self.t_dispatch = now
 
+    def step_begins(self) -> None:
+        """The count gate was left staged behind a later dispatch and is
+        fetched only now: the step span, the host blocked on this
+        cycle's gate, starts here and not at the dispatch."""
+        self.t_dispatch = self.tracer.clock()
+
     def step_wait(self):
         """``siddhi.step_wait`` on the profiler's clock, for the caller
         to hold around the blocking count-gate fetch; the ring's
-        ``step`` span (from dispatch to ``step_done``) is the record."""
+        ``step`` span (from dispatch, or from ``step_begins`` for a gate
+        that was deferred, to ``step_done``) is the record."""
         return annotation(ANNOTATION_STEP_WAIT)
 
     def step_done(self, n_emit: int) -> None:

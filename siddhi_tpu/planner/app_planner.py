@@ -133,14 +133,17 @@ class AppPlanner:
                             f"@app:execution: emit.depth='{depth}' must be "
                             "a positive integer or 'auto'")
                     self.app_context.tpu_emit_depth = ed
+            # ingest.depth pins the staging window (core/ingest_stage.py):
+            # '1' finishes every count gate inline, so send_batch always
+            # returns with its callbacks delivered; 'N' > 1 keeps each
+            # gate behind the next N-1 dispatches, finished by them or a
+            # barrier alone.  Unset (and 'auto', which says the same)
+            # the stage chooses per batch from what it observes, at most
+            # one batch in flight, an idle one finished within a cycle.
             idepth = exec_ann.element("ingest.depth")
             if idepth:
                 if idepth.lower() == "auto":
-                    # adaptive: the staging window derives its depth
-                    # from observed count-fetch RTT vs batch cadence
-                    # (core/ingest_stage.py, same controller as
-                    # emit.depth='auto')
-                    self.app_context.tpu_ingest_depth = "auto"
+                    self.app_context.tpu_ingest_depth = None
                 else:
                     try:
                         nid = int(idepth)

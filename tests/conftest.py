@@ -35,3 +35,25 @@ def _assert_virtual_mesh():
     assert platform == "cpu" and n >= 8, (
         f"virtual CPU mesh failed to materialize: {n} {platform} devices"
     )
+
+
+@pytest.fixture
+def force_pipelined(monkeypatch):
+    """``force_pipelined(idle=True)``: the ingest stage's rule
+    (core/ingest_stage.py ``PipelineRule``) says 'one batch in flight'
+    at every arrival, whatever it observes; a barrier or an idle finish
+    still returns the stage to inline until the next arrival.  With
+    ``idle`` False the idle finisher never finds a gate overdue, so
+    what is staged stays staged until a submit or a barrier takes it."""
+    from siddhi_tpu.core import ingest_stage
+
+    def force(idle=True):
+        def arrival(self, think_s):
+            self.pipelined = True
+
+        monkeypatch.setattr(ingest_stage.PipelineRule, "arrival", arrival)
+        if not idle:
+            monkeypatch.setattr(ingest_stage, "IDLE_CYCLES", 1e9)
+            monkeypatch.setattr(ingest_stage, "IDLE_MAX_S", 3600.0)
+
+    return force

@@ -17,7 +17,20 @@ promises:
 - a sampled cycle holds one ``step_wait`` and one ``step`` span;
 - an exception that leaves the shell's own work closes the cycle's
   token as raised.
+
+And, with no ``ingest.depth`` and the stage's rule forced on
+(``force_pipelined`` of conftest.py), to what holds of a batch left in
+flight behind the next dispatch: the same callbacks in the same order
+whoever finishes its gate (the next submit, a barrier, the idle
+finisher with or without a ``drain()`` racing it from another thread);
+a failing deferred gate drops that batch only; its ``step`` span starts
+at its ``resolve()``; the counters say who finished what; ``shutdown()``
+leaves no thread behind.
 """
+
+import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -216,10 +229,17 @@ def test_failing_count_gate_drops_one_batch(kind):
         assert len(app.pipe.emit_queue) == 0
 
 
+@pytest.mark.parametrize("window", ["depth2", "rule"])
 @pytest.mark.parametrize("kind", KINDS)
-def test_drain_finishes_staged_batches_before_emits(kind):
+def test_drain_finishes_staged_batches_before_emits(kind, window,
+                                                    force_pipelined):
     want = [r for rows in reference(kind) for r in rows]
-    with Deployed(kind, depths="ingest.depth='2', emit.depth='4'") as app:
+    depths = "emit.depth='4'"
+    if window == "rule":
+        force_pipelined(idle=False)
+    else:
+        depths = "ingest.depth='2', " + depths
+    with Deployed(kind, depths=depths) as app:
         for i in range(N_BATCHES):
             app.send(i)
         stage, queue = app.pipe.ingest_stage, app.pipe.emit_queue
@@ -230,6 +250,162 @@ def test_drain_finishes_staged_batches_before_emits(kind):
         assert len(stage) == 0 and len(queue) == 0
         assert app.rows == want
         assert app.pipe.ingest_stats.dropped_batches == 0 and not app.errors
+
+
+def idle_threads():
+    return [t for t in threading.enumerate()
+            if t.name.startswith("ingest-idle-")]
+
+
+def wait_for(cond, seconds=20.0):
+    deadline = time.monotonic() + seconds
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.002)
+    return cond()
+
+
+@pytest.mark.parametrize("racing_drain", [False, True],
+                         ids=["alone", "racing_drain"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_burst_ends_and_its_last_batch_is_delivered(kind, racing_drain,
+                                                    force_pipelined):
+    """No further send, no barrier: the idle finisher hands the last
+    batch of a burst to the callback, in order, once; a bare ``drain()``
+    from another thread all through the burst changes nothing."""
+    want = [r for rows in reference(kind) for r in rows]
+    force_pipelined()
+    with Deployed(kind) as app:
+        if CASES[kind][4] is None:   # (a set-up sends a batch of its own)
+            assert not idle_threads(), "a thread before a batch was staged"
+        stop, raised = threading.Event(), []
+
+        def drains():
+            while not stop.is_set():
+                try:
+                    app.shell.drain()   # bare: the client holds no lock
+                except Exception as e:  # noqa: BLE001 — the test's verdict
+                    raised.append(e)
+                    return
+
+        interval = sys.getswitchinterval()
+        racer = threading.Thread(target=drains)
+        try:
+            app.send(0)     # the stage has left a batch in flight:
+            if racing_drain:    # from here on every drain takes the lock
+                sys.setswitchinterval(1e-5)
+                racer.start()
+            for i in range(1, N_BATCHES):
+                app.send(i)
+            stop.set()
+            if racing_drain:
+                racer.join(20.0)
+                assert not racer.is_alive()
+        finally:
+            stop.set()
+            sys.setswitchinterval(interval)
+        assert wait_for(lambda: len(app.rows) >= len(want)), (
+            len(app.rows), len(want))
+        assert wait_for(lambda: len(app.pipe.ingest_stage) == 0)
+        with app.rt.app_context.process_lock:   # a finish in progress ends
+            assert app.rows == want and not raised and not app.errors
+        st = app.pipe.ingest_stats
+        assert st.dropped_batches == 0
+        assert st.pipeline_entries >= 1
+        assert st.pipeline_exits in (st.pipeline_entries,
+                                     st.pipeline_entries - 1)
+        if not racing_drain:
+            assert st.flush_syncs == 0
+            assert st.gates_by_idle >= 1
+            # nothing arrived after the last batch: the stage is inline
+            assert wait_for(lambda: app.pipe.ingest_stage.depth == 1)
+        assert st.gates_by_submit + st.gates_by_idle + st.flush_syncs >= 1
+        assert len(idle_threads()) == 1
+        finisher = app.rt.app_context.idle_finisher
+        assert finisher.alive()
+        app.rt.shutdown()
+        assert not finisher.alive() and not idle_threads()
+        assert app.rows == want
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_inline_app_never_starts_the_finisher(kind):
+    with Deployed(kind) as app:
+        for i in range(N_BATCHES):
+            app.send(i)
+        st = app.pipe.ingest_stats
+        assert (st.pipeline_entries, st.gates_by_submit, st.gates_by_idle,
+                st.max_staging_depth) == (0, 0, 0, 1)
+        assert st.as_dict()["autoIngestDepth"] == 1
+        assert not app.rt.app_context.idle_finisher.alive()
+        assert not idle_threads()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_failing_deferred_gate_drops_one_batch(kind, force_pipelined):
+    per_batch = reference(kind)
+    want = [r for i, rows in enumerate(per_batch) if i != 3 for r in rows]
+    force_pipelined(idle=False)
+    with Deployed(kind) as app:
+        for i in range(3):
+            app.send(i)
+        real, broken = app.pipe.submit, []
+
+        def breaking(tok, pending, deliver):
+            broken.append(pending)
+            real(tok, _BrokenGate(pending), deliver)
+
+        app.pipe.submit = breaking
+        app.send(3)
+        del app.pipe.submit
+        if kind != "hotkey":    # (submits twice a batch: cold rows, hot)
+            assert not app.errors, "the gate is staged: nothing failed yet"
+        for i in range(4, N_BATCHES):
+            app.send(i)
+        app.shell.drain()
+        assert broken and per_batch[3]
+        assert app.rows == want
+        assert app.pipe.ingest_stats.dropped_batches == len(broken)
+        assert [str(e) for e in app.errors] == [
+            "injected count-gate failure"] * len(broken)
+        assert len(app.pipe.ingest_stage) == 0
+        assert len(app.pipe.emit_queue) == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_deferred_step_span_starts_at_its_resolve(kind, force_pipelined):
+    """``step`` is the host blocked on the cycle's count gate: from the
+    dispatch when the gate is finished inline, from the start of its
+    ``resolve()``, after the NEXT cycle's dispatch, when it was left
+    staged."""
+    force_pipelined(idle=False)
+    with Deployed(kind, trace="@app:trace(sample='1', cycles='32') ") as app:
+        for i in range(N_BATCHES):
+            app.send(i)
+        app.shell.drain()
+        # every cycle that went through this stage, in submit order (the
+        # hot-key shell's cold rows are cycles of kind 'dense')
+        groups = {cid: {s[1]: s for s in spans}
+                  for cid, spans in app.rt.app_context.tracer.recorder
+                  .cycle_groups().items()}
+        on_stage = {app.engine_kind} | ({"dense"} if kind == "hotkey"
+                                        else set())
+        ours = [g for _cid, g in sorted(groups.items())
+                if "step" in g and g["step"][2] in on_stage]
+        assert any(g["step"][2] == app.engine_kind for g in ours)
+        assert len(ours) >= N_BATCHES - 1
+        st = app.pipe.ingest_stats
+        assert st.gates_by_submit >= N_BATCHES - 2 and st.flush_syncs >= 1
+        inline = deferred = 0
+        for cur, nxt in zip(ours, ours[1:]):
+            ingest, step = cur["ingest"], cur["step"]
+            if step[3] == ingest[4]:
+                inline += 1
+                continue
+            deferred += 1
+            # left staged: fetched after the next cycle's dispatch
+            assert step[3] >= nxt["ingest"][4] > ingest[4]
+        # the first arrival has no think and is finished inline
+        assert inline <= 1 and deferred >= N_BATCHES - 2
 
 
 @pytest.mark.parametrize("kind", KINDS)

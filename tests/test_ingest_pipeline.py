@@ -18,7 +18,14 @@ assert the IngestStats evidence that staging actually happened
 barrier ``flush_syncs``).  ``emit.depth='auto'`` rides along: the
 controller's effective depth must track rtt/cadence and never exceed
 its bound, with output still bit-identical to host.
+
+Left alone (no ``ingest.depth``) the stage chooses the window itself
+(``PipelineRule``): the rule is held to its promises on an injected
+clock, and the fault and crash differentials run a second time with the
+rule forced on (``pipelined`` fixture) in place of the pinned depth 2.
 """
+
+import threading
 
 import numpy as np
 import pytest
@@ -30,6 +37,12 @@ from siddhi_tpu.core.emit_queue import EmitDepthController
 from siddhi_tpu.core.exceptions import (
     SiddhiAppCreationError,
     SimulatedCrashError,
+)
+from siddhi_tpu.core.ingest_stage import (
+    BLOCKED_MIN_S,
+    ENGAGE_RUN,
+    IdleFinisher,
+    IngestStage,
 )
 from siddhi_tpu.util.persistence import InMemoryPersistenceStore
 
@@ -51,6 +64,19 @@ ENGINES = {
     "dense_nfa": (", instances='32'", PATTERN_APP),
     "sharded": (", partitions='16', devices='8'", AGG_APP),
 }
+
+
+# how the staged cases open the window: pinned by the annotation, or
+# left to the rule with the rule forced on
+WINDOWS = {"depth2": ", ingest.depth='2'", "rule": ""}
+
+
+@pytest.fixture(params=sorted(WINDOWS))
+def window(request, force_pipelined):
+    """The @app:execution tail that opens the staging window."""
+    if request.param == "rule":
+        force_pipelined()
+    return WINDOWS[request.param]
 
 
 def series(n, seed, n_keys=4, t0=1000, dt_max=400):
@@ -210,14 +236,17 @@ class TestIngestFlushBarriers:
 
 class TestIngestFaultDifferential:
     @pytest.mark.parametrize("engine", sorted(ENGINES))
-    def test_transient_ingest_put_recovered_staged(self, engine):
+    def test_transient_ingest_put_recovered_staged(self, engine, window):
         extra, body = ENGINES[engine]
         sends = series(40, seed=31, n_keys=4)
-        clean, _, _ = run_app(body, sends,
-                              exec_opts=f"{extra}, ingest.depth='2'",
-                              want_runtime=True)
+        clean, stage_rt, _ = run_app(body, sends,
+                                     exec_opts=f"{extra}{window}",
+                                     want_runtime=True)
+        assert stage_rt.ingest_stats.max_staging_depth == 2
+        assert clean == run_app(body, sends,
+                                exec_opts=f"{extra}, ingest.depth='1'")
         chaotic, _, rt = run_app(
-            body, sends, exec_opts=f"{extra}, ingest.depth='2'",
+            body, sends, exec_opts=f"{extra}{window}",
             faults=("transfer.retry.scale='0.0001', "
                     "ingest.put='transient:count=2'"),
             want_runtime=True)
@@ -230,16 +259,18 @@ class TestIngestFaultDifferential:
         assert fi.stats.drains_failed == 0
 
     @pytest.mark.parametrize("engine", sorted(ENGINES))
-    def test_crash_recovery_staged_bit_identical(self, engine):
+    def test_crash_recovery_staged_bit_identical(self, engine, window):
         """Crash mid-stream with batches in the staging window: the
         journal replay on a fresh runtime must reproduce the exact
         uninterrupted sequence (staged ingest defers only EMISSION —
         journal + checkpoint semantics are untouched)."""
         extra, body = ENGINES[engine]
-        exec_opts = f"{extra}, ingest.depth='2'"
+        exec_opts = f"{extra}{window}"
         sends = series(30, seed=32, n_keys=3)
         ref = run_app(body, sends, exec_opts=exec_opts)
         assert len(ref) > 4, "series too tame; differential is vacuous"
+        assert ref == run_app(body, sends,
+                              exec_opts=f"{extra}, ingest.depth='1'")
 
         header = ("@app:name('ingestcrash') @app:playback "
                   "@app:faults(journal='256') "
@@ -282,6 +313,286 @@ class TestIngestFaultDifferential:
                 "from the uninterrupted run")
         finally:
             m.shutdown()
+
+
+# -- the rule, on an injected clock -------------------------------------------
+
+
+class Clock:
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+
+class Watcher:
+    """What a stage needs of the app's idle finisher, with no thread."""
+
+    def __init__(self):
+        self.watched = []
+
+    def watch(self, stage):
+        self.watched.append(stage)
+
+
+class Driven:
+    """A stage driven batch by batch: the caller thinks, the host
+    prepares, the device steps; an inline gate keeps the host for what
+    is left of the step, a deferred one for what the next batch's
+    preparation did not cover."""
+
+    HOST_S = 0.002
+
+    def __init__(self, depth=None):
+        self.clock = Clock()
+        self.finisher = Watcher()
+        self.stage = IngestStage(depth, finisher=self.finisher,
+                                 clock=self.clock)
+        self.done_at = {}       # batch -> when its step is done
+        self.finished = []      # batches in the order their gates resolved
+        self.n = 0
+
+    def batch(self, think_s, step_s):
+        n, stage, clock = self.n, self.stage, self.clock
+        self.n += 1
+        clock.t += think_s
+        stage.arrive()
+        clock.t += self.HOST_S
+        # the device takes one step at a time
+        self.done_at[n] = max(clock.t, self.done_at.get(n - 1, 0.0)) + step_s
+
+        def finish():
+            blocked = max(0.0, self.done_at[n] - clock.t)
+            clock.t += blocked
+            self.finished.append(n)
+            return blocked
+
+        stage.submit(None, finish)
+        return stage.depth
+
+    def stream(self, n, think_s, step_s):
+        return [self.batch(think_s, step_s) for _ in range(n)]
+
+
+class TestPipelineRule:
+    STEP = 3 * BLOCKED_MIN_S
+
+    def test_engages_after_the_run_and_not_before(self):
+        d = Driven()
+        depths = d.stream(ENGAGE_RUN + 4, think_s=self.STEP / 20,
+                          step_s=self.STEP)
+        # batch 0 has no think; ENGAGE_RUN arrivals qualify after it
+        assert depths == [1] * ENGAGE_RUN + [2] * 4
+        st = d.stage.stats
+        assert (st.pipeline_entries, st.pipeline_exits) == (1, 0)
+        assert st.auto_depth == 2 and st.max_staging_depth == 2
+        # one batch in flight, its gate finished by the next submit
+        assert len(d.stage) == 1
+        assert d.finished == list(range(d.n - 1))
+        assert st.gates_by_submit == 3
+        assert d.finisher.watched == [d.stage]
+
+    @pytest.mark.parametrize("think_s, step_s", [
+        (40 * BLOCKED_MIN_S, 3 * BLOCKED_MIN_S),   # a slow cadence
+        (3 * BLOCKED_MIN_S, 3 * BLOCKED_MIN_S),    # back as late as the wait
+        (0.0, BLOCKED_MIN_S / 2),                  # a wait not worth hiding
+        (0.0, 0.0),                                # no wait at all
+    ], ids=["slow_cadence", "think_equals_wait", "short_wait", "no_wait"])
+    def test_never_engages(self, think_s, step_s):
+        d = Driven()
+        assert set(d.stream(200, think_s, step_s)) == {1}
+        st = d.stage.stats
+        assert (st.pipeline_entries, st.gates_by_submit) == (0, 0)
+        assert d.finished == list(range(200)) and len(d.stage) == 0
+        assert d.finisher.watched == []
+
+    def test_a_run_broken_once_starts_anew(self):
+        d = Driven()
+        d.stream(ENGAGE_RUN, think_s=0.0, step_s=self.STEP)
+        assert d.batch(think_s=self.STEP, step_s=self.STEP) == 1
+        assert set(d.stream(ENGAGE_RUN - 1, 0.0, self.STEP)) == {1}
+        assert d.batch(0.0, self.STEP) == 2
+
+    def test_leaves_on_one_long_gap_staged_batch_first(self):
+        d = Driven()
+        d.stream(ENGAGE_RUN + 3, think_s=0.0, step_s=self.STEP)
+        assert d.stage.depth == 2 and len(d.stage) == 1
+        staged = d.n - 1
+        assert d.batch(think_s=2 * self.STEP, step_s=self.STEP) == 1
+        # the staged batch's gate, then the arriving batch's, inline
+        assert d.finished[-2:] == [staged, staged + 1]
+        assert len(d.stage) == 0
+        assert d.stage.stats.pipeline_exits == 1
+        # and the way back in is a whole run again
+        assert d.stream(ENGAGE_RUN, 0.0, self.STEP) == (
+            [1] * (ENGAGE_RUN - 1) + [2])
+
+    @pytest.mark.parametrize("engaged", [False, True])
+    def test_a_gap_between_the_thresholds_changes_nothing(self, engaged):
+        """Hysteresis: in needs a think under a quarter of the wait,
+        out one over the whole of it; between them the regime stays."""
+        d = Driven()
+        if engaged:
+            d.stream(ENGAGE_RUN + 1, 0.0, self.STEP)
+        want = 2 if engaged else 1
+        for i in range(100):
+            think = self.STEP * (0.3 if i % 2 else 0.9)
+            assert d.batch(think, self.STEP) == want
+        st = d.stage.stats
+        assert st.pipeline_entries == int(engaged) and st.pipeline_exits == 0
+
+    def test_a_barrier_returns_the_stage_to_inline(self):
+        d = Driven()
+        d.stream(ENGAGE_RUN + 2, 0.0, self.STEP)
+        assert d.stage.depth == 2 and len(d.stage) == 1
+        d.stage.flush()
+        st = d.stage.stats
+        assert (d.stage.depth, len(d.stage)) == (1, 0)
+        assert (st.flush_syncs, st.pipeline_exits, st.auto_depth) == (1, 1, 1)
+        assert d.finished == list(range(d.n))
+        assert d.stage.idle_wait() is None
+        assert d.batch(0.0, self.STEP) == 1
+
+    def test_a_hidden_gate_is_a_short_wait(self):
+        """In the regime the host pays its own work a batch, not its
+        work plus the step: what the rule is for."""
+        d = Driven()
+        d.stream(ENGAGE_RUN + 1, 0.0, self.STEP)
+        t0 = d.clock.t
+        d.stream(50, 0.0, self.STEP)
+        per_batch = (d.clock.t - t0) / 50
+        assert per_batch == pytest.approx(self.STEP, rel=1e-6)
+        assert per_batch < d.HOST_S + self.STEP
+
+    @pytest.mark.parametrize("depth", [1, 2, 3])
+    def test_a_pinned_depth_knows_no_rule(self, depth):
+        d = Driven(depth)
+        assert set(d.stream(40, 0.0, self.STEP)) == {depth}
+        st = d.stage.stats
+        assert (st.pipeline_entries, st.auto_depth) == (0, 0)
+        assert len(d.stage) == depth - 1
+        assert d.finisher.watched == [] and d.stage.idle_wait() is None
+
+    def test_without_a_finisher_the_window_stays_shut(self):
+        """A staged gate never waits for an arrival that does not come:
+        no finisher (a runtime built by hand), no deferral."""
+        d = Driven()
+        d.stage.finisher = None
+        assert set(d.stream(3 * ENGAGE_RUN, 0.0, self.STEP)) == {1}
+        assert d.stage.stats.pipeline_entries == 0 and len(d.stage) == 0
+
+    def test_auto_means_the_rule(self):
+        assert IngestStage("auto").rule is not None
+        assert IngestStage(None).rule is not None
+        assert IngestStage(2).rule is None
+
+
+class TestOutsideTheLock:
+    def test_an_async_junctions_worker_never_defers(self, force_pipelined):
+        """The worker of an ``@async`` junction holds no ``process_lock``
+        (a sender blocked on its full queue may): its submits finish
+        inline whatever the rule says, its stage never meets the
+        finisher, and its drains take no lock."""
+        force_pipelined()
+        sends = series(60, seed=51)
+        host = run_app(FILTER_APP, sends)
+        m = SiddhiManager()
+        try:
+            rt = m.create_siddhi_app_runtime(
+                "@app:playback @app:execution('tpu') "
+                "@async(buffer.size='64', batch.size.max='4') "
+                + FILTER_APP)
+            got = []
+            rt.add_callback("OutputStream",
+                            lambda evs: got.extend(tuple(e.data)
+                                                   for e in evs))
+            rt.start()
+            h = rt.get_input_handler("S")
+            for row, ts in sends:
+                h.send(row, timestamp=ts)
+            drt = next(iter(rt.query_runtimes.values())).device_runtime
+            served = threading.Event()
+            for _ in range(2000):       # the worker serves its queue
+                if len(got) >= len(host):
+                    break
+                served.wait(0.005)
+            rt.shutdown()
+            st = drt.ingest_stats
+            assert got == host
+            assert st.staged_batches > 0
+            assert (st.pipeline_entries, st.gates_by_submit,
+                    st.gates_by_idle, st.max_staging_depth) == (0, 0, 0, 1)
+            assert not rt.app_context.idle_finisher.alive()
+        finally:
+            m.shutdown()
+
+
+class TestIdleFinisher:
+    """The thread alone, on stages whose clock is the real one."""
+
+    def staged(self, finisher, done, grace_s=0.01):
+        stage = IngestStage(2, finisher=finisher)
+        stage.submit(None, lambda: done.append(len(done)))
+        # what a submit under the rule leaves behind it
+        stage._idle_mark = (stage._seq, stage.clock() + grace_s, None)
+        finisher.watch(stage)
+        return stage
+
+    def test_finishes_what_nothing_came_for_and_stops(self):
+        fin, done = IdleFinisher(), []
+        stage = self.staged(fin, done)
+        assert fin.alive()
+        deadline = stage.clock() + 10.0
+        while not done and stage.clock() < deadline:
+            threading.Event().wait(0.005)
+        assert done == [0] and len(stage) == 0
+        assert stage.stats.gates_by_idle == 1
+        assert stage.idle_wait() is None
+        fin.stop()
+        assert not fin.alive()
+
+    def test_never_started_without_a_staged_batch(self):
+        fin = IdleFinisher()
+        assert not fin.alive()
+        fin.stop()
+        assert not fin.alive()
+
+    def test_a_held_lock_holds_it_off_and_stop_does_not_hang(self):
+        fin, done = IdleFinisher(), []
+        with fin.lock():
+            stage = self.staged(fin, done, grace_s=0.0)
+            threading.Event().wait(0.2)
+            assert done == [] and len(stage) == 1
+            fin.stop(timeout=10.0)   # holding the lock it wants
+            assert not fin.alive()
+        assert done == []
+        stage.flush()
+        assert done == [0]
+
+    def test_a_barrier_from_another_thread_wins_once(self):
+        fin = IdleFinisher()
+        for _round in range(20):
+            done = []
+            stage = self.staged(fin, done, grace_s=0.002)
+
+            def barrier():
+                with fin.lock():
+                    stage.flush()
+
+            t = threading.Thread(target=barrier)
+            threading.Event().wait(0.002)
+            t.start()
+            t.join(10.0)
+            assert not t.is_alive()
+            deadline = stage.clock() + 10.0
+            while not done and stage.clock() < deadline:
+                threading.Event().wait(0.001)
+            assert done == [0], "finished once, by one of the two"
+            st = stage.stats
+            assert st.gates_by_idle + st.flush_syncs == 1
+        fin.stop()
+        assert not fin.alive()
 
 
 class TestAutoEmitDepth:
@@ -378,3 +689,44 @@ class TestAnnotationValidation:
             rt.shutdown()
         finally:
             m.shutdown()
+
+    def test_statistics_expose_the_rules_counters(self, force_pipelined):
+        """Who finished the gates, and how often the window opened, ride
+        ``statistics()`` beside ``overlappedBatches`` / ``ingestStalls``."""
+        force_pipelined(idle=False)
+        app = ("@app:name('ruleApp') @app:statistics('true') "
+               "@app:playback @app:execution('tpu') " + DEFINE +
+               "@info(name='q') from S[v > 50.0] select k, v "
+               "insert into OutputStream;")
+        m = SiddhiManager()
+        try:
+            rt = m.create_siddhi_app_runtime(app)
+            got = []
+            rt.add_callback("OutputStream", got.extend)
+            rt.start()
+            h = rt.get_input_handler("S")
+            for i, v in enumerate([60.0, 70.0, 10.0, 80.0]):
+                h.send([i, v], timestamp=1000 + i)
+            pre = "io.siddhi.SiddhiApps.ruleApp.Siddhi.Queries.q."
+            stats = rt.statistics()
+            assert len(got) == 2, "the third gate skips, the fourth is staged"
+            assert stats[pre + "stagedBatches"] == 4
+            assert stats[pre + "gatesBySubmit"] == 3
+            assert stats[pre + "gatesByIdle"] == 0
+            assert stats[pre + "flushSyncs"] == 0
+            assert stats[pre + "pipelineEntries"] == 1
+            assert stats[pre + "pipelineExits"] == 0
+            assert stats[pre + "autoIngestDepth"] == 2
+            assert stats[pre + "maxStagingDepth"] == 2
+            assert (stats[pre + "overlappedBatches"]
+                    + stats[pre + "ingestStalls"]) == 3
+            rt.drain_device_emits()     # a barrier
+            stats = rt.statistics()
+            assert len(got) == 3
+            assert stats[pre + "flushSyncs"] == 1
+            assert stats[pre + "pipelineExits"] == 1
+            assert stats[pre + "autoIngestDepth"] == 1
+            rt.shutdown()
+        finally:
+            m.shutdown()
+

