@@ -635,6 +635,9 @@ class DensePatternRuntime:
 
     def _check_overflow(self):
         total = self.overflow_total()
+        # Queries.<q>.droppedInstances in statistics(): the polled
+        # total, so reading it costs no device fetch
+        self.emit_stats.dropped_instances = total
         if total > self._ovf_warned:
             msg = (
                 f"dense pattern '{self.out_stream_id}': "

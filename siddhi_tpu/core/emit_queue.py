@@ -7,7 +7,8 @@ device runtime shares:
 
 - ``EmitStats``: per-runtime transfer counters surfaced through
   ``util/statistics.py`` (``emitTransfers`` / ``deferredBatches`` /
-  ``zeroMatchSkips`` / ``maxPendingDepth``).
+  ``zeroMatchSkips`` / ``maxPendingDepth``, and ``droppedInstances``,
+  the dense pattern runtime's overflow total as of its last poll).
 - ``EmitQueue``: a bounded pending-emit queue.  Each entry is one
   junction batch whose match outputs are still resident on the device;
   when the queue reaches its configured depth (``emit.depth`` on
@@ -48,7 +49,8 @@ class EmitStats:
     util/statistics.py)."""
 
     __slots__ = ("emit_transfers", "deferred_batches", "zero_match_skips",
-                 "dropped_batches", "max_pending_depth", "auto_depth")
+                 "dropped_batches", "max_pending_depth", "auto_depth",
+                 "dropped_instances")
 
     def __init__(self):
         self.emit_transfers = 0
@@ -63,6 +65,11 @@ class EmitStats:
         # effective depth the 'auto' controller is currently running at
         # (0 = static emit.depth, no controller)
         self.auto_depth = 0
+        # pending pattern instances dropped because every lane of their
+        # node was taken, as of the dense runtime's last overflow poll
+        # (core/dense_pattern.py _check_overflow): rows the host engine
+        # would have emitted may be missing once this is not 0
+        self.dropped_instances = 0
 
     def note_depth(self, depth: int):
         if depth > self.max_pending_depth:
@@ -76,6 +83,7 @@ class EmitStats:
             "droppedBatches": self.dropped_batches,
             "maxPendingDepth": self.max_pending_depth,
             "autoEffectiveDepth": self.auto_depth,
+            "droppedInstances": self.dropped_instances,
         }
 
 

@@ -120,6 +120,9 @@ ANNOTATION_STEP_WAIT = "step_wait"
 #: device trace groups operations by phase whatever XLA names them
 SCOPE_DENSE_GATHER = "siddhi.dense.gather"      # ops/dense_nfa.py make_step
 SCOPE_DENSE_ADVANCE = "siddhi.dense.advance"
+# inside advance, a count node's part: the capture and count update, the
+# `every` re-arm at the minimum, an open count's via-path clone
+SCOPE_DENSE_KLEENE = "siddhi.dense.kleene"
 SCOPE_DENSE_SCATTER = "siddhi.dense.scatter"
 SCOPE_DENSE_COUNT = "siddhi.dense.count"
 # make_rounds: the wide rounds past a batch's first, and the run of
@@ -139,9 +142,9 @@ SCOPE_PANE_REDUCE = "siddhi.pane.reduce"        # per (pane, group) segment
 SCOPE_PANE_EMIT = "siddhi.pane.emit"            # a group's last row, select
 SCOPE_PANE_COUNT = "siddhi.pane.count"
 DEVICE_SCOPES = (
-    SCOPE_DENSE_GATHER, SCOPE_DENSE_ADVANCE, SCOPE_DENSE_SCATTER,
-    SCOPE_DENSE_COUNT, SCOPE_DENSE_ROUNDS, SCOPE_DENSE_RUN,
-    SCOPE_SHARD_COUNT_PSUM, SCOPE_WINDOW_FILTER,
+    SCOPE_DENSE_GATHER, SCOPE_DENSE_ADVANCE, SCOPE_DENSE_KLEENE,
+    SCOPE_DENSE_SCATTER, SCOPE_DENSE_COUNT, SCOPE_DENSE_ROUNDS,
+    SCOPE_DENSE_RUN, SCOPE_SHARD_COUNT_PSUM, SCOPE_WINDOW_FILTER,
     SCOPE_WINDOW_SLOT, SCOPE_WINDOW_AGGREGATE, SCOPE_WINDOW_EMIT,
     SCOPE_WINDOW_UPDATE, SCOPE_WINDOW_COUNT, SCOPE_PANE_ASSIGN,
     SCOPE_PANE_REDUCE, SCOPE_PANE_EMIT, SCOPE_PANE_COUNT)

@@ -57,6 +57,15 @@ ops/nfa.py — the planner falls back to the host engine otherwise):
    refs see the captures BEFORE the cloning event, on both engines);
    an open count's successor must be a plain stream node (fall back
    otherwise);
+ - ``every`` over a count head (``every e1=S[..]<m:> -> e2``) re-arms
+   the head when a count REACHES its minimum, not at every event and
+   not at the emit: a fresh arm takes the first free lane of node 0
+   on the next matching event, while the satisfied arms go on counting
+   beside it.  A burst of F matching events therefore holds
+   ceil(F / m) arms (at ``<3:>``: 4 lanes hold a burst of 12, the
+   13th event finds no lane and counts one ``overflow``), and the
+   successor's event emits one row an arm at or over the minimum,
+   each with its own first capture and the ``[last]`` they share;
  - capture references limited to first (``ref.attr``/``ref[0]``) and
    last (``ref[last]``) events of a count state;
  - numeric attributes only (string keys are interned to partition ids
@@ -80,6 +89,7 @@ from siddhi_tpu.observability.trace import (
     SCOPE_DENSE_ADVANCE,
     SCOPE_DENSE_COUNT,
     SCOPE_DENSE_GATHER,
+    SCOPE_DENSE_KLEENE,
     SCOPE_DENSE_ROUNDS,
     SCOPE_DENSE_RUN,
     SCOPE_DENSE_SCATTER,
@@ -746,6 +756,7 @@ class DensePatternEngine:
                 (B, I))
 
         n_iout = sum(self.out_int)
+        named_scope = self.jax.named_scope
 
         def advance(a, first, counts, regs, iregs, ovf, dl, cols, ts,
                     valid):
@@ -1069,25 +1080,26 @@ class DensePatternEngine:
                 pending = a[:, s, :]
                 if s == 0 and every_start:
                     if is_count:
-                        # a fresh virgin arms only while no unsatisfied
-                        # counting instance exists (the host rearms at
-                        # satisfaction — StreamPostStateProcessor
-                        # addEveryState), taking the first free lane
-                        unsat = (a[:, 0, :] & (counts[:, 0, :] > 0)
-                                 & (counts[:, 0, :] < max(node.min_count, 1)))
-                        has_unsat = jnp.any(unsat, axis=1)  # [B]
-                        free0 = ~a[:, 0, :] & (counts[:, 0, :] == 0)
-                        vrank = jnp.cumsum(free0.astype(jnp.int32), axis=1) - 1
-                        virgin = free0 & (vrank == 0) & ~has_unsat[:, None]
-                        pending = pending | virgin
-                        # a virgin that SHOULD arm (no unsatisfied arm, the
-                        # event passes the start filter) but finds no free
-                        # lane is a dropped instance — count it (node-0
-                        # filters read candidate columns only, so lane 0
-                        # of ok is lane-uniform)
-                        no_lane = (~has_unsat & ~jnp.any(free0, axis=1)
-                                   & ok_pre[s][:, 0] & valid)
-                        ovf = ovf + no_lane.astype(jnp.int32)
+                        with named_scope(SCOPE_DENSE_KLEENE):
+                            # a fresh virgin arms only while no unsatisfied
+                            # counting instance exists (the host rearms at
+                            # satisfaction — StreamPostStateProcessor
+                            # addEveryState), taking the first free lane
+                            unsat = (a[:, 0, :] & (counts[:, 0, :] > 0)
+                                     & (counts[:, 0, :] < max(node.min_count, 1)))
+                            has_unsat = jnp.any(unsat, axis=1)  # [B]
+                            free0 = ~a[:, 0, :] & (counts[:, 0, :] == 0)
+                            vrank = jnp.cumsum(free0.astype(jnp.int32), axis=1) - 1
+                            virgin = free0 & (vrank == 0) & ~has_unsat[:, None]
+                            pending = pending | virgin
+                            # a virgin that SHOULD arm (no unsatisfied arm, the
+                            # event passes the start filter) but finds no free
+                            # lane is a dropped instance — count it (node-0
+                            # filters read candidate columns only, so lane 0
+                            # of ok is lane-uniform)
+                            no_lane = (~has_unsat & ~jnp.any(free0, axis=1)
+                                       & ok_pre[s][:, 0] & valid)
+                            ovf = ovf + no_lane.astype(jnp.int32)
                     elif group_every:
                         pending = pending | (lane0 & grp_virgin_ok)
                     else:
@@ -1096,58 +1108,59 @@ class DensePatternEngine:
                         pending = pending | lane0
                 fire = pending & ok_pre[s] & valid[:, None]
                 if is_count:
-                    below_max = (node.max_count == ANY) | (counts[:, s, :] < node.max_count)
-                    cap = fire & below_max
-                    first_cap = cap & (counts[:, s, :] == 0)
-                    counts = counts.at[:, s, :].set(
-                        jnp.where(cap, counts[:, s, :] + 1, counts[:, s, :]))
-                    # a counting lane is occupied from its first capture
-                    a = a.at[:, s, :].set(a[:, s, :] | first_cap)
-                    for slot in self.node_writes[s]:
-                        if slot.ref != spec.ref:
-                            continue
-                        upd = cap if slot.last else first_cap
-                        regs, iregs = write_slot(regs, iregs, s, slot, upd)
-                    first = first.at[:, s, :].set(
-                        jnp.where(first_cap & (first[:, s, :] == 0), ts[:, None],
-                                  first[:, s, :]))
-                    open_count = (node.max_count == ANY
-                                  or node.max_count > node.min_count)
-                    advance = cap & (counts[:, s, :] == max(node.min_count, 1))
-                    if not open_count or s == S - 1:
-                        # exact counts ({n}) move at min==max; a count
-                        # LAST node emits once at satisfaction
-                        # (emitted_at_node semantics — later captures
-                        # don't re-emit because advance fires at == min)
-                        carry = _advance(s, advance,
-                                         (a, first, counts, regs, iregs, emit,
-                                          out_vals, out_ivals, emit_anchor, ovf))
-                        a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
-                    # lane lifecycle at max: exact counts are spent (their
-                    # advance already placed the instance); open counts
-                    # MOVE the still-pending instance to s+1 at max
-                    # (reference _try_capture: count >= max ->
-                    # _enter_node(pos+1)); its clones already advanced via
-                    # the via-path at earlier successor events
-                    if node.max_count != ANY:
-                        at_max = cap & (counts[:, s, :] >= node.max_count)
-                        if open_count and s < S - 1:
-                            anchor_s = jnp.where(
-                                first[:, s, :] > 0, first[:, s, :], ts[:, None])
-                            carry = _place(at_max, anchor_s, regs[:, s, :, :],
-                                           s + 1,
-                                           (a, first, counts, regs, iregs,
-                                            emit, out_vals, out_ivals,
-                                            emit_anchor, ovf),
-                                           src_iregs=iregs[:, s, :, :])
-                            a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
-                        a = a.at[:, s, :].set(a[:, s, :] & ~at_max)
+                    with named_scope(SCOPE_DENSE_KLEENE):
+                        below_max = (node.max_count == ANY) | (counts[:, s, :] < node.max_count)
+                        cap = fire & below_max
+                        first_cap = cap & (counts[:, s, :] == 0)
                         counts = counts.at[:, s, :].set(
-                            jnp.where(at_max, 0, counts[:, s, :]))
+                            jnp.where(cap, counts[:, s, :] + 1, counts[:, s, :]))
+                        # a counting lane is occupied from its first capture
+                        a = a.at[:, s, :].set(a[:, s, :] | first_cap)
+                        for slot in self.node_writes[s]:
+                            if slot.ref != spec.ref:
+                                continue
+                            upd = cap if slot.last else first_cap
+                            regs, iregs = write_slot(regs, iregs, s, slot, upd)
                         first = first.at[:, s, :].set(
-                            jnp.where(at_max, 0, first[:, s, :]))
-                    carry = (a, first, counts, regs, iregs, emit, out_vals,
-                             out_ivals, emit_anchor, ovf)
+                            jnp.where(first_cap & (first[:, s, :] == 0), ts[:, None],
+                                      first[:, s, :]))
+                        open_count = (node.max_count == ANY
+                                      or node.max_count > node.min_count)
+                        advance = cap & (counts[:, s, :] == max(node.min_count, 1))
+                        if not open_count or s == S - 1:
+                            # exact counts ({n}) move at min==max; a count
+                            # LAST node emits once at satisfaction
+                            # (emitted_at_node semantics — later captures
+                            # don't re-emit because advance fires at == min)
+                            carry = _advance(s, advance,
+                                             (a, first, counts, regs, iregs, emit,
+                                              out_vals, out_ivals, emit_anchor, ovf))
+                            a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
+                        # lane lifecycle at max: exact counts are spent (their
+                        # advance already placed the instance); open counts
+                        # MOVE the still-pending instance to s+1 at max
+                        # (reference _try_capture: count >= max ->
+                        # _enter_node(pos+1)); its clones already advanced via
+                        # the via-path at earlier successor events
+                        if node.max_count != ANY:
+                            at_max = cap & (counts[:, s, :] >= node.max_count)
+                            if open_count and s < S - 1:
+                                anchor_s = jnp.where(
+                                    first[:, s, :] > 0, first[:, s, :], ts[:, None])
+                                carry = _place(at_max, anchor_s, regs[:, s, :, :],
+                                               s + 1,
+                                               (a, first, counts, regs, iregs,
+                                                emit, out_vals, out_ivals,
+                                                emit_anchor, ovf),
+                                               src_iregs=iregs[:, s, :, :])
+                                a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
+                            a = a.at[:, s, :].set(a[:, s, :] & ~at_max)
+                            counts = counts.at[:, s, :].set(
+                                jnp.where(at_max, 0, counts[:, s, :]))
+                            first = first.at[:, s, :].set(
+                                jnp.where(at_max, 0, first[:, s, :]))
+                        carry = (a, first, counts, regs, iregs, emit, out_vals,
+                                 out_ivals, emit_anchor, ovf)
                 else:
                     # capture the node's slots for real pending lanes
                     for slot in self.node_writes[s]:
@@ -1187,72 +1200,73 @@ class DensePatternEngine:
                                  or prev.max_count > prev.min_count)
                         )
                         if prev_open:
-                            a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
-                            sat = (a[:, s - 1, :]
-                                   & (counts[:, s - 1, :] >= max(prev.min_count, 1)))
-                            if prev.max_count != ANY:
-                                sat = sat & (counts[:, s - 1, :] < prev.max_count)
-                            ok_via = (
-                                jnp.broadcast_to(jnp.asarray(
-                                    node_filters[s][0].fn(
-                                        env_for(s, cols, ts, regs, iregs,
-                                                regs_node=s - 1))).astype(bool),
-                                    (B, I))
-                                if node_filters[s][0] is not None
-                                else jnp.ones((B, I), dtype=bool)
-                            )
-                            fire_via = sat & ok_via & valid[:, None]
-                            via_regs = regs[:, s - 1, :, :]
-                            via_iregs = iregs[:, s - 1, :, :]
-                            for slot in self.node_writes[s]:
-                                if slot.ref != spec.ref:
-                                    continue
-                                if slot.integer:
-                                    hk, lk = (f"{slot.attr}|hi",
-                                              f"{slot.attr}|lo")
-                                    if hk not in cols:
+                            with named_scope(SCOPE_DENSE_KLEENE):
+                                a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
+                                sat = (a[:, s - 1, :]
+                                       & (counts[:, s - 1, :] >= max(prev.min_count, 1)))
+                                if prev.max_count != ANY:
+                                    sat = sat & (counts[:, s - 1, :] < prev.max_count)
+                                ok_via = (
+                                    jnp.broadcast_to(jnp.asarray(
+                                        node_filters[s][0].fn(
+                                            env_for(s, cols, ts, regs, iregs,
+                                                    regs_node=s - 1))).astype(bool),
+                                        (B, I))
+                                    if node_filters[s][0] is not None
+                                    else jnp.ones((B, I), dtype=bool)
+                                )
+                                fire_via = sat & ok_via & valid[:, None]
+                                via_regs = regs[:, s - 1, :, :]
+                                via_iregs = iregs[:, s - 1, :, :]
+                                for slot in self.node_writes[s]:
+                                    if slot.ref != spec.ref:
                                         continue
-                                    via_iregs = via_iregs.at[
-                                        :, :, 2 * slot.index].set(jnp.where(
-                                            fire_via, cols[hk][:, None],
-                                            via_iregs[:, :, 2 * slot.index]))
-                                    via_iregs = via_iregs.at[
-                                        :, :, 2 * slot.index + 1].set(jnp.where(
-                                            fire_via, cols[lk][:, None],
-                                            via_iregs[:, :, 2 * slot.index + 1]))
-                                elif slot.attr in cols:
-                                    via_regs = via_regs.at[:, :, slot.index].set(
-                                        jnp.where(
-                                            fire_via,
-                                            cols[slot.attr].astype(jnp.float32)[:, None],
-                                            via_regs[:, :, slot.index]))
-                            via_anchor = jnp.where(
-                                first[:, s - 1, :] > 0, first[:, s - 1, :],
-                                ts[:, None])
-                            carry = (a, first, counts, regs, iregs, emit,
-                                     out_vals, out_ivals, emit_anchor, ovf)
-                            if s == S - 1:
-                                carry = _emit_rows(fire_via, via_anchor,
-                                                   via_regs, carry, bank=1,
+                                    if slot.integer:
+                                        hk, lk = (f"{slot.attr}|hi",
+                                                  f"{slot.attr}|lo")
+                                        if hk not in cols:
+                                            continue
+                                        via_iregs = via_iregs.at[
+                                            :, :, 2 * slot.index].set(jnp.where(
+                                                fire_via, cols[hk][:, None],
+                                                via_iregs[:, :, 2 * slot.index]))
+                                        via_iregs = via_iregs.at[
+                                            :, :, 2 * slot.index + 1].set(jnp.where(
+                                                fire_via, cols[lk][:, None],
+                                                via_iregs[:, :, 2 * slot.index + 1]))
+                                    elif slot.attr in cols:
+                                        via_regs = via_regs.at[:, :, slot.index].set(
+                                            jnp.where(
+                                                fire_via,
+                                                cols[slot.attr].astype(jnp.float32)[:, None],
+                                                via_regs[:, :, slot.index]))
+                                via_anchor = jnp.where(
+                                    first[:, s - 1, :] > 0, first[:, s - 1, :],
+                                    ts[:, None])
+                                carry = (a, first, counts, regs, iregs, emit,
+                                         out_vals, out_ivals, emit_anchor, ovf)
+                                if s == S - 1:
+                                    carry = _emit_rows(fire_via, via_anchor,
+                                                       via_regs, carry, bank=1,
+                                                       src_iregs=via_iregs)
+                                else:
+                                    carry = _place(fire_via, via_anchor, via_regs,
+                                                   s + 1, carry,
                                                    src_iregs=via_iregs)
-                            else:
-                                carry = _place(fire_via, via_anchor, via_regs,
-                                               s + 1, carry,
-                                               src_iregs=via_iregs)
-                            # PATTERN forward-once: the dually-pending arm
-                            # is consumed at its successor match — it can
-                            # emit at most once (reference
-                            # removeIfNextStateProcessed; the host engine
-                            # kills the source on via-advance likewise)
-                            a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
-                            a = a.at[:, s - 1, :].set(
-                                a[:, s - 1, :] & ~fire_via)
-                            counts = counts.at[:, s - 1, :].set(
-                                jnp.where(fire_via, 0, counts[:, s - 1, :]))
-                            first = first.at[:, s - 1, :].set(
-                                jnp.where(fire_via, 0, first[:, s - 1, :]))
-                            carry = (a, first, counts, regs, iregs, emit,
-                                     out_vals, out_ivals, emit_anchor, ovf)
+                                # PATTERN forward-once: the dually-pending arm
+                                # is consumed at its successor match — it can
+                                # emit at most once (reference
+                                # removeIfNextStateProcessed; the host engine
+                                # kills the source on via-advance likewise)
+                                a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
+                                a = a.at[:, s - 1, :].set(
+                                    a[:, s - 1, :] & ~fire_via)
+                                counts = counts.at[:, s - 1, :].set(
+                                    jnp.where(fire_via, 0, counts[:, s - 1, :]))
+                                first = first.at[:, s - 1, :].set(
+                                    jnp.where(fire_via, 0, first[:, s - 1, :]))
+                                carry = (a, first, counts, regs, iregs, emit,
+                                         out_vals, out_ivals, emit_anchor, ovf)
 
             a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
 
@@ -1267,8 +1281,6 @@ class DensePatternEngine:
 
             return (a, first, counts, regs, iregs, ovf, dlh[0], emit,
                     out_vals, out_ivals, emit_anchor)
-
-        named_scope = self.jax.named_scope
 
         def advance_fields(f, cols, ts, valid):
             B = ts.shape[0]

@@ -5,7 +5,8 @@ engine compiles one program per padded collision-round shape, so every
 entry point keeps JAX's persistent compilation cache on.  The directory
 is part of the cache key: it is either the one the environment names
 (``JAX_COMPILATION_CACHE_DIR``) or one fixed path beside the package —
-never a temporary name.
+never a temporary name.  The names of the device scopes are part of the
+key too (``_SETTINGS``).
 """
 
 from __future__ import annotations
@@ -18,10 +19,16 @@ DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
 
-# store every program, however small or quick to compile
-_STORE_ALL = {
+_SETTINGS = {
+    # store every program, however small or quick to compile
     "jax_persistent_cache_min_compile_time_secs": 0.0,
     "jax_persistent_cache_min_entry_size_bytes": -1,
+    # JAX leaves an operation's metadata out of the key, so two programs
+    # that differ only in their ``jax.named_scope`` share one entry and
+    # the second runs with the first one's names.  Device time is read
+    # by those names (``observability/trace.py`` ``DEVICE_SCOPES``): a
+    # step fetched from before a scope existed reports nothing under it
+    "jax_compilation_cache_include_metadata_in_key": True,
 }
 
 
@@ -34,7 +41,7 @@ def configure_compile_cache() -> None:
     JAX: when it is not imported yet the settings go into the
     environment, which JAX reads at import.
     """
-    settings = dict(_STORE_ALL)
+    settings = dict(_SETTINGS)
     if not os.environ.get(CACHE_DIR_ENV):
         settings["jax_compilation_cache_dir"] = DEFAULT_CACHE_DIR
     jax = sys.modules.get("jax")

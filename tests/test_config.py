@@ -118,7 +118,8 @@ class TestCompileCachePlacement:
 
         names = ("jax_compilation_cache_dir",
                  "jax_persistent_cache_min_compile_time_secs",
-                 "jax_persistent_cache_min_entry_size_bytes")
+                 "jax_persistent_cache_min_entry_size_bytes",
+                 "jax_compilation_cache_include_metadata_in_key")
         saved = {n: getattr(jax.config, n) for n in names}
         yield jax.config
         for n, v in saved.items():
@@ -146,3 +147,42 @@ class TestCompileCachePlacement:
             os.path.abspath(siddhi_tpu.__file__))), ".jax_cache")
         assert jax_config.jax_compilation_cache_dir == want
         assert jax_config.jax_persistent_cache_min_entry_size_bytes == -1
+
+    def test_a_named_scope_is_part_of_the_cache_key(self, jax_config):
+        """Device metrics are read by ``jax.named_scope`` names: a
+        program that differs from a cached one only in them must not be
+        handed the cached one (the chip run of PR 35: the parent's
+        traced run reported the change's ``siddhi.dense.kleene``)."""
+        import jax
+        import numpy as np
+        from jax._src import cache_key
+
+        from siddhi_tpu.util import compile_cache
+
+        from jax._src import compiler
+
+        def key_of(scope):
+            def f(x):
+                with jax.named_scope(scope):
+                    return x + 1
+            return cache_key.get(
+                jax.jit(f).lower(np.float32(0)).compiler_ir(),
+                np.array(jax.devices()[:1]),
+                compiler.get_compile_options(num_replicas=1,
+                                             num_partitions=1),
+                jax.devices()[0].client)
+
+        def keys():     # one call site: the caller's line is metadata too
+            return [key_of(s) for s in ("siddhi.a", "siddhi.b", "siddhi.a")]
+
+        jax_config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        try:
+            a, b, again = keys()
+        except Exception as e:      # a private API that moved
+            pytest.skip(f"cache_key.get: {e!r}")
+        assert a == b == again      # the default this guards against
+        compile_cache.configure_compile_cache()
+        assert jax_config.jax_compilation_cache_include_metadata_in_key
+        a, b, again = keys()
+        assert a != b and a == again

@@ -660,9 +660,10 @@ def test_jitted_steps_carry_the_device_scopes():
     cols = eng.prepare_cols(sk, {"k": idx.astype(np.int64),
                                  "v": np.linspace(0.0, 20.0, n)})
     dense = {sc for sc in trace_mod.DEVICE_SCOPES if ".dense." in sc}
-    assert len(dense) == 6
+    assert len(dense) == 7
     rounds = {trace_mod.SCOPE_DENSE_ROUNDS, trace_mod.SCOPE_DENSE_RUN}
-    dense -= rounds
+    # a chain of plain stream nodes has no count node's part
+    dense -= rounds | {trace_mod.SCOPE_DENSE_KLEENE}
     assert scopes_in(eng.make_step(sk).lower(
         eng.init_state(), idx, cols, idx, np.ones(n, bool))) == dense
     # the rounds program: wide enough to have wide rounds beside the run
@@ -708,6 +709,36 @@ def test_jitted_steps_carry_the_device_scopes():
         rt.shutdown()
     finally:
         m.shutdown()
+
+
+def test_a_count_nodes_part_of_advance_has_a_scope_of_its_own():
+    """``siddhi.dense.kleene`` nests in ``siddhi.dense.advance`` and
+    names the count node's capture, the re-arm at the minimum and the
+    via-path clone; a plain node's operations stay ``advance``'s."""
+    from siddhi_tpu.ops.dense_nfa import compile_pattern
+
+    eng = compile_pattern(
+        "define stream Login (user long, ok int, ip int); "
+        "from every e1=Login[ok == 0]<3:> -> e2=Login[ok == 1] "
+        "within 10 min select e1[0].ip as firstIp, e1[last].ip as lastIp, "
+        "e2.ip as okIp insert into Alerts;", n_partitions=64)
+    n = 16
+    idx = np.arange(n, dtype=np.int32)
+    sk = eng.default_stream
+    cols = eng.prepare_cols(sk, {"user": idx.astype(np.int64),
+                                 "ok": idx % 2, "ip": idx})
+    text = eng.make_step(sk).lower(
+        eng.init_state(), idx, cols, idx, np.ones(n, bool)).as_text(
+            debug_info=True)
+    nested = (trace_mod.SCOPE_DENSE_ADVANCE + "/"
+              + trace_mod.SCOPE_DENSE_KLEENE)
+    assert nested in text
+    assert trace_mod.SCOPE_DENSE_KLEENE in trace_mod.DEVICE_SCOPES
+    # the scope is never opened outside advance
+    assert text.count(trace_mod.SCOPE_DENSE_KLEENE) == text.count(nested)
+    # and the cumulative sum that finds the head's free lane is under it
+    assert re.search(r'cumsum[^\n]*' + re.escape(nested) + r'|'
+                     + re.escape(nested) + r'[^\n]*cumsum', text)
 
 
 # -- prometheus exposition ----------------------------------------------------
