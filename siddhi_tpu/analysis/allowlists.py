@@ -62,7 +62,9 @@ ALLOWLISTS = {
         f"{_DQ}:DeviceQueryEngine.host_lane_cols":
             "ingest: HOST lane materialization for host fallbacks",
         f"{_DQ}:DeviceQueryEngine._pad_lanes":
-            "ingest: pads HOST cols to the pow-2 batch shape",
+            "ingest: names the HOST lanes of the step's packed buffer",
+        f"{_DQ}:DeviceQueryEngine._pack":
+            "ingest: packs HOST lanes into the one buffer of a put",
         f"{_DQ}:DeviceQueryEngine._host_filter_mask":
             "ingest: null-safe HOST filter probe",
         f"{_DQ}:DeviceQueryEngine.process_batch_deferred":

@@ -438,8 +438,9 @@ class SiddhiAppRuntime:
             # async pipeline counters, one gauge pair per device-lowered
             # query: emit side (emitTransfers / deferredBatches /
             # zeroMatchSkips / maxPendingDepth / autoEffectiveDepth) and
-            # ingest side (stagedBatches / devicePuts / ingestStalls /
-            # overlappedBatches / flushSyncs / maxStagingDepth)
+            # ingest side (stagedBatches / devicePuts / deviceChunks /
+            # ingestStalls / overlappedBatches / flushSyncs /
+            # maxStagingDepth)
             for name, qr in list(self.query_runtimes.items()) + [
                 (n, q)
                 for pr in self.partitions.values()

@@ -11,8 +11,8 @@ This module holds the pieces every device runtime shares:
 
 - ``IngestStats``: per-runtime staging counters surfaced through
   ``util/statistics.py`` (``stagedBatches`` / ``devicePuts`` /
-  ``ingestStalls`` / ``overlappedBatches`` / ``flushSyncs`` /
-  ``maxStagingDepth``, and how often the window opened:
+  ``deviceChunks`` / ``ingestStalls`` / ``overlappedBatches`` /
+  ``flushSyncs`` / ``maxStagingDepth``, and how often the window opened:
   ``gatesBySubmit`` / ``gatesByIdle`` / ``pipelineEntries`` /
   ``pipelineExits``).
 - ``IngestStage``: a bounded staging window.  ``submit(probe, finish)``
@@ -76,14 +76,18 @@ class IngestStats:
     """Staging counters for one device runtime (host-side ints, same
     thin-gauge style as ``EmitStats``)."""
 
-    __slots__ = ("staged_batches", "device_puts", "ingest_stalls",
-                 "overlapped_batches", "flush_syncs", "dropped_batches",
+    __slots__ = ("staged_batches", "device_puts", "device_chunks",
+                 "ingest_stalls", "overlapped_batches", "flush_syncs",
+                 "dropped_batches",
                  "max_staging_depth", "auto_depth", "gates_by_submit",
                  "gates_by_idle", "pipeline_entries", "pipeline_exits")
 
     def __init__(self):
         self.staged_batches = 0
         self.device_puts = 0
+        # chunks the batches were cut into for the device (the window
+        # path, ops/device_query.py: a chunk is a put and a dispatch)
+        self.device_chunks = 0
         # staged batches whose finish (the count-gate fetch, where XLA
         # reports an asynchronous step failure) raised and was isolated
         self.dropped_batches = 0
@@ -111,6 +115,7 @@ class IngestStats:
         return {
             "stagedBatches": self.staged_batches,
             "devicePuts": self.device_puts,
+            "deviceChunks": self.device_chunks,
             "ingestStalls": self.ingest_stalls,
             "overlappedBatches": self.overlapped_batches,
             "gatesBySubmit": self.gates_by_submit,

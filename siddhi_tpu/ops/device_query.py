@@ -88,6 +88,28 @@ Numeric lanes (TPU-first dtype policy):
    interned host-side; bare ``select attr`` items gather from the input
    batch, never touching a device lane).
  - emitted columns are cast back to the declared attribute types.
+
+Host to device: a batch crosses as ONE packed ``int32 [k, B]`` buffer a
+chunk (``_pack``), one ``staged_put`` of one leaf.  The per-event kinds
+(``_pad_lanes``) give it a row for each lane the compiled expressions
+read (found by tracing them over a recording ``env``; float32 lanes by
+bit pattern, so NaN payloads, -0.0 and denormals arrive as sent), the
+relative timestamps where the step keeps or reads them, the interned
+group and window-group ids where the query has any, and the valid
+mask; the one-program lengthBatch path (``_pane_chunk``) the lanes of
+``pane_lanes()`` and the group ids.  The jitted program takes it apart
+again by static slices (``_unpack``).
+
+A batch is cut into chunks only as far as the kind's own working set
+asks (``_chunk_rows``): the ``[B, B]`` same-group masks of the running
+and keyed-sliding kinds (and of a sliding window that holds
+minForever / maxForever) bound a chunk at ``MAX_DEVICE_BATCH`` rows;
+the global sliding window gathers ``rows x W x aggregates`` elements
+and takes as many rows as keep that gather inside the same budget (a
+``length(10)`` window with two aggregates: 131,072); the filter and
+tumbling kinds take a batch whole.  Chunks advance the state in order,
+and each output row reduces the same window entries however the batch
+was cut.
 """
 
 from __future__ import annotations
@@ -160,10 +182,14 @@ _SUM_KINDS = ("sum", "avg", "stdDev", "and", "or")
 PER_EVENT = "per_event"
 PER_FLUSH = "per_flush"
 
-# host-side chunking bound for the per-event step: the running and
-# keyed-sliding kinds build [B, B] same-group masks, so an unbounded
-# junction batch would allocate quadratically; chunks advance state
-# sequentially, which is semantics-preserving for every kind
+# rows a chunk where the per-event step builds [B, B] same-group masks
+# (the running and keyed-sliding kinds, the sliding kind's
+# minForever/maxForever prefix): an unbounded junction batch would
+# allocate quadratically.  Its square is also the element budget the
+# global sliding window's [rows, W, A] gather is held to
+# (DeviceQueryEngine._chunk_rows); the filter and tumbling kinds are
+# not chunked.  The fused graph and the sharded wrapper cut every batch
+# at this bound in loops of their own
 MAX_DEVICE_BATCH = 2048
 
 # longest lengthBatch pane the one-program path tiles.  Its same-group
@@ -329,6 +355,9 @@ def _mm_f32(a, b):
 # and these two
 GRP_KEY = "__grp"   # interned group id
 SEQ_KEY = "__seq"   # place in the stream
+# further rows of the packed ingest buffer (DeviceQueryEngine._pad_lanes)
+WGRP_KEY = "__wgrp"     # interned window-group (partition key) id
+VALID_KEY = "__valid"   # 1 for a row of the batch, 0 for padding
 
 
 def _copy_rows(rows: Optional[Dict]) -> Optional[Dict]:
@@ -610,6 +639,22 @@ class DeviceQueryEngine:
 
         self._trace_check()
         self._step_cache: Dict[str, Callable] = {}
+        # the rows of the packed ingest buffer (_pad_lanes): the lanes
+        # the device expressions read, then whatever else the kind's
+        # step takes.  The sliding kinds keep every row's timestamp in
+        # their ring; a group id means something only where the host
+        # interns one (the tumbling sweep's callers bring their own)
+        self.interns = self.kind != "filter" and bool(
+            self.partition_mode or self.group_exprs)
+        self.lane_rows: List[str] = list(self.read_lanes)
+        if self.W or self._reads_ts:
+            self.lane_rows.append(TS_KEY)
+        if self.interns or self.kind == "tumbling":
+            self.lane_rows.append(GRP_KEY)
+        if self.kind == "keyed_sliding":
+            self.lane_rows.append(WGRP_KEY)
+        self.lane_rows.append(VALID_KEY)
+        self.chunk_rows = self._chunk_rows()
 
         # host-side interning / pane bookkeeping.  In partition mode the
         # group key space is the composed tuple (partition_key, *group
@@ -768,21 +813,28 @@ class DeviceQueryEngine:
         env[N_KEY] = G
         return env
 
-    def _trace_one(self, compiled, shapes):
+    def _trace_one(self, compiled, shapes, seen: Optional[set] = None):
         import jax
 
-        jax.eval_shape(lambda env: compiled.fn(env), shapes)
+        seen = set() if seen is None else seen
+        jax.eval_shape(lambda env: compiled.fn(RecordingEnv(env, seen)),
+                       shapes)
 
     def _trace_check(self):
         """Compile-time eligibility: every expression must be
-        jax-traceable (no object-dtype ops, no host-only functions)."""
+        jax-traceable (no object-dtype ops, no host-only functions).
+        The traces over the input lanes run on an ``env`` that records
+        its lookups (as ``pane_lanes`` does for the pane program):
+        ``read_lanes`` are the lanes a step evaluates an expression on,
+        the only ones a batch has to bring to the device."""
         shapes = self._env_shapes()
+        seen = set()
         try:
             for f in self.filters:
-                self._trace_one(f, shapes)
+                self._trace_one(f, shapes, seen)
             for a in self.aggs:
                 if a.arg is not None:
-                    self._trace_one(a.arg, shapes)
+                    self._trace_one(a.arg, shapes, seen)
             for g in self.group_exprs:
                 # group keys are evaluated host-side (interning), so any
                 # type is fine — no trace needed
@@ -790,9 +842,9 @@ class DeviceQueryEngine:
             if self.mode == PER_EVENT:
                 for kind, v, _n in self.out_spec:
                     if kind == "expr":
-                        self._trace_one(v, shapes)
+                        self._trace_one(v, shapes, seen)
                 if self.having is not None:
-                    self._trace_one(self.having, shapes)
+                    self._trace_one(self.having, shapes, seen)
             else:
                 fshapes = self._flush_env_shapes()
                 for kind, v, _n in self.out_spec:
@@ -806,6 +858,29 @@ class DeviceQueryEngine:
             raise SiddhiAppCreationError(
                 f"query not device-eligible (expression not jax-traceable): {e}"
             ) from e
+        # of every lane a batch could fill (the numeric and bool
+        # attributes, a hi/lo pair for each LONG), those looked up
+        self.read_lanes: List[str] = [
+            k for k in self.attrs + [a + part for a in self.long_attrs
+                                     for part in ("|hi", "|lo")]
+            if k in seen]
+        self._reads_ts = TS_KEY in seen
+
+    def _chunk_rows(self) -> Optional[int]:
+        """Rows a chunk of the per-event step, from what the kind
+        allocates (None: a batch is never cut).  Where a ``[B, B]``
+        mask exists the bound is ``MAX_DEVICE_BATCH``.  The global
+        sliding window without minForever/maxForever gathers ``rows x W
+        x A`` elements and nothing wider: the largest power of two that
+        keeps the gather inside the ``[B, B]`` kinds' element budget,
+        never fewer rows than they take."""
+        if self.kind in ("filter", "tumbling"):
+            return None
+        if self.kind != "sliding" or self._kinds() & {"minForever",
+                                                      "maxForever"}:
+            return MAX_DEVICE_BATCH
+        rows = MAX_DEVICE_BATCH ** 2 // (self.W * max(len(self.aggs), 1))
+        return max(1 << max(rows.bit_length() - 1, 0), MAX_DEVICE_BATCH)
 
     # -- state ---------------------------------------------------------------
 
@@ -1132,6 +1207,10 @@ class DeviceQueryEngine:
         ``n_match`` is the async-emit count gate: the host fetches this
         ONE scalar per batch and skips the column fetch entirely when it
         is zero (the common case for selective filters).
+
+        Jitted, the program takes the one packed buffer a chunk puts
+        (``_pad_lanes``) in place of the five arguments behind the
+        state: ``step(state, buf int32 [k, B])``.
         """
         key = ("step", jit)
         if key in self._step_cache:
@@ -1223,7 +1302,10 @@ class DeviceQueryEngine:
                 n = jnp.sum((ov.astype(bool) & valid).astype(jnp.int32))
             return new_state, ov, out, n
 
-        fn = (self.jax.jit(step_counted, donate_argnums=(0,)) if jit
+        def packed(state, buf):
+            return step_counted(state, *self._unpack_lanes(buf))
+
+        fn = (self.jax.jit(packed, donate_argnums=(0,)) if jit
               else step_counted)
         self._step_cache[key] = fn
         return fn
@@ -1445,7 +1527,11 @@ class DeviceQueryEngine:
         mask — at most ``L`` float32 terms a sum, never a difference of
         batch-long prefixes — and the row that is its group's last in
         the pane emits, so a pane's rows come in the host engine's
-        order.  No state: the open pane lives host-side as rows."""
+        order.  No state: the open pane lives host-side as rows.
+
+        Jitted, the program takes the one packed buffer ``_pane_chunk``
+        puts (``_pack``): ``panes(buf int32 [k, B])``, a row for each of
+        ``pane_lanes()``, then the group ids."""
         key = ("panes", jit)
         if key in self._step_cache:
             return self._step_cache[key]
@@ -1496,7 +1582,16 @@ class DeviceQueryEngine:
                 n = jnp.sum(ov.astype(jnp.int32))
             return ov, out, n
 
-        fn = self.jax.jit(panes) if jit else panes
+        fn = panes
+        if jit:
+            names = [*self.pane_lanes(), GRP_KEY]
+
+            def packed(buf):
+                rows = self._unpack(buf, names)
+                grp = rows.pop(GRP_KEY)
+                return panes(rows, grp)
+
+            fn = self.jax.jit(packed)
         self._step_cache[key] = fn
         return fn
 
@@ -1827,49 +1922,76 @@ class DeviceQueryEngine:
             out[k + "|hi"], out[k + "|lo"] = hi, lo
         return out
 
-    def _pad_lanes(self, cols, rel, grp, n, wgrp=None):
-        """The batch as zero-padded power-of-two host lanes:
-        ``((cols, ts, grp, wgrp, valid), B)``."""
-        B = _pow2(n)
-        valid = np.zeros(B, dtype=bool)
-        valid[:n] = True
-        c = {}
-        for k in self.attrs:
-            lane = self._lane_dtype[k]
-            col = np.zeros(B, dtype=lane)
-            if k in cols:
-                col[:n] = np.asarray(cols[k])[:n].astype(lane)
-            c[k] = col
-        for k in self.long_attrs:
-            hi = np.zeros(B, dtype=np.int32)
-            lo = np.zeros(B, dtype=np.int32)
-            if k in cols:
-                h, l = _split_i64(np.asarray(cols[k])[:n])
-                hi[:n], lo[:n] = h, l
-            c[k + "|hi"] = hi
-            c[k + "|lo"] = lo
-        t = np.zeros(B, dtype=np.int32)
-        t[:n] = rel[:n]
-        g = np.zeros(B, dtype=np.int32)
-        g[:n] = grp[:n]
-        wg = np.zeros(B, dtype=np.int32)
-        if wgrp is not None:
-            wg[:n] = wgrp[:n]
-        return (c, t, g, wg, valid), B
+    def _pack(self, lanes: Dict[str, Optional[np.ndarray]], n: int,
+              B: int) -> np.ndarray:
+        """Named host lanes as ONE buffer, ``int32 [k, B]``, a row a
+        lane, zeros past its first ``n`` entries (a lane given as None:
+        all zeros).  A float32 lane is written through a float32 view
+        of its row, so what crosses is its bit pattern, not its value;
+        ``_unpack`` is the device's half."""
+        buf = np.zeros((len(lanes), B), dtype=np.int32)
+        for row, (k, v) in zip(buf, lanes.items()):
+            if v is None:
+                continue
+            lane = self._lane_dtype.get(k, np.int32)
+            row.view(np.float32 if lane == np.float32 else np.int32)[:n] = (
+                np.asarray(v)[:n].astype(lane, copy=False))
+        return buf
+
+    def _unpack(self, buf, names: List[str]) -> Dict:
+        """Traced: the rows of a packed buffer by name, each in its
+        lane's dtype again (static slices; float32 by
+        ``bitcast_convert_type``)."""
+        rows = {}
+        for k, row in zip(names, buf):
+            lane = self._lane_dtype.get(k, np.int32)
+            if lane == np.float32:
+                row = self.jax.lax.bitcast_convert_type(row, self.jnp.float32)
+            rows[k] = row != 0 if lane == np.bool_ else row
+        return rows
+
+    def _pad_lanes(self, cols, rel, grp, n, wgrp=None) -> np.ndarray:
+        """The batch as the step's one packed buffer, padded to a power
+        of two: a row for each of ``lane_rows``."""
+        lanes: Dict[str, Optional[np.ndarray]] = {
+            k: cols.get(k) for k in self.read_lanes if "|" not in k}
+        # a LONG the expressions compare: both words of its hi/lo pair
+        for a in {k.split("|")[0] for k in self.read_lanes if "|" in k}:
+            lanes[a + "|hi"], lanes[a + "|lo"] = (
+                _split_i64(np.asarray(cols[a])[:n]) if a in cols
+                else (None, None))
+        lanes.update({TS_KEY: rel, GRP_KEY: grp, WGRP_KEY: wgrp,
+                      VALID_KEY: np.ones(n, dtype=np.int32)})
+        return self._pack({k: lanes[k] for k in self.lane_rows}, n, _pow2(n))
+
+    def _unpack_lanes(self, buf):
+        """Traced: the packed buffer back into the step's arguments,
+        ``(cols, ts, grp, wgrp, valid)``; a row the buffer does not
+        carry as zeros made here."""
+        rows = self._unpack(buf, self.lane_rows)
+        zeros = self.jnp.zeros(buf.shape[1], self.jnp.int32)
+        return ({k: rows[k] for k in self.read_lanes},
+                rows.get(TS_KEY, zeros), rows.get(GRP_KEY, zeros),
+                rows.get(WGRP_KEY, zeros), rows[VALID_KEY] != 0)
 
     def _put_lanes(self, lanes):
-        # ONE H2D put for the whole padded batch (a pytree device_put),
-        # behind the ingest.put fault site — the single sanctioned
-        # ingest transfer (core/ingest_stage.py, tests/test_ingest_guard)
+        # ONE H2D put for the whole padded batch, behind the ingest.put
+        # fault site — the single sanctioned ingest transfer
+        # (core/ingest_stage.py, tests/test_ingest_guard)
         from siddhi_tpu.core.ingest_stage import staged_put
 
         return staged_put(lanes, faults=self.faults,
                           stats=getattr(self, "ingest_stats", None))
 
     def _pad(self, cols, rel, grp, n, wgrp=None):
+        """The batch on the device as the separate arrays the tumbling
+        sweep's steps take: ``(cols, ts, grp, wgrp, valid, B)``."""
         with span(STAGE_CONVERT, n):
-            lanes, B = self._pad_lanes(cols, rel, grp, n, wgrp)
-        return (*self._put_lanes(lanes), B)
+            buf = self._pad_lanes(cols, rel, grp, n, wgrp)
+        key = ("unpack",)
+        if key not in self._step_cache:
+            self._step_cache[key] = self.jax.jit(self._unpack_lanes)
+        return (*self._step_cache[key](self._put_lanes(buf)), buf.shape[1])
 
     def _out_columns(self, vals, sel, gids, in_cols, in_sel,
                      host_env=None, key_cols=None,
@@ -1999,12 +2121,13 @@ class DeviceQueryEngine:
                 "partitioned device query needs per-row partition keys")
         pk = np.asarray(part_keys) if part_keys is not None else None
         pending = DeferredDeviceEmit(self)
-        # the chunk bound exists for the [B, B] same-group masks of the
-        # running/keyed-sliding kinds (and sliding's [B, W+B] gathers);
-        # the stateless filter kind is purely per-row — one dispatch
-        if n > MAX_DEVICE_BATCH and self.kind not in ("tumbling", "filter"):
-            for i in range(0, n, MAX_DEVICE_BATCH):
-                sl = slice(i, i + MAX_DEVICE_BATCH)
+        rows = self.chunk_rows or n
+        stats = getattr(self, "ingest_stats", None)
+        if stats is not None:
+            stats.device_chunks += -(-n // rows)
+        if n > rows:
+            for i in range(0, n, rows):
+                sl = slice(i, i + rows)
                 state = self._deferred_chunk(
                     state, {k: np.asarray(v)[sl] for k, v in cols.items()},
                     ts[sl], pk[sl] if pk is not None else None, pending)
@@ -2013,11 +2136,10 @@ class DeviceQueryEngine:
         return state, (pending if pending.chunks else None)
 
     def _deferred_chunk(self, state, cols, ts, pk, pending):
-        """Process one <=MAX_DEVICE_BATCH slice; non-empty match outputs
-        are appended to ``pending`` as device refs."""
+        """Process one slice of at most ``chunk_rows`` rows; non-empty
+        match outputs are appended to ``pending`` as device refs."""
         n = len(ts)
-        if self.kind != "filter" and (self.partition_mode
-                                      or self.group_exprs):
+        if self.interns:
             with span(STAGE_INTERN, n):
                 now = int(ts.max())
                 if self.partition_mode:
@@ -2044,17 +2166,17 @@ class DeviceQueryEngine:
                 state, rel64 = self._re_anchor(state, rel64)
             rel = rel64.astype(np.int32)
             if device:
-                lanes, _b = self._pad_lanes(cols, rel, grp, n, wgrp)
+                buf = self._pad_lanes(cols, rel, grp, n, wgrp)
         if device:
             step = self.make_step()
-            lanes = self._put_lanes(lanes)
+            buf = self._put_lanes(buf)
             if self.faults is not None:
                 self.faults.check("step.device")
             with span(STAGE_DISPATCH, 1):
-                state, ov, out, n_match = step(state, *lanes)
-                # the call's inputs are released with it: dropping five
-                # device buffers a chunk is time of the dispatch
-                del lanes
+                state, ov, out, n_match = step(state, buf)
+                # the call's input is released with it: dropping the
+                # device buffer is time of the dispatch
+                del buf
             # the count gate is DEFERRED: ``n_match`` stays a device
             # scalar until ``DeferredDeviceEmit.resolve()`` fetches it
             # (the ingest stage calls resolve only after the NEXT
@@ -2088,11 +2210,11 @@ class DeviceQueryEngine:
     def _pane_chunk(self, cols, ts, rel, grp, n, pending):
         """One batch of the one-program lengthBatch path: the open
         pane's carried rows and the batch's passing rows, every pane
-        they complete through ``make_pane_step`` as one put and one
-        dispatch, the rest carried.  The emit is a "device" chunk of
-        ``pending`` like the sliding kind's: a mask over the lanes, bare
-        attributes and row timestamps gathered host-side from the joined
-        rows at materialize time."""
+        they complete through ``make_pane_step`` as one put of one
+        packed buffer and one dispatch, the rest carried.  The emit is
+        a "device" chunk of ``pending`` like the sliding kind's: a mask
+        over the lanes, bare attributes and row timestamps gathered
+        host-side from the joined rows at materialize time."""
         L = int(self.window_param)
         read = self.pane_lanes()
         with span(STAGE_PANE) as sp:
@@ -2119,21 +2241,18 @@ class DeviceQueryEngine:
             # a whole number of panes that holds whatever a batch of
             # this size (to its power of two) and a carry can complete
             B = (_pow2(n) + L - 1) // L * L
-            lanes = {}
-            for a in read:
-                dtype = np.int32 if a == TS_KEY else self._lane_dtype[a]
-                lanes[a] = np.zeros(B, dtype=dtype)
-                lanes[a][:m] = (rows[a] - self.base_ts if a == TS_KEY
-                                else rows[a])
-            g = np.full(B, -1, dtype=np.int32)
-            g[:m] = rows[GRP_KEY]
+            lanes = {a: rows[a] - self.base_ts if a == TS_KEY else rows[a]
+                     for a in read}
+            lanes[GRP_KEY] = rows[GRP_KEY]
+            buf = self._pack(lanes, m, B)
+            buf[-1, m:] = -1    # no group: a lane past the last pane
         step = self.make_pane_step()
-        lanes, g = self._put_lanes((lanes, g))
+        buf = self._put_lanes(buf)
         if self.faults is not None:
             self.faults.check("step.device")
         with span(STAGE_DISPATCH, 1):
-            ov, out, n_match = step(lanes, g)
-            del lanes, g
+            ov, out, n_match = step(buf)
+            del buf
         stamps, order = rows[TS_KEY], None
         if self.group_exprs and not self.bare_attrs:
             # with no bare attribute a pane's rows come as the per-pane
@@ -2449,10 +2568,11 @@ class DeviceQueryEngine:
 
 class DeferredDeviceEmit:
     """Device-resident match outputs of one ``process_batch_deferred``
-    call (one junction batch; possibly several >MAX_DEVICE_BATCH-row
-    chunks).  ``resolve()`` fetches the deferred count gates (the only
-    blocking point of the whole ingest path — the ingest stage times it
-    to land AFTER the next batch's dispatch); the pending-emit queue
+    call (one junction batch; several chunks where it is longer than
+    the engine's ``chunk_rows``).  ``resolve()`` fetches the deferred
+    count gates (the only blocking point of the whole ingest path — the
+    ingest stage times it to land AFTER the next batch's dispatch); the
+    pending-emit queue
     (core/emit_queue.py) then fetches ``device_arrays()`` with one
     coalesced transfer and hands the host copies back to
     ``materialize``; the result is byte-identical to what the

@@ -699,13 +699,13 @@ def test_jitted_steps_carry_the_device_scopes():
             "select symbol, sum(price) * 2.0 as total, avg(volume) as av "
             "having total > 3.0 insert into Out;", register=False)
         weng = rt.query_runtimes["q"].device_runtime.engine
-        c, t, g, wg, valid, _b = weng._pad(
+        buf = weng._pad_lanes(
             {"price": np.linspace(0.0, 9.0, n).astype(np.float32),
              "volume": idx}, idx, np.zeros(n, np.int32), n)
         window = {sc for sc in trace_mod.DEVICE_SCOPES if ".window." in sc}
         assert len(window) == 6
         assert scopes_in(weng.make_step().lower(
-            weng.init_state(), c, t, g, wg, valid)) == window
+            weng.init_state(), buf)) == window
         rt.shutdown()
     finally:
         m.shutdown()
