@@ -48,6 +48,7 @@ from siddhi_tpu.core.key_index import index_for
 from siddhi_tpu.observability.trace import (
     STAGE_CONVERT,
     STAGE_INTERN,
+    STAGE_POLL,
     span,
 )
 from siddhi_tpu.query_api import AttrType, StateInputStream, Variable
@@ -543,23 +544,23 @@ class DensePatternRuntime:
             self._wake_dirty = True
         if self.step_invocations % self._OVF_POLL == 0:
             # the poll's fetch blocks on the step just dispatched
-            if tok is None:
+            with span(STAGE_POLL, 1):
                 self._check_overflow()
-            else:
-                with tok.step_wait():
-                    self._check_overflow()
         # clock sampled at RECEIVE time: the count gate may resolve a
         # batch later (ingest.depth > 1) but replays the synchronous `now`
         now = self.pipeline.now()
         self.pipeline.submit(
             tok, pending,
-            lambda host: self._emit_deferred(pending, ts, keys, host,
-                                             now=now))
+            lambda host: self._build_deferred(pending, ts, keys, host,
+                                              now=now),
+            self.emit_cb)
 
-    def _emit_deferred(self, pending, ts, keys, host_arrays, now=None):
+    def _build_deferred(self, pending, ts, keys, host_arrays, now=None):
+        """The batch's match rows as the ``EventBatch`` the synchronous
+        path would have emitted (None: no row)."""
         ev_idx, out = pending.materialize(host_arrays)
         if len(ev_idx) == 0:
-            return
+            return None
         eng = self.engine
         out_cols: Dict[str, np.ndarray] = {}
         names = eng.output_names
@@ -584,7 +585,7 @@ class DensePatternRuntime:
             # drains replay time-based rate limiters exactly (the
             # sync-path `now` sequence, not the drain time)
             mb.aux["emit_now"] = now
-        self.emit_cb(mb)
+        return mb
 
     # -- instance-capacity overflow ------------------------------------------
 

@@ -43,6 +43,7 @@ from siddhi_tpu.core.emit_queue import (
     fetch_coalesced,
 )
 from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats
+from siddhi_tpu.observability.trace import STAGE_BUILD, STAGE_DELIVER, span
 from siddhi_tpu.util import faults as _faults
 
 log = logging.getLogger("siddhi_tpu")
@@ -176,17 +177,33 @@ class DevicePipeline:
         tok = self.tracer.begin_cycle(kind or self.engine_kind, n)
         return _NO_CYCLE if tok is None else _Cycle(tok)
 
-    def submit(self, tok, pending, deliver: Optional[Callable]) -> None:
+    def submit(self, tok, pending, build: Optional[Callable],
+               emit: Optional[Callable]) -> None:
         """Stage one dispatched step.  ``pending`` has ``probe()``,
         ``resolve() -> int`` and ``device_arrays()`` (None: the batch
-        made no device work); ``deliver(host_arrays)`` materializes and
-        emits the batch once its arrays are fetched.
+        made no device work); once its arrays are fetched,
+        ``build(host_arrays)`` makes the batch's ``EventBatch`` of them
+        (None or empty: nothing to hand on) and ``emit(batch)`` hands it
+        to the output chain and the user's callback.  The two are the
+        ``build`` and ``deliver`` spans of the cycle the drain has open
+        (core/emit_queue.py): with the ``fetch`` before them they tile
+        ``emit``, whatever the shell.
 
         The count-gate fetch (``resolve``) is what blocks on the device;
         staging it lets batch N+1's H2D put + step dispatch go out
         before batch N's scalar is fetched.  ``finish`` returns how
         long it kept the host: what the stage's rule goes by."""
         queue, stage = self.emit_queue, self.ingest_stage
+
+        def deliver(host_arrays):
+            with span(STAGE_BUILD) as sp:
+                batch = build(host_arrays)
+                n = 0 if batch is None else len(batch)
+                if sp is not None:
+                    sp.count = n
+            if n:
+                with span(STAGE_DELIVER, n):
+                    emit(batch)
 
         def finish():
             blocked = None

@@ -122,11 +122,8 @@ class DeviceQueryRuntime:
         now = pipe.now()  # sampled at receive time, bound into deliver
         pipe.submit(
             tok, pending,
-            lambda host: self._emit_deferred(pending, host, now))
-
-    def _emit_deferred(self, pending, host_arrays, now=None):
-        out_cols, out_ts, keys = pending.materialize(host_arrays)
-        self._emit(out_cols, out_ts, keys, now=now)
+            lambda host: self._batch(*pending.materialize(host), now=now),
+            self.emit_cb)
 
     def purge_idle(self, now: int, idle_ms) -> int:
         """Partition-mode idle-key purge (the dense analog of dropping
@@ -136,10 +133,12 @@ class DeviceQueryRuntime:
         self.state, n = self.engine.purge_idle_keys(self.state, now, idle_ms)
         return n
 
-    def _emit(self, out_cols: Dict[str, np.ndarray], out_ts: np.ndarray,
-              keys=None, now=None):
+    def _batch(self, out_cols: Dict[str, np.ndarray], out_ts: np.ndarray,
+               keys=None, now=None) -> Optional[EventBatch]:
+        """The rows a step (or a pane flush) yielded as the
+        ``EventBatch`` for the output chain; None where there is none."""
         if len(out_ts) == 0:
-            return
+            return None
         self.rows_emitted += len(out_ts)
         mb = EventBatch(
             self.out_stream_id, self.engine.output_names, out_cols,
@@ -159,7 +158,7 @@ class DeviceQueryRuntime:
             mb.aux["group_keys"] = list(keys)
         if now is not None:
             mb.aux["emit_now"] = now
-        self.emit_cb(mb)
+        return mb
 
     def stats(self) -> Dict:
         """Ops introspection: the engine's kind, the rows this runtime
@@ -181,8 +180,11 @@ class DeviceQueryRuntime:
         # timer tick must emit first (the synchronous order)
         self.drain()
         self.state, out_cols, out_ts = self.engine.flush_due(self.state, now)
-        self._emit(out_cols, out_ts,
-                   getattr(self.engine, "last_group_keys", None), now=now)
+        mb = self._batch(out_cols, out_ts,
+                         getattr(self.engine, "last_group_keys", None),
+                         now=now)
+        if mb is not None:
+            self.emit_cb(mb)
 
     def on_start(self, now: int):
         pass

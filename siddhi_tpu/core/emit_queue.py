@@ -37,7 +37,7 @@ from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
-from ..observability.trace import STAGE_DELIVER, STAGE_FETCH, annotation
+from ..observability.trace import STAGE_FETCH, annotation, reopen
 from .exceptions import TransferFaultError
 
 log = logging.getLogger("siddhi_tpu.emit")
@@ -346,6 +346,21 @@ class EmitQueue:
         ``drains_failed`` / ``callback_faults_isolated`` beside them).
         Either way the queue stays usable and the runtime stays
         alive."""
+        if not self._entries:
+            return
+        # the thread's open cycle (observability/trace.py) is each
+        # entry's own while its rows are built and delivered, so that
+        # the shells' ``span`` sites record there; a callback that
+        # re-enters ``send_batch`` leaves another (or none) open, so it
+        # is set afresh for every entry, and what the drain found is
+        # put back when it ends
+        found = reopen(None)
+        try:
+            self._drain()
+        finally:
+            reopen(found)
+
+    def _drain(self):
         while self._entries:
             entries, self._entries = self._entries, []
             arrays: List = []
@@ -356,16 +371,18 @@ class EmitQueue:
             had_device = any(_is_device_array(a) for a in arrays)
             t0 = (time.monotonic()
                   if self.controller is not None and had_device else None)
-            # emit-span clock for sampled cycle tokens: one coalesced
-            # fetch serves every entry in this round, so they share the
-            # fetch start and each stamps its own materialize end
-            t_fetch = time.perf_counter()
-            traced = any(e.trace is not None for e in entries)
+            # emit-span clock for sampled cycle tokens (their tracer's:
+            # one app, one tracer): one coalesced fetch serves every
+            # entry in this round, so they share the fetch start and
+            # each stamps its own materialize end
+            clock = next((e.trace.tracer.clock for e in entries
+                          if e.trace is not None), None)
             try:
-                if traced:
+                if clock is not None:
+                    t_fetch = clock()
                     with annotation(STAGE_FETCH):
                         host = self._fetch(arrays)
-                    t_fetched = time.perf_counter()
+                    t_fetched = clock()
                 else:
                     host = self._fetch(arrays)
             except Exception as err:
@@ -389,16 +406,16 @@ class EmitQueue:
             for e, n in zip(entries, spans):
                 seg = host[off:off + n]
                 off += n
+                reopen(e.trace)
                 try:
-                    if e.trace is None:
-                        e.materialize(seg)
-                    else:
+                    if e.trace is not None:
                         # one fetch serves the round: each sampled
-                        # entry records it with its own bytes
+                        # entry records it with its own bytes; ``build``
+                        # and ``deliver`` are the materializer's
+                        # (DevicePipeline.submit)
                         e.trace.record(STAGE_FETCH, t_fetch, t_fetched,
                                        sum(a.nbytes for a in seg))
-                        with e.trace.span(STAGE_DELIVER, e.trace.n_emit):
-                            e.materialize(seg)
+                    e.materialize(seg)
                 except Exception as err:
                     fi = self.faults
                     if fi is not None:

@@ -92,19 +92,20 @@ class FusedChainRuntime:
         now = pipe.now()  # sampled at receive time, bound into deliver
         pipe.submit(
             tok, pending,
-            lambda host: self._emit_deferred(pending, host, now))
+            lambda host: self._build_deferred(pending, host, now),
+            self.emit_cb)
 
-    def _emit_deferred(self, pending, host_arrays, now=None):
+    def _build_deferred(self, pending, host_arrays, now=None):
         out_cols, out_ts = pending.materialize(host_arrays)
         if len(out_ts) == 0:
-            return
+            return None
         mb = EventBatch(
             self.out_stream_id, self.graph.output_names, out_cols,
             out_ts, np.full(len(out_ts), ev.CURRENT, dtype=np.int8),
         )
         if now is not None:
             mb.aux["emit_now"] = now
-        self.emit_cb(mb)
+        return mb
 
     def close(self):
         self.drain()

@@ -389,15 +389,16 @@ class HotKeyRouterRuntime:
                     for _nm, attr in self._out_pairs()}
         pipe.submit(
             tok, CountGate(n_rows, [emit_dev]),
-            lambda host: self._emit_hot(host, meta, out_cols, ts, keys,
-                                        now))
+            lambda host: self._build_hot(host, meta, out_cols, ts, keys,
+                                         now),
+            self._dense.emit_cb)
 
     def _out_pairs(self):
         """(output name, final-node attribute) pairs — eligibility
         guarantees every dense out_spec source is ('cand', attr)."""
         return [(nm, src[1]) for nm, src in self._dense.engine.out_spec]
 
-    def _emit_hot(self, host, meta, out_cols, ts, keys, now):
+    def _build_hot(self, host, meta, out_cols, ts, keys, now):
         emit_h = host[0]  # [H, n_pad] f32 per-event row counts
         parts = []
         for slot, pos in meta["slot_pos"].items():
@@ -405,7 +406,7 @@ class HotKeyRouterRuntime:
             if cnt.any():
                 parts.append(np.repeat(pos, cnt))
         if not parts:
-            return
+            return None
         rep = np.sort(np.concatenate(parts))
         pairs = self._out_pairs()
         names = [nm for nm, _a in pairs]
@@ -418,7 +419,7 @@ class HotKeyRouterRuntime:
         mb.aux["event_indices"] = rep
         if now is not None:
             mb.aux["emit_now"] = now
-        self._dense.emit_cb(mb)
+        return mb
 
     # -- barriers / lifecycle ------------------------------------------------
 

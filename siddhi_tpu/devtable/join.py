@@ -25,7 +25,8 @@ to the host ``JoinRuntime._join``'s row-major ``np.nonzero`` order.
 The runtime mirrors ``DeviceQueryRuntime``'s pipeline discipline:
 ``IngestStage`` staging for the count gate, ``EmitQueue`` for deferred
 materialization, per-batch fault isolation through ``on_fault``, cycle
-tokens and ``table.probe`` spans for observability.  A demoted table
+tokens for observability (the probe's put is a ``put`` span of its
+cycle).  A demoted table
 (or a null-carrying batch) falls back per batch to the exact host
 cross-product semantics — after a pipeline drain, so emit order holds.
 """
@@ -159,24 +160,18 @@ class DevTableJoinRuntime:
             lanes[ek] = col
         # snapshot-consistent: CURRENT immutable refs, under the table lock
         tcols, tvalid = self.table.device_state()
-        t0 = time.perf_counter()
         k_d, m_d, l_d = staged_put((klane, mlane, lanes),
                                    faults=self.faults, stats=self.ingest_stats)
         mask_d, gathered_d, count_d = self._probe(
             k_d, m_d, l_d, tcols[self.table.pk], tcols, tvalid)
         self.step_invocations += 1
         self.probe_invocations += 1
-        if self.tracer is not None:
-            from siddhi_tpu.observability.trace import STAGE_TABLE_PROBE
-
-            self.tracer.record_span(STAGE_TABLE_PROBE, self.engine_kind,
-                                    t0, time.perf_counter(), n_events=cn)
-
         self.pipeline.submit(
             tok,
             CountGate(count_d, [mask_d] + [gathered_d[nm]
                                            for nm in self._tbl_names]),
-            lambda host: self._materialize(host, cur, lo, now))
+            lambda host: self._materialize(host, cur, lo, now),
+            self.emit)
 
     # -- deferred materialization (runs on fetched HOST arrays) -----------
 
@@ -199,7 +194,7 @@ class DevTableJoinRuntime:
             np.full(len(rows), ev.CURRENT, dtype=np.int8),
         )
         out.aux["emit_now"] = now
-        self.emit(out)
+        return out
 
     # -- per-batch host fallback (exact host-join semantics) ---------------
 

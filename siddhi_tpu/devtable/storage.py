@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import logging
 import threading
-import time
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -101,7 +100,7 @@ class DeviceTable:
     ``fetch_coalesced``; the pk probe never leaves the host)."""
 
     def __init__(self, definition, capacity: int = 1024, faults=None,
-                 tracer=None, statistics_manager=None):
+                 statistics_manager=None):
         import jax
 
         self.definition = definition
@@ -170,7 +169,6 @@ class DeviceTable:
         self.demotions = 0
         self._host = None  # set on graceful demotion
         self._faults = faults
-        self._tracer = tracer
         self._sm = statistics_manager
         self._pin()
 
@@ -268,7 +266,6 @@ class DeviceTable:
                        kill_slots: List[int]):
         """ONE jitted one-hot LWW scatter for this mutation batch; pads
         to pow-2 row counts so retraces stay bounded."""
-        t0 = time.perf_counter()
         n = len(write_slots)
         npad = _pow2(n)
         w = np.full(npad, -1, dtype=np.int32)
@@ -290,12 +287,6 @@ class DeviceTable:
             self._dcols, self._dvalid, v_d, w_d, k_d)
         self.scatter_steps += 1
         self._dirty = True
-        if self._tracer is not None:
-            from siddhi_tpu.observability.trace import STAGE_TABLE_UPSERT
-
-            self._tracer.record_span(
-                STAGE_TABLE_UPSERT, "devtable", t0, time.perf_counter(),
-                n_events=n)
 
     def device_state(self):
         """(cols, valid) CURRENT device references — a probe closing

@@ -12,21 +12,42 @@ rides a :class:`CycleToken` through the existing async machinery:
     EmitQueue.drain (batch materialized)        -> tok.emitted(t0)   [emit span]
 
 Inside those three, one flat vocabulary tiles the rest of a batch's
-time in ``send_batch``: ``intern`` (keys to engine rows), ``convert``
-(host columns to padded device lanes), ``plan`` (the dense engine's
-round plan), ``pane`` (the open tumbling pane's carried rows joined to
-the batch, lengthBatch queries only), ``route`` (bucketing by shard,
-sharded engines only), ``put`` (one per H2D transfer), ``dispatch``
-(the call of the jitted step), and under ``emit`` the coalesced
-``fetch`` and the ``deliver`` of rows to the callback.  A span's parent
-is the span of the same cycle whose interval contains it; siblings
-never overlap, so a stage's time is the plain sum of its spans.
+time in ``send_batch``.  The way in, inside (or, for ``intern`` on the
+partitioned path, ahead of) ``ingest``: ``intern`` (keys to engine
+rows), ``convert`` (host columns to padded device lanes), ``plan`` (the
+dense engine's round plan), ``pane`` (the open tumbling pane's carried
+rows joined to the batch, lengthBatch queries only), ``route``
+(bucketing by shard, sharded engines only), ``put`` (one per H2D
+transfer), ``dispatch`` (the call of the jitted step) and, every 256th
+step of a dense pattern runtime, ``poll`` (the overflow total fetched:
+it waits for the step just dispatched).  The way back, three siblings
+that tile ``emit`` in this order: the coalesced ``fetch``, the
+``build`` of the ``EventBatch`` from the fetched host arrays
+(``materialize``, the column casts, the key side channels) and the
+``deliver`` of it to the output chain and the user's callback.  A
+span's parent is the span of the same cycle whose interval contains
+it; siblings never overlap, so a stage's time is the plain sum of its
+spans.
+
+Two intervals are histograms only (``Stages.staged``,
+``Stages.cycle``), with no tuple in the ring and no annotation, because
+either would lie over other batches' spans and a reader that takes the
+union of the ring (or puts a device's idle gap down to the innermost
+host span) would read nothing of them: ``staged``, from a cycle's
+dispatch to the start of its count-gate fetch where the gate was left
+behind the next batch's dispatch (in the ring: the start of ``step``
+less the end of ``ingest``, by cycle id; 0 for a gate finished
+inline), and ``cycle``, from ``begin_cycle`` to the end of ``emit``:
+how soon a batch's matches reach the callback (in the ring: the
+cycle's first span's start to its ``emit``'s end).
 
 The code that does that work lives in engines that know no tracer
-(``ops/``, ``parallel/``, ``core/ingest_stage.py``).  It reaches the
-cycle through :func:`span`: ``begin_cycle`` leaves the token open on
-the calling thread until its ingest span ends, and ``span`` reads it
-there.  Every sampled span is also a
+(``ops/``, ``parallel/``, ``core/ingest_stage.py``, the runtime
+shells).  It reaches the cycle through :func:`span`: ``begin_cycle``
+leaves the token open on the calling thread until its ingest span
+ends, ``EmitQueue.drain`` opens each entry's again while its rows are
+built and delivered (:func:`reopen`), and ``span`` reads it there.
+Every sampled span is also a
 ``jax.profiler.TraceAnnotation('siddhi.<stage>')`` around the work, so
 a profiler trace of the process carries the program's spans in its
 host plane beside the device's operations (``step`` appears as
@@ -73,13 +94,19 @@ STAGE_PANE = "pane"          # tumbling panes the batch closed
 STAGE_ROUTE = "route"        # events
 STAGE_PUT = "put"            # bytes handed to device_put
 STAGE_DISPATCH = "dispatch"  # 1 per call of a jitted step
+STAGE_POLL = "poll"          # 1 per overflow poll (every 256th dense step)
 STAGE_STEP = "step"          # events
 STAGE_EMIT = "emit"          # rows
 STAGE_FETCH = "fetch"        # bytes fetched
+STAGE_BUILD = "build"        # rows built into the EventBatch
 STAGE_DELIVER = "deliver"    # rows delivered
 CYCLE_STAGES = (STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT, STAGE_PLAN,
                 STAGE_PANE, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH,
-                STAGE_STEP, STAGE_EMIT, STAGE_FETCH, STAGE_DELIVER)
+                STAGE_POLL, STAGE_STEP, STAGE_EMIT, STAGE_FETCH,
+                STAGE_BUILD, STAGE_DELIVER)
+#: intervals of a cycle kept as histograms alone (module docstring)
+STAGE_STAGED = "staged"      # dispatch to the start of a deferred gate's fetch
+STAGE_CYCLE = "cycle"        # begin_cycle to the end of emit
 #: what a further round of one batch repeats where rounds are stepped
 #: from the host (the sharded engine; a device chunk of the window
 #: path).  The dense engine runs its rounds on the device: whatever the
@@ -96,18 +123,14 @@ SPANS_PER_CYCLE = len(CYCLE_STAGES) + 2 + len(ROUND_STAGES)
 #: checkpoint-path stages (free-running, engine kind 'persist')
 STAGE_PERSIST_CAPTURE = "persist.capture"
 STAGE_PERSIST_WRITE = "persist.write"
-#: device-table stages (devtable/): join-probe dispatch and the
-#: mutation scatter step
-STAGE_TABLE_PROBE = "table.probe"
-STAGE_TABLE_UPSERT = "table.upsert"
 #: watchdog self-heal (robustness/watchdog.py): one span per trip,
 #: covering the replan-driven restore-and-replay — recovery time is a
 #: latency distribution like any other stage
 STAGE_WATCHDOG_HEAL = "watchdog.heal"
 
 _STAGES = CYCLE_STAGES + (
+    STAGE_STAGED, STAGE_CYCLE,
     STAGE_PERSIST_CAPTURE, STAGE_PERSIST_WRITE,
-    STAGE_TABLE_PROBE, STAGE_TABLE_UPSERT,
     STAGE_WATCHDOG_HEAL)
 
 #: host spans on the profiler's clock are named ANNOTATION_PREFIX + stage;
@@ -150,7 +173,8 @@ DEVICE_SCOPES = (
     SCOPE_PANE_REDUCE, SCOPE_PANE_EMIT, SCOPE_PANE_COUNT)
 
 # the calling thread's open cycle: set by begin_cycle (None for an
-# unsampled cycle), cleared when the cycle's ingest span ends
+# unsampled cycle), cleared when the cycle's ingest span ends; set
+# again by the emit drain round each entry's build and delivery
 _open = threading.local()
 _annotation = None  # jax.profiler.TraceAnnotation, bound on first use
 
@@ -215,6 +239,19 @@ def span(stage: str, count: int = 0):
     return _NO_SPAN if tok is None else Span(tok, stage, count)
 
 
+def reopen(tok: Optional["CycleToken"]) -> Optional["CycleToken"]:
+    """Make ``tok`` (a sampled cycle's token, or None) the calling
+    thread's open cycle and hand back the one it replaces.  For the
+    emit drain: an entry's rows are built and delivered long after its
+    ingest span closed, by shells that reach the cycle through
+    :func:`span` alone; a callback may re-enter ``send_batch``, whose
+    ``begin_cycle`` takes the thread's open cycle, so the drain opens
+    its own afresh for each entry and puts back what it found."""
+    prev = getattr(_open, "tok", None)
+    _open.tok = tok
+    return prev
+
+
 class CycleToken:
     """One sampled batch cycle's identity + in-flight timestamps.
 
@@ -223,7 +260,7 @@ class CycleToken:
     span and stamps the start of the next."""
 
     __slots__ = ("tracer", "cycle", "engine", "n_events", "n_emit",
-                 "t0", "t_dispatch")
+                 "t_begin", "t0", "t_dispatch")
 
     def __init__(self, tracer: "Tracer", cycle: int, engine: str,
                  n_events: int, t0: float):
@@ -232,12 +269,11 @@ class CycleToken:
         self.engine = engine
         self.n_events = n_events
         self.n_emit = 0
+        # the cycle's own start: ``ingest_begins`` moves ``t0`` past the
+        # interning, ``Stages.cycle`` counts from here
+        self.t_begin = t0
         self.t0 = t0
         self.t_dispatch = t0
-
-    def span(self, stage: str, count: int = 0) -> Span:
-        """A stage of this cycle, for code that holds the token."""
-        return Span(self, stage, count)
 
     def record(self, stage: str, t_start: float, t_end: float,
                count: int) -> None:
@@ -262,8 +298,12 @@ class CycleToken:
     def step_begins(self) -> None:
         """The count gate was left staged behind a later dispatch and is
         fetched only now: the step span, the host blocked on this
-        cycle's gate, starts here and not at the dispatch."""
-        self.t_dispatch = self.tracer.clock()
+        cycle's gate, starts here and not at the dispatch.  How long it
+        was left goes into ``Stages.staged`` alone: a span over it would
+        lie over the next batch's whole way in."""
+        now = self.tracer.clock()
+        self.tracer.stage_hist[STAGE_STAGED].record_s(now - self.t_dispatch)
+        self.t_dispatch = now
 
     def step_wait(self):
         """``siddhi.step_wait`` on the profiler's clock, for the caller
@@ -281,9 +321,11 @@ class CycleToken:
 
     def emitted(self, t_fetch_start: float) -> None:
         """This cycle's batch materialized on the host (post coalesced
-        fetch + callback).  Ends the emit span."""
-        self.record(STAGE_EMIT, t_fetch_start, self.tracer.clock(),
-                    self.n_emit)
+        fetch + callback).  Ends the emit span, and the cycle: its whole
+        life, arrival to callback, goes into ``Stages.cycle``."""
+        now = self.tracer.clock()
+        self.record(STAGE_EMIT, t_fetch_start, now, self.n_emit)
+        self.tracer.stage_hist[STAGE_CYCLE].record_s(now - self.t_begin)
 
     def aborted(self, stage: str) -> None:
         """The cycle died inside ``stage`` (isolated fault): leave a
@@ -305,9 +347,12 @@ class CycleToken:
 class Tracer:
     """Per-app cycle-id source, span sink and flight-recorder owner."""
 
-    #: default: record every 64th cycle (every cycle costs 12 ms window
-    #: batches 2.6% on the chip, of which the spans' own bookkeeping is
-    #: 0.4%: PERF.md, PR 26)
+    #: default: record every 64th cycle.  Every cycle (``sample='1'``)
+    #: costs 5.4 ms window batches of 10 spans 3.2% on the chip (four
+    #: pairs, -1.0% to -6.3%) and host-bound 14 ms pattern batches of 13
+    #: spans 1.8% (three pairs, -1.4% to -3.7%): 17-20 us a span, the
+    #: ring and the histogram a tenth of it (PERF.md section 6, PR 37;
+    #: PR 26 read 2.6% on 12 ms window batches of 18 spans)
     DEFAULT_SAMPLE = 64
     #: default flight-recorder depth in cycles
     DEFAULT_CYCLES = 64

@@ -849,7 +849,6 @@ class AppPlanner:
                     table = DeviceTable(
                         td, capacity=self.app_context.devtable_capacity,
                         faults=self.app_context.fault_injector,
-                        tracer=self.app_context.tracer,
                         statistics_manager=sm)
                     if sm is not None:
                         sm.register_devtable(td.id, table)

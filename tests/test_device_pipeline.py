@@ -210,9 +210,9 @@ def test_failing_count_gate_drops_one_batch(kind):
         got = [app.send(i) for i in range(3)]
         real, broken = app.pipe.submit, []
 
-        def breaking(tok, pending, deliver):
+        def breaking(tok, pending, build, emit):
             broken.append(pending)
-            real(tok, _BrokenGate(pending), deliver)
+            real(tok, _BrokenGate(pending), build, emit)
 
         app.pipe.submit = breaking
         got.append(app.send(3))
@@ -350,9 +350,9 @@ def test_failing_deferred_gate_drops_one_batch(kind, force_pipelined):
             app.send(i)
         real, broken = app.pipe.submit, []
 
-        def breaking(tok, pending, deliver):
+        def breaking(tok, pending, build, emit):
             broken.append(pending)
-            real(tok, _BrokenGate(pending), deliver)
+            real(tok, _BrokenGate(pending), build, emit)
 
         app.pipe.submit = breaking
         app.send(3)

@@ -395,7 +395,7 @@ SERVED_PATHS = {
                        {"intern": 1, "convert": 2, "put": 1, "dispatch": 1},
                        "device"),
 }
-EVERY_CYCLE = ("ingest", "step", "emit", "fetch", "deliver")
+EVERY_CYCLE = ("ingest", "step", "emit", "fetch", "build", "deliver")
 
 
 def covered(spans, lo, hi):
@@ -466,7 +466,8 @@ def test_spans_tile_send_batch(path, monkeypatch):
                 # to the count gate, emit from the fetch to the delivery
                 assert step[3] == ingest[4] and step[4] <= emit[3]
                 assert by["fetch"][0][3] == emit[3]
-                assert emit[3] <= by["fetch"][0][4] <= by["deliver"][0][3]
+                assert (emit[3] <= by["fetch"][0][4] <= by["build"][0][3]
+                        <= by["build"][0][4] <= by["deliver"][0][3])
                 assert by["deliver"][0][4] <= emit[4]
                 inside = [s for st in ("convert", "plan", "route", "put",
                                        "dispatch")
@@ -488,6 +489,7 @@ def test_spans_tile_send_batch(path, monkeypatch):
                 assert (sum(s[5] for s in by["dispatch"])
                         == len(by["dispatch"]))
                 assert by["deliver"][0][5] == emit[5] > 0
+                assert by["build"][0][5] == emit[5]
                 assert by["fetch"][0][5] > 0
                 assert all(t0 <= s[3] and s[4] <= t1 for s in spans)
                 remainders.append((t1 - t0) - covered(spans, t0, t1))
