@@ -129,8 +129,9 @@ ALLOWLISTS = {
             "ingest: host-side mesh construction",
         f"{_M}:route_to_shards":
             "ingest: host-side shard routing of HOST batches",
-        f"{_M}:ShardedPatternEngine.route":
-            "ingest: host-side shard routing of HOST batches",
+        f"{_M}:pack_round":
+            "ingest: host-side shard routing of a HOST round into the one "
+            "buffer staged_put sends",
         f"{_M}:ShardedPatternEngine.process_deferred":
             "ingest: converts HOST batch inputs before device placement",
     },
@@ -138,8 +139,8 @@ ALLOWLISTS = {
         "siddhi_tpu/core/ingest_stage.py:staged_put":
             "staging: the sanctioned wrapper itself (arms ingest.put)",
         f"{_M}:ShardedPatternEngine._put":
-            "mesh: STATE-row placement; batch-path faults still flow "
-            "through staged_put in parallel/device_shard.py",
+            "mesh: STATE-row placement only (init_state, re-anchor); a "
+            "routed round goes through staged_put in route()",
         f"{_DN}:DensePatternEngine.init_state":
             "state: one-time engine state initialization, not ingest",
         f"{_DN}:DensePatternEngine.maybe_re_anchor":

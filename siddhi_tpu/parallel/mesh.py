@@ -7,13 +7,12 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from siddhi_tpu.core.exceptions import SiddhiAppCreationError
-from siddhi_tpu.core.ingest_stage import host_nbytes
+from siddhi_tpu.core.ingest_stage import staged_put
 from siddhi_tpu.observability.trace import (
     SCOPE_SHARD_COUNT_PSUM,
     STAGE_CONVERT,
     STAGE_DISPATCH,
     STAGE_PLAN,
-    STAGE_PUT,
     STAGE_ROUTE,
     span,
 )
@@ -79,6 +78,45 @@ def _pow2(n: int, floor: int = 16) -> int:
     return max(1 << (max(n, 1) - 1).bit_length(), floor)
 
 
+def _shard_buckets(n_shards: int, parts_per_shard: int, part: np.ndarray,
+                   batch_per_shard: Optional[int]
+                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """Bucket a round's events by owning shard: ``(order, counts,
+    starts, B)``.  ``order`` lists the events shard by shard, each
+    shard's in arrival order; shard ``s`` holds ``order[starts[s] :
+    starts[s] + counts[s]]``; ``B`` is the padded bucket length.
+
+    The rank is a counting sort: the owner cast to the smallest unsigned
+    integer that holds ``n_shards`` (uint8 to 256 shards, uint16 above),
+    for which numpy's stable argsort is a radix pass and not the merge
+    sort an int64 owner gets."""
+    owner = part // parts_per_shard
+    if len(part) and (owner.max() >= n_shards or owner.min() < 0):
+        raise SiddhiAppCreationError(
+            f"partition id out of range for {n_shards} x {parts_per_shard} layout")
+    counts = np.bincount(owner, minlength=n_shards)
+    max_count = int(counts.max()) if len(part) else 0
+    B = int(batch_per_shard) if batch_per_shard is not None else _pow2(max_count)
+    if max_count > B:
+        raise SiddhiAppCreationError(
+            f"shard bucket overflow: {max_count} events for one shard "
+            f"> batch_per_shard={B}")
+    order = np.argsort(
+        owner.astype(np.uint8 if n_shards <= 256 else np.uint16),
+        kind="stable")
+    return order, counts, np.cumsum(counts) - counts, B
+
+
+def _slots(order: np.ndarray, counts: np.ndarray, starts: np.ndarray,
+           B: int) -> np.ndarray:
+    """``pos[i]``: the padded slot of input event ``i`` (shard * B +
+    rank within the shard's bucket)."""
+    pos = np.empty(len(order), dtype=np.int64)
+    pos[order] = (np.arange(len(order))
+                  + np.repeat(np.arange(len(counts)) * B - starts, counts))
+    return pos
+
+
 def route_to_shards(n_shards: int, parts_per_shard: int,
                     part: np.ndarray, cols: Dict[str, np.ndarray],
                     ts: np.ndarray,
@@ -103,36 +141,66 @@ def route_to_shards(n_shards: int, parts_per_shard: int,
     :meth:`ShardedPatternEngine.process`, which splits collision rounds.
     """
     part = np.asarray(part)
-    owner = part // parts_per_shard
-    if len(part) and (owner.max() >= n_shards or owner.min() < 0):
-        raise SiddhiAppCreationError(
-            f"partition id out of range for {n_shards} x {parts_per_shard} layout")
-    counts = np.bincount(owner, minlength=n_shards)
-    max_count = int(counts.max()) if len(part) else 0
-    B = int(batch_per_shard) if batch_per_shard is not None else _pow2(max_count)
-    if max_count > B:
-        raise SiddhiAppCreationError(
-            f"shard bucket overflow: {max_count} events for one shard "
-            f"> batch_per_shard={B}")
+    order, counts, starts, B = _shard_buckets(
+        n_shards, parts_per_shard, part, batch_per_shard)
+    pos = _slots(order, counts, starts, B)
     n = n_shards * B
     # scratch slot: local index parts_per_shard (one reserved row/shard)
     local_part = np.full(n, parts_per_shard, dtype=np.int32)
     out_ts = np.zeros(n, dtype=np.asarray(ts).dtype)
     valid = np.zeros(n, dtype=bool)
     out_cols = {k: np.zeros(n, dtype=np.asarray(v).dtype) for k, v in cols.items()}
-    # vectorized within-bucket rank (cumcount over stably-sorted owners)
-    order = np.argsort(owner, kind="stable")
-    sorted_owner = owner[order]
-    starts = np.searchsorted(sorted_owner, np.arange(n_shards), side="left")
-    rank_sorted = np.arange(len(part)) - starts[sorted_owner]
-    pos = np.empty(len(part), dtype=np.int64)
-    pos[order] = sorted_owner * B + rank_sorted
     local_part[pos] = (part % parts_per_shard).astype(np.int32)
     out_ts[pos] = np.asarray(ts)
     valid[pos] = True
     for k, v in cols.items():
         out_cols[k][pos] = np.asarray(v)
     return local_part, out_cols, out_ts, valid, pos
+
+
+#: Rows of the packed round buffer ahead of the column rows.
+_ROW_PART, _ROW_TS, _ROW_COLS = 0, 1, 2
+
+
+def _lane_dtype(col_key: str):
+    """A device column's lane: an integer attribute's ``|hi`` / ``|lo``
+    word is int32, every other column float32 (``prepare_cols``)."""
+    return np.int32 if "|" in col_key else np.float32
+
+
+def pack_round(n_shards: int, parts_per_shard: int, part: np.ndarray,
+               cols: Dict[str, np.ndarray], ts: np.ndarray,
+               col_keys: List[str],
+               batch_per_shard: Optional[int] = None
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """One routed round as ONE host buffer, ``int32 [2 + len(col_keys),
+    n_shards * B]``: a row a lane, shard ``s`` in columns ``s * B :
+    (s + 1) * B``, the slots ``route_to_shards`` gives.  Row 0: the
+    shard-local partition row (padding: ``parts_per_shard``, the
+    scratch row, so a lane is valid exactly where it is anything else);
+    row 1: the relative timestamp; then ``col_keys`` in order, a float32
+    column through a float32 view of its row, so what crosses is its bit
+    pattern.  Each row is filled by gathers into the shards' contiguous
+    slices.  Returns ``(buf, pos)``."""
+    part = np.asarray(part)
+    order, counts, starts, B = _shard_buckets(
+        n_shards, parts_per_shard, part, batch_per_shard)
+    buf = np.zeros((_ROW_COLS + len(col_keys), n_shards * B), dtype=np.int32)
+    lanes = [(buf[_ROW_TS], np.asarray(ts))]
+    for row, k in zip(buf[_ROW_COLS:], col_keys):
+        lane = _lane_dtype(k)
+        lanes.append((row.view(lane), np.asarray(cols[k]).astype(
+            lane, copy=False)))
+    local = buf[_ROW_PART]
+    for s in range(n_shards):
+        lo, c = int(starts[s]), int(counts[s])
+        at = s * B
+        idx = order[lo:lo + c]
+        local[at:at + c] = part[idx] - s * parts_per_shard
+        local[at + c:at + B] = parts_per_shard
+        for row, lane in lanes:
+            row[at:at + c] = lane[idx]
+    return buf, _slots(order, counts, starts, B)
 
 
 class ShardedPatternEngine:
@@ -183,9 +251,19 @@ class ShardedPatternEngine:
         }
         specs = self.state_specs
 
-        def sharded_step(state, part, cols, ts, valid):
-            new_state, emit, outs, anchor, local = step(state, part, cols,
-                                                        ts, valid)
+        pps = self.parts_per_shard
+        col_keys = self.col_keys
+
+        def sharded_step(state, buf):
+            # a shard's slice of the round's one buffer (pack_round),
+            # taken apart by static slices
+            part = buf[_ROW_PART]
+            cols = {
+                k: (row if _lane_dtype(k) == np.int32
+                    else jax.lax.bitcast_convert_type(row, jnp.float32))
+                for k, row in zip(col_keys, buf[_ROW_COLS:])}
+            new_state, emit, outs, anchor, local = step(
+                state, part, cols, buf[_ROW_TS], part != pps)
             with jax.named_scope(SCOPE_SHARD_COUNT_PSUM):
                 total = jax.lax.psum(local, axis_name=a)
             return new_state, emit, outs, anchor, total
@@ -195,13 +273,12 @@ class ShardedPatternEngine:
         self._step = jax.jit(jax.shard_map(
             sharded_step,
             mesh=mesh,
-            in_specs=(specs, P(a), {k: P(a) for k in self.col_keys},
-                      P(a), P(a)),
+            in_specs=(specs, P(None, a)),
             out_specs=(specs, P(a, None),
                        {"f": P(a, None, None), "i": P(a, None, None)},
                        P(a, None), P()),
         ), donate_argnums=(0,))
-        self._P = P
+        self._round_sharding = NamedSharding(mesh, P(None, a))
         self._NamedSharding = NamedSharding
         self._jax = jax
 
@@ -228,38 +305,33 @@ class ShardedPatternEngine:
     # -- stepping ------------------------------------------------------------
 
     def route(self, part, cols, ts, batch_per_shard=None):
-        """Host arrays -> device arrays routed/padded per shard; also
-        returns the input->slot map.  Caller contract: at most one event
-        per partition per call, timestamps already relative int32, cols
-        already device-lane columns (engine.prepare_cols: float32 floats
-        + int32 hi/lo pairs)."""
-        P = self._P
-        a = self.axis_name
+        """Host arrays -> the round's one device buffer (``pack_round``:
+        routed and padded per shard, a row a lane, split over the mesh
+        by columns); also returns the input->slot map.  Caller contract:
+        at most one event per partition per call, timestamps already
+        relative int32, cols already device-lane columns
+        (engine.prepare_cols: float32 floats + int32 hi/lo pairs).
+        Returns ``((buf,), pos)`` for ``step(state, *args)``."""
         with span(STAGE_ROUTE, len(part)):
-            lp, rc, rts, valid, pos = route_to_shards(
+            buf, pos = pack_round(
                 self.n_shards, self.parts_per_shard, part, cols, ts,
-                batch_per_shard)
-            rc = {k: np.asarray(v) for k, v in rc.items()}
-            rts = np.asarray(rts, dtype=np.int32)
-        # the round's H2D transfer: one put span over its sharded puts
-        with span(STAGE_PUT) as sp:
-            if sp is not None:
-                sp.count = host_nbytes((lp, rc, rts, valid))
-            return (
-                self._put(lp, P(a)),
-                {k: self._put(v, P(a)) for k, v in rc.items()},
-                self._put(rts, P(a)),
-                self._put(valid, P(a)),
-            ), pos
+                self.col_keys, batch_per_shard)
+        # the round's H2D transfer: one put of one leaf behind the
+        # ingest.put fault site (core/ingest_stage.py)
+        return (staged_put(
+            buf, self._round_sharding,
+            faults=getattr(self.engine, "faults", None),
+            stats=getattr(self.engine, "ingest_stats", None)),), pos
 
-    def step(self, state, part, cols, ts, valid):
-        """One sharded step: ``(state', emit[B, 2I], out_vals[B, 2I, O],
-        emit_anchor[B, 2I], global_matches)``.
+    def step(self, state, buf):
+        """One sharded step over a routed round's buffer: ``(state',
+        emit[B, 2I], out_vals[B, 2I, O], emit_anchor[B, 2I],
+        global_matches)``.
 
         The input ``state`` is DONATED (its buffers are consumed, on the
         CPU backend too — snapshot it before stepping if needed; always
         rebind to the returned state)."""
-        return self._step(state, part, cols, ts, valid)
+        return self._step(state, buf)
 
     def process(self, state, part: np.ndarray, cols: Dict[str, np.ndarray],
                 ts: np.ndarray):

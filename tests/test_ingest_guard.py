@@ -51,3 +51,26 @@ def test_allowlist_not_stale():
     surfaces as a ``stale-allowlist`` finding — the list only shrinks."""
     stale = [f for f in _run()["findings"] if f.rule == "stale-allowlist"]
     assert not stale, "\n  ".join(f.render() for f in stale)
+
+
+def test_sharded_route_puts_through_staging():
+    """The mesh's batch path is held to the sanctioned primitive like
+    every other engine's: ``route`` hands its round to ``staged_put``,
+    and ``_put`` (allowlisted: state rows) is not on it."""
+    import ast
+    import inspect
+    import textwrap
+
+    from siddhi_tpu.parallel.mesh import ShardedPatternEngine
+
+    def calls(fn):
+        tree = ast.parse(textwrap.dedent(inspect.getsource(fn)))
+        return {getattr(n.func, "attr", getattr(n.func, "id", None))
+                for n in ast.walk(tree) if isinstance(n, ast.Call)}
+
+    route = calls(ShardedPatternEngine.route)
+    assert "staged_put" in route
+    assert not route & {"_put", "device_put"}
+    # the rounds of a batch reach the device through route alone
+    deferred = calls(ShardedPatternEngine.process_deferred)
+    assert "route" in deferred and "device_put" not in deferred
