@@ -439,7 +439,7 @@ class SiddhiAppRuntime:
             # query: emit side (emitTransfers / deferredBatches /
             # zeroMatchSkips / maxPendingDepth / autoEffectiveDepth) and
             # ingest side (stagedBatches / devicePuts / deviceChunks /
-            # ingestStalls / overlappedBatches / flushSyncs /
+            # fusedHops / ingestStalls / overlappedBatches / flushSyncs /
             # maxStagingDepth)
             for name, qr in list(self.query_runtimes.items()) + [
                 (n, q)

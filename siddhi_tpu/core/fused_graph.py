@@ -51,10 +51,10 @@ class FusedChainRuntime:
         self.state = graph.init_state()
         self.step_invocations = 0  # fused program dispatches (tests)
         # hops kept device-resident: (stages - 1) junction dispatches
-        # saved per fused dispatch (the fusedHops counter)
+        # saved per batch (``IngestStats.fused_hops``: ``fusedHops`` in
+        # ``statistics()`` under the tail query's name)
         self.hops_per_dispatch = (
             len(graph.stages) + (1 if graph.dense is not None else 0) - 1)
-        self.fused_hops = 0
         # count gate, emit queue, drain(), fault isolation, poison
         # quarantine (core/device_pipeline.py); one fused dispatch is
         # one cycle, labeled with the 'fused' kind
@@ -82,7 +82,7 @@ class FusedChainRuntime:
         self.state, pending = self.graph.process_batch_deferred(
             self.state, cols, ts)
         self.step_invocations += 1
-        self.fused_hops += self.hops_per_dispatch
+        self.ingest_stats.fused_hops += self.hops_per_dispatch
         # NaN/Inf quarantine over the WHOLE chain's state tuple
         self.state, poisoned = pipe.quarantine(
             self.state, self.graph.init_state)
@@ -133,7 +133,7 @@ class FusedChainRuntime:
             "stages": len(self.graph.stages)
             + (1 if self.graph.dense is not None else 0),
             "step_invocations": self.step_invocations,
-            "fused_hops": self.fused_hops,
+            "fused_hops": self.ingest_stats.fused_hops,
         }
 
     # -- snapshot contract ---------------------------------------------------

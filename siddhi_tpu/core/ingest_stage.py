@@ -11,9 +11,9 @@ This module holds the pieces every device runtime shares:
 
 - ``IngestStats``: per-runtime staging counters surfaced through
   ``util/statistics.py`` (``stagedBatches`` / ``devicePuts`` /
-  ``deviceChunks`` / ``ingestStalls`` / ``overlappedBatches`` /
-  ``flushSyncs`` / ``maxStagingDepth``, and how often the window opened:
-  ``gatesBySubmit`` / ``gatesByIdle`` / ``pipelineEntries`` /
+  ``deviceChunks`` / ``fusedHops`` / ``ingestStalls`` /
+  ``overlappedBatches`` / ``flushSyncs`` / ``maxStagingDepth``, and how
+  often the window opened: ``gatesBySubmit`` / ``gatesByIdle`` / ``pipelineEntries`` /
   ``pipelineExits``).
 - ``IngestStage``: a bounded staging window.  ``submit(probe, finish)``
   records one dispatched batch whose count gate has NOT been fetched
@@ -77,7 +77,8 @@ class IngestStats:
     thin-gauge style as ``EmitStats``)."""
 
     __slots__ = ("staged_batches", "device_puts", "device_chunks",
-                 "ingest_stalls", "overlapped_batches", "flush_syncs",
+                 "fused_hops", "ingest_stalls", "overlapped_batches",
+                 "flush_syncs",
                  "dropped_batches",
                  "max_staging_depth", "auto_depth", "gates_by_submit",
                  "gates_by_idle", "pipeline_entries", "pipeline_exits")
@@ -88,6 +89,9 @@ class IngestStats:
         # chunks the batches were cut into for the device (the window
         # path, ops/device_query.py: a chunk is a put and a dispatch)
         self.device_chunks = 0
+        # junction hops a fused chain kept on the device: stages - 1 a
+        # batch (core/fused_graph.py); 0 on every other runtime
+        self.fused_hops = 0
         # staged batches whose finish (the count-gate fetch, where XLA
         # reports an asynchronous step failure) raised and was isolated
         self.dropped_batches = 0
@@ -116,6 +120,7 @@ class IngestStats:
             "stagedBatches": self.staged_batches,
             "devicePuts": self.device_puts,
             "deviceChunks": self.device_chunks,
+            "fusedHops": self.fused_hops,
             "ingestStalls": self.ingest_stalls,
             "overlappedBatches": self.overlapped_batches,
             "gatesBySubmit": self.gates_by_submit,

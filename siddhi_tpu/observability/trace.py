@@ -164,13 +164,22 @@ SCOPE_PANE_ASSIGN = "siddhi.pane.assign"        # lanes tiled [L, panes]
 SCOPE_PANE_REDUCE = "siddhi.pane.reduce"        # per (pane, group) segment
 SCOPE_PANE_EMIT = "siddhi.pane.emit"            # a group's last row, select
 SCOPE_PANE_COUNT = "siddhi.pane.count"
+# ops/fused_graph.py: a fused chain's program by the place of the stage.
+# A stage's scope holds the hop that feeds it (the valid mask, the wired
+# lanes) and its filter and select; a window stage's own
+# ``siddhi.window.*`` scopes stay innermost inside it
+SCOPE_FUSED_HEAD = "siddhi.fused.head"          # stage 0
+SCOPE_FUSED_INTERIOR = "siddhi.fused.interior"  # every stage between
+SCOPE_FUSED_TAIL = "siddhi.fused.tail"          # the last stage
+SCOPE_FUSED_COUNT = "siddhi.fused.count"        # the emit count
 DEVICE_SCOPES = (
     SCOPE_DENSE_GATHER, SCOPE_DENSE_ADVANCE, SCOPE_DENSE_KLEENE,
     SCOPE_DENSE_SCATTER, SCOPE_DENSE_COUNT, SCOPE_DENSE_ROUNDS,
     SCOPE_DENSE_RUN, SCOPE_SHARD_COUNT_PSUM, SCOPE_WINDOW_FILTER,
     SCOPE_WINDOW_SLOT, SCOPE_WINDOW_AGGREGATE, SCOPE_WINDOW_EMIT,
     SCOPE_WINDOW_UPDATE, SCOPE_WINDOW_COUNT, SCOPE_PANE_ASSIGN,
-    SCOPE_PANE_REDUCE, SCOPE_PANE_EMIT, SCOPE_PANE_COUNT)
+    SCOPE_PANE_REDUCE, SCOPE_PANE_EMIT, SCOPE_PANE_COUNT, SCOPE_FUSED_HEAD,
+    SCOPE_FUSED_INTERIOR, SCOPE_FUSED_TAIL, SCOPE_FUSED_COUNT)
 
 # the calling thread's open cycle: set by begin_cycle (None for an
 # unsampled cycle), cleared when the cycle's ingest span ends; set

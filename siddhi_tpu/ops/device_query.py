@@ -114,6 +114,7 @@ was cut.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -1196,7 +1197,7 @@ class DeviceQueryEngine:
             self._forever_scatter(state, new_state, argvals, grp, fmask)
         return new_state, ov, out
 
-    def make_step(self, jit: bool = True) -> Callable:
+    def make_step(self, jit: bool = True, scoped: bool = True) -> Callable:
         """Per-event step (filter / running / sliding / keyed_sliding):
 
         step(state, cols {attr: [B] f32}, ts[B] i32 relative-ms,
@@ -1211,14 +1212,20 @@ class DeviceQueryEngine:
         Jitted, the program takes the one packed buffer a chunk puts
         (``_pad_lanes``) in place of the five arguments behind the
         state: ``step(state, buf int32 [k, B])``.
+
+        ``scoped=False`` (a stage of a fused chain, ops/fused_graph.py):
+        the filter, and the select of the stateless kind, open no
+        ``siddhi.window.*`` scope: the chain names them by the stage's
+        place.  A window's own phases keep theirs.
         """
-        key = ("step", jit)
+        key = ("step", jit, scoped)
         if key in self._step_cache:
             return self._step_cache[key]
         jnp = self.jnp
         A = max(len(self.aggs), 1)
 
-        named_scope = self.jax.named_scope
+        named_scope = (self.jax.named_scope if scoped
+                       else lambda _name: contextlib.nullcontext())
 
         def step(state, cols, ts, grp, wgrp, valid):
             B = ts.shape[0]

@@ -60,6 +60,15 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from siddhi_tpu.core.exceptions import SiddhiAppCreationError
+from siddhi_tpu.observability.trace import (
+    SCOPE_FUSED_COUNT,
+    SCOPE_FUSED_HEAD,
+    SCOPE_FUSED_INTERIOR,
+    SCOPE_FUSED_TAIL,
+    STAGE_CONVERT,
+    STAGE_DISPATCH,
+    span,
+)
 from siddhi_tpu.ops.device_query import (
     MAX_DEVICE_BATCH,
     _pow2,
@@ -199,7 +208,15 @@ class FusedGraphEngine:
         seam: ShardedFusedGraphEngine (parallel/fused_shard.py) wraps
         it in shard_map before jitting."""
         jax, jnp = self.jax, self.jnp
-        dev_steps = [eng.make_step(jit=False) for eng in self.stages]
+        named_scope = jax.named_scope
+        # a stage's filter and select go by its place in the chain; a
+        # window stage's own ``siddhi.window.*`` phases stay innermost
+        dev_steps = [eng.make_step(jit=False, scoped=False)
+                     for eng in self.stages]
+        places = [SCOPE_FUSED_INTERIOR] * len(dev_steps)
+        if self.dense is None:  # else every device stage lies before it
+            places[-1] = SCOPE_FUSED_TAIL
+        places[0] = SCOPE_FUSED_HEAD
         wires = self._wires
         dense = self.dense
         if dense is not None:
@@ -215,21 +232,25 @@ class FusedGraphEngine:
             ov = valid
             out: Dict = {}
             for si, step in enumerate(dev_steps):
-                if si > 0:
-                    # the hop: wire producer lanes straight into the
-                    # consumer's input env — no compaction, no transfer;
-                    # rows the producer dropped just lose their valid bit
-                    v = v & ov.astype(bool)
-                    cur = {
-                        a: (out[key] if src == "out" else cur[key])
-                        for a, src, key in wires[si]
-                    }
-                st, ov, out, _n = step(states[si], cur, rels[si],
-                                       grp, grp, v)
+                # the hop belongs to the stage it feeds
+                with named_scope(places[si]):
+                    if si > 0:
+                        # the hop: wire producer lanes straight into the
+                        # consumer's input env — no compaction, no
+                        # transfer; rows the producer dropped just lose
+                        # their valid bit
+                        v = v & ov.astype(bool)
+                        cur = {
+                            a: (out[key] if src == "out" else cur[key])
+                            for a, src, key in wires[si]
+                        }
+                    st, ov, out, _n = step(states[si], cur, rels[si],
+                                           grp, grp, v)
                 new_states.append(st)
             if dense is None:
-                emitmask = ov.astype(bool) & v
-                count = jnp.sum(emitmask.astype(jnp.int32))
+                with named_scope(SCOPE_FUSED_COUNT):
+                    emitmask = ov.astype(bool) & v
+                    count = jnp.sum(emitmask.astype(jnp.int32))
                 fwd = {k: cur[k] for k in self.fwd_names}
                 return tuple(new_states), emitmask, out, fwd, count
             # dense tail: the junction path feeds an unpartitioned
@@ -237,39 +258,42 @@ class FusedGraphEngine:
             # that exact sequence inside the same program.  Invalid rows
             # route to the scratch partition row (what the junction
             # path's padding lanes do) so state stays bit-identical.
-            v = v & ov.astype(bool)
-            dcols = {}
-            for a, src, key, is_int in dwire:
-                lane = out[key] if src == "out" else cur[key]
-                if is_int:
-                    # int32 lane -> the engine's bit-exact hi/lo pair
-                    # (prepare_cols semantics, computed in-jit)
-                    lane = lane.astype(jnp.int32)
-                    dcols[a + "|hi"] = jnp.where(
-                        lane < 0, jnp.int32(-1), jnp.int32(0))
-                    dcols[a + "|lo"] = jnp.bitwise_xor(
-                        lane, jnp.int32(-(2 ** 31)))
-                else:
-                    dcols[a] = lane.astype(jnp.float32)
-            xs = {"__t": rels[-1], "__v": v}
-            for k in dkeys:
-                xs[k] = dcols[k]
+            # the engine's own ``siddhi.dense.*`` scopes stay innermost
+            with named_scope(SCOPE_FUSED_TAIL):
+                v = v & ov.astype(bool)
+                dcols = {}
+                for a, src, key, is_int in dwire:
+                    lane = out[key] if src == "out" else cur[key]
+                    if is_int:
+                        # int32 lane -> the engine's bit-exact hi/lo pair
+                        # (prepare_cols semantics, computed in-jit)
+                        lane = lane.astype(jnp.int32)
+                        dcols[a + "|hi"] = jnp.where(
+                            lane < 0, jnp.int32(-1), jnp.int32(0))
+                        dcols[a + "|lo"] = jnp.bitwise_xor(
+                            lane, jnp.int32(-(2 ** 31)))
+                    else:
+                        dcols[a] = lane.astype(jnp.float32)
+                xs = {"__t": rels[-1], "__v": v}
+                for k in dkeys:
+                    xs[k] = dcols[k]
 
-            def body(dstate, x):
-                vb = x["__v"][None]
-                pi = jnp.where(x["__v"], jnp.int32(0),
-                               jnp.int32(P)).astype(jnp.int32)[None]
-                cb = {k: x[k][None] for k in dkeys}
-                dstate, emit, outs, anchor, _ne = dstep(
-                    dstate, pi, cb, x["__t"][None], vb)
-                return dstate, (emit[0], outs["f"][0], outs["i"][0],
-                                anchor[0])
+                def body(dstate, x):
+                    vb = x["__v"][None]
+                    pi = jnp.where(x["__v"], jnp.int32(0),
+                                   jnp.int32(P)).astype(jnp.int32)[None]
+                    cb = {k: x[k][None] for k in dkeys}
+                    dstate, emit, outs, anchor, _ne = dstep(
+                        dstate, pi, cb, x["__t"][None], vb)
+                    return dstate, (emit[0], outs["f"][0], outs["i"][0],
+                                    anchor[0])
 
-            dstate, ys = jax.lax.scan(body, states[-1], xs)
+                dstate, ys = jax.lax.scan(body, states[-1], xs)
             new_states.append(dstate)
             emit, out_f, out_i, anchor = ys
-            emitmask = emit & v[:, None]
-            count = jnp.sum(emitmask.astype(jnp.int32))
+            with named_scope(SCOPE_FUSED_COUNT):
+                emitmask = emit & v[:, None]
+                count = jnp.sum(emitmask.astype(jnp.int32))
             return (tuple(new_states), emitmask, out_f, out_i, anchor,
                     count)
 
@@ -288,6 +312,8 @@ class FusedGraphEngine:
         if n == 0:
             return states, None
         chunks: List[dict] = []
+        if self.ingest_stats is not None:
+            self.ingest_stats.device_chunks += -(-n // MAX_DEVICE_BATCH)
         if n > MAX_DEVICE_BATCH:
             for i in range(0, n, MAX_DEVICE_BATCH):
                 sl = slice(i, i + MAX_DEVICE_BATCH)
@@ -308,6 +334,45 @@ class FusedGraphEngine:
         n = len(ts)
         B = self._pad_batch(n)
         states = list(states)
+        with span(STAGE_CONVERT, n):
+            c, rels, grp, valid = self._lanes(states, cols, ts, n, B)
+        from siddhi_tpu.core.ingest_stage import staged_put
+
+        c, rels_t, grp, valid = staged_put(
+            (c, tuple(rels), grp, valid), faults=self.faults,
+            stats=self.ingest_stats)
+        if self.faults is not None:
+            self.faults.check("step.device")
+            if self.dense is not None:
+                self.faults.check("step.dense")
+        step = self.make_step()
+        with span(STAGE_DISPATCH, 1):
+            res = step(tuple(states), c, rels_t, grp, valid)
+            # the call's inputs are released with it: dropping the
+            # device buffers is time of the dispatch
+            del c, rels_t, grp, valid
+        if self.tail_kind == TAIL_DEVICE:
+            new_states, emitmask, out, fwd, count = res
+            chunks.append({
+                "kind": TAIL_DEVICE, "emitmask": emitmask,
+                "out": dict(out), "names": list(out),
+                "fwd": dict(fwd), "fwd_names": list(fwd),
+                "count": count, "n": n, "ts": ts,
+            })
+        else:
+            new_states, emitmask, out_f, out_i, anchor, count = res
+            chunks.append({
+                "kind": TAIL_DENSE, "emitmask": emitmask, "f": out_f,
+                "i": out_i, "anchor": anchor, "count": count, "n": n,
+                "offset": offset,
+            })
+        return tuple(new_states)
+
+    def _lanes(self, states: List, cols: Dict[str, np.ndarray],
+               ts: np.ndarray, n: int, B: int):
+        """A chunk's host arrays, padded to ``B``: the head's lanes, a
+        relative timestamp lane a stage, ``grp`` and ``valid``.  A stage
+        past the int32 horizon is re-anchored in ``states``."""
         # per-stage relative timestamps: each stage keeps its own epoch
         # (base_ts), re-anchored host-side at the int32 horizon exactly
         # like its standalone runtime would
@@ -347,33 +412,7 @@ class FusedGraphEngine:
         grp = np.zeros(B, dtype=np.int32)
         valid = np.zeros(B, dtype=bool)
         valid[:n] = True
-        from siddhi_tpu.core.ingest_stage import staged_put
-
-        c, rels_t, grp, valid = staged_put(
-            (c, tuple(rels), grp, valid), faults=self.faults,
-            stats=self.ingest_stats)
-        if self.faults is not None:
-            self.faults.check("step.device")
-            if self.dense is not None:
-                self.faults.check("step.dense")
-        step = self.make_step()
-        res = step(tuple(states), c, rels_t, grp, valid)
-        if self.tail_kind == TAIL_DEVICE:
-            new_states, emitmask, out, fwd, count = res
-            chunks.append({
-                "kind": TAIL_DEVICE, "emitmask": emitmask,
-                "out": dict(out), "names": list(out),
-                "fwd": dict(fwd), "fwd_names": list(fwd),
-                "count": count, "n": n, "ts": ts,
-            })
-        else:
-            new_states, emitmask, out_f, out_i, anchor, count = res
-            chunks.append({
-                "kind": TAIL_DENSE, "emitmask": emitmask, "f": out_f,
-                "i": out_i, "anchor": anchor, "count": count, "n": n,
-                "offset": offset,
-            })
-        return tuple(new_states)
+        return c, rels, grp, valid
 
 
 class FusedDeferredEmit:

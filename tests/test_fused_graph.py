@@ -446,3 +446,186 @@ group by sym insert into Out;
             SiddhiManager().create_siddhi_app_runtime(
                 "@app:fuse define stream S (v double); "
                 "@info(name='q') from S select v insert into Out;")
+
+
+# -- the benchmark's chained deployment (benchmark/configs/cse_chain3.json) ---
+
+def _cse_chain3():
+    """The configuration ``cse_chain3``, its traffic mix and its cell's
+    generator."""
+    from test_cse_chain3_reference import cell_files
+
+    return cell_files()[:3]
+
+
+class TestCseChain3:
+    """The cell's own app, as the harness deploys it: the fused form
+    against the junction-hopped one, and what the fused engine records
+    of a batch."""
+
+    def _deploy(self, mgr, fuse, tag, extra=""):
+        config, _traffic, _gen = _cse_chain3()
+        header = config["header"].format(**config["rehearsal"])
+        if not fuse:
+            header = header.replace("@app:fuse", "")
+        return config, mgr.create_siddhi_app_runtime(
+            f"@app:name('c3{tag}') {header} {extra} {config['app']}")
+
+    def _rows(self, fuse, schedule, n_batches):
+        mgr = SiddhiManager()
+        try:
+            config, rt = self._deploy(mgr, fuse, "F" if fuse else "J")
+            got = []
+            rt.add_callback(config["output"], lambda evs: got.extend(
+                (e.timestamp, *e.data) for e in evs))
+            rt.start()
+            h = rt.get_input_handler(config["stream"])
+            for n in range(-schedule.warmup, n_batches - schedule.warmup):
+                h.send_batch(schedule.batch(n))
+            low = rt.lowering()
+            fallbacks = dict(
+                rt.app_context.statistics_manager.fused_fallbacks)
+            rt.shutdown()
+            return got, low, fallbacks
+        finally:
+            mgr.shutdown()
+
+    @pytest.mark.parametrize("rehearsal", [True, False],
+                             ids=["512-row", "8192-row"])
+    def test_fused_equals_junction_hopped_at_the_cells_batches(
+            self, rehearsal):
+        """Rows, values and event timestamps bit-equal at the
+        rehearsal's batch and at the cell's (four 2,048-row chunks a
+        batch on the fused side, one 8,192-row step a stage on the
+        other); the window is carried over every batch boundary."""
+        config, traffic, gen = _cse_chain3()
+        schedule = gen.make(2**31 + 39, config, traffic, rehearsal)
+        assert schedule.batch_events == (512 if rehearsal else 8192)
+        gf, lf, ff = self._rows(True, schedule, 5)
+        gj, lj, _ = self._rows(False, schedule, 5)
+        assert lf == config["expect"]["lowering"] and not ff
+        assert lj == {"q1": "device", "q2": "device", "q3": "device"}
+        # a third of the events: two thirds pass q1, half of those q3
+        assert 0.25 < len(gf) / (5 * schedule.batch_events) < 0.42
+        assert gf == gj
+
+    def test_the_four_fused_scopes_are_on_the_lowered_program(self):
+        """Declared in ``DEVICE_SCOPES`` and on the operations: the head
+        and tail filters under their place alone, the window's own
+        phases innermost inside ``interior``."""
+        import re
+
+        from siddhi_tpu.observability import trace as trace_mod
+
+        fused = {sc for sc in trace_mod.DEVICE_SCOPES if ".fused." in sc}
+        assert fused == {"siddhi.fused.head", "siddhi.fused.interior",
+                         "siddhi.fused.tail", "siddhi.fused.count"}
+        mgr = SiddhiManager()
+        try:
+            _config, rt = self._deploy(mgr, True, "S")
+            graph = rt.query_runtimes["q3"].device_runtime.graph
+            B = 64
+            lanes = graph._lanes(
+                list(graph.init_state()),
+                {"price": np.linspace(100.0, 999.0, B).astype(np.float32),
+                 "volume": np.arange(B, dtype=np.int32)},
+                1000 + np.arange(B, dtype=np.int64), B, B)
+            c, rels, grp, valid = lanes
+            text = graph.make_step().lower(
+                graph.init_state(), c, tuple(rels), grp,
+                valid).compile().as_text()
+            rt.shutdown()
+        finally:
+            mgr.shutdown()
+        paths = set(re.findall(r'op_name="([^"]*)"', text))
+
+        def innermost(path):
+            named = [p for p in path.split("/") if p.startswith("siddhi.")]
+            return named[-1] if named else None
+
+        owned = {innermost(p) for p in paths}
+        assert fused <= owned
+        # a window keeps its phases, and only inside the interior stage
+        for p in paths:
+            if "siddhi.window." in p:
+                assert "siddhi.fused.interior/" in p.split(
+                    "siddhi.window.")[0]
+        assert {"siddhi.window.slot", "siddhi.window.aggregate"} <= owned
+        # the stateless stages open no window scope of their own
+        assert not any("siddhi.window." in p for p in paths
+                       if "siddhi.fused.head" in p
+                       or "siddhi.fused.tail" in p)
+
+    def test_convert_put_dispatch_tile_ingest_chunk_by_chunk(self):
+        """On a clock that ticks at every reading: a chunk is a
+        ``convert``, a ``put`` and a ``dispatch``, each starting at the
+        next reading after the one before ended, the first of them at
+        the next after ``ingest`` began and ``ingest`` ending at the
+        next after the last; the counters say the same."""
+        from test_way_back import Ticks
+
+        config, traffic, gen = _cse_chain3()
+        schedule = gen.make(7, config, dict(
+            traffic, rehearsal={"batch": 5000, "warmup": 1}), True)
+        mgr = SiddhiManager()
+        try:
+            _config, rt = self._deploy(
+                mgr, True, "T", "@app:trace(sample='1', cycles='64') "
+                "@app:statistics(reporter='none')")
+            tracer = rt.app_context.tracer
+            tracer.clock = Ticks()
+            rt.add_callback(config["output"], lambda evs: None)
+            rt.start()
+            h = rt.get_input_handler(config["stream"])
+            for n in range(-1, 2):
+                h.send_batch(schedule.batch(n))
+            groups = tracer.recorder.cycle_groups()
+            assert len(groups) == 3
+            for spans in groups.values():
+                assert {s[2] for s in spans} == {"fused"}
+                (ingest,) = [s for s in spans if s[1] == "ingest"]
+                inside = [s for s in spans
+                          if ingest[3] < s[3] and s[4] < ingest[4]]
+                # 5,000 rows: chunks of 2,048, 2,048 and 904
+                assert [s[1] for s in inside] == [
+                    "convert", "put", "dispatch"] * 3
+                assert [s[5] for s in inside if s[1] == "convert"] == [
+                    2048, 2048, 904]
+                assert all(s[5] == 1 for s in inside
+                           if s[1] == "dispatch")
+                assert all(s[5] > 0 for s in inside if s[1] == "put")
+                edges = [ingest[3]] + [t for s in inside
+                                       for t in (s[3], s[4])] + [ingest[4]]
+                assert all(b - a == 1 for a, b in zip(edges, edges[1:]))
+                assert ingest[5] == 5000
+            st = rt.statistics()
+            pre = "io.siddhi.SiddhiApps.c3T.Siddhi.Queries.q3."
+            assert st[pre + "deviceChunks"] == 9
+            assert st[pre + "devicePuts"] == 9
+            assert st[pre + "fusedHops"] == 6    # two hops a batch
+            dr = rt.query_runtimes["q3"].device_runtime
+            assert dr.stats()["fused_hops"] == 6
+            assert dr.step_invocations == 3
+            rt.shutdown()
+        finally:
+            mgr.shutdown()
+
+    def test_a_junction_hopped_query_counts_no_fused_hop(self):
+        mgr = SiddhiManager()
+        try:
+            config, rt = self._deploy(
+                mgr, False, "H", "@app:statistics(reporter='none')")
+            rt.add_callback(config["output"], lambda evs: None)
+            rt.start()
+            _c, traffic, gen = _cse_chain3()
+            schedule = gen.make(3, config, traffic, True)
+            rt.get_input_handler(config["stream"]).send_batch(
+                schedule.batch(0))
+            st = rt.statistics()
+            pre = "io.siddhi.SiddhiApps.c3H.Siddhi.Queries."
+            for q in ("q1", "q2", "q3"):
+                assert st[pre + q + ".fusedHops"] == 0
+                assert st[pre + q + ".deviceChunks"] == 1
+            rt.shutdown()
+        finally:
+            mgr.shutdown()
