@@ -61,8 +61,8 @@ ALLOWLISTS = {
             "ingest: host-side window-group interning",
         f"{_DQ}:DeviceQueryEngine.host_lane_cols":
             "ingest: HOST lane materialization for host fallbacks",
-        f"{_DQ}:DeviceQueryEngine._pad_lanes":
-            "ingest: names the HOST lanes of the step's packed buffer",
+        f"{_DQ}:DeviceQueryEngine._host_lanes":
+            "ingest: names the HOST lanes of a packed buffer",
         f"{_DQ}:DeviceQueryEngine._pack":
             "ingest: packs HOST lanes into the one buffer of a put",
         f"{_DQ}:DeviceQueryEngine._host_filter_mask":

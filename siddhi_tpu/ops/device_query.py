@@ -189,8 +189,9 @@ PER_FLUSH = "per_flush"
 # allocate quadratically.  Its square is also the element budget the
 # global sliding window's [rows, W, A] gather is held to
 # (DeviceQueryEngine._chunk_rows); the filter and tumbling kinds are
-# not chunked.  The fused graph and the sharded wrapper cut every batch
-# at this bound in loops of their own
+# not chunked.  A fused chain (ops/fused_graph.py) takes the smallest
+# bound among its stages; the sharded wrapper (parallel/device_shard.py)
+# cuts every batch at this bound in a loop of its own
 MAX_DEVICE_BATCH = 2048
 
 # longest lengthBatch pane the one-program path tiles.  Its same-group
@@ -1957,16 +1958,23 @@ class DeviceQueryEngine:
             rows[k] = row != 0 if lane == np.bool_ else row
         return rows
 
-    def _pad_lanes(self, cols, rel, grp, n, wgrp=None) -> np.ndarray:
-        """The batch as the step's one packed buffer, padded to a power
-        of two: a row for each of ``lane_rows``."""
+    def _host_lanes(self, cols, n: int, names: List[str]
+                    ) -> Dict[str, Optional[np.ndarray]]:
+        """The batch's columns under the lane names ``names`` (None: a
+        column the batch does not bring), as ``_pack`` takes them."""
         lanes: Dict[str, Optional[np.ndarray]] = {
-            k: cols.get(k) for k in self.read_lanes if "|" not in k}
+            k: cols.get(k) for k in names if "|" not in k}
         # a LONG the expressions compare: both words of its hi/lo pair
-        for a in {k.split("|")[0] for k in self.read_lanes if "|" in k}:
+        for a in {k.split("|")[0] for k in names if "|" in k}:
             lanes[a + "|hi"], lanes[a + "|lo"] = (
                 _split_i64(np.asarray(cols[a])[:n]) if a in cols
                 else (None, None))
+        return lanes
+
+    def _pad_lanes(self, cols, rel, grp, n, wgrp=None) -> np.ndarray:
+        """The batch as the step's one packed buffer, padded to a power
+        of two: a row for each of ``lane_rows``."""
+        lanes = self._host_lanes(cols, n, self.read_lanes)
         lanes.update({TS_KEY: rel, GRP_KEY: grp, WGRP_KEY: wgrp,
                       VALID_KEY: np.ones(n, dtype=np.int32)})
         return self._pack({k: lanes[k] for k in self.lane_rows}, n, _pow2(n))

@@ -19,6 +19,19 @@ re-padded.  The host is touched only at the chain head (one
 `staged_put` per chunk), at the count-gated emit drain, and at the
 re-anchor horizon (~24.8 days), exactly like a single device query.
 
+Host to device, as a single device query (ops/device_query.py): a
+chunk crosses as ONE packed ``int32 [k, B]`` buffer (the head's
+``_pack``), one ``staged_put`` of one leaf and one call of the fused
+program, which takes it apart by static slices (``_unpack``).  Its
+rows (``buf_rows``): the head lanes the chain consumes (those the
+head's expressions read and those a wire carries downstream), a
+relative-timestamp row for each stage whose step keeps or reads one,
+and the valid mask; group ids and the timestamps of the other stages
+are zeros made in the program.  A batch is cut only as far as the
+stages ask: the smallest ``chunk_rows`` among them (a running stage
+2,048; a ``length(10)`` window with two aggregates 131,072; filters
+never).
+
 Stage subset (the planner falls back to the junction path, with a
 counted reason, for anything else — planner/fusion.py):
 
@@ -69,11 +82,7 @@ from siddhi_tpu.observability.trace import (
     STAGE_DISPATCH,
     span,
 )
-from siddhi_tpu.ops.device_query import (
-    MAX_DEVICE_BATCH,
-    _pow2,
-    _split_i64,
-)
+from siddhi_tpu.ops.device_query import TS_KEY, VALID_KEY, _pow2
 
 TAIL_DEVICE = "device"
 TAIL_DENSE = "dense"
@@ -151,6 +160,30 @@ class FusedGraphEngine:
             self.fwd_names = sorted({
                 v for kind, v, _n in tail.out_spec if kind == "passthrough"
             })
+        # the packed buffer's rows.  Head lanes: what the head's
+        # expressions read and what the first wire (or a dense tail
+        # right behind the head) takes from the head's input; further
+        # wires and the tail's passthroughs reach the head only through
+        # those
+        wire = self._wires[1] if len(stages) > 1 else self._dense_wire
+        self.head_rows: List[str] = list(dict.fromkeys(
+            [*head.read_lanes, *(w[2] for w in wire if w[1] == "in")]))
+        # a relative-timestamp row where a step keeps or reads it (each
+        # stage has its own epoch); the dense tail always does
+        self.ts_rows: Dict[int, str] = {
+            si: f"{TS_KEY}|{si}" for si, eng in enumerate(stages)
+            if eng.W or eng._reads_ts}
+        if dense_tail is not None:
+            self.ts_rows[len(stages)] = f"{TS_KEY}|{len(stages)}"
+        self.buf_rows: List[str] = [
+            *self.head_rows, *self.ts_rows.values(), VALID_KEY]
+        # rows a chunk: the tightest bound among the stages' own (None:
+        # a batch is never cut).  A dense tail adds none: its scan
+        # holds one row's working set at a time and its emit arrays
+        # grow with the rows however they are cut
+        bounds = [eng.chunk_rows for eng in stages
+                  if eng.chunk_rows is not None]
+        self.chunk_rows: Optional[int] = min(bounds) if bounds else None
         # wired by the runtime (staged_put device-put accounting)
         self.ingest_stats = None
         # @app:faults injector (planner-wired; one chain = one step site)
@@ -189,8 +222,7 @@ class FusedGraphEngine:
     def make_step(self) -> Callable:
         """One jit over the whole chain:
 
-        fused(states, cols {head lane: [B]}, rels (per-stage [B] i32),
-              grp [B] i32, valid [B] bool)
+        fused(states, buf int32 [k, B]: a row for each of ``buf_rows``)
           -> device tail: (states, emitmask[B], out {name: [B]},
                            fwd {attr: [B]}, count)
           -> dense tail:  (states, emitmask[B, 2I], f, i, anchor, count)
@@ -225,7 +257,16 @@ class FusedGraphEngine:
             dwire = self._dense_wire
             P = dense.n_partitions
 
-        def fused(states, cols, rels, grp, valid):
+        head = self.stages[0]
+        n_rels = len(dev_steps) + (dense is not None)
+
+        def fused(states, buf):
+            rows = head._unpack(buf, self.buf_rows)
+            grp = jnp.zeros(buf.shape[1], jnp.int32)
+            cols = {k: rows[k] for k in self.head_rows}
+            rels = [rows[self.ts_rows[si]] if si in self.ts_rows else grp
+                    for si in range(n_rels)]
+            valid = rows[VALID_KEY] != 0
             new_states = []
             v = valid
             cur = cols
@@ -304,19 +345,21 @@ class FusedGraphEngine:
     def process_batch_deferred(self, states: Tuple,
                                cols: Dict[str, np.ndarray],
                                ts: np.ndarray):
-        """Run the fused program over one junction batch (chunked at
-        MAX_DEVICE_BATCH) and keep every output device-resident behind
-        a FusedDeferredEmit — the async-emit contract of the per-query
-        engines, for the whole chain at once."""
+        """Run the fused program over one junction batch (whole, or
+        in chunks of ``chunk_rows`` where a stage bounds them) and keep
+        every output device-resident behind a FusedDeferredEmit — the
+        async-emit contract of the per-query engines, for the whole
+        chain at once."""
         n = len(ts)
         if n == 0:
             return states, None
         chunks: List[dict] = []
+        rows = self.chunk_rows or n
         if self.ingest_stats is not None:
-            self.ingest_stats.device_chunks += -(-n // MAX_DEVICE_BATCH)
-        if n > MAX_DEVICE_BATCH:
-            for i in range(0, n, MAX_DEVICE_BATCH):
-                sl = slice(i, i + MAX_DEVICE_BATCH)
+            self.ingest_stats.device_chunks += -(-n // rows)
+        if n > rows:
+            for i in range(0, n, rows):
+                sl = slice(i, i + rows)
                 states = self._chunk(
                     states, {k: v[sl] for k, v in cols.items()}, ts[sl],
                     i, chunks)
@@ -335,22 +378,20 @@ class FusedGraphEngine:
         B = self._pad_batch(n)
         states = list(states)
         with span(STAGE_CONVERT, n):
-            c, rels, grp, valid = self._lanes(states, cols, ts, n, B)
+            buf = self._lanes(states, cols, ts, n, B)
         from siddhi_tpu.core.ingest_stage import staged_put
 
-        c, rels_t, grp, valid = staged_put(
-            (c, tuple(rels), grp, valid), faults=self.faults,
-            stats=self.ingest_stats)
+        buf = staged_put(buf, faults=self.faults, stats=self.ingest_stats)
         if self.faults is not None:
             self.faults.check("step.device")
             if self.dense is not None:
                 self.faults.check("step.dense")
         step = self.make_step()
         with span(STAGE_DISPATCH, 1):
-            res = step(tuple(states), c, rels_t, grp, valid)
-            # the call's inputs are released with it: dropping the
-            # device buffers is time of the dispatch
-            del c, rels_t, grp, valid
+            res = step(tuple(states), buf)
+            # the call's input is released with it: dropping the
+            # device buffer is time of the dispatch
+            del buf
         if self.tail_kind == TAIL_DEVICE:
             new_states, emitmask, out, fwd, count = res
             chunks.append({
@@ -369,50 +410,31 @@ class FusedGraphEngine:
         return tuple(new_states)
 
     def _lanes(self, states: List, cols: Dict[str, np.ndarray],
-               ts: np.ndarray, n: int, B: int):
-        """A chunk's host arrays, padded to ``B``: the head's lanes, a
-        relative timestamp lane a stage, ``grp`` and ``valid``.  A stage
-        past the int32 horizon is re-anchored in ``states``."""
+               ts: np.ndarray, n: int, B: int) -> np.ndarray:
+        """A chunk as the fused program's one packed buffer, ``int32
+        [len(buf_rows), B]``, zeros past ``n``.  A stage past the int32
+        horizon is re-anchored in ``states``."""
+        head = self.stages[0]
+        lanes = head._host_lanes(cols, n, self.head_rows)
         # per-stage relative timestamps: each stage keeps its own epoch
         # (base_ts), re-anchored host-side at the int32 horizon exactly
-        # like its standalone runtime would
-        rels: List[np.ndarray] = []
+        # like its standalone runtime would, whether or not its step
+        # takes a row of them
         for si, eng in enumerate(self.stages):
             if eng.base_ts is None:
                 eng.base_ts = int(ts[0]) - 1
             rel64 = ts - eng.base_ts
             if int(rel64.max()) >= eng._REL_LIMIT:
                 states[si], rel64 = eng._re_anchor(states[si], rel64)
-            r = np.zeros(B, dtype=np.int32)
-            r[:n] = rel64.astype(np.int32)
-            rels.append(r)
+            if si in self.ts_rows:
+                lanes[self.ts_rows[si]] = rel64
         if self.dense is not None:
             rel64 = self.dense.rel_ts64(ts)
             states[-1], rel64 = self.dense.maybe_re_anchor(
                 states[-1], rel64)
-            r = np.zeros(B, dtype=np.int32)
-            r[:n] = rel64.astype(np.int32)
-            rels.append(r)
-        # head lanes: zero-padded to B, one staged_put for the whole
-        # chain's chunk (the single sanctioned ingest device_put)
-        head = self.stages[0]
-        c: Dict[str, np.ndarray] = {}
-        for a, lane in head._lane_dtype.items():
-            col = np.zeros(B, dtype=lane)
-            if a in cols:
-                col[:n] = cols[a].astype(lane)
-            c[a] = col
-        for a in head.long_attrs:
-            hi = np.zeros(B, dtype=np.int32)
-            lo = np.zeros(B, dtype=np.int32)
-            if a in cols:
-                hi[:n], lo[:n] = _split_i64(cols[a])
-            c[a + "|hi"] = hi
-            c[a + "|lo"] = lo
-        grp = np.zeros(B, dtype=np.int32)
-        valid = np.zeros(B, dtype=bool)
-        valid[:n] = True
-        return c, rels, grp, valid
+            lanes[self.ts_rows[len(self.stages)]] = rel64
+        lanes[VALID_KEY] = np.ones(n, dtype=np.int32)
+        return head._pack({k: lanes[k] for k in self.buf_rows}, n, B)
 
 
 class FusedDeferredEmit:

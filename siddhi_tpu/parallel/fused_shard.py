@@ -75,20 +75,20 @@ class ShardedFusedGraphEngine(FusedGraphEngine):
         raw = self._build_fused()
         a = self.axis_name
 
-        def sharded(states, cols, rels, grp, valid):
-            states2, emitmask, out, fwd, n_local = raw(
-                states, cols, rels, grp, valid)
+        def sharded(states, buf):
+            states2, emitmask, out, fwd, n_local = raw(states, buf)
             # one replicated count scalar for the async-emit gate
             total = jax.lax.psum(n_local, axis_name=a)
             return states2, emitmask, out, fwd, total
 
         # pytree-prefix specs: filter stages hold EMPTY state dicts
-        # (nothing to place), every lane/mask shards along the batch
-        # axis, and the count comes back replicated
+        # (nothing to place), the packed buffer shards along its batch
+        # (second) axis as every output lane and mask does along its
+        # first, and the count comes back replicated
         self._fused_step = jax.jit(shard_map(
             sharded,
             mesh=self.mesh,
-            in_specs=(P(), P(a), P(a), P(a), P(a)),
+            in_specs=(P(), P(None, a)),
             out_specs=(P(), P(a), P(a), P(a), P()),
         ), donate_argnums=(0,))
         return self._fused_step

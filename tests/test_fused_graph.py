@@ -495,7 +495,7 @@ class TestCseChain3:
     def test_fused_equals_junction_hopped_at_the_cells_batches(
             self, rehearsal):
         """Rows, values and event timestamps bit-equal at the
-        rehearsal's batch and at the cell's (four 2,048-row chunks a
+        rehearsal's batch and at the cell's (one 8,192-row chunk a
         batch on the fused side, one 8,192-row step a stage on the
         other); the window is carried over every batch boundary."""
         config, traffic, gen = _cse_chain3()
@@ -525,15 +525,13 @@ class TestCseChain3:
             _config, rt = self._deploy(mgr, True, "S")
             graph = rt.query_runtimes["q3"].device_runtime.graph
             B = 64
-            lanes = graph._lanes(
+            buf = graph._lanes(
                 list(graph.init_state()),
                 {"price": np.linspace(100.0, 999.0, B).astype(np.float32),
                  "volume": np.arange(B, dtype=np.int32)},
                 1000 + np.arange(B, dtype=np.int64), B, B)
-            c, rels, grp, valid = lanes
             text = graph.make_step().lower(
-                graph.init_state(), c, tuple(rels), grp,
-                valid).compile().as_text()
+                graph.init_state(), buf).compile().as_text()
             rt.shutdown()
         finally:
             mgr.shutdown()
@@ -561,7 +559,8 @@ class TestCseChain3:
         ``convert``, a ``put`` and a ``dispatch``, each starting at the
         next reading after the one before ended, the first of them at
         the next after ``ingest`` began and ``ingest`` ending at the
-        next after the last; the counters say the same."""
+        next after the last; the counters say the same.  The chain's
+        bound is its window's (131,072 rows): a batch is one chunk."""
         from test_way_back import Ticks
 
         config, traffic, gen = _cse_chain3()
@@ -586,22 +585,24 @@ class TestCseChain3:
                 (ingest,) = [s for s in spans if s[1] == "ingest"]
                 inside = [s for s in spans
                           if ingest[3] < s[3] and s[4] < ingest[4]]
-                # 5,000 rows: chunks of 2,048, 2,048 and 904
+                # 5,000 rows: one chunk, one put of one leaf, one call
                 assert [s[1] for s in inside] == [
-                    "convert", "put", "dispatch"] * 3
+                    "convert", "put", "dispatch"]
                 assert [s[5] for s in inside if s[1] == "convert"] == [
-                    2048, 2048, 904]
+                    5000]
                 assert all(s[5] == 1 for s in inside
                            if s[1] == "dispatch")
-                assert all(s[5] > 0 for s in inside if s[1] == "put")
+                # price, volume, q2's timestamps, valid, padded to 8,192
+                assert [s[5] for s in inside if s[1] == "put"] == [
+                    4 * 8192 * 4]
                 edges = [ingest[3]] + [t for s in inside
                                        for t in (s[3], s[4])] + [ingest[4]]
                 assert all(b - a == 1 for a, b in zip(edges, edges[1:]))
                 assert ingest[5] == 5000
             st = rt.statistics()
             pre = "io.siddhi.SiddhiApps.c3T.Siddhi.Queries.q3."
-            assert st[pre + "deviceChunks"] == 9
-            assert st[pre + "devicePuts"] == 9
+            assert st[pre + "deviceChunks"] == 3
+            assert st[pre + "devicePuts"] == 3
             assert st[pre + "fusedHops"] == 6    # two hops a batch
             dr = rt.query_runtimes["q3"].device_runtime
             assert dr.stats()["fused_hops"] == 6
