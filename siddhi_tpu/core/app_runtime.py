@@ -486,11 +486,15 @@ class SiddhiAppRuntime:
         device query engine) — so an ``execution('tpu')`` user can see
         WHICH queries actually lowered instead of silently getting host
         execution (the dense path's capacity introspection analog for
-        the general query path)."""
-        out = {
-            name: getattr(qr, "lowered_to", "host")
-            for name, qr in self.query_runtimes.items()
-        }
+        the general query path).  A ``'devtable'`` join whose table has
+        demoted itself to the host mid-run answers every batch by the
+        host join from then on, and reads ``'host'``."""
+        out = {}
+        for name, qr in self.query_runtimes.items():
+            to = getattr(qr, "lowered_to", "host")
+            if to == "devtable" and qr.device_runtime.table.demoted:
+                to = "host"
+            out[name] = to
         for pr in self.partitions.values():
             if hasattr(pr, "query_lowering"):
                 out.update(pr.query_lowering())

@@ -25,8 +25,12 @@ to the host ``JoinRuntime._join``'s row-major ``np.nonzero`` order.
 The runtime mirrors ``DeviceQueryRuntime``'s pipeline discipline:
 ``IngestStage`` staging for the count gate, ``EmitQueue`` for deferred
 materialization, per-batch fault isolation through ``on_fault``, cycle
-tokens for observability (the probe's put is a ``put`` span of its
-cycle).  A demoted table
+tokens for observability: a chunk's way in is ``convert`` (the key
+expression, the lane padding), ``put`` and ``dispatch`` inside
+``ingest``; a batch of more than ``MAX_CHUNK`` events is as many
+chunks, each finished (gate, fetch, rows) before the next is put, and
+every chunk's spans are its batch's cycle's.  The probe's phases on the
+device are the ``siddhi.devtable.*`` scopes.  A demoted table
 (or a null-carrying batch) falls back per batch to the exact host
 cross-product semantics — after a pipeline drain, so emit order holds.
 """
@@ -43,6 +47,15 @@ from siddhi_tpu.core import event as ev
 from siddhi_tpu.core.device_pipeline import CountGate, DevicePipeline
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.ingest_stage import staged_put
+from siddhi_tpu.observability.trace import (
+    SCOPE_DEVTABLE_CONDITION,
+    SCOPE_DEVTABLE_GATHER,
+    SCOPE_DEVTABLE_PROBE,
+    STAGE_CONVERT,
+    STAGE_DISPATCH,
+    reopen,
+    span,
+)
 from siddhi_tpu.planner.expr import N_KEY, TS_KEY
 
 log = logging.getLogger("siddhi_tpu")
@@ -94,20 +107,31 @@ class DevTableJoinRuntime:
         def probe(keys, ev_mask, ev_lanes, pk_col, tcols, valid):
             import jax.numpy as jnp
 
-            oneh = (keys[:, None] == pk_col[None, :]) & valid[None, :]
-            matched = oneh.any(axis=1) & ev_mask
-            slot = jnp.argmax(oneh, axis=1)
-            gathered = {nm: c[slot] for nm, c in tcols.items()}
-            env = dict(ev_lanes)
-            for qk, nm in tbl_env.items():
-                env[qk] = gathered[nm]
-            env[N_KEY] = keys.shape[0]
-            ok = jnp.broadcast_to(
-                jnp.asarray(cond_fn(env)).astype(bool), matched.shape)
-            mask = matched & ok
-            return mask, gathered, jnp.sum(mask.astype(jnp.int32))
+            with jax.named_scope(SCOPE_DEVTABLE_PROBE):
+                oneh = (keys[:, None] == pk_col[None, :]) & valid[None, :]
+                matched = oneh.any(axis=1) & ev_mask
+                slot = jnp.argmax(oneh, axis=1)
+            with jax.named_scope(SCOPE_DEVTABLE_GATHER):
+                gathered = {nm: c[slot] for nm, c in tcols.items()}
+            with jax.named_scope(SCOPE_DEVTABLE_CONDITION):
+                env = dict(ev_lanes)
+                for qk, nm in tbl_env.items():
+                    env[qk] = gathered[nm]
+                env[N_KEY] = keys.shape[0]
+                ok = jnp.broadcast_to(
+                    jnp.asarray(cond_fn(env)).astype(bool), matched.shape)
+                mask = matched & ok
+                count = jnp.sum(mask.astype(jnp.int32))
+            return mask, gathered, count
 
         self._probe = jax.jit(probe)
+
+    @property
+    def state(self):
+        """Where the join's state lives: the table's current ``(columns,
+        validity lane)`` device references, the very arrays the next
+        probe would read.  A table demoted to the host holds none."""
+        return () if self.table.demoted else self.table.device_state()
 
     # -- batch entry ------------------------------------------------------
 
@@ -126,7 +150,9 @@ class DevTableJoinRuntime:
             self._host_join(cur, now)
             return
         with self.pipeline.cycle(n) as tok:
-            keys = self._event_keys(cur)
+            with span(STAGE_CONVERT):  # counted by the chunks' own
+                keys = self._event_keys(cur)
+            self.ingest_stats.device_chunks += -(-n // self.MAX_CHUNK)
             for lo in range(0, n, self.MAX_CHUNK):
                 hi = min(n, lo + self.MAX_CHUNK)
                 self._dispatch_chunk(cur, keys, lo, hi, now, tok)
@@ -148,22 +174,30 @@ class DevTableJoinRuntime:
 
     def _dispatch_chunk(self, cur, keys, lo, hi, now, tok):
         cn = hi - lo
-        B = _pow2(cn)
-        klane = np.zeros(B, dtype=np.int32)
-        klane[:cn] = keys[lo:hi].astype(np.int32, copy=False)
-        mlane = np.zeros(B, dtype=bool)
-        mlane[:cn] = True
-        lanes = {}
-        for ek, (attr, dt) in self._cond_lanes.items():
-            col = np.zeros(B, dtype=dt)
-            col[:cn] = cur.columns[attr][lo:hi].astype(dt, copy=False)
-            lanes[ek] = col
-        # snapshot-consistent: CURRENT immutable refs, under the table lock
-        tcols, tvalid = self.table.device_state()
+        if lo and tok is not None:
+            # the chunk before was finished inside its submit, which
+            # closed the cycle's way in: this chunk's opens where it starts
+            reopen(tok)
+            tok.ingest_begins()
+        with span(STAGE_CONVERT, cn):
+            B = _pow2(cn)
+            klane = np.zeros(B, dtype=np.int32)
+            klane[:cn] = keys[lo:hi].astype(np.int32, copy=False)
+            mlane = np.zeros(B, dtype=bool)
+            mlane[:cn] = True
+            lanes = {}
+            for ek, (attr, dt) in self._cond_lanes.items():
+                col = np.zeros(B, dtype=dt)
+                col[:cn] = cur.columns[attr][lo:hi].astype(dt, copy=False)
+                lanes[ek] = col
+            # snapshot-consistent: CURRENT immutable refs, under the
+            # table lock
+            tcols, tvalid = self.table.device_state()
         k_d, m_d, l_d = staged_put((klane, mlane, lanes),
                                    faults=self.faults, stats=self.ingest_stats)
-        mask_d, gathered_d, count_d = self._probe(
-            k_d, m_d, l_d, tcols[self.table.pk], tcols, tvalid)
+        with span(STAGE_DISPATCH, 1):
+            mask_d, gathered_d, count_d = self._probe(
+                k_d, m_d, l_d, tcols[self.table.pk], tcols, tvalid)
         self.step_invocations += 1
         self.probe_invocations += 1
         self.pipeline.submit(

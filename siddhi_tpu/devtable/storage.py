@@ -42,6 +42,12 @@ from siddhi_tpu.core.emit_queue import fetch_coalesced
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.exceptions import SiddhiAppCreationError
 from siddhi_tpu.core.ingest_stage import IngestStats, staged_put
+from siddhi_tpu.observability.trace import (
+    SCOPE_DEVTABLE_SCATTER,
+    STAGE_MUTATE,
+    reopen,
+    span,
+)
 from siddhi_tpu.query_api import AttrType
 from siddhi_tpu.query_api.annotation import find_annotation
 from siddhi_tpu.table.table import TBL, _scalar
@@ -70,21 +76,23 @@ def _scatter_body(cols, valid, vals, write_slots, kill_slots):
     ``kill_slots`` clear validity and win over same-step writes (a
     displaced row is dead even if the step also wrote it, matching the
     host table's sequential delete-then-update bookkeeping)."""
+    import jax
     import jax.numpy as jnp
 
-    cap = valid.shape[0]
-    n = write_slots.shape[0]
-    lane = jnp.arange(cap, dtype=jnp.int32)[None, :]
-    w1h = write_slots[:, None] == lane  # [N, C]; -1 rows touch nothing
-    touched = w1h.any(axis=0)
-    order = jnp.arange(1, n + 1, dtype=jnp.int32)[:, None]
-    winner = jnp.argmax(jnp.where(w1h, order, 0), axis=0)  # last writer
-    out = {}
-    for nm, col in cols.items():
-        v = vals.get(nm)
-        out[nm] = col if v is None else jnp.where(touched, v[winner], col)
-    killed = (kill_slots[:, None] == lane).any(axis=0)
-    return out, (valid | touched) & ~killed
+    with jax.named_scope(SCOPE_DEVTABLE_SCATTER):
+        cap = valid.shape[0]
+        n = write_slots.shape[0]
+        lane = jnp.arange(cap, dtype=jnp.int32)[None, :]
+        w1h = write_slots[:, None] == lane  # [N, C]; -1 rows touch nothing
+        touched = w1h.any(axis=0)
+        order = jnp.arange(1, n + 1, dtype=jnp.int32)[:, None]
+        winner = jnp.argmax(jnp.where(w1h, order, 0), axis=0)  # last writer
+        out = {}
+        for nm, col in cols.items():
+            v = vals.get(nm)
+            out[nm] = col if v is None else jnp.where(touched, v[winner], col)
+        killed = (kill_slots[:, None] == lane).any(axis=0)
+        return out, (valid | touched) & ~killed
 
 
 class _NotDeviceable(Exception):
@@ -281,8 +289,14 @@ class DeviceTable:
             col = np.zeros(npad, dtype=self._dtypes[nm])
             col[:n] = v
             pv[nm] = col
-        w_d, k_d, v_d = staged_put(
-            (w, k, pv), faults=self._faults, stats=self.ingest_stats)
+        # the mutation is ONE span, ``mutate``: its put is on the way
+        # back, and no ``put`` of the cycle's way in
+        cycle = reopen(None)
+        try:
+            w_d, k_d, v_d = staged_put(
+                (w, k, pv), faults=self._faults, stats=self.ingest_stats)
+        finally:
+            reopen(cycle)
         self._dcols, self._dvalid = self._scatter(
             self._dcols, self._dvalid, v_d, w_d, k_d)
         self.scatter_steps += 1
@@ -298,8 +312,12 @@ class DeviceTable:
 
     def insert(self, batch: EventBatch):
         """Add rows; duplicate keys replace (LWW) — within the batch the
-        duplicates share one slot and the kernel argmax picks the last."""
-        with self._lock:
+        duplicates share one slot and the kernel argmax picks the last.
+
+        Like every batched mutation below, one ``mutate`` span of the
+        calling thread's open cycle (the ``deliver`` of the query that
+        writes), lock wait included; its count is the keys handed in."""
+        with span(STAGE_MUTATE, len(batch)), self._lock:
             if self._host is not None:
                 self._host.insert(batch)
                 return
@@ -372,7 +390,7 @@ class DeviceTable:
 
     def delete_keys(self, keys: np.ndarray):
         """Lowered delete: unmap + tombstone, one kill scatter."""
-        with self._lock:
+        with span(STAGE_MUTATE, len(keys)), self._lock:
             if self._host is not None:
                 slots = [self._pk_map[int(kk)] for kk in keys.tolist()
                          if int(kk) in self._pk_map]
@@ -414,7 +432,7 @@ class DeviceTable:
     def update_keys(self, keys: np.ndarray, values: Dict[str, np.ndarray]):
         """Lowered update (no primary-key rewrite — gated at plan time):
         rows whose key misses are dropped, matching the host probe."""
-        with self._lock:
+        with span(STAGE_MUTATE, len(keys)), self._lock:
             if self._host is not None:
                 slots, idx = self._key_slots(keys)
                 if slots:
@@ -499,7 +517,7 @@ class DeviceTable:
         insert of a slot AFTER an update of the same slot (the two-phase
         scatter order would invert host sequential semantics); the
         caller delegates that batch to the generic host-path callback."""
-        with self._lock:
+        with span(STAGE_MUTATE, len(keys)), self._lock:
             if self._host is not None:
                 self._host_upsert(keys, insert_cols, set_cols, ts)
                 return True

@@ -24,7 +24,12 @@ it waits for the step just dispatched).  The way back, three siblings
 that tile ``emit`` in this order: the coalesced ``fetch``, the
 ``build`` of the ``EventBatch`` from the fetched host arrays
 (``materialize``, the column casts, the key side channels) and the
-``deliver`` of it to the output chain and the user's callback.  A
+``deliver`` of it to the output chain and the user's callback.  Where
+the output chain ends in a device table, the batch the table's callback
+hands to it is one ``mutate`` inside that ``deliver``: the host's passes
+over the key map, the slot allocation, the write lanes, their put and
+the call of the scatter, whole and with no child (``convert``, ``put``
+and ``dispatch`` stay the names of the way in).  A
 span's parent is the span of the same cycle whose interval contains
 it; siblings never overlap, so a stage's time is the plain sum of its
 spans.
@@ -100,10 +105,11 @@ STAGE_EMIT = "emit"          # rows
 STAGE_FETCH = "fetch"        # bytes fetched
 STAGE_BUILD = "build"        # rows built into the EventBatch
 STAGE_DELIVER = "deliver"    # rows delivered
+STAGE_MUTATE = "mutate"      # keys written to a device table (in deliver)
 CYCLE_STAGES = (STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT, STAGE_PLAN,
                 STAGE_PANE, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH,
                 STAGE_POLL, STAGE_STEP, STAGE_EMIT, STAGE_FETCH,
-                STAGE_BUILD, STAGE_DELIVER)
+                STAGE_BUILD, STAGE_DELIVER, STAGE_MUTATE)
 #: intervals of a cycle kept as histograms alone (module docstring)
 STAGE_STAGED = "staged"      # dispatch to the start of a deferred gate's fetch
 STAGE_CYCLE = "cycle"        # begin_cycle to the end of emit
@@ -172,6 +178,14 @@ SCOPE_FUSED_HEAD = "siddhi.fused.head"          # stage 0
 SCOPE_FUSED_INTERIOR = "siddhi.fused.interior"  # every stage between
 SCOPE_FUSED_TAIL = "siddhi.fused.tail"          # the last stage
 SCOPE_FUSED_COUNT = "siddhi.fused.count"        # the emit count
+# devtable/join.py, the probe of a stream-table join: the [B, C] key
+# plane with its ``any`` and ``argmax``, the row gathers by the slot
+# found, the full join condition on the gathered lanes and the count
+SCOPE_DEVTABLE_PROBE = "siddhi.devtable.probe"
+SCOPE_DEVTABLE_GATHER = "siddhi.devtable.gather"
+SCOPE_DEVTABLE_CONDITION = "siddhi.devtable.condition"
+# devtable/storage.py ``_scatter_body``: a mutation batch's [N, C] plane
+SCOPE_DEVTABLE_SCATTER = "siddhi.devtable.scatter"
 DEVICE_SCOPES = (
     SCOPE_DENSE_GATHER, SCOPE_DENSE_ADVANCE, SCOPE_DENSE_KLEENE,
     SCOPE_DENSE_SCATTER, SCOPE_DENSE_COUNT, SCOPE_DENSE_ROUNDS,
@@ -179,7 +193,9 @@ DEVICE_SCOPES = (
     SCOPE_WINDOW_SLOT, SCOPE_WINDOW_AGGREGATE, SCOPE_WINDOW_EMIT,
     SCOPE_WINDOW_UPDATE, SCOPE_WINDOW_COUNT, SCOPE_PANE_ASSIGN,
     SCOPE_PANE_REDUCE, SCOPE_PANE_EMIT, SCOPE_PANE_COUNT, SCOPE_FUSED_HEAD,
-    SCOPE_FUSED_INTERIOR, SCOPE_FUSED_TAIL, SCOPE_FUSED_COUNT)
+    SCOPE_FUSED_INTERIOR, SCOPE_FUSED_TAIL, SCOPE_FUSED_COUNT,
+    SCOPE_DEVTABLE_PROBE, SCOPE_DEVTABLE_GATHER, SCOPE_DEVTABLE_CONDITION,
+    SCOPE_DEVTABLE_SCATTER)
 
 # the calling thread's open cycle: set by begin_cycle (None for an
 # unsampled cycle), cleared when the cycle's ingest span ends; set

@@ -74,3 +74,65 @@ def test_the_run_kernel_goes_through_mosaic(one_chip, monkeypatch):
         fields, {"v": tiles(np.float32)}, tiles(np.int32), links, links,
         shape((), np.int32)).lower(lowering_platforms=("tpu",)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_device_tables_planes_fit_a_v5e_at_a_million_slots(one_chip):
+    """``profile_join_1m``'s two programs at the cell's size, through the
+    chip's own compiler (kept in this file: one worker holds libtpu):
+    the ``[4096, C]`` probe and the ``[8192, C]`` scatter at C =
+    1,048,576 fuse their planes (temporaries 0 bytes), so the deployment
+    fits whatever the planes cost in time; the scopes the benchmark reads
+    are on the compiled programs."""
+    import json
+    import os
+
+    import jax
+    import numpy as np
+
+    from siddhi_tpu import SiddhiManager
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "profile_join_1m.json")) as f:
+        config = json.load(f)
+    C, B, N = config["full"]["capacity"], 4096, 8192
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(
+            config["header"].format(capacity=64) + " " + config["app"])
+        join = rt.query_runtimes["probe"].device_runtime
+        table = join.table
+        assert B == join.MAX_CHUNK
+        tcols = {nm: shape((C,), dt) for nm, dt in table._dtypes.items()}
+        valid = shape((C,), np.bool_)
+        lanes = {ek: shape((B,), dt)
+                 for ek, (_attr, dt) in join._cond_lanes.items()}
+        probe = join._probe.trace(
+            shape((B,), np.int32), shape((B,), np.bool_), lanes,
+            tcols[table.pk], tcols, valid).lower(
+                lowering_platforms=("tpu",)).compile()
+        vals = {nm: shape((N,), dt) for nm, dt in table._dtypes.items()
+                if nm != table.pk}
+        scatter = table._scatter.trace(
+            tcols, valid, vals, shape((N,), np.int32),
+            shape((8,), np.int32)).lower(
+                lowering_platforms=("tpu",)).compile()
+    finally:
+        m.shutdown()
+    row = sum(dt.itemsize for dt in table._dtypes.values()) + 1
+    assert row == 42 and C * row == 44_040_192    # a key, ten fields, validity
+    for compiled, scopes in ((probe, ("probe", "gather", "condition")),
+                             (scatter, ("scatter",))):
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes == 0
+        assert mem.argument_size_in_bytes < 2 * C * row
+        text = compiled.as_text()
+        for sc in scopes:
+            assert f"siddhi.devtable.{sc}" in text
+    # a whole new table an upsert batch (nothing is donated), and no more
+    out = scatter.memory_analysis().output_size_in_bytes
+    assert C * row <= out < C * row + 4096
