@@ -3,19 +3,26 @@
 ``DevTableJoinRuntime`` replaces the host ``JoinRuntime`` +
 ``JoinStreamReceiver`` pair for eligible queries (inner join, one
 ``DeviceTable`` side, windowless/filterless stream side, a primary-key
-equality conjunct): the arriving micro-batch ships its key lane and the
-condition-referenced attribute lanes to the device once, a jitted
-``[B, C]`` masked probe gathers the matched table row per event and
-evaluates the FULL join condition on device lanes, and matched pairs
-ride the existing async emit pipeline — zero host materialization
-between ingest and emit.
+equality conjunct): the host resolves each event's key to its table
+slot (``DeviceTable.probe_view``: one vectorised lookup in the table's
+array-form index), the arriving micro-batch ships its key lane, that
+slot lane and the condition-referenced attribute lanes to the device
+once, a jitted probe gathers the table row at each slot — no plane over
+the capacity: the work is the batch's — checks on the device that the
+slot is live and still holds the event's key (the guard: a wrong slot
+can only ever read as a miss, never as another key's row), evaluates
+the FULL join condition on device lanes, and matched pairs ride the
+existing async emit pipeline — zero host materialization between ingest
+and emit.
 
 Snapshot consistency: the probe closes over the table's CURRENT column
-references at dispatch (``DeviceTable.device_state`` under the table
-lock).  JAX arrays are immutable, so scatter mutations landing while
-the probe is in flight produce NEW arrays and never tear the probed
-view — the probe reads exactly the revision-in-progress it dispatched
-against, the device analog of the host path's lock-ordered probe.
+references at dispatch, taken with the slot lane in ONE hold of the
+table lock (``probe_view``), so lane and arrays are of one revision.
+JAX arrays are immutable and no scatter donates its inputs, so
+mutations landing while the probe is in flight produce NEW arrays and
+never tear the probed view — the probe reads exactly the
+revision-in-progress it dispatched against, the device analog of the
+host path's lock-ordered probe.
 
 Because the eligibility gate requires a primary-key equality conjunct,
 at most ONE table row matches each event, so output shapes are fixed
@@ -26,13 +33,15 @@ The runtime mirrors ``DeviceQueryRuntime``'s pipeline discipline:
 ``IngestStage`` staging for the count gate, ``EmitQueue`` for deferred
 materialization, per-batch fault isolation through ``on_fault``, cycle
 tokens for observability: a chunk's way in is ``convert`` (the key
-expression, the lane padding), ``put`` and ``dispatch`` inside
-``ingest``; a batch of more than ``MAX_CHUNK`` events is as many
-chunks, each finished (gate, fetch, rows) before the next is put, and
-every chunk's spans are its batch's cycle's.  The probe's phases on the
-device are the ``siddhi.devtable.*`` scopes.  A demoted table
-(or a null-carrying batch) falls back per batch to the exact host
-cross-product semantics — after a pipeline drain, so emit order holds.
+expression; then the slot lookup and the lane padding), ``put`` and
+``dispatch`` inside ``ingest``.  A batch is ONE chunk — one put, one
+call, one gate, one fetch — unless it holds more than ``MAX_CHUNK``
+events: then it is as many chunks, every one's spans its batch's
+cycle's, and a chunk the gate finishes inline is finished (gate, fetch,
+rows) before the next is put.  The probe's phases on the device are the
+``siddhi.devtable.*`` scopes.  A demoted table (or a null-carrying
+batch) falls back per batch to the exact host cross-product semantics —
+after a pipeline drain, so emit order holds.
 """
 
 from __future__ import annotations
@@ -53,6 +62,7 @@ from siddhi_tpu.observability.trace import (
     SCOPE_DEVTABLE_PROBE,
     STAGE_CONVERT,
     STAGE_DISPATCH,
+    STAGE_INGEST,
     reopen,
     span,
 )
@@ -68,7 +78,9 @@ def _pow2(n: int, floor: int = 16) -> int:
 class DevTableJoinRuntime:
     """One stream-table join lowered onto the device batch cycle."""
 
-    MAX_CHUNK = 4096  # [B, C] probe work bound; larger batches chunk
+    # a chunk's lanes and gathered columns, not the table, bound it:
+    # 65,536 events of thirteen 4-byte lanes are 3 MB on the device
+    MAX_CHUNK = 65536
 
     def __init__(self, name: str, stream_side, table_side, stream_is_left: bool,
                  condition, key_expr, cond_stream_lanes: Dict[str, Tuple[str, np.dtype]],
@@ -90,6 +102,9 @@ class DevTableJoinRuntime:
         self.step_invocations = 0
         self.probe_invocations = 0
         self.host_fallback_batches = 0
+        # events whose key the host resolved to a slot, or did not
+        self.slot_hits = 0
+        self.slot_misses = 0
         # count gate, emit queue, drain(), fault isolation
         # (core/device_pipeline.py); the table owns its own puts
         self.pipeline = DevicePipeline(app_context, "devtable_join")
@@ -104,15 +119,18 @@ class DevTableJoinRuntime:
                    for a in self.table.definition.attributes}
         cond_fn = condition.fn
 
-        def probe(keys, ev_mask, ev_lanes, pk_col, tcols, valid):
+        def probe(keys, slots, ev_mask, ev_lanes, pk_col, tcols, valid):
             import jax.numpy as jnp
 
+            def at(lane):  # ``s`` is clipped: no bounds handling needed
+                return lane.at[s].get(mode="promise_in_bounds")
+
             with jax.named_scope(SCOPE_DEVTABLE_PROBE):
-                oneh = (keys[:, None] == pk_col[None, :]) & valid[None, :]
-                matched = oneh.any(axis=1) & ev_mask
-                slot = jnp.argmax(oneh, axis=1)
+                s = jnp.clip(slots, 0, valid.shape[0] - 1)
+                matched = ((slots >= 0) & at(valid) & (at(pk_col) == keys)
+                           & ev_mask)
             with jax.named_scope(SCOPE_DEVTABLE_GATHER):
-                gathered = {nm: c[slot] for nm, c in tcols.items()}
+                gathered = {nm: at(c) for nm, c in tcols.items()}
             with jax.named_scope(SCOPE_DEVTABLE_CONDITION):
                 env = dict(ev_lanes)
                 for qk, nm in tbl_env.items():
@@ -155,7 +173,16 @@ class DevTableJoinRuntime:
             self.ingest_stats.device_chunks += -(-n // self.MAX_CHUNK)
             for lo in range(0, n, self.MAX_CHUNK):
                 hi = min(n, lo + self.MAX_CHUNK)
-                self._dispatch_chunk(cur, keys, lo, hi, now, tok)
+                if not self._dispatch_chunk(cur, keys, lo, hi, now, tok):
+                    # another thread's mutation demoted the table since
+                    # the check above: the rest of the batch joins on
+                    # the host, behind what is queued
+                    if tok is not None:
+                        tok.aborted(STAGE_INGEST)
+                    self.drain()
+                    self.host_fallback_batches += 1
+                    self._host_join(cur.take(np.arange(lo, n)), now)
+                    return
 
     def _host_only_reason(self, cur: EventBatch) -> Optional[str]:
         if self.table.demoted:
@@ -172,7 +199,9 @@ class DevTableJoinRuntime:
         env[N_KEY] = len(cur)
         return np.broadcast_to(self.key_expr.fn(env), (len(cur),))
 
-    def _dispatch_chunk(self, cur, keys, lo, hi, now, tok):
+    def _dispatch_chunk(self, cur, keys, lo, hi, now, tok) -> bool:
+        """Put and dispatch events ``lo:hi`` and stage their gate; False,
+        with nothing sent, if the table has demoted to the host."""
         cn = hi - lo
         if lo and tok is not None:
             # the chunk before was finished inside its submit, which
@@ -183,6 +212,17 @@ class DevTableJoinRuntime:
             B = _pow2(cn)
             klane = np.zeros(B, dtype=np.int32)
             klane[:cn] = keys[lo:hi].astype(np.int32, copy=False)
+            # snapshot-consistent: the slots and the CURRENT immutable
+            # refs from one hold of the table lock
+            view = self.table.probe_view(klane[:cn])
+            if view is None:
+                return False
+            slots, tcols, tvalid = view
+            slane = np.full(B, -1, dtype=np.int32)
+            slane[:cn] = slots
+            hits = int(np.count_nonzero(slots >= 0))
+            self.slot_hits += hits
+            self.slot_misses += cn - hits
             mlane = np.zeros(B, dtype=bool)
             mlane[:cn] = True
             lanes = {}
@@ -190,14 +230,12 @@ class DevTableJoinRuntime:
                 col = np.zeros(B, dtype=dt)
                 col[:cn] = cur.columns[attr][lo:hi].astype(dt, copy=False)
                 lanes[ek] = col
-            # snapshot-consistent: CURRENT immutable refs, under the
-            # table lock
-            tcols, tvalid = self.table.device_state()
-        k_d, m_d, l_d = staged_put((klane, mlane, lanes),
-                                   faults=self.faults, stats=self.ingest_stats)
+        k_d, s_d, m_d, l_d = staged_put(
+            (klane, slane, mlane, lanes),
+            faults=self.faults, stats=self.ingest_stats)
         with span(STAGE_DISPATCH, 1):
             mask_d, gathered_d, count_d = self._probe(
-                k_d, m_d, l_d, tcols[self.table.pk], tcols, tvalid)
+                k_d, s_d, m_d, l_d, tcols[self.table.pk], tcols, tvalid)
         self.step_invocations += 1
         self.probe_invocations += 1
         self.pipeline.submit(
@@ -206,6 +244,13 @@ class DevTableJoinRuntime:
                                            for nm in self._tbl_names]),
             lambda host: self._materialize(host, cur, lo, now),
             self.emit)
+        return True
+
+    def slot_metrics(self) -> Dict[str, int]:
+        """``Queries.<q>.slotHits`` / ``slotMisses`` of ``statistics()``:
+        probed events whose key the host's index resolved to a slot, or
+        did not (those read as misses without a look at the table)."""
+        return {"slotHits": self.slot_hits, "slotMisses": self.slot_misses}
 
     # -- deferred materialization (runs on fetched HOST arrays) -----------
 

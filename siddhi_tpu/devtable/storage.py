@@ -4,12 +4,22 @@ Re-design of ``table/table.py``'s ``InMemoryTable`` with the row storage
 moved onto the accelerator: one ``[C]``-capacity device column per
 attribute plus a validity lane, while the slot-index map (primary key ->
 slot), timestamps and a liveness mirror stay host-side so probes and
-eligibility decisions never synchronize.  Mutations lower to ONE jitted
-in-place scatter step per callback batch, reusing the collision-free
-one-hot discipline of ``kernels/bank_scatter.py``: every write row
-scatters through a ``[N, C]`` one-hot plane and an argmax over the row
-order resolves duplicate keys last-writer-wins *inside* the kernel, so
-duplicate keys within a batch never race.
+eligibility decisions never synchronize.  **A row is addressed by its
+slot**: the host resolves key -> slot and the device reads and writes
+by index, so no program's work grows with the capacity.  Mutations
+lower to ONE jitted scatter step per callback batch: the host picks
+each slot's last writer (``np.unique`` over the reversed slot lane), so
+duplicate keys within a batch never race, ships only the winners, and
+the device does one indexed write a column (``col.at[w].set``) — the
+pad lanes carry distinct indices past the capacity and are dropped.
+Nothing is donated: every scatter returns NEW arrays.
+
+``_pk_map`` (a dict) is the truth of key -> slot.  Beside it the table
+holds an array-form index (``core/key_index.py`` ``HashKeyIndex``) as a
+rebuildable cache, for the join's batched lookup (``probe_view``): new
+keys go in where their slots are allocated; the index never deletes, so
+every path that unmaps a key marks it stale, and the next lookup
+rebuilds it from the dict first (counted: ``devtableIndexRebuilds``).
 
 Consistency is MVCC-ish revision pinning: JAX arrays are immutable, so
 each scatter produces NEW column arrays; ``drain()`` — called at the
@@ -42,6 +52,7 @@ from siddhi_tpu.core.emit_queue import fetch_coalesced
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.exceptions import SiddhiAppCreationError
 from siddhi_tpu.core.ingest_stage import IngestStats, staged_put
+from siddhi_tpu.core.key_index import HashKeyIndex, index_for
 from siddhi_tpu.observability.trace import (
     SCOPE_DEVTABLE_SCATTER,
     STAGE_MUTATE,
@@ -70,29 +81,24 @@ def _pow2(n: int, floor: int = 8) -> int:
 
 
 def _scatter_body(cols, valid, vals, write_slots, kill_slots):
-    """One-hot LWW scatter (the bank_scatter discipline): write row j
-    lands at ``write_slots[j]`` (-1 inert); duplicate slots within the
-    batch resolve to the LAST row via argmax over the row order;
+    """Indexed scatter: value row j lands at ``write_slots[j]``.  The
+    host has already picked each slot's last writer, so the slots are
+    unique and ascending; a pad lane holds an index at or past the
+    capacity, each its own, and is dropped (never -1: a negative index
+    wraps to the last slot before the bounds are checked).
     ``kill_slots`` clear validity and win over same-step writes (a
     displaced row is dead even if the step also wrote it, matching the
     host table's sequential delete-then-update bookkeeping)."""
     import jax
-    import jax.numpy as jnp
 
     with jax.named_scope(SCOPE_DEVTABLE_SCATTER):
-        cap = valid.shape[0]
-        n = write_slots.shape[0]
-        lane = jnp.arange(cap, dtype=jnp.int32)[None, :]
-        w1h = write_slots[:, None] == lane  # [N, C]; -1 rows touch nothing
-        touched = w1h.any(axis=0)
-        order = jnp.arange(1, n + 1, dtype=jnp.int32)[:, None]
-        winner = jnp.argmax(jnp.where(w1h, order, 0), axis=0)  # last writer
-        out = {}
-        for nm, col in cols.items():
-            v = vals.get(nm)
-            out[nm] = col if v is None else jnp.where(touched, v[winner], col)
-        killed = (kill_slots[:, None] == lane).any(axis=0)
-        return out, (valid | touched) & ~killed
+        def put(lane, v):
+            return lane.at[write_slots].set(
+                v, mode="drop", unique_indices=True, indices_are_sorted=True)
+
+        out = {nm: put(col, vals[nm]) if nm in vals else col
+               for nm, col in cols.items()}
+        return out, put(valid, True).at[kill_slots].set(False, mode="drop")
 
 
 class _NotDeviceable(Exception):
@@ -158,6 +164,11 @@ class DeviceTable:
         self._hwm = 0
         self._free: List[int] = []
         self._tombstones: List[int] = []
+        # array-form cache of ``_pk_map`` for batched lookups; it never
+        # deletes, so a path that unmaps a key sets the flag instead
+        self._index = HashKeyIndex(self._cap, np.int32)
+        self._index_stale = False
+        self.index_rebuilds = 0
 
         # -- device-resident state ----------------------------------------
         self.ingest_stats = IngestStats()
@@ -223,6 +234,7 @@ class DeviceTable:
         # probes — rebind so in-flight CompiledTableCondition objects
         # follow the demotion without replanning
         self._pk_map = host._pk_map
+        self._index_stale = True
         self.demotions += 1
         if self._sm is not None:
             self._sm.record_devtable_fallback(
@@ -269,26 +281,37 @@ class DeviceTable:
 
     # -- the scatter step -------------------------------------------------
 
+    def _pad_slots(self, slots: np.ndarray, width: int) -> np.ndarray:
+        """``slots`` then pad lanes the scatter drops: each its own
+        index past the capacity, ascending."""
+        lane = np.arange(self._cap, self._cap + width, dtype=np.int32)
+        lane[:len(slots)] = slots
+        return lane
+
     def _apply_scatter(self, write_slots: List[int],
                        vals: Dict[str, np.ndarray],
                        kill_slots: List[int]):
-        """ONE jitted one-hot LWW scatter for this mutation batch; pads
-        to pow-2 row counts so retraces stay bounded."""
+        """ONE jitted indexed scatter for this mutation batch.  The last
+        writer of each slot is picked here (the first occurrence in the
+        reversed lane), so the device is handed unique ascending slots;
+        lanes pad to the pow-2 of the rows handed in (not of the
+        winners: a batch's width then never depends on its duplicates)
+        so retraces stay bounded."""
         n = len(write_slots)
         npad = _pow2(n)
-        w = np.full(npad, -1, dtype=np.int32)
-        if n:
-            w[:n] = np.fromiter(write_slots, dtype=np.int32, count=n)
-        kpad = _pow2(len(kill_slots))
-        k = np.full(kpad, -1, dtype=np.int32)
-        if kill_slots:
-            k[:len(kill_slots)] = np.fromiter(
-                kill_slots, dtype=np.int32, count=len(kill_slots))
+        w, first = np.unique(
+            np.fromiter(write_slots, dtype=np.int32, count=n)[::-1],
+            return_index=True)
+        last = n - 1 - first
         pv = {}
         for nm, v in vals.items():
             col = np.zeros(npad, dtype=self._dtypes[nm])
-            col[:n] = v
+            col[:len(w)] = v[last]
             pv[nm] = col
+        w = self._pad_slots(w, npad)
+        k = self._pad_slots(
+            np.fromiter(kill_slots, dtype=np.int32, count=len(kill_slots)),
+            _pow2(len(kill_slots)))
         # the mutation is ONE span, ``mutate``: its put is on the way
         # back, and no ``put`` of the cycle's way in
         cycle = reopen(None)
@@ -308,11 +331,47 @@ class DeviceTable:
         with self._lock:
             return self._dcols, self._dvalid
 
+    # -- the array-form index ----------------------------------------------
+
+    def _index_add(self, keys: List[int], slots: List[int]):
+        """Keys that were just given their slots (new to ``_pk_map``, so
+        new to a fresh index; a stale one is rebuilt whole anyway)."""
+        if keys and not self._index_stale:
+            n = len(keys)
+            self._index.insert(np.fromiter(keys, dtype=np.int32, count=n),
+                               np.fromiter(slots, dtype=np.int32, count=n))
+
+    def _rebuild_index(self):
+        n = len(self._pk_map)
+        self._index = index_for(
+            np.fromiter(self._pk_map.keys(), dtype=np.int32, count=n),
+            np.fromiter(self._pk_map.values(), dtype=np.int32, count=n),
+            self._cap)
+        self._index_stale = False
+        self.index_rebuilds += 1
+
+    def probe_view(self, keys: np.ndarray):
+        """What a probe of ``keys`` (an int32 lane) reads, in one hold of
+        the lock so that it is of one revision: the slot of each key
+        (int32, -1 for a key the table does not hold) and the CURRENT
+        ``(cols, valid)`` references.  None once the table has demoted.
+        A slot the index gets wrong can only read as a miss on the
+        device (the probe compares the key at the slot again); the
+        index is never trusted while stale."""
+        with self._lock:
+            if self._host is not None:
+                return None
+            if self._index_stale:
+                self._rebuild_index()
+            slots = self._index.lookup(keys)[0]
+            np.maximum(slots, -1, out=slots)  # a never-seen key is -1 - j
+            return slots, self._dcols, self._dvalid
+
     # -- batched lowered mutations ----------------------------------------
 
     def insert(self, batch: EventBatch):
         """Add rows; duplicate keys replace (LWW) — within the batch the
-        duplicates share one slot and the kernel argmax picks the last.
+        duplicates share one slot and ``_apply_scatter`` ships the last.
 
         Like every batched mutation below, one ``mutate`` span of the
         calling thread's open cycle (the ``deliver`` of the query that
@@ -340,6 +399,8 @@ class DeviceTable:
                 self._host.insert(batch)
                 return
             write_slots: List[int] = []
+            new_keys: List[int] = []
+            new_slots: List[int] = []
             for j in range(n):
                 kk = int(keys[j])
                 s = self._pk_map.get(kk)
@@ -347,9 +408,12 @@ class DeviceTable:
                     s = self._alloc()
                     self._pk_map[kk] = s
                     self._slot_key[s] = kk
+                    new_keys.append(kk)
+                    new_slots.append(s)
                 self._hlive[s] = True
                 self._ts[s] = int(batch.timestamps[j])
                 write_slots.append(s)
+            self._index_add(new_keys, new_slots)
             self._apply_scatter(write_slots, cols, [])
 
     def _insert_row(self, row: Dict, ts: int) -> int:
@@ -383,6 +447,7 @@ class DeviceTable:
                 s = self._alloc()
                 self._pk_map[kk] = s
                 self._slot_key[s] = kk
+                self._index_add([kk], [s])
             self._hlive[s] = True
             self._ts[s] = int(ts)
             self._apply_scatter([s], cols, [])
@@ -399,7 +464,10 @@ class DeviceTable:
             kills: List[int] = []
             for kk in keys.tolist():
                 s = self._pk_map.pop(int(kk), None)
-                if s is None or not self._hlive[s]:
+                if s is None:
+                    continue
+                self._index_stale = True
+                if not self._hlive[s]:
                     continue
                 self._slot_key.pop(s, None)
                 self._hlive[s] = False
@@ -423,6 +491,7 @@ class DeviceTable:
                 kk = self._slot_key.pop(s, None)
                 if kk is not None and self._pk_map.get(kk) == s:
                     del self._pk_map[kk]
+                    self._index_stale = True
                 self._hlive[s] = False
                 self._tombstones.append(s)
                 kills.append(s)
@@ -490,6 +559,8 @@ class DeviceTable:
                     nk = int(new_keys[r])
                     if old == nk:
                         continue
+                    # a key leaves its slot, another may take it over
+                    self._index_stale = True
                     if old is not None and self._pk_map.get(old) == s:
                         del self._pk_map[old]
                     other = self._pk_map.get(nk)
@@ -569,6 +640,8 @@ class DeviceTable:
             ins_idx: List[int] = []
             upd_slots: List[int] = []
             upd_idx: List[int] = []
+            new_keys: List[int] = []
+            new_slots: List[int] = []
             for j, kk in enumerate(keys.tolist()):
                 s = self._pk_map.get(int(kk))
                 if s is not None:
@@ -579,12 +652,15 @@ class DeviceTable:
                 s = self._pk_map.get(ik)  # in-place replace on collision
                 if s is None:
                     s = self._alloc()
+                    new_keys.append(ik)
+                    new_slots.append(s)
                 self._pk_map[ik] = s
                 self._slot_key[s] = ik
                 self._hlive[s] = True
                 self._ts[s] = int(ts[j])
                 ins_slots.append(s)
                 ins_idx.append(j)
+            self._index_add(new_keys, new_slots)
             if ins_slots:
                 ii = np.fromiter(ins_idx, dtype=np.int64, count=len(ins_idx))
                 self._apply_scatter(
@@ -677,6 +753,7 @@ class DeviceTable:
             "devtableRevision": self.revision,
             "devtableScatterSteps": self.scatter_steps,
             "devtableCompactions": self.compactions,
+            "devtableIndexRebuilds": self.index_rebuilds,
             "devtableDemotions": self.demotions,
             "devtableDemoted": self._host is not None,
         }
@@ -715,6 +792,7 @@ class DeviceTable:
                 return
             cols = fetch_coalesced([state["cols"][nm] for nm in names])
             self._pk_map = {}
+            self._index_stale = True
             self._slot_key = {}
             self._free = []
             self._tombstones = []

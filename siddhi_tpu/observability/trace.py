@@ -178,13 +178,15 @@ SCOPE_FUSED_HEAD = "siddhi.fused.head"          # stage 0
 SCOPE_FUSED_INTERIOR = "siddhi.fused.interior"  # every stage between
 SCOPE_FUSED_TAIL = "siddhi.fused.tail"          # the last stage
 SCOPE_FUSED_COUNT = "siddhi.fused.count"        # the emit count
-# devtable/join.py, the probe of a stream-table join: the [B, C] key
-# plane with its ``any`` and ``argmax``, the row gathers by the slot
-# found, the full join condition on the gathered lanes and the count
+# devtable/join.py, the probe of a stream-table join: the guard on the
+# host's slot lane (the slot live and still holding the event's key: two
+# gathers and a compare), the row gathers by that slot, the full join
+# condition on the gathered lanes and the count
 SCOPE_DEVTABLE_PROBE = "siddhi.devtable.probe"
 SCOPE_DEVTABLE_GATHER = "siddhi.devtable.gather"
 SCOPE_DEVTABLE_CONDITION = "siddhi.devtable.condition"
-# devtable/storage.py ``_scatter_body``: a mutation batch's [N, C] plane
+# devtable/storage.py ``_scatter_body``: a mutation batch's indexed
+# writes, one a column and the validity lane, and its kills
 SCOPE_DEVTABLE_SCATTER = "siddhi.devtable.scatter"
 DEVICE_SCOPES = (
     SCOPE_DENSE_GATHER, SCOPE_DENSE_ADVANCE, SCOPE_DENSE_KLEENE,

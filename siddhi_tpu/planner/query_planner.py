@@ -435,7 +435,7 @@ class QueryPlanner:
         )
         qr.join_runtime = jr
         # @app:devtables: an inner join against a DeviceTable side lowers
-        # to the [B,C] masked device probe (devtable/join.py) — the
+        # to the slot-addressed device probe (devtable/join.py) — the
         # stream side subscribes the devtable receiver INSTEAD of the
         # host JoinStreamReceiver, so matched pairs never materialize on
         # the host between ingest and emit
@@ -459,6 +459,9 @@ class QueryPlanner:
                         app_context=self.app.app_context)
                     qr.device_runtime = devtable_runtime
                     qr.lowered_to = "devtable"
+                    sm = self.app.app_context.statistics_manager
+                    if sm is not None:
+                        sm.register_devtable_join(name, devtable_runtime)
                     logging.getLogger("siddhi_tpu").info(
                         "query '%s': stream-table join lowered to the "
                         "device-resident table probe", name)

@@ -321,10 +321,12 @@ class StatisticsManager:
         # join/mutation gates, mid-run demotions and per-batch generic
         # delegations: count + last reason, keyed '<query>' or
         # 'table:<id>'; and the live DeviceTable instances, read each
-        # report for their rows/capacity/revision/demotion gauges
+        # report for their rows/capacity/revision/demotion gauges, and
+        # the live devtable joins for their slot-lookup counters
         self.devtable_fallbacks: Dict[str, int] = {}
         self.devtable_fallback_reasons: Dict[str, str] = {}
         self.devtables: Dict[str, object] = {}
+        self.devtable_joins: Dict[str, object] = {}
         # cost-based planner feed (planner/costmodel.py): candidates the
         # cost gates rejected (count + last reason — same discipline as
         # every other fallback family), pins that LOST to a
@@ -479,6 +481,12 @@ class StatisticsManager:
         demotions) join the feed under ``Tables.<name>.*``."""
         self.devtables[tname] = table
 
+    def register_devtable_join(self, qname: str, join):
+        """A live DevTableJoinRuntime; its ``slot_metrics()`` counters
+        (``slotHits`` / ``slotMisses``) join the feed under
+        ``Queries.<name>.*``, beside its ingest counters."""
+        self.devtable_joins[qname] = join
+
     def register_hotkey_router(self, qname: str, router):
         """A live HotKeyRouterRuntime; its ``hot_metrics()`` gauges
         (promotions/demotions/routed events/active keys) join the
@@ -589,6 +597,9 @@ class StatisticsManager:
                 rec.predicted_cost)
             out[self._metric("Queries", qname, "plannerReplans")] = (
                 len(rec.replans))
+        for qname, join in list(self.devtable_joins.items()):
+            for metric, v in join.slot_metrics().items():
+                out[self._metric("Queries", qname, metric)] = v
         for tname, table in list(self.devtables.items()):
             for metric, v in table.devtable_metrics().items():
                 out[self._metric("Tables", tname, metric)] = v

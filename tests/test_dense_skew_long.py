@@ -76,13 +76,13 @@ def test_the_run_kernel_goes_through_mosaic(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_the_device_tables_planes_fit_a_v5e_at_a_million_slots(one_chip):
+def test_the_device_tables_programs_fit_a_v5e_at_a_million_slots(one_chip):
     """``profile_join_1m``'s two programs at the cell's size, through the
     chip's own compiler (kept in this file: one worker holds libtpu):
-    the ``[4096, C]`` probe and the ``[8192, C]`` scatter at C =
-    1,048,576 fuse their planes (temporaries 0 bytes), so the deployment
-    fits whatever the planes cost in time; the scopes the benchmark reads
-    are on the compiled programs."""
+    the slot-addressed probe of one 8,192-event batch and the indexed
+    scatter of 8,192 rows at C = 1,048,576 hold no temporaries and no
+    plane (a gather a column, a ``scatter`` a lane written); the scopes
+    the benchmark reads are on the compiled programs."""
     import json
     import os
 
@@ -95,7 +95,7 @@ def test_the_device_tables_planes_fit_a_v5e_at_a_million_slots(one_chip):
     with open(os.path.join(root, "benchmark", "configs",
                            "profile_join_1m.json")) as f:
         config = json.load(f)
-    C, B, N = config["full"]["capacity"], 4096, 8192
+    C, B, N = config["full"]["capacity"], 8192, 8192
 
     def shape(s, dt):
         return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
@@ -106,15 +106,15 @@ def test_the_device_tables_planes_fit_a_v5e_at_a_million_slots(one_chip):
             config["header"].format(capacity=64) + " " + config["app"])
         join = rt.query_runtimes["probe"].device_runtime
         table = join.table
-        assert B == join.MAX_CHUNK
+        assert B <= join.MAX_CHUNK      # the cell's batch is one chunk
         tcols = {nm: shape((C,), dt) for nm, dt in table._dtypes.items()}
         valid = shape((C,), np.bool_)
         lanes = {ek: shape((B,), dt)
                  for ek, (_attr, dt) in join._cond_lanes.items()}
         probe = join._probe.trace(
-            shape((B,), np.int32), shape((B,), np.bool_), lanes,
-            tcols[table.pk], tcols, valid).lower(
-                lowering_platforms=("tpu",)).compile()
+            shape((B,), np.int32), shape((B,), np.int32),
+            shape((B,), np.bool_), lanes, tcols[table.pk], tcols,
+            valid).lower(lowering_platforms=("tpu",)).compile()
         vals = {nm: shape((N,), dt) for nm, dt in table._dtypes.items()
                 if nm != table.pk}
         scatter = table._scatter.trace(
@@ -133,6 +133,10 @@ def test_the_device_tables_planes_fit_a_v5e_at_a_million_slots(one_chip):
         text = compiled.as_text()
         for sc in scopes:
             assert f"siddhi.devtable.{sc}" in text
+        # no operand with a batch and a capacity dimension
+        assert f"{B},{C}" not in text and f"{C},{B}" not in text
+    # the matched lanes alone come back from a probe
+    assert probe.memory_analysis().output_size_in_bytes < 64 * B
     # a whole new table an upsert batch (nothing is donated), and no more
     out = scatter.memory_analysis().output_size_in_bytes
     assert C * row <= out < C * row + 4096

@@ -2,10 +2,10 @@
 
 ``@app:devtables`` stores eligible tables as device-resident columnar
 arrays (``siddhi_tpu/devtable/``): one ``[capacity]`` device column per
-attribute plus a validity lane, mutations lowered to jitted one-hot
-last-writer-wins scatters, and stream-table joins lowered to a ``[B, C]``
-masked probe that keeps matched pairs device-resident from ingest to the
-coalesced emit drain.  The contracts pinned here:
+attribute plus a validity lane, mutations lowered to jitted indexed
+last-writer-wins scatters, and stream-table joins lowered to a
+slot-addressed probe that keeps matched pairs device-resident from ingest
+to the coalesced emit drain.  The contracts pinned here:
 
 * **Differential exactness** — every mutation shape (insert, delete,
   update, update-or-insert, duplicate keys inside one batch, mutations

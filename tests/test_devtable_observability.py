@@ -4,7 +4,8 @@
 follows a mutation; ``lowering()`` says ``host`` once the table has
 demoted itself.  On an injected ``tracer.clock`` that advances by one at
 each reading ("adjacent" is an equality): a probe chunk's ``convert``,
-``put`` and ``dispatch`` tile its ``ingest``, a batch of more than
+``put`` and ``dispatch`` tile its ``ingest``, a batch under ``MAX_CHUNK``
+is one chunk (one ``ingest``, ``step`` and ``emit``), one of more than
 ``MAX_CHUNK`` events is as many such ways in inside one cycle, a
 mutation is one ``mutate`` span inside the writing query's ``deliver``,
 childless, with the keys handed to the table as its count, and ``fetch``, ``build`` and
@@ -163,9 +164,9 @@ def test_convert_put_dispatch_tile_a_probes_ingest():
             # the events are counted once, by the chunk's convert
             assert (keys[5], lanes[5], ingest[5]) == (0, 30, 30)
             assert dispatch[5] == 1
-            # key lane, mask lane and the condition's two lanes (the key
-            # again, and x), padded to 32; m rides none
-            assert put[5] == 32 * (4 + 1 + 4 + 4)
+            # key lane, slot lane, mask lane and the condition's two
+            # lanes (the key again, and x), padded to 32; m rides none
+            assert put[5] == 32 * (4 + 4 + 1 + 4 + 4)
             assert by["step"][0][3] == ingest[4]
             assert {s[2] for spans in by.values() for s in spans} == {
                 "devtable_join"}
@@ -174,6 +175,30 @@ def test_convert_put_dispatch_tile_a_probes_ingest():
         assert (st.device_chunks, st.device_puts) == (3, 3)
         stats = app.tracer.stage_stats()
         assert stats["dispatch"]["spans"] >= 3 and "mutate" in stats
+
+
+def test_a_batch_under_max_chunk_is_one_ordinary_cycle():
+    """8,192 events under the real bound: one chunk, so one ``ingest``
+    (one ``put`` of five leaves, one ``dispatch``), one ``step``, one
+    ``emit``; the host resolved every key to its slot."""
+    assert DevTableJoinRuntime.MAX_CHUNK >= 65536
+    with App(capacity=12000) as app:
+        keys = np.arange(8192, dtype=np.int32)
+        app.upsert(keys, np.zeros(8192, dtype=np.float32))
+        app.probe(keys)
+        (by,) = app.cycles("devtable_join")
+        assert len(by["ingest"]) == len(by["step"]) == len(by["emit"]) == 1
+        (put,), (dispatch,), (fetch,) = by["put"], by["dispatch"], by["fetch"]
+        key_expr, lanes = by["convert"]
+        tiles(by["ingest"][0], [key_expr, lanes, put, dispatch])
+        assert (key_expr[5], lanes[5], dispatch[5]) == (0, 8192, 1)
+        assert put[5] == 8192 * (4 + 4 + 1 + 4 + 4)
+        assert [e.data[2] for e in app.rows] == list(range(8192))
+        st = app.join.ingest_stats
+        assert (st.device_chunks, st.device_puts) == (1, 1)
+        stats = app.rt.statistics()
+        assert stats["io.siddhi.SiddhiApps.dt_obs.Siddhi.Queries.j.slotHits"] == 8192
+        assert stats["io.siddhi.SiddhiApps.dt_obs.Siddhi.Queries.j.slotMisses"] == 0
 
 
 def test_a_batch_past_max_chunk_is_as_many_ways_in_in_one_cycle(monkeypatch):
@@ -271,14 +296,16 @@ def test_the_four_devtable_scopes_are_on_the_lowered_programs():
         lanes = {ek: np.zeros(B, dtype=dt)
                  for ek, (_attr, dt) in app.join._cond_lanes.items()}
         assert set(lanes) == {"S.k", "S.x"}     # m rides no lane
+        # probe: the guard's gathers (validity, the key at the slot) and
+        # its compare; gather: the other columns by the same slot
         probe = app.join._probe.lower(
-            np.zeros(B, np.int32), np.ones(B, bool), lanes, tcols["k"],
-            tcols, valid)
+            np.zeros(B, np.int32), np.zeros(B, np.int32), np.ones(B, bool),
+            lanes, tcols["k"], tcols, valid)
         assert scopes_in(probe) == devtable - {"siddhi.devtable.scatter"}
         vals = {"v": np.zeros(8, np.float32), "f": np.zeros(8, bool)}
         scatter = app.table._scatter.lower(
-            tcols, valid, vals, np.zeros(8, np.int32),
-            np.full(8, -1, np.int32))
+            tcols, valid, vals, app.table._pad_slots(np.arange(3), 8),
+            app.table._pad_slots(np.arange(0), 8))
         assert scopes_in(scatter) == {"siddhi.devtable.scatter"}
 
 
