@@ -437,7 +437,8 @@ class SiddhiAppRuntime:
             sm.lowering.update(self.lowering())
             # async pipeline counters, one gauge pair per device-lowered
             # query: emit side (emitTransfers / deferredBatches /
-            # zeroMatchSkips / maxPendingDepth / autoEffectiveDepth) and
+            # zeroMatchSkips / maxPendingDepth / autoEffectiveDepth /
+            # earlyCopyBatches / earlyCopyHits / earlyCopyWastedBytes) and
             # ingest side (stagedBatches / devicePuts / deviceChunks /
             # fusedHops / ingestStalls / overlappedBatches / flushSyncs /
             # maxStagingDepth)

@@ -14,6 +14,11 @@ routing, chunking, building the output ``EventBatch`` — and holds one
   decides from what it observes (core/ingest_stage.py), then either a
   counted skip or a device-resident ``PendingEmit`` in the bounded emit
   queue (core/emit_queue.py) — :meth:`DevicePipeline.submit`;
+- the early copies: the step's counts, and the emit arrays of every
+  chunk position whose last batch owed rows and has kept foretelling
+  the next, start for the host when the step is dispatched, so the
+  drain's fetch finds them there (:meth:`DevicePipeline._start_copies`,
+  ``_Position``);
 - the flush barrier, ingest stage before emit queue
   (:meth:`DevicePipeline.drain`); once the stage has ever left a
   batch in flight it takes the app's ``process_lock`` itself, so a
@@ -54,7 +59,7 @@ class CountGate:
     scalar and a fixed list of device arrays (the hot-key scan, the
     devtable probe).  The engines' own deferred emits
     (``DeferredDenseEmit``, ``DeferredDeviceEmit``, the fused graph's)
-    carry the same three methods."""
+    carry the same four methods."""
 
     __slots__ = ("count", "arrays")
 
@@ -68,8 +73,51 @@ class CountGate:
     def resolve(self) -> int:
         return int(fetch_coalesced([self.count])[0])
 
+    def gates(self) -> Sequence:
+        return [(self.count, self.arrays)]
+
     def device_arrays(self) -> Sequence:
         return self.arrays
+
+
+class _Position:
+    """What the pipeline has seen of one chunk position of one kind of
+    pending: the evidence ``_start_copies`` goes by.  The forecast is
+    the plainest there is: a position that owed rows on the last batch
+    the pipeline has resolved will owe rows on this one.  It is scored
+    on every batch, acted on or not: ``right`` counts the forecasts in
+    a row that came true, and the arrays are started only once
+    ``right`` has reached ``need``.  A copy that was started and never
+    read (the gate came back 0) doubles ``need``; one that was read
+    takes one off, down to 1.  So a stream that matches batch after
+    batch is started early from its third batch on and stays so; one
+    whose matches come and go in a pattern (a pass of batches of which
+    some owe rows) pays a few wasted copies, each buying twice the
+    patience, and is then left to fetch on demand as it always did;
+    and one that settles down wins its way back.  Nothing here is a
+    size or a time: a wasted copy of 20 MB and one of 70 kB count alike,
+    since what a started copy saves is a round trip whatever it
+    carries."""
+
+    __slots__ = ("owed", "right", "need")
+
+    def __init__(self):
+        self.owed = False
+        self.right = 0
+        self.need = 1
+
+    def start(self) -> bool:
+        return self.owed and self.right >= self.need
+
+    def saw(self, forecast: bool, started: bool, owed: bool) -> None:
+        """The batch is resolved: ``owed`` rows or not, where
+        ``forecast`` was this position's ``owed`` when the batch was
+        dispatched and ``started`` whether its arrays were."""
+        if forecast:
+            self.right = self.right + 1 if owed else 0
+            if started:
+                self.need = max(1, self.need - 1) if owed else self.need * 2
+        self.owed = owed
 
 
 class _Cycle:
@@ -140,6 +188,9 @@ class DevicePipeline:
             on_fault=self.on_fault,
             finisher=getattr(app_context, "idle_finisher", None))
         self.emit_queue.step_in_flight = self.ingest_stage.__len__
+        # kind of pending -> its chunk positions' records (the hot-key
+        # shell hands one pipeline two kinds: cold rows and hot)
+        self._positions: dict = {}
         # last known-poison-free host copy of the quarantined state,
         # kept only while a state.poison fault is watched
         self._last_good = None
@@ -180,8 +231,8 @@ class DevicePipeline:
     def submit(self, tok, pending, build: Optional[Callable],
                emit: Optional[Callable]) -> None:
         """Stage one dispatched step.  ``pending`` has ``probe()``,
-        ``resolve() -> int`` and ``device_arrays()`` (None: the batch
-        made no device work); once its arrays are fetched,
+        ``resolve() -> int``, ``gates()`` and ``device_arrays()`` (None:
+        the batch made no device work); once its arrays are fetched,
         ``build(host_arrays)`` makes the batch's ``EventBatch`` of them
         (None or empty: nothing to hand on) and ``emit(batch)`` hands it
         to the output chain and the user's callback.  The two are the
@@ -194,6 +245,8 @@ class DevicePipeline:
         before batch N's scalar is fetched.  ``finish`` returns how
         long it kept the host: what the stage's rule goes by."""
         queue, stage = self.emit_queue, self.ingest_stage
+        gates, plan, started = ((), (), ()) if pending is None else (
+            self._start_copies(pending))
 
         def deliver(host_arrays):
             with span(STAGE_BUILD) as sp:
@@ -220,16 +273,64 @@ class DevicePipeline:
             if tok is not None:
                 # count gate resolved: the jitted step finished
                 tok.step_done(c)
+            kept = pending.device_arrays() if c else ()
+            early = (frozenset() if pending is None else
+                     self._copies_used(pending, gates, plan, started, kept))
             if c == 0:
                 queue.skip()
             else:
-                queue.push(PendingEmit(pending.device_arrays(), deliver,
-                                       trace=tok))
+                queue.push(PendingEmit(kept, deliver, trace=tok,
+                                       started=early))
             return blocked
 
         stage.submit(
             pending.probe() if pending is not None else None, finish,
             trace=tok, may_defer=self._in_lock())
+
+    def _start_copies(self, pending):
+        """Start, behind the step just dispatched, the copies to the
+        host of what its gate and its drain will ask for: every chunk's
+        count, then the emit arrays of each chunk position whose record
+        says so (``_Position``).  A fetch on demand is a round trip
+        after the step has ended; a copy queued here travels as soon as
+        the step ends.  A position that owed nothing on the last
+        resolved batch starts nothing, so a stream that matches nothing
+        moves no column, and the first batches and those after a zero
+        are fetched on demand as before.  Returns the gates, per gate
+        ``(forecast, started)``, and the arrays started."""
+        gates = pending.gates()
+        seen = self._positions.setdefault(type(pending), [])
+        while len(seen) < len(gates):
+            seen.append(_Position())
+        for count, _arrays in gates:
+            count.copy_to_host_async()
+        plan, started = [], []
+        for pos, (_count, arrays) in zip(seen, gates):
+            plan.append((pos.owed, pos.start()))
+            if plan[-1][1]:
+                for a in arrays:
+                    a.copy_to_host_async()
+                started.extend(arrays)
+        if started:
+            self.emit_stats.early_copy_batches += 1
+        return gates, plan, started
+
+    def _copies_used(self, pending, gates, plan, started, kept) -> frozenset:
+        """The gate is resolved: tell every chunk position whether it
+        owed rows (the counts are on the host), and count what the early
+        copies were good for.  Returns the ids of the arrays in ``kept``
+        (what the drain will fetch) whose copy was started."""
+        for pos, (count, _arrays), (forecast, began) in zip(
+                self._positions[type(pending)], gates, plan):
+            pos.saw(forecast, began, int(count) != 0)
+        if not started:
+            return frozenset()
+        ids = frozenset(map(id, started)) & frozenset(map(id, kept))
+        if ids:
+            self.emit_stats.early_copy_hits += 1
+        self.emit_stats.early_copy_wasted_bytes += sum(
+            a.nbytes for a in started if id(a) not in ids)
+        return ids
 
     def drain(self) -> None:
         """Flush barrier: materialize and emit every queued batch (one

@@ -2,24 +2,30 @@
 
 Every device→host fetch of jit outputs is a synchronous round trip on
 the served path, so emits are count-gated and coalesced (what a fetch
-costs on the chip: not measured).  This module holds the pieces every
-device runtime shares:
+costs on the chip: PERF.md, section 6).  This module holds the pieces
+every device runtime shares:
 
 - ``EmitStats``: per-runtime transfer counters surfaced through
   ``util/statistics.py`` (``emitTransfers`` / ``deferredBatches`` /
-  ``zeroMatchSkips`` / ``maxPendingDepth``, and ``droppedInstances``,
-  the dense pattern runtime's overflow total as of its last poll).
+  ``zeroMatchSkips`` / ``maxPendingDepth``, ``droppedInstances``, the
+  dense pattern runtime's overflow total as of its last poll, and the
+  early copies' ``earlyCopyBatches`` / ``earlyCopyHits`` /
+  ``earlyCopyWastedBytes``).
 - ``EmitQueue``: a bounded pending-emit queue.  Each entry is one
   junction batch whose match outputs are still resident on the device;
   when the queue reaches its configured depth (``emit.depth`` on
   ``@app:execution``), ALL queued outputs are drained with one
   coalesced transfer.  Depth 1 (the default) drains right after each
   batch — emit timing is then identical to the synchronous path while
-  still benefiting from count-gating and the per-batch coalesced fetch.
+  still benefiting from count-gating and the one ``device_get`` a
+  batch (one entry's arrays are fetched as they are: no concatenation).
 - ``fetch_coalesced``: groups device arrays by (dtype, trailing shape),
   concatenates each group on device along axis 0, fetches everything in
   a single ``jax.device_get``, and splits back host-side — one transfer
-  round trip instead of one per column per batch.
+  round trip instead of one per column per batch.  An array whose copy
+  to the host was started when its step was dispatched
+  (``DevicePipeline.submit``) is fetched as it is: the bytes are on
+  their way or there, and a concatenation would only send them again.
 
 Exactness contract: entries drain strictly FIFO and each entry
 materializes into exactly the EventBatch the synchronous path would
@@ -50,7 +56,8 @@ class EmitStats:
 
     __slots__ = ("emit_transfers", "deferred_batches", "zero_match_skips",
                  "dropped_batches", "max_pending_depth", "auto_depth",
-                 "dropped_instances")
+                 "dropped_instances", "early_copy_batches",
+                 "early_copy_hits", "early_copy_wasted_bytes")
 
     def __init__(self):
         self.emit_transfers = 0
@@ -70,6 +77,13 @@ class EmitStats:
         # (core/dense_pattern.py _check_overflow): rows the host engine
         # would have emitted may be missing once this is not 0
         self.dropped_instances = 0
+        # batches some of whose emit arrays started for the host at
+        # their dispatch (core/device_pipeline.py), those of them whose
+        # drain took such an array, and the bytes started for a chunk
+        # whose gate then came back 0: copied, never read
+        self.early_copy_batches = 0
+        self.early_copy_hits = 0
+        self.early_copy_wasted_bytes = 0
 
     def note_depth(self, depth: int):
         if depth > self.max_pending_depth:
@@ -84,6 +98,9 @@ class EmitStats:
             "maxPendingDepth": self.max_pending_depth,
             "autoEffectiveDepth": self.auto_depth,
             "droppedInstances": self.dropped_instances,
+            "earlyCopyBatches": self.early_copy_batches,
+            "earlyCopyHits": self.early_copy_hits,
+            "earlyCopyWastedBytes": self.early_copy_wasted_bytes,
         }
 
 
@@ -91,8 +108,8 @@ def _is_device_array(a) -> bool:
     return not isinstance(a, (np.ndarray, np.generic, int, float, bool))
 
 
-def fetch_coalesced(arrays: Sequence,
-                    on_device: bool = True) -> List[np.ndarray]:
+def fetch_coalesced(arrays: Sequence, on_device: bool = True,
+                    started=frozenset()) -> List[np.ndarray]:
     """One device→host round trip for a list of arrays.
 
     Device arrays are grouped by (dtype, trailing shape), each group is
@@ -104,7 +121,9 @@ def fetch_coalesced(arrays: Sequence,
     ``on_device`` False fetches every array as it is, still in the one
     ``device_get`` (its copies are all started before any is awaited):
     a concatenation is a program, and a program dispatched while a step
-    is in flight runs after that step.
+    is in flight runs after that step.  So is every array in
+    ``started`` (by ``id``) fetched: its copy was started when its step
+    was dispatched, and ``device_get`` finds it there.
     """
     if not arrays:
         return []
@@ -115,7 +134,7 @@ def fetch_coalesced(arrays: Sequence,
             out[i] = np.asarray(a)
             continue
         shape = getattr(a, "shape", ())
-        if len(shape) == 0 or not on_device:
+        if len(shape) == 0 or not on_device or id(a) in started:
             key = ("alone", i)  # 0-d: no concat axis; fetch alone
         else:
             key = (str(a.dtype), tuple(shape[1:]))
@@ -176,15 +195,18 @@ class PendingEmit:
     """One deferred junction batch: device refs + a materializer that
     turns the fetched host arrays into the exact synchronous emit."""
 
-    __slots__ = ("arrays", "materialize", "trace")
+    __slots__ = ("arrays", "materialize", "trace", "started")
 
-    def __init__(self, arrays: Sequence, materialize: Callable, trace=None):
+    def __init__(self, arrays: Sequence, materialize: Callable, trace=None,
+                 started=frozenset()):
         # materialize(host_arrays) -> None (runs the emit callback);
         # trace is the batch's sampled cycle token (observability/
-        # trace.py CycleToken, or None) — the drain stamps its emit span
+        # trace.py CycleToken, or None) — the drain stamps its emit span;
+        # started: ids of the arrays whose copy to the host is under way
         self.arrays = list(arrays)
         self.materialize = materialize
         self.trace = trace
+        self.started = started
 
 
 class EmitDepthController:
@@ -274,7 +296,10 @@ class EmitQueue:
         # is a later step dispatched and not yet waited for (the ingest
         # stage holds a batch in flight)?  Then a drain concatenates
         # nothing on the device: that program would queue behind the
-        # step and the fetch wait it out.  The pipeline wires it.
+        # step and the fetch wait it out.  The pipeline wires it.  Nor
+        # does a drain of one entry: a concatenation joins the same
+        # column of several batches into one transfer, and one batch
+        # has each column once (_drain).
         self.step_in_flight: Callable[[], bool] = lambda: False
         self._entries: List[PendingEmit] = []
 
@@ -297,21 +322,23 @@ class EmitQueue:
         """Record a zero-match batch that transferred nothing."""
         self.stats.zero_match_skips += 1
 
-    def _fetch(self, arrays: Sequence) -> List[np.ndarray]:
+    def _fetch(self, arrays: Sequence, on_device: bool = True,
+               started=frozenset()) -> List[np.ndarray]:
         """``fetch_coalesced`` behind the ``emit.drain`` injection site,
         with bounded retry-with-backoff on transient transfer faults
-        (sticky device loss and other errors propagate immediately)."""
+        (sticky device loss and other errors propagate immediately).
+        A copy started early that failed raises here too, from the
+        ``device_get`` that awaits it."""
         fi = self.faults
-        on_device = not self.step_in_flight()
         if fi is None:
-            return fetch_coalesced(arrays, on_device)
+            return fetch_coalesced(arrays, on_device, started)
         attempts = fi.transfer_retry_attempts
         backoff = None
         attempt = 0
         while True:
             try:
                 fi.check("emit.drain")
-                host = fetch_coalesced(arrays, on_device)
+                host = fetch_coalesced(arrays, on_device, started)
                 if attempt:
                     fi.stats.drains_recovered += 1
                 return host
@@ -365,6 +392,13 @@ class EmitQueue:
             entries, self._entries = self._entries, []
             arrays: List = []
             spans: List[int] = []
+            started = frozenset().union(*(e.started for e in entries))
+            # several batches' columns are worth a concatenation program
+            # a kind; one batch's are fetched as they are, in the one
+            # device_get (on the v5e a program costs more than the
+            # transfers it saves: PERF.md, section 6, PR 44), and the
+            # default depth then compiles no program on the way back
+            coalesce = len(entries) > 1 and not self.step_in_flight()
             for e in entries:
                 spans.append(len(e.arrays))
                 arrays.extend(e.arrays)
@@ -381,10 +415,10 @@ class EmitQueue:
                 if clock is not None:
                     t_fetch = clock()
                     with annotation(STAGE_FETCH):
-                        host = self._fetch(arrays)
+                        host = self._fetch(arrays, coalesce, started)
                     t_fetched = clock()
                 else:
-                    host = self._fetch(arrays)
+                    host = self._fetch(arrays, coalesce, started)
             except Exception as err:
                 fi = self.faults
                 if fi is not None:

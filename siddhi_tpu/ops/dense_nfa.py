@@ -2163,11 +2163,16 @@ class DeferredDenseEmit:
         self._total = int(sum(int(c) for c in counts))
         return self._total
 
+    def gates(self) -> List[Tuple]:
+        """``(count, arrays)`` of every round the batch still holds, in
+        ``device_arrays()`` order: before ``resolve()`` every dispatched
+        round, which is what the pipeline starts for the host at
+        dispatch (core/device_pipeline.py)."""
+        return [(ch["count"], (ch["emit"], ch["f"], ch["i"], ch["anchor"]))
+                for ch in self.chunks]
+
     def device_arrays(self) -> List:
-        arrs: List = []
-        for ch in self.chunks:
-            arrs.extend((ch["emit"], ch["f"], ch["i"], ch["anchor"]))
-        return arrs
+        return [a for _count, arrays in self.gates() for a in arrays]
 
     def materialize(self, host_arrays) -> Tuple[np.ndarray, np.ndarray]:
         eng = self.engine

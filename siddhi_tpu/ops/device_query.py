@@ -2655,14 +2655,17 @@ class DeferredDeviceEmit:
         self._total = total
         return total
 
+    def gates(self) -> List[Tuple]:
+        """``(count, arrays)`` of every device chunk the batch still
+        holds, in ``device_arrays()`` order: before ``resolve()`` every
+        dispatched chunk, which is what the pipeline starts for the host
+        at dispatch (core/device_pipeline.py)."""
+        return [(ch["count"],
+                 [ch["ov"]] + [ch["out"][nm] for nm in ch["names"]])
+                for ch in self.chunks if ch["kind"] in ("device", "flush")]
+
     def device_arrays(self) -> List:
-        arrs: List = []
-        for ch in self.chunks:
-            if ch["kind"] not in ("device", "flush"):
-                continue
-            arrs.append(ch["ov"])
-            arrs.extend(ch["out"][nm] for nm in ch["names"])
-        return arrs
+        return [a for _count, arrays in self.gates() for a in arrays]
 
     def materialize(self, host_arrays):
         """``host_arrays``: fetched copies aligned with

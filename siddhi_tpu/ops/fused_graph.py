@@ -470,16 +470,24 @@ class FusedDeferredEmit:
         self._total = int(sum(int(c) for c in counts))
         return self._total
 
-    def device_arrays(self) -> List:
-        arrs: List = []
+    def gates(self) -> List[Tuple]:
+        """``(count, arrays)`` of every chunk the batch still holds, in
+        ``device_arrays()`` order: before ``resolve()`` every dispatched
+        chunk, which is what the pipeline starts for the host at
+        dispatch (core/device_pipeline.py)."""
+        out = []
         for ch in self.chunks:
-            arrs.append(ch["emitmask"])
+            arrs = [ch["emitmask"]]
             if ch["kind"] == TAIL_DEVICE:
                 arrs.extend(ch["out"][nm] for nm in ch["names"])
                 arrs.extend(ch["fwd"][k] for k in ch["fwd_names"])
             else:
                 arrs.extend((ch["f"], ch["i"], ch["anchor"]))
-        return arrs
+            out.append((ch["count"], arrs))
+        return out
+
+    def device_arrays(self) -> List:
+        return [a for _count, arrays in self.gates() for a in arrays]
 
     def materialize(self, host_arrays
                     ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:

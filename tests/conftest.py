@@ -57,3 +57,24 @@ def force_pipelined(monkeypatch):
             monkeypatch.setattr(ingest_stage, "IDLE_MAX_S", 3600.0)
 
     return force
+
+
+@pytest.fixture
+def no_early_copies(monkeypatch):
+    """The device pipeline starts no emit array for the host at dispatch
+    (core/device_pipeline.py ``_Position.start``): every drain fetches
+    on demand, as it did before the early copies.  What the pipeline
+    observes still decides on every app; this is the tests' twin to
+    compare with, not a knob."""
+    from siddhi_tpu.core.device_pipeline import _Position
+
+    monkeypatch.setattr(_Position, "start", lambda self: False)
+
+
+@pytest.fixture(params=["early", "on_demand"])
+def copies(request):
+    """Both ways a drain's arrays reach the host: their copies started
+    at dispatch, or fetched on demand (``no_early_copies``)."""
+    if request.param == "on_demand":
+        request.getfixturevalue("no_early_copies")
+    return request.param

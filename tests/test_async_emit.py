@@ -12,6 +12,11 @@ produce identical callbacks across every flush trigger (queue-full,
 timer fire, snapshot mid-stream, pull query, shutdown) on the
 device-single, partitioned, dense, and sharded paths — and assert the
 transfer counters: zero-match batches perform no column transfer.
+
+The drain's fault handling (the ``emit.drain`` site, its retry, the
+dropped drain) is held with the emit arrays' copies started at dispatch
+(core/device_pipeline.py) and with every fetch on demand
+(``no_early_copies`` of conftest.py): the same rows either way.
 """
 
 import numpy as np
@@ -506,3 +511,114 @@ class TestIsolatedFailuresAreVisible:
 
         runtime = self._run(monkeypatch, DeferredDenseEmit, "resolve")
         assert runtime.ingest_stats.dropped_batches == 1
+
+
+class TestDrainFaultsWithEarlyCopies:
+    """A copy started at dispatch is awaited inside ``EmitQueue._fetch``:
+    the injection site, the retry ladder and the drop-this-drain
+    isolation sit where they sat, and ``emitTransfers`` still counts one
+    a drain."""
+
+    APP = DEFINE + ("@info(name='q') from S[v > 0.0] select k, v "
+                    "insert into OutputStream;")
+    SENDS = [[i, float(i + 1)] for i in range(8)]
+
+    def _run(self, faults=""):
+        m = SiddhiManager()
+        try:
+            rt = m.create_siddhi_app_runtime(
+                "@app:playback " + faults + "@app:execution('tpu') "
+                + self.APP)
+            got, errors = [], []
+            rt.add_callback("OutputStream", lambda evs: got.extend(
+                tuple(e.data) for e in evs))
+            rt.add_exception_listener(errors.append)
+            rt.start()
+            h = rt.get_input_handler("S")
+            for i, row in enumerate(self.SENDS):
+                h.send(list(row), timestamp=1000 + i)
+            runtime = rt.query_runtimes["q"].device_runtime
+            rt.shutdown()
+            return got, runtime, rt.app_context.fault_injector, errors
+        finally:
+            m.shutdown()
+
+    def _started(self, runtime, copies):
+        st = runtime.emit_stats
+        if copies == "early":
+            # every batch matches: started from the third on
+            assert st.early_copy_batches == len(self.SENDS) - 2
+        else:
+            assert st.early_copy_batches == 0
+        return st
+
+    def test_clean_run_counts_one_transfer_a_drain(self, copies):
+        got, runtime, _fi, errors = self._run()
+        assert got == [tuple(r) for r in self.SENDS] and not errors
+        st = self._started(runtime, copies)
+        assert st.emit_transfers == len(self.SENDS)
+        assert st.early_copy_hits == st.early_copy_batches
+        assert st.early_copy_wasted_bytes == 0
+
+    def test_transient_fault_is_retried_and_bit_exact(self, copies):
+        clean, _rt, _fi, _errors = self._run()
+        got, runtime, fi, errors = self._run(
+            "@app:faults(seed='3', transfer.retry.scale='0.0001', "
+            "emit.drain='transient:count=3:after=3') ")
+        assert got == clean and not errors
+        # the fourth drain (its arrays started early, where they are)
+        # trips three times and succeeds on the fourth attempt
+        assert fi.stats.faults_injected == 3
+        assert fi.stats.transfer_retries == 3
+        assert fi.stats.drains_recovered == 1
+        assert fi.stats.drains_failed == 0
+        st = self._started(runtime, copies)
+        assert st.emit_transfers == len(self.SENDS)
+        assert st.dropped_batches == 0
+
+    def test_exhausted_retries_drop_that_drain_alone(self, copies):
+        got, runtime, fi, errors = self._run(
+            "@app:faults(transfer.retry.attempts='1', "
+            "transfer.retry.scale='0.0001', "
+            "emit.drain='transient:count=2:after=3') ")
+        want = [tuple(r) for i, r in enumerate(self.SENDS) if i != 3]
+        assert got == want
+        assert fi.stats.drains_failed == 1 and len(errors) == 1
+        st = self._started(runtime, copies)
+        assert st.dropped_batches == 1
+        assert st.emit_transfers == len(self.SENDS) - 1
+        if copies == "early":
+            # the dropped drain's arrays were handed on all the same
+            assert st.early_copy_hits == st.early_copy_batches
+
+    def test_sticky_loss_fails_every_drain_and_the_runtime_lives(
+            self, copies):
+        got, runtime, fi, errors = self._run(
+            "@app:faults(emit.drain='sticky') ")
+        assert got == []
+        assert fi.stats.drains_failed == len(self.SENDS) == len(errors)
+        assert fi.stats.drains_recovered == 0
+        st = self._started(runtime, copies)
+        assert st.dropped_batches == len(self.SENDS)
+        assert st.emit_transfers == 0
+        assert runtime.step_invocations == len(self.SENDS)
+
+    def test_failing_fetch_without_a_harness_drops_one_drain(
+            self, copies, monkeypatch):
+        from siddhi_tpu.core import emit_queue
+
+        real, calls = emit_queue.fetch_coalesced, []
+
+        def boom(arrays, *a, **kw):
+            calls.append(len(arrays))
+            if len(calls) == 4:
+                raise RuntimeError("device step failed")
+            return real(arrays, *a, **kw)
+
+        monkeypatch.setattr(emit_queue, "fetch_coalesced", boom)
+        got, runtime, fi, errors = self._run()
+        assert fi is None
+        assert got == [tuple(r) for i, r in enumerate(self.SENDS) if i != 3]
+        assert [str(e) for e in errors] == ["device step failed"]
+        st = self._started(runtime, copies)
+        assert st.dropped_batches == 1

@@ -26,6 +26,16 @@ finisher with or without a ``drain()`` racing it from another thread);
 a failing deferred gate drops that batch only; its ``step`` span starts
 at its ``resolve()``; the counters say who finished what; ``shutdown()``
 leaves no thread behind.
+
+And to the early copies (``DevicePipeline._start_copies``): a step's
+counts, then the emit arrays of the chunk positions whose last batch
+owed rows and has kept foretelling the next, start for the host inside
+``submit``, before the gate is resolved; the drain then concatenates
+nothing; the rows are those of the same app with the copies off
+(``no_early_copies`` of conftest.py); a stream that matches nothing
+starts no array; a copy that was wasted doubles the run of right
+forecasts the position has to show, a copy that was used takes one off;
+and ``statistics()`` says how often.
 """
 
 import sys
@@ -47,33 +57,40 @@ def _batch(attrs, cols, i):
                       np.full(n, 1_000 + i * 10, dtype=np.int64))
 
 
-def window_batch(i, n=16):
+# (``quiet``: a batch that owes no row — every value under the filters,
+# every probed key absent from the table)
+
+
+def window_batch(i, n=16, quiet=False):
     rng = np.random.default_rng(10 + i)
+    v = rng.uniform(0.0, 20.0, n).astype(np.float32)
     return _batch(["k", "v"], {
         "k": (np.arange(n) % 4).astype(np.int32),
-        "v": rng.uniform(0.0, 20.0, n).astype(np.float32)}, i)
+        "v": v - 30.0 if quiet else v}, i)
 
 
-def keyed_batch(i, n=32):
+def keyed_batch(i, n=32, quiet=False):
     rng = np.random.default_rng(20 + i)
     # 24 keys over 32 rows: eight keys come twice in every batch
     return _batch(["k", "v"], {
         "k": np.arange(n, dtype=np.int64) % 24,
-        "v": rng.uniform(0.0, 20.0, n)}, i)
+        "v": rng.uniform(0.0, 20.0, n) * (not quiet)}, i)
 
 
-def skewed_batch(i, n=40):
+def skewed_batch(i, n=40, quiet=False):
     rng = np.random.default_rng(30 + i)
     # key 7 takes four rows in five: promoted by the second batch
     k = np.where(np.arange(n) % 5 < 4, 7, np.arange(n) % 24)
     return _batch(["k", "v"], {
-        "k": k.astype(np.int64), "v": rng.uniform(0.0, 20.0, n)}, i)
+        "k": k.astype(np.int64),
+        "v": rng.uniform(0.0, 20.0, n) * (not quiet)}, i)
 
 
-def probe_batch(i, n=16):
+def probe_batch(i, n=16, quiet=False):
     rng = np.random.default_rng(40 + i)
     return _batch(["k", "x"], {
-        "k": (np.arange(n) % 12).astype(np.int32),   # keys 8..11 miss
+        # keys 8..11 miss
+        "k": (np.arange(n) % 12 + 100 * quiet).astype(np.int32),
         "x": rng.uniform(0.0, 20.0, n).astype(np.float32)}, i)
 
 
@@ -99,7 +116,8 @@ CASES = {
     "window": (
         "", "",
         "define stream S (k int, v float); @info(name='q') "
-        "from S#window.length(4) select k, sum(v) as s insert into Out;",
+        "from S[v >= 0.0]#window.length(4) select k, sum(v) as s "
+        "insert into Out;",
         window_batch, None, "device", "device",
         lambda shell: (shell.engine, "process_batch_deferred")),
     "dense": (
@@ -162,10 +180,10 @@ class Deployed:
         self.pipe = self.shell.pipeline
         assert isinstance(self.pipe, DevicePipeline)
 
-    def send(self, i):
+    def send(self, i, quiet=False):
         """Batch ``i``; returns the rows it delivered at once."""
         before = len(self.rows)
-        self.handler.send_batch(self.make(i))
+        self.handler.send_batch(self.make(i, quiet=quiet))
         return self.rows[before:]
 
     def __enter__(self):
@@ -198,6 +216,9 @@ class _BrokenGate:
 
     def resolve(self):
         raise RuntimeError("injected count-gate failure")
+
+    def gates(self):
+        return self.pending.gates()
 
     def device_arrays(self):
         return self.pending.device_arrays()
@@ -470,6 +491,240 @@ def test_exception_in_the_shell_closes_the_token_as_raised(kind, monkeypatch):
             assert got == want[:3] + want[4:]
         else:
             assert app.send(4), "the runtime serves on"
+
+
+# -- the early copies ---------------------------------------------------------
+
+
+class CopyLog:
+    """Every ``copy_to_host_async`` made outside a ``device_get`` (which
+    starts its own), every ``jax.device_get`` and every device
+    concatenation, in call order."""
+
+    def __init__(self, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+        from jax._src.array import ArrayImpl
+
+        self.calls = calls = []
+        real_copy = ArrayImpl.copy_to_host_async
+        real_get, real_cat = jax.device_get, jnp.concatenate
+
+        getting = []
+
+        def copy(arr):
+            if not getting:
+                calls.append(("copy", id(arr), arr.shape))
+            return real_copy(arr)
+
+        def get(tree):
+            calls.append(("get", None, None))
+            getting.append(1)
+            try:
+                return real_get(tree)
+            finally:
+                getting.pop()
+
+        def cat(arrays, *a, **kw):
+            calls.append(("concatenate", None, None))
+            return real_cat(arrays, *a, **kw)
+
+        monkeypatch.setattr(ArrayImpl, "copy_to_host_async", copy)
+        monkeypatch.setattr(jax, "device_get", get)
+        monkeypatch.setattr(jnp, "concatenate", cat)
+
+    def before_first_get(self, since=0):
+        """The copies started before anything was fetched."""
+        out = []
+        for what, ident, shape in self.calls[since:]:
+            if what == "get":
+                break
+            if what == "copy":
+                out.append((ident, shape))
+        return out
+
+
+def submitted(app):
+    """Spy on ``submit``: every pending's gates as they stood at its
+    dispatch, and every entry the pipeline pushed for the drain."""
+    seen, pushed = [], []
+    real_submit, real_push = app.pipe.submit, app.pipe.emit_queue.push
+
+    def submit(tok, pending, build, emit):
+        if pending is not None:
+            seen.append(pending.gates())
+        real_submit(tok, pending, build, emit)
+
+    def push(entry):
+        pushed.append((list(entry.arrays), entry.started))
+        real_push(entry)
+
+    app.pipe.submit = submit
+    app.pipe.emit_queue.push = push
+    return seen, pushed
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_copies_start_inside_submit_counts_first(kind, monkeypatch):
+    """Once a batch has owed rows, the next one's counts and then its
+    emit arrays start for the host inside ``submit``, before the gate
+    is resolved, and its drain dispatches no concatenation."""
+    with Deployed(kind) as app:
+        for i in range(4):
+            assert app.send(i) or i < 2
+        log = CopyLog(monkeypatch)
+        seen, pushed = submitted(app)
+        assert app.send(4)
+        gates = seen[-1]    # (the hot-key shell: the hot rows' submit)
+        counts = [id(c) for c, _arrays in gates]
+        arrays = [id(a) for _c, arrs in gates for a in arrs]
+        # the last submit's copies: from its first count on
+        at = next(n for n, call in enumerate(log.calls)
+                  if call[:2] == ("copy", counts[0]))
+        early = log.before_first_get(at)
+        assert [ident for ident, _shape in early[:len(counts)]] == counts
+        assert all(shape == () for _ident, shape in early[:len(counts)])
+        started = [ident for ident, _shape in early[len(counts):]]
+        assert started and set(started) <= set(arrays)
+        # in the order the drain will hand them on
+        assert started == [a for a in arrays if a in set(started)]
+        kept, ids = pushed[-1]
+        assert ids and ids <= set(started)
+        if len(gates) == 1:
+            # one chunk: every array of the drain was started
+            assert ids == {id(a) for a in kept} == set(arrays)
+            tail = log.calls[at:]
+            assert ("concatenate", None, None) not in tail
+        st = app.pipe.emit_stats
+        assert st.early_copy_batches >= 1 and st.early_copy_hits >= 1
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_no_concatenation_once_every_array_is_started(kind, monkeypatch,
+                                                      request):
+    """A drain of two batches whose arrays were all started fetches them
+    as they are; the twin with the copies off concatenates every group
+    of like arrays, as before.  A drain of one batch concatenates
+    nothing either way."""
+    def concatenations(off, depths):
+        if off:
+            request.getfixturevalue("no_early_copies")
+        with Deployed(kind, depths=depths) as app:
+            for i in range(4):
+                app.send(i)
+            app.shell.drain()
+            with monkeypatch.context() as mp:
+                log = CopyLog(mp)
+                seen, pushed = submitted(app)
+                for i in range(4, 8):
+                    app.send(i)
+                app.shell.drain()
+            assert all((ids == {id(a) for a in kept}) is not off
+                       for kept, ids in pushed[1:] if len(kept) > 1)
+            return sum(1 for c in log.calls if c[0] == "concatenate")
+
+    deep = "emit.depth='2'"
+    on = concatenations(False, deep)
+    # (hot-key, dense: a drain's two entries are not always alike, and
+    # the first batch after the barrier may be fetched on demand)
+    assert on <= 1
+    assert concatenations(False, "") == 0
+    # (the fixture holds to the end of the test: the twins come last)
+    # (the hot-key shell's two entries a drain, cold rows and hot, hold
+    # no two arrays alike)
+    assert concatenations(True, deep) > on or kind == "hotkey"
+    assert concatenations(True, "") == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_rows_are_those_of_the_app_without_early_copies(kind, request):
+    on = reference(kind)
+    with Deployed(kind) as app:
+        for i in range(N_BATCHES):
+            app.send(i)
+        assert app.pipe.emit_stats.early_copy_hits >= N_BATCHES - 4
+    request.getfixturevalue("no_early_copies")
+    off = reference(kind)
+    assert on == off
+    with Deployed(kind) as app:
+        for i in range(N_BATCHES):
+            app.send(i)
+        st = app.pipe.emit_stats
+        assert (st.early_copy_batches, st.early_copy_hits,
+                st.early_copy_wasted_bytes) == (0, 0, 0)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_stream_that_matches_nothing_starts_no_array(kind, monkeypatch):
+    with Deployed(kind) as app:
+        log = CopyLog(monkeypatch)
+        seen, pushed = submitted(app)
+        for i in range(N_BATCHES):
+            assert app.send(i, quiet=True) == []
+        assert len(seen) >= N_BATCHES and not app.rows
+        # the counts travel (the gate needs them); no column does
+        copies = [shape for what, _id, shape in log.calls if what == "copy"]
+        assert copies and set(copies) == {()}
+        st = app.pipe.emit_stats
+        assert st.zero_match_skips == len(seen)
+        assert (st.early_copy_batches, st.early_copy_hits,
+                st.early_copy_wasted_bytes) == (0, 0, 0)
+        # (a promotion fetches the key's dense row through the queue)
+        assert st.emit_transfers == (1 if kind == "hotkey" else 0)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_wasted_copy_doubles_the_run_a_position_has_to_show(kind):
+    """The forecast is "owed rows last time, will owe rows now"; the
+    arrays start once it has come true ``need`` times running.  The
+    quiet batch's arrays were started and never read: ``need`` goes from
+    1 to 2, so the first matching batch after it is fetched on demand
+    (no forecast), the next two as well (the forecast right once, then
+    twice), and the fourth is started early again and takes ``need``
+    back to 1."""
+    want = reference(kind)
+    with Deployed(kind) as app:
+        st = app.pipe.emit_stats
+
+        def counters():
+            return (st.early_copy_batches, st.early_copy_hits,
+                    st.early_copy_wasted_bytes, st.zero_match_skips)
+
+        got = [app.send(i) for i in range(5)]
+        assert got == want[:5]
+        b0, h0, w0, z0 = counters()
+        assert b0 >= 1 and h0 >= 1
+        assert app.send(9, quiet=True) == []
+        b1, h1, w1, z1 = counters()
+        assert b1 > b0 and h1 == h0 and w1 > w0 and z1 > z0
+        for i in (10, 11, 12):
+            assert app.send(i)
+            assert counters()[:3] == (b1, h1, w1), i
+        assert app.send(13)
+        b2, h2, w2, _z = counters()
+        assert b2 > b1 and h2 > h1 and w2 == w1
+        # one record a chunk position of each kind of pending
+        records = [pos for seen in app.pipe._positions.values()
+                   for pos in seen]
+        assert records and all(pos.need in (1, 2) for pos in records)
+        assert any(pos.right >= 3 for pos in records)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_early_copy_counters_reach_statistics(kind):
+    with Deployed(kind, trace="@app:statistics('true') ") as app:
+        for i in range(5):
+            app.send(i)
+        app.send(9, quiet=True)
+        stats = app.rt.statistics()
+        st = app.pipe.emit_stats
+        for name, value in (("earlyCopyBatches", st.early_copy_batches),
+                            ("earlyCopyHits", st.early_copy_hits),
+                            ("earlyCopyWastedBytes",
+                             st.early_copy_wasted_bytes)):
+            (key,) = [k for k in stats if k.endswith(f".q.{name}")]
+            assert stats[key] == value > 0, (key, stats[key], value)
+        assert st.early_copy_hits < st.early_copy_batches
 
 
 # -- the quarantine, on the pipeline alone ------------------------------------

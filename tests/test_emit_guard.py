@@ -37,3 +37,13 @@ def test_allowlist_not_stale():
     surfaces as a ``stale-allowlist`` finding — the list only shrinks."""
     stale = [f for f in _run()["findings"] if f.rule == "stale-allowlist"]
     assert not stale, "\n  ".join(f.render() for f in stale)
+
+
+def test_early_copies_start_at_the_one_seam():
+    """``copy_to_host_async`` is called by ``DevicePipeline`` alone
+    (core/device_pipeline.py ``_start_copies``): no engine and no shell
+    keeps a copy of the rule that decides which arrays start early."""
+    callers = sorted(
+        str(p.relative_to(REPO)) for p in (REPO / "siddhi_tpu").rglob("*.py")
+        if "copy_to_host_async(" in p.read_text())
+    assert callers == ["siddhi_tpu/core/device_pipeline.py"]

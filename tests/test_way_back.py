@@ -103,17 +103,32 @@ class App:
 
 
 @pytest.mark.parametrize("path", list(PATHS))
-def test_fetch_build_deliver_tile_emit(path):
+def test_fetch_build_deliver_tile_emit(path, copies):
     """Three siblings in this order, none overlapping another, nothing
     between them but the clock's own readings: ``fetch`` starts where
     ``emit`` does, ``build`` at the next reading after ``fetch`` ends,
     ``deliver`` at the next after ``build``, and ``emit`` ends at the
-    next after ``deliver``."""
+    next after ``deliver``.  So with the arrays' copies started at the
+    dispatch as with every fetch on demand, and ``fetch`` counts the
+    bytes of the arrays its entry kept either way."""
     with App(path) as app:
+        queue = app.shell().emit_queue
+        kept, push = {}, queue.push
+
+        def pushing(entry):
+            kept[entry.trace.cycle] = sum(a.nbytes for a in entry.arrays)
+            push(entry)
+
+        queue.push = pushing
         for i in range(6):
             app.send(i)
         assert app.rows
-        emitted = [by for by in app.cycles().values() if "emit" in by]
+        st = app.shell().emit_stats
+        assert (st.early_copy_hits >= 2) is (copies == "early")
+        cycles = app.cycles()
+        assert kept and all(cycles[cid]["fetch"][0][5] == nbytes
+                            for cid, nbytes in kept.items())
+        emitted = [by for by in cycles.values() if "emit" in by]
         assert len(emitted) >= 3
         for by in emitted:
             (emit,), (fetch,) = by["emit"], by["fetch"]
