@@ -47,9 +47,8 @@ SLIDING_WINDOW_Q = (
     "timestamp insert into outputStream;")
 
 # GroupByWindowSingleQueryPerformance.java:35 without its bare
-# `timestamp` select item: group keys + aggregates only (the form
-# chip_smoke.py's windows phase runs; the faithful shape lowers to the
-# device too since PR 33)
+# `timestamp` select item: group keys + aggregates only (the faithful
+# shape lowers to the device too since PR 33)
 GROUPBY_LENGTH_BATCH_AGG_ONLY_Q = (
     CSE_DEF + "@info(name='q0') from cseEventStream"
     "#window.lengthBatch(10) select symbol, sum(price) as total, "

@@ -62,12 +62,6 @@ shapes ride the bank exactly:
 Remaining integer shapes (last/set) keep the exact host numpy scatter
 ufuncs at native width.
 
-``use_kernel`` swaps the jitted ``.at[rows].add/min/max`` scatter for
-the Pallas segmented-reduce kernel (siddhi_tpu/kernels/bank_scatter.py)
-— same per-row results on int and extrema lanes bit-exactly (order-free
-ops); f32 SUM lanes may associate differently than the scatter's
-collision rounds, within the same documented f32 contract.
-
 Row layout: ``cap`` assignable rows + one dump row (index ``cap``) that
 absorbs padded lanes and out-of-order events, which take the host
 merge path instead (aggregation/runtime.py ``_merge_out_of_order``).
@@ -112,14 +106,11 @@ class DeviceBucketBank:
     (bucket_start, group_key) -> row index shared by every lane.
     """
 
-    def __init__(self, fields, cap: int = 4096, use_kernel: bool = False):
+    def __init__(self, fields, cap: int = 4096):
         self.fields = list(fields)
         self.names: List[str] = [f.name for f in self.fields]
         self.ops: Tuple[str, ...] = tuple(f.op for f in self.fields)
         self.cap = int(cap)
-        # @app:kernels('bank'): Pallas segmented-reduce scatter instead
-        # of .at[rows].add/min/max (module docstring)
-        self.use_kernel = bool(use_kernel)
         self.rows: Dict[Tuple[int, Tuple], int] = {}
         self._free: List[int] = list(range(self.cap))
         self._arrays = None  # per-lane jnp [cap+1]; lazy (jax import)
@@ -224,7 +215,6 @@ class DeviceBucketBank:
             import jax.numpy as jnp
 
             lanes = tuple(self._lanes)
-            cap1 = self.cap + 1
             # hi-lane index -> op for LONG extrema pairs: their two
             # lanes update together lexicographically, unlike the
             # LONG-sum pairs whose lanes stay independent adds
@@ -233,29 +223,7 @@ class DeviceBucketBank:
                 if len(fl) == 2 and self.ops[fi] in ("min", "max"):
                     pair_ops[fl[0]] = self.ops[fi]
 
-            if self.use_kernel:
-                from siddhi_tpu.kernels import bank_scatter, probe
-
-                r_pad = bank_scatter.pad_rows(cap1)
-                interp = probe.interpret_mode()
-
-                def reduce_delta(rows, v, op, ident):
-                    d = bank_scatter.segmented_reduce(
-                        rows, v, r_pad, op, ident, interp)
-                    return d[:cap1]
-
-            else:
-                reduce_delta = None
-
-            def upd(a, rows, v, op, kind):
-                if reduce_delta is not None:
-                    ident = (_I32_IDENTITY[op] if kind == "i32"
-                             else _IDENTITY[op])
-                    d = reduce_delta(rows, v, op, ident)
-                    if op in ("sum", "count"):
-                        return a + d
-                    return jnp.minimum(a, d) if op == "min" else (
-                        jnp.maximum(a, d))
+            def upd(a, rows, v, op):
                 if op in ("sum", "count"):
                     return a.at[rows].add(v)
                 return a.at[rows].min(v) if op == "min" else (
@@ -264,25 +232,12 @@ class DeviceBucketBank:
             def pair_update(a_hi, a_lo, rows, vh, vl, op):
                 # lexicographic (hi, lo) extrema: hi decides; lo
                 # competes only where its hi TIES the row's new hi
-                # winner.  min/max over ints is order-free, so the
-                # kernel and scatter paths are bit-identical.
+                # winner.
                 ident = _I32_IDENTITY[op]
-                comb = jnp.minimum if op == "min" else jnp.maximum
-                if reduce_delta is not None:
-                    new_hi = comb(a_hi, reduce_delta(rows, vh, op, ident))
-                elif op == "min":
-                    new_hi = a_hi.at[rows].min(vh)
-                else:
-                    new_hi = a_hi.at[rows].max(vh)
+                new_hi = upd(a_hi, rows, vh, op)
                 cand = jnp.where(vh == new_hi[rows], vl, ident)
                 base = jnp.where(a_hi == new_hi, a_lo, ident)
-                if reduce_delta is not None:
-                    new_lo = comb(base, reduce_delta(rows, cand, op, ident))
-                elif op == "min":
-                    new_lo = base.at[rows].min(cand)
-                else:
-                    new_lo = base.at[rows].max(cand)
-                return new_hi, new_lo
+                return new_hi, upd(base, rows, cand, op)
 
             def fn(arrays, rows, vals):
                 out = list(arrays)
@@ -294,32 +249,12 @@ class DeviceBucketBank:
                             vals[li], vals[li + 1], pair_ops[li])
                         li += 2
                         continue
-                    op, kind = lanes[li]
-                    out[li] = upd(arrays[li], rows, vals[li], op, kind)
+                    out[li] = upd(arrays[li], rows, vals[li], lanes[li][0])
                     li += 1
                 return out
 
             self._scatter = jax.jit(fn)
         return self._scatter
-
-    def smoke_compile(self):
-        """Compile this bank's scatter — every lane's op and dtype — and
-        raise on failure with the compiler's message.  One event block
-        is enough: the kernel's block shapes do not depend on the batch
-        (kernels/bank_scatter.py)."""
-        import jax
-
-        from siddhi_tpu.kernels.bank_scatter import EVENT_BLOCK
-
-        def lane(kind, n):
-            return jax.ShapeDtypeStruct(
-                (n,), np.int32 if kind == "i32" else np.float32)
-
-        self._scatter_fn().lower(
-            [lane(kind, self.cap + 1) for _op, kind in self._lanes],
-            lane("i32", EVENT_BLOCK),
-            [lane(kind, EVENT_BLOCK) for _op, kind in self._lanes],
-        ).compile()
 
     # -- row assignment ------------------------------------------------------
 

@@ -459,17 +459,6 @@ class AggregationRuntime:
                 )
 
                 self._bank = DeviceBucketBank(bank_fields)
-                # @app:kernels('bank'): Pallas segmented-reduce scatter
-                # when the bank's own scatter compiles through it;
-                # otherwise a counted fallback to the XLA scatter
-                ctx = app_planner.app_context
-                if getattr(ctx, "kernels", False) and (
-                        "bank" in getattr(ctx, "kernel_kinds", ())):
-                    from siddhi_tpu.planner.kernels import (
-                        try_enable_bank_kernel,
-                    )
-
-                    try_enable_bank_kernel(ctx, self.name, self._bank)
 
         self.output_definition = StreamDefinition(
             id=self.name, attributes=[Attribute(AGG_START_TS, AttrType.LONG)] + out_attrs

@@ -199,19 +199,12 @@ ALLOWLISTS = {
         # STATIC values (shapes, python scalars, config sets) — legal
         # at trace time; the cast heuristic cannot prove staticness
         # without type inference, so each is sanctioned by hand:
-        "siddhi_tpu/kernels/bank_scatter.py:segmented_reduce":
-            "int(rows.shape[0]) / int(r_pad): static shape + python int "
-            "forming the compile-cache key, not tracer material",
         "siddhi_tpu/ops/device_query.py:DeviceQueryEngine.make_step.step":
             "bool(kinds & {...}) on a python set of aggregation kinds — "
             "static config closed over at trace time, not a tracer",
     },
     "retrace-hazard": {
         # hot-sounding names that are actually plan-time, one-shot:
-        "siddhi_tpu/planner/kernels.py:try_enable_scan_kernel":
-            "smoke_compile() jits once per app creation to compile the "
-            "fused chain kernel before committing to it — plan time, "
-            "never on the batch path",
         f"{_DN}:DensePatternEngine._make_run_kernel":
             "smoke_compile() jits once per engine and stream to put the "
             "run kernel through Mosaic before make_rounds commits to "

@@ -224,7 +224,7 @@ class ProjectIndex:
     def resolve_dotted_function(self, idx: ModuleIndex, dotted: str
                                 ) -> Optional[Tuple[ModuleIndex, ast.AST, str]]:
         """Resolve an ``a.b.f(...)`` receiver chain rooted at an import
-        (``bank_scatter.segmented_reduce``); None for plain names (use
+        (``dense_run.build_run``); None for plain names (use
         ``resolve_function_name``) and unresolvable roots."""
         hit = self.resolve_symbol(self.module_of(idx), dotted)
         if hit is not None and hit[0] == "function":

@@ -60,9 +60,6 @@ SCANNED = (
     # Pallas kernels: the hottest device code in the tree — a
     # materialization inside a kernel wrapper would sync every step
     "siddhi_tpu/kernels/probe.py",
-    "siddhi_tpu/kernels/bank_scatter.py",
-    "siddhi_tpu/kernels/scan_chain.py",
-    "siddhi_tpu/kernels/dense_step.py",
     # device tables: columnar HBM storage + join probes — mutations may
     # only touch the device through staged_put and leave it through the
     # count-gated fetch_coalesced drain (demotion rebuilds included)

@@ -134,16 +134,6 @@ class SiddhiAppRuntime:
                 n += len(stage)
         return n
 
-    def _queue_fill(self) -> float:
-        """Worst async-junction fill fraction in [0, 1] — the sustained-
-        pressure signal the degradation ladder watches."""
-        worst = 0.0
-        for j in self.junctions.values():
-            q = j._queue if j.is_async else None
-            if q is not None and q.maxsize > 0:
-                worst = max(worst, q.qsize() / q.maxsize)
-        return min(worst, 1.0)
-
     # -- lifecycle ----------------------------------------------------------
 
     def debug(self):
@@ -222,18 +212,14 @@ class SiddhiAppRuntime:
             self._plan_monitor.start()
         if (self.app_context.watchdog_deadline_ms > 0
                 and getattr(self, "_watchdog", None) is None):
-            from siddhi_tpu.robustness import DegradationLadder, Watchdog
+            from siddhi_tpu.robustness import Watchdog
 
-            # @app:limits(watchdog='...', ladder='true'): stall detector
-            # + self-heal daemon, optionally driving the degradation
-            # ladder.  replan() restarts the pair through here, with the
+            # @app:limits(watchdog='...'): stall detector + self-heal
+            # daemon.  replan() restarts it through here, with the
             # transplanted stats so counters survive the heal.
-            rb = self.app_context.robustness
-            self._ladder = (DegradationLadder(self, rb)
-                            if self.app_context.ladder else None)
             self._watchdog = Watchdog(
-                self, rb, self.app_context.watchdog_deadline_ms,
-                ladder=self._ladder)
+                self, self.app_context.robustness,
+                self.app_context.watchdog_deadline_ms)
             self._watchdog.start()
 
     def _start_playback_heartbeat(self):
@@ -303,7 +289,6 @@ class SiddhiAppRuntime:
         if wd is not None:
             wd.stop()
             self._watchdog = None
-            self._ladder = None
         mon = getattr(self, "_plan_monitor", None)
         if mon is not None:
             mon.stop()
@@ -574,8 +559,8 @@ class SiddhiAppRuntime:
                 planner.app_context.plan_pins = dict(pins or {})
                 # robustness continuity (BEFORE build, so breakers and
                 # trackers bind to the carried objects): shed/breaker
-                # counters, token-bucket levels and the degradation rung
-                # survive a self-heal exactly like the journal does
+                # counters and token-bucket levels survive a self-heal
+                # exactly like the journal does
                 rb = self.app_context.robustness
                 if rb is not None and planner.app_context.robustness is not None:
                     planner.app_context.robustness = rb
@@ -584,17 +569,6 @@ class SiddhiAppRuntime:
                         ac.app_context = planner.app_context
                         ac.stats = rb
                         planner.app_context.admission = ac
-                level = self.app_context.degrade_level
-                if level:
-                    from siddhi_tpu.robustness import apply_degradation
-
-                    planner.app_context.degrade_level = level
-                    # record what the rung disabled: the rebuilt ladder
-                    # derives its rung list from these flags, and the
-                    # now-cleared annotation flags alone would leave it
-                    # zero-rung — unable to ever re-promote
-                    planner.app_context.degraded_features = tuple(
-                        apply_degradation(planner.app_context, level))
                 new_rt = planner.build()
 
                 fi = self.app_context.fault_injector
@@ -724,7 +698,6 @@ class SiddhiAppRuntime:
         ac = ctx.admission
         rb = ctx.robustness
         wd = getattr(self, "_watchdog", None)
-        ld = getattr(self, "_ladder", None)
         breakers = []
         for s in list(self.sinks) + list(self.sources):
             for t in [s] + list(getattr(s, "children", None) or []):
@@ -741,11 +714,9 @@ class SiddhiAppRuntime:
             "running": self.running,
             "shedding": shedding,
             "wedged": wedged,
-            "degrade_level": ctx.degrade_level,
             "admission": ac.snapshot() if ac is not None else None,
             "breakers": breakers,
             "watchdog": wd.describe() if wd is not None else None,
-            "ladder": ld.describe() if ld is not None else None,
             "counters": rb.as_dict() if rb is not None else {},
         }
 

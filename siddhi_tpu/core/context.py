@@ -167,14 +167,6 @@ class SiddhiAppContext:
         self.hotkey_k = 8
         self.hotkey_promote = 0.25
         self.hotkey_demote = 0.10
-        # @app:kernels('nfa,bank,scan'): swap the hot inner step of
-        # eligible runtimes for hand-written Pallas kernels
-        # (siddhi_tpu/kernels/), each pinned bit-identical to the XLA
-        # formulation it replaces (planner/kernels.py).  Off by
-        # default; ineligible/unlowertable cases fall back with counted
-        # kernelFallbackReasons.
-        self.kernels = False
-        self.kernel_kinds = ("nfa", "bank", "scan")
         # @app:devtables(capacity='N'): store eligible tables as
         # device-resident columnar arrays (siddhi_tpu/devtable/) — one
         # [capacity] device column per attribute + validity lane, jitted
@@ -209,10 +201,10 @@ class SiddhiAppContext:
         self.persist_interval_ms = 0
         # @app:limits(rate='N/s', burst='M', shed='drop|oldest|block',
         # block.max='1 sec', watchdog='2 sec', breaker='3',
-        # breaker.cooldown='1 sec', ladder='true'): overload protection
-        # (robustness/).  All off by default — without the annotation
-        # the admission/watchdog/breaker/ladder hooks are None and
-        # behavior is bit-identical to an unprotected app.
+        # breaker.cooldown='1 sec'): overload protection (robustness/).
+        # All off by default — without the annotation the
+        # admission/watchdog/breaker hooks are None and behavior is
+        # bit-identical to an unprotected app.
         self.limits_rate = 0.0          # events/s per stream (0 = off)
         self.limits_burst = 0.0         # bucket depth (default = rate)
         self.limits_shed = "drop"
@@ -220,15 +212,6 @@ class SiddhiAppContext:
         self.watchdog_deadline_ms = 0   # 0 = watchdog off
         self.breaker_threshold = 0      # 0 = breakers off
         self.breaker_cooldown_ms = 1000
-        self.ladder = False
-        # degradation-ladder rung currently applied (replan() threads it
-        # through each rebuilt context via robustness.apply_degradation)
-        # plus the features that rung disabled — a rebuilt context's
-        # annotation flags no longer show them as enabled, so the ladder
-        # needs this record to keep its rung list (and the ability to
-        # re-promote) across the rebuild
-        self.degrade_level = 0
-        self.degraded_features = ()
         # live robustness handles: counters, admission controller.
         # Created by the planner when @app:limits is present; replan()
         # re-adopts BOTH onto the replacement context so budgets and

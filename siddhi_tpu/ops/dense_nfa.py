@@ -528,11 +528,6 @@ class DensePatternEngine:
                  or before.max_count > before.min_count))
         self.emit_lanes = self.I * (2 if via_emits else 1)
         self._step_cache: Dict[str, Callable] = {}
-        # @app:kernels: swap the jitted XLA step for the bit-packed
-        # Pallas plane kernel (siddhi_tpu/kernels/dense_step.py).  Set
-        # by planner/kernels.py after its eligibility gate; flipping it
-        # requires clearing _step_cache.
-        self.use_kernel = False
 
     # -- compilation --------------------------------------------------------
 
@@ -696,12 +691,6 @@ class DensePatternEngine:
         cache_key = (stream_key, "advance")
         if cache_key in self._step_cache:
             return self._step_cache[cache_key]
-        if self.use_kernel:
-            from siddhi_tpu.kernels.dense_step import build_plane_advance
-
-            fn = build_plane_advance(self, stream_key)
-            self._step_cache[cache_key] = fn
-            return fn
         jnp = self.jnp
         S = self.S
         I = self.I

@@ -92,11 +92,6 @@ class HotKeyScanEngine:
         self.n_slots = int(n_slots)
         self.base_ts: Optional[int] = None
         self._step_fn = None
-        # @app:kernels: fuse the max-plus + counting chains into one
-        # Pallas kernel (siddhi_tpu/kernels/scan_chain.py) instead of
-        # materializing M/T and scanning twice.  Set by
-        # planner/kernels.py; flipping it requires resetting _step_fn.
-        self.use_kernel = False
 
     # -- state ---------------------------------------------------------------
 
@@ -194,26 +189,6 @@ class HotKeyScanEngine:
             return self._step_fn
         jax, jnp = self.jax, self.jnp
         S = self.n_nodes
-
-        if self.use_kernel:
-            from siddhi_tpu.kernels.scan_chain import fused_scan
-
-            def kstep(state, cols, ts_rel, valid, delta):
-                v, c = state["v"], state["c"]
-                live = v > NEG / 2
-                live = live.at[:, 0].set(False)
-                v = jnp.where(live, v - delta, v)
-                H, n = ts_rel.shape
-                env = dict(cols)
-                env[N_KEY] = n
-                F = self._filter_matrix(env, H, n) & valid[:, :, None]
-                nv, nc, emit = fused_scan(
-                    jax, jnp, F.astype(jnp.float32), ts_rel, v, c, NEG)
-                n_rows = jnp.sum(emit).astype(jnp.int32)
-                return {"v": nv, "c": nc}, emit, n_rows
-
-            self._step_fn = jax.jit(kstep)
-            return self._step_fn
 
         def combine(a, b):
             Ma, Ta = a

@@ -245,32 +245,6 @@ class AppPlanner:
             self.app_context.hotkey_promote = promote
             self.app_context.hotkey_demote = demote
 
-        # @app:kernels / @app:kernels('nfa,bank,scan'): hand-written
-        # Pallas kernels for the hot step of eligible runtimes
-        # (planner/kernels.py); ineligible cases stay on the XLA
-        # formulation with counted kernelFallbackReasons.
-        kn_ann = find_annotation(siddhi_app.annotations, "app:kernels")
-        if kn_ann is not None:
-            if self.app_context.execution_mode != "tpu":
-                raise SiddhiAppCreationError(
-                    "@app:kernels needs @app:execution('tpu')")
-            v = (kn_ann.element() or "true").strip().lower()
-            if v == "false":
-                pass  # explicit off: annotation present but disabled
-            elif v == "true":
-                self.app_context.kernels = True
-            else:
-                kinds = tuple(
-                    k.strip() for k in v.split(",") if k.strip())
-                bad = [k for k in kinds if k not in ("nfa", "bank", "scan")]
-                if bad or not kinds:
-                    raise SiddhiAppCreationError(
-                        f"@app:kernels: unknown kernel kind(s) "
-                        f"{bad or [v]} — valid kinds are 'nfa', 'bank', "
-                        "'scan'")
-                self.app_context.kernels = True
-                self.app_context.kernel_kinds = kinds
-
         # @app:devtables / @app:devtables(capacity='N'): device-resident
         # columnar tables (siddhi_tpu/devtable/); ineligible tables and
         # queries keep the host path with counted devtableFallbackReasons.
@@ -448,11 +422,10 @@ class AppPlanner:
 
         # @app:limits(rate='N/s', burst='M', shed='drop|oldest|block',
         # block.max='1 sec', watchdog='2 sec', breaker='3',
-        # breaker.cooldown='1 sec', ladder='true'): overload protection
-        # (robustness/) — admission control at ingest, watchdog-driven
-        # self-healing, transport circuit breakers, and the unified
-        # degradation ladder.  Absent ⇒ every hook stays None and the
-        # engine is bit-identical to an unprotected app.
+        # breaker.cooldown='1 sec'): overload protection (robustness/) —
+        # admission control at ingest, watchdog-driven self-healing and
+        # transport circuit breakers.  Absent ⇒ every hook stays None and
+        # the engine is bit-identical to an unprotected app.
         limits_ann = find_annotation(siddhi_app.annotations, "app:limits")
         if limits_ann is not None:
             from siddhi_tpu.compiler.parser import parse_time_string
@@ -537,15 +510,6 @@ class AppPlanner:
             bc = limits_time_ms("breaker.cooldown")
             if bc is not None:
                 ctx.breaker_cooldown_ms = bc
-            lv = (limits_ann.element("ladder") or "false").strip().lower()
-            if lv not in ("true", "false"):
-                raise SiddhiAppCreationError(
-                    f"@app:limits: ladder='{lv}' must be 'true' or 'false'")
-            ctx.ladder = lv == "true"
-            if ctx.ladder and not ctx.watchdog_deadline_ms:
-                raise SiddhiAppCreationError(
-                    "@app:limits: ladder='true' needs watchdog='<deadline>'"
-                    " — the watchdog tick is what drives the ladder")
             if not (ctx.limits_rate or ctx.watchdog_deadline_ms
                     or ctx.breaker_threshold):
                 raise SiddhiAppCreationError(

@@ -740,14 +740,6 @@ class QueryPlanner:
                 sm.record_planner_conflict(
                     name, "@app:hotkeys pinned but the partition axis is "
                     "mesh-sharded (precedence: shard > hotkeys)")
-        # @app:kernels: swap the hot inner step for Pallas kernels where
-        # the runtime is eligible; counted fallback otherwise.  After the
-        # hotkey wrap so the router's dense and scan halves gate
-        # independently.
-        if self.app.app_context.kernels:
-            from siddhi_tpu.planner.kernels import try_enable_query_kernels
-
-            try_enable_query_kernels(self.app, runtime, name)
         qr.pattern_processor = runtime
         if subscribe:
             for sk in engine.stream_keys:

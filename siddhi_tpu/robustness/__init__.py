@@ -18,10 +18,6 @@ is present — without the annotation behavior is bit-identical):
   sources atop ``ConnectRetryMixin``; while open, sink output spools
   to a bounded buffer behind the output ledger so nothing double-emits
   on close.
-- ``ladder``     — the unified degradation ladder: under sustained
-  pressure, demote lowerings in documented order (kernels→XLA,
-  devtable→host, fused→junction) via counted ``replan`` passes,
-  re-promoting under hysteresis.
 
 Every decision is counted on ``RobustnessStats`` (surfaced on the
 statistics feed and ``GET /siddhi-health/<app>``) and choke-pointed
@@ -35,8 +31,8 @@ from __future__ import annotations
 class RobustnessStats:
     """Counters for every overload-protection decision.
 
-    Owned by the hot paths (admission controller, breakers, watchdog,
-    ladder); the statistics layer wraps this object in a thin gauge
+    Owned by the hot paths (admission controller, breakers,
+    watchdog); the statistics layer wraps this object in a thin gauge
     (``StatisticsManager.robustness_tracker``) so metric assembly reads
     the same integers the health endpoint reports — the two can never
     disagree.
@@ -65,9 +61,6 @@ class RobustnessStats:
         "watchdog_near_misses",
         "watchdog_recoveries",
         "watchdog_recovery_failures",
-        # degradation ladder
-        "ladder_demotions",
-        "ladder_promotions",
     )
 
     def __init__(self):
@@ -83,20 +76,12 @@ from siddhi_tpu.robustness.admission import (  # noqa: E402
     TokenBucket,
 )
 from siddhi_tpu.robustness.breaker import CircuitBreaker  # noqa: E402
-from siddhi_tpu.robustness.ladder import (  # noqa: E402
-    DEMOTE_ORDER,
-    DegradationLadder,
-    apply_degradation,
-)
 from siddhi_tpu.robustness.watchdog import Watchdog  # noqa: E402
 
 __all__ = [
     "AdmissionController",
     "CircuitBreaker",
-    "DEMOTE_ORDER",
-    "DegradationLadder",
     "RobustnessStats",
     "TokenBucket",
     "Watchdog",
-    "apply_degradation",
 ]

@@ -7,7 +7,7 @@ staged ingest windows, pending emit drains).  Liveness contract:
 
 - **progress**  — the beat advanced since the last tick: healthy.
 - **near-miss** — work is pending and the beat is older than half the
-  deadline: counted once per episode, feeds the degradation ladder.
+  deadline: counted once per episode.
 - **stall**     — work is pending and the beat is older than the full
   deadline (a wedged batch cycle or emit drain): the watchdog fires
   the ``watchdog.trip`` fault site, freezes a FlightRecorder dump
@@ -34,19 +34,17 @@ log = logging.getLogger("siddhi_tpu")
 
 
 class Watchdog:
-    def __init__(self, runtime, stats, deadline_ms: int, ladder=None,
+    def __init__(self, runtime, stats, deadline_ms: int,
                  interval_ms: int = 0):
         self.runtime = runtime
         self.stats = stats
         self.deadline_ms = int(deadline_ms)
-        self.ladder = ladder
         self.interval_s = (interval_ms or max(self.deadline_ms // 4, 10)
                            ) / 1000.0
         self._stop = threading.Event()
         self._thread = None
         self._last_beats = -1
         self._last_progress = time.monotonic()
-        self._last_shed = 0
         self._in_near_miss = False
         #: health-endpoint state
         self.wedged = False
@@ -105,16 +103,6 @@ class Watchdog:
         if near and not self._in_near_miss:
             self.stats.watchdog_near_misses += 1
             self._in_near_miss = True
-        if self.ladder is not None:
-            shed_total = self.stats.events_shed
-            shed_delta = shed_total - self._last_shed
-            self._last_shed = shed_total
-            pressure = max(
-                self.runtime._queue_fill(),
-                1.0 if shed_delta > 0 else 0.0,
-                1.0 if (near or stalled) else 0.0,
-            )
-            self.ladder.observe(pressure)
         if stalled:
             self._trip(age_ms, pending)
 

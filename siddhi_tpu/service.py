@@ -202,7 +202,7 @@ class SiddhiService:
 
     def health(self, name: str):
         """Overload-protection health of a deployed app: admission
-        budgets + shed counts, breaker states, watchdog and ladder
+        budgets + shed counts, breaker states, watchdog
         state, and the full robustness counter block (the same live
         objects the statistics feed reads).  200 healthy / 503 not."""
         with self._lock:

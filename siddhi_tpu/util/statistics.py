@@ -228,7 +228,7 @@ class DurabilityTracker:
 class RobustnessTracker:
     """Overload-protection counters (robustness/ RobustnessStats):
     same thin-gauge pattern as FaultTracker — the admission controller,
-    breakers, watchdog and ladder increment their own counters, this
+    breakers and watchdog increment their own counters, this
     view just reads them (and the health endpoint reads the SAME
     object, so feed and endpoint cannot disagree)."""
 
@@ -310,12 +310,6 @@ class StatisticsManager:
         self.hotkey_fallbacks: Dict[str, int] = {}
         self.hotkey_fallback_reasons: Dict[str, str] = {}
         self.hotkey_routers: Dict[str, object] = {}
-        # queries under @app:kernels whose Pallas kernel(s) could not
-        # be enabled (probe failure, ineligible shape, lowering
-        # rejection): count + last reason per query — the downgrade to
-        # the plain XLA formulation is never silent
-        self.kernel_fallbacks: Dict[str, int] = {}
-        self.kernel_fallback_reasons: Dict[str, str] = {}
         # queries/tables under @app:devtables that kept (or returned to)
         # the host table path — build-time eligibility gates, plan-time
         # join/mutation gates, mid-run demotions and per-batch generic
@@ -425,14 +419,6 @@ class StatisticsManager:
         self.hotkey_fallbacks[qname] = (
             self.hotkey_fallbacks.get(qname, 0) + 1)
         self.hotkey_fallback_reasons[qname] = reason
-
-    def record_kernel_fallback(self, qname: str, reason: str):
-        """A query (or aggregation) under @app:kernels is running the
-        plain XLA formulation for at least one kernel kind; counted per
-        query with the last reason kept."""
-        self.kernel_fallbacks[qname] = (
-            self.kernel_fallbacks.get(qname, 0) + 1)
-        self.kernel_fallback_reasons[qname] = reason
 
     def record_devtable_fallback(self, name: str, reason: str):
         """A query or table under @app:devtables is using the host
@@ -570,10 +556,6 @@ class StatisticsManager:
         for qname, router in list(self.hotkey_routers.items()):
             for metric, v in router.hot_metrics().items():
                 out[self._metric("Queries", qname, metric)] = v
-        for qname, n in list(self.kernel_fallbacks.items()):
-            out[self._metric("Queries", qname, "kernelFallbacks")] = n
-            out[self._metric("Queries", qname, "kernelFallbackReason")] = (
-                self.kernel_fallback_reasons.get(qname, ""))
         for qname, n in list(self.devtable_fallbacks.items()):
             out[self._metric("Queries", qname, "devtableFallbacks")] = n
             out[self._metric("Queries", qname, "devtableFallbackReason")] = (

@@ -1,7 +1,7 @@
 """Test configuration: the CPU platform with an 8-device virtual mesh.
 
 Tests run on the CPU backend by contract: sharding correctness is
-validated on 8 virtual CPU devices and the Pallas kernels run
+validated on 8 virtual CPU devices and the Pallas kernel runs
 interpreted.  Both settings must be in place before any backend
 starts; anything less than 8 CPU devices is a loud failure (not a
 silent skip) — see _assert_virtual_mesh.

@@ -482,3 +482,155 @@ class TestLongSumDeviceBank:
         assert bank.long_overflow_risk({"_SUM0": v}, 2)
         bank.clear()
         assert not bank.long_overflow_risk({"_SUM0": v}, 2)
+
+
+class TestBankScatter:
+    """The bank's scatter (``.at[rows].add/min/max`` in one jitted
+    program) against numpy's unbuffered ufuncs on the same events."""
+
+    @pytest.mark.parametrize("op", ["sum", "min", "max"])
+    def test_matches_numpy_reference_int32(self, op):
+        from siddhi_tpu.aggregation.device_bank import DeviceBucketBank
+        from siddhi_tpu.aggregation.runtime import BaseField
+        from siddhi_tpu.query_api import AttrType
+        import numpy as np
+
+        rng = np.random.default_rng(11)
+        # 700 events pad to 1,024 lanes: the padding targets the dump
+        # row and leaves the 40 assigned rows alone
+        n, r = 700, 40
+        rows = rng.integers(0, r, n).astype(np.int32)
+        vals = rng.integers(-1000, 1000, n).astype(np.int32)
+        # a sum rides the exact hi/lo pair, an extremum one int32 lane
+        ftype = AttrType.LONG if op == "sum" else AttrType.INT
+        bank = DeviceBucketBank([BaseField("_F0", op, None, ftype)], cap=256)
+        keys = [(0, (i,)) for i in range(r)]
+        assert bank.assign(keys)
+        bank.scatter(
+            np.asarray([bank.rows[keys[i]] for i in rows], dtype=np.int32),
+            {"_F0": vals})
+        ident = {"sum": 0, "min": np.iinfo(np.int32).max,
+                 "max": np.iinfo(np.int32).min}[op]
+        want = np.full(r, ident, dtype=np.int64)
+        getattr(np, {"sum": "add", "min": "minimum", "max": "maximum"}[op]
+                ).at(want, rows, vals)
+        got = bank.flush()
+        assert [got[k]["_F0"] for k in keys] == want.tolist()
+
+    def test_collision_stress_all_events_one_key(self):
+        """The scatter's worst case: every event on ONE row."""
+        from siddhi_tpu.aggregation.device_bank import DeviceBucketBank
+        from siddhi_tpu.aggregation.runtime import BaseField
+        from siddhi_tpu.query_api import AttrType
+        import numpy as np
+
+        fields = [
+            BaseField("_SUM0", "sum", None, AttrType.LONG),
+            BaseField("_MIN1", "min", None, AttrType.LONG),
+            BaseField("_MAX2", "max", None, AttrType.LONG),
+            BaseField("_SUM3", "sum", None, AttrType.DOUBLE),
+        ]
+        rng = np.random.default_rng(13)
+        n = 2048
+        fvals = {
+            # sums ride the 16-bit hi/lo split: keep 2048 summands small
+            # enough that the int32 hi lane cannot overflow
+            "_SUM0": rng.integers(-(2**20), 2**20, n),
+            "_MIN1": rng.integers(-(2**60), 2**60, n),
+            "_MAX2": rng.integers(-(2**60), 2**60, n),
+            # integer-valued floats: f32 sum reassociation cannot bite
+            "_SUM3": rng.integers(0, 100, n).astype(np.float64),
+        }
+        bank = DeviceBucketBank(fields, cap=8)
+        assert bank.assign([(0, ())])
+        bank.scatter(np.full(n, bank.rows[(0, ())], dtype=np.int32), fvals)
+        out = bank.flush()[(0, ())]
+        assert out["_SUM0"] == int(fvals["_SUM0"].sum())
+        assert out["_MIN1"] == int(fvals["_MIN1"].min())
+        assert out["_MAX2"] == int(fvals["_MAX2"].max())
+        assert out["_SUM3"] == float(fvals["_SUM3"].sum())
+
+
+class TestLongExtremaDeviceBank:
+    """LONG min/max ride the bank as lexicographic hi/lo int32 pairs —
+    the signed 64-bit compare must be exact at full width."""
+
+    def test_unit_differential_negative_heavy(self):
+        from siddhi_tpu.aggregation.device_bank import DeviceBucketBank
+        from siddhi_tpu.aggregation.runtime import BaseField
+        from siddhi_tpu.query_api import AttrType
+        import numpy as np
+
+        fields = [BaseField("_MIN0", "min", None, AttrType.LONG),
+                  BaseField("_MAX1", "max", None, AttrType.LONG)]
+        bank = DeviceBucketBank(fields, cap=16)
+        rng = np.random.default_rng(17)
+        keys = [(0, ("a",)), (0, ("b",)), (1, ("a",))]
+        assert bank.assign(keys)
+        ref = {k: [None, None] for k in keys}
+        for _batch in range(3):
+            n = 200
+            ks = rng.integers(0, len(keys), n)
+            # negative-heavy incl. values whose hi word ties but lo
+            # differs (the lexicographic second pass must decide)
+            v = rng.integers(-(2**62), 2**20, n)
+            v[::7] = -(2**62) + rng.integers(0, 3, len(v[::7]))
+            rows = np.asarray([bank.rows[keys[k]] for k in ks],
+                              dtype=np.int32)
+            bank.scatter(rows, {"_MIN0": v, "_MAX1": v.copy()})
+            for k, x in zip(ks, v):
+                cur = ref[keys[k]]
+                cur[0] = int(x) if cur[0] is None else min(cur[0], int(x))
+                cur[1] = int(x) if cur[1] is None else max(cur[1], int(x))
+        got = bank.flush()
+        for k in keys:
+            assert got[k]["_MIN0"] == ref[k][0], (k, got[k], ref[k])
+            assert got[k]["_MAX1"] == ref[k][1], (k, got[k], ref[k])
+
+    APP = (
+        "{mode}@app:playback "
+        "define stream S (sym string, v long, ts long); "
+        "define aggregation A from S select sym, min(v) as lo, "
+        "max(v) as hi group by sym aggregate by ts every sec...min;"
+    )
+
+    def _run(self, manager, mode, vals, probe=False):
+        import numpy as np
+
+        rt = manager.create_siddhi_app_runtime(self.APP.format(mode=mode))
+        rt.start()
+        agg = rt.aggregations["A"]
+        rng = np.random.default_rng(11)
+        n = len(vals)
+        ts = np.sort(BASE + rng.integers(0, 5_000, n)).astype(np.int64)
+        h = rt.get_input_handler("S")
+        for j in range(n):
+            h.send([f"s{int(rng.integers(0, 6))}", int(vals[j]), int(ts[j])])
+        if probe:
+            assert agg._bank is not None, "LONG extrema did not bank"
+            assert agg._bank.scatters > 0
+            # extrema pairs are excluded from the sum-overflow guard
+            assert not agg._bank.long_names
+        out = rt.query(
+            f"from A within {BASE - 1000}, {BASE + 100_000} per 'seconds' "
+            "select sym, lo, hi;")
+        rt.shutdown()
+        return sorted([list(e.data) for e in out], key=lambda r: r[0])
+
+    @pytest.mark.parametrize("seed,low,high", [
+        (3, -(2**40), 2**40),
+        # every value negative, down to -(2**62)
+        (5, -(2**62), -1),
+    ], ids=["mixed", "negative_heavy"])
+    def test_app_level_exact_vs_host(self, manager, seed, low, high):
+        import numpy as np
+
+        vals = np.random.default_rng(seed).integers(low, high, 300)
+        host = self._run(manager, "", vals)
+        m2 = SiddhiManager()
+        try:
+            dev = self._run(m2, "@app:execution('tpu') ", vals, probe=True)
+        finally:
+            m2.shutdown()
+        assert len(host) == len(dev) > 0
+        assert host == dev, (host[:3], dev[:3])

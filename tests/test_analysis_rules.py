@@ -305,7 +305,7 @@ def test_fallback_discipline_fires_when_not_logged():
             try:
                 lower(name)
             except SiddhiAppCreationError as e:
-                sm.record_kernel_fallback(name, str(e))
+                sm.record_hotkey_fallback(name, str(e))
     """)
     assert [f.scope for f in hits] == ["plan"]
     assert "no log.warning" in hits[0].message
@@ -319,7 +319,7 @@ def test_fallback_discipline_quiet_when_counted_and_logged_or_reraised():
                 lower(name)
             except SiddhiAppCreationError as e:
                 log.warning("query '%s': fallback (%s)", name, e)
-                sm.record_kernel_fallback(name, str(e))
+                sm.record_hotkey_fallback(name, str(e))
     """
     reraise = """
         from siddhi_tpu.core.exceptions import SiddhiAppCreationError

@@ -95,6 +95,18 @@ class TestDensePath:
         m2.shutdown()
         assert dense == host == [[150.0, 200.0], [200.0, 300.0]]
 
+    @pytest.mark.parametrize("ann", ["kernels('nfa')", "nosuchoption('x')"])
+    def test_an_app_annotation_the_planner_does_not_know_is_ignored(
+            self, manager, ann):
+        # the planner looks `@app:` annotations up by name and lists
+        # none it does not know: a text that carries one (a removed
+        # option, a misspelling) builds, lowers and answers as the text
+        # without it
+        rt, got = run_app(
+            manager, f"@app:{ann} " + TPU + PATTERN_APP, SENDS)
+        assert rt.lowering() == {"q": "dense"}
+        assert got == [[150.0, 200.0], [200.0, 300.0]]
+
     def test_fallback_on_long_filter_operand(self, manager):
         # LONG filter comparisons ride the bit-exact hi/lo int32 pair
         # bank — values one apart above 2^24 (where float32 would

@@ -189,6 +189,12 @@ def test_skewed_batch_equals_one_event_at_a_time(eng_name, n_dev):
     check_against_one_event_at_a_time(eng_name, n_dev, "skewed")
 
 
+def test_kernels_are_interpreted_off_tpu():
+    from siddhi_tpu.kernels import probe
+
+    assert probe.interpret_mode()  # tests are CPU-only by contract
+
+
 @pytest.fixture
 def run_kernel(monkeypatch):
     """The Pallas kernel for the run, interpreted: off a TPU the engine

@@ -7,7 +7,6 @@ entry point must work in-process here.
 """
 
 import numpy as np
-import pytest
 
 
 def test_dryrun_multichip_8():
@@ -49,25 +48,3 @@ def test_sharded_engine_init_is_host_only(monkeypatch):
     sharded = ShardedPatternEngine(eng, mesh)
     state = sharded.init_state()
     assert state["rows"].shape[0] == 8 * (64 + 1)
-
-
-def test_chip_smoke_refuses_without_a_tpu(capsys):
-    import chip_smoke
-
-    assert chip_smoke.main([]) != 0
-    assert capsys.readouterr().out == ""  # no result line, nothing run
-
-
-def test_chip_smoke_rehearsal(capsys):
-    """The chip smoke's own logic at tiny sizes on the CPU platform —
-    every phase, the four-device one included (8 virtual devices)."""
-    import json
-
-    import chip_smoke
-
-    assert chip_smoke.main(["--rehearsal"]) == 0
-    out = capsys.readouterr().out
-    assert "[multichip[4]] matches equal the one-chip run" in out
-    result = json.loads(out.strip().splitlines()[-1])
-    assert result["rehearsal"] is True and result["ok"] is True
-    assert result["device"]["platform"] == "cpu"

@@ -15,9 +15,6 @@ The contract under test, end to end:
   counted, never attempted.
 - Circuit breakers on sinks spool output while open (bounded) and
   flush exactly once on close — no duplicates, order preserved.
-- The degradation ladder demotes lowerings in the documented order
-  under sustained pressure and re-promotes under hysteresis, each rung
-  a counted bit-exact replan.
 - ``GET /siddhi-health/<app>`` reports the same counters the
   statistics feed carries; overloaded apps answer 503 with a JSON body
   instead of blocking on the app lock.
@@ -25,7 +22,6 @@ The contract under test, end to end:
 """
 
 import time
-import types
 import urllib.error
 import urllib.request
 
@@ -40,13 +36,7 @@ from siddhi_tpu.core.exceptions import (
     SiddhiAppCreationError,
     SimulatedCrashError,
 )
-from siddhi_tpu.robustness import (
-    DEMOTE_ORDER,
-    DegradationLadder,
-    RobustnessStats,
-    TokenBucket,
-    apply_degradation,
-)
+from siddhi_tpu.robustness import RobustnessStats, TokenBucket
 
 
 def _collector(res):
@@ -109,7 +99,6 @@ class TestLimitsAnnotation:
         ("@app:limits(burst='5')", "burst needs rate"),
         ("@app:limits(rate='0/s')", "positive"),
         ("@app:limits(rate='5/s', shed='weird')", "drop, oldest, block"),
-        ("@app:limits(ladder='true')", "needs watchdog"),
         ("@app:limits(rate='5/s', burst='0')", "burst"),
         ("@app:limits(breaker='0')", "breaker"),
     ])
@@ -119,6 +108,29 @@ class TestLimitsAnnotation:
             with pytest.raises(SiddhiAppCreationError, match=msg):
                 m.create_siddhi_app_runtime(
                     ann + " define stream S (k long);")
+        finally:
+            m.shutdown()
+
+    def test_an_element_the_annotation_does_not_know_is_ignored(self):
+        # a key @app:limits never looks up (a removed element, a
+        # misspelling) is read by nothing: alone it is still "none of
+        # rate, watchdog, breaker", beside a known one it changes nothing
+        m = SiddhiManager()
+        try:
+            with pytest.raises(SiddhiAppCreationError, match="at least one"):
+                m.create_siddhi_app_runtime(
+                    "@app:limits(ladder='true') define stream S (k long);")
+            rt = m.create_siddhi_app_runtime(
+                "@app:limits(watchdog='60 sec', ladder='true', nosuch='1') "
+                "define stream S (k long);")
+            rt.start()
+            doc = rt.health()
+            assert doc["watchdog"]["deadline_ms"] == 60_000
+            assert set(doc) == {
+                "app", "healthy", "running", "shedding", "wedged",
+                "admission", "breakers", "watchdog", "counters"}
+            assert not any("ladder" in k for k in doc["counters"])
+            rt.shutdown()
         finally:
             m.shutdown()
 
@@ -733,125 +745,6 @@ class TestRetryShutdownRace:
             rt.shutdown()
         finally:
             m.shutdown()
-
-
-class TestDegradationLadder:
-    def _fake_runtime(self, **flags):
-        attrs = dict(name="fake", degrade_level=0, plan_pins={},
-                     statistics_manager=None, kernels=False,
-                     devtables=False, fuse=False)
-        attrs.update(flags)
-        ctx = types.SimpleNamespace(**attrs)
-        rt = types.SimpleNamespace(app_context=ctx, replans=[])
-        rt.replan = lambda pins, forced=True, reason="": \
-            rt.replans.append((dict(pins), reason))
-        return rt
-
-    def test_apply_degradation_demotes_in_documented_order(self):
-        ctx = types.SimpleNamespace(kernels=True, devtables=True,
-                                    fuse=True)
-        assert apply_degradation(ctx, 2) == ["kernels", "devtables"]
-        assert (ctx.kernels, ctx.devtables, ctx.fuse) == \
-            (False, False, True)
-        # only ENABLED features count as rungs
-        ctx2 = types.SimpleNamespace(kernels=False, devtables=False,
-                                     fuse=True)
-        assert apply_degradation(ctx2, 1) == ["fuse"]
-        assert DEMOTE_ORDER == ("kernels", "devtables", "fuse")
-
-    def test_hysteresis_demote_then_promote(self):
-        rt = self._fake_runtime(fuse=True)
-        ladder = DegradationLadder(rt, RobustnessStats(), dwell=3)
-        assert ladder.features == ["fuse"]
-        assert not ladder.observe(1.0) and not ladder.observe(1.0)
-        assert ladder.observe(1.0)            # 3rd hot tick: demote
-        assert ladder.level == 1
-        assert ladder.stats.ladder_demotions == 1
-        # mid-band pressure resets BOTH streaks (no flip-flop)
-        ladder.observe(0.5)
-        for _ in range(5):
-            assert not ladder.observe(0.0)
-        assert ladder.observe(0.0)            # 6th cool tick: promote
-        assert ladder.level == 0
-        assert ladder.stats.ladder_promotions == 1
-        assert len(rt.replans) == 2
-
-    def test_rungs_survive_a_degraded_rebuild(self):
-        """A context rebuilt at level 1 reads ``fuse=False`` — the
-        ``degraded_features`` record is what keeps the consumed rung on
-        the rebuilt ladder's list so it can still re-promote."""
-        rt = self._fake_runtime(fuse=False, degrade_level=1,
-                                degraded_features=("fuse",))
-        ladder = DegradationLadder(rt, RobustnessStats(), dwell=1)
-        assert ladder.features == ["fuse"] and ladder.level == 1
-        assert not ladder.observe(0.0)
-        assert ladder.observe(0.0)            # 2*dwell cool: promote
-        assert rt.replans and rt.app_context.degrade_level == 0
-
-    def test_zero_rung_ladder_is_inert(self):
-        rt = self._fake_runtime()
-        ladder = DegradationLadder(rt, RobustnessStats())
-        for _ in range(20):
-            assert not ladder.observe(1.0)
-        assert rt.replans == []
-
-    def test_real_demote_and_promote_stay_bit_identical(self):
-        """Integration: the ladder's forced replans ride the same
-        restore-and-replay protocol — fused → device → fused mid-stream
-        with outputs identical to an uninterrupted run."""
-        app = """
-@app:name('ld{tag}') @app:playback @app:execution('tpu') @app:fuse
-@app:faults(journal='8192')
-{limits}
-define stream SIn (sym int, price float, vol int);
-@info(name='q1') from SIn[price > 10.0]
-select sym, price, vol insert into Mid;
-@info(name='q2') from Mid[vol > 50] select sym, price insert into Out;
-"""
-        rng = np.random.default_rng(7)
-        sends = [([int(rng.integers(0, 5)),
-                   float(np.float32(rng.uniform(0, 30))),
-                   int(rng.integers(1, 100))], 1000 + 3 * i)
-                 for i in range(300)]
-
-        def run(tag, limits, steps=None):
-            m = SiddhiManager()
-            try:
-                rt = m.create_siddhi_app_runtime(
-                    app.format(tag=tag, limits=limits))
-                got = []
-                rt.add_callback("Out", _collector(got))
-                rt.start()
-                h = rt.get_input_handler("SIn")
-                lows = []
-                for i, (row, ts) in enumerate(sends):
-                    if steps and i in steps:
-                        ladder = rt._ladder
-                        assert ladder is not None
-                        pressure, ticks = steps[i]
-                        for _ in range(ticks):
-                            ladder.observe(pressure)
-                        lows.append(dict(rt.lowering()))
-                        h = rt.get_input_handler("SIn")
-                    h.send(list(row), timestamp=ts)
-                rb = rt.app_context.robustness
-                rt.shutdown()
-                return got, lows, rb
-            finally:
-                m.shutdown()
-
-        ref, _, _ = run("r", "")
-        # watchdog interval 15s: its own ticks never interfere here
-        limits = "@app:limits(watchdog='60 sec', ladder='true')"
-        got, lows, rb = run("s", limits, steps={
-            100: (1.0, 3),   # 3 hot ticks -> demote fuse
-            200: (0.0, 6),   # 6 cool ticks -> promote back
-        })
-        assert lows == [{"q1": "device", "q2": "device"},
-                        {"q1": "fused", "q2": "fused"}]
-        assert rb.ladder_demotions == 1 and rb.ladder_promotions == 1
-        assert len(ref) > 0
-        assert got == ref
 
 
 class TestHealthEndpoint:
