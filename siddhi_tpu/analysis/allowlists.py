@@ -49,6 +49,10 @@ ALLOWLISTS = {
             "barrier: idle purge, behind drain()",
         f"{_DP}:DensePatternRuntime.on_time":
             "barrier: timer step, behind drain()",
+        f"{_DP}:DensePatternRuntime.snapshot":
+            "barrier: snapshot path, behind drain(): the state's fetch, "
+            "made here so that it has a span of its own (persist.fetch) "
+            "apart from DenseStateLayout.unpack's copies",
         f"{_DP}:DensePatternRuntime.restore":
             "barrier: restore path, behind drain()",
         f"{_DQ}:_split_i64":

@@ -8,6 +8,7 @@ their subsystems.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Dict, List, Optional, Union
 
 from siddhi_tpu.core.context import SiddhiAppContext
@@ -20,6 +21,11 @@ from siddhi_tpu.core.stream import (
     QueryCallback,
     StreamCallback,
     StreamJunction,
+)
+from siddhi_tpu.observability.trace import (
+    STAGE_PERSIST_CAPTURE,
+    STAGE_PERSIST_DRAIN,
+    span,
 )
 
 
@@ -246,19 +252,32 @@ class SiddhiAppRuntime:
         self._playback_thread = t
         t.start()
 
-    def _start_persist_daemon(self):
+    def _start_persist_daemon(self, clock=None, wait=None):
         """@app:persist(interval, mode): periodic checkpoint daemon — a
-        persist() every interval, in the annotation's mode (async by
-        default, so the loop only stalls for the in-barrier capture)."""
+        persist() at a fixed rate from ``start()``, in the annotation's
+        mode: tick ``k`` is due at ``start + k * interval`` whatever the
+        persists before it took (upstream schedules at a fixed rate; a
+        period of interval plus stall would drift with the stall).  A
+        tick that comes due while the last persist is still inside its
+        call, its capture under the barrier, is skipped and counted
+        (``Durability.<app>.persist_ticks_skipped``).  ``clock`` and
+        ``wait`` are ``time.monotonic`` and the stop event's ``wait``
+        unless a test hands in its own."""
         import logging
         import threading
+        import time as _time
 
         log = logging.getLogger("siddhi_tpu")
         interval_s = self.app_context.persist_interval_ms / 1000.0
         stop = threading.Event()
+        clock = clock or _time.monotonic
+        wait = wait or stop.wait
+        stats = self._durability_stats()
+        t_start = clock()
 
         def loop():
-            while not stop.wait(interval_s):
+            k = 1
+            while not wait(max(0.0, t_start + k * interval_s - clock())):
                 try:
                     self.persist()
                 except Exception as e:
@@ -275,6 +294,9 @@ class SiddhiAppRuntime:
                     log.error("app '%s': persist daemon stopped: %s",
                               self.name, e)
                     break
+                due = max(k + 1, int((clock() - t_start) / interval_s) + 1)
+                stats.persist_ticks_skipped += due - (k + 1)
+                k = due
 
         t = threading.Thread(target=loop, name=f"persist-{self.name}",
                              daemon=True)
@@ -784,13 +806,22 @@ class SiddhiAppRuntime:
         return svc
 
     def _persistence_store(self):
+        """The app's own store (``@app:persist(location=...)``) or the
+        manager's; never both, so there is no precedence to know."""
         from siddhi_tpu.core.exceptions import NoPersistenceStoreError
 
+        own = self.app_context.persistence_store
         store = getattr(self.app_context.siddhi_context, "persistence_store", None)
+        if own is not None and store is not None:
+            raise SiddhiAppRuntimeError(
+                f"app '{self.name}': @app:persist names a location and the "
+                "manager has a persistence store; an app has one store")
+        store = own if own is not None else store
         if store is None:
             raise NoPersistenceStoreError(
                 f"app '{self.name}': no persistence store configured "
-                "(SiddhiManager.set_persistence_store)"
+                "(@app:persist(location='...') or "
+                "SiddhiManager.set_persistence_store)"
             )
         return store
 
@@ -846,7 +877,7 @@ class SiddhiAppRuntime:
             blobs = capture.materialize_blobs()
             store.save_tree(self.name, revision, blobs,
                             checker=fi.check if fi is not None else None,
-                            version=capture.version)
+                            version=capture.version, clock=capture.clock)
             st.blobs_written += len(blobs)
             st.bytes_written += sum(len(b) for _, _, b in blobs)
         else:
@@ -918,18 +949,22 @@ class SiddhiAppRuntime:
         # (reference: SiddhiAppRuntimeImpl.persist:677-691 pauses sources)
         for s in self.sources:
             s.pause()
-        # barrier: queued device emits must land in downstream state
-        # (selectors, windows, tables) before it is captured
-        self.drain_device_emits()
         tracer = self.app_context.tracer
         try:
-            t_cap = tracer.clock() if tracer is not None else 0.0
-            capture = svc.capture(on_fallback=on_fallback)
-            if tracer is not None:
-                # the in-barrier capture is THE persist-path stall the
-                # batch loop feels — span it like a pipeline stage
-                tracer.record_span("persist.capture", "persist",
-                                   t_cap, tracer.clock())
+            # the barrier: the app's process lock from the drain to the
+            # end of the capture.  A batch sent from another thread
+            # waits here; one that slipped in between a drain outside
+            # the lock and the capture would have its rows delivered
+            # into elements captured before its own engine's state
+            with self.app_context.process_lock, (
+                    tracer.free_span(STAGE_PERSIST_CAPTURE, "persist")
+                    if tracer is not None else contextlib.nullcontext()):
+                # queued device emits must land in downstream state
+                # (selectors, windows, tables) before it is captured
+                with span(STAGE_PERSIST_DRAIN):
+                    self.drain_device_emits()
+                capture = svc.capture(on_fallback=on_fallback)
+            st.persist_fetch_bytes += capture.fetched_bytes
             if jr is not None:
                 # watermark + ledger counts at the capture point; the
                 # prune happens at commit, AFTER the store write lands
@@ -1100,6 +1135,13 @@ class SiddhiAppRuntime:
         raise CannotRestoreSiddhiAppStateError(
             f"app '{self.name}': all {len(revs)} persisted revisions "
             f"failed to restore (last error: {last_error})")
+
+    def applied_time(self) -> int:
+        """The app's clock as its snapshots record it: under
+        @app:playback the event time of the last batch the state has
+        applied; after a restore, the restored revision's.  A process
+        that recovers sends the events after it."""
+        return self.app_context.applied_time()
 
     def clear_all_revisions(self):
         self._flush_persists()

@@ -199,6 +199,17 @@ class SiddhiAppContext:
         # daemon interval (0 = no daemon).
         self.persist_mode = "sync"
         self.persist_interval_ms = 0
+        # @app:persist(location='...', revisions.to.keep='N'): the app's
+        # own durable store (durability/store.py ``open_store``), for a
+        # deployment whose text has to name it; None = the manager's
+        self.persistence_store = None
+        # event time of the newest batch the state has applied (set
+        # under the process lock, after the junction took the batch):
+        # what a snapshot's ``clock`` says under @app:playback.  The
+        # timestamp generator runs ahead of it: ``send_batch`` advances
+        # that before it takes the lock, so a capture that wins the
+        # lock would read a clock one batch ahead of the state.
+        self.applied_event_time = -1
         # @app:limits(rate='N/s', burst='M', shed='drop|oldest|block',
         # block.max='1 sec', watchdog='2 sec', breaker='3',
         # breaker.cooldown='1 sec'): overload protection (robustness/).
@@ -244,6 +255,28 @@ class SiddhiAppContext:
         # InputJournal); shared through siddhi_context.input_journals so
         # it outlives a crashed runtime.  None = journaling disabled.
         self.input_journal = None
+
+    def applied(self, event_time: int) -> None:
+        """A batch whose newest event carries ``event_time`` has been
+        applied (the caller holds the process lock)."""
+        if event_time > self.applied_event_time:
+            self.applied_event_time = event_time
+
+    def applied_time(self) -> int:
+        """The app's clock as a snapshot records it: under
+        @app:playback the event time of the last batch the state has
+        applied (-1 before the first), else the wall clock."""
+        if self.playback:
+            return self.applied_event_time
+        return self.timestamp_generator.current_time()
+
+    def restore_time(self, clock) -> None:
+        """Set the clock a restored tree carries (None: a tree from
+        before it was kept).  Under @app:playback event time goes back
+        to it, or forward: the state is the revision's, so is its time."""
+        if clock is None or not self.playback:
+            return
+        self.applied_event_time = self.timestamp_generator._event_time = clock
 
     def set_playback(self, enabled: bool, increment_ms: int = 0):
         self.playback = enabled

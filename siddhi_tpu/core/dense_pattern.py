@@ -45,9 +45,12 @@ from siddhi_tpu.core.exceptions import (
     SiddhiAppRuntimeError,
 )
 from siddhi_tpu.core.key_index import index_for
+from siddhi_tpu.durability.capture import note_fetched
 from siddhi_tpu.observability.trace import (
     STAGE_CONVERT,
     STAGE_INTERN,
+    STAGE_PERSIST_FETCH,
+    STAGE_PERSIST_UNPACK,
     STAGE_POLL,
     span,
 )
@@ -666,11 +669,21 @@ class DensePatternRuntime:
     def snapshot(self) -> Dict:
         self.drain()
         self._check_overflow()
+        # the whole state crosses to the host here, and is unpacked
+        # here: under the caller's barrier where a persist calls
+        with span(STAGE_PERSIST_FETCH) as sp:
+            host = {k: np.asarray(v) for k, v in self.state.items()}
+            fetched = sum(a.nbytes for a in host.values())
+            note_fetched(fetched)
+            if sp is not None:
+                sp.count = fetched
+        with span(STAGE_PERSIST_UNPACK):
+            logical = self.engine.layout.unpack(host)
         return {
             # the LOGICAL form ([rows, S, I] / [rows, S, I, R] arrays
             # per field): checkpoints do not depend on the resident
             # layout (ops/dense_layout.py)
-            "dense_state": self.engine.layout.unpack(self.state),
+            "dense_state": logical,
             "base_ts": self.engine.base_ts,
             "key_rows": dict(self._key_rows),
             "next_row": self._next_row,

@@ -296,13 +296,15 @@ class InputHandler:
             if scheduler is not None:
                 scheduler.advance(tsgen.current_time())
             self.junction.send(batch)
+            self.app_context.applied(max(e.timestamp for e in events))
 
     def send_batch(self, batch: EventBatch):
         self._check_running()
+        newest = -1
         if len(batch):
             # event time is monotone-max; one update per batch suffices
-            self.app_context.timestamp_generator.set_event_time(
-                int(batch.timestamps.max()))
+            newest = int(batch.timestamps.max())
+            self.app_context.timestamp_generator.set_event_time(newest)
         batch = self._admit(batch)
         if batch is None:
             return
@@ -312,6 +314,7 @@ class InputHandler:
             if scheduler is not None:
                 scheduler.advance(self.app_context.timestamp_generator.current_time())
             self.junction.send(batch)
+            self.app_context.applied(newest)
 
     def _admit(self, batch: EventBatch) -> Optional[EventBatch]:
         """Admission control (@app:limits, robustness/admission.py):

@@ -20,13 +20,20 @@ happen in :meth:`StateCapture.materialize_blobs` on the writer thread.
 
 from __future__ import annotations
 
+import contextlib
 import pickle
+import threading
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from siddhi_tpu.core.event import Event, EventBatch
+from siddhi_tpu.observability.trace import (
+    STAGE_PERSIST_FREEZE,
+    STAGE_PERSIST_PICKLE,
+    span,
+)
 from siddhi_tpu.util.faults import host_copy
 
 
@@ -35,6 +42,31 @@ class UnfreezableStateError(Exception):
 
 
 _SCALARS = (type(None), bool, int, float, complex, str, bytes)
+
+# the calling thread's open tally of bytes its elements' ``snapshot()``
+# fetched from the device while the state tree was built
+_fetched = threading.local()
+
+
+@contextlib.contextmanager
+def fetch_tally():
+    """Open a tally round the walk that builds a state tree; yields a
+    one-item list that ends up holding the bytes :func:`note_fetched`
+    was told of on this thread meanwhile."""
+    found = getattr(_fetched, "tally", None)
+    tally = _fetched.tally = [0]
+    try:
+        yield tally
+    finally:
+        _fetched.tally = found
+
+
+def note_fetched(nbytes: int) -> None:
+    """An engine's ``snapshot()`` fetched ``nbytes`` of device state
+    (core/dense_pattern.py); nothing where no tally is open."""
+    tally = getattr(_fetched, "tally", None)
+    if tally is not None:
+        tally[0] += nbytes
 
 
 def _is_device_array(obj: Any) -> bool:
@@ -122,14 +154,20 @@ class StateCapture:
     the monolithic tree-pickle (plain stores) — both restore through the
     unchanged ``SnapshotService.restore`` path."""
 
-    __slots__ = ("app", "version", "elements", "fallbacks")
+    __slots__ = ("app", "version", "elements", "fallbacks", "clock",
+                 "fetched_bytes")
 
     def __init__(self, app: str, version: int,
                  elements: List[CapturedElement],
-                 fallbacks: List[Tuple[str, str]]):
+                 fallbacks: List[Tuple[str, str]],
+                 clock: Optional[int] = None, fetched_bytes: int = 0):
         self.app = app
         self.version = version
         self.elements = elements
+        # the tree's clock (util/snapshot.py): the app's time at the barrier
+        self.clock = clock
+        # what the elements' snapshots fetched from the device under it
+        self.fetched_bytes = fetched_bytes
         # [(element key, reason)] for elements that took the in-barrier
         # pickle fallback — surfaced as persistFallbackReason
         self.fallbacks = fallbacks
@@ -141,9 +179,12 @@ class StateCapture:
             if el.prepickled is not None:
                 out.append((el.kind, el.name, el.prepickled))
             else:
-                out.append((el.kind, el.name, pickle.dumps(
-                    _materialize(el.state),
-                    protocol=pickle.HIGHEST_PROTOCOL)))
+                with span(STAGE_PERSIST_PICKLE) as sp:
+                    out.append((el.kind, el.name, pickle.dumps(
+                        _materialize(el.state),
+                        protocol=pickle.HIGHEST_PROTOCOL)))
+                    if sp is not None:
+                        sp.count = len(out[-1][2])
         return out
 
     def tree_bytes(self) -> bytes:
@@ -156,6 +197,8 @@ class StateCapture:
         tree: Dict = {"version": self.version, "app": self.app,
                       "queries": {}, "tables": {}, "named_windows": {},
                       "partitions": {}, "aggregations": {}}
+        if self.clock is not None:
+            tree["clock"] = self.clock
         for el in self.elements:
             if el.prepickled is not None:
                 tree[el.kind][el.name] = pickle.loads(el.prepickled)
@@ -167,7 +210,7 @@ class StateCapture:
 def capture_elements(app: str, version: int, tree: Dict,
                      element_kinds: Tuple[str, ...],
                      on_fallback: Optional[Callable[[str, str], None]] = None,
-                     ) -> StateCapture:
+                     fetched_bytes: int = 0) -> StateCapture:
     """Freeze a just-built state tree into a :class:`StateCapture`.
 
     Caller holds the barrier (process lock, sources paused, emits
@@ -179,8 +222,9 @@ def capture_elements(app: str, version: int, tree: Dict,
     for kind in element_kinds:
         for name, state in tree.get(kind, {}).items():
             try:
-                elements.append(CapturedElement(kind, name,
-                                                state=freeze(state)))
+                with span(STAGE_PERSIST_FREEZE):
+                    frozen = freeze(state)
+                elements.append(CapturedElement(kind, name, state=frozen))
             except UnfreezableStateError as e:
                 reason = f"unfreezable:{e}"
                 fallbacks.append((f"{kind}:{name}", reason))
@@ -190,4 +234,5 @@ def capture_elements(app: str, version: int, tree: Dict,
                     kind, name,
                     prepickled=pickle.dumps(
                         state, protocol=pickle.HIGHEST_PROTOCOL)))
-    return StateCapture(app, version, elements, fallbacks)
+    return StateCapture(app, version, elements, fallbacks,
+                        clock=tree.get("clock"), fetched_bytes=fetched_bytes)

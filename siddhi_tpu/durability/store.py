@@ -34,6 +34,11 @@ import shutil
 import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
+from siddhi_tpu.observability.trace import (
+    STAGE_PERSIST_HASH,
+    STAGE_PERSIST_STORE,
+    span,
+)
 from siddhi_tpu.util.persistence import (
     FileJournalSegmentMixin,
     PersistenceStore,
@@ -53,6 +58,26 @@ def _manifest_checksum(manifest: Dict) -> str:
     body = {k: v for k, v in manifest.items() if k != "checksum"}
     canon = json.dumps(body, sort_keys=True, separators=(",", ":"))
     return hashlib.sha256(canon.encode("utf-8")).hexdigest()
+
+
+def open_store(location: Optional[str],
+               revisions_to_keep=None) -> "DurableFileSystemPersistenceStore":
+    """The store ``@app:persist(location='...', revisions.to.keep='N')``
+    names: the durable one, under ``location``.  The planner builds an
+    app's store through this, and so does a process that recovers one:
+    the two then agree on the layout.  ``ValueError`` on a bad value."""
+    if not location or not str(location).strip():
+        raise ValueError("location must name a directory")
+    if revisions_to_keep is None:
+        return DurableFileSystemPersistenceStore(str(location))
+    try:
+        keep = int(revisions_to_keep)
+    except (TypeError, ValueError):
+        keep = 0
+    if keep < 1:
+        raise ValueError(f"revisions.to.keep {revisions_to_keep!r} must be "
+                         "a whole number of 1 or more")
+    return DurableFileSystemPersistenceStore(str(location), keep)
 
 
 class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
@@ -75,10 +100,12 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
     def save_tree(self, app_name: str, revision: str,
                   blobs: List[Tuple[str, str, bytes]],
                   checker: Optional[Callable[[str], None]] = None,
-                  version: int = 1):
+                  version: int = 1, clock: Optional[int] = None):
         """Write per-element ``blobs`` [(kind, name, bytes)] and commit
         the revision by atomically publishing its manifest.  Idempotent:
-        a retry after a partial failure overwrites and re-commits."""
+        a retry after a partial failure overwrites and re-commits.
+        ``clock`` is the tree's (util/snapshot.py): the app's time at
+        the barrier, kept in the manifest as ``version`` is."""
         with self._lock:
             rev_dir = self._rev_dir(app_name, revision)
             os.makedirs(rev_dir, exist_ok=True)
@@ -86,20 +113,24 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
             for idx, (kind, name, data) in enumerate(blobs):
                 fname = f"{idx:04d}.blob"
                 path = os.path.join(rev_dir, fname)
-                with open(path, "wb") as f:
-                    f.write(data)
-                    f.flush()
-                    os.fsync(f.fileno())
+                with span(STAGE_PERSIST_STORE, len(data)):
+                    with open(path, "wb") as f:
+                        f.write(data)
+                        f.flush()
+                        os.fsync(f.fileno())
+                with span(STAGE_PERSIST_HASH, len(data)):
+                    digest = hashlib.sha256(data).hexdigest()
                 elements.append({
                     "kind": kind, "name": name, "file": fname,
-                    "sha256": hashlib.sha256(data).hexdigest(),
-                    "size": len(data),
+                    "sha256": digest, "size": len(data),
                 })
             if checker is not None:
                 checker("persist.post_blob")
             manifest = {"format": MANIFEST_FORMAT, "app": app_name,
                         "revision": revision, "version": version,
                         "elements": elements}
+            if clock is not None:
+                manifest["clock"] = clock
             manifest["checksum"] = _manifest_checksum(manifest)
             if checker is not None:
                 checker("persist.pre_manifest")
@@ -210,9 +241,11 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
             log.warning("durability: revision %r of app %r holds an "
                         "unreadable element (%s)", revision, app_name, e)
             return None
-        manifest = self._read_manifest(app_name, revision)
-        tree["version"] = manifest.get("version", 1) if manifest else 1
+        manifest = self._read_manifest(app_name, revision) or {}
+        tree["version"] = manifest.get("version", 1)
         tree["app"] = app_name
+        if "clock" in manifest:
+            tree["clock"] = manifest["clock"]
         return pickle.dumps(tree, protocol=pickle.HIGHEST_PROTOCOL)
 
     # -- revisions ----------------------------------------------------------

@@ -22,6 +22,7 @@ marks stay put, and recovery goes through
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -32,6 +33,7 @@ from siddhi_tpu.core.exceptions import (
     SimulatedCrashError,
     TransferFaultError,
 )
+from siddhi_tpu.observability.trace import STAGE_PERSIST_WRITE
 from siddhi_tpu.util.faults import (
     DEFAULT_TRANSFER_RETRY_ATTEMPTS,
     DEFAULT_TRANSFER_RETRY_SCALE,
@@ -60,6 +62,11 @@ class DurabilityStats:
         "capture_fallback_elements",
         "blobs_written",
         "bytes_written",
+        # ticks of the @app:persist daemon that came due while the last
+        # persist was still inside its call (core/app_runtime.py)
+        "persist_ticks_skipped",
+        # bytes of device state the captures fetched under the barrier
+        "persist_fetch_bytes",
     )
 
     def __init__(self) -> None:
@@ -229,13 +236,11 @@ class AsyncCheckpointWriter:
             try:
                 if fi is not None:
                     fi.check("persist.write")
-                t_job = tracer.clock() if tracer is not None else 0.0
-                job()
-                if tracer is not None:
-                    # one span per successful store write — retries that
-                    # failed are visible as the counters, not as spans
-                    tracer.record_span("persist.write", "persist",
-                                       t_job, tracer.clock())
+                # one span per successful store write — retries that
+                # failed are visible as the counters, not as spans
+                with (tracer.free_span(STAGE_PERSIST_WRITE, "persist")
+                      if tracer is not None else contextlib.nullcontext()):
+                    job()
                 with self._lock:
                     self._results[revision] = "committed"
                     self.stats.persist_commits += 1

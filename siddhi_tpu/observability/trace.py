@@ -61,9 +61,12 @@ host plane beside the device's operations (``step`` appears as
 device side of the same trace.
 
 Free-running ``persist.capture`` / ``persist.write`` spans from the
-checkpoint path (``record_span``) draw ids from the same counter, so a
-capture and its async write stay ordered against the batch cycles
-around them.
+checkpoint path (``Tracer.free_span``) draw ids from the same counter,
+so a capture and its async write stay ordered against the batch cycles
+around them.  Each is the open cycle of its own thread while it lasts,
+so what the checkpoint does inside it (the drain, the state's fetch and
+unpack, the freeze; the pickle, the hash, the store's write) records
+its child spans through :func:`span`, as a batch's stages do.
 
 Everything here is host-side bookkeeping OUTSIDE jit: a span is a
 six-tuple appended to the flight recorder's deque (GIL-atomic) plus a
@@ -81,6 +84,7 @@ is the whole default-on cost.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -129,15 +133,31 @@ SPANS_PER_CYCLE = len(CYCLE_STAGES) + 2 + len(ROUND_STAGES)
 #: checkpoint-path stages (free-running, engine kind 'persist')
 STAGE_PERSIST_CAPTURE = "persist.capture"
 STAGE_PERSIST_WRITE = "persist.write"
+#: inside ``persist.capture``, under the barrier: the emit drain, the
+#: device state's fetch (count: bytes), its unpack into the logical form
+#: (core/dense_pattern.py ``snapshot``), the copy of every element
+#: (durability/capture.py ``freeze``)
+STAGE_PERSIST_DRAIN = "persist.drain"
+STAGE_PERSIST_FETCH = "persist.fetch"
+STAGE_PERSIST_UNPACK = "persist.unpack"
+STAGE_PERSIST_FREEZE = "persist.freeze"
+#: inside ``persist.write``, on the writer thread, one a blob (count:
+#: its bytes): the element's pickle, its SHA-256, its write and fsync
+STAGE_PERSIST_PICKLE = "persist.pickle"
+STAGE_PERSIST_HASH = "persist.hash"
+STAGE_PERSIST_STORE = "persist.store"
+PERSIST_STAGES = (
+    STAGE_PERSIST_CAPTURE, STAGE_PERSIST_WRITE, STAGE_PERSIST_DRAIN,
+    STAGE_PERSIST_FETCH, STAGE_PERSIST_UNPACK, STAGE_PERSIST_FREEZE,
+    STAGE_PERSIST_PICKLE, STAGE_PERSIST_HASH, STAGE_PERSIST_STORE)
 #: watchdog self-heal (robustness/watchdog.py): one span per trip,
 #: covering the replan-driven restore-and-replay — recovery time is a
 #: latency distribution like any other stage
 STAGE_WATCHDOG_HEAL = "watchdog.heal"
 
 _STAGES = CYCLE_STAGES + (
-    STAGE_STAGED, STAGE_CYCLE,
-    STAGE_PERSIST_CAPTURE, STAGE_PERSIST_WRITE,
-    STAGE_WATCHDOG_HEAL)
+    STAGE_STAGED, STAGE_CYCLE) + PERSIST_STAGES + (
+    STAGE_WATCHDOG_HEAL,)
 
 #: host spans on the profiler's clock are named ANNOTATION_PREFIX + stage;
 #: the ``step`` stage appears as the blocking part of it, ``step_wait``
@@ -427,11 +447,28 @@ class Tracer:
     def record_span(self, stage: str, engine: str, t_start: float,
                     t_end: float, n_events: int = 0,
                     cycle: Optional[int] = None) -> int:
-        """Free-running span (persist path): allocates its own cycle id
-        from the shared counter unless the caller correlates one."""
+        """Free-running span with no children (the watchdog's heal):
+        allocates its own cycle id from the shared counter unless the
+        caller correlates one."""
         cid = cycle if cycle is not None else next(self._ids)
         self.record(cid, stage, engine, t_start, t_end, n_events)
         return cid
+
+    @contextlib.contextmanager
+    def free_span(self, stage: str, engine: str):
+        """One free-running span (a checkpoint's capture or write) as
+        the calling thread's open cycle: :func:`span` inside the body
+        records its children under the same id.  The span itself is
+        recorded when the body returns; one that raises leaves its
+        children and the counters."""
+        tok = CycleToken(self, next(self._ids), engine, 0, self.clock())
+        found = reopen(tok)
+        try:
+            with annotation(stage):
+                yield tok
+        finally:
+            reopen(found)
+        tok.record(stage, tok.t_begin, self.clock(), 0)
 
     # -- read-out ------------------------------------------------------------
 

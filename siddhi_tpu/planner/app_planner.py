@@ -397,10 +397,36 @@ class AppPlanner:
                 jr.spill_sink = JournalSpillSink(
                     siddhi_context, self.name, self.app_context)
 
-        # @app:persist(interval='30 sec', mode='async'): default persist
-        # mode + optional periodic-checkpoint daemon (durability/)
+        # @app:persist(interval='30 sec', mode='async', location='/var/ckpt',
+        # revisions.to.keep='2'): default persist mode, optional
+        # periodic-checkpoint daemon, and the app's own durable store for
+        # a deployment whose text has to name it (durability/)
         persist_ann = find_annotation(siddhi_app.annotations, "app:persist")
         if persist_ann is not None:
+            location = persist_ann.element("location")
+            keep = persist_ann.element("revisions.to.keep")
+            if location is None and keep is not None:
+                raise SiddhiAppCreationError(
+                    "@app:persist: revisions.to.keep belongs to the store "
+                    "that location names; the manager's store has its own")
+            if location is not None:
+                from siddhi_tpu.durability.store import open_store
+
+                if siddhi_context.persistence_store is not None:
+                    raise SiddhiAppCreationError(
+                        "@app:persist: location names a store and the "
+                        "manager has one (set_persistence_store); an app "
+                        "has one store")
+                if name_ann is None:
+                    raise SiddhiAppCreationError(
+                        "@app:persist: location needs @app:name: the "
+                        "revisions lie under the app's name, and a "
+                        "process that restarts finds them by it")
+                try:
+                    self.app_context.persistence_store = open_store(
+                        location, keep)
+                except ValueError as e:
+                    raise SiddhiAppCreationError(f"@app:persist: {e}") from e
             mode = (persist_ann.element("mode")
                     or persist_ann.element() or "async").lower()
             if mode not in ("sync", "async"):

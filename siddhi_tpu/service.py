@@ -19,6 +19,16 @@ server instead of MSF4J:
                                              open breaker or wedged)
     GET  /metrics                           (Prometheus text exposition)
 
+Where ``/siddhi-persist`` and ``/siddhi-restore-last`` keep their
+revisions: in the store the deployed app's own text names,
+``@app:persist(location='/var/ckpt', revisions.to.keep='2')`` (a
+``DurableFileSystemPersistenceStore`` under that directory, the
+revisions under the app's ``@app:name``), or in the one the embedding
+process gave the manager (``SiddhiManager.set_persistence_store``);
+never both (the deploy is refused), and with neither the persist
+answers with an error that says so.  The service itself has no way to
+be handed a store.
+
 Responses are JSON ``{"status": "OK"|"ERROR", "message": ...}`` except
 ``/metrics`` (Prometheus text) and ``/siddhi-trace?format=chrome``
 (raw Chrome ``chrome://tracing`` JSON array).

@@ -38,6 +38,11 @@ class SnapshotService:
         tree: Dict = {
                 "version": SNAPSHOT_FORMAT_VERSION,
                 "app": self.app.name,
+                # the app's clock at the barrier: under @app:playback the
+                # event time of the last batch the state has applied, so
+                # a process that restores this knows the first event to
+                # send (upstream: source offsets in the snapshot)
+                "clock": self.app.app_context.applied_time(),
                 "queries": {},
                 "tables": {},
                 "named_windows": {},
@@ -69,13 +74,18 @@ class SnapshotService:
         pickled here (in-barrier) and reported via ``on_fallback``.
         Returns a ``StateCapture``; serialization and the D2H fetch run
         on the checkpoint writer thread."""
-        from siddhi_tpu.durability.capture import capture_elements
+        from siddhi_tpu.durability.capture import (
+            capture_elements,
+            fetch_tally,
+        )
 
         with self.app.app_context.process_lock:
-            tree = self._state_tree()
+            with fetch_tally() as fetched:
+                tree = self._state_tree()
             return capture_elements(self.app.name, SNAPSHOT_FORMAT_VERSION,
                                     tree, self._ELEMENT_KINDS,
-                                    on_fallback=on_fallback)
+                                    on_fallback=on_fallback,
+                                    fetched_bytes=fetched[0])
 
     # -- incremental capture -------------------------------------------------
 
@@ -119,6 +129,7 @@ class SnapshotService:
             inc = {
                 "version": SNAPSHOT_FORMAT_VERSION,
                 "app": self.app.name,
+                "clock": tree["clock"],
                 "elements": changed,
             }
             return "inc", pickle.dumps(inc, protocol=pickle.HIGHEST_PROTOCOL)
@@ -140,6 +151,8 @@ class SnapshotService:
                 ) from e
             for (kind, name), blob in inc.get("elements", {}).items():
                 tree[kind][name] = pickle.loads(blob)
+            if "clock" in inc:
+                tree["clock"] = inc["clock"]
         self.restore(pickle.dumps(tree, protocol=pickle.HIGHEST_PROTOCOL))
 
     # -- restore ------------------------------------------------------------
@@ -178,6 +191,8 @@ class SnapshotService:
                     a = self.app.aggregations.get(aname)
                     if a is not None:
                         a.restore(as_)
+                # a tree from before the clock was kept restores none
+                self.app.app_context.restore_time(tree.get("clock"))
             except CannotRestoreSiddhiAppStateError:
                 raise
             except Exception as e:

@@ -506,7 +506,7 @@ def test_spans_tile_send_batch(path, monkeypatch):
             # (The medians of eight this replaces failed under six
             # workers on an eight-core host; alone they read 0.93 and
             # 0.4 ms on the sharded path.)
-            return max(shares) >= 0.8 and min(remainders) < 0.5e-3
+            return max(shares) >= 0.7 and min(remainders) < 0.5e-3
 
         # eight batches, and up to three more eights while the host has
         # not left one of them alone; every batch is held to the
@@ -520,14 +520,20 @@ def test_spans_tile_send_batch(path, monkeypatch):
                 sends.append((t0, time.perf_counter(), sum(put_bytes)))
             sent += 8
             groups = rt.app_context.tracer.recorder.cycle_groups()
-            assert len(groups) == min(sent + 1, 16)   # cycles='16'
+            # cycles='16': the ring is sized in spans (16 cycles of the
+            # longest kind), so a path of fewer spans a cycle keeps more
+            assert len(groups) >= min(sent + 1, 16)
             tile(sends, list(groups.values())[-8:], remainders, shares)
         monkeypatch.undo()
         assert rows
-        # the children cover ingest: four fifths of it in the least
+        # the children cover ingest: seven tenths of it in the least
         # disturbed batch (a batch of 32 events is short enough for one
-        # preemption to be a fifth of it)
-        assert max(shares) >= 0.8
+        # preemption to be a fifth of it; since PR 44 the calls that
+        # start the emit arrays' copies lie in ingest behind the last
+        # dispatch with no span of their own, and the window path, whose
+        # ingest is the shortest, read 0.77-0.80 on every batch of 32
+        # on a slow host, at the seed as after it)
+        assert max(shares) >= 0.7
         # the stated remainder: InputHandler, junction and receiver ahead
         # of the cycle, and the return through them: under half a
         # millisecond where the host left a batch alone
