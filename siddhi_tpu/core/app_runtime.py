@@ -871,19 +871,25 @@ class SiddhiAppRuntime:
     def _persist_write(self, store, revision: str, capture):
         """Serialize + store + commit one captured checkpoint.  Runs on
         the checkpoint writer thread (async) or inline (sync)."""
+        from siddhi_tpu.durability.capture import fetch_tally
+
         fi = self.app_context.fault_injector
         st = self._durability_stats()
-        if hasattr(store, "save_tree"):
-            blobs = capture.materialize_blobs()
-            store.save_tree(self.name, revision, blobs,
-                            checker=fi.check if fi is not None else None,
-                            version=capture.version, clock=capture.clock)
-            st.blobs_written += len(blobs)
-            st.bytes_written += sum(len(b) for _, _, b in blobs)
-        else:
-            data = capture.tree_bytes()
-            store.save(self.name, revision, data)
-            st.bytes_written += len(data)
+        # what the capture kept by reference is fetched in here
+        with fetch_tally() as deferred:
+            if hasattr(store, "save_tree"):
+                blobs = capture.materialize_blobs()
+                written = store.save_tree(
+                    self.name, revision, blobs,
+                    checker=fi.check if fi is not None else None,
+                    version=capture.version, clock=capture.clock)
+                st.blobs_written += len(blobs)
+            else:
+                data = capture.tree_bytes()
+                store.save(self.name, revision, data)
+                written = len(data)
+        st.bytes_written += written
+        st.persist_deferred_bytes += deferred[0]
         if fi is not None:
             # crash point: revision durable, journal mark not committed
             fi.check("persist.post_manifest")
@@ -896,12 +902,25 @@ class SiddhiAppRuntime:
         (reference: SiddhiAppRuntimeImpl.persist:677).  Returns the
         revision id.
 
-        ``mode='sync'`` (historical default) writes inside the call;
-        ``mode='async'`` (or ``@app:persist(mode='async')``) stalls the
-        batch loop only for the in-barrier capture and hands
-        serialization + store write to the checkpoint writer thread
-        (durability/writer.py) with single-in-flight coalescing
-        backpressure.  Incremental stores force the sync path (their
+        Both modes capture under the barrier: the app's process lock
+        from the emit drain to the end of every element's
+        ``snapshot()`` and its freeze (durability/capture.py).  What
+        that costs the batch loop is each engine's: the dense pattern
+        engine dispatches a snapshot program on the device and hands
+        out references (tens of milliseconds at a million
+        partitions); an engine whose ``snapshot()`` hands out numpy
+        holds the barrier for its own fetch and one copy
+        (``Durability.<app>.persist_fetch_bytes`` against
+        ``persist_deferred_bytes`` says which ran).
+
+        ``mode='sync'`` (historical default) then fetches, serializes
+        and writes inside the call; ``mode='async'`` (or
+        ``@app:persist(mode='async')``) hands all of that to the
+        checkpoint writer thread (durability/writer.py) with
+        single-in-flight coalescing backpressure: the wait for the
+        transfer, the store's write and the SHA-256 let the interpreter
+        go, and what is pickled is an element's skeleton, its arrays
+        out of band.  Incremental stores force the sync path (their
         digest chain cannot interleave with background writes) with a
         counted ``persistFallbackReason``."""
         from siddhi_tpu.util.persistence import IncrementalPersistenceStore

@@ -45,12 +45,9 @@ from siddhi_tpu.core.exceptions import (
     SiddhiAppRuntimeError,
 )
 from siddhi_tpu.core.key_index import index_for
-from siddhi_tpu.durability.capture import note_fetched
 from siddhi_tpu.observability.trace import (
     STAGE_CONVERT,
     STAGE_INTERN,
-    STAGE_PERSIST_FETCH,
-    STAGE_PERSIST_UNPACK,
     STAGE_POLL,
     span,
 )
@@ -457,10 +454,13 @@ class DensePatternRuntime:
         of PartitionRuntime's idle-instance purge)."""
         if not self._key_rows:
             return
-        idle = [
-            (k, r) for k, r in self._key_rows.items()
-            if now - int(self._row_last_used[r]) >= idle_ms
-        ]
+        # by row, not in the dict's order: a runtime restored from the
+        # index's vectors holds its keys in another order than the one
+        # that interned them, and must free the same rows in the same order
+        idle = sorted(
+            ((k, r) for k, r in self._key_rows.items()
+             if now - int(self._row_last_used[r]) >= idle_ms),
+            key=lambda kr: kr[1])
         if not idle:
             return
         # barrier: purged keys' pending matches must reach per-key
@@ -669,25 +669,27 @@ class DensePatternRuntime:
     def snapshot(self) -> Dict:
         self.drain()
         self._check_overflow()
-        # the whole state crosses to the host here, and is unpacked
-        # here: under the caller's barrier where a persist calls
-        with span(STAGE_PERSIST_FETCH) as sp:
-            host = {k: np.asarray(v) for k, v in self.state.items()}
-            fetched = sum(a.nbytes for a in host.values())
-            note_fetched(fetched)
-            if sp is not None:
-                sp.count = fetched
-        with span(STAGE_PERSIST_UNPACK):
-            logical = self.engine.layout.unpack(host)
+        # a snapshot on the device: the logical fields ([rows, S, I] /
+        # [rows, S, I, R] per field, so a checkpoint does not depend on
+        # the resident layout, ops/dense_layout.py) in buffers of their
+        # own, their copies to the host started and not waited for.
+        # Whoever holds a barrier round this call holds it for a
+        # dispatch; the bytes arrive for whoever asks np.asarray of a
+        # field (durability/capture.py: the checkpoint writer)
+        logical = self.engine.snapshot_state(self.state)
+        for field in logical.values():
+            field.flat.copy_to_host_async()
+        # the index's two vectors, not a walk of the dict (a million
+        # entries at the flagship's size); the dict form only for keys
+        # that form no array
+        index = self._index
         return {
-            # the LOGICAL form ([rows, S, I] / [rows, S, I, R] arrays
-            # per field): checkpoints do not depend on the resident
-            # layout (ops/dense_layout.py)
             "dense_state": logical,
             "base_ts": self.engine.base_ts,
-            "key_rows": dict(self._key_rows),
+            "key_rows": (dict(self._key_rows) if index is None
+                         else index.items()),
             "next_row": self._next_row,
-            "free_rows": list(self._free_rows),
+            "free_rows": np.asarray(self._free_rows, dtype=np.int32),
             "row_last_used": self._row_last_used.copy(),
         }
 
@@ -720,14 +722,23 @@ class DensePatternRuntime:
         else:
             self.state = {k: jnp.asarray(v) for k, v in physical.items()}
         self.engine.base_ts = state["base_ts"]
-        self._key_rows = dict(state["key_rows"])
+        # either form: a dict (a revision from before the vectors, or
+        # keys that form no array) or the index's (keys, rows)
+        key_rows = state["key_rows"]
+        if isinstance(key_rows, dict):
+            self._key_rows = dict(key_rows)
+            self._rebuild_key_index()
+        else:
+            keys, rows = (np.asarray(a) for a in key_rows)
+            self._key_rows = dict(zip(keys.tolist(), rows.tolist()))
+            self._index = (index_for(keys, rows, self.engine.n_partitions)
+                           if self._vector_intern and len(keys) else None)
         self._row_keys = {r: k for k, r in self._key_rows.items()}
         self._next_row = state.get("next_row", len(self._key_rows))
-        self._free_rows = list(state.get("free_rows", []))
+        self._free_rows = [int(r) for r in state.get("free_rows", ())]
         rlu = state.get("row_last_used")
         if rlu is not None:
             self._row_last_used = np.asarray(rlu).copy()
-        self._rebuild_key_index()
         self._wake_dirty = True
 
     # -- scheduler integration: absent-node deadline timers.  Engines

@@ -36,6 +36,7 @@ from siddhi_tpu.core import event as ev
 from siddhi_tpu.core.device_pipeline import DevicePipeline
 from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.exceptions import SiddhiAppRuntimeError
+from siddhi_tpu.durability.capture import note_fetched
 from siddhi_tpu.observability.trace import STAGE_CONVERT, span
 
 
@@ -196,10 +197,13 @@ class DeviceQueryRuntime:
 
     def snapshot(self) -> Dict:
         self.drain()
-        return {
-            "device_state": {k: np.asarray(v) for k, v in self.state.items()},
-            "host": self.engine.host_snapshot(),
-        }
+        # the state crosses to the host here: under the caller's barrier
+        # where a persist calls (the steps donate it, so a reference
+        # would not outlive the next batch; the dense pattern engine
+        # snapshots on the device instead, core/dense_pattern.py)
+        host = {k: np.asarray(v) for k, v in self.state.items()}
+        note_fetched(sum(a.nbytes for a in host.values()))
+        return {"device_state": host, "host": self.engine.host_snapshot()}
 
     def restore(self, state: Dict):
         self.drain()

@@ -11,14 +11,20 @@ batches a runtime observes (``core/dense_pattern.py`` ``_intern``):
   the sorted array of known keys.
 
 Both are rebuildable caches of the runtime's ``_key_rows`` dict, which
-stays the truth for snapshots and purges.  Neither allocates rows:
+stays the truth for purges; a snapshot takes ``items()``, the known keys
+and their rows as two vectors, and never walks the dict.  Neither
+allocates rows:
 
 - ``lookup(keys)`` -> ``(rows, new_keys, probed)``: the int32 row of
   each lane; the never-seen keys, sorted ascending and unique; the
   lanes that probed past their first slot (0 for the sorted index).  A
   lane whose key is ``new_keys[j]`` holds ``-1 - j`` in ``rows``;
 - the runtime gives ``new_keys`` rows and ``insert(new_keys, rows)``
-  records them (unique keys the index does not hold).
+  records them (unique keys the index does not hold);
+- ``items()`` -> ``(keys, rows)``: every key the index holds, in its
+  dtype, beside its int32 row.  What it returns is never written
+  again (the hash index's log only grows, the sorted index replaces
+  its arrays), so a snapshot may keep it as it is.
 
 Nothing is ever deleted from an index; a purge rebuilds it.
 """
@@ -80,6 +86,17 @@ class HashKeyIndex:
         # that is 64 MB at a million rows
         self._tab = np.empty((1 << bits, 2), dtype=np.int64)
         self._tab.fill(0)
+        # what was inserted, in order: ``items()`` without a pass over
+        # the table's slots (64 MB of them at a million rows)
+        self._n = 0
+        self._keys = np.empty(max(capacity, 1), dtype=np.int64)
+        self._rows = np.empty(max(capacity, 1), dtype=np.int32)
+
+    def items(self) -> Tuple[np.ndarray, np.ndarray]:
+        keys = self._keys[:self._n]
+        if self.dtype.kind == "u":
+            keys = keys.view(np.uint64)
+        return keys.astype(self.dtype, copy=False), self._rows[:self._n]
 
     def widen(self, dtype):
         """The index after the runtime widened its key dtype to
@@ -89,11 +106,8 @@ class HashKeyIndex:
         if dtype.kind in "iu":
             self.dtype = dtype
             return self
-        used = self._tab[self._tab[:, 1] > 0]
-        keys = used[:, 0]
-        if self.dtype.kind == "u":
-            keys = keys.view(np.uint64)
-        return SortedKeyIndex(keys.astype(dtype), used[:, 1] - 1)
+        keys, rows = self.items()
+        return SortedKeyIndex(keys.astype(dtype), rows)
 
     def home(self, bits: np.ndarray) -> np.ndarray:
         """First slot of each key (int64 words -> slot numbers)."""
@@ -147,6 +161,8 @@ class HashKeyIndex:
         back) and the rest move on."""
         words, mask = self._tab.reshape(-1), self._mask
         bits = _key_bits(keys)
+        n, end = self._n, self._n + len(bits)
+        self._keys[n:end], self._rows[n:end], self._n = bits, rows, end
         rows = rows.astype(np.int64) + 1
         slot = self.home(bits)
         while len(slot):
@@ -181,6 +197,9 @@ class SortedKeyIndex:
     def widen(self, dtype):
         self._keys = self._keys.astype(dtype)
         return self
+
+    def items(self) -> Tuple[np.ndarray, np.ndarray]:
+        return self._keys, self._rows
 
     def lookup(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray, int]:
         uniq, inv = np.unique(keys, return_inverse=True)

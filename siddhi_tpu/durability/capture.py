@@ -1,21 +1,29 @@
 """Non-blocking checkpoint capture: freeze state under the barrier.
 
-The synchronous persist path pickles the whole state tree while sources
-are paused — the batch loop stalls for the full serialize+write.  The
-async path instead calls :func:`freeze` per element under the barrier:
+What the barrier holds (``SiddhiAppRuntime.persist``: the app's process
+lock from the emit drain to the end of :func:`capture_elements`) is each
+element's ``snapshot()`` call and :func:`freeze` of what it returned:
 
-* device arrays (jax) are kept **by reference** — they are immutable, so
-  the D2H fetch can happen later on the writer thread;
+* device arrays (jax, or an engine's own device-side snapshot:
+  ``ops/dense_layout.py`` ``SnapshotField``) are kept **by reference**:
+  they are immutable, so the D2H fetch happens later, on whoever asks;
 * host containers (dicts/lists/EventBatch/numpy) are **shallow-cheap
   copied** so post-barrier mutation cannot race the background pickle;
 * anything freeze does not understand makes that ELEMENT fall back to an
   in-barrier ``pickle.dumps`` (``prepickled``), counted through
-  ``persistFallbackReason`` — degradation, never corruption.
+  ``persistFallbackReason`` (degradation, never corruption).
 
-Materialization (D2H via ``util.faults.host_copy``, the sanctioned
-materializer — this module is in the host-sync-hazard scan set and must
-not call ``np.asarray``/``np.array`` itself) and per-element pickling
-happen in :meth:`StateCapture.materialize_blobs` on the writer thread.
+So an engine that hands out device arrays holds the stream for a
+dispatch, and one that hands out numpy holds it for its own fetch and
+one copy.  Every pass over host memory at the scale of the state is
+:func:`materialize`'s and :meth:`StateCapture.materialize_blobs`', on the
+writer thread (async) or in the caller (sync, ``snapshot()``): the wait
+for the transfer (``util.faults.host_view``, the sanctioned
+materializer: this module is in the host-sync-hazard scan set and must
+not call ``np.asarray``/``np.array`` itself), then a pickle of the
+element's skeleton with every array of a page or more left **out of
+band** (protocol 5), a buffer the store writes and hashes as it lies.
+All three let the interpreter go, so a sender is not held by them.
 """
 
 from __future__ import annotations
@@ -30,11 +38,12 @@ import numpy as np
 
 from siddhi_tpu.core.event import Event, EventBatch
 from siddhi_tpu.observability.trace import (
+    STAGE_PERSIST_FETCH,
     STAGE_PERSIST_FREEZE,
     STAGE_PERSIST_PICKLE,
     span,
 )
-from siddhi_tpu.util.faults import host_copy
+from siddhi_tpu.util.faults import host_view
 
 
 class UnfreezableStateError(Exception):
@@ -43,16 +52,22 @@ class UnfreezableStateError(Exception):
 
 _SCALARS = (type(None), bool, int, float, complex, str, bytes)
 
-# the calling thread's open tally of bytes its elements' ``snapshot()``
-# fetched from the device while the state tree was built
+#: a buffer of this many bytes or more leaves an element's pickle and
+#: reaches the store as it lies (a page: below it a file of its own
+#: costs more than the copy)
+OUT_OF_BAND_BYTES = 4096
+
+# the calling thread's open tally of bytes fetched from the device: by
+# its elements' ``snapshot()`` while a state tree is built (under the
+# barrier), by :func:`materialize` where a tally is open round that
+# (``SiddhiAppRuntime._persist_write``: off the barrier)
 _fetched = threading.local()
 
 
 @contextlib.contextmanager
 def fetch_tally():
-    """Open a tally round the walk that builds a state tree; yields a
-    one-item list that ends up holding the bytes :func:`note_fetched`
-    was told of on this thread meanwhile."""
+    """Open a tally on this thread; yields a one-item list that ends up
+    holding the bytes :func:`note_fetched` was told of meanwhile."""
     found = getattr(_fetched, "tally", None)
     tally = _fetched.tally = [0]
     try:
@@ -62,8 +77,9 @@ def fetch_tally():
 
 
 def note_fetched(nbytes: int) -> None:
-    """An engine's ``snapshot()`` fetched ``nbytes`` of device state
-    (core/dense_pattern.py); nothing where no tally is open."""
+    """``nbytes`` of device state reached the host: in an engine's
+    ``snapshot()`` (core/device_single.py) or in :func:`materialize`;
+    nothing where no tally is open."""
     tally = getattr(_fetched, "tally", None)
     if tally is not None:
         tally[0] += nbytes
@@ -116,21 +132,41 @@ def freeze(obj: Any) -> Any:
     raise UnfreezableStateError(type(obj).__name__)
 
 
-def _materialize(obj: Any) -> Any:
-    """Fetch captured-by-reference device arrays to host.  Runs OFF the
-    barrier (writer thread); only called on ``freeze`` output, whose
-    containers are private copies."""
+def materialize(obj: Any) -> Any:
+    """``obj`` with every device array replaced by its host value (a
+    read-only view of the buffer its transfer filled).  Containers are
+    rebuilt, so ``obj`` itself stays as it was: a retry of the writer's
+    job finds the device arrays again (JAX keeps their host copies)."""
     if _is_device_array(obj):
-        return host_copy(obj)
+        with span(STAGE_PERSIST_FETCH, obj.nbytes):
+            host = host_view(obj)
+        note_fetched(host.nbytes)
+        return host
     if isinstance(obj, dict):
-        return {k: _materialize(v) for k, v in obj.items()}
+        return {k: materialize(v) for k, v in obj.items()}
     if isinstance(obj, list):
-        return [_materialize(v) for v in obj]
+        return [materialize(v) for v in obj]
     if isinstance(obj, tuple):
-        return tuple(_materialize(v) for v in obj)
+        return tuple(materialize(v) for v in obj)
     if isinstance(obj, deque):
-        return deque((_materialize(v) for v in obj), maxlen=obj.maxlen)
+        return deque((materialize(v) for v in obj), maxlen=obj.maxlen)
     return obj
+
+
+def dumps_out_of_band(obj: Any) -> Tuple[bytes, List[memoryview]]:
+    """``(skeleton, buffers)``: ``obj`` pickled with every contiguous
+    buffer of :data:`OUT_OF_BAND_BYTES` or more left out, in the order
+    ``pickle.loads(skeleton, buffers=buffers)`` wants them back."""
+    buffers: List[memoryview] = []
+
+    def keep_out(buf: pickle.PickleBuffer):
+        view = buf.raw()
+        if view.nbytes < OUT_OF_BAND_BYTES:
+            return True  # in band
+        buffers.append(view)
+        return False
+
+    return pickle.dumps(obj, protocol=5, buffer_callback=keep_out), buffers
 
 
 class CapturedElement:
@@ -172,19 +208,22 @@ class StateCapture:
         # pickle fallback — surfaced as persistFallbackReason
         self.fallbacks = fallbacks
 
-    def materialize_blobs(self) -> List[Tuple[str, str, bytes]]:
-        """[(kind, name, pickled bytes)] — D2H fetch + pickle, off-barrier."""
-        out: List[Tuple[str, str, bytes]] = []
+    def materialize_blobs(self) -> List[Tuple[str, str, bytes,
+                                              List[memoryview]]]:
+        """[(kind, name, skeleton, buffers)]: the D2H fetch and the
+        pickle, off-barrier.  The span of a pickle counts what it holds
+        in band; the buffers are the store's to count."""
+        out = []
         for el in self.elements:
             if el.prepickled is not None:
-                out.append((el.kind, el.name, el.prepickled))
-            else:
-                with span(STAGE_PERSIST_PICKLE) as sp:
-                    out.append((el.kind, el.name, pickle.dumps(
-                        _materialize(el.state),
-                        protocol=pickle.HIGHEST_PROTOCOL)))
-                    if sp is not None:
-                        sp.count = len(out[-1][2])
+                out.append((el.kind, el.name, el.prepickled, []))
+                continue
+            state = materialize(el.state)
+            with span(STAGE_PERSIST_PICKLE) as sp:
+                data, buffers = dumps_out_of_band(state)
+                if sp is not None:
+                    sp.count = len(data)
+            out.append((el.kind, el.name, data, buffers))
         return out
 
     def tree_bytes(self) -> bytes:
@@ -203,7 +242,7 @@ class StateCapture:
             if el.prepickled is not None:
                 tree[el.kind][el.name] = pickle.loads(el.prepickled)
             else:
-                tree[el.kind][el.name] = _materialize(el.state)
+                tree[el.kind][el.name] = materialize(el.state)
         return tree
 
 
@@ -233,6 +272,7 @@ def capture_elements(app: str, version: int, tree: Dict,
                 elements.append(CapturedElement(
                     kind, name,
                     prepickled=pickle.dumps(
-                        state, protocol=pickle.HIGHEST_PROTOCOL)))
+                        materialize(state),
+                        protocol=pickle.HIGHEST_PROTOCOL)))
     return StateCapture(app, version, elements, fallbacks,
                         clock=tree.get("clock"), fetched_bytes=fetched_bytes)

@@ -1,23 +1,39 @@
-"""Durable persistence store: blob-per-element + checksummed manifest.
+"""Durable persistence store: files per element + checksummed manifest.
 
-Revision layout (under ``<base>/<app>/``)::
+Revision layout (under ``<base>/<app>/``), manifest format 2::
 
     <revision>.ckpt/
-        0000.blob ... NNNN.blob     per-element pickles, fsynced
-        MANIFEST.json               committed LAST: tmp + fsync + rename
+        0000.blob           element 0: its pickle (protocol 5) without
+        0000.000.buf ...    the buffers it left out of band, one file
+        0001.blob ...       each, as they lay in memory; all fsynced
+        MANIFEST.json       committed LAST: tmp + fsync + rename
 
-The manifest carries a SHA-256 per blob plus a self-checksum over its
-canonical JSON, so ``load`` detects torn blobs, bit flips, and partial
-manifests — a revision without a valid manifest simply does not exist
+**Every file is an entry of the manifest's** ``elements`` with its own
+``file``, ``sha256`` and ``size``, in the order written; an entry with a
+``buffer`` number is that buffer of the element entry before it (so a
+reader that only hashes files needs to know nothing of this).  An
+element's arrays of a page or more (``durability/capture.py``
+``dumps_out_of_band``) are written and hashed from the buffer the
+device transfer filled: no ``bytes`` of the element is ever built, and
+``write`` and ``sha256`` let the interpreter go.  **Format 1** (one
+``.blob`` an element, its arrays in band; no ``buffer`` entries) is the
+same layout with nothing left out, and loads as it always did.
+
+The manifest carries a SHA-256 per file plus a self-checksum over its
+canonical JSON, so ``load`` detects torn files, bit flips, and partial
+manifests: a revision without a valid manifest simply does not exist
 (``revisions()`` skips it) and ``restore_last_revision()`` walks back to
 the previous one.  Crash at ANY point mid-save therefore leaves either
-the previous or the new revision fully restorable.
+the previous or the new revision fully restorable.  A commit evicts the
+revisions past ``revisions_to_keep`` by withdrawing their manifests; their
+files go one commit later (``_evict_locked``), so a reader that is
+hashing a committed revision when the next one lands is not cut short.
 
 ``save_tree`` threads an optional ``checker(site)`` callable (the fault
 injector's ``check``) through the commit sequence so the crash-point
 matrix can kill the writer between every durability step:
-``persist.post_blob`` / ``persist.pre_manifest`` / ``persist.mid_manifest``
-(tmp manifest durable, rename pending).
+``persist.post_blob`` (every file durable) / ``persist.pre_manifest`` /
+``persist.mid_manifest`` (tmp manifest durable, rename pending).
 
 Journal spill segments live beside the revisions under
 ``<base>/<app>/journal/`` (util/persistence.py FileJournalSegmentMixin).
@@ -48,7 +64,9 @@ from siddhi_tpu.util.persistence import (
 log = logging.getLogger("siddhi_tpu.durability")
 
 MANIFEST_NAME = "MANIFEST.json"
-MANIFEST_FORMAT = 1
+MANIFEST_FORMAT = 2
+# what an evicted revision's manifest is renamed to until its files go
+_EVICTED_NAME = "EVICTED.json"
 _SUFFIX = ".ckpt"
 # monolithic fallback: PersistenceStore.save bytes wrapped as one blob
 _TREE_KIND = "__tree__"
@@ -97,33 +115,42 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
 
     # -- save ---------------------------------------------------------------
 
-    def save_tree(self, app_name: str, revision: str,
-                  blobs: List[Tuple[str, str, bytes]],
+    def _write(self, path: str, data) -> Dict:
+        """One file of a revision, durable: ``data`` (bytes or a
+        buffer) written as it lies, and its manifest fields."""
+        size = memoryview(data).nbytes
+        with span(STAGE_PERSIST_STORE, size):
+            with open(path, "wb") as f:
+                f.write(data)
+                f.flush()
+                os.fsync(f.fileno())
+        with span(STAGE_PERSIST_HASH, size):
+            digest = hashlib.sha256(data).hexdigest()
+        return {"file": os.path.basename(path), "sha256": digest,
+                "size": size}
+
+    def save_tree(self, app_name: str, revision: str, blobs: List[Tuple],
                   checker: Optional[Callable[[str], None]] = None,
-                  version: int = 1, clock: Optional[int] = None):
-        """Write per-element ``blobs`` [(kind, name, bytes)] and commit
-        the revision by atomically publishing its manifest.  Idempotent:
-        a retry after a partial failure overwrites and re-commits.
-        ``clock`` is the tree's (util/snapshot.py): the app's time at
-        the barrier, kept in the manifest as ``version`` is."""
+                  version: int = 1, clock: Optional[int] = None) -> int:
+        """Write per-element ``blobs`` [(kind, name, pickle)] or
+        [(kind, name, pickle, buffers left out of band)] and commit the
+        revision by atomically publishing its manifest; returns the
+        bytes written.  Idempotent: a retry after a partial failure
+        overwrites and re-commits.  ``clock`` is the tree's
+        (util/snapshot.py): the app's time at the barrier, kept in the
+        manifest as ``version`` is."""
         with self._lock:
             rev_dir = self._rev_dir(app_name, revision)
             os.makedirs(rev_dir, exist_ok=True)
             elements = []
-            for idx, (kind, name, data) in enumerate(blobs):
-                fname = f"{idx:04d}.blob"
-                path = os.path.join(rev_dir, fname)
-                with span(STAGE_PERSIST_STORE, len(data)):
-                    with open(path, "wb") as f:
-                        f.write(data)
-                        f.flush()
-                        os.fsync(f.fileno())
-                with span(STAGE_PERSIST_HASH, len(data)):
-                    digest = hashlib.sha256(data).hexdigest()
-                elements.append({
-                    "kind": kind, "name": name, "file": fname,
-                    "sha256": digest, "size": len(data),
-                })
+            for idx, (kind, name, data, *rest) in enumerate(blobs):
+                whose = {"kind": kind, "name": name}
+                elements.append({**whose, **self._write(
+                    os.path.join(rev_dir, f"{idx:04d}.blob"), data)})
+                for k, buf in enumerate(rest[0] if rest else ()):
+                    elements.append({**whose, **self._write(
+                        os.path.join(rev_dir, f"{idx:04d}.{k:03d}.buf"),
+                        buf), "buffer": k})
             if checker is not None:
                 checker("persist.post_blob")
             manifest = {"format": MANIFEST_FORMAT, "app": app_name,
@@ -147,6 +174,7 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
             fsync_dir(rev_dir)
             fsync_dir(self._app_dir(app_name))
             self._evict_locked(app_name)
+            return sum(el["size"] for el in elements)
 
     def save(self, app_name: str, revision: str, snapshot: bytes):
         """PersistenceStore SPI: monolithic bytes become one blob."""
@@ -154,15 +182,19 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
                        [(_TREE_KIND, _TREE_KIND, snapshot)])
 
     def _evict_locked(self, app_name: str):
+        """Eviction takes two commits.  A revision past
+        ``revisions_to_keep`` first loses its manifest (renamed aside,
+        atomically): it is committed no longer, ``revisions()`` does not
+        list it and no walk restores it.  Its files stay until the NEXT
+        commit sweeps them with the torn directories, so a reader that
+        has a revision's manifest open (a backup, a plain reference that
+        hashes every file) finishes on files that are still there;
+        removed under it, they left it a revision short.  The price is
+        one revision's files on disk for one more interval."""
         committed = self._committed_locked(app_name)
-        app_dir = self._app_dir(app_name)
-        for old in committed[: max(0, len(committed)
-                                   - self.revisions_to_keep)]:
-            shutil.rmtree(self._rev_dir(app_name, old), ignore_errors=True)
-        # garbage-collect torn dirs (no valid manifest) older than the
-        # newest committed revision — crash leftovers, never restorable
         if not committed:
             return
+        app_dir = self._app_dir(app_name)
         newest_ts = int(committed[-1].split("_", 1)[0])
         try:
             names = os.listdir(app_dir)
@@ -178,9 +210,19 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
             except ValueError:
                 continue
             if ts < newest_ts:
-                log.warning("durability: removing torn revision %r of "
-                            "app %r (no valid manifest)", rev, app_name)
+                if not os.path.isfile(os.path.join(app_dir, d, _EVICTED_NAME)):
+                    # a crash's leftover, never restorable
+                    log.warning("durability: removing torn revision %r of "
+                                "app %r (no valid manifest)", rev, app_name)
                 shutil.rmtree(os.path.join(app_dir, d), ignore_errors=True)
+        for old in committed[: max(0, len(committed)
+                                   - self.revisions_to_keep)]:
+            rev_dir = self._rev_dir(app_name, old)
+            try:
+                os.replace(os.path.join(rev_dir, MANIFEST_NAME),
+                           os.path.join(rev_dir, _EVICTED_NAME))
+            except OSError:
+                shutil.rmtree(rev_dir, ignore_errors=True)
 
     # -- load ---------------------------------------------------------------
 
@@ -199,17 +241,22 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
             return None
         return manifest
 
-    def _read_blobs(self, app_name: str,
-                    revision: str) -> Optional[List[Tuple[str, str, bytes]]]:
+    def _read_blobs(self, app_name: str, revision: str) -> Optional[
+            List[Tuple[str, str, bytearray, List[bytearray]]]]:
+        """[(kind, name, pickle, its out-of-band buffers)] of a revision
+        of either format, every file held to its manifest entry."""
         manifest = self._read_manifest(app_name, revision)
         if manifest is None:
             return None
         rev_dir = self._rev_dir(app_name, revision)
-        out = []
+        out: List[Tuple[str, str, bytearray, List[bytearray]]] = []
         for el in manifest.get("elements", []):
             try:
                 with open(os.path.join(rev_dir, el["file"]), "rb") as f:
-                    data = f.read()
+                    # a buffer comes back writable: what restores from
+                    # it may keep the array and write to it
+                    data = bytearray(os.fstat(f.fileno()).st_size)
+                    f.readinto(data)
             except OSError as e:
                 log.warning("durability: blob %r missing from revision "
                             "%r of app %r (%s)", el.get("file"), revision,
@@ -220,7 +267,15 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
                             "%r fails its checksum", el.get("file"),
                             revision, app_name)
                 return None
-            out.append((el["kind"], el["name"], data))
+            if "buffer" not in el:
+                out.append((el["kind"], el["name"], data, []))
+            elif not out or el["buffer"] != len(out[-1][3]):
+                log.warning("durability: buffer %r of revision %r of app "
+                            "%r is out of place", el.get("file"), revision,
+                            app_name)
+                return None
+            else:
+                out[-1][3].append(data)
         return out
 
     def load(self, app_name: str, revision: str) -> Optional[bytes]:
@@ -231,12 +286,12 @@ class DurableFileSystemPersistenceStore(FileJournalSegmentMixin,
         if blobs is None:
             return None
         if len(blobs) == 1 and blobs[0][0] == _TREE_KIND:
-            return blobs[0][2]
+            return bytes(blobs[0][2])
         tree: Dict = {"queries": {}, "tables": {}, "named_windows": {},
                       "partitions": {}, "aggregations": {}}
         try:
-            for kind, name, data in blobs:
-                tree[kind][name] = pickle.loads(data)
+            for kind, name, data, buffers in blobs:
+                tree[kind][name] = pickle.loads(data, buffers=buffers)
         except Exception as e:
             log.warning("durability: revision %r of app %r holds an "
                         "unreadable element (%s)", revision, app_name, e)

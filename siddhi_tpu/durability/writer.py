@@ -66,7 +66,11 @@ class DurabilityStats:
         # persist was still inside its call (core/app_runtime.py)
         "persist_ticks_skipped",
         # bytes of device state the captures fetched under the barrier
+        # (an engine whose snapshot() hands out numpy)
         "persist_fetch_bytes",
+        # bytes captured by reference under it and fetched off it, by
+        # the writer thread or a sync persist's own call
+        "persist_deferred_bytes",
     )
 
     def __init__(self) -> None:
@@ -219,6 +223,10 @@ class AsyncCheckpointWriter:
                     self._crashed = e
                     self._lock.notify_all()
                 return
+            # the job holds the capture, and the capture its device
+            # arrays and their host copies: let them go now, not when
+            # the next checkpoint replaces these names
+            job = on_abandon = None
             with self._lock:
                 self._inflight = None
                 self._lock.notify_all()

@@ -49,6 +49,7 @@ from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.exceptions import SiddhiAppRuntimeError, TransferFaultError
 from siddhi_tpu.core.ingest_stage import IngestStats
 from siddhi_tpu.multiplex.common import retry_guard
+from siddhi_tpu.observability.trace import STAGE_PERSIST_UNPACK, span
 from siddhi_tpu.util import faults as _faults
 
 log = logging.getLogger(__name__)
@@ -288,11 +289,12 @@ class DenseMultiplexGroup:
         with self.lock:
             self._dispatch_locked()
             t = adapter.slot
-            return {
-                "dense_state": self.engine.layout.unpack(
-                    {k: v[t:t + 1] for k, v in self.state.items()}),
-                "base_ts": self.engine.base_ts,
-            }
+            # one tenant's row, fetched and unpacked here: under the
+            # caller's barrier where a persist calls
+            with span(STAGE_PERSIST_UNPACK):
+                logical = self.engine.layout.unpack(
+                    {k: v[t:t + 1] for k, v in self.state.items()})
+            return {"dense_state": logical, "base_ts": self.engine.base_ts}
 
     def restore_tenant(self, adapter, snap: Dict) -> None:
         eng = self.engine

@@ -134,15 +134,21 @@ SPANS_PER_CYCLE = len(CYCLE_STAGES) + 2 + len(ROUND_STAGES)
 STAGE_PERSIST_CAPTURE = "persist.capture"
 STAGE_PERSIST_WRITE = "persist.write"
 #: inside ``persist.capture``, under the barrier: the emit drain, the
-#: device state's fetch (count: bytes), its unpack into the logical form
-#: (core/dense_pattern.py ``snapshot``), the copy of every element
-#: (durability/capture.py ``freeze``)
+#: copy of every element (durability/capture.py ``freeze``: a device
+#: array by reference), a tenant's rows unpacked into the logical form
+#: on the host (multiplex/dense_group.py ``snapshot_tenant``; the dense
+#: pattern engine makes that form on the device and records none)
 STAGE_PERSIST_DRAIN = "persist.drain"
-STAGE_PERSIST_FETCH = "persist.fetch"
 STAGE_PERSIST_UNPACK = "persist.unpack"
 STAGE_PERSIST_FREEZE = "persist.freeze"
-#: inside ``persist.write``, on the writer thread, one a blob (count:
-#: its bytes): the element's pickle, its SHA-256, its write and fsync
+#: the wait for one captured device array's transfer (count: bytes),
+#: wherever its host value is asked for (durability/capture.py
+#: ``materialize``): inside ``persist.write`` for an async persist
+STAGE_PERSIST_FETCH = "persist.fetch"
+#: inside ``persist.write``, on the writer thread (count: bytes): an
+#: element's pickle (what it holds in band; its arrays of a page or
+#: more are left out), then a file's write and fsync and its SHA-256,
+#: for the pickle and for each buffer left out of it
 STAGE_PERSIST_PICKLE = "persist.pickle"
 STAGE_PERSIST_HASH = "persist.hash"
 STAGE_PERSIST_STORE = "persist.store"

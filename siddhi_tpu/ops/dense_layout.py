@@ -21,6 +21,12 @@ whole state twice a step (PERF.md, PR 27).
 W and the offsets follow from S, I and the register allocator alone;
 no app, annotation or option selects anything here.  ``overflow`` stays
 its own vector: ``overflow_total`` sums it without touching the rows.
+
+A **snapshot** is the logical state, made on the device: ``logical``
+traces the split of every row into fresh arrays (the steps donate
+``rows``, so a reference to the resident state would not outlive the
+next batch), each a :class:`SnapshotField`, and the host only ever
+receives bytes it writes out as they are.
 """
 
 from __future__ import annotations
@@ -29,17 +35,54 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from siddhi_tpu.util.faults import host_view
+
 ROWS = "rows"
 OVERFLOW = "overflow"
 LANES = 128
 
 
+class SnapshotField:
+    """One logical field of a snapshot made on the device: the array as
+    the program wrote it, one dimension long, under the logical shape
+    its host copy takes.  (A TPU gives a ``[N, S, I]`` result the
+    partition axis on its lanes, and the copy to the host would have to
+    transpose a gigabyte; one dimension crosses as it lies.)
+
+    To ``durability/capture.py`` it is a device array: it has a shape
+    and a dtype and is no numpy type, so a capture keeps it by
+    reference and whoever needs the host value asks ``np.asarray``."""
+
+    __slots__ = ("flat", "shape")
+
+    def __init__(self, flat, shape: Tuple[int, ...]):
+        self.flat = flat
+        self.shape = tuple(shape)
+
+    @property
+    def dtype(self):
+        return self.flat.dtype
+
+    @property
+    def nbytes(self) -> int:
+        return self.flat.nbytes
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def __array__(self, dtype=None, copy=None):
+        # the host copy JAX keeps, waited for here: read-only, no copy
+        out = host_view(self.flat).reshape(self.shape)
+        return out if dtype is None else out.astype(dtype)
+
+
 class DenseStateLayout:
     """Offsets of every logical field inside a partition's row, and the
     conversions between the logical and the physical form: ``pack`` /
-    ``unpack`` on the host (numpy), ``split`` / ``join`` on gathered rows
-    inside a jitted step, ``words`` / ``decode`` / ``with_field`` for
-    callers that read or write single fields of a few rows."""
+    ``unpack`` on the host (numpy), ``logical`` for a whole state on the
+    device, ``split`` / ``join`` on gathered rows inside a jitted step,
+    ``words`` / ``decode`` / ``with_field`` for callers that read or
+    write single fields of a few rows."""
 
     def __init__(self, S: int, I: int, n_regs: int, n_iregs: int,
                  has_deadlines: bool, armed_start: bool):
@@ -154,6 +197,29 @@ class DenseStateLayout:
                for name, (off, w) in self.offsets.items()}
         out[OVERFLOW] = np.array(physical[OVERFLOW], dtype=np.int32)
         return out
+
+    # -- the whole state, on the device ---------------------------------------
+
+    def logical(self, state) -> Dict[str, object]:
+        """Traced, never donated: physical state -> its logical fields
+        as arrays of their own, each flattened to one dimension
+        (:class:`SnapshotField` gives them their shape back).  Slices
+        and casts over the partition axis alone, so a state sharded by
+        rows stays sharded."""
+        import jax.numpy as jnp
+
+        out = {name: x.reshape(-1) for name, x in
+               self.split(state[ROWS], shaped=False).items()}
+        # an output that IS an input would be the donated buffer itself
+        out[OVERFLOW] = jnp.array(state[OVERFLOW], copy=True)
+        return out
+
+    def snapshot_fields(self, flat: Dict[str, object]) -> Dict[str, SnapshotField]:
+        """``logical``'s outputs under their logical shapes."""
+        n = flat[OVERFLOW].shape[0]
+        shapes = self.logical_shapes(n)
+        return {name: SnapshotField(x, shapes[name])
+                for name, x in flat.items()}
 
     # -- single fields of a few rows (eager device ops) -----------------------
 

@@ -1689,6 +1689,21 @@ class DensePatternEngine:
         self._step_cache[cache_key] = fn
         return fn
 
+    # -- snapshot -------------------------------------------------------------
+
+    def snapshot_state(self, state) -> Dict[str, object]:
+        """The logical fields of ``state`` as device arrays of their
+        own (``ops/dense_layout.py`` ``logical``), each a
+        ``SnapshotField`` under its logical shape: one program over the
+        whole state, dispatched and not waited for.  Nothing is
+        donated, so ``state`` is what it was and the fields outlive
+        every later step."""
+        fn = self._step_cache.get("snapshot")
+        if fn is None:
+            fn = self._step_cache["snapshot"] = self.jax.jit(
+                self.layout.logical)
+        return self.layout.snapshot_fields(fn(state))
+
     def next_wakeup_state(self, state) -> Optional[int]:
         """Earliest armed absent deadline (absolute ms), or None.  One
         device reduction + scalar transfer; engines without deadline

@@ -374,6 +374,15 @@ def host_copy(state: Any) -> Any:
     return state
 
 
+def host_view(array: Any) -> np.ndarray:
+    """The host value of one device array as JAX hands it over: the
+    read-only buffer its transfer filled, waited for with the
+    interpreter let go, and no second copy.  For a value that is only
+    read or written out (a checkpoint's arrays, durability/capture.py);
+    state that must outlive its device array takes ``host_copy``."""
+    return np.asarray(array)
+
+
 def _leaves(state: Any) -> List[Any]:
     if isinstance(state, dict):
         out: List[Any] = []

@@ -63,17 +63,22 @@ class SnapshotService:
         return tree
 
     def full_snapshot(self) -> bytes:
+        # the caller wants host values at once: the same tree, its
+        # device arrays fetched here (durability/capture.py)
+        from siddhi_tpu.durability.capture import materialize
+
         with self.app.app_context.process_lock:
-            return pickle.dumps(self._state_tree(), protocol=pickle.HIGHEST_PROTOCOL)
+            return pickle.dumps(materialize(self._state_tree()),
+                                protocol=pickle.HIGHEST_PROTOCOL)
 
     def capture(self, on_fallback=None):
-        """Non-blocking capture for the async persist path
-        (durability/capture.py): under the lock, freeze each element —
-        immutable device-array references + cheap host copies — instead
-        of pickling the whole tree.  Elements freeze cannot copy are
-        pickled here (in-barrier) and reported via ``on_fallback``.
-        Returns a ``StateCapture``; serialization and the D2H fetch run
-        on the checkpoint writer thread."""
+        """The capture of a persist (durability/capture.py): under the
+        lock, freeze each element (device arrays by reference, cheap
+        host copies) instead of pickling the whole tree.  Elements
+        freeze cannot copy are pickled here (in-barrier) and reported
+        via ``on_fallback``.  Returns a ``StateCapture``; the D2H fetch
+        and the serialization are its ``materialize_blobs``, on the
+        checkpoint writer thread or in a sync persist's own call."""
         from siddhi_tpu.durability.capture import (
             capture_elements,
             fetch_tally,
@@ -102,8 +107,10 @@ class SnapshotService:
         Returns ``(kind, bytes)`` with kind 'base' (full tree) or 'inc'
         (changed elements only).  A base is emitted on the first call and
         every ``base_interval`` increments."""
+        from siddhi_tpu.durability.capture import materialize
+
         with self.app.app_context.process_lock:
-            tree = self._state_tree()
+            tree = materialize(self._state_tree())
             blobs: Dict[Tuple[str, str], bytes] = {}
             digests: Dict[Tuple[str, str], str] = {}
             for kind in self._ELEMENT_KINDS:

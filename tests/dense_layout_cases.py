@@ -239,10 +239,10 @@ def record_all():
             rec[f"{app}/dense_state/{k}"] = np.asarray(v)
         rec[f"{app}/base_ts"] = np.asarray(snap["base_ts"])
         rec[f"{app}/row_last_used"] = np.asarray(snap["row_last_used"])
-        keys = sorted(snap["key_rows"])
+        key_rows = dict(zip(*(a.tolist() for a in snap["key_rows"])))
+        keys = sorted(key_rows)
         rec[f"{app}/keys"] = np.asarray(keys)
-        rec[f"{app}/key_rows"] = np.asarray(
-            [snap["key_rows"][k] for k in keys])
+        rec[f"{app}/key_rows"] = np.asarray([key_rows[k] for k in keys])
         rec[f"{app}/next_row"] = np.asarray(snap["next_row"])
         rec[f"{app}/free_rows"] = np.asarray(snap["free_rows"],
                                              dtype=np.int64)

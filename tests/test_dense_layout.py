@@ -112,8 +112,16 @@ def test_snapshot_has_the_parent_format(n_dev, parent):
     assert sorted(snap["dense_state"]) == sorted(want["dense_state"])
     for k, v in want["dense_state"].items():
         _same(np.asarray(snap["dense_state"][k]), v, f"{app}/{k}")
-    for k in ("base_ts", "key_rows", "next_row", "free_rows"):
+    for k in ("base_ts", "next_row"):
         assert snap[k] == want[k], k
+    # the index's two vectors since PR 49 (the parent's dict restores
+    # too: the test above), free rows an array
+    keys, rows = snap["key_rows"]
+    assert dict(zip(keys.tolist(), rows.tolist())) == want["key_rows"]
+    # the same rows; a purge frees them in row order since PR 49 (the
+    # parent freed them in its dict's order), so that a runtime restored
+    # from the vectors recycles as the one that was never interrupted
+    assert snap["free_rows"].tolist() == sorted(want["free_rows"])
     # and it restores under the change as it does under the parent
     again, snap2 = cases.drive_app(n_dev, restore_from=snap)
     assert again == got

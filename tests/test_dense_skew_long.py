@@ -140,3 +140,43 @@ def test_the_device_tables_programs_fit_a_v5e_at_a_million_slots(one_chip):
     # a whole new table an upsert batch (nothing is donated), and no more
     out = scatter.memory_analysis().output_size_in_bytes
     assert C * row <= out < C * row + 4096
+
+
+def test_the_snapshot_program_fits_a_v5e_at_a_million_partitions(one_chip):
+    """A checkpoint's snapshot of the flagship's state (``fraud16_1m``:
+    16 nodes, 4 lanes, one register; 1,000,001 rows of 256 words),
+    through the chip's own compiler (kept in this file: one worker
+    holds libtpu).  Every field leaves as ONE dimension, so its copy to
+    the host is the buffer as it lies (a ``[N, S, I]`` result gets the
+    partition axis on its lanes here, and the transfer would transpose
+    it); the fields are buffers of their own, none an alias of the rows
+    the next step donates; and the program fits beside the state."""
+    import jax
+    import numpy as np
+
+    from siddhi_tpu.ops.dense_layout import DenseStateLayout
+
+    layout = DenseStateLayout(16, 4, 1, 0, False, False)
+    n = 1_000_001
+    assert layout.width == 256
+    state = {name: jax.ShapeDtypeStruct(shape, np.int32, sharding=one_chip)
+             for name, shape in layout.physical_shapes(n).items()}
+    compiled = jax.jit(layout.logical).trace(state).lower(
+        lowering_platforms=("tpu",)).compile()
+    logical = sum(int(np.prod(shape)) * np.dtype(dt).itemsize
+                  for shape, dt in (
+                      [((n,) + s, dt) for dt, s in layout.fields.values()]
+                      + [((n,), np.int32)]))
+    assert logical == 836_000_836    # what a revision holds of the state
+    mem = compiled.memory_analysis()
+    assert logical <= mem.output_size_in_bytes < logical + (1 << 20)
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < 1.1 * n * layout.width * 4
+    text = compiled.as_text()
+    assert "input_output_alias" not in text
+    (entry,) = [ln for ln in text.splitlines() if ln.startswith("ENTRY")]
+    result = entry.split("->", 1)[1]
+    for dt, count in (("pred", 64 * n), ("s32", 64 * n), ("f32", 64 * n),
+                      ("s32", n)):
+        assert f"{dt}[{count}]" in result, result
+    assert f"{n},16" not in result and f"{n},64" not in result

@@ -12,7 +12,8 @@ here:
   post-manifest-before-journal-mark, mid-spill) leaves either the
   previous or the new revision fully restorable, and restore + journal
   replay is bit-identical to an uninterrupted run — across the
-  device-single, sharded, fused, multiplexed, and hotkey engines.
+  device-single, sharded, fused, multiplexed, dense-pattern and
+  hotkey engines.
 * **Checksummed manifests** — a flipped byte anywhere in a revision
   (blob or manifest) fails validation and the restore walk falls back
   to the previous revision with a warning.
@@ -77,6 +78,16 @@ HOTKEY_BODY = (
     "select b.v as bv insert into Out; end;")
 
 
+# a partitioned pattern on the dense engine, at a size whose logical
+# fields leave the element's pickle (durability/capture.py
+# OUT_OF_BAND_BYTES): a revision of it is a skeleton and raw buffers
+DENSE_BODY = (
+    "define stream S (k long, u double, v double); "
+    "partition with (k of S) begin "
+    "@info(name='q') from every a=S[v > 8.0] -> b=S[v > 12.0 and v > a.v] "
+    "select a.v as av, b.v as bv insert into Out; end;")
+
+
 def kv_series(n, seed=11, n_keys=3):
     rng = np.random.default_rng(seed)
     keys = rng.integers(0, n_keys, size=n)
@@ -115,6 +126,8 @@ ENGINES = {
               fused_series(30)),
     "multiplex": ("@app:execution('tpu') @app:multiplex(slots='8') ",
                   MUX_BODY, "S", kv_series(30)),
+    "dense": ("@app:execution('tpu', partitions='4096') ", DENSE_BODY, "S",
+              hk_series(60)),
     "hotkey": ("@app:execution('tpu', instances='16') "
                "@app:hotkeys(k='4', promote='0.3', demote='0.1') ",
                HOTKEY_BODY, "S", hk_series(60)),
@@ -309,11 +322,11 @@ class TestCrashMatrix:
 
 
 class TestChecksummedManifests:
-    def _persist_twice(self, m, tmp_path):
-        _exec, _body, stream, sends = ENGINES["device_single"]
+    def _persist_twice(self, m, tmp_path, engine="device_single"):
+        _exec, _body, stream, sends = ENGINES[engine]
         m.set_persistence_store(
             DurableFileSystemPersistenceStore(str(tmp_path)))
-        rt = m.create_siddhi_app_runtime(_app("device_single"))
+        rt = m.create_siddhi_app_runtime(_app(engine))
         rt.start()
         h = rt.get_input_handler(stream)
         for row, ts in sends[:8]:
@@ -325,25 +338,28 @@ class TestChecksummedManifests:
         rt.shutdown()
         return rev1, rev2
 
-    @pytest.mark.parametrize("victim", ["blob", "manifest"])
+    @pytest.mark.parametrize("victim", ["blob", "buffer", "manifest"])
     def test_flipped_byte_walks_back_to_previous_revision(
             self, victim, tmp_path, caplog):
         import logging
 
+        engine = "dense" if victim == "buffer" else "device_single"
         m = SiddhiManager()
         try:
-            rev1, rev2 = self._persist_twice(m, tmp_path)
+            rev1, rev2 = self._persist_twice(m, tmp_path, engine)
             rev_dir = tmp_path / "dur" / f"{rev2}.ckpt"
-            if victim == "blob":
-                target = sorted(p for p in rev_dir.iterdir()
-                                if p.name.endswith(".blob"))[0]
-            else:
+            if victim == "manifest":
                 target = rev_dir / "MANIFEST.json"
+            else:
+                # an element's pickle, or an array it left out of band
+                ends = ".blob" if victim == "blob" else ".buf"
+                target = sorted(p for p in rev_dir.iterdir()
+                                if p.name.endswith(ends))[0]
             raw = bytearray(target.read_bytes())
             raw[len(raw) // 2] ^= 0xFF
             target.write_bytes(bytes(raw))
 
-            rt2 = m.create_siddhi_app_runtime(_app("device_single"))
+            rt2 = m.create_siddhi_app_runtime(_app(engine))
             rt2.start()
             with caplog.at_level(logging.WARNING, logger="siddhi_tpu"):
                 assert rt2.restore_last_revision() == rev1
@@ -374,6 +390,194 @@ class TestChecksummedManifests:
             store.save("a", f"{1000 + i}_a", pickle.dumps({"i": i}))
         assert store.revisions("a") == ["1003_a", "1004_a"]
         assert pickle.loads(store.load("a", "1004_a")) == {"i": 4}
+
+
+def _plain_manifest(rev_dir):
+    """A revision as ``benchmark/references/pattern_chain_ckpt.py`` reads
+    it, in plain ``json`` / ``hashlib``: the manifest, and per entry of
+    its ``elements`` whether the file hashes to it."""
+    import hashlib
+    import json
+
+    with open(os.path.join(rev_dir, "MANIFEST.json")) as f:
+        manifest = json.load(f)
+    sound = []
+    for el in manifest["elements"]:
+        h = hashlib.sha256()
+        with open(os.path.join(rev_dir, el["file"]), "rb") as f:
+            while chunk := f.read(1 << 16):
+                h.update(chunk)
+        sound.append(h.hexdigest() == el["sha256"]
+                     and os.path.getsize(os.path.join(
+                         rev_dir, el["file"])) == el["size"])
+    return manifest, sound
+
+
+class TestManifestFormats:
+    """Format 2 (an element's pickle and the buffers it left out of
+    band, a file each, every file an entry of ``elements``) and format 1
+    (one pickle an element, arrays in band), which still loads."""
+
+    def _run(self, m, n, header="", persist=None):
+        _exec, _body, stream, sends = ENGINES["dense"]
+        rt = m.create_siddhi_app_runtime(
+            _app("dense").replace("@app:playback", "@app:playback " + header))
+        got = []
+        rt.add_callback("Out", lambda evs: got.extend(tuple(e.data)
+                                                      for e in evs))
+        rt.start()
+        h = rt.get_input_handler(stream)
+        for row, ts in sends[:n]:
+            h.send(list(row), timestamp=ts)
+        return rt, got, h
+
+    def test_every_file_of_a_revision_is_an_entry_that_hashes(
+            self, tmp_path):
+        m = SiddhiManager()
+        try:
+            m.set_persistence_store(
+                DurableFileSystemPersistenceStore(str(tmp_path)))
+            rt, _got, _h = self._run(m, 30, "@app:trace(sample='1')")
+            rev = rt.persist(mode="async")
+            assert rt.wait_for_persist(rev, timeout=30) == "committed"
+            spans = list(rt.app_context.tracer.recorder.spans())
+            rt.shutdown()
+        finally:
+            m.shutdown()
+        rev_dir = str(tmp_path / "dur" / f"{rev}.ckpt")
+        manifest, sound = _plain_manifest(rev_dir)
+        assert manifest["format"] == 2 and all(sound)
+        elements = manifest["elements"]
+        assert all({"kind", "name", "file", "sha256", "size"} <= set(el)
+                   for el in elements)
+        files = [el["file"] for el in elements]
+        assert sorted(files + ["MANIFEST.json"]) == sorted(
+            os.listdir(rev_dir))
+        # the partition's pickle, then its buffers in their order: the
+        # four logical fields, the overflow vector, the last-used stamps
+        (blob,) = [el for el in elements if "buffer" not in el]
+        buffers = [el for el in elements if "buffer" in el]
+        assert blob["file"] == "0000.blob" and elements[0] is blob
+        assert [el["buffer"] for el in buffers] == list(range(len(buffers)))
+        assert len(buffers) >= 6
+        assert all(el["kind"] == "partitions" and el["name"] == blob["name"]
+                   and el["size"] >= 4096 for el in buffers)
+        # what is still pickled in band is under a hundredth of what
+        # the store wrote
+        by_stage = {}
+        for sp in spans:
+            by_stage.setdefault(sp[1], []).append(sp[5])
+        (in_band,) = by_stage["persist.pickle"]
+        stored = sum(by_stage["persist.store"])
+        assert in_band == blob["size"]
+        assert stored == sum(el["size"] for el in elements)
+        assert stored == sum(by_stage["persist.hash"])
+        assert in_band < 0.01 * stored
+
+    def test_a_format_1_revision_still_restores(self, tmp_path):
+        """A revision in the parent's layout, written here by hand in
+        plain ``pickle`` / ``json`` / ``hashlib``: one ``.blob`` an
+        element with its arrays in band, ``key_rows`` a dict,
+        ``free_rows`` a list, no ``buffer`` entry, ``format`` 1."""
+        import hashlib
+        import json
+
+        ref = _reference("dense")
+        _exec, _body, stream, sends = ENGINES["dense"]
+        m = SiddhiManager()
+        try:
+            rt, got, _h = self._run(m, 30)
+            tree = pickle.loads(rt.snapshot())
+            rt.shutdown()
+            (partition,) = tree["partitions"].values()
+            (pattern,) = partition["__dense__"].values()
+            pattern = pattern["pattern"]
+            assert not isinstance(pattern["key_rows"], dict)
+            keys, rows = pattern["key_rows"]
+            pattern["key_rows"] = dict(zip(keys.tolist(), rows.tolist()))
+            pattern["free_rows"] = pattern["free_rows"].tolist()
+            assert pattern["dense_state"]["active"].ndim == 3
+
+            rev = "1700000000000_dur"
+            rev_dir = tmp_path / "dur" / (rev + ".ckpt")
+            rev_dir.mkdir(parents=True)
+            elements = []
+            kinds = ("queries", "tables", "named_windows", "partitions",
+                     "aggregations")
+            blobs = [(kind, name, state) for kind in kinds
+                     for name, state in tree[kind].items()]
+            for idx, (kind, name, state) in enumerate(blobs):
+                data = pickle.dumps(state, protocol=pickle.HIGHEST_PROTOCOL)
+                (rev_dir / f"{idx:04d}.blob").write_bytes(data)
+                elements.append({
+                    "kind": kind, "name": name, "file": f"{idx:04d}.blob",
+                    "sha256": hashlib.sha256(data).hexdigest(),
+                    "size": len(data)})
+            manifest = {"format": 1, "app": "dur", "revision": rev,
+                        "version": tree["version"], "elements": elements,
+                        "clock": tree["clock"]}
+            manifest["checksum"] = hashlib.sha256(json.dumps(
+                manifest, sort_keys=True,
+                separators=(",", ":")).encode("utf-8")).hexdigest()
+            (rev_dir / "MANIFEST.json").write_text(json.dumps(manifest))
+
+            m.set_persistence_store(
+                DurableFileSystemPersistenceStore(str(tmp_path)))
+            rt2 = m.create_siddhi_app_runtime(_app("dense"))
+            rt2.add_callback("Out", lambda evs: got.extend(tuple(e.data)
+                                                           for e in evs))
+            rt2.start()
+            assert rt2.restore_last_revision() == rev
+            h2 = rt2.get_input_handler(stream)
+            for row, ts in sends[30:]:
+                h2.send(list(row), timestamp=ts)
+            rt2.shutdown()
+            assert got == ref
+        finally:
+            m.shutdown()
+
+
+    def test_an_evicted_revision_keeps_its_files_for_one_more_commit(
+            self, tmp_path):
+        """A reader that has a committed revision's manifest when the
+        next commit evicts that revision still finds every file (the
+        benchmark's reference hashes the revisions of a store whose
+        daemon goes on ticking); the commit after sweeps them."""
+        import hashlib
+        import json
+
+        from siddhi_tpu.durability.capture import dumps_out_of_band
+
+        store = DurableFileSystemPersistenceStore(
+            str(tmp_path), revisions_to_keep=2)
+
+        def save(i):
+            state = {"i": i, "v": np.arange(4096, dtype=np.int64) + i}
+            store.save_tree("a", f"{1000 + i}_a",
+                            [("queries", "q", *dumps_out_of_band(state))])
+
+        save(0)
+        save(1)
+        oldest = tmp_path / "a" / "1000_a.ckpt"
+        with open(oldest / "MANIFEST.json") as f:
+            save(2)     # evicts the revision whose manifest is open
+            manifest = json.load(f)
+        assert store.revisions("a") == ["1001_a", "1002_a"]
+        assert store.load("a", "1000_a") is None
+        assert not (oldest / "MANIFEST.json").exists()
+        assert len(manifest["elements"]) == 2
+        for el in manifest["elements"]:
+            data = (oldest / el["file"]).read_bytes()
+            assert hashlib.sha256(data).hexdigest() == el["sha256"]
+        save(3)
+        assert not oldest.exists()
+        assert store.revisions("a") == ["1002_a", "1003_a"]
+        assert sorted(os.listdir(tmp_path / "a")) == [
+            "1001_a.ckpt", "1002_a.ckpt", "1003_a.ckpt"]
+        assert pickle.loads(store.load("a", "1003_a"))["queries"]["q"][
+            "i"] == 3
+        store.clear_all_revisions("a")
+        assert os.listdir(tmp_path / "a") == []
 
 
 class TestAsyncSyncEquivalence:
@@ -566,6 +770,30 @@ class TestWriterUnit:
         assert w.wait("r3", timeout=10) == "committed"
         assert w.stats.persists_coalesced == 1
         assert w.stats.persist_commits == 2
+        w.shutdown()
+
+    def test_a_committed_job_is_let_go(self):
+        """The job holds the capture, and the capture its device arrays
+        (0.84 GB of HBM at the flagship's size): the idle writer keeps
+        no name on it until the next checkpoint comes."""
+        import gc
+        import weakref
+
+        class Capture:
+            pass
+
+        w = AsyncCheckpointWriter("t")
+        capture = Capture()
+        held = weakref.ref(capture)
+        w.submit("r1", lambda c=capture: None)
+        del capture
+        assert w.wait("r1", timeout=10) == "committed"
+        assert w.wait(timeout=10) == "idle"
+        deadline = time.monotonic() + 5
+        while held() is not None and time.monotonic() < deadline:
+            gc.collect()
+            time.sleep(0.005)
+        assert held() is None
         w.shutdown()
 
     def test_retryable_fault_retries_then_commits(self):

@@ -40,10 +40,16 @@ def test_allowlist_not_stale():
 
 
 def test_early_copies_start_at_the_one_seam():
-    """``copy_to_host_async`` is called by ``DevicePipeline`` alone
-    (core/device_pipeline.py ``_start_copies``): no engine and no shell
-    keeps a copy of the rule that decides which arrays start early."""
-    callers = sorted(
-        str(p.relative_to(REPO)) for p in (REPO / "siddhi_tpu").rglob("*.py")
-        if "copy_to_host_async(" in p.read_text())
-    assert callers == ["siddhi_tpu/core/device_pipeline.py"]
+    """On the way back ``copy_to_host_async`` is called by
+    ``DevicePipeline`` alone (core/device_pipeline.py ``_start_copies``):
+    no engine and no shell keeps a copy of the rule that decides which
+    arrays start early.  The one other caller is no emit: a dense
+    runtime's ``snapshot()`` starts the copies of the fields its
+    snapshot program wrote (core/dense_pattern.py, PR 49)."""
+    callers = {
+        str(p.relative_to(REPO)): p.read_text().count("copy_to_host_async(")
+        for p in (REPO / "siddhi_tpu").rglob("*.py")
+        if "copy_to_host_async(" in p.read_text()}
+    assert sorted(callers) == ["siddhi_tpu/core/dense_pattern.py",
+                               "siddhi_tpu/core/device_pipeline.py"]
+    assert callers["siddhi_tpu/core/dense_pattern.py"] == 1

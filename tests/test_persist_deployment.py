@@ -417,7 +417,17 @@ def test_the_schedules_recover_restores_the_newest_into_a_fresh_runtime(
 # -- spans and counters ------------------------------------------------------
 
 
+def logical_state(pattern):
+    """The dense runtime's state in its logical form, host copies."""
+    return pattern.engine.layout.unpack(pattern.state)
+
+
 def test_a_checkpoints_child_spans_and_counters_carry_its_bytes(tmp_path):
+    """What the barrier holds and what the writer does, by their spans:
+    under ``persist.capture`` the drain and the freeze of the one
+    element, and no fetch (the dense engine snapshots on the device);
+    under ``persist.write`` the wait for each logical field's transfer,
+    the pickle of what stays in band, and a write and a hash a file."""
     config = config_for("dense")
     config["recover_header"] += " @app:trace(sample='1')"
     schedule = schedule_of(config, tmp_path)
@@ -429,9 +439,7 @@ def test_a_checkpoints_child_spans_and_counters_carry_its_bytes(tmp_path):
         revision = rt.persist()
         assert rt.wait_for_persist(revision, 60) == "committed"
         spans = list(rt.app_context.tracer.recorder.spans())
-        state = engine_of(rt).state
-        state_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                          for a in state.values())
+        logical = logical_state(engine_of(rt))
         stats = {k.rsplit(".", 1)[-1]: v for k, v in rt.statistics().items()
                  if ".Durability." in k}
         rt.shutdown()
@@ -444,23 +452,113 @@ def test_a_checkpoints_child_spans_and_counters_carry_its_bytes(tmp_path):
             by_stage[s[1]].append(s)
     (capture,), (write,) = by_stage["persist.capture"], by_stage["persist.write"]
     assert capture[2] == write[2] == "persist"
-    for parent, children in ((capture, ("drain", "fetch", "unpack", "freeze")),
-                             (write, ("pickle", "hash", "store"))):
-        for child in children:
-            (c,) = by_stage["persist." + child]
-            # the parent's cycle id, and inside its interval
-            assert c[0] == parent[0]
-            assert parent[3] <= c[3] <= c[4] <= parent[4]
     assert capture[0] < write[0] and capture[4] <= write[3]
-    (fetch,), (store,) = by_stage["persist.fetch"], by_stage["persist.store"]
-    assert fetch[5] == state_bytes == stats["persist_fetch_bytes"] > 0
-    blob = os.path.join(tmp_path, config["name"], revision + ".ckpt",
-                        "0000.blob")
-    assert (store[5] == by_stage["persist.pickle"][0][5]
-            == by_stage["persist.hash"][0][5] == os.path.getsize(blob)
-            == stats["bytes_written"] > 0)
+    rev_dir = os.path.join(tmp_path, config["name"], revision + ".ckpt")
+    with open(os.path.join(rev_dir, "MANIFEST.json")) as f:
+        files = [el["file"] for el in json.load(f)["elements"]]  # as written
+    for parent, children in ((capture, {"drain": 1, "freeze": 1}),
+                             (write, {"fetch": len(logical), "pickle": 1,
+                                      "store": len(files),
+                                      "hash": len(files)})):
+        for child, n in children.items():
+            found = by_stage["persist." + child]
+            assert len(found) == n, child
+            for c in found:
+                # the parent's cycle id, and inside its interval
+                assert c[0] == parent[0]
+                assert parent[3] <= c[3] <= c[4] <= parent[4]
+    assert not by_stage["persist.unpack"]
+    # the fetch is the writer's: every logical field, by reference
+    state_bytes = sum(a.nbytes for a in logical.values())
+    assert sorted(c[5] for c in by_stage["persist.fetch"]) == sorted(
+        a.nbytes for a in logical.values())
+    assert stats["persist_deferred_bytes"] == state_bytes > 0
+    assert stats["persist_fetch_bytes"] == 0
+    assert stats["capture_fallback_elements"] == 0
+    sizes = [os.path.getsize(os.path.join(rev_dir, f)) for f in files]
+    assert files[0] == "0000.blob" and len(files) > len(logical)
+    assert by_stage["persist.pickle"][0][5] == sizes[0]
+    assert ([c[5] for c in by_stage["persist.store"]] == sizes
+            == [c[5] for c in by_stage["persist.hash"]])
+    assert stats["bytes_written"] == sum(sizes) > state_bytes
     assert stats["persist_ticks_skipped"] == 0
     assert stats["persist_commits"] == 1 and stats["persist_failures"] == 0
+
+
+@pytest.mark.parametrize("engine", ["dense", "devices4"])
+def test_a_capture_outlives_the_steps_that_donate_the_state(engine, tmp_path):
+    """The steps donate the resident rows, so what a capture keeps by
+    reference has to be buffers of its own.  The writer held before it
+    materialises anything, the batches that follow change the captured
+    keys' rows; the revision then committed restores, exactly, the
+    state at the capture, and the fetch was all the writer's."""
+    config = config_for(engine)
+    config["recover_header"] += " @app:faults(seed='1')"
+    schedule = schedule_of(config, tmp_path)
+    n_c = 2
+    m, rt, got, errors = runtime(config, schedule)
+    try:
+        send = rt.get_input_handler(config["stream"]).send_batch
+        for n in range(-PER_PASS, n_c + 1):
+            send(schedule.batch(n))
+        rt.drain_device_emits()
+        pattern = engine_of(rt)
+        at_capture = logical_state(pattern)
+        keys_at_capture = dict(pattern._key_rows)
+        used_at_capture = pattern._row_last_used.copy()
+
+        fi = rt.app_context.fault_injector
+        check, held, go = fi.check, threading.Event(), threading.Event()
+
+        def hold_the_writer(site):
+            if site == "persist.write":
+                held.set()
+                assert go.wait(60)
+            check(site)
+
+        fi.check = hold_the_writer
+        revision = rt.persist()
+        assert held.wait(60)
+        for n in range(n_c + 1, 2 * PER_PASS):
+            send(schedule.batch(n))
+        rt.drain_device_emits()
+        later = logical_state(pattern)
+        assert any((later[k] != v).any() for k, v in at_capture.items())
+        durability = rt._durability_stats()
+        assert durability.persist_deferred_bytes == 0     # nothing fetched
+        go.set()
+        assert rt.wait_for_persist(revision, 60) == "committed"
+        assert durability.persist_fetch_bytes == 0
+        assert durability.persist_deferred_bytes == sum(
+            a.nbytes for a in at_capture.values())
+        assert durability.capture_fallback_elements == 0 and not errors
+        unbroken = sorted(got.rows)
+        rt.shutdown()
+    finally:
+        m.shutdown()
+
+    m, rt, got, errors = runtime(config, schedule)
+    try:
+        assert rt.restore_last_revision() == revision
+        assert schedule.batch_of(rt.applied_time()) == n_c
+        pattern = engine_of(rt)
+        restored = logical_state(pattern)
+        assert sorted(restored) == sorted(at_capture)
+        for k, v in at_capture.items():
+            assert restored[k].dtype == v.dtype and (restored[k] == v).all(), k
+        assert pattern._key_rows == keys_at_capture
+        assert pattern._index is not None
+        assert (pattern._row_last_used == used_at_capture).all()
+        send = rt.get_input_handler(config["stream"]).send_batch
+        for n in range(n_c + 1, 2 * PER_PASS):
+            send(schedule.batch(n))
+        rt.drain_device_emits()
+        want = owed(schedule, 0, 2 * PER_PASS - 1, after=n_c)
+        assert sorted(got.rows) == want and len(want) > 10 and not errors
+        assert [r for r in unbroken if r[0] > schedule.ts_of(n_c)] == want
+        rt.shutdown()
+    finally:
+        m.shutdown()
 
 
 # -- the daemon beside a sending thread --------------------------------------
