@@ -24,11 +24,17 @@ This module holds the pieces every device runtime shares:
   window 1 always: the gate is finished inline, as synchronous ingest
   did.  The stage opens the window to 2 by itself (``PipelineRule``,
   below) only while it observes a caller that comes straight back for
-  more and a gate that keeps the host waiting: then ONE batch stays in
-  flight past ``send_batch``'s return, its gate fetched after the next
-  batch's conversion, ``device_put`` and step dispatch are out, so the
-  step of batch N runs while the host prepares N+1.  What bounds it:
-  never more than one batch; the next ``submit``, any flush barrier or,
+  more and a gate that keeps the host waiting: ``ENGAGE_RUN`` batches
+  running whose inline gate took ``BLOCKED_MIN_S`` or more and whose
+  sender was back within ``THINK_SHARE`` of it.  On the chip that is
+  every closed loop over a device query (a window, a fused chain, a
+  table join's probe, a pattern); it is never a handful of batches, a
+  paced source or a console.  Then ONE batch stays in flight past
+  ``send_batch``'s return, its callbacks owed, its gate fetched after
+  the next batch's conversion, ``device_put`` and step dispatch are
+  out, so the step of batch N runs while the host prepares N+1.  What
+  bounds it: never more than one batch; the next ``submit``, any flush
+  barrier or,
   when neither comes within about a cycle, the app's ``IdleFinisher``
   thread finishes it, in submit order; one slow arrival, a barrier or
   an idle finish returns the stage to inline.  An explicit
@@ -57,6 +63,13 @@ finishes under that lock, and a drain of a stage that has ever deferred
 takes it (core/device_pipeline.py): so an entry is finished once, by
 one of the three, oldest first.  A junction's async worker holds no
 lock: its submits never defer and its stage never meets the finisher.
+
+What defers with the emit is whatever the emit does: a query whose
+rows end in a table or a named window would leave its last batch
+unwritten past ``send_batch``'s return, and a reader on another stream
+would miss it.  The planner, which knows where a query's rows go, pins
+such a query's stage at 1 (planner/app_planner.py
+``_pin_state_writers``); nothing here knows it.
 """
 
 from __future__ import annotations
@@ -108,7 +121,8 @@ class IngestStats:
         self.pipeline_entries = 0
         self.pipeline_exits = 0
         self.max_staging_depth = 0
-        # the window the rule runs at now (0 = pinned by ingest.depth)
+        # the window the rule runs at now (0 = pinned: ingest.depth, the
+        # debugger, or the planner for a query that writes state)
         self.auto_depth = 0
 
     def note_depth(self, depth: int):
@@ -195,27 +209,22 @@ def staged_put(x, sharding=None, faults=None, stats: Optional[IngestStats] = Non
 #
 # The thresholds of ``PipelineRule``, each with its reason.
 
-#: An inline gate is worth hiding only if it kept the host this long.
-#: The steps the regime is for block the host 6.7-12 ms a batch (the
-#: pattern cells on the chip, PERF.md).  A gate of 1-2 ms is a round
-#: trip with little device work behind it (the window cells, four
-#: chips): a batch in flight would buy a tenth at best and cost every
-#: caller the read straight after a send.  Tier-1's steps on the CPU
-#: are under a millisecond but for three apps (the hot-key scan, a
-#: fused chain, the smoke rehearsal: 5-8.5 ms under six workers), and
-#: those ENGAGE_RUN keeps out or they end on a barrier.
-BLOCKED_MIN_S = 4e-3
+#: An inline gate is worth hiding only if it kept the host this long:
+#: only then is there a step behind it.  The floor sits between the
+#: longest gate that is the count's way back alone (0.9 ms on the v5e)
+#: and the shortest with a step behind it (1.5 ms): PERF.md section 6,
+#: PR 50, has the per-arrival log.
+BLOCKED_MIN_S = 1.25e-3
 #: ... and only if the caller was back for more within this share of
-#: that wait: a closed loop comes back in under a tenth of it, a paced
-#: source after many times it.  Leaving takes a gap LONGER than the
-#: wait, so between a quarter of the wait and the whole of it the stage
-#: keeps the regime it has: it cannot flap on a gap that hovers.
+#: that wait (a closed loop: 0.06-0.19 of it; a paced source: a hundred
+#: times it).  Leaving takes a gap LONGER than the wait, so between a
+#: quarter of the wait and the whole of it the stage keeps the regime
+#: it has: it cannot flap on a gap that hovers.
 THINK_SHARE = 0.25
 #: Consecutive batches that have to qualify before the window opens.  A
 #: caller who sends a handful of batches and reads the result straight
-#: after the last (a console, most tests: of tier-1's 62 cases that
-#: read after a send none runs even four gates of a millisecond in a
-#: row) never engages; a stream does with its ninth batch.
+#: after the last (a console, most tests) never engages whatever its
+#: gates; a stream does with its ninth batch.
 ENGAGE_RUN = 8
 #: The idle finisher takes a staged gate nothing has come for within
 #: this many observed cycles (submit to submit).  One cycle is when the
@@ -492,7 +501,8 @@ class IngestStage:
 
     def pin(self, depth: int) -> None:
         """Finish what is staged and hold the window at ``depth`` from
-        here on, the rule off (the debugger: every emit at its batch)."""
+        here on, the rule off (the debugger: every emit at its batch;
+        the planner: a query whose rows end in a table)."""
         self.flush()
         self.rule = None
         self.depth = max(1, int(depth))

@@ -896,6 +896,62 @@ class AppPlanner:
                 qname, "@app:hotkeys pinned but the query fused "
                 "(precedence: fuse > hotkeys)")
 
+    def _pin_state_writers(self):
+        """A staged count gate defers a batch's emit and whatever the
+        emit does (core/ingest_stage.py).  Rows that end in a table, a
+        named window or an aggregation, at once or through the streams
+        between, are state that a query on ANOTHER stream reads: left
+        in flight past ``send_batch``'s return they would be missed.
+        The stage of a device query that writes such state stays
+        inline, as under ``ingest.depth='1'``; a window the app pinned
+        itself is left as it is."""
+        from siddhi_tpu.core.query import (
+            InsertIntoStreamCallback,
+            QueryCallbackOutput,
+        )
+        from siddhi_tpu.planner.fusion import _query_inputs
+
+        sa = self.siddhi_app
+        queries = []
+        for element in sa.execution_elements:
+            queries.extend(element.queries if isinstance(element, Partition)
+                           else [element])
+        # the targets whose events are state or reach it: a fixpoint over
+        # the queries' inputs and targets, by junction key
+        feeds = set(sa.table_definitions) | set(sa.window_definitions) | {
+            ad.input_stream.stream_id
+            for ad in sa.aggregation_definitions.values()}
+        grew = True
+        while grew:
+            grew = False
+            for q in queries:
+                out = q.output_stream
+                key = self._key(getattr(out, "target", None) or "",
+                                getattr(out, "is_inner", False),
+                                getattr(out, "is_fault", False))
+                if key in feeds:
+                    new = set(_query_inputs(q)) - feeds
+                    feeds |= new
+                    grew = grew or bool(new)
+        key_of = {id(j): key for key, j in self.junctions.items()}
+
+        def writes_state(output) -> bool:
+            if isinstance(output, InsertIntoStreamCallback):
+                return key_of.get(id(output.junction)) in feeds
+            # else a table's callback or a named window's
+            return not isinstance(output, QueryCallbackOutput)
+
+        planned = list(self.query_runtimes.values())
+        for pr in self.partition_runtimes.values():
+            planned.extend(getattr(pr, "dense_query_runtimes", {}).values())
+        for qr in planned:
+            rt = (getattr(qr, "device_runtime", None)
+                  or getattr(qr, "pattern_processor", None))
+            stage = getattr(rt, "ingest_stage", None)
+            if (stage is not None and stage.rule is not None
+                    and writes_state(qr.output)):
+                stage.pin(1)
+
     def build(self):
         from siddhi_tpu.core.app_runtime import SiddhiAppRuntime
         from siddhi_tpu.planner.query_planner import QueryPlanner
@@ -995,6 +1051,8 @@ class AppPlanner:
                 pr = PartitionRuntime(element, self, pi)
                 pi += 1
                 self.partition_runtimes[pr.name] = pr
+
+        self._pin_state_writers()
 
         input_manager = InputManager(self.app_context)
         for key, j in self.junctions.items():

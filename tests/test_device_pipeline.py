@@ -27,6 +27,14 @@ a failing deferred gate drops that batch only; its ``step`` span starts
 at its ``resolve()``; the counters say who finished what; ``shutdown()``
 leaves no thread behind.
 
+And, on the window, the fused chain and the table join, to the rule
+itself (nothing forced: ``ChipModel`` gives the stage's clock the
+timing the v5e shows on those cells, a gate of 2.5 ms and a sender back
+in 0.3): a closed loop of twenty batches and more engages, and its rows
+are those of ``ingest.depth='1'``, in order; on the join, whose loop
+holds an upsert batch, every probe answers from the table as the last
+upsert sent before it left it.
+
 And to the early copies (``DevicePipeline._start_copies``): a step's
 counts, then the emit arrays of the chunk positions whose last batch
 owed rows and has kept foretelling the next, start for the host inside
@@ -44,6 +52,7 @@ import time
 
 import numpy as np
 import pytest
+from loop_clock import LoopClock, PacedClock
 
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.core.device_pipeline import DevicePipeline
@@ -141,6 +150,9 @@ CASES = {
         "define stream Ins (k int, v float); "
         "@PrimaryKey('k') define table T (k int, v float); "
         "from Ins insert into T; "
+        "define stream Ups (k int, v float); "
+        "@info(name='ups') from Ups select k, v "
+        "update or insert into T on T.k == k; "
         "@info(name='q') from S join T as t on S.k == t.k "
         "select S.k as k, S.x as x, t.v as v insert into Out;",
         probe_batch, fill_table, "devtable_join", "devtable",
@@ -148,13 +160,15 @@ CASES = {
 }
 KINDS = list(CASES)
 N_BATCHES = 6
+# the reference of every case: the window pinned at 1
+INLINE = "ingest.depth='1'"
 
 
 class Deployed:
     """One case's app, started, with its rows, its listener and its
     runtime shell at hand."""
 
-    def __init__(self, kind, depths="", trace=""):
+    def __init__(self, kind, depths="", trace="", paced=False):
         (opts, extra, body, self.make, setup, self.engine_kind, lowering,
          self.breaks) = CASES[kind]
         opts = ", ".join(o for o in (opts, depths) if o)
@@ -179,6 +193,13 @@ class Deployed:
                       or queries["q"].pattern_processor)
         self.pipe = self.shell.pipeline
         assert isinstance(self.pipe, DevicePipeline)
+        if paced:
+            # a case that holds a batch's rows to its own ``send_batch``
+            # runs under the rule, with a sender the rule never admits:
+            # left to the host's clock, nine arrivals whose gates a busy
+            # host stretched past the floor would leave one in flight
+            # (the hot-key shell submits twice a batch)
+            self.pipe.ingest_stage.clock = PacedClock()
 
     def send(self, i, quiet=False):
         """Batch ``i``; returns the rows it delivered at once."""
@@ -196,7 +217,7 @@ class Deployed:
 
 def reference(kind):
     """Rows per batch at depth 1, where every batch delivers inline."""
-    with Deployed(kind) as app:
+    with Deployed(kind, depths=INLINE) as app:
         per_batch = [app.send(i) for i in range(N_BATCHES)]
         if kind == "hotkey":
             assert app.shell.hot_stats.routed_cycles > 0
@@ -204,9 +225,9 @@ def reference(kind):
     return per_batch
 
 
-class _BrokenGate:
-    """A pending whose count-gate fetch fails, as XLA reports an
-    asynchronous step failure."""
+class _Gate:
+    """A step's ``pending`` passed through, for a case to change what
+    its ``resolve()`` does."""
 
     def __init__(self, pending):
         self.pending = pending
@@ -215,7 +236,7 @@ class _BrokenGate:
         return self.pending.probe()
 
     def resolve(self):
-        raise RuntimeError("injected count-gate failure")
+        return self.pending.resolve()
 
     def gates(self):
         return self.pending.gates()
@@ -224,10 +245,18 @@ class _BrokenGate:
         return self.pending.device_arrays()
 
 
+class _BrokenGate(_Gate):
+    """A pending whose count-gate fetch fails, as XLA reports an
+    asynchronous step failure."""
+
+    def resolve(self):
+        raise RuntimeError("injected count-gate failure")
+
+
 @pytest.mark.parametrize("kind", KINDS)
 def test_failing_count_gate_drops_one_batch(kind):
     want = reference(kind)
-    with Deployed(kind) as app:
+    with Deployed(kind, paced=True) as app:
         got = [app.send(i) for i in range(3)]
         real, broken = app.pipe.submit, []
 
@@ -350,7 +379,9 @@ def test_burst_ends_and_its_last_batch_is_delivered(kind, racing_drain,
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_inline_app_never_starts_the_finisher(kind):
-    with Deployed(kind) as app:
+    """A sender that is away between its batches never opens the
+    window, whatever their gates took, and the app has no thread."""
+    with Deployed(kind, paced=True) as app:
         for i in range(N_BATCHES):
             app.send(i)
         st = app.pipe.ingest_stats
@@ -493,6 +524,183 @@ def test_exception_in_the_shell_closes_the_token_as_raised(kind, monkeypatch):
             assert app.send(4), "the runtime serves on"
 
 
+# -- the rule itself, on the chip's timing ------------------------------------
+
+
+class _TimedGate(_Gate):
+    """A pending whose step is done at ``done_at`` on the model's
+    clock: resolving it earlier keeps the host until then."""
+
+    def __init__(self, pending, clock, done_at):
+        super().__init__(pending)
+        self.clock, self.done_at = clock, done_at
+
+    def resolve(self):
+        self.clock.resolved(self.done_at)
+        return self.pending.resolve()
+
+
+class ChipModel:
+    """The timing of a closed loop on the chip, on the injected clock of
+    one pipeline's stage (the steps themselves run on the CPU as they
+    are).  The window cells and the fused chain on the v5e: a gate of
+    2.5 ms, a sender back in 0.3 (PERF.md, PR 50)."""
+
+    def __init__(self, pipe, think_s=0.3e-3, host_s=1.0e-3, step_s=2.5e-3):
+        self.clock = clock = LoopClock()
+        self.think_s = think_s
+        self.away_s = 0.0       # the sender's next gap is longer by this
+        stage, submit = pipe.ingest_stage, pipe.submit
+        arrive = stage.arrive
+        stage.clock = clock
+
+        def arrives():
+            clock.arrives(arrive, self.think_s + self.away_s, host_s)
+            self.away_s = 0.0
+
+        def submits(tok, pending, build, emit):
+            if pending is not None:
+                pending = _TimedGate(pending, clock,
+                                     clock.dispatched(step_s))
+            submit(tok, pending, build, emit)
+
+        stage.arrive, pipe.submit = arrives, submits
+
+
+def upsert_batch(j, n=6):
+    """Upsert batch ``j``: six of the table's keys, two of them new the
+    first time, every value one that names the batch."""
+    k = (np.arange(n) * 2 + j) % 12
+    return EventBatch("Ups", ["k", "v"], {
+        "k": k.astype(np.int32),
+        "v": (1000.0 * (j + 1) + k).astype(np.float32)},
+        np.full(n, 950 + j, dtype=np.int64))
+
+
+LOOP = 36           # batches of the closed loop, an upsert every twelfth
+
+
+def closed_loop(app, kind, model=None):
+    """The loop's rows, and on the join the table's ``v`` of every key
+    as the upserts sent so far have left it, probe batch by probe
+    batch."""
+    table, seen = {k: k * 1.5 for k in range(8)}, []
+    ups = (app.rt.get_input_handler("Ups") if kind == "devtable" else None)
+    for i in range(LOOP):
+        if ups is not None and i % 12 == 11:
+            b = upsert_batch(i // 12)
+            ups.send_batch(b)
+            table.update(zip(b.columns["k"].tolist(),
+                             b.columns["v"].tolist()))
+            if model is not None:
+                model.away_s = 12.7e-3      # what an upsert batch takes
+        else:
+            app.send(i)
+            seen.append(dict(table))
+    app.shell.drain()
+    return list(app.rows), seen
+
+
+@pytest.mark.parametrize("kind", ["window", "fused", "devtable"])
+def test_a_closed_loop_engages_by_the_rule_and_keeps_its_rows(kind):
+    with Deployed(kind, depths=INLINE) as app:
+        want, _ = closed_loop(app, kind)
+        assert app.pipe.ingest_stats.max_staging_depth == 1
+    assert want
+    with Deployed(kind) as app:
+        got, seen = closed_loop(app, kind, ChipModel(app.pipe))
+        st = app.pipe.ingest_stats
+        assert st.pipeline_entries >= 1 and st.gates_by_submit >= 1
+        assert st.max_staging_depth == 2, "never more than one in flight"
+        assert st.dropped_batches == 0 and not app.errors
+        assert len(app.pipe.ingest_stage) == 0
+        assert got == want
+        if kind != "devtable":
+            return
+        # the upsert query's own stage is the planner's: inline
+        ups = app.rt.query_runtimes["ups"].device_runtime.ingest_stats
+        assert (ups.pipeline_entries, ups.max_staging_depth) == (0, 1)
+        # the sender's absence closed the window, the run reopened it
+        assert st.pipeline_entries == 3 and st.pipeline_exits == 3
+        # every probe row carries its key's value as the upserts SENT
+        # before that probe left it: none missed, none from a later one
+        probes = [i for i in range(LOOP) if i % 12 != 11]
+        by_ts = {1_000 + i * 10: table for i, table in zip(probes, seen)}
+        assert len(got) > 10 * len(probes)
+        for ts, (k, _x, v) in got:
+            assert v == pytest.approx(by_ts[ts][k]), (ts, k)
+
+
+def test_a_table_writer_stays_inline_whatever_its_gates():
+    """A bulk load in a closed loop whose every gate is one the rule
+    admits (the chip's timing on the WRITER's stage: 2.5 ms, back in
+    0.3), then a probe from the other stream straight after the last
+    upsert, no barrier between: the planner pinned the writer's stage
+    because its rows end in a table, so the probe answers from the
+    table as the last upsert left it."""
+    with Deployed("devtable") as app:
+        writer = app.rt.query_runtimes["ups"].device_runtime
+        stage, st = writer.ingest_stage, writer.ingest_stats
+        assert stage.rule is None and st.as_dict()["autoIngestDepth"] == 0
+        ChipModel(writer.pipeline)
+        ups = app.rt.get_input_handler("Ups")
+        table = {k: k * 1.5 for k in range(8)}
+        for j in range(20):
+            b = upsert_batch(j)
+            ups.send_batch(b)
+            table.update(zip(b.columns["k"].tolist(),
+                             b.columns["v"].tolist()))
+        got = app.send(0)
+        assert (st.staged_batches, st.pipeline_entries,
+                st.max_staging_depth, len(stage)) == (20, 0, 1, 0)
+        assert len(got) == 16 and not app.errors
+        for _ts, (k, _x, v) in got:
+            assert v == pytest.approx(table[k]), k
+        # the probe query, whose rows go to a stream nothing reads into
+        # a table, keeps its rule
+        assert app.pipe.ingest_stage.rule is not None
+
+
+@pytest.mark.parametrize("body, pinned", [
+    # a table's writer, and the query whose stream feeds it
+    ("define table T (k int, v double); "
+     "@info(name='a') from S#window.length(4) select k, sum(v) as v "
+     "insert into Mid; "
+     "@info(name='b') from Mid#window.length(2) select k, v insert into T;",
+     {"a": True, "b": True}),
+    # a named window's writer; a stream that only a callback reads
+    ("define window W (k int, v double) length(4); "
+     "@info(name='a') from S#window.length(4) select k, sum(v) as v "
+     "insert into W; "
+     "@info(name='b') from S#window.length(2) select k, sum(v) as v "
+     "insert into Out;",
+     {"a": True, "b": False}),
+    # the app's own pin is the app's
+    ("define table T (k int, v double); "
+     "@info(name='a') from S#window.length(4) select k, sum(v) as v "
+     "insert into T;",
+     {"a": 2}),
+], ids=["table_through_a_stream", "named_window", "pinned_by_the_app"])
+def test_the_planner_pins_the_stage_of_a_query_that_writes_state(
+        body, pinned):
+    m = SiddhiManager()
+    try:
+        depth = "ingest.depth='2'" if 2 in pinned.values() else ""
+        rt = m.create_siddhi_app_runtime(
+            f"@app:execution('tpu'{', ' + depth if depth else ''}) "
+            "define stream S (k int, v double); " + body)
+        for name, want in pinned.items():
+            stage = rt.query_runtimes[name].device_runtime.ingest_stage
+            if want is True:
+                assert stage.rule is None and stage.depth == 1, name
+            elif want is False:
+                assert stage.rule is not None, name
+            else:
+                assert stage.rule is None and stage.depth == want, name
+    finally:
+        m.shutdown()
+
+
 # -- the early copies ---------------------------------------------------------
 
 
@@ -569,7 +777,7 @@ def test_copies_start_inside_submit_counts_first(kind, monkeypatch):
     """Once a batch has owed rows, the next one's counts and then its
     emit arrays start for the host inside ``submit``, before the gate
     is resolved, and its drain dispatches no concatenation."""
-    with Deployed(kind) as app:
+    with Deployed(kind, paced=True) as app:
         for i in range(4):
             assert app.send(i) or i < 2
         log = CopyLog(monkeypatch)
@@ -661,6 +869,7 @@ def test_a_stream_that_matches_nothing_starts_no_array(kind, monkeypatch):
         seen, pushed = submitted(app)
         for i in range(N_BATCHES):
             assert app.send(i, quiet=True) == []
+        app.shell.drain()
         assert len(seen) >= N_BATCHES and not app.rows
         # the counts travel (the gate needs them); no column does
         copies = [shape for what, _id, shape in log.calls if what == "copy"]
@@ -683,7 +892,7 @@ def test_a_wasted_copy_doubles_the_run_a_position_has_to_show(kind):
     twice), and the fourth is started early again and takes ``need``
     back to 1."""
     want = reference(kind)
-    with Deployed(kind) as app:
+    with Deployed(kind, paced=True) as app:
         st = app.pipe.emit_stats
 
         def counters():

@@ -29,6 +29,7 @@ import threading
 
 import numpy as np
 import pytest
+from loop_clock import LoopClock
 
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.core.dense_pattern import DensePatternRuntime
@@ -43,6 +44,7 @@ from siddhi_tpu.core.ingest_stage import (
     ENGAGE_RUN,
     IdleFinisher,
     IngestStage,
+    THINK_SHARE,
 )
 from siddhi_tpu.util.persistence import InMemoryPersistenceStore
 
@@ -318,14 +320,6 @@ class TestIngestFaultDifferential:
 # -- the rule, on an injected clock -------------------------------------------
 
 
-class Clock:
-    def __init__(self):
-        self.t = 100.0
-
-    def __call__(self):
-        return self.t
-
-
 class Watcher:
     """What a stage needs of the app's idle finisher, with no thread."""
 
@@ -345,31 +339,25 @@ class Driven:
     HOST_S = 0.002
 
     def __init__(self, depth=None):
-        self.clock = Clock()
+        self.clock = LoopClock()
         self.finisher = Watcher()
         self.stage = IngestStage(depth, finisher=self.finisher,
                                  clock=self.clock)
-        self.done_at = {}       # batch -> when its step is done
         self.finished = []      # batches in the order their gates resolved
         self.n = 0
 
     def batch(self, think_s, step_s):
-        n, stage, clock = self.n, self.stage, self.clock
+        n, clock = self.n, self.clock
         self.n += 1
-        clock.t += think_s
-        stage.arrive()
-        clock.t += self.HOST_S
-        # the device takes one step at a time
-        self.done_at[n] = max(clock.t, self.done_at.get(n - 1, 0.0)) + step_s
+        clock.arrives(self.stage.arrive, think_s, self.HOST_S)
+        done_at = clock.dispatched(step_s)
 
         def finish():
-            blocked = max(0.0, self.done_at[n] - clock.t)
-            clock.t += blocked
             self.finished.append(n)
-            return blocked
+            return clock.resolved(done_at)
 
-        stage.submit(None, finish)
-        return stage.depth
+        self.stage.submit(None, finish)
+        return self.stage.depth
 
     def stream(self, n, think_s, step_s):
         return [self.batch(think_s, step_s) for _ in range(n)]
@@ -378,10 +366,17 @@ class Driven:
 class TestPipelineRule:
     STEP = 3 * BLOCKED_MIN_S
 
-    def test_engages_after_the_run_and_not_before(self):
+    # (gate, think) of the closed loops the rule is for: the pattern
+    # cells' 6.7-12 ms, then what the v5e shows on the window cells and
+    # the fused chain (2.5 ms, back in 0.3) and on the table join (1.9)
+    @pytest.mark.parametrize("step_s, think_s", [
+        (3 * BLOCKED_MIN_S, 3 * BLOCKED_MIN_S / 20),
+        (2.5e-3, 0.3e-3),
+        (1.9e-3, 0.3e-3),
+    ], ids=["long_gate", "gate_2.5ms", "gate_1.9ms"])
+    def test_engages_after_the_run_and_not_before(self, step_s, think_s):
         d = Driven()
-        depths = d.stream(ENGAGE_RUN + 4, think_s=self.STEP / 20,
-                          step_s=self.STEP)
+        depths = d.stream(ENGAGE_RUN + 4, think_s=think_s, step_s=step_s)
         # batch 0 has no think; ENGAGE_RUN arrivals qualify after it
         assert depths == [1] * ENGAGE_RUN + [2] * 4
         st = d.stage.stats
@@ -397,8 +392,16 @@ class TestPipelineRule:
         (40 * BLOCKED_MIN_S, 3 * BLOCKED_MIN_S),   # a slow cadence
         (3 * BLOCKED_MIN_S, 3 * BLOCKED_MIN_S),    # back as late as the wait
         (0.0, BLOCKED_MIN_S / 2),                  # a wait not worth hiding
+        (0.0, 0.9 * BLOCKED_MIN_S),                # ... just under the floor
         (0.0, 0.0),                                # no wait at all
-    ], ids=["slow_cadence", "think_equals_wait", "short_wait", "no_wait"])
+        # a paced source, a hundred gates between its batches: at the
+        # paced cell's gate, at a window cell's, at the flagship's
+        (100 * 1.2e-3, 1.2e-3),
+        (100 * 2.5e-3, 2.5e-3),
+        (100 * 12e-3, 12e-3),
+    ], ids=["slow_cadence", "think_equals_wait", "short_wait",
+            "under_the_floor", "no_wait", "paced_1.2ms", "paced_2.5ms",
+            "paced_12ms"])
     def test_never_engages(self, think_s, step_s):
         d = Driven()
         assert set(d.stream(200, think_s, step_s)) == {1}
@@ -428,19 +431,39 @@ class TestPipelineRule:
         assert d.stream(ENGAGE_RUN, 0.0, self.STEP) == (
             [1] * (ENGAGE_RUN - 1) + [2])
 
+    @pytest.mark.parametrize("step_s", [3 * BLOCKED_MIN_S, 2.5e-3],
+                             ids=["long_gate", "gate_2.5ms"])
     @pytest.mark.parametrize("engaged", [False, True])
-    def test_a_gap_between_the_thresholds_changes_nothing(self, engaged):
-        """Hysteresis: in needs a think under a quarter of the wait,
-        out one over the whole of it; between them the regime stays."""
+    def test_a_gap_between_the_thresholds_changes_nothing(self, engaged,
+                                                          step_s):
+        """Hysteresis: in needs a think under ``THINK_SHARE`` of the
+        wait, out one over the whole of it; between them the regime
+        stays, so a think that hovers cannot make the stage flap."""
         d = Driven()
         if engaged:
-            d.stream(ENGAGE_RUN + 1, 0.0, self.STEP)
+            d.stream(ENGAGE_RUN + 1, 0.0, step_s)
         want = 2 if engaged else 1
         for i in range(100):
-            think = self.STEP * (0.3 if i % 2 else 0.9)
-            assert d.batch(think, self.STEP) == want
+            think = step_s * (THINK_SHARE + 0.05 if i % 2 else 0.95)
+            assert d.batch(think, step_s) == want
         st = d.stage.stats
         assert st.pipeline_entries == int(engaged) and st.pipeline_exits == 0
+
+    def test_a_leave_once_a_pass_comes_back_after_the_run(self):
+        """The table join's loop: nineteen probe batches come straight
+        back, then the sender is away for a whole upsert batch.  The
+        stage leaves at that arrival (the staged batch first), is back
+        after the run, and hides the rest of the pass."""
+        d, step, per_pass = Driven(), 1.9e-3, 20
+        d.stream(ENGAGE_RUN + 1, 0.3e-3, step)
+        for p in range(1, 6):
+            assert d.batch(think_s=12.7e-3, step_s=step) == 1
+            depths = d.stream(per_pass - 2, 0.3e-3, step)
+            assert depths == [1] * (ENGAGE_RUN - 1) + [2] * (
+                per_pass - 1 - ENGAGE_RUN)
+            st = d.stage.stats
+            assert (st.pipeline_entries, st.pipeline_exits) == (p + 1, p)
+        assert d.finished == list(range(d.n - 1)) and len(d.stage) == 1
 
     def test_a_barrier_returns_the_stage_to_inline(self):
         d = Driven()

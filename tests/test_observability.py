@@ -17,6 +17,7 @@ import urllib.request
 
 import numpy as np
 import pytest
+from loop_clock import PacedClock
 
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.core.event import EventBatch
@@ -435,6 +436,13 @@ def test_spans_tile_send_batch(path, monkeypatch):
         rows = []
         rt.add_callback("Out", rows.extend)
         rt.start()
+        # the structure held below is that of a cycle whose gate is
+        # finished inside its own send (a staged gate's is
+        # tests/test_device_pipeline.py's): the stage's rule is left on
+        # and shown a sender it never admits, where the host's clock
+        # would let the ninth of these sends leave a batch in flight
+        for dr in rt._device_runtimes():
+            dr.ingest_stage.clock = PacedClock()
         h = rt.get_input_handler("S")
 
         def tile(sends, cycles, remainders, shares):

@@ -71,6 +71,9 @@ def run(app, sends, store=None, upto=None):
             h.send_batch(EventBatch(
                 "S", NAMES, {k: v.copy() for k, v in cols.items()},
                 ts.copy()))
+        # the barrier before anything is read: a closed loop of nine
+        # batches may have left the last one's gate staged
+        rt.drain_device_emits()
         rev = rt.persist() if upto is not None else None
         lowering = rt.lowering()
         dr = getattr(rt.query_runtimes["q0"], "device_runtime", None)
