@@ -11,9 +11,10 @@ This module holds the pieces every device runtime shares:
 
 - ``IngestStats``: per-runtime staging counters surfaced through
   ``util/statistics.py`` (``stagedBatches`` / ``devicePuts`` /
-  ``deviceChunks`` / ``fusedHops`` / ``ingestStalls`` /
-  ``overlappedBatches`` / ``flushSyncs`` / ``maxStagingDepth``, and how
-  often the window opened: ``gatesBySubmit`` / ``gatesByIdle`` / ``pipelineEntries`` /
+  ``deviceChunks`` / ``steppedLanes`` / ``fusedHops`` /
+  ``ingestStalls`` / ``overlappedBatches`` / ``flushSyncs`` /
+  ``maxStagingDepth``, and how often the window opened:
+  ``gatesBySubmit`` / ``gatesByIdle`` / ``pipelineEntries`` /
   ``pipelineExits``).
 - ``IngestStage``: a bounded staging window.  ``submit(probe, finish)``
   records one dispatched batch whose count gate has NOT been fetched
@@ -90,8 +91,8 @@ class IngestStats:
     thin-gauge style as ``EmitStats``)."""
 
     __slots__ = ("staged_batches", "device_puts", "device_chunks",
-                 "fused_hops", "ingest_stalls", "overlapped_batches",
-                 "flush_syncs",
+                 "stepped_lanes", "fused_hops", "ingest_stalls",
+                 "overlapped_batches", "flush_syncs",
                  "dropped_batches",
                  "max_staging_depth", "auto_depth", "gates_by_submit",
                  "gates_by_idle", "pipeline_entries", "pipeline_exits")
@@ -102,6 +103,12 @@ class IngestStats:
         # chunks the batches were cut into for the device (the window
         # path, ops/device_query.py: a chunk is a put and a dispatch)
         self.device_chunks = 0
+        # lanes the dense engine's programs stepped, padding and all: a
+        # batch's first program at its padded width, every later round
+        # at the width its loop slices it at (ops/dense_nfa.py
+        # ``rounds_lanes``); over the events sent, the lanes an event
+        # costs the device.  0 on every other engine
+        self.stepped_lanes = 0
         # junction hops a fused chain kept on the device: stages - 1 a
         # batch (core/fused_graph.py); 0 on every other runtime
         self.fused_hops = 0
@@ -134,6 +141,7 @@ class IngestStats:
             "stagedBatches": self.staged_batches,
             "devicePuts": self.device_puts,
             "deviceChunks": self.device_chunks,
+            "steppedLanes": self.stepped_lanes,
             "fusedHops": self.fused_hops,
             "ingestStalls": self.ingest_stalls,
             "overlappedBatches": self.overlapped_batches,

@@ -46,6 +46,10 @@ inline), and ``cycle``, from ``begin_cycle`` to the end of ``emit``:
 how soon a batch's matches reach the callback (in the ring: the
 cycle's first span's start to its ``emit``'s end).
 
+One count is a tuple in the ring and no interval (``lanes``,
+:func:`counted`): the lanes the dense engine's programs step for the
+batch, which it knows from the round plan and fetches nothing for.
+
 The code that does that work lives in engines that know no tracer
 (``ops/``, ``parallel/``, ``core/ingest_stage.py``, the runtime
 shells).  It reaches the cycle through :func:`span`: ``begin_cycle``
@@ -114,6 +118,10 @@ CYCLE_STAGES = (STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT, STAGE_PLAN,
                 STAGE_PANE, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH,
                 STAGE_POLL, STAGE_STEP, STAGE_EMIT, STAGE_FETCH,
                 STAGE_BUILD, STAGE_DELIVER, STAGE_MUTATE)
+#: a count of a cycle that is no interval: one zero-width tuple in the
+#: ring (:func:`counted`), no annotation and no histogram
+STAGE_LANES = "lanes"        # lanes the batch's programs step (dense engine)
+CYCLE_COUNTS = (STAGE_LANES,)
 #: intervals of a cycle kept as histograms alone (module docstring)
 STAGE_STAGED = "staged"      # dispatch to the start of a deferred gate's fetch
 STAGE_CYCLE = "cycle"        # begin_cycle to the end of emit
@@ -129,7 +137,8 @@ ROUND_STAGES = (STAGE_CONVERT, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH)
 #: second host-stepped round or, on the dense engine, the rounds
 #: program's lanes, put and dispatch.  The tracer sizes the recorder's
 #: ring from it.
-SPANS_PER_CYCLE = len(CYCLE_STAGES) + 2 + len(ROUND_STAGES)
+SPANS_PER_CYCLE = (len(CYCLE_STAGES) + len(CYCLE_COUNTS) + 2
+                   + len(ROUND_STAGES))
 #: checkpoint-path stages (free-running, engine kind 'persist')
 STAGE_PERSIST_CAPTURE = "persist.capture"
 STAGE_PERSIST_WRITE = "persist.write"
@@ -290,6 +299,15 @@ def span(stage: str, count: int = 0):
     open).  For code that does a batch's work and knows no tracer."""
     tok = getattr(_open, "tok", None)
     return _NO_SPAN if tok is None else Span(tok, stage, count)
+
+
+def counted(stage: str, count: int) -> None:
+    """A count of the calling thread's open cycle that took no time of
+    its own (``CYCLE_COUNTS``): nothing for an unsampled cycle."""
+    tok = getattr(_open, "tok", None)
+    if tok is not None:
+        now = tok.tracer.clock()
+        tok.record(stage, now, now, count)
 
 
 def reopen(tok: Optional["CycleToken"]) -> Optional["CycleToken"]:

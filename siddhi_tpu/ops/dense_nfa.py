@@ -95,7 +95,9 @@ from siddhi_tpu.observability.trace import (
     SCOPE_DENSE_SCATTER,
     STAGE_CONVERT,
     STAGE_DISPATCH,
+    STAGE_LANES,
     STAGE_PLAN,
+    counted,
     span,
 )
 from siddhi_tpu.ops.dense_layout import OVERFLOW, ROWS, DenseStateLayout
@@ -1304,6 +1306,25 @@ class DensePatternEngine:
     #: width of the widest
     ROUNDS_NARROW = 8
 
+    def rounds_ladder(self, R: int) -> List[Tuple[int, int]]:
+        """The wide loops of a rounds program of ``R`` padded lanes,
+        widest first: the static width each slices a round at, and the
+        round width at which it hands over to the next (the last to the
+        run).  A segment no wider than the run has none."""
+        widths = [w for w in (R, R // self.ROUNDS_NARROW)
+                  if w > self.RUN_WIDTH]
+        return list(zip(widths, widths[1:] + [self.RUN_WIDTH]))
+
+    def rounds_lanes(self, R: int, widths: np.ndarray) -> int:
+        """Lanes a rounds program of ``R`` padded lanes steps for
+        rounds ``widths`` wide (each no wider than the one before):
+        every round at the width of the loop that takes it, a link of
+        the run at ``RUN_WIDTH``."""
+        sliced = np.full(len(widths), self.RUN_WIDTH, dtype=np.int64)
+        for w, narrower in reversed(self.rounds_ladder(R)):
+            sliced[widths > narrower] = w
+        return int(sliced.sum())
+
     def _make_run_kernel(self, stream_key: str) -> Optional[Callable]:
         """The Pallas kernel for the run (``kernels/dense_run.py``) where
         the engine is in its class and Mosaic compiles it; None where
@@ -1424,11 +1445,9 @@ class DensePatternEngine:
                     at["t"], valid)
                 return st, emit, outs, anchor
 
-            # a segment no wider than the run has no wide rounds
-            widths = [w for w in (R, R // self.ROUNDS_NARROW) if w > H]
             carry = (state, bufs, jnp.int32(0), jnp.int32(0))
             with named_scope(SCOPE_DENSE_ROUNDS):
-                for w, narrower in zip(widths, widths[1:] + [H]):
+                for w, narrower in self.rounds_ladder(R):
                     carry = loop(stepped, w, narrower, carry)
             state, bufs, count, r = carry
 
@@ -1897,7 +1916,10 @@ class DensePatternEngine:
         program, which loops over the remaining rounds on the device (a
         single second round through the step again).  At most two H2D
         puts and two dispatches a batch, however long the longest run
-        of one partition is."""
+        of one partition is.  The lanes those programs step, padding
+        and all, are the cycle's ``lanes`` count and add to the
+        runtime's ``steppedLanes``: known here from the plan's widths,
+        with nothing fetched."""
         faults = getattr(self, "faults", None)
         if faults is not None:
             faults.check("step.dense")
@@ -1924,6 +1946,8 @@ class DensePatternEngine:
                     else (step, self.make_rounds(stream_key)))
         bounds = (0, int(plan.off[1]), n)[:len(programs) + 1]
         pending = DeferredDenseEmit(self)
+        stats = getattr(self, "ingest_stats", None)
+        stepped = 0     # lanes the programs step, padding and all
         for program, lo, hi in zip(programs, bounds, bounds[1:]):
             ev = plan.lanes[lo:hi]
             with span(STAGE_CONVERT, hi - lo):
@@ -1931,17 +1955,19 @@ class DensePatternEngine:
                 if program is step:
                     where = np.zeros(len(lanes[0]), dtype=bool)  # valid
                     where[:hi - lo] = True
+                    stepped += len(where)
                 else:
                     # starts of rounds 1.. within the rest, then its
                     # end on every further entry
                     where = np.full(len(lanes[0]) + 1, hi - lo,
                                     dtype=np.int32)
                     where[:plan.n_rounds] = plan.off[1:] - lo
+                    stepped += self.rounds_lanes(len(lanes[0]),
+                                                 np.diff(plan.off[1:]))
             # one pytree H2D put a program behind the ingest.put fault
             # site (core/ingest_stage.py — the sanctioned ingest path);
             # the second goes while the device steps the first round
-            args = staged_put(lanes + (where,), faults=faults,
-                              stats=getattr(self, "ingest_stats", None))
+            args = staged_put(lanes + (where,), faults=faults, stats=stats)
             with span(STAGE_DISPATCH, 1):
                 state, emit, outs, emit_anchor, n_emit = program(
                     state, *args)
@@ -1952,6 +1978,9 @@ class DensePatternEngine:
                 "anchor": emit_anchor, "sel": slice(0, hi - lo),
                 "ridx": ev, "count": n_emit,
             })
+        counted(STAGE_LANES, stepped)
+        if stats is not None:
+            stats.stepped_lanes += stepped
         return state, pending
 
     def _pad_lanes(self, part_idx, prepared, rel, ev):

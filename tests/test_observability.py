@@ -237,10 +237,11 @@ def test_trace_annotation_configures_tracer(tmp_path):
 
 def test_recorder_ring_evicts_to_newest_cycles():
     """The ring at cycles=N holds the last N complete cycles: every
-    stage once, the host preparation in two more pieces, a second
-    host-stepped round, and the two persist spans interleaving."""
-    one_cycle = (trace_mod.CYCLE_STAGES + ("convert", "convert")
-                 + trace_mod.ROUND_STAGES)
+    stage once, the dense engine's count of lanes, the host
+    preparation in two more pieces, a second host-stepped round, and
+    the two persist spans interleaving."""
+    one_cycle = (trace_mod.CYCLE_STAGES + trace_mod.CYCLE_COUNTS
+                 + ("convert", "convert") + trace_mod.ROUND_STAGES)
     assert len(one_cycle) == trace_mod.SPANS_PER_CYCLE
     n = 3
     r = FlightRecorder("app", cycles=n,
@@ -382,8 +383,8 @@ def window_batch(i, n=32):
 # the least number of each, engine kind)
 SERVED_PATHS = {
     "dense": ("partitions='64'", PARTITIONED_BODY, keyed_batch,
-              {"intern": 1, "convert": 4, "plan": 1, "put": 2, "dispatch": 2},
-              "dense"),
+              {"intern": 1, "convert": 4, "plan": 1, "lanes": 1, "put": 2,
+               "dispatch": 2}, "dense"),
     "shard": ("partitions='64', devices='4'", PARTITIONED_BODY, keyed_batch,
               {"intern": 1, "convert": 4, "plan": 1, "route": 2, "put": 2,
                "dispatch": 2}, "shard"),
@@ -469,6 +470,12 @@ def test_spans_tile_send_batch(path, monkeypatch):
                     assert [s[5] for s in by["plan"]] == [2]  # rounds
                 ingest, step = by["ingest"][0], by["step"][0]
                 emit = by["emit"][0]
+                if "lanes" in by:
+                    # once a batch, and no span of time: 24 keys and 8
+                    # of them again, stepped 32 and 16 lanes wide
+                    assert [(s[5], s[4] - s[3]) for s in by["lanes"]] == [
+                        (32 + 16, 0.0)]
+                    assert ingest[3] <= by["lanes"][0][3] <= ingest[4]
                 # ingest, step and emit start and end where they always
                 # did: ingest closes on the dispatch, step runs from there
                 # to the count gate, emit from the fetch to the delivery
@@ -596,7 +603,9 @@ def test_unsampled_cycles_allocate_nothing(monkeypatch):
         # every Span and the two spans clocked by hand (step_wait, fetch)
         # made one annotation each
         assert made["annotation"] == made["span"] + 2
-        assert made["span"] == len(spans) - 4  # ingest, step, emit, fetch
+        # ingest, step, emit, fetch; and lanes, a count and no Span
+        assert made["span"] == len(spans) - 5
+        assert [s[1] for s in spans].count("lanes") == 1
         before = dict(made)
         for i in range(4, 7):   # 5..7 unsampled again
             h.send_batch(keyed_batch(i))
