@@ -447,8 +447,8 @@ class SiddhiAppRuntime:
             # zeroMatchSkips / maxPendingDepth / autoEffectiveDepth /
             # earlyCopyBatches / earlyCopyHits / earlyCopyWastedBytes) and
             # ingest side (stagedBatches / devicePuts / deviceChunks /
-            # steppedLanes / fusedHops / ingestStalls / overlappedBatches /
-            # flushSyncs / maxStagingDepth)
+            # steppedLanes / plannedRepeats / fusedHops / ingestStalls /
+            # overlappedBatches / flushSyncs / maxStagingDepth)
             for name, qr in list(self.query_runtimes.items()) + [
                 (n, q)
                 for pr in self.partitions.values()

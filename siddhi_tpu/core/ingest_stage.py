@@ -11,7 +11,8 @@ This module holds the pieces every device runtime shares:
 
 - ``IngestStats``: per-runtime staging counters surfaced through
   ``util/statistics.py`` (``stagedBatches`` / ``devicePuts`` /
-  ``deviceChunks`` / ``steppedLanes`` / ``fusedHops`` /
+  ``deviceChunks`` / ``steppedLanes`` / ``plannedRepeats`` /
+  ``fusedHops`` /
   ``ingestStalls`` / ``overlappedBatches`` / ``flushSyncs`` /
   ``maxStagingDepth``, and how often the window opened:
   ``gatesBySubmit`` / ``gatesByIdle`` / ``pipelineEntries`` /
@@ -91,7 +92,8 @@ class IngestStats:
     thin-gauge style as ``EmitStats``)."""
 
     __slots__ = ("staged_batches", "device_puts", "device_chunks",
-                 "stepped_lanes", "fused_hops", "ingest_stalls",
+                 "stepped_lanes", "planned_repeats", "fused_hops",
+                 "ingest_stalls",
                  "overlapped_batches", "flush_syncs",
                  "dropped_batches",
                  "max_staging_depth", "auto_depth", "gates_by_submit",
@@ -109,6 +111,11 @@ class IngestStats:
         # ``rounds_lanes``); over the events sent, the lanes an event
         # costs the device.  0 on every other engine
         self.stepped_lanes = 0
+        # events past their partition's first in their batch: what the
+        # dense engine's round plan sorts, and what goes through a
+        # second call of the step or the rounds program
+        # (ops/dense_nfa.py ``round_plan``).  0 on every other engine
+        self.planned_repeats = 0
         # junction hops a fused chain kept on the device: stages - 1 a
         # batch (core/fused_graph.py); 0 on every other runtime
         self.fused_hops = 0
@@ -142,6 +149,7 @@ class IngestStats:
             "devicePuts": self.device_puts,
             "deviceChunks": self.device_chunks,
             "steppedLanes": self.stepped_lanes,
+            "plannedRepeats": self.planned_repeats,
             "fusedHops": self.fused_hops,
             "ingestStalls": self.ingest_stalls,
             "overlappedBatches": self.overlapped_batches,

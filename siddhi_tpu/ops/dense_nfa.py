@@ -361,6 +361,8 @@ class DensePatternEngine:
         self.ref_defs = ref_defs
         self.within_ms = within_ms
         self.n_partitions = n_partitions
+        # round_plan's vector over the rows, made by the first batch
+        self._plan_first: Optional[np.ndarray] = None
         self.every_start = every_start
         self.reset_on_emit = reset_on_emit
         self.is_sequence = is_sequence
@@ -1935,7 +1937,7 @@ class DensePatternEngine:
             rel = rel64.astype(np.int32)
             prepared = self.prepare_cols(stream_key, cols)
         with span(STAGE_PLAN) as sp:
-            plan = round_plan(part_idx)
+            plan = self.plan_rounds(part_idx)
             if sp is not None:
                 sp.count = plan.n_rounds
         # the first round, and everything behind it; a second round that
@@ -1982,6 +1984,21 @@ class DensePatternEngine:
         if stats is not None:
             stats.stepped_lanes += stepped
         return state, pending
+
+    def plan_rounds(self, part_idx: np.ndarray) -> "RoundPlan":
+        """:func:`round_plan` of one batch of this engine's rows, with
+        the engine's own vector over the row space (made once: the
+        callers plan under their runtime's lock, one batch at a time).
+        The events past their partition's first add to the runtime's
+        ``plannedRepeats``."""
+        if self._plan_first is None:
+            self._plan_first = np.empty(self.n_partitions + 1,
+                                        dtype=np.int32)
+        plan = round_plan(part_idx, self._plan_first)
+        stats = getattr(self, "ingest_stats", None)
+        if stats is not None and plan.n_rounds:
+            stats.planned_repeats += len(part_idx) - int(plan.off[1])
+        return plan
 
     def _pad_lanes(self, part_idx, prepared, rel, ev):
         """Host lanes of the events ``ev`` padded to a power of two (at
@@ -2264,7 +2281,8 @@ class RoundPlan(NamedTuple):
         return self.lanes[self.off[r]:self.off[r + 1]]
 
 
-def round_plan(part_idx: np.ndarray) -> RoundPlan:
+def round_plan(part_idx: np.ndarray,
+               first: Optional[np.ndarray] = None) -> RoundPlan:
     """Split a batch into rounds in which each partition appears at
     most once, preserving per-partition order: round ``r`` holds every
     partition's ``r``-th event of the batch.
@@ -2277,40 +2295,81 @@ def round_plan(part_idx: np.ndarray) -> RoundPlan:
     lets the device keep the last rounds' few rows resident
     (:meth:`DensePatternEngine.make_rounds`).
 
-    One sort of the batch and one of the repeated events; no pass over
-    the batch per round."""
+    The partitions are rows: a partition's first arrival is found by
+    one write and one read of ``first``, an ``int32`` vector over the
+    row space (an engine keeps its own, :meth:`DensePatternEngine.
+    plan_rounds`; without one it is made here).  It need not be clean:
+    only entries this batch has written are read.  Only the events past
+    their partition's first are sorted, as packed words by the plain
+    sort; a batch in which no partition repeats is done after the read."""
     part_idx = np.asarray(part_idx)
     n = len(part_idx)
     if n == 0:
         return RoundPlan(np.empty(0, dtype=np.int64),
                          np.zeros(1, dtype=np.int64))
-    # (partition, arrival) packed into one word: the keys are distinct,
-    # so the plain sort, several times faster than a stable one, orders
-    # each partition's events by arrival
-    key = (part_idx.astype(np.int64) << 32) | np.arange(n, dtype=np.int64)
-    key.sort()
-    order = key & 0xFFFFFFFF
-    sorted_parts = key >> 32
-    is_new = np.ones(n, dtype=bool)
-    is_new[1:] = sorted_parts[1:] != sorted_parts[:-1]
-    starts = np.flatnonzero(is_new)            # of each partition's group
-    if len(starts) == n:                       # no partition repeats
+    idx = part_idx.astype(np.intp)      # what an index is cast to anyway
+    if first is None:
+        first = np.empty(int(idx.max()) + 1, dtype=np.int32)
+    arrival = np.arange(n, dtype=np.int32)
+    # written last to first, a partition's first arrival is written last
+    # and stays
+    first[idx[::-1]] = arrival[::-1]
+    lead = first[idx]       # the first arrival of each event's partition
+    while (lead > arrival).any():
+        # numpy does not promise which write of a repeated index stays:
+        # an event that comes before its partition's entry takes it
+        # (tests/test_dense_skew.py holds that this never runs)
+        early = np.flatnonzero(lead > arrival)
+        first[idx[early[::-1]]] = early[::-1]
+        lead = first[idx]
+    repeated = lead != arrival          # events past their partition's first
+    later = np.flatnonzero(repeated)
+    m = len(later)
+    if m == 0:
         return RoundPlan(np.arange(n, dtype=np.int64),
                          np.asarray([0, n], dtype=np.int64))
-    cnt = np.diff(starts, append=n)            # events per group
-    group = np.cumsum(is_new) - 1              # group of each sorted event
-    pos = np.flatnonzero(cnt[group] > 1)       # sorted events that repeat
-    g = group[pos]
-    occ = pos - starts[g]                      # occurrence within the group
-    # a group's first arrival is its first sorted event
-    ranked = order[pos[np.lexsort((order[starts[g]], -cnt[g], occ))]]
-    widths = np.bincount(occ)
-    repeated = np.zeros(n, dtype=bool)
-    repeated[ranked] = True
-    once = np.flatnonzero(~repeated)           # arrival order
-    lanes = np.concatenate([ranked[:widths[0]], once, ranked[widths[0]:]])
-    widths[0] += len(once)
-    return RoundPlan(lanes, np.concatenate([[0], np.cumsum(widths)]))
+    # (first arrival, arrival) packed into one word: the keys are
+    # distinct, so the plain sort groups the later events by partition,
+    # each group in arrival order and the groups by first arrival
+    bits = (n - 1).bit_length()
+    key = lead[later].astype(np.int64)
+    key <<= bits
+    key |= later
+    key.sort()
+    later = key & ((1 << bits) - 1)
+    key >>= bits                        # the first arrival again
+    is_new = np.empty(m, dtype=bool)
+    is_new[0] = True
+    np.not_equal(key[1:], key[:-1], out=is_new[1:])
+    starts = np.flatnonzero(is_new)     # of each partition's group
+    extra = np.diff(starts, append=m)   # a partition's events less one
+    top = int(extra.max())              # rounds behind the first
+    # the groups by (more events, first arrival): they stand by first
+    # arrival, so a stable counting pass over the counts ranks them (a
+    # stable argsort of uint16 is a radix pass); past its reach the
+    # count and the group's place packed into one word, plainly sorted
+    if top < 1 << 16:
+        ranked = np.argsort((top - extra).astype(np.uint16), kind="stable")
+    else:
+        gbits = (len(starts) - 1).bit_length()
+        ranked = ((top - extra) << gbits) | np.arange(len(starts))
+        ranked.sort()
+        ranked &= (1 << gbits) - 1
+    starts = starts[ranked]
+    # round t behind the first holds the widths[t] first groups of that
+    # order, each one's t-th later event
+    widths = np.cumsum(np.bincount(extra)[:0:-1])[::-1]
+    off = np.zeros(top + 2, dtype=np.int64)
+    np.cumsum(widths, out=off[2:])
+    src = np.arange(m)
+    src -= np.repeat(off[1:-1], widths)
+    src = starts[src]
+    src += np.repeat(np.arange(top), widths)
+    firsts = key[starts]                 # the repeating partitions' firsts
+    repeated[firsts] = True
+    once = np.flatnonzero(~repeated)    # arrival order
+    off[1:] += len(firsts) + len(once)
+    return RoundPlan(np.concatenate([firsts, once, later[src]]), off)
 
 
 # ---------------------------------------------------------------------------

@@ -363,7 +363,7 @@ class ShardedPatternEngine:
         ``pending.resolve()`` — the ingest stage (core/ingest_stage.py)
         defers that fetch past the next batch's dispatch.  Returns
         ``(state, pending_or_None)``."""
-        from siddhi_tpu.ops.dense_nfa import DeferredDenseEmit, round_plan
+        from siddhi_tpu.ops.dense_nfa import DeferredDenseEmit
 
         with span(STAGE_CONVERT, len(part)):
             part = np.asarray(part)
@@ -374,7 +374,7 @@ class ShardedPatternEngine:
             rel = rel64.astype(np.int32)
             prepared = self.engine.prepare_cols(self.stream_key, cols)
         with span(STAGE_PLAN) as sp:
-            plan = round_plan(part)
+            plan = self.engine.plan_rounds(part)
             if sp is not None:
                 sp.count = plan.n_rounds
         pending = DeferredDenseEmit(self.engine)

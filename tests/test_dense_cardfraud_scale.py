@@ -6,8 +6,9 @@ with more events than there are cards, cut into four rounds or into
 eight, through ``make_rounds``' wide loop at both widths of its ladder
 and through a narrow tail; a counted last node whose filter reads a
 float capture with all four instance lanes live; the overflow accounting
-where a card opens a fifth instance; and ``steppedLanes``, the lanes the
-engine's programs step for a batch, against widths worked out by hand.
+where a card opens a fifth instance; ``steppedLanes``, the lanes the
+engine's programs step for a batch, against widths worked out by hand;
+and ``plannedRepeats``, the events past their card's first in a batch.
 """
 
 import logging
@@ -140,6 +141,10 @@ def test_dense_rows_equal_the_host_engines(schedule, batches, host, shape):
     passes = len(batches) // 2
     assert stat(stats, "steppedLanes") == passes * lanes * (
         2 if shape != "eight_rounds" else 1)
+    # the events behind a batch's first round: 1,929 of a batch as sent
+    assert stat(stats, "plannedRepeats") == passes * {
+        "four_rounds": 2 * (1599 + 288 + 42), "one_round": 0,
+        "eight_rounds": sum(SHAPES["eight_rounds"][0][1:])}[shape]
 
 
 def test_all_four_lanes_are_live_and_a_row_names_its_charges(schedule, host):
@@ -216,6 +221,36 @@ def test_stepped_lanes_are_the_widths_the_programs_were_cut_at():
             h.send_batch(keyed(times))
             total += lanes
             assert stat(rt.statistics(), "steppedLanes") == total, name
+        rt.shutdown()
+    finally:
+        m.shutdown()
+
+
+# name -> (events a card, the events past their card's first)
+REPEATS = {
+    "even": ([1] * 120, 0),
+    "five_of_one_partition": ([5], 4),
+    "two_rounds": (LANES["two_rounds"][0], 20),
+    "skewed": (LANES["skewed"][0], 299 + 600),
+}
+
+
+def test_planned_repeats_are_the_events_past_their_cards_first():
+    """(The cell's own batch at its rehearsal size, 1,929 of 5,369:
+    ``test_dense_rows_equal_the_host_engines[four_rounds]``.)"""
+    from siddhi_tpu.core.ingest_stage import IngestStats
+
+    assert IngestStats().as_dict()["plannedRepeats"] == 0
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(DENSE + " " + CONFIG["app"])
+        rt.start()
+        h = rt.get_input_handler(CONFIG["stream"])
+        total = 0
+        for name, (times, repeats) in REPEATS.items():
+            h.send_batch(keyed(times))
+            total += repeats
+            assert stat(rt.statistics(), "plannedRepeats") == total, name
         rt.shutdown()
     finally:
         m.shutdown()

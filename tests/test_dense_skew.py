@@ -1,5 +1,6 @@
 """Skewed batches through the dense engine: one key many times in one
-batch.  ``round_plan`` orders the events, the first occurrences go
+batch.  ``round_plan`` orders the events (held, element for element,
+to the sort it replaced: ``_plan_by_sort``), the first occurrences go
 through the plain step and every later one through ``make_rounds``'
 device loop.  Whatever the skew, the result must be what the same events
 give one at a time: state and emissions exact, for every engine kind of
@@ -16,7 +17,7 @@ from dense_layout_cases import ENGINES, MESHES, STREAMS, logical
 
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.core.event import EventBatch
-from siddhi_tpu.ops.dense_nfa import compile_pattern, round_plan
+from siddhi_tpu.ops.dense_nfa import RoundPlan, compile_pattern, round_plan
 
 P = 24
 
@@ -83,6 +84,267 @@ def test_round_plan(case):
             first.tolist(),
             key=lambda k: (-count[k], arrival[k]) if count[k] > 1
             else (0, arrival[k]))
+
+
+# -- the plan against the sort it replaced -------------------------------------
+
+def _plan_by_sort(part_idx: np.ndarray) -> RoundPlan:
+    """``round_plan`` as it stood until PR 52: one sort of the batch,
+    one ``lexsort`` of the repeated events.  The oracle: the plan is a
+    contract (the device's gathers, scatters and resident rows follow
+    it), so the planner returns it element for element."""
+    part_idx = np.asarray(part_idx)
+    n = len(part_idx)
+    if n == 0:
+        return RoundPlan(np.empty(0, dtype=np.int64),
+                         np.zeros(1, dtype=np.int64))
+    # (partition, arrival) packed into one word: the keys are distinct,
+    # so the plain sort, several times faster than a stable one, orders
+    # each partition's events by arrival
+    key = (part_idx.astype(np.int64) << 32) | np.arange(n, dtype=np.int64)
+    key.sort()
+    order = key & 0xFFFFFFFF
+    sorted_parts = key >> 32
+    is_new = np.ones(n, dtype=bool)
+    is_new[1:] = sorted_parts[1:] != sorted_parts[:-1]
+    starts = np.flatnonzero(is_new)            # of each partition's group
+    if len(starts) == n:                       # no partition repeats
+        return RoundPlan(np.arange(n, dtype=np.int64),
+                         np.asarray([0, n], dtype=np.int64))
+    cnt = np.diff(starts, append=n)            # events per group
+    group = np.cumsum(is_new) - 1              # group of each sorted event
+    pos = np.flatnonzero(cnt[group] > 1)       # sorted events that repeat
+    g = group[pos]
+    occ = pos - starts[g]                      # occurrence within the group
+    # a group's first arrival is its first sorted event
+    ranked = order[pos[np.lexsort((order[starts[g]], -cnt[g], occ))]]
+    widths = np.bincount(occ)
+    repeated = np.zeros(n, dtype=bool)
+    repeated[ranked] = True
+    once = np.flatnonzero(~repeated)           # arrival order
+    lanes = np.concatenate([ranked[:widths[0]], once, ranked[widths[0]:]])
+    widths[0] += len(once)
+    return RoundPlan(lanes, np.concatenate([[0], np.cumsum(widths)]))
+
+
+def _same_plan(got: RoundPlan, want: RoundPlan):
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert (a == b).all()
+
+
+def _zipf(rng, n, n_rows, theta=0.99):
+    """``n`` rows of ``n_rows`` by Zipf(``theta``), scattered over the
+    row space as the skewed cell's are."""
+    p = 1.0 / np.arange(1, n_rows + 1) ** theta
+    ranks = rng.choice(n_rows, size=n, p=p / p.sum())
+    return (ranks * 2654435761 % n_rows).astype(np.int32)
+
+
+def _card_batch(rehearsal, widths):
+    """A batch of ``cardfraud_100k.saturated``'s own generator under
+    ``benchmark/traffic/card_pass_saturated.json``, its cards as rows."""
+    from cardfraud_bench import CONFIG, GEN, TRAFFIC
+
+    cards = GEN.make(2**31 + 52, CONFIG, TRAFFIC, rehearsal).batch(
+        0).columns["card"]
+    keys, part = np.unique(cards, return_inverse=True)
+    assert [int((np.bincount(part) > r).sum())
+            for r in range(len(widths) + 1)] == widths + [0]
+    return len(keys), part
+
+
+# name -> (rows of the engine, the batch's partition rows)
+SHAPES = {
+    # 131,072 events, 4,096 keys twice, 1,000,000 rows
+    "flagship": lambda rng: (1_000_000, _skewed(
+        rng, [2] * 4_096, 131_072 - 2 * 4_096, 1_000_000)),
+    # more events than rows: cardfraud_100k's batch, and its rehearsal
+    "card_full": lambda rng: _card_batch(
+        False, [84_000, 39_024, 7_024, 1_024]),
+    "card_rehearsal": lambda rng: _card_batch(True, [3_440, 1_599, 288, 42]),
+    "zipf": lambda rng: (1_000_000, _zipf(rng, 16_384, 1_000_000)),
+    "one_key_only": lambda rng: (1_000, np.full(5_000, 77, dtype=np.int32)),
+    # a run past what the counting pass over uint16 holds
+    "one_key_70000_times": lambda rng: (64, np.concatenate(
+        [np.full(70_000, 5), rng.integers(0, 64, 300)]).astype(np.int64)),
+    "no_repeat": lambda rng: (1_000_000, _skewed(
+        rng, [], 131_072, 1_000_000)),
+    "the_last_row_and_the_scratch_row": lambda rng: (
+        8, np.asarray([7, 8, 7, 0, 8, 8], dtype=np.int32)),
+}
+
+
+@pytest.mark.parametrize("case", list(PLAN_CASES) + list(SHAPES)
+                         + [f"random_{s}" for s in range(50)])
+def test_round_plan_matches_sort(case):
+    if case in PLAN_CASES:
+        runs, n_once = PLAN_CASES[case]
+        n_rows = 10_000
+        part = _skewed(np.random.default_rng(len(case)), runs, n_once)
+    elif case in SHAPES:
+        n_rows, part = SHAPES[case](np.random.default_rng(52))
+    else:
+        rng = np.random.default_rng(int(case.split("_")[1]))
+        n_rows = int(rng.integers(1, 501))
+        part = rng.integers(0, n_rows, size=int(rng.integers(1, 3_001)))
+    want = _plan_by_sort(part)
+    _same_plan(round_plan(part), want)
+    # over a vector of the row space that is not clean
+    first = np.full(n_rows + 1, 3, dtype=np.int32)
+    _same_plan(round_plan(part, first), want)
+
+
+class _FirstWriteStays(np.ndarray):
+    """A vector on which the FIRST write of a repeated index stays: the
+    other order numpy could write in."""
+
+    def __setitem__(self, idx, value):
+        if np.ndim(value):
+            idx, value = idx[::-1], value[::-1]
+        super().__setitem__(idx, value)
+
+
+@pytest.mark.parametrize("case", ["several_hot_keys", "run_of_1000",
+                                  "equal_runs"])
+def test_round_plan_whichever_write_stays(case):
+    """numpy does not say which value stays where an index repeats in
+    ``a[idx] = v``; ``round_plan`` reads the entry back and lets an
+    earlier event take it, so the plan is the same either way."""
+    runs, n_once = PLAN_CASES[case]
+    part = _skewed(np.random.default_rng(len(case)), runs, n_once)
+    first = np.zeros(10_001, dtype=np.int32).view(_FirstWriteStays)
+    probe = np.zeros(2, dtype=np.int32).view(_FirstWriteStays)
+    probe[np.asarray([1, 1])] = np.asarray([5, 6], dtype=np.int32)
+    assert probe[1] == 5
+    _same_plan(round_plan(part, first), _plan_by_sort(part))
+
+
+def test_the_last_write_of_a_repeated_index_stays():
+    """What ``round_plan`` is fast by and not right by: numpy writes
+    ``first[idx[::-1]] = arrival[::-1]`` in index order, so a row's
+    first arrival, written last, stays, and the loop under that line in
+    ``siddhi_tpu/ops/dense_nfa.py`` (``while (lead > arrival).any()``)
+    never runs.  Were this to fail, the plan would still be right (the
+    test above) and a pass slower for each time round."""
+    rng = np.random.default_rng(7)
+    for n, n_rows in ((10, 3), (5_000, 40), (131_072, 100_000)):
+        idx = rng.integers(0, n_rows, size=n)
+        arrival = np.arange(n, dtype=np.int32)
+        first = np.empty(n_rows, dtype=np.int32)
+        first[idx[::-1]] = arrival[::-1]
+        rows, firsts = np.unique(idx, return_index=True)
+        assert (first[rows] == firsts).all()
+        assert not (first[idx] > arrival).any()
+
+
+# -- the engine's vector over the row space ------------------------------------
+
+def _plain_engine(n_partitions):
+    return compile_pattern(STREAMS + ENGINES["every_r2"][0], "q",
+                           n_partitions=n_partitions, n_instances=4)
+
+
+def test_one_engine_plans_batch_after_batch():
+    """What a batch wrote into the engine's vector is nothing to the
+    next: all of the first's keys again, a part of them, none twice."""
+    from siddhi_tpu.core.ingest_stage import IngestStats
+
+    eng = _plain_engine(10_000)
+    eng.ingest_stats = IngestStats()
+    assert eng._plan_first is None          # made by the first batch
+    rng = np.random.default_rng(3)
+    first = _skewed(rng, [300, 300, 120, 17, 5, 2, 2, 2], 200)
+    repeats = 0
+    for part in (first, first[::-1].copy(), first[first % 3 == 0],
+                 rng.permutation(10_000)[:500].astype(np.int32), first):
+        _same_plan(eng.plan_rounds(part), _plan_by_sort(part))
+        repeats += len(part) - len(np.unique(part))
+        assert eng.ingest_stats.planned_repeats == repeats
+    vector = eng._plan_first
+    assert vector.dtype == np.int32 and vector.shape == (10_001,)
+    eng.plan_rounds(first[:0])              # an empty batch counts nothing
+    assert eng._plan_first is vector        # made once and kept
+    assert eng.ingest_stats.planned_repeats == repeats
+
+
+def test_two_engines_plan_in_turn():
+    """Each engine's vector is its own, as long as its own rows."""
+    small, large = _plain_engine(24), _plain_engine(5_000)
+    rng = np.random.default_rng(4)
+    for _ in range(3):
+        a = rng.integers(0, 24, size=200).astype(np.int32)
+        b = _skewed(rng, [40, 9, 2], 300, n_keys=5_000)
+        _same_plan(small.plan_rounds(a), _plan_by_sort(a))
+        _same_plan(large.plan_rounds(b), _plan_by_sort(b))
+    assert small._plan_first.shape == (25,)
+    assert large._plan_first.shape == (5_001,)
+
+
+@pytest.fixture
+def planned(monkeypatch):
+    """Every ``round_plan`` the program calls while the test runs, as
+    ``(events, rounds, length of the vector it was given)``, each plan
+    held to ``_plan_by_sort``'s."""
+    import siddhi_tpu.ops.dense_nfa as dense_nfa
+
+    calls, real = [], dense_nfa.round_plan
+
+    def spy(part_idx, first=None):
+        plan = real(part_idx, first)
+        _same_plan(plan, _plan_by_sort(part_idx))
+        calls.append((len(part_idx), plan.n_rounds,
+                      None if first is None else len(first)))
+        return plan
+
+    monkeypatch.setattr(dense_nfa, "round_plan", spy)
+    return calls
+
+
+@pytest.mark.parametrize("devices", ["", ", devices='4'"],
+                         ids=["one_device", "four_devices"])
+def test_the_served_engines_plan_with_their_own_vector(planned, devices):
+    """The dense engine and the sharded one (which plans with its inner
+    engine's) through ``SiddhiManager``: tests/test_parallel.py's
+    collision case, one key four times in a batch, then a batch of
+    other keys once each."""
+    keys = np.asarray([5, 5, 5, 5, 9], dtype=np.int64)
+    batches = [
+        EventBatch("Txn", ["key", "v"], {"key": keys, "v": np.arange(5) + 1.5},
+                   1_000 + np.arange(5, dtype=np.int64)),
+        EventBatch("Txn", ["key", "v"],
+                   {"key": np.arange(20, 30, dtype=np.int64),
+                    "v": np.full(10, 0.5)},
+                   2_000 + np.arange(10, dtype=np.int64))]
+    got, lowering, overflow = _run_app(
+        f"@app:playback @app:execution('tpu', partitions='64'{devices}) ",
+        batches)
+    assert lowering == {"bench": "dense"} and overflow == 0
+    assert len(got) == 1                    # 1.5 -> 2.5 -> 3.5 -> 4.5 of key 5
+    assert planned == [(5, 4, 64 + 1), (10, 1, 64 + 1)]
+
+
+def test_a_multiplexed_group_plans_with_its_engines_vector(planned):
+    """``multiplex/dense_group.py`` goes through the shared engine's
+    ``process_deferred``: a tenant's batch of five events is five rounds
+    of its one row."""
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(
+            "@app:name('t0') @app:execution('tpu') @app:playback "
+            "@app:multiplex(slots='8') define stream A (v double); "
+            "define stream B (w double); "
+            "@info(name='qp') from every e1=A[v > 2] -> e2=B[w > e1.v] "
+            "select e1.v as v1, e2.w as w2 insert into OutP;")
+        rt.start()
+        assert rt.lowering() == {"qp": "multiplex"}
+        rt.get_input_handler("A").send_batch(EventBatch(
+            "A", ["v"], {"v": np.arange(5) + 3.0},
+            1_000 + np.arange(5, dtype=np.int64)))
+        rt.shutdown()
+    finally:
+        m.shutdown()
+    assert planned == [(5, 5, 8 + 1)]
 
 
 # -- every engine kind: a skewed batch against one event at a time ------------
