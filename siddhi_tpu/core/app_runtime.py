@@ -202,6 +202,13 @@ class SiddhiAppRuntime:
         if sm is not None and Level.at_least(self.app_context.root_metrics_level, Level.BASIC):
             sm.start_reporting()
         self.running = True
+        tracer = self.app_context.tracer
+        if tracer is not None:
+            # a stall's record reads the gates in flight and the emits
+            # pending (observability/stall.py); the watch's thread is
+            # started by the sends themselves
+            tracer.watch.pending_work = self._pending_work
+            tracer.watch.resume()
         if self.app_context.playback and self.app_context.playback_idle_ms > 0:
             self._start_playback_heartbeat()
         if self.app_context.persist_interval_ms > 0:
@@ -315,6 +322,9 @@ class SiddhiAppRuntime:
         if mon is not None:
             mon.stop()
             self._plan_monitor = None
+        tracer = self.app_context.tracer
+        if tracer is not None:
+            tracer.watch.stop()
         stop = getattr(self, "_persist_stop", None)
         if stop is not None:
             stop.set()

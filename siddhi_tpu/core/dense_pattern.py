@@ -45,6 +45,7 @@ from siddhi_tpu.core.exceptions import (
     SiddhiAppRuntimeError,
 )
 from siddhi_tpu.core.key_index import index_for
+from siddhi_tpu.observability.stall import waits_on_device
 from siddhi_tpu.observability.trace import (
     STAGE_CONVERT,
     STAGE_INTERN,
@@ -637,6 +638,7 @@ class DensePatternRuntime:
             "intern_new_keys": self._intern_new_keys,
         }
 
+    @waits_on_device
     def _check_overflow(self):
         total = self.overflow_total()
         # Queries.<q>.droppedInstances in statistics(): the polled

@@ -48,6 +48,7 @@ from siddhi_tpu.core.emit_queue import (
     fetch_coalesced,
 )
 from siddhi_tpu.core.ingest_stage import IngestStage, IngestStats
+from siddhi_tpu.observability.stall import waits_on_device
 from siddhi_tpu.observability.trace import STAGE_BUILD, STAGE_DELIVER, span
 from siddhi_tpu.util import faults as _faults
 
@@ -70,6 +71,7 @@ class CountGate:
     def probe(self):
         return self.count
 
+    @waits_on_device
     def resolve(self) -> int:
         return int(fetch_coalesced([self.count])[0])
 

@@ -43,6 +43,7 @@ from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
+from ..observability.stall import waits_on_device
 from ..observability.trace import STAGE_FETCH, annotation, reopen
 from .exceptions import TransferFaultError
 
@@ -108,6 +109,7 @@ def _is_device_array(a) -> bool:
     return not isinstance(a, (np.ndarray, np.generic, int, float, bool))
 
 
+@waits_on_device
 def fetch_coalesced(arrays: Sequence, on_device: bool = True,
                     started=frozenset()) -> List[np.ndarray]:
     """One device→host round trip for a list of arrays.

@@ -33,6 +33,7 @@ from siddhi_tpu.core.event import (
     events_from_batch,
 )
 from siddhi_tpu.core.exceptions import OnErrorAction, SiddhiAppRuntimeError
+from siddhi_tpu.observability.stall import WAITS_LOCK
 from siddhi_tpu.query_api.definition import StreamDefinition
 
 log = logging.getLogger("siddhi_tpu")
@@ -191,7 +192,15 @@ class StreamJunction:
                     break
                 batches.append(nxt)
                 total += len(nxt)
-            self._dispatch(EventBatch.concat(batches))
+            # the worker is this batch's entry: on the tracer's clock as
+            # InputHandler.send_batch is (observability/stall.py)
+            tracer = self.app_context.tracer
+            stamp = tracer.send_begins() if tracer is not None else None
+            try:
+                self._dispatch(EventBatch.concat(batches))
+            finally:
+                if stamp is not None:
+                    tracer.send_ends(stamp, total)
 
     def _dispatch(self, batch: EventBatch):
         self.dispatches += 1
@@ -274,47 +283,72 @@ class InputHandler:
 
     def send(self, data: Union[Event, Sequence, List[Event]], timestamp: Optional[int] = None):
         self._check_running()
-        tsgen = self.app_context.timestamp_generator
-        if isinstance(data, Event):
-            events = [data]
-        elif isinstance(data, list) and data and isinstance(data[0], Event):
-            events = data
-        else:
-            ts = timestamp if timestamp is not None else tsgen.current_time()
-            events = [Event(ts, list(data))]
-        for e in events:
-            if e.timestamp < 0:
-                e.timestamp = tsgen.current_time()
-            tsgen.set_event_time(e.timestamp)
-        batch = batch_from_events(self.definition, events)
-        batch = self._admit(batch)
-        if batch is None:
-            return
-        with self.app_context.process_lock:
-            self._journal_and_check(batch)
-            scheduler = self.app_context.scheduler
-            if scheduler is not None:
-                scheduler.advance(tsgen.current_time())
-            self.junction.send(batch)
-            self.app_context.applied(max(e.timestamp for e in events))
+        # on the tracer's clock from here to the return, whatever the
+        # sample (observability/stall.py): None with tracing off
+        tracer = self.app_context.tracer
+        stamp = tracer.send_begins() if tracer is not None else None
+        n = 0
+        try:
+            tsgen = self.app_context.timestamp_generator
+            if isinstance(data, Event):
+                events = [data]
+            elif isinstance(data, list) and data and isinstance(data[0], Event):
+                events = data
+            else:
+                ts = timestamp if timestamp is not None else tsgen.current_time()
+                events = [Event(ts, list(data))]
+            n = len(events)
+            for e in events:
+                if e.timestamp < 0:
+                    e.timestamp = tsgen.current_time()
+                tsgen.set_event_time(e.timestamp)
+            batch = batch_from_events(self.definition, events)
+            batch = self._admit(batch)
+            if batch is None:
+                return
+            if stamp is not None:
+                stamp.waits = WAITS_LOCK   # what a stall's samples read
+            with self.app_context.process_lock:
+                if stamp is not None:
+                    stamp.waits = None
+                self._journal_and_check(batch)
+                scheduler = self.app_context.scheduler
+                if scheduler is not None:
+                    scheduler.advance(tsgen.current_time())
+                self.junction.send(batch)
+                self.app_context.applied(max(e.timestamp for e in events))
+        finally:
+            if stamp is not None:
+                tracer.send_ends(stamp, n)
 
     def send_batch(self, batch: EventBatch):
         self._check_running()
-        newest = -1
-        if len(batch):
-            # event time is monotone-max; one update per batch suffices
-            newest = int(batch.timestamps.max())
-            self.app_context.timestamp_generator.set_event_time(newest)
-        batch = self._admit(batch)
-        if batch is None:
-            return
-        with self.app_context.process_lock:
-            self._journal_and_check(batch)
-            scheduler = self.app_context.scheduler
-            if scheduler is not None:
-                scheduler.advance(self.app_context.timestamp_generator.current_time())
-            self.junction.send(batch)
-            self.app_context.applied(newest)
+        tracer = self.app_context.tracer
+        stamp = tracer.send_begins() if tracer is not None else None
+        n = len(batch)
+        try:
+            newest = -1
+            if n:
+                # event time is monotone-max; one update per batch suffices
+                newest = int(batch.timestamps.max())
+                self.app_context.timestamp_generator.set_event_time(newest)
+            batch = self._admit(batch)
+            if batch is None:
+                return
+            if stamp is not None:
+                stamp.waits = WAITS_LOCK
+            with self.app_context.process_lock:
+                if stamp is not None:
+                    stamp.waits = None
+                self._journal_and_check(batch)
+                scheduler = self.app_context.scheduler
+                if scheduler is not None:
+                    scheduler.advance(self.app_context.timestamp_generator.current_time())
+                self.junction.send(batch)
+                self.app_context.applied(newest)
+        finally:
+            if stamp is not None:
+                tracer.send_ends(stamp, n)
 
     def _admit(self, batch: EventBatch) -> Optional[EventBatch]:
         """Admission control (@app:limits, robustness/admission.py):

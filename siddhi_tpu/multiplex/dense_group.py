@@ -49,6 +49,7 @@ from siddhi_tpu.core.event import EventBatch
 from siddhi_tpu.core.exceptions import SiddhiAppRuntimeError, TransferFaultError
 from siddhi_tpu.core.ingest_stage import IngestStats
 from siddhi_tpu.multiplex.common import retry_guard
+from siddhi_tpu.observability.stall import waits_on_device
 from siddhi_tpu.observability.trace import STAGE_PERSIST_UNPACK, span
 from siddhi_tpu.util import faults as _faults
 
@@ -273,6 +274,7 @@ class DenseMultiplexGroup:
             for k in self.state
         }
 
+    @waits_on_device
     def _check_overflow(self) -> None:
         total = int(self.engine.jnp.sum(self.state["overflow"]))
         if total > self._ovf_warned:

@@ -14,6 +14,11 @@ queries, which is what makes the exposition scrapable (a family's
 grouped under it).  String-valued feed entries (engine placement,
 fallback reasons) become ``*_info`` gauges with the text in a
 ``value`` label, the textfile-collector idiom for non-numeric facts.
+The stall tallies (``Stalls.<cause>.count`` / ``.seconds``,
+observability/stall.py) are the one family of counters:
+``siddhi_stalls_total{app,cause}`` and
+``siddhi_stall_seconds_total{app,cause}``; the ``all`` row, their sum,
+gives only its ``siddhi_stall_longest_ms{app}``.
 """
 
 from __future__ import annotations
@@ -23,6 +28,8 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"
 
+_STALL_COUNTERS = {"count": "siddhi_stalls_total",
+                   "seconds": "siddhi_stall_seconds_total"}
 _CAMEL = re.compile(r"([a-z0-9])([A-Z])")
 _BAD_METRIC = re.compile(r"[^a-zA-Z0-9_]")
 
@@ -67,10 +74,20 @@ def render_prometheus(apps: Iterable[Tuple[str, Dict[str, object], list]]) -> st
     Scalar samples and histograms are grouped per family across apps
     so every ``# TYPE`` appears once."""
     gauges: Dict[str, List[Tuple[str, str]]] = {}
+    counters: Dict[str, List[Tuple[str, str]]] = {}
     hists: Dict[str, List[Tuple[str, object]]] = {}
     for app, stats, histogram_entries in apps:
         for key, value in sorted(stats.items()):
             parsed = _parse_key(app, key)
+            if parsed is not None and parsed[0] == "Stalls":
+                _kind, cause, metric = parsed
+                if cause != "all":
+                    counters.setdefault(_STALL_COUNTERS[metric], []).append(
+                        (_labels({"app": app, "cause": cause}), _num(value)))
+                elif metric == "longestMs":
+                    gauges.setdefault("siddhi_stall_longest_ms", []).append(
+                        (_labels({"app": app}), _num(value)))
+                continue
             if parsed is None:
                 family = "siddhi_metric"
                 labels = {"app": app, "key": key}
@@ -92,6 +109,10 @@ def render_prometheus(apps: Iterable[Tuple[str, Dict[str, object], list]]) -> st
     for family in sorted(gauges):
         lines.append(f"# TYPE {family} gauge")
         for labels, value in gauges[family]:
+            lines.append(f"{family}{{{labels}}} {value}")
+    for family in sorted(counters):
+        lines.append(f"# TYPE {family} counter")
+        for labels, value in counters[family]:
             lines.append(f"{family}{{{labels}}} {value}")
     for family in sorted(hists):
         lines.append(f"# TYPE {family} histogram")

@@ -14,6 +14,12 @@ JSON payload: kept in memory as ``last_dump`` (served by
 ``GET /siddhi-trace/<app>``) and written best-effort to the dump
 directory so a post-mortem survives the process.  ``chrome_trace()``
 renders the same spans as Chrome ``chrome://tracing`` complete events.
+
+Beside the ring it keeps the last ``KEPT_STALLS`` stall records
+(``observability/stall.py``: a send that ran far past its usual length,
+with where the host was and why), and a payload also holds the sends
+the watch has noticed and that have not ended: a watchdog's trip dump
+says where the wedge stands.
 """
 
 from __future__ import annotations
@@ -26,7 +32,9 @@ import os
 import re
 import tempfile
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
+
+from .stall import KEPT_STALLS
 
 log = logging.getLogger("siddhi_tpu.observability")
 
@@ -60,6 +68,10 @@ class FlightRecorder:
         self.ring: collections.deque = collections.deque(
             maxlen=self.cycles * spans_per_cycle)
         self.dump_dir = dump_dir if dump_dir is not None else default_dump_dir()
+        self.stalls: collections.deque = collections.deque(
+            maxlen=KEPT_STALLS)
+        # the tracer's watch: the stalls still open, for a payload
+        self.in_flight: Optional[Callable[[], List[dict]]] = None
         self.last_dump: Optional[dict] = None
         self.dumps = 0
         self.dump_files_written = 0
@@ -99,6 +111,8 @@ class FlightRecorder:
             "reason": reason,
             "unix_time": time.time(),
             "spans": [self._span_dict(s) for s in self.spans()],
+            "stalls": list(self.stalls) + (
+                self.in_flight() if self.in_flight is not None else []),
         }
 
     def dump(self, reason: str) -> dict:

@@ -43,8 +43,9 @@ dispatch to the start of its count-gate fetch where the gate was left
 behind the next batch's dispatch (in the ring: the start of ``step``
 less the end of ``ingest``, by cycle id; 0 for a gate finished
 inline), and ``cycle``, from ``begin_cycle`` to the end of ``emit``:
-how soon a batch's matches reach the callback (in the ring: the
-cycle's first span's start to its ``emit``'s end).
+how soon a batch's matches reach the callback (in the ring: the end of
+the cycle's ``admit``, or its first span's start where it took none, to
+its ``emit``'s end).
 
 One count is a tuple in the ring and no interval (``lanes``,
 :func:`counted`): the lanes the dense engine's programs step for the
@@ -79,11 +80,35 @@ materialized, which is what keeps the ``jit-purity`` and
 ``host-sync-hazard`` analysis rules clean with zero allowlist entries.
 
 Sampling (``@app:trace(sample='1/64')``) gates token creation: an
-unsampled cycle pays one ``itertools.count`` tick, a modulo and the
-store of ``None`` as the thread's open cycle; every downstream hook
-short-circuits on ``token is None`` and every ``span`` site on one
-thread-local read — no token, span or annotation is allocated.  That
-is the whole default-on cost.
+unsampled cycle pays one ``itertools.count`` tick, a modulo and two
+stores on the thread's local (``None`` as its open cycle, its send's
+lead taken); every downstream hook short-circuits on ``token is None``
+and every ``span`` site on one thread-local read — no token, span or
+annotation is allocated.
+
+The entry is on the clock whatever the sample (``sample='off'`` alone
+turns it off with the rest): ``InputHandler.send_batch`` / ``send`` and
+an async junction's worker call ``Tracer.send_begins`` and
+``send_ends`` round every batch.  An unsampled, unstalled send pays for
+them a read of its thread's :class:`~.stall.Sender` on the tracer's
+thread-local, two clock reads, four stores on the ``Sender`` and one on
+the thread's local (the send's lead, for the first cycle to take), the
+update of the thread's typical send and four compares, and allocates
+nothing: 0.72 us a send on the chip's host (1.72 at ``sample='1'``,
+where the entry also opens the ``siddhi.admit`` annotation; 0.10 with
+tracing off; PERF.md section 6, PR 55), against the benchmark's
+shortest send of 2,295 us; ``InputHandler`` adds two stores round the
+process lock's acquire (``Sender.waits``).  A send that runs far past
+the typical one leaves a stall record that says why
+(``observability/stall.py``), whose watching thread wakes twenty times
+a second and reads one clock a sending thread.  For a sampled cycle the time from the
+send's entry to ``begin_cycle`` is the ``admit`` span (the entry's own
+work ahead of the cycle: the newest timestamp, admission, the process
+lock, the journal, the scheduler, the junction and the receiver), and
+the whole send goes into ``Stages.send``, a histogram alone like
+``cycle`` and ``staged``: a tuple over the send would cover what
+``host_unattributed`` is there to show.  With the cycle's spans and
+these the default-on cost is whole.
 """
 
 from __future__ import annotations
@@ -96,9 +121,12 @@ from typing import Dict, Optional
 
 from .histograms import LatencyHistogram
 from .recorder import FlightRecorder
+from .stall import (SEED_SENDS, STALL_FACTOR, STALL_FLOOR_S, TYPICAL_WEIGHT,
+                    Sender, StallWatch, log)
 
 #: batch-cycle stages in pipeline order; the n_events slot of each
 #: span carries the count named beside it
+STAGE_ADMIT = "admit"        # events: send_batch's entry to begin_cycle
 STAGE_INTERN = "intern"      # keys interned
 STAGE_INGEST = "ingest"      # events
 STAGE_CONVERT = "convert"    # events
@@ -114,8 +142,8 @@ STAGE_FETCH = "fetch"        # bytes fetched
 STAGE_BUILD = "build"        # rows built into the EventBatch
 STAGE_DELIVER = "deliver"    # rows delivered
 STAGE_MUTATE = "mutate"      # keys written to a device table (in deliver)
-CYCLE_STAGES = (STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT, STAGE_PLAN,
-                STAGE_PANE, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH,
+CYCLE_STAGES = (STAGE_ADMIT, STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT,
+                STAGE_PLAN, STAGE_PANE, STAGE_ROUTE, STAGE_PUT, STAGE_DISPATCH,
                 STAGE_POLL, STAGE_STEP, STAGE_EMIT, STAGE_FETCH,
                 STAGE_BUILD, STAGE_DELIVER, STAGE_MUTATE)
 #: a count of a cycle that is no interval: one zero-width tuple in the
@@ -125,6 +153,7 @@ CYCLE_COUNTS = (STAGE_LANES,)
 #: intervals of a cycle kept as histograms alone (module docstring)
 STAGE_STAGED = "staged"      # dispatch to the start of a deferred gate's fetch
 STAGE_CYCLE = "cycle"        # begin_cycle to the end of emit
+STAGE_SEND = "send"          # a sampled send, entry to exit
 #: what a further round of one batch repeats where rounds are stepped
 #: from the host (the sharded engine; a device chunk of the window
 #: path).  The dense engine runs its rounds on the device: whatever the
@@ -171,7 +200,7 @@ PERSIST_STAGES = (
 STAGE_WATCHDOG_HEAL = "watchdog.heal"
 
 _STAGES = CYCLE_STAGES + (
-    STAGE_STAGED, STAGE_CYCLE) + PERSIST_STAGES + (
+    STAGE_STAGED, STAGE_CYCLE, STAGE_SEND) + PERSIST_STAGES + (
     STAGE_WATCHDOG_HEAL,)
 
 #: host spans on the profiler's clock are named ANNOTATION_PREFIX + stage;
@@ -423,7 +452,11 @@ class Tracer:
     #: pairs, -1.0% to -6.3%) and host-bound 14 ms pattern batches of 13
     #: spans 1.8% (three pairs, -1.4% to -3.7%): 17-20 us a span, the
     #: ring and the histogram a tenth of it (PERF.md section 6, PR 37;
-    #: PR 26 read 2.6% on 12 ms window batches of 18 spans)
+    #: PR 26 read 2.6% on 12 ms window batches of 18 spans).  Since
+    #: PR 55 a sampled cycle holds one span more, ``admit``: traced
+    #: against traced, 2.3-2.4 ms window batches read ``send`` 0.8-1.0%
+    #: longer than the parent's.  Whatever the sample, the stamp pair
+    #: round every send costs 0.72 us (module docstring)
     DEFAULT_SAMPLE = 64
     #: default flight-recorder depth in cycles
     DEFAULT_CYCLES = 64
@@ -443,6 +476,69 @@ class Tracer:
         # pre-created so hot-path record() never mutates the dict
         self.stage_hist: Dict[str, LatencyHistogram] = {
             stage: LatencyHistogram() for stage in _STAGES}
+        # the sends on the clock and the thread that watches them
+        # (observability/stall.py); the free spans now open, by id, for
+        # a stall's record to name a checkpoint still under way
+        self.watch = StallWatch(self)
+        self._mine = threading.local()   # .st: the thread's Sender
+        self.free_open: Dict[int, tuple] = {}
+        self.recorder.in_flight = self.watch.in_flight
+
+    # -- the entry -----------------------------------------------------------
+
+    def send_begins(self) -> Optional[Sender]:
+        """Entry of ``InputHandler.send_batch`` / ``send`` and of an
+        async junction's worker: stamp the calling thread's send.  None
+        with tracing off, and for a send that a callback makes inside a
+        send of its own thread: the outer send's stamp stands, the inner
+        one is its callback's time."""
+        if not self.sample:
+            return None
+        st = getattr(self._mine, "st", None)
+        if st is None:
+            st = self._mine.st = self.watch.sender()
+        elif st.t_in > st.t_out:
+            return None
+        st.t_in = self.clock()
+        # the send's lead is the first cycle's to take (``begin_cycle``)
+        _open.lead = st
+        if self.sample == 1:
+            # every cycle is sampled: the lead is a span from here
+            st.sampled = True
+            st.ann = annotation(STAGE_ADMIT)
+            st.ann.__enter__()
+        return st
+
+    def send_ends(self, st: Sender, n_events: int) -> None:
+        """Exit of the send ``send_begins`` stamped: judge it against
+        the thread's typical send, which it then joins unless it was a
+        stall (a stall joins as the threshold it passed, so that a
+        change of regime is learned and one stall moves little)."""
+        now = self.clock()
+        took = now - st.t_in
+        # closed from here, whatever the record's writing does
+        left, st.t_out = st.t_out, now
+        st.n += 1
+        if st.n <= SEED_SENDS:
+            # compiles only ever add: the shortest seeds the mean
+            st.typical = took if st.n == 1 else min(st.typical, took)
+            if st.n == SEED_SENDS:
+                self.watch.start()
+        elif took < STALL_FLOOR_S or took < STALL_FACTOR * st.typical:
+            st.typical += (took - st.typical) * TYPICAL_WEIGHT
+        else:
+            try:
+                self.watch.stalled(st, left, n_events)
+            except Exception:  # noqa: BLE001 — the send's result stands
+                log.exception("app '%s': a stall's record failed",
+                              self.app_name)
+            st.typical += (STALL_FACTOR - 1) * st.typical * TYPICAL_WEIGHT
+        if st.sampled:
+            self.stage_hist[STAGE_SEND].record_s(took)
+            if st.ann is not None:   # no cycle began: a host query's send
+                st.ann.__exit__(None, None, None)
+                st.ann = None
+            st.sampled, st.cycle = False, 0
 
     # -- cycle ids -----------------------------------------------------------
 
@@ -453,9 +549,21 @@ class Tracer:
         reads, so no span of this batch lands in an older cycle."""
         if not self.sample or (cid := next(self._ids)) % self.sample:
             _open.tok = None
+            _open.lead = None
             return None
-        tok = _open.tok = CycleToken(self, cid, engine, n_events,
-                                     self.clock())
+        now = self.clock()
+        tok = _open.tok = CycleToken(self, cid, engine, n_events, now)
+        st = getattr(_open, "lead", None)
+        if st is not None:
+            # the first cycle of the thread's open send takes the lead
+            # as its ``admit``; a later one's lies in the cycles before
+            _open.lead = None
+            if st.watch is self.watch and st.t_in > st.t_out:
+                if st.ann is not None:
+                    st.ann.__exit__(None, None, None)
+                    st.ann = None
+                st.sampled, st.cycle = True, cid
+                self.record(cid, STAGE_ADMIT, engine, st.t_in, now, n_events)
         return tok
 
     # -- span sink -----------------------------------------------------------
@@ -487,11 +595,13 @@ class Tracer:
         children and the counters."""
         tok = CycleToken(self, next(self._ids), engine, 0, self.clock())
         found = reopen(tok)
+        self.free_open[tok.cycle] = (stage, tok.t_begin)
         try:
             with annotation(stage):
                 yield tok
         finally:
             reopen(found)
+            del self.free_open[tok.cycle]
         tok.record(stage, tok.t_begin, self.clock(), 0)
 
     # -- read-out ------------------------------------------------------------

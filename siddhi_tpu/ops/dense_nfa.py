@@ -85,6 +85,7 @@ from siddhi_tpu.core.exceptions import (
     SiddhiAppCreationError,
     SiddhiAppRuntimeError,
 )
+from siddhi_tpu.observability.stall import waits_on_device
 from siddhi_tpu.observability.trace import (
     SCOPE_DENSE_ADVANCE,
     SCOPE_DENSE_COUNT,
@@ -2197,6 +2198,7 @@ class DeferredDenseEmit:
         evidence); None when no round dispatched."""
         return self.chunks[-1]["count"] if self.chunks else None
 
+    @waits_on_device
     def resolve(self) -> int:
         """Fetch the deferred per-round count gates (scalars only) and
         prune rounds that matched nothing, so their column banks are

@@ -133,6 +133,7 @@ from siddhi_tpu.planner.expr import (
     Scope,
     TS_KEY,
 )
+from siddhi_tpu.observability.stall import waits_on_device
 from siddhi_tpu.observability.trace import (
     SCOPE_PANE_ASSIGN,
     SCOPE_PANE_COUNT,
@@ -2609,6 +2610,7 @@ class DeferredDeviceEmit:
                 return ch["count"]
         return None
 
+    @waits_on_device
     def resolve(self) -> int:
         """Fetch the per-chunk count gates (one ``device_get``, scalars
         only), prune zero-match chunks so their columns are never

@@ -73,6 +73,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from siddhi_tpu.core.exceptions import SiddhiAppCreationError
+from siddhi_tpu.observability.stall import waits_on_device
 from siddhi_tpu.observability.trace import (
     SCOPE_FUSED_COUNT,
     SCOPE_FUSED_HEAD,
@@ -457,6 +458,7 @@ class FusedDeferredEmit:
     def probe(self):
         return self.chunks[0]["count"] if self.chunks else None
 
+    @waits_on_device
     def resolve(self) -> int:
         if self._total is not None:
             return self._total

@@ -589,6 +589,11 @@ class StatisticsManager:
             for stage, metrics in self.tracer.stage_stats().items():
                 for metric, v in metrics.items():
                     out[self._metric("Stages", stage, metric)] = v
+            # sends that ran far past their usual length, ``all`` and by
+            # cause (observability/stall.py); nothing while none did
+            for cause, metrics in self.tracer.watch.stats().items():
+                for metric, v in metrics.items():
+                    out[self._metric("Stalls", cause, metric)] = v
         return out
 
     def reset(self):

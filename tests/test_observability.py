@@ -397,7 +397,8 @@ SERVED_PATHS = {
                        {"intern": 1, "convert": 2, "put": 1, "dispatch": 1},
                        "device"),
 }
-EVERY_CYCLE = ("ingest", "step", "emit", "fetch", "build", "deliver")
+EVERY_CYCLE = ("admit", "ingest", "step", "emit", "fetch", "build",
+               "deliver")
 
 
 def covered(spans, lo, hi):
@@ -415,8 +416,9 @@ def covered(spans, lo, hi):
 def test_spans_tile_send_batch(path, monkeypatch):
     """At sample='1' every batch yields every span its path has, under
     one cycle id, children inside parents, ``put`` counting the bytes
-    put; together they cover ``send_batch`` but for the entry's own
-    work ahead of the receiver."""
+    put; together they cover ``send_batch`` from its entry: ``admit``
+    is the entry's own work ahead of the cycle (PR 55), and what no
+    span covers is the way out and the microseconds between spans."""
     import jax
 
     opts, body, make, owed, kind = SERVED_PATHS[path]
@@ -470,6 +472,13 @@ def test_spans_tile_send_batch(path, monkeypatch):
                     assert [s[5] for s in by["plan"]] == [2]  # rounds
                 ingest, step = by["ingest"][0], by["step"][0]
                 emit = by["emit"][0]
+                # admit: from the send's entry stamp (inside the send,
+                # microseconds behind the caller's own) to begin_cycle,
+                # ahead of every other span of its cycle; the count is
+                # the batch's events
+                admit = by["admit"][0]
+                assert t0 <= admit[3] <= t0 + 0.5e-3 and admit[5] == 32
+                assert admit[4] <= min(s[3] for s in spans if s is not admit)
                 if "lanes" in by:
                     # once a batch, and no span of time: 24 keys and 8
                     # of them again, stepped 32 and 16 lanes wide
@@ -549,10 +558,18 @@ def test_spans_tile_send_batch(path, monkeypatch):
         # ingest is the shortest, read 0.77-0.80 on every batch of 32
         # on a slow host, at the seed as after it)
         assert max(shares) >= 0.7
-        # the stated remainder: InputHandler, junction and receiver ahead
-        # of the cycle, and the return through them: under half a
-        # millisecond where the host left a batch alone
+        # the stated remainder: the return through receiver, junction
+        # and InputHandler after the last span, and the microseconds
+        # between spans (the way in ahead of the cycle is ``admit``
+        # now): under half a millisecond where the host left a batch
+        # alone
         assert min(remainders) < 0.5e-3
+        # every send was sampled, so every one is on the histogram, and
+        # none is a tuple: a span over the send would hide the remainder
+        tracer = rt.app_context.tracer
+        assert tracer.stage_hist["send"].count == sent + 1
+        assert tracer.stage_hist["admit"].count == sent + 1
+        assert not [s for s in tracer.recorder.spans() if s[1] == "send"]
     finally:
         m.shutdown()
 
@@ -603,8 +620,12 @@ def test_unsampled_cycles_allocate_nothing(monkeypatch):
         # every Span and the two spans clocked by hand (step_wait, fetch)
         # made one annotation each
         assert made["annotation"] == made["span"] + 2
-        # ingest, step, emit, fetch; and lanes, a count and no Span
-        assert made["span"] == len(spans) - 5
+        # ingest, step, emit, fetch; lanes, a count and no Span; and
+        # admit, clocked from the send's entry stamp (at a sample under
+        # 1 nobody knows at the entry that the cycle will be sampled:
+        # no annotation)
+        assert made["span"] == len(spans) - 6
+        assert spans[0][1] == "admit"
         assert [s[1] for s in spans].count("lanes") == 1
         before = dict(made)
         for i in range(4, 7):   # 5..7 unsampled again

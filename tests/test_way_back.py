@@ -233,11 +233,13 @@ def test_a_cycles_life_reaches_statistics_and_prometheus():
         hist = app.tracer.stage_hist[trace_mod.STAGE_CYCLE]
         assert hist.count == len(emitted) >= 3
         # the ring yields the same interval, joined by cycle id: the
-        # cycle's first span (``intern``, ahead of ``ingest``) starts one
-        # reading after ``begin_cycle``'s
-        lives = [by["emit"][0][4] - (min(s[3] for spans in by.values()
-                                         for s in spans) - 1)
-                 for by in emitted]
+        # cycle's first span is its ``admit`` (PR 55), the send's lead
+        # ahead of the cycle, which ends at ``begin_cycle``'s reading:
+        # a cycle's life counts from there as it did
+        assert all(by["admit"][0][3] == min(s[3] for spans in by.values()
+                                            for s in spans)
+                   for by in emitted)
+        lives = [by["emit"][0][4] - by["admit"][0][4] for by in emitted]
         assert all(by["intern"][0][3] < by["ingest"][0][3] for by in emitted)
         assert hist.sum_ms == pytest.approx(1e3 * sum(lives))
         stats = app.rt.statistics()
