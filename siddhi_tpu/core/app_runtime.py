@@ -456,9 +456,10 @@ class SiddhiAppRuntime:
             # query: emit side (emitTransfers / deferredBatches /
             # zeroMatchSkips / maxPendingDepth / autoEffectiveDepth /
             # earlyCopyBatches / earlyCopyHits / earlyCopyWastedBytes) and
-            # ingest side (stagedBatches / devicePuts / deviceChunks /
-            # steppedLanes / plannedRepeats / fusedHops / ingestStalls /
-            # overlappedBatches / flushSyncs / maxStagingDepth)
+            # ingest side (stagedBatches / devicePuts / putLeaves /
+            # deviceChunks / steppedLanes / plannedRepeats / fusedHops /
+            # ingestStalls / overlappedBatches / flushSyncs /
+            # maxStagingDepth)
             for name, qr in list(self.query_runtimes.items()) + [
                 (n, q)
                 for pr in self.partitions.values()

@@ -11,8 +11,8 @@ This module holds the pieces every device runtime shares:
 
 - ``IngestStats``: per-runtime staging counters surfaced through
   ``util/statistics.py`` (``stagedBatches`` / ``devicePuts`` /
-  ``deviceChunks`` / ``steppedLanes`` / ``plannedRepeats`` /
-  ``fusedHops`` /
+  ``putLeaves`` / ``deviceChunks`` / ``steppedLanes`` /
+  ``plannedRepeats`` / ``fusedHops`` /
   ``ingestStalls`` / ``overlappedBatches`` / ``flushSyncs`` /
   ``maxStagingDepth``, and how often the window opened:
   ``gatesBySubmit`` / ``gatesByIdle`` / ``pipelineEntries`` /
@@ -91,7 +91,8 @@ class IngestStats:
     """Staging counters for one device runtime (host-side ints, same
     thin-gauge style as ``EmitStats``)."""
 
-    __slots__ = ("staged_batches", "device_puts", "device_chunks",
+    __slots__ = ("staged_batches", "device_puts", "put_leaves",
+                 "device_chunks",
                  "stepped_lanes", "planned_repeats", "fused_hops",
                  "ingest_stalls",
                  "overlapped_batches", "flush_syncs",
@@ -102,6 +103,11 @@ class IngestStats:
     def __init__(self):
         self.staged_batches = 0
         self.device_puts = 0
+        # arrays those puts handed to ``device_put``: a leaf costs the
+        # host about 0.2 ms on the chip whatever its size (PERF.md
+        # section 6), so an engine whose batch crosses as one packed
+        # buffer reads ``put_leaves == device_puts``
+        self.put_leaves = 0
         # chunks the batches were cut into for the device (the window
         # path, ops/device_query.py: a chunk is a put and a dispatch)
         self.device_chunks = 0
@@ -147,6 +153,7 @@ class IngestStats:
         return {
             "stagedBatches": self.staged_batches,
             "devicePuts": self.device_puts,
+            "putLeaves": self.put_leaves,
             "deviceChunks": self.device_chunks,
             "steppedLanes": self.stepped_lanes,
             "plannedRepeats": self.planned_repeats,
@@ -179,13 +186,15 @@ def staged_put(x, sharding=None, faults=None, stats: Optional[IngestStats] = Non
     injector's ``ingest.put`` site (when a harness is configured) with
     the same bounded retry-with-backoff ladder the emit drain uses, so
     transient transfer faults recover and sticky ones propagate.  Counts
-    one ``device_puts`` per call when ``stats`` is supplied, and is one
-    ``put`` span of the calling thread's open cycle (retries included).
+    one ``device_puts`` and the pytree's leaves (``put_leaves``) per
+    call when ``stats`` is supplied, and is one ``put`` span of the
+    calling thread's open cycle (retries included).
     """
     import jax
 
     if stats is not None:
         stats.device_puts += 1
+        stats.put_leaves += len(jax.tree_util.tree_leaves(x))
     with span(STAGE_PUT) as sp:
         if sp is not None:
             sp.count = host_nbytes(x)

@@ -103,6 +103,7 @@ from siddhi_tpu.observability.trace import (
 )
 from siddhi_tpu.ops.dense_layout import OVERFLOW, ROWS, DenseStateLayout
 from siddhi_tpu.ops.nfa import ANY, NFABuilder, Node, PatternScope, Spec
+from siddhi_tpu.ops.packed_lanes import LaneTable
 from siddhi_tpu.planner.expr import (
     CompiledExpression,
     ExpressionCompiler,
@@ -114,6 +115,11 @@ from siddhi_tpu.query_api import AttrType, StateInputStream, Variable
 from siddhi_tpu.query_api.definition import StreamDefinition
 
 log = logging.getLogger("siddhi_tpu.dense")
+
+#: Rows of a program's packed buffer beside the device columns
+#: (:meth:`DensePatternEngine.lane_table`): no attribute can take the
+#: names.
+LANE_PART, LANE_REL, LANE_OFF = "__part", "__rel", "__off"
 
 
 @dataclass
@@ -625,13 +631,20 @@ class DensePatternEngine:
 
     # -- step ---------------------------------------------------------------
 
-    def make_step(self, stream_key: str, jit: bool = True) -> Callable:
+    def make_step(self, stream_key: str, jit: bool = True,
+                  col_keys: Optional[Tuple[str, ...]] = None) -> Callable:
         """Build the step for events of one source stream.
 
         step(state, part_idx[B] i32, cols {attr: [B] f32}, ts[B] i32
              relative-ms, valid[B] bool)
           -> (state, emit[B, E] bool, out_vals[B, E, n_out] f32,
               emit_anchor[B, E] i32, n_emit i32 scalar)
+
+        The jitted program takes ``(state, buf)``: ``buf`` the ONE
+        packed ``int32 [k, B]`` buffer of :meth:`lane_table` for the
+        columns ``col_keys`` (default: every one the automaton reads),
+        taken apart by static slices at its top; a lane is valid where
+        its partition row is not the scratch row.
 
         ``emit[b, i]``: a pending instance of event ``b``'s partition
         completed the chain on this event.  The emit arrays carry
@@ -650,6 +663,20 @@ class DensePatternEngine:
         ``jit=False`` returns the raw traceable function (for embedding in
         shard_map / outer jit).
         """
+        if jit:
+            table = self.lane_table(stream_key, col_keys)
+            cache_key = (stream_key, jit, table.names)
+            if cache_key not in self._step_cache:
+                raw = self.make_step(stream_key, jit=False)
+
+                def step(state, buf):
+                    part, cols, ts, _off = self._unpack_lanes(table, buf)
+                    return raw(state, part, cols, ts,
+                               part != self.n_partitions)
+
+                self._step_cache[cache_key] = self.jax.jit(
+                    step, donate_argnums=(0,))
+            return self._step_cache[cache_key]
         cache_key = (stream_key, jit)
         if cache_key in self._step_cache:
             return self._step_cache[cache_key]
@@ -676,9 +703,8 @@ class DensePatternEngine:
                 n_emit = jnp.sum((emit & valid[:, None]).astype(jnp.int32))
             return new_state, emit, outs, emit_anchor, n_emit
 
-        fn = self.jax.jit(step, donate_argnums=(0,)) if jit else step
-        self._step_cache[cache_key] = fn
-        return fn
+        self._step_cache[cache_key] = step
+        return step
 
     def make_advance(self, stream_key: str) -> Callable:
         """The automaton alone, on the logical fields of B rows:
@@ -1348,19 +1374,23 @@ class DensePatternEngine:
                 return None
         return run
 
-    def make_rounds(self, stream_key: str) -> Callable:
+    def make_rounds(self, stream_key: str,
+                    col_keys: Optional[Tuple[str, ...]] = None) -> Callable:
         """Build the program for a batch's rounds past the first.
 
-        rounds(state, part_idx[R] i32, cols {attr: [R]}, ts[R] i32,
-               off[R + 1] i32)
+        rounds(state, buf i32 [k, R])
           -> as :meth:`make_step`, the emit arrays indexed by lane
 
-        The lanes hold the events of the batch's second and later
-        rounds in :func:`round_plan`'s order: round ``r`` is lanes
-        ``off[r]:off[r + 1]``, each round's partitions a prefix of the
-        one before, ``off`` padded with the number of lanes.  How many
-        rounds there are and how wide each is are runtime values: one
-        program serves every batch of ``R`` padded lanes.
+        ``buf`` is the packed buffer of :meth:`lane_table` with its
+        offsets row: partition rows, the columns ``col_keys``, relative
+        ms and ``off``.  The lanes hold the events of the batch's second
+        and later rounds in :func:`round_plan`'s order: round ``r`` is
+        lanes ``off[r]:off[r + 1]``, each round's partitions a prefix of
+        the one before, ``off`` padded with the number of lanes (which
+        is also ``off[R]``, an entry the row has no room for: the lanes
+        whose partition row is not the scratch row, counted here).  How
+        many rounds there are and how wide each is are runtime values:
+        one program serves every batch of ``R`` padded lanes.
 
         Wide rounds each go through :meth:`make_step`'s step (gather,
         automaton, scatter) inside a ``while_loop``.  From the first
@@ -1376,7 +1406,8 @@ class DensePatternEngine:
         Pallas kernel that mirrors that automaton bit for bit (a link
         is some 220 XLA operations of 128 rows each, a thousand links a
         quarter of a million kernel launches)."""
-        cache_key = (stream_key, "rounds")
+        table = self.lane_table(stream_key, col_keys, offsets=True)
+        cache_key = (stream_key, "rounds", table.names)
         if cache_key in self._step_cache:
             return self._step_cache[cache_key]
         jax, jnp = self.jax, self.jnp
@@ -1392,10 +1423,12 @@ class DensePatternEngine:
         H = self.RUN_WIDTH
         named_scope = jax.named_scope
 
-        def rounds(state, part_idx, cols, ts, off):
+        def rounds(state, buf):
+            part_idx, cols, ts, off = self._unpack_lanes(table, buf)
             R = part_idx.shape[0]
             scratch = state[ROWS].shape[0] - 1
-            end = off[R]
+            end = jnp.sum((part_idx != scratch).astype(jnp.int32))
+            off = jnp.concatenate([off, end[None]])
             n_rounds = jnp.sum((off[:R] < end).astype(jnp.int32))
             # a round is sliced at its loop's static width: pad the
             # lanes so that no slice is clamped back into other rounds
@@ -1918,8 +1951,9 @@ class DensePatternEngine:
         plain step, every later occurrence through :meth:`make_rounds`'
         program, which loops over the remaining rounds on the device (a
         single second round through the step again).  At most two H2D
-        puts and two dispatches a batch, however long the longest run
-        of one partition is.  The lanes those programs step, padding
+        puts, each of one packed buffer (:meth:`lane_table`), and two
+        dispatches a batch, however long the longest run of one
+        partition is.  The lanes those programs step, padding
         and all, are the cycle's ``lanes`` count and add to the
         runtime's ``steppedLanes``: known here from the plan's widths,
         with nothing fetched."""
@@ -1932,11 +1966,14 @@ class DensePatternEngine:
         if n == 0:
             return state, None
         with span(STAGE_CONVERT, n):
-            step = self.make_step(stream_key)
+            part_idx = np.asarray(part_idx, dtype=np.int32)
             rel64 = self.rel_ts64(np.asarray(ts, dtype=np.int64))
             state, rel64 = self.maybe_re_anchor(state, rel64)
             rel = rel64.astype(np.int32)
             prepared = self.prepare_cols(stream_key, cols)
+            # the programs are keyed on the columns the batch brings
+            col_keys = tuple(prepared)
+            step = self.make_step(stream_key, col_keys=col_keys)
         with span(STAGE_PLAN) as sp:
             plan = self.plan_rounds(part_idx)
             if sp is not None:
@@ -1946,34 +1983,31 @@ class DensePatternEngine:
         # third makes the rounds program worth its trace (a second or
         # two a shape, which a cell of two rounds would pay at set-up)
         programs = ((step,) * plan.n_rounds if plan.n_rounds <= 2
-                    else (step, self.make_rounds(stream_key)))
+                    else (step, self.make_rounds(stream_key, col_keys)))
         bounds = (0, int(plan.off[1]), n)[:len(programs) + 1]
         pending = DeferredDenseEmit(self)
         stats = getattr(self, "ingest_stats", None)
         stepped = 0     # lanes the programs step, padding and all
         for program, lo, hi in zip(programs, bounds, bounds[1:]):
             ev = plan.lanes[lo:hi]
+            rounds = program is not step
             with span(STAGE_CONVERT, hi - lo):
-                lanes = self._pad_lanes(part_idx, prepared, rel, ev)
-                if program is step:
-                    where = np.zeros(len(lanes[0]), dtype=bool)  # valid
-                    where[:hi - lo] = True
-                    stepped += len(where)
-                else:
-                    # starts of rounds 1.. within the rest, then its
-                    # end on every further entry
-                    where = np.full(len(lanes[0]) + 1, hi - lo,
-                                    dtype=np.int32)
-                    where[:plan.n_rounds] = plan.off[1:] - lo
-                    stepped += self.rounds_lanes(len(lanes[0]),
-                                                 np.diff(plan.off[1:]))
-            # one pytree H2D put a program behind the ingest.put fault
-            # site (core/ingest_stage.py — the sanctioned ingest path);
-            # the second goes while the device steps the first round
-            args = staged_put(lanes + (where,), faults=faults, stats=stats)
+                # the rounds program's offsets row: starts of rounds 1..
+                # within the rest, then its end on every further entry
+                buf = self._pad_lanes(
+                    self.lane_table(stream_key, col_keys, offsets=rounds),
+                    part_idx, prepared, rel, ev,
+                    plan.off[1:-1] - lo if rounds else None)
+                stepped += (self.rounds_lanes(buf.shape[1],
+                                              np.diff(plan.off[1:]))
+                            if rounds else buf.shape[1])
+            # ONE H2D put of one leaf a program behind the ingest.put
+            # fault site (core/ingest_stage.py — the sanctioned ingest
+            # path); the second goes while the device steps the first
+            # round
+            buf = staged_put(buf, faults=faults, stats=stats)
             with span(STAGE_DISPATCH, 1):
-                state, emit, outs, emit_anchor, n_emit = program(
-                    state, *args)
+                state, emit, outs, emit_anchor, n_emit = program(state, buf)
             # count gate deferred: n_emit stays a device scalar until
             # DeferredDenseEmit.resolve() (driven by the ingest stage)
             pending.chunks.append({
@@ -2001,22 +2035,52 @@ class DensePatternEngine:
             stats.planned_repeats += len(part_idx) - int(plan.off[1])
         return plan
 
-    def _pad_lanes(self, part_idx, prepared, rel, ev):
-        """Host lanes of the events ``ev`` padded to a power of two (at
-        least 16, bounding jit recompilation): partition rows (padding
-        points at the scratch row), device columns, relative ms."""
+    def lane_table(self, stream_key: str,
+                   col_keys: Optional[Tuple[str, ...]] = None,
+                   offsets: bool = False) -> LaneTable:
+        """The rows of the one buffer a dispatched program's host lanes
+        cross as (``ops/packed_lanes.py``): the partition row, a row a
+        device column (``col_keys``: those a batch brings, in
+        :meth:`device_col_keys`' order; by default all of them), the
+        relative ms and, for the rounds program, the rounds' offsets."""
+        if col_keys is None:
+            col_keys = tuple(self.device_col_keys(stream_key))
+        cache_key = (stream_key, "lanes", col_keys, offsets)
+        if cache_key not in self._step_cache:
+            rows = [(LANE_PART, np.int32)]
+            rows += [(k, np.int32 if "|" in k else np.float32)
+                     for k in col_keys]
+            rows.append((LANE_REL, np.int32))
+            if offsets:
+                rows.append((LANE_OFF, np.int32))
+            self._step_cache[cache_key] = LaneTable(rows)
+        return self._step_cache[cache_key]
+
+    def _pad_lanes(self, table: LaneTable, part_idx, prepared, rel, ev,
+                   starts=None) -> np.ndarray:
+        """Host lanes of the events ``ev`` as ``table``'s one buffer,
+        padded to a power of two (at least 16, bounding jit
+        recompilation): partition rows (padding points at the scratch
+        row, which is what says a lane is padding), device columns,
+        relative ms.  ``starts``: the leading entries of the offsets
+        row, whose every further entry is the number of events."""
         b = len(ev)
         bp = max(1 << (b - 1).bit_length(), 16)
-        pi = np.full(bp, self.n_partitions, dtype=np.int32)  # scratch row
-        pi[:b] = part_idx[ev]
-        tb = np.zeros(bp, dtype=np.int32)
-        tb[:b] = rel[ev]
-        cb = {}
-        for k, v in prepared.items():
-            col = np.zeros(bp, dtype=v.dtype)
-            col[:b] = v[ev]
-            cb[k] = col
-        return pi, cb, tb
+        buf = table.pack(
+            {**prepared, LANE_PART: part_idx, LANE_REL: rel}, ev, bp,
+            pad={LANE_PART: self.n_partitions, LANE_OFF: b})
+        if starts is not None:
+            buf[-1, :len(starts)] = starts
+        return buf
+
+    def _unpack_lanes(self, table: LaneTable, buf):
+        """Traced: ``table``'s packed buffer back into a program's
+        ``(part_idx, cols, ts, off)``; ``off`` None where the table has
+        no offsets row."""
+        cols = table.unpack(buf)
+        part, ts = cols.pop(LANE_PART), cols.pop(LANE_REL)
+        off = cols.pop(LANE_OFF, None)
+        return part, cols, ts, off
 
     def assemble_out(self, out_f: np.ndarray, out_i: np.ndarray,
                      rows: np.ndarray, lanes: np.ndarray) -> np.ndarray:

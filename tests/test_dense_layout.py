@@ -214,13 +214,9 @@ def test_step_has_no_whole_state_instruction(eng_name):
         host = eng.init_state_host()
         state = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                  for k, v in host.items()}
-        cols = {k: jax.ShapeDtypeStruct(
-            (B,), np.int32 if "|" in k else np.float32)
-            for k in eng.device_col_keys(sk)}
-        i32 = jax.ShapeDtypeStruct((B,), np.int32)
-        hlo = eng.make_step(sk).lower(
-            state, i32, cols, i32,
-            jax.ShapeDtypeStruct((B,), np.bool_)).compile().as_text()
+        # the jitted step takes its lane table's one packed buffer
+        buf = jax.ShapeDtypeStruct((len(eng.lane_table(sk)), B), np.int32)
+        hlo = eng.make_step(sk).lower(state, buf).compile().as_text()
         comps = _computations(hlo)
         assert comps and any(whole.search(t) for c in comps.values()
                              for _n, t, _o, _l in c)

@@ -710,15 +710,18 @@ def test_jitted_steps_carry_the_device_scopes():
     rounds = {trace_mod.SCOPE_DENSE_ROUNDS, trace_mod.SCOPE_DENSE_RUN}
     # a chain of plain stream nodes has no count node's part
     dense -= rounds | {trace_mod.SCOPE_DENSE_KLEENE}
+    # the jitted programs take the one packed buffer of their lane table
     assert scopes_in(eng.make_step(sk).lower(
-        eng.init_state(), idx, cols, idx, np.ones(n, bool))) == dense
+        eng.init_state(), eng._pad_lanes(
+            eng.lane_table(sk), idx, cols, idx, idx))) == dense
     # the rounds program: wide enough to have wide rounds beside the run
     wide = np.arange(4 * eng.RUN_WIDTH, dtype=np.int32) % 64
     text = eng.make_rounds(sk).lower(
-        eng.init_state(), wide,
-        eng.prepare_cols(sk, {"k": wide.astype(np.int64),
-                              "v": np.linspace(0.0, 20.0, len(wide))}),
-        wide, np.append(wide, 0)).compile().as_text()
+        eng.init_state(), eng._pad_lanes(
+            eng.lane_table(sk, offsets=True), wide,
+            eng.prepare_cols(sk, {"k": wide.astype(np.int64),
+                                  "v": np.linspace(0.0, 20.0, len(wide))}),
+            wide, wide, wide[:-1])).compile().as_text()
     for outer in rounds:
         # the wide rounds gather, advance and scatter round by round;
         # the run gathers once and scatters once, and between them this
@@ -774,8 +777,9 @@ def test_a_count_nodes_part_of_advance_has_a_scope_of_its_own():
     cols = eng.prepare_cols(sk, {"user": idx.astype(np.int64),
                                  "ok": idx % 2, "ip": idx})
     text = eng.make_step(sk).lower(
-        eng.init_state(), idx, cols, idx, np.ones(n, bool)).as_text(
-            debug_info=True)
+        eng.init_state(), eng._pad_lanes(
+            eng.lane_table(sk), idx, cols, idx, idx)).as_text(
+                debug_info=True)
     nested = (trace_mod.SCOPE_DENSE_ADVANCE + "/"
               + trace_mod.SCOPE_DENSE_KLEENE)
     assert nested in text
