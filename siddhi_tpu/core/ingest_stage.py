@@ -12,7 +12,7 @@ This module holds the pieces every device runtime shares:
 - ``IngestStats``: per-runtime staging counters surfaced through
   ``util/statistics.py`` (``stagedBatches`` / ``devicePuts`` /
   ``putLeaves`` / ``deviceChunks`` / ``steppedLanes`` /
-  ``plannedRepeats`` / ``fusedHops`` /
+  ``plannedRepeats`` / ``batchesByStream.<stream>`` / ``fusedHops`` /
   ``ingestStalls`` / ``overlappedBatches`` / ``flushSyncs`` /
   ``maxStagingDepth``, and how often the window opened:
   ``gatesBySubmit`` / ``gatesByIdle`` / ``pipelineEntries`` /
@@ -93,7 +93,8 @@ class IngestStats:
 
     __slots__ = ("staged_batches", "device_puts", "put_leaves",
                  "device_chunks",
-                 "stepped_lanes", "planned_repeats", "fused_hops",
+                 "stepped_lanes", "planned_repeats", "batches_by_stream",
+                 "fused_hops",
                  "ingest_stalls",
                  "overlapped_batches", "flush_syncs",
                  "dropped_batches",
@@ -122,6 +123,11 @@ class IngestStats:
         # second call of the step or the rounds program
         # (ops/dense_nfa.py ``round_plan``).  0 on every other engine
         self.planned_repeats = 0
+        # non-empty batches the dense engine took, by the input stream
+        # they came on (ops/dense_nfa.py ``process_deferred``): a
+        # pattern over two streams steps another program for each.
+        # Empty on every other engine
+        self.batches_by_stream = {}
         # junction hops a fused chain kept on the device: stages - 1 a
         # batch (core/fused_graph.py); 0 on every other runtime
         self.fused_hops = 0
@@ -168,6 +174,8 @@ class IngestStats:
             "droppedBatches": self.dropped_batches,
             "maxStagingDepth": self.max_staging_depth,
             "autoIngestDepth": self.auto_depth,
+            **{f"batchesByStream.{stream}": n
+               for stream, n in self.batches_by_stream.items()},
         }
 
 

@@ -47,9 +47,12 @@ how soon a batch's matches reach the callback (in the ring: the end of
 the cycle's ``admit``, or its first span's start where it took none, to
 its ``emit``'s end).
 
-One count is a tuple in the ring and no interval (``lanes``,
-:func:`counted`): the lanes the dense engine's programs step for the
-batch, which it knows from the round plan and fetches nothing for.
+Two counts are tuples in the ring and no intervals (:func:`counted`):
+``lanes``, the lanes the dense engine's programs step for the batch,
+which it knows from the round plan and fetches nothing for, and
+``stream``, the place of the batch's input stream among the streams its
+pattern reads (0 for the first, and for every batch of a pattern over
+one stream).
 
 The code that does that work lives in engines that know no tracer
 (``ops/``, ``parallel/``, ``core/ingest_stage.py``, the runtime
@@ -149,7 +152,8 @@ CYCLE_STAGES = (STAGE_ADMIT, STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT,
 #: a count of a cycle that is no interval: one zero-width tuple in the
 #: ring (:func:`counted`), no annotation and no histogram
 STAGE_LANES = "lanes"        # lanes the batch's programs step (dense engine)
-CYCLE_COUNTS = (STAGE_LANES,)
+STAGE_STREAM = "stream"      # the batch's stream, as its place in stream_keys
+CYCLE_COUNTS = (STAGE_LANES, STAGE_STREAM)
 #: intervals of a cycle kept as histograms alone (module docstring)
 STAGE_STAGED = "staged"      # dispatch to the start of a deferred gate's fetch
 STAGE_CYCLE = "cycle"        # begin_cycle to the end of emit
@@ -216,6 +220,9 @@ SCOPE_DENSE_ADVANCE = "siddhi.dense.advance"
 # inside advance, a count node's part: the capture and count update, the
 # `every` re-arm at the minimum, an open count's via-path clone
 SCOPE_DENSE_KLEENE = "siddhi.dense.kleene"
+# inside advance, a logical node's part: its sides' captures and side
+# bits, an and-not's kill, the completion and the lane's release
+SCOPE_DENSE_LOGICAL = "siddhi.dense.logical"
 SCOPE_DENSE_SCATTER = "siddhi.dense.scatter"
 SCOPE_DENSE_COUNT = "siddhi.dense.count"
 # make_rounds: the wide rounds past a batch's first, and the run of
@@ -254,14 +261,14 @@ SCOPE_DEVTABLE_CONDITION = "siddhi.devtable.condition"
 SCOPE_DEVTABLE_SCATTER = "siddhi.devtable.scatter"
 DEVICE_SCOPES = (
     SCOPE_DENSE_GATHER, SCOPE_DENSE_ADVANCE, SCOPE_DENSE_KLEENE,
-    SCOPE_DENSE_SCATTER, SCOPE_DENSE_COUNT, SCOPE_DENSE_ROUNDS,
-    SCOPE_DENSE_RUN, SCOPE_SHARD_COUNT_PSUM, SCOPE_WINDOW_FILTER,
-    SCOPE_WINDOW_SLOT, SCOPE_WINDOW_AGGREGATE, SCOPE_WINDOW_EMIT,
-    SCOPE_WINDOW_UPDATE, SCOPE_WINDOW_COUNT, SCOPE_PANE_ASSIGN,
-    SCOPE_PANE_REDUCE, SCOPE_PANE_EMIT, SCOPE_PANE_COUNT, SCOPE_FUSED_HEAD,
-    SCOPE_FUSED_INTERIOR, SCOPE_FUSED_TAIL, SCOPE_FUSED_COUNT,
-    SCOPE_DEVTABLE_PROBE, SCOPE_DEVTABLE_GATHER, SCOPE_DEVTABLE_CONDITION,
-    SCOPE_DEVTABLE_SCATTER)
+    SCOPE_DENSE_LOGICAL, SCOPE_DENSE_SCATTER, SCOPE_DENSE_COUNT,
+    SCOPE_DENSE_ROUNDS, SCOPE_DENSE_RUN, SCOPE_SHARD_COUNT_PSUM,
+    SCOPE_WINDOW_FILTER, SCOPE_WINDOW_SLOT, SCOPE_WINDOW_AGGREGATE,
+    SCOPE_WINDOW_EMIT, SCOPE_WINDOW_UPDATE, SCOPE_WINDOW_COUNT,
+    SCOPE_PANE_ASSIGN, SCOPE_PANE_REDUCE, SCOPE_PANE_EMIT, SCOPE_PANE_COUNT,
+    SCOPE_FUSED_HEAD, SCOPE_FUSED_INTERIOR, SCOPE_FUSED_TAIL,
+    SCOPE_FUSED_COUNT, SCOPE_DEVTABLE_PROBE, SCOPE_DEVTABLE_GATHER,
+    SCOPE_DEVTABLE_CONDITION, SCOPE_DEVTABLE_SCATTER)
 
 # the calling thread's open cycle: set by begin_cycle (None for an
 # unsampled cycle), cleared when the cycle's ingest span ends; set

@@ -91,6 +91,7 @@ from siddhi_tpu.observability.trace import (
     SCOPE_DENSE_COUNT,
     SCOPE_DENSE_GATHER,
     SCOPE_DENSE_KLEENE,
+    SCOPE_DENSE_LOGICAL,
     SCOPE_DENSE_ROUNDS,
     SCOPE_DENSE_RUN,
     SCOPE_DENSE_SCATTER,
@@ -98,6 +99,7 @@ from siddhi_tpu.observability.trace import (
     STAGE_DISPATCH,
     STAGE_LANES,
     STAGE_PLAN,
+    STAGE_STREAM,
     counted,
     span,
 )
@@ -1008,89 +1010,90 @@ class DensePatternEngine:
                         carry = (a, first, counts, regs, iregs, emit, out_vals,
                                  out_ivals, emit_anchor, ovf)
                         continue
-                    pending = a[:, s, :]
-                    if s == 0 and every_start:
-                        # the standing virgin lives in lane 0
-                        pending = pending | lane0
-                    for si in kills:
-                        # and-not violation: the absent side arriving
-                        # while the node is pending kills the instance
-                        # (virgins re-arm per event, so only real armed
-                        # lanes die)
-                        viol = a[:, s, :] & ok_pre[s][si] & valid[:, None]
-                        a = a.at[:, s, :].set(a[:, s, :] & ~viol)
-                        counts = counts.at[:, s, :].set(
-                            jnp.where(viol, 0, counts[:, s, :]))
-                        first = first.at[:, s, :].set(
-                            jnp.where(viol, 0, first[:, s, :]))
-                        if dlh[0] is not None:
-                            dlh[0] = dlh[0].at[:, s, :].set(
-                                jnp.where(viol, 0, dlh[0][:, s, :]))
-                        pending = pending & ~viol
+                    with named_scope(SCOPE_DENSE_LOGICAL):
+                        pending = a[:, s, :]
                         if s == 0 and every_start:
+                            # the standing virgin lives in lane 0
                             pending = pending | lane0
-                    # event-time completion requires a present side to
-                    # have matched THIS event (host completes only inside
-                    # _try_capture's got branch); deferred completions —
-                    # sides matched earlier, and-not-for deadline passing
-                    # later — fire from the timer step alone
-                    matched_now = jnp.zeros((B, I), dtype=bool)
-                    for si in sides:
-                        ok = ok_pre[s][si]
-                        # an already-matched side ignores further events
-                        # (the reference skips si in matched_sides —
-                        # neither registers nor the anchor may refresh)
-                        unmatched = (counts[:, s, :] & (1 << si)) == 0
-                        fire = pending & ok & valid[:, None] & unmatched
-                        if node.logical_op == "or":
-                            # 'or' consumes only the FIRST matching side
-                            # (host/reference leave the other side's
-                            # capture null — LogicalPatternTestCase.
-                            # testQuery3); 'and' lets one event fill both
-                            fire = fire & ~matched_now
-                        matched_now = matched_now | fire
+                        for si in kills:
+                            # and-not violation: the absent side arriving
+                            # while the node is pending kills the instance
+                            # (virgins re-arm per event, so only real armed
+                            # lanes die)
+                            viol = a[:, s, :] & ok_pre[s][si] & valid[:, None]
+                            a = a.at[:, s, :].set(a[:, s, :] & ~viol)
+                            counts = counts.at[:, s, :].set(
+                                jnp.where(viol, 0, counts[:, s, :]))
+                            first = first.at[:, s, :].set(
+                                jnp.where(viol, 0, first[:, s, :]))
+                            if dlh[0] is not None:
+                                dlh[0] = dlh[0].at[:, s, :].set(
+                                    jnp.where(viol, 0, dlh[0][:, s, :]))
+                            pending = pending & ~viol
+                            if s == 0 and every_start:
+                                pending = pending | lane0
+                        # event-time completion requires a present side to
+                        # have matched THIS event (host completes only inside
+                        # _try_capture's got branch); deferred completions —
+                        # sides matched earlier, and-not-for deadline passing
+                        # later — fire from the timer step alone
+                        matched_now = jnp.zeros((B, I), dtype=bool)
+                        for si in sides:
+                            ok = ok_pre[s][si]
+                            # an already-matched side ignores further events
+                            # (the reference skips si in matched_sides —
+                            # neither registers nor the anchor may refresh)
+                            unmatched = (counts[:, s, :] & (1 << si)) == 0
+                            fire = pending & ok & valid[:, None] & unmatched
+                            if node.logical_op == "or":
+                                # 'or' consumes only the FIRST matching side
+                                # (host/reference leave the other side's
+                                # capture null — LogicalPatternTestCase.
+                                # testQuery3); 'and' lets one event fill both
+                                fire = fire & ~matched_now
+                            matched_now = matched_now | fire
+                            counts = counts.at[:, s, :].set(
+                                jnp.where(fire, counts[:, s, :] | (1 << si),
+                                          counts[:, s, :]))
+                            for slot in self.node_writes[s]:
+                                if slot.ref == node.specs[si].ref:
+                                    regs, iregs = write_slot(regs, iregs, s, slot, fire)
+                            first = first.at[:, s, :].set(
+                                jnp.where(fire & (first[:, s, :] == 0), ts[:, None],
+                                          first[:, s, :]))
+                        # completion needs every PRESENT side (absent sides
+                        # contribute by staying silent); `and not B for t`
+                        # additionally requires the deadline to have passed
+                        # (host _logical_complete: now >= deadline, with a
+                        # timer-consumed deadline reading as satisfied)
+                        pmask = sum(1 << i for i, sp in enumerate(node.specs)
+                                    if not sp.is_absent)
+                        need = counts[:, s, :] & pmask
+                        complete = (
+                            (need == pmask)
+                            if node.logical_op == "and"
+                            else (need > 0)
+                        ) & pending & valid[:, None] & matched_now
+                        if self.deadline_w[s] is not None:
+                            dls = dlh[0][:, s, :]
+                            complete = complete & (
+                                (dls == 0) | (ts[:, None] >= dls))
+                        carry = _advance(s, complete,
+                                         (a, first, counts, regs, iregs, emit, out_vals,
+                                          out_ivals, emit_anchor, ovf))
+                        a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
+                        # a completed logical node releases its lane (the host
+                        # instance moves on); the lane-0 virgin re-arms fresh
+                        a = a.at[:, s, :].set(a[:, s, :] & ~complete)
                         counts = counts.at[:, s, :].set(
-                            jnp.where(fire, counts[:, s, :] | (1 << si),
-                                      counts[:, s, :]))
-                        for slot in self.node_writes[s]:
-                            if slot.ref == node.specs[si].ref:
-                                regs, iregs = write_slot(regs, iregs, s, slot, fire)
+                            jnp.where(complete, 0, counts[:, s, :]))
                         first = first.at[:, s, :].set(
-                            jnp.where(fire & (first[:, s, :] == 0), ts[:, None],
-                                      first[:, s, :]))
-                    # completion needs every PRESENT side (absent sides
-                    # contribute by staying silent); `and not B for t`
-                    # additionally requires the deadline to have passed
-                    # (host _logical_complete: now >= deadline, with a
-                    # timer-consumed deadline reading as satisfied)
-                    pmask = sum(1 << i for i, sp in enumerate(node.specs)
-                                if not sp.is_absent)
-                    need = counts[:, s, :] & pmask
-                    complete = (
-                        (need == pmask)
-                        if node.logical_op == "and"
-                        else (need > 0)
-                    ) & pending & valid[:, None] & matched_now
-                    if self.deadline_w[s] is not None:
-                        dls = dlh[0][:, s, :]
-                        complete = complete & (
-                            (dls == 0) | (ts[:, None] >= dls))
-                    carry = _advance(s, complete,
-                                     (a, first, counts, regs, iregs, emit, out_vals,
-                                      out_ivals, emit_anchor, ovf))
-                    a, first, counts, regs, iregs, emit, out_vals, out_ivals, emit_anchor, ovf = carry
-                    # a completed logical node releases its lane (the host
-                    # instance moves on); the lane-0 virgin re-arms fresh
-                    a = a.at[:, s, :].set(a[:, s, :] & ~complete)
-                    counts = counts.at[:, s, :].set(
-                        jnp.where(complete, 0, counts[:, s, :]))
-                    first = first.at[:, s, :].set(
-                        jnp.where(complete, 0, first[:, s, :]))
-                    if dlh[0] is not None and self.deadline_w[s] is not None:
-                        dlh[0] = dlh[0].at[:, s, :].set(
-                            jnp.where(complete, 0, dlh[0][:, s, :]))
-                    carry = (a, first, counts, regs, iregs, emit, out_vals,
-                             out_ivals, emit_anchor, ovf)
+                            jnp.where(complete, 0, first[:, s, :]))
+                        if dlh[0] is not None and self.deadline_w[s] is not None:
+                            dlh[0] = dlh[0].at[:, s, :].set(
+                                jnp.where(complete, 0, dlh[0][:, s, :]))
+                        carry = (a, first, counts, regs, iregs, emit, out_vals,
+                                 out_ivals, emit_anchor, ovf)
                     continue
                 if spec.stream_key != stream_key:
                     carry = (a, first, counts, regs, iregs, emit, out_vals,
@@ -1956,7 +1959,9 @@ class DensePatternEngine:
         partition is.  The lanes those programs step, padding
         and all, are the cycle's ``lanes`` count and add to the
         runtime's ``steppedLanes``: known here from the plan's widths,
-        with nothing fetched."""
+        with nothing fetched.  The batch's stream, as its place in
+        ``stream_keys``, is the cycle's ``stream`` count, and the
+        runtime's ``batchesByStream.<stream>`` counts its batches."""
         faults = getattr(self, "faults", None)
         if faults is not None:
             faults.check("step.dense")
@@ -2016,8 +2021,11 @@ class DensePatternEngine:
                 "ridx": ev, "count": n_emit,
             })
         counted(STAGE_LANES, stepped)
+        counted(STAGE_STREAM, self.stream_keys.index(stream_key))
         if stats is not None:
             stats.stepped_lanes += stepped
+            stats.batches_by_stream[stream_key] = (
+                stats.batches_by_stream.get(stream_key, 0) + 1)
         return state, pending
 
     def plan_rounds(self, part_idx: np.ndarray) -> "RoundPlan":

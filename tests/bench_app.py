@@ -53,9 +53,11 @@ def run_app(config: dict, header: str, batches, inspect=None):
             (e.timestamp, *e.data) for e in evs))
         rt.add_exception_listener(errors.append)
         rt.start()
-        h = rt.get_input_handler(config["stream"])
+        streams = config["stream"]      # one name, or a list of them
+        send = {s: rt.get_input_handler(s).send_batch for s in (
+            [streams] if isinstance(streams, str) else streams)}
         for b in batches:
-            h.send_batch(b)
+            send[b.stream_id](b)
         rt.drain_device_emits()
         lowering = rt.lowering()
         seen = inspect(rt) if inspect else None

@@ -237,7 +237,8 @@ def test_trace_annotation_configures_tracer(tmp_path):
 
 def test_recorder_ring_evicts_to_newest_cycles():
     """The ring at cycles=N holds the last N complete cycles: every
-    stage once, the dense engine's count of lanes, the host
+    stage once, the dense engine's counts of lanes and of the batch's
+    stream, the host
     preparation in two more pieces, a second host-stepped round, and
     the two persist spans interleaving."""
     one_cycle = (trace_mod.CYCLE_STAGES + trace_mod.CYCLE_COUNTS
@@ -383,8 +384,8 @@ def window_batch(i, n=32):
 # the least number of each, engine kind)
 SERVED_PATHS = {
     "dense": ("partitions='64'", PARTITIONED_BODY, keyed_batch,
-              {"intern": 1, "convert": 4, "plan": 1, "lanes": 1, "put": 2,
-               "dispatch": 2}, "dense"),
+              {"intern": 1, "convert": 4, "plan": 1, "lanes": 1, "stream": 1,
+               "put": 2, "dispatch": 2}, "dense"),
     "shard": ("partitions='64', devices='4'", PARTITIONED_BODY, keyed_batch,
               {"intern": 1, "convert": 4, "plan": 1, "route": 2, "put": 2,
                "dispatch": 2}, "shard"),
@@ -485,6 +486,11 @@ def test_spans_tile_send_batch(path, monkeypatch):
                     assert [(s[5], s[4] - s[3]) for s in by["lanes"]] == [
                         (32 + 16, 0.0)]
                     assert ingest[3] <= by["lanes"][0][3] <= ingest[4]
+                    # the batch's stream, once a batch and of no width
+                    # either: 0 on an app of one input stream
+                    assert [(s[5], s[4] - s[3]) for s in by["stream"]] == [
+                        (0, 0.0)]
+                    assert ingest[3] <= by["stream"][0][3] <= ingest[4]
                 # ingest, step and emit start and end where they always
                 # did: ingest closes on the dispatch, step runs from there
                 # to the count gate, emit from the fetch to the delivery
@@ -620,13 +626,15 @@ def test_unsampled_cycles_allocate_nothing(monkeypatch):
         # every Span and the two spans clocked by hand (step_wait, fetch)
         # made one annotation each
         assert made["annotation"] == made["span"] + 2
-        # ingest, step, emit, fetch; lanes, a count and no Span; and
+        # ingest, step, emit, fetch; lanes and stream, counts and no
+        # Spans; and
         # admit, clocked from the send's entry stamp (at a sample under
         # 1 nobody knows at the entry that the cycle will be sampled:
         # no annotation)
-        assert made["span"] == len(spans) - 6
+        assert made["span"] == len(spans) - 7
         assert spans[0][1] == "admit"
         assert [s[1] for s in spans].count("lanes") == 1
+        assert [s[1] for s in spans].count("stream") == 1
         before = dict(made)
         for i in range(4, 7):   # 5..7 unsampled again
             h.send_batch(keyed_batch(i))
@@ -706,10 +714,12 @@ def test_jitted_steps_carry_the_device_scopes():
     cols = eng.prepare_cols(sk, {"k": idx.astype(np.int64),
                                  "v": np.linspace(0.0, 20.0, n)})
     dense = {sc for sc in trace_mod.DEVICE_SCOPES if ".dense." in sc}
-    assert len(dense) == 7
+    assert len(dense) == 8
     rounds = {trace_mod.SCOPE_DENSE_ROUNDS, trace_mod.SCOPE_DENSE_RUN}
-    # a chain of plain stream nodes has no count node's part
-    dense -= rounds | {trace_mod.SCOPE_DENSE_KLEENE}
+    # a chain of plain stream nodes has no count node's part and no
+    # logical node's
+    dense -= rounds | {trace_mod.SCOPE_DENSE_KLEENE,
+                       trace_mod.SCOPE_DENSE_LOGICAL}
     # the jitted programs take the one packed buffer of their lane table
     assert scopes_in(eng.make_step(sk).lower(
         eng.init_state(), eng._pad_lanes(
@@ -789,6 +799,42 @@ def test_a_count_nodes_part_of_advance_has_a_scope_of_its_own():
     # and the cumulative sum that finds the head's free lane is under it
     assert re.search(r'cumsum[^\n]*' + re.escape(nested) + r'|'
                      + re.escape(nested) + r'[^\n]*cumsum', text)
+
+
+def test_a_logical_nodes_part_of_advance_has_a_scope_of_its_own():
+    """``siddhi.dense.logical`` nests in ``siddhi.dense.advance`` on
+    the programs of BOTH input streams of a two-stream ``and``, and
+    names the side's capture, its side bit and the completion; the
+    expiry and the filters stay ``advance``'s."""
+    from siddhi_tpu.ops.dense_nfa import compile_pattern
+
+    eng = compile_pattern(
+        "define stream StockTick (symbol long, price float, volume int); "
+        "define stream NewsEvent (symbol long, sentiment float, source int);"
+        " from every (t=StockTick[price > 0.0] and "
+        "n=NewsEvent[sentiment > 0.0]) within 5 sec select t.price as "
+        "price, n.sentiment as sentiment insert into Alerts;",
+        n_partitions=64)
+    assert eng.stream_keys == ["StockTick", "NewsEvent"]
+    n = 16
+    idx = np.arange(n, dtype=np.int32)
+    nested = (trace_mod.SCOPE_DENSE_ADVANCE + "/"
+              + trace_mod.SCOPE_DENSE_LOGICAL)
+    assert trace_mod.SCOPE_DENSE_LOGICAL in trace_mod.DEVICE_SCOPES
+    for sk, value in (("StockTick", "price"), ("NewsEvent", "sentiment")):
+        cols = eng.prepare_cols(sk, {"symbol": idx.astype(np.int64),
+                                     value: np.linspace(0.5, 2.0, n)})
+        text = eng.make_step(sk).lower(
+            eng.init_state(), eng._pad_lanes(
+                eng.lane_table(sk), idx, cols, idx, idx)).as_text(
+                    debug_info=True)
+        assert nested in text
+        # the scope is never opened outside advance, and no count node's
+        assert text.count(trace_mod.SCOPE_DENSE_LOGICAL) == text.count(nested)
+        assert trace_mod.SCOPE_DENSE_KLEENE not in text
+        # the side bit's ``or`` into counts is under it
+        assert re.search(r'\bor\b[^\n]*' + re.escape(nested) + r'|'
+                         + re.escape(nested) + r'[^\n]*\bor\b', text)
 
 
 # -- prometheus exposition ----------------------------------------------------

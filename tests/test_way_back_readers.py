@@ -1,7 +1,8 @@
 """The benchmark's readers of a batch's way back, on hand-made records.
 
-``benchmark/layers/way_back.py`` on hand-made rings and
-``benchmark/layers/round_trip.py`` on a hand-made ``Trace``
+``benchmark/layers/way_back.py`` and ``benchmark/layers/streams.py`` on
+hand-made rings and ``benchmark/layers/round_trip.py`` on a hand-made
+``Trace``
 (``benchmark/lib/xplane.py``): known intervals in, known per-batch
 values out; a busy plane gives 0; a program without the spans yields
 nothing.  And once through ``benchmark/run.py`` itself, as a rehearsal
@@ -25,6 +26,7 @@ _added = [p for p in (BENCH, os.path.join(BENCH, "layers"))
 sys.path[:0] = _added   # the readers import program_spans and lib.xplane
 try:
     import round_trip   # noqa: E402  (benchmark/layers/round_trip.py)
+    import streams      # noqa: E402  (benchmark/layers/streams.py)
     import way_back     # noqa: E402  (benchmark/layers/way_back.py)
     from lib import xplane  # noqa: E402  (benchmark/lib/xplane.py)
 finally:
@@ -143,6 +145,56 @@ def test_an_evicting_ring_reads_only_whole_cycles():
     got = way_back.read(make_run(ring))
     assert got["events.match_delay_ms_p50"] == pytest.approx(
         (12.5 + 22.5) / 2)
+
+
+# -- the second stream's batches (layers/streams.py) ---------------------------
+
+STREAM2 = ["events." + streams.ROWS, "events." + streams.EMIT_MS,
+           "events.rows_per_batch"]
+
+
+def stream_of(cid, t, place):
+    """The dense engine's ``stream`` count of a cycle: a tuple of no
+    width inside its ``ingest``."""
+    return span(cid, "stream", t + 3.0, t + 3.0, place)
+
+
+def test_the_second_streams_cycles_are_read_alone():
+    # four clean batches: a tick batch that delivered 7 rows, a news
+    # batch that delivered 7 (1 ms of fetch, 1.5 of build, 0.5 of
+    # delivery), a news batch that owed nothing, a tick batch; the
+    # profiler's batch is a news batch too, and is not read
+    ring = (cycle(1, -100) + [stream_of(1, -100, 1)]
+            + cycle(2, 0) + [stream_of(2, 0, 0)]
+            + cycle(3, 100) + [stream_of(3, 100, 1)]
+            + cycle(4, 200, back=False) + [stream_of(4, 200, 1)]
+            + cycle(5, 300) + [stream_of(5, 300, 0)]
+            + cycle(6, 400) + [stream_of(6, 400, 1)])
+    got = streams.read(make_run(ring, n_sends=5, clean=4, wanted=STREAM2))
+    assert got == {
+        "events.stream2_rows_per_batch": pytest.approx(7 / 2),
+        "events.stream2_emit_ms_per_batch": pytest.approx(3.0 / 2)}
+    # a reader asked for neither reads nothing
+    assert streams.read(make_run(ring, wanted=NAMES)) == {}
+
+
+def test_a_window_of_one_stream_and_an_older_ring_yield_nothing():
+    # every batch on the first stream: no cycle to average over
+    first = [s for n in range(4) for s in cycle(2 + n, 100 * n)
+             + [stream_of(2 + n, 100 * n, 0)]]
+    assert streams.read(make_run(first, wanted=STREAM2)) == {}
+    # a program from before the count (an older commit): nothing, never 0
+    old = [s for n in range(4) for s in cycle(2 + n, 100 * n)]
+    assert streams.read(make_run(old, wanted=STREAM2)) == {}
+    assert streams.read(make_run([], wanted=STREAM2)) == {}
+
+
+def test_second_stream_cycles_that_owe_nothing_read_zero():
+    ring = [s for n in range(4) for s in cycle(2 + n, 100 * n, back=False)
+            + [stream_of(2 + n, 100 * n, n % 2)]]
+    assert streams.read(make_run(ring, wanted=STREAM2)) == {
+        "events.stream2_rows_per_batch": 0.0,
+        "events.stream2_emit_ms_per_batch": 0.0}
 
 
 # -- the shared clock ----------------------------------------------------------
