@@ -59,7 +59,7 @@ log = logging.getLogger("siddhi_tpu")
 
 def build_dense_engine(query, st: StateInputStream, resolve_def,
                        n_partitions: int, n_instances: int = 4,
-                       select_override=None, builder=None):
+                       select_override=None, builder=None, mesh=None):
     """Lower one pattern/sequence query to a DensePatternEngine or raise
     SiddhiAppCreationError with the reason it is not dense-eligible.
 
@@ -68,7 +68,9 @@ def build_dense_engine(query, st: StateInputStream, resolve_def,
     CALLER owns selection semantics (the aggregating-selector form runs
     the host QuerySelector over dense match rows).  ``builder`` reuses a
     caller's NFABuilder (one lowering serves both the selector scope and
-    the engine)."""
+    the engine).  ``mesh``: the mesh the runtime will shard the state
+    over, so that the engine is made, and checked below, with the rows
+    a sharded state has (``ops/dense_layout.py`` ``row_shape``)."""
     from siddhi_tpu.ops.dense_nfa import DensePatternEngine
     from siddhi_tpu.ops.nfa import NFABuilder
 
@@ -123,6 +125,7 @@ def build_dense_engine(query, st: StateInputStream, resolve_def,
         reset_on_emit=not every_start,
         is_sequence=st.type == StateInputStream.SEQUENCE,
         n_instances=n_instances,
+        mesh=mesh,
     )
 
     # INT/LONG captures, filters (plain comparisons) and selects ride
@@ -636,6 +639,11 @@ class DensePatternRuntime:
                 else self._index.kind),
             "intern_probe_lanes": self._intern_probe_lanes,
             "intern_new_keys": self._intern_new_keys,
+            # the write-back of a batch's rows: the row-scatter kernel
+            # or XLA's scatter (None before a step is traced), and the
+            # shape a partition's row is resident in
+            "scatter_path": self.engine.layout.scatter_path,
+            "state_row_shape": self.engine.layout.row_shape,
         }
 
     @waits_on_device

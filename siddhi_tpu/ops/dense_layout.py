@@ -7,14 +7,25 @@ optionally ``iregs`` [S, I, 2*RI] int32 and ``deadline`` [S, I] int32,
 plus one ``overflow`` int32 counter.
 
 **Physical** state (what lives in HBM and what the jitted steps take
-and donate): ``{"rows": int32[N, W], "overflow": int32[N]}``.  A
-partition is ONE contiguous row of W 32-bit words, the partition axis
+and donate): ``{"rows": int32[N, *row_shape], "overflow": int32[N]}``.
+A partition is ONE contiguous row of W 32-bit words, the partition axis
 major, W a multiple of 128 lanes: the fields above, flattened in the
 order listed and bit-cast to int32 (``active`` one 0/1 word per lane),
-then zero padding up to W.  A batch is then one gather of B rows and one
-scatter of B rows; the fields are split out of the gathered rows (a
-relayout of B rows, never of N), and the donated ``rows`` array is
-updated in place.  Trailing dims of 16 x 4 as separate ``[N, S, I]``
+then zero padding up to W.  ``row_shape`` follows the write-back: it is
+``(W // 128, 128)`` where the row-scatter kernel will write the rows (a
+row wider than one vector of lanes, the state on one chip) and ``(W,)``
+wherever XLA's scatter will (a row of one vector; a state sharded over
+a mesh, whose step runs under ``shard_map``, where the kernel has never
+run).  A TPU tiles the two minor dimensions of an array: ``[N, 256]``
+in tiles of 8 rows by 128 words, which puts the two halves of a row
+4 KB apart, and ``[N, 2, 128]`` in tiles of 2 by 128, which is the row
+itself, 1,024 bytes on end, and what one DMA can name
+(``kernels/row_scatter.py``); XLA's own scatter is slower on that shape
+than on ``[N, 256]`` (8.4 against 4.7 ms for 131,072 rows, PERF.md
+section 6, PR 58), so a state the kernel will not write keeps the flat
+one.  A batch is then one gather of B rows and one scatter of B rows;
+the fields are split out of the gathered rows (a relayout of B rows,
+never of N), and the donated ``rows`` array is updated in place.  Trailing dims of 16 x 4 as separate ``[N, S, I]``
 arrays made XLA put the partition axis on the lanes and transpose the
 whole state twice a step (PERF.md, PR 27).
 
@@ -85,7 +96,8 @@ class DenseStateLayout:
     write single fields of a few rows."""
 
     def __init__(self, S: int, I: int, n_regs: int, n_iregs: int,
-                 has_deadlines: bool, armed_start: bool):
+                 has_deadlines: bool, armed_start: bool,
+                 sharded: bool = False):
         self.S, self.I = S, I
         # name -> (logical dtype, trailing logical shape)
         self.fields: Dict[str, Tuple[np.dtype, Tuple[int, ...]]] = {
@@ -110,6 +122,14 @@ class DenseStateLayout:
             off += w
         self.used = off
         self.width = -(-off // LANES) * LANES
+        # what a partition's W words look like in the resident array:
+        # a vector of lanes at a time where the row-scatter kernel will
+        # write them (``scatter``), flat where XLA's scatter will
+        self.row_shape = ((self.width,) if self.width == LANES or sharded
+                          else (self.width // LANES, LANES))
+        # what writes a batch's rows back, "kernel" or "xla": said by
+        # ``scatter`` when a program is traced, None until then
+        self.scatter_path = None
         # non-every: node 0 armed once per partition (lane 0); after a
         # match reset_on_emit clears it and the automaton is done
         self.armed_start = armed_start
@@ -125,14 +145,15 @@ class DenseStateLayout:
     def physical_shapes(self, n_rows: int) -> Dict[str, Tuple[int, ...]]:
         """Shapes of the physical arrays (both int32), for abstract
         tracing without allocating a state."""
-        return {ROWS: (n_rows, self.width), OVERFLOW: (n_rows,)}
+        return {ROWS: (n_rows,) + self.row_shape, OVERFLOW: (n_rows,)}
 
     def pspecs(self, axis: str):
         """Partition-axis sharding spec per physical array (row-sharded,
         the words of a row stay together)."""
         from jax.sharding import PartitionSpec as Pspec
 
-        return {ROWS: Pspec(axis, None), OVERFLOW: Pspec(axis)}
+        return {ROWS: Pspec(axis, *(None,) * len(self.row_shape)),
+                OVERFLOW: Pspec(axis)}
 
     # -- host side (numpy) ----------------------------------------------------
 
@@ -149,7 +170,57 @@ class DenseStateLayout:
         rows = np.zeros((n_rows, self.width), dtype=np.int32)
         if self.armed_start:
             rows[:, self.offsets["active"][0]] = 1
-        return {ROWS: rows, OVERFLOW: np.zeros(n_rows, dtype=np.int32)}
+        return {ROWS: self._physical(rows),
+                OVERFLOW: np.zeros(n_rows, dtype=np.int32)}
+
+    def init_device(self, n_rows: int):
+        """``init_physical`` made on the device: one row broadcast by a
+        program, where the host's gigabyte would cross in seconds."""
+        import jax
+        import jax.numpy as jnp
+
+        row = self.init_physical(1)[ROWS][0]
+        return {ROWS: jax.jit(lambda r: jnp.broadcast_to(
+                    r, (n_rows,) + r.shape))(row),
+                OVERFLOW: jnp.zeros(n_rows, dtype=jnp.int32)}
+
+    def _physical(self, flat):
+        """Rows ``[..., W]`` (host or device) in the resident shape."""
+        if len(self.row_shape) == 1:
+            return flat
+        return flat.reshape(flat.shape[:-1] + self.row_shape)
+
+    def _flat(self, rows):
+        """A few resident rows ``[..., *row_shape]`` as ``[..., W]``."""
+        if len(self.row_shape) == 1:
+            return rows
+        return rows.reshape(rows.shape[:-2] + (self.width,))
+
+    def _words(self, rows, off: int, w: int, at=slice(None)):
+        """Words ``off : off + w`` of resident device rows
+        ``[n, *row_shape]``, of every row as ``[n, w]`` or of the rows
+        ``at`` (an index or an array of them), by slices alone: the
+        rows, which may be the whole state, are never reshaped and no
+        row is gathered whole."""
+        import jax.numpy as jnp
+
+        if rows.ndim == 2:
+            return rows[at, off:off + w]
+        pieces = [rows[at, c, lo:hi] for c, lo, hi in self._pieces(off, w)]
+        return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces,
+                                                                  axis=-1)
+
+    @staticmethod
+    def _pieces(off: int, w: int):
+        """Words ``off : off + w`` of a row as ``(vector, from, to)``
+        within each vector of 128 lanes they touch."""
+        out = []
+        while w > 0:
+            c, lo = divmod(off, LANES)
+            take = min(w, LANES - lo)
+            out.append((c, lo, lo + take))
+            off, w = off + take, w - take
+        return out
 
     def encode(self, name: str, value) -> np.ndarray:
         """Logical host array [..., *shape] -> int32 words [..., w]."""
@@ -187,12 +258,12 @@ class DenseStateLayout:
                     f"{None if got is None else tuple(np.shape(got))}, "
                     f"this engine needs {want[name]}")
             rows[:, off:off + w] = self.encode(name, got)
-        return {ROWS: rows,
+        return {ROWS: self._physical(rows),
                 OVERFLOW: np.asarray(logical[OVERFLOW], dtype=np.int32)}
 
     def unpack(self, physical) -> Dict[str, np.ndarray]:
         """Physical state (host or device arrays) -> logical host state."""
-        rows = np.asarray(physical[ROWS])
+        rows = self._flat(np.asarray(physical[ROWS]))
         out = {name: self.decode(name, rows[:, off:off + w])
                for name, (off, w) in self.offsets.items()}
         out[OVERFLOW] = np.array(physical[OVERFLOW], dtype=np.int32)
@@ -228,10 +299,8 @@ class DenseStateLayout:
         physical row indices, or of every row.  ``decode`` turns the
         fetched words into the logical view."""
         off, w = self.offsets[name]
-        r = state[ROWS]
-        if rows is None:
-            return r[:, off:off + w]
-        return r[rows, off:off + w]
+        return self._words(state[ROWS], off, w,
+                           slice(None) if rows is None else rows)
 
     def field(self, state, name: str, rows=None) -> np.ndarray:
         """Logical host view ``[..., *shape]`` of one field (fetches the
@@ -244,24 +313,30 @@ class DenseStateLayout:
         import jax.numpy as jnp
 
         off, w = self.offsets[name]
+        r = state[ROWS]
+        words = jnp.asarray(self.encode(name, value))
         new = dict(state)
-        new[ROWS] = state[ROWS].at[rows, off:off + w].set(
-            jnp.asarray(self.encode(name, value)))
+        if r.ndim == 2:
+            new[ROWS] = r.at[rows, off:off + w].set(words)
+        else:
+            # the rows laid flat, the field set, the rows written back
+            flat = self._flat(r[rows]).at[..., off:off + w].set(words)
+            new[ROWS] = r.at[rows].set(self._physical(flat))
         return new
 
     # -- inside a jitted step (traced) ----------------------------------------
 
     def split(self, rows, shaped: bool = True) -> Dict[str, object]:
-        """Rows [N, W] -> logical fields, ``[N, *shape]`` each, or flat
-        ``[N, w]`` with ``shaped=False`` (the timer step, which runs over
-        every row and must not reshape the partition axis)."""
+        """Rows [N, *row_shape] -> logical fields, ``[N, *shape]`` each,
+        or flat ``[N, w]`` with ``shaped=False`` (the timer step, which
+        runs over every row and must not reshape the partition axis)."""
         import jax
         import jax.numpy as jnp
 
         out = {}
         for name, (off, w) in self.offsets.items():
             dt, shape = self.fields[name]
-            x = rows[:, off:off + w]
+            x = self._words(rows, off, w)
             if dt == np.float32:
                 x = jax.lax.bitcast_convert_type(x, jnp.float32)
             elif dt == np.bool_:
@@ -270,7 +345,7 @@ class DenseStateLayout:
         return out
 
     def join(self, fields: Dict[str, object]):
-        """Logical fields (shaped or flat) -> rows [N, W]."""
+        """Logical fields (shaped or flat) -> rows [N, *row_shape]."""
         import jax
         import jax.numpy as jnp
 
@@ -288,27 +363,62 @@ class DenseStateLayout:
             parts.append(x)
         if self.width > self.used:
             parts.append(jnp.zeros((n, self.width - self.used), jnp.int32))
-        return jnp.concatenate(parts, axis=1)
+        if len(self.row_shape) == 1:
+            return jnp.concatenate(parts, axis=1)
+        # a vector of 128 lanes at a time, from the pieces of the fields
+        # that lie in it: the rows are written in their resident shape
+        # and never relaid from [N, W]
+        vectors = [[] for _ in range(self.row_shape[0])]
+        off = 0
+        for x in parts:
+            at = 0
+            for c, lo, hi in self._pieces(off, x.shape[1]):
+                vectors[c].append(x[:, at:at + hi - lo])
+                at += hi - lo
+            off += x.shape[1]
+        return jnp.stack([v[0] if len(v) == 1 else jnp.concatenate(v, axis=1)
+                          for v in vectors], axis=1)
 
     def gather(self, state, part_idx):
-        """The batch's rows: ``(fields [B, *shape], old rows [B, W])``.
-        ``old`` feeds ``scatter`` so padded batch rows write back exactly
-        what they read.  ``overflow`` is not gathered: a step only ever
-        adds to it."""
+        """The batch's rows: ``(fields [B, *shape], old rows
+        [B, *row_shape])``.  The gathered rows are laid flat before they
+        are split: one relayout of B rows, and the split reads ``[B, W]``
+        whatever the resident shape is.  ``old`` feeds ``scatter`` so
+        padded batch rows write back exactly what they read.
+        ``overflow`` is not gathered: a step only ever adds to it."""
         old = state[ROWS][part_idx]
-        return self.split(old), old
+        return self.split(self._flat(old)), old
 
     def scatter(self, state, part_idx, fields, ovf_delta, valid, old):
         """Write the batch's rows back in place and add each row's newly
         dropped instances to its ``overflow`` counter.  Invalid (padded)
         batch rows all point at the scratch row: they write back the
-        words they gathered and add 0, so the scratch row and every row
-        outside the batch keep their values."""
+        words they gathered (``old``), or nothing at all where the
+        row-scatter kernel takes the call, and add 0, so the scratch row
+        and every row outside the batch keep their values."""
         import jax.numpy as jnp
 
-        new = jnp.where(valid[:, None], self.join(fields), old)
+        from siddhi_tpu.kernels import row_scatter
+
+        rows = state[ROWS]
+        if row_scatter.eligible(rows, part_idx.shape[0]):
+            # the kernel starts no copy for a lane on the scratch row:
+            # an invalid lane is sent there (the step's and the rounds'
+            # lanes are invalid exactly where they name it already) and
+            # nothing reads ``old``
+            scratch = rows.shape[0] - 1
+            self.scatter_path = "kernel"
+            rows = row_scatter.row_scatter(
+                rows, jnp.where(valid, part_idx, scratch), self.join(fields))
+        else:
+            # (a width the kernel's loop does not divide, beside wider
+            # ones that took it, leaves "kernel" standing)
+            self.scatter_path = self.scatter_path or "xla"
+            keep = valid[(slice(None),) + (None,) * len(self.row_shape)]
+            rows = rows.at[part_idx].set(
+                jnp.where(keep, self.join(fields), old))
         return {
-            ROWS: state[ROWS].at[part_idx].set(new),
+            ROWS: rows,
             OVERFLOW: state[OVERFLOW].at[part_idx].add(
                 jnp.where(valid, ovf_delta, 0)),
         }

@@ -18,7 +18,9 @@ jit-compiled step over **micro-batches of events across partitions**:
   fields out of the gathered rows, unrolls the node chain in reverse
   (so an event advances at most one node, the staged-update semantics
   of the host engine), evaluates all node filters vectorized, and
-  scatters the rows back in place on the donated state;
+  scatters the rows back in place on the donated state (rows wider
+  than 128 words on one chip: a DMA a row, ``kernels/row_scatter.py``;
+  the resident shape of a row follows, ``layout.row_shape``);
 - cost is O(batch × states × regs) independent of the partition count —
   1M+ partitions are just HBM rows, and no operation of the step reads
   or writes more than the batch's rows;
@@ -521,10 +523,10 @@ class DensePatternEngine:
                         writes.append(slot)
             self.node_writes.append(writes)
         # where each field of a partition's state lives in its row: a
-        # function of S, I and the register banks alone
-        self.layout = DenseStateLayout(
-            self.S, self.I, self.alloc.n, self.alloc.n_int,
-            self.has_deadlines, armed_start=not self.every_start)
+        # function of S, I and the register banks alone; the shape a row
+        # is resident in follows from its width and from whether the
+        # state is sharded over a mesh
+        self.layout = self._make_layout(sharded=mesh is not None)
         # emit lanes of a step: bank [0, I) for completions at the last
         # node and, only where the chain has one, bank [I, 2I) for the
         # via-path's clones (a dually-pending open count before a plain
@@ -602,6 +604,22 @@ class DensePatternEngine:
 
     # -- state --------------------------------------------------------------
 
+    def _make_layout(self, sharded: bool) -> DenseStateLayout:
+        return DenseStateLayout(
+            self.S, self.I, self.alloc.n, self.alloc.n_int,
+            self.has_deadlines, armed_start=not self.every_start,
+            sharded=sharded)
+
+    def shard_rows(self) -> None:
+        """The state will live sharded by rows, its step under
+        ``shard_map`` (``parallel/mesh.py`` wraps an engine that was
+        built without its mesh): the rows take the shape a sharded state
+        has, as with ``mesh`` given at construction, and programs traced
+        for the other shape are dropped."""
+        if len(self.layout.row_shape) > 1:
+            self.layout = self._make_layout(sharded=True)
+            self._step_cache.clear()
+
     def init_state_host(self) -> Dict[str, np.ndarray]:
         """Zero state in its PHYSICAL form (ops/dense_layout.py: one
         int32 row of ``layout.width`` words per partition, plus the
@@ -619,17 +637,19 @@ class DensePatternEngine:
         return self.layout.pspecs(self.partition_axis)
 
     def init_state(self):
-        jnp = self.jnp
-        state = {k: jnp.asarray(v) for k, v in self.init_state_host().items()}
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding
+        if self.mesh is None:
+            # made on the device: the host's copy of a million rows
+            # would cross in seconds
+            return self.layout.init_device(self.n_partitions + 1)
+        from jax.sharding import NamedSharding
 
-            specs = self.state_pspecs()
-            state = {
-                k: self.jax.device_put(v, NamedSharding(self.mesh, specs[k]))
-                for k, v in state.items()
-            }
-        return state
+        jnp = self.jnp
+        specs = self.state_pspecs()
+        return {
+            k: self.jax.device_put(jnp.asarray(v),
+                                   NamedSharding(self.mesh, specs[k]))
+            for k, v in self.init_state_host().items()
+        }
 
     # -- step ---------------------------------------------------------------
 
@@ -1768,7 +1788,8 @@ class DensePatternEngine:
         nodes return None without touching the device."""
         if not self.has_deadlines or self.base_ts is None:
             return None
-        if not hasattr(self, "_wakeup_fn"):
+        fn = self._step_cache.get("wakeup")
+        if fn is None:
             jnp = self.jnp
             layout = self.layout
 
@@ -1778,8 +1799,8 @@ class DensePatternEngine:
                 return jnp.min(jnp.where(f["active"] & (dl > 0), dl,
                                          jnp.int32(2**31 - 1)))
 
-            self._wakeup_fn = self.jax.jit(earliest)
-        m = int(self._wakeup_fn(state[ROWS]))
+            fn = self._step_cache["wakeup"] = self.jax.jit(earliest)
+        m = int(fn(state[ROWS]))
         if m >= 2**31 - 1:
             return None
         return self.base_ts + m

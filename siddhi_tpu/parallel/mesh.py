@@ -237,6 +237,9 @@ class ShardedPatternEngine:
 
         self.stream_key = stream_key or engine.default_stream
         self.col_keys = engine.device_col_keys(self.stream_key)
+        # a sharded state's rows stay flat, ``[N, W]``, under XLA's
+        # scatter (``ops/dense_layout.py`` ``row_shape``)
+        engine.shard_rows()
         step = engine.make_step(self.stream_key, jit=False)
         jnp = engine.jnp
         a = axis_name

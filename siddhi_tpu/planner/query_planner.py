@@ -639,6 +639,16 @@ class QueryPlanner:
                 "dense path: partitioned queries with output rate limits "
                 "need per-key limiters — host instances used")
 
+        # @app:execution('tpu', devices='N'): shard the partition axis
+        # over an N-device mesh (BASELINE config 5's scale-out form);
+        # pointless for single-partition queries.  Known before the
+        # engine is built: a sharded state's rows have a shape of
+        # their own (ops/dense_layout.py)
+        mesh = None
+        nd = self.app.app_context.tpu_devices
+        if nd and n_partitions > 1 and self._want("shard", name):
+            mesh = self.app.tpu_mesh
+
         sel = query.selector
         aggregating = bool(sel.group_by) or sel.having is not None \
             or self._has_aggregators(sel)
@@ -680,7 +690,7 @@ class QueryPlanner:
                 query, st, self.app.resolve_stream_definition, n_partitions,
                 n_instances=self.app.app_context.tpu_instances,
                 select_override=(select_vars, select_names),
-                builder=builder)
+                builder=builder, mesh=mesh)
             if partitioned:
                 # ONE shared selector keeps per-(key, group) state via
                 # the partition-key side channel on match rows (timer
@@ -690,7 +700,7 @@ class QueryPlanner:
         else:
             engine = build_dense_engine(
                 query, st, self.app.resolve_stream_definition, n_partitions,
-                n_instances=self.app.app_context.tpu_instances)
+                n_instances=self.app.app_context.tpu_instances, mesh=mesh)
 
             out_target = getattr(query.output_stream, "target", None) or f"__ret_{name}"
             out_names = engine.output_names
@@ -703,13 +713,6 @@ class QueryPlanner:
         rate_limiter = self._plan_rate_limiter(query)
         qr = QueryRuntime(name, [[]], selector, rate_limiter, output, self.app.app_context)
 
-        # @app:execution('tpu', devices='N'): shard the partition axis
-        # over an N-device mesh (BASELINE config 5's scale-out form);
-        # pointless for single-partition queries
-        mesh = None
-        nd = self.app.app_context.tpu_devices
-        if nd and n_partitions > 1 and self._want("shard", name):
-            mesh = self.app.tpu_mesh
         runtime = DensePatternRuntime(
             engine, f"#matches_{name}", emit=lambda b: qr.process(b, 0),
             key_fn=key_fn, mesh=mesh, app_context=self.app.app_context)

@@ -160,7 +160,7 @@ def test_pack_unpack_round_trip(eng_name):
     phys = lay.pack(logical)
     assert set(phys) == {"rows", "overflow"}
     assert phys["rows"].dtype == np.int32
-    assert phys["rows"].shape == (8, lay.width) and lay.width % 128 == 0
+    assert phys["rows"].shape == (8,) + lay.row_shape and lay.width % 128 == 0
     assert lay.width - lay.used < 128
     back = lay.unpack(phys)
     for k, v in logical.items():
@@ -177,6 +177,74 @@ def test_pack_unpack_round_trip(eng_name):
     state = lay.with_field(state, "regs", 3, logical["regs"][6])
     _same(lay.field(state, "regs", 3), logical["regs"][6], "regs")
     _same(lay.field(state, "regs", 4), logical["regs"][4], "regs kept")
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one_chip", "sharded"])
+@pytest.mark.parametrize("dims", [(16, 4, 1, 0, False, False),
+                                  (5, 4, 3, 1, True, False),
+                                  (7, 4, 4, 2, True, True)])
+def test_a_row_wider_than_one_vector(dims, sharded):
+    """A row of more than 128 words is resident as ``[W // 128, 128]``
+    on one chip (what one DMA can name, ``kernels/row_scatter.py``) and
+    flat, ``[W]``, where the state is sharded over a mesh (XLA's
+    scatter writes it): the flagship's 256 words with every field inside
+    a vector, and two layouts whose fields cross from one vector into
+    the next.  In either shape pack and unpack, the accessors of single
+    fields, ``split`` / ``join`` inside a jitted program and ``logical``
+    give what the words of a flat row give."""
+    import jax
+    import jax.numpy as jnp
+
+    from siddhi_tpu.ops.dense_layout import OVERFLOW, ROWS, DenseStateLayout
+
+    lay = DenseStateLayout(*dims, sharded=sharded)
+    assert lay.width > 128 and lay.row_shape == (
+        (lay.width,) if sharded else (lay.width // 128, 128))
+    assert lay.pspecs("p")[ROWS] == jax.sharding.PartitionSpec(
+        "p", *(None,) * len(lay.row_shape))
+    crossing = [k for k, (off, w) in lay.offsets.items()
+                if off // 128 != (off + w - 1) // 128]
+    assert bool(crossing) == (dims[0] != 16)
+    rng = np.random.default_rng(dims[0])
+    logical = {}
+    for k, shape in lay.logical_shapes(9).items():
+        dt = lay.fields[k][0] if k in lay.fields else np.dtype(np.int32)
+        raw = rng.integers(-2 ** 31, 2 ** 31, size=shape, dtype=np.int64)
+        logical[k] = ((raw & 1).astype(bool) if dt == np.bool_ else
+                      raw.astype(np.int32).view(dt) if dt == np.float32
+                      else raw.astype(np.int32))
+    phys = lay.pack(logical)
+    assert phys[ROWS].shape == (9,) + lay.row_shape
+    assert lay.physical_shapes(9)[ROWS] == phys[ROWS].shape
+    assert lay.init_physical(9)[ROWS].shape == phys[ROWS].shape
+    for k, v in lay.init_device(9).items():      # the same, made there
+        assert np.array_equal(np.asarray(v), lay.init_physical(9)[k]), k
+    flat = phys[ROWS].reshape(9, lay.width)
+    for k, (off, w) in lay.offsets.items():
+        _same(lay.decode(k, flat[:, off:off + w]), logical[k], k)
+    for k, v in lay.unpack(phys).items():
+        _same(v, logical[k], k)
+    state = {k: jnp.asarray(v) for k, v in phys.items()}
+    for k in lay.fields:
+        _same(lay.field(state, k), logical[k], k)                # every row
+        _same(lay.field(state, k, np.asarray([5, 2])), logical[k][[5, 2]], k)
+        _same(lay.field(state, k, 7), logical[k][7], k)          # one row
+        moved = lay.with_field(state, k, 3, logical[k][6])
+        _same(lay.field(moved, k, 3), logical[k][6], k)
+        want = flat.copy()
+        off, w = lay.offsets[k]
+        want[3, off:off + w] = flat[6, off:off + w]
+        assert np.array_equal(
+            np.asarray(moved[ROWS]).reshape(9, lay.width), want), k
+    # inside a program: the fields of the rows, and the rows of the fields
+    again = jax.jit(lambda r: lay.join(lay.split(r)))(state[ROWS])
+    assert again.shape == phys[ROWS].shape
+    assert np.array_equal(np.asarray(again), phys[ROWS])
+    snap = jax.jit(lay.logical)(state)
+    for k, field in lay.snapshot_fields(snap).items():
+        _same(np.asarray(field), logical[k], k)
+    _same(np.asarray(snap[OVERFLOW]), logical[OVERFLOW], OVERFLOW)
 
 
 # -- (iii) -------------------------------------------------------------------

@@ -27,15 +27,20 @@ def test_run_of_1000_through_the_run_kernel(run_kernel):
 # -- the run kernel through the chip's own compiler, with no chip --------------
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no libtpu here: nothing to compile with
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -74,6 +79,72 @@ def test_the_run_kernel_goes_through_mosaic(one_chip, monkeypatch):
         fields, {"v": tiles(np.float32)}, tiles(np.int32), links, links,
         shape((), np.int32)).lower(lowering_platforms=("tpu",)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("row_shape", [(2, 128), (128,)])
+def test_the_row_scatter_kernel_goes_through_mosaic(one_chip, monkeypatch,
+                                                    row_shape):
+    """The write-back of a 131,072-event batch into a million rows, at
+    both shapes a resident row takes: one DMA a row (Mosaic takes a
+    single row only where it lies on end: ``[N, 256]`` it refuses), the
+    index scalar-prefetched, and the state aliased: no second one."""
+    import jax
+    import numpy as np
+
+    from siddhi_tpu.kernels import probe, row_scatter
+
+    monkeypatch.setattr(probe, "interpret_mode", lambda: False)
+    def shape(s):
+        return jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
+
+    n, b = 1_000_001, 131_072
+    # (a row of one vector compiles too; the engine keeps XLA's there)
+    assert row_scatter.eligible(shape((n,) + row_shape), b) == (
+        len(row_shape) == 2)
+    compiled = jax.jit(row_scatter.row_scatter, donate_argnums=(0,)).trace(
+        shape((n,) + row_shape), shape((b,)), shape((b,) + row_shape)
+    ).lower(lowering_platforms=("tpu",)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    state = 4 * n * int(np.prod(row_shape))
+    assert mem.alias_size_in_bytes >= state and mem.temp_size_in_bytes < 2**20
+
+
+def test_the_sharded_step_keeps_xlas_scatter(topo, monkeypatch):
+    """Inside ``shard_map`` the step keeps XLA's scatter and the flat
+    ``[N, 256]`` rows XLA's scatter is fastest on (``DenseStateLayout
+    .row_shape``: the kernel was never run on four chips, and a shard
+    of ``[N, 2, 128]`` cost the four-chip cell 6%, PR 58): a shard's
+    rows as at the commit before the kernel, the state still donated."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from siddhi_tpu.kernels import probe
+    from siddhi_tpu.ops.dense_nfa import compile_pattern
+    from siddhi_tpu.parallel.mesh import ShardedPatternEngine
+    from test_dense_one_transfer import pattern_of
+
+    monkeypatch.setattr(probe, "interpret_mode", lambda: False)
+    mesh = Mesh(np.array(topo.devices), ("p",))
+    eng = compile_pattern(pattern_of("fraud16_1m"), "bench",
+                          n_partitions=65_536)
+    sharded = ShardedPatternEngine(eng, mesh, "p")
+    rows = sharded.n_shards * sharded.rows_per_shard
+    state = {k: jax.ShapeDtypeStruct(s, np.int32, sharding=NamedSharding(
+        mesh, sharded.state_specs[k]))
+        for k, s in eng.layout.physical_shapes(rows).items()}
+    buf = jax.ShapeDtypeStruct(
+        (2 + len(sharded.col_keys), sharded.n_shards * 4096), np.int32,
+        sharding=NamedSharding(mesh, PartitionSpec(None, "p")))
+    compiled = sharded._step.trace(state, buf).lower(
+        lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert eng.layout.row_shape == (256,)
+    assert "s32[16385,256]" in text and ",2,128]" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        4 * int(np.prod(s.shape)) for s in state.values()) // 4
 
 
 def test_the_device_tables_programs_fit_a_v5e_at_a_million_slots(one_chip):
