@@ -457,7 +457,8 @@ class SiddhiAppRuntime:
             # zeroMatchSkips / maxPendingDepth / autoEffectiveDepth /
             # earlyCopyBatches / earlyCopyHits / earlyCopyWastedBytes) and
             # ingest side (stagedBatches / devicePuts / putLeaves /
-            # deviceChunks / steppedLanes / plannedRepeats /
+            # deviceChunks / steppedLanes / steppedStateBytes /
+            # plannedRepeats /
             # batchesByStream.<stream> / fusedHops /
             # ingestStalls / overlappedBatches / flushSyncs /
             # maxStagingDepth)

@@ -12,6 +12,7 @@ This module holds the pieces every device runtime shares:
 - ``IngestStats``: per-runtime staging counters surfaced through
   ``util/statistics.py`` (``stagedBatches`` / ``devicePuts`` /
   ``putLeaves`` / ``deviceChunks`` / ``steppedLanes`` /
+  ``steppedStateBytes`` /
   ``plannedRepeats`` / ``batchesByStream.<stream>`` / ``fusedHops`` /
   ``ingestStalls`` / ``overlappedBatches`` / ``flushSyncs`` /
   ``maxStagingDepth``, and how often the window opened:
@@ -93,7 +94,8 @@ class IngestStats:
 
     __slots__ = ("staged_batches", "device_puts", "put_leaves",
                  "device_chunks",
-                 "stepped_lanes", "planned_repeats", "batches_by_stream",
+                 "stepped_lanes", "stepped_state_bytes", "planned_repeats",
+                 "batches_by_stream",
                  "fused_hops",
                  "ingest_stalls",
                  "overlapped_batches", "flush_syncs",
@@ -118,6 +120,10 @@ class IngestStats:
         # ``rounds_lanes``); over the events sent, the lanes an event
         # costs the device.  0 on every other engine
         self.stepped_lanes = 0
+        # bytes of resident rows those lanes gathered (and wrote back):
+        # a lane is one row of the layout's width, 4 bytes a word
+        # (ops/dense_layout.py).  0 on every other engine
+        self.stepped_state_bytes = 0
         # events past their partition's first in their batch: what the
         # dense engine's round plan sorts, and what goes through a
         # second call of the step or the rounds program
@@ -162,6 +168,7 @@ class IngestStats:
             "putLeaves": self.put_leaves,
             "deviceChunks": self.device_chunks,
             "steppedLanes": self.stepped_lanes,
+            "steppedStateBytes": self.stepped_state_bytes,
             "plannedRepeats": self.planned_repeats,
             "fusedHops": self.fused_hops,
             "ingestStalls": self.ingest_stalls,

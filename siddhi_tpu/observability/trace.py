@@ -47,12 +47,14 @@ how soon a batch's matches reach the callback (in the ring: the end of
 the cycle's ``admit``, or its first span's start where it took none, to
 its ``emit``'s end).
 
-Two counts are tuples in the ring and no intervals (:func:`counted`):
+Three counts are tuples in the ring and no intervals (:func:`counted`):
 ``lanes``, the lanes the dense engine's programs step for the batch,
-which it knows from the round plan and fetches nothing for, and
-``stream``, the place of the batch's input stream among the streams its
-pattern reads (0 for the first, and for every batch of a pattern over
-one stream).
+which it knows from the round plan and fetches nothing for;
+``state_bytes``, the bytes of resident rows those programs gather (and
+write back): a row of the layout's width a lane, whatever the row
+holds; and ``stream``, the place of the batch's input stream among the
+streams its pattern reads (0 for the first, and for every batch of a
+pattern over one stream).
 
 The code that does that work lives in engines that know no tracer
 (``ops/``, ``parallel/``, ``core/ingest_stage.py``, the runtime
@@ -153,7 +155,8 @@ CYCLE_STAGES = (STAGE_ADMIT, STAGE_INTERN, STAGE_INGEST, STAGE_CONVERT,
 #: ring (:func:`counted`), no annotation and no histogram
 STAGE_LANES = "lanes"        # lanes the batch's programs step (dense engine)
 STAGE_STREAM = "stream"      # the batch's stream, as its place in stream_keys
-CYCLE_COUNTS = (STAGE_LANES, STAGE_STREAM)
+STAGE_STATE_BYTES = "state_bytes"  # bytes of resident rows those lanes gather
+CYCLE_COUNTS = (STAGE_LANES, STAGE_STREAM, STAGE_STATE_BYTES)
 #: intervals of a cycle kept as histograms alone (module docstring)
 STAGE_STAGED = "staged"      # dispatch to the start of a deferred gate's fetch
 STAGE_CYCLE = "cycle"        # begin_cycle to the end of emit

@@ -101,6 +101,7 @@ from siddhi_tpu.observability.trace import (
     STAGE_DISPATCH,
     STAGE_LANES,
     STAGE_PLAN,
+    STAGE_STATE_BYTES,
     STAGE_STREAM,
     counted,
     span,
@@ -1980,7 +1981,9 @@ class DensePatternEngine:
         partition is.  The lanes those programs step, padding
         and all, are the cycle's ``lanes`` count and add to the
         runtime's ``steppedLanes``: known here from the plan's widths,
-        with nothing fetched.  The batch's stream, as its place in
+        with nothing fetched; a lane gathers one resident row, so the
+        lanes times the row's bytes are the cycle's ``state_bytes`` and
+        add to ``steppedStateBytes``.  The batch's stream, as its place in
         ``stream_keys``, is the cycle's ``stream`` count, and the
         runtime's ``batchesByStream.<stream>`` counts its batches."""
         faults = getattr(self, "faults", None)
@@ -2041,10 +2044,13 @@ class DensePatternEngine:
                 "anchor": emit_anchor, "sel": slice(0, hi - lo),
                 "ridx": ev, "count": n_emit,
             })
+        state_bytes = stepped * self.layout.width * 4
         counted(STAGE_LANES, stepped)
+        counted(STAGE_STATE_BYTES, state_bytes)
         counted(STAGE_STREAM, self.stream_keys.index(stream_key))
         if stats is not None:
             stats.stepped_lanes += stepped
+            stats.stepped_state_bytes += state_bytes
             stats.batches_by_stream[stream_key] = (
                 stats.batches_by_stream.get(stream_key, 0) + 1)
         return state, pending

@@ -183,14 +183,17 @@ def test_pack_unpack_round_trip(eng_name):
                          ids=["one_chip", "sharded"])
 @pytest.mark.parametrize("dims", [(16, 4, 1, 0, False, False),
                                   (5, 4, 3, 1, True, False),
-                                  (7, 4, 4, 2, True, True)])
+                                  (7, 4, 4, 2, True, True),
+                                  (32, 4, 1, 0, False, False)])
 def test_a_row_wider_than_one_vector(dims, sharded):
     """A row of more than 128 words is resident as ``[W // 128, 128]``
     on one chip (what one DMA can name, ``kernels/row_scatter.py``) and
     flat, ``[W]``, where the state is sharded over a mesh (XLA's
     scatter writes it): the flagship's 256 words with every field inside
-    a vector, and two layouts whose fields cross from one vector into
-    the next.  In either shape pack and unpack, the accessors of single
+    a vector, two layouts whose fields cross from one vector into the
+    next, and the 512 words of a chain at the engine's limit of 32
+    nodes (``iot32_1250k``), a field a vector and not one word of
+    padding.  In either shape pack and unpack, the accessors of single
     fields, ``split`` / ``join`` inside a jitted program and ``logical``
     give what the words of a flat row give."""
     import jax
@@ -205,7 +208,10 @@ def test_a_row_wider_than_one_vector(dims, sharded):
         "p", *(None,) * len(lay.row_shape))
     crossing = [k for k, (off, w) in lay.offsets.items()
                 if off // 128 != (off + w - 1) // 128]
-    assert bool(crossing) == (dims[0] != 16)
+    assert bool(crossing) == (dims[0] not in (16, 32))
+    if dims[0] == 32:
+        assert lay.used == lay.width == 512
+        assert lay.row_shape == ((512,) if sharded else (4, 128))
     rng = np.random.default_rng(dims[0])
     logical = {}
     for k, shape in lay.logical_shapes(9).items():

@@ -1,6 +1,7 @@
 """The row-scatter kernel (``kernels/row_scatter.py``), interpreted, held
 bit for bit to the ``rows.at[part_idx].set(new)`` it stands in for:
-alone over both row shapes the resident state takes, with no padded
+alone over the row shapes the resident state takes (one vector of
+lanes, the flagship's two and ``iot32_1250k``'s four), with no padded
 lane, some, and all of them; and inside the engines' programs (the
 step, ``make_rounds``' wide loops and the run's write-back) on the
 flagship's and the card app's patterns.  Off a TPU the engine keeps
@@ -26,7 +27,7 @@ N = 5_001           # rows, the last one the scratch row
 
 @pytest.mark.parametrize("padded", ["none", "some", "all"])
 @pytest.mark.parametrize("B", [128, 4096])
-@pytest.mark.parametrize("W", [128, 256])
+@pytest.mark.parametrize("W", [128, 256, 512])
 def test_the_kernel_is_at_set(W, B, padded):
     """Distinct real rows in any order; a lane on the scratch row
     writes nothing; the state donated and every other row untouched."""
@@ -53,10 +54,13 @@ def test_what_takes_the_kernel(monkeypatch):
     divides: read from the traced operand alone."""
     monkeypatch.setattr(row_scatter, "INTERPRET_OFF_TPU", True)
     wide = jax.ShapeDtypeStruct((N, 2, 128), np.int32)
+    four = jax.ShapeDtypeStruct((N, 4, 128), np.int32)   # 32 nodes' row
     narrow = jax.ShapeDtypeStruct((N, 128), np.int32)
     flat = jax.ShapeDtypeStruct((N, 256), np.int32)      # a sharded state
     assert row_scatter.eligible(wide, 131_072)
     assert row_scatter.eligible(wide, 128)       # the run's write-back
+    assert row_scatter.eligible(four, 131_072)
+    assert row_scatter.eligible(four, 4_096)     # a second round
     assert not row_scatter.eligible(wide, 131_072 + 1)
     assert not row_scatter.eligible(narrow, 131_072)
     assert not row_scatter.eligible(flat, 131_072)

@@ -1,8 +1,8 @@
 """The benchmark's readers of a batch's way back, on hand-made records.
 
-``benchmark/layers/way_back.py`` and ``benchmark/layers/streams.py`` on
-hand-made rings and ``benchmark/layers/round_trip.py`` on a hand-made
-``Trace``
+``benchmark/layers/way_back.py``, ``benchmark/layers/streams.py`` and
+``benchmark/layers/state_bytes.py`` on hand-made rings and
+``benchmark/layers/round_trip.py`` on a hand-made ``Trace``
 (``benchmark/lib/xplane.py``): known intervals in, known per-batch
 values out; a busy plane gives 0; a program without the spans yields
 nothing.  And once through ``benchmark/run.py`` itself, as a rehearsal
@@ -26,6 +26,7 @@ _added = [p for p in (BENCH, os.path.join(BENCH, "layers"))
 sys.path[:0] = _added   # the readers import program_spans and lib.xplane
 try:
     import round_trip   # noqa: E402  (benchmark/layers/round_trip.py)
+    import state_bytes  # noqa: E402  (benchmark/layers/state_bytes.py)
     import streams      # noqa: E402  (benchmark/layers/streams.py)
     import way_back     # noqa: E402  (benchmark/layers/way_back.py)
     from lib import xplane  # noqa: E402  (benchmark/lib/xplane.py)
@@ -195,6 +196,44 @@ def test_second_stream_cycles_that_owe_nothing_read_zero():
     assert streams.read(make_run(ring, wanted=STREAM2)) == {
         "events.stream2_rows_per_batch": 0.0,
         "events.stream2_emit_ms_per_batch": 0.0}
+
+
+# -- the resident bytes a batch's steps gather (layers/state_bytes.py) ---------
+
+GATHERED = ["events." + state_bytes.NAME, "events.rows_per_batch"]
+
+
+def bytes_of(cid, t, lanes, width=512):
+    """The dense engine's ``state_bytes`` count of a cycle: a tuple of
+    no width inside its ``ingest``, ``lanes`` rows of ``width`` words."""
+    return span(cid, "state_bytes", t + 3.0, t + 3.0, lanes * width * 4)
+
+
+def test_the_gathered_bytes_are_the_mean_over_the_clean_batches():
+    # three clean batches: two of 1,024 + 512 lanes, one of 1,024 alone;
+    # the batch before the window and the profiler's are not read
+    ring = (cycle(1, -100) + [bytes_of(1, -100, 9_999)]
+            + cycle(2, 0) + [bytes_of(2, 0, 1_536)]
+            + cycle(3, 100, back=False) + [bytes_of(3, 100, 1_024)]
+            + cycle(4, 200) + [bytes_of(4, 200, 1_536)]
+            + cycle(5, 300) + [bytes_of(5, 300, 9_999)])
+    assert state_bytes.read(make_run(ring, wanted=GATHERED)) == {
+        "events.gathered_bytes_per_batch": pytest.approx(
+            (1_536 + 1_024 + 1_536) * 2_048 / 3)}
+    # the flagship's row is half as wide: half the bytes a lane
+    narrow = [s for n in range(3) for s in cycle(2 + n, 100 * n)
+              + [bytes_of(2 + n, 100 * n, 1_536, width=256)]]
+    assert state_bytes.read(make_run(narrow, wanted=GATHERED)) == {
+        "events.gathered_bytes_per_batch": 1_536 * 1_024}
+    # a reader asked for another name reads nothing
+    assert state_bytes.read(make_run(ring, wanted=NAMES)) == {}
+
+
+def test_a_ring_without_the_count_yields_no_gathered_bytes():
+    # a program from before the count (the parent): nothing, never 0
+    old = [s for n in range(4) for s in cycle(2 + n, 100 * n)]
+    assert state_bytes.read(make_run(old, wanted=GATHERED)) == {}
+    assert state_bytes.read(make_run([], wanted=GATHERED)) == {}
 
 
 # -- the shared clock ----------------------------------------------------------
