@@ -25,13 +25,31 @@ than on ``[N, 256]`` (8.4 against 4.7 ms for 131,072 rows, PERF.md
 section 6, PR 58), so a state the kernel will not write keeps the flat
 one.  A batch is then one gather of B rows and one scatter of B rows;
 the fields are split out of the gathered rows (a relayout of B rows,
-never of N), and the donated ``rows`` array is updated in place.  Trailing dims of 16 x 4 as separate ``[N, S, I]``
-arrays made XLA put the partition axis on the lanes and transpose the
-whole state twice a step (PERF.md, PR 27).
+never of N), and the donated ``rows`` array is updated in place.
+Trailing dims of 16 x 4 as separate ``[N, S, I]`` arrays made XLA put the
+partition axis on the lanes and transpose the whole state twice a step
+(PERF.md, PR 27).  The way back, ``join``, is the split's mirror and
+costs three passes over the batch's rows where the chip owes one: the
+automaton leaves a field batch-on-lanes with its 4 instance lanes on the
+sublanes, and XLA re-tiles each field to tiles of 8 (a ``reshape``),
+transposes it (a ``copy``) and joins the vectors by a padded add.  No
+form of it written in XLA saves half a millisecond of the 3.1 it takes
+on 2,048-byte rows (PERF.md section 6, PR 61: the fields concatenated
+along the node axis first, -0.2 to -0.3 ms of the step; a preallocated
+buffer under ``dynamic_update_slice``, flat fields and an explicit
+transposition, slower or level), so it stands as it was; a Pallas kernel
+does it in one pass (0.75 ms, -1.8 ms of the step) and is the next
+issue's, with the split's.
 
 W and the offsets follow from S, I and the register allocator alone;
 no app, annotation or option selects anything here.  ``overflow`` stays
 its own vector: ``overflow_total`` sums it without touching the rows.
+**It is written only by a batch that dropped an instance** (``scatter``:
+the add stands behind a conditional on the batch's own increments; a
+batch inside its ``instances``, which is every batch of a deployment
+that heeds the overflow warning, reads their reduction and hands the
+donated vector on untouched, where the add of 131,072 zeros took 0.92
+ms of every step on a v5e, PERF.md section 6, PR 61).
 
 A **snapshot** is the logical state, made on the device: ``logical``
 traces the split of every row into fresh arrays (the steps donate
@@ -395,7 +413,16 @@ class DenseStateLayout:
         batch rows all point at the scratch row: they write back the
         words they gathered (``old``), or nothing at all where the
         row-scatter kernel takes the call, and add 0, so the scratch row
-        and every row outside the batch keep their values."""
+        and every row outside the batch keep their values.
+
+        The ``overflow`` vector is written when a valid lane of this
+        call dropped an instance, and then takes the add lane for lane;
+        otherwise the conditional's quiet branch hands the donated
+        vector on, with no scatter and no copy (the compiled step holds
+        it so: ``tests/test_dense_layout_overflow.py``).  What decides
+        is the batch's own ``ovf_delta``: in a rounds program's loops
+        each round's, under ``shard_map`` each shard's (no collective)."""
+        import jax
         import jax.numpy as jnp
 
         from siddhi_tpu.kernels import row_scatter
@@ -417,8 +444,11 @@ class DenseStateLayout:
             keep = valid[(slice(None),) + (None,) * len(self.row_shape)]
             rows = rows.at[part_idx].set(
                 jnp.where(keep, self.join(fields), old))
+        dropped = jnp.where(valid, ovf_delta, 0)
         return {
             ROWS: rows,
-            OVERFLOW: state[OVERFLOW].at[part_idx].add(
-                jnp.where(valid, ovf_delta, 0)),
+            OVERFLOW: jax.lax.cond(
+                jnp.any(dropped != 0),
+                lambda ovf: ovf.at[part_idx].add(dropped),
+                lambda ovf: ovf, state[OVERFLOW]),
         }
